@@ -13,7 +13,7 @@ This run replaces that regime:
   seed), evaluated every 250 steps through the STEP-KEYED eval_factory
   (r4's fix, now exercised across a crash-resume end to end);
 - trainer: the r5 headline operating point — b12 x T2048, remat_skip=6,
-  adafactor, param_storage=bfloat16_sr (R5SWEEP.jsonl: 14,605 tok/s) —
+  adafactor, param_storage=bfloat16_sr —
   so the convergence story covers the storage mode the benches ship;
 - same deliberate mid-async-save SIGKILL + crash-resume as v1.
 

@@ -51,11 +51,9 @@ def run(tag, batch_size, seq_len=2048, iters=10, **model_kw):
 
 
 if __name__ == "__main__":
-    import jax
+    from orion_tpu.utils.cache import enable_compile_cache
 
-    cache_dir = "/root/repo/.jax_cache"
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
+    enable_compile_cache()
 
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     exps = {

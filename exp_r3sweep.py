@@ -59,7 +59,7 @@ def run(tag, batch_size, seq_len=2048, iters=8, **model_kw):
 if __name__ == "__main__":
     from orion_tpu.utils.cache import enable_compile_cache
 
-    enable_compile_cache("/root/repo/.jax_cache")
+    enable_compile_cache()
     which = sys.argv[1:] or ["0", "2", "4", "6"]
     for k in which:
         run(f"b16_fusedce_skip{k}", 16, remat_skip=int(k))

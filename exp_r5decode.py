@@ -53,7 +53,7 @@ def decode_only(config: str, prompt_len: int, quant: str = "") -> dict:
 if __name__ == "__main__":
     from orion_tpu.utils.cache import enable_compile_cache
 
-    enable_compile_cache("/root/repo/.jax_cache")
+    enable_compile_cache()
     for cfg in ("lm_1b3", "hybrid_1b3"):
         for p in (512, 16384):
             decode_only(cfg, p)

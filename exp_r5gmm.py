@@ -3,8 +3,8 @@ vs a >=95% target; the r4 diagnosis blamed backward scatter/gather
 transposes + dw traffic). The dw kernel re-reads x nh times and dy nd
 times, so its HBM bill scales with nd*nh — this sweeps the dw output-tile
 size at the flagship dropless shapes (m=24576 padded rows, d=2048,
-h=5504, E=4) and times the FULL gmm fwd+bwd. Emits JSON lines appended
-to R5GMM.jsonl.
+h=5504, E=4) and times the FULL gmm fwd+bwd. Emits JSON lines on
+stdout.
 """
 import functools
 import json
@@ -52,7 +52,7 @@ def bench(bd, bh, iters=20):
 if __name__ == "__main__":
     from orion_tpu.utils.cache import enable_compile_cache
 
-    enable_compile_cache("/root/repo/.jax_cache")
+    enable_compile_cache()
     for bd, bh in [(512, 512), (1024, 512), (1024, 1024), (2048, 1024),
                    (1024, 2048), (2048, 688)]:
         bench(bd, bh)

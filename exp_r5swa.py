@@ -107,7 +107,7 @@ def bench_step(tag, config, bq=512, bk=512, iters=10):
 if __name__ == "__main__":
     from orion_tpu.utils.cache import enable_compile_cache
 
-    enable_compile_cache("/root/repo/.jax_cache")
+    enable_compile_cache()
     phases = sys.argv[1:] or ["kernel", "step"]
     if "kernel" in phases:
         bench_kernel(512, 512, banded=False)  # the r4 masked-grid control

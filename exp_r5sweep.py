@@ -6,8 +6,7 @@ bf16 storage + stochastic-rounding updates halves both the persistent
 param bytes and the grad buffer (~5.3GB back at 1.3B), which should buy
 un-rematted blocks (~11ms each by the r3/r4 accounting). Control row
 reproduces the fp32 headline at its shipped operating point. Emits one
-JSON line per point (appended by the caller to R5SWEEP.jsonl — the
-machine artifact the round's claims trace to).
+JSON line per point on stdout.
 """
 import dataclasses as dc
 import json
@@ -97,6 +96,6 @@ PHASES = {
 if __name__ == "__main__":
     from orion_tpu.utils.cache import enable_compile_cache
 
-    enable_compile_cache("/root/repo/.jax_cache")
+    enable_compile_cache()
     for phase in (sys.argv[1:] or ["phase1"]):
         PHASES[phase]()

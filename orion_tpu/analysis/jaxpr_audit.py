@@ -54,7 +54,8 @@ COLLECTIVE_PRIMS = frozenset({
 })
 
 HOST_CALLBACK_PRIMS = frozenset({
-    "debug_callback", "pure_callback", "io_callback", "callback",
+    "debug_callback", "debug_print", "pure_callback", "io_callback",
+    "callback",
     "outside_call", "infeed", "outfeed",
 })
 
@@ -77,12 +78,15 @@ def iter_eqns(jaxpr) -> Iterator[Any]:
 
 
 def _user_frames(eqn) -> List[Any]:
-    try:
-        from jax._src import source_info_util
+    """The eqn's Python frames outside jax itself, innermost first (each
+    has ``file_name``, ``function_name`` — a qualname — and ``line_num``)."""
+    import jax
 
-        return list(source_info_util.user_frames(eqn.source_info))
-    except Exception:
+    tb = eqn.source_info.traceback
+    if tb is None:
         return []
+    jax_dir = os.path.dirname(os.path.abspath(jax.__file__)) + os.sep
+    return [fr for fr in tb.frames if not fr.file_name.startswith(jax_dir)]
 
 
 def _repo_root() -> str:
@@ -93,12 +97,9 @@ def _repo_root() -> str:
 
 def _where(eqn, target: str) -> Tuple[str, int]:
     for fr in _user_frames(eqn):
-        fname = getattr(fr, "file_name", "") or ""
-        line = getattr(fr, "start_line", None) or getattr(fr, "line_num", 0)
-        if fname:
-            # repo-relative like Tier A findings, so baseline.json entries
-            # match on any checkout
-            return normalize_path(fname, _repo_root()), int(line or 0)
+        # repo-relative like Tier A findings, so baseline.json entries
+        # match on any checkout
+        return normalize_path(fr.file_name, _repo_root()), int(fr.line_num)
     return f"<jaxpr:{target}>", 0
 
 
@@ -106,9 +107,10 @@ def _scope_names(eqn) -> List[str]:
     """'file.py' and 'file.py::function' labels for every user frame."""
     out = []
     for fr in _user_frames(eqn):
-        base = (getattr(fr, "file_name", "") or "").rsplit("/", 1)[-1]
-        fn = getattr(fr, "function_name", "") or ""
-        out.extend((base, f"{base}::{fn}"))
+        base = fr.file_name.rsplit("/", 1)[-1]
+        fn = fr.function_name
+        # scopes are declared by bare function name; frames carry qualnames
+        out.extend((base, f"{base}::{fn}", f"{base}::{fn.rsplit('.', 1)[-1]}"))
     return out
 
 

@@ -30,7 +30,6 @@ deliberately-broken toys and doctored budgets.
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 from orion_tpu.analysis.findings import Finding
@@ -66,20 +65,13 @@ def ensure_cpu_devices(n: int = N_VIRTUAL_DEVICES) -> Optional[str]:
     import jax
 
     try:
-        from jax._src import xla_bridge
-
-        initialized = xla_bridge.backends_are_initialized()
-    except Exception:
-        initialized = True  # can't tell: just inspect the live backend
-    if not initialized:
+        # jax refuses this update once a backend is live: that is the
+        # "already initialized" case, judged by the check below
+        jax.config.update("jax_num_cpu_devices", n)
+    except RuntimeError:
+        pass
+    else:
         jax.config.update("jax_platforms", "cpu")
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            # the installed jax (0.4.x) predates jax_num_cpu_devices; the
-            # XLA flag is honored as long as no backend has initialized
-            os.environ["XLA_FLAGS"] = (
-                flags + f" --xla_force_host_platform_device_count={n}"
-            ).strip()
     # golden snapshots (analysis/snapshots.py) hash the compiled program;
     # partitionable threefry is what the test mesh uses — pin it so the
     # CLI and pytest produce byte-identical artifacts

@@ -169,9 +169,10 @@ def _decode_abstracts(model_cfg, slots: int, qmode: str, tp: int):
     model = TransformerLM(model_cfg, quant=qmode if qmode != "off" else "")
     mesh = None
     if tp > 1:
-        from orion_tpu.parallel.decode import serving_mesh
+        from orion_tpu.parallel.decode import mesh_model, serving_mesh
 
         mesh = serving_mesh(tp)
+        model = mesh_model(model, mesh)
 
     prompt = jax.ShapeDtypeStruct((1, 8), jnp.int32)
     abstract = jax.eval_shape(model.init, jax.random.PRNGKey(0), prompt)
@@ -298,9 +299,11 @@ def decode_cost_entries(
     pchunk = 0
     if int(prefill_chunk) > 0 and int(bucket) > 0:
         from orion_tpu.ops.dispatch import resolve, resolve_chunk
+        from orion_tpu.parallel.decode import mesh_backend
 
         align = resolve_chunk(
-            model_cfg.chunk, model_cfg.max_seq_len, resolve(model_cfg.backend)
+            model_cfg.chunk, model_cfg.max_seq_len,
+            resolve(mesh_backend(model_cfg.backend, tp)),
         )
         pchunk = -(-int(prefill_chunk) // align) * align
         pbuf = shaped((slots, int(bucket)), jnp.int32)
@@ -398,9 +401,11 @@ def decode_plan(
     pchunk = 0
     if int(prefill_chunk) > 0:
         from orion_tpu.ops.dispatch import resolve, resolve_chunk
+        from orion_tpu.parallel.decode import mesh_backend
 
         align = resolve_chunk(
-            model_cfg.chunk, model_cfg.max_seq_len, resolve(model_cfg.backend)
+            model_cfg.chunk, model_cfg.max_seq_len,
+            resolve(mesh_backend(model_cfg.backend, tp)),
         )
         pchunk = -(-int(prefill_chunk) // align) * align
     for bucket in prefill_buckets or ():

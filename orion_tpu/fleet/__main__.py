@@ -22,6 +22,7 @@ import sys
 from orion_tpu.fleet.replica import (
     LocalReplica,
     ProcessReplica,
+    ReplicaGone,
     ReplicaSpec,
     build_model,
     serve_config,
@@ -337,10 +338,16 @@ def main(argv=None) -> int:
             queue_high=float(args.replica_max_inflight),
             queue_low=max(args.replica_max_inflight / 4.0, 1.0),
         )
-    sup = Supervisor(
-        factory, args.replicas, max_inflight=args.max_inflight,
-        tracer=tracer, autoscale=autoscale,
-    ).start()
+    try:
+        sup = Supervisor(
+            factory, args.replicas, max_inflight=args.max_inflight,
+            tracer=tracer, autoscale=autoscale,
+        ).start()
+    except ReplicaGone as e:
+        # e.g. process replicas outnumber the chips (one process per
+        # chip; --local shares one client): one error, no respawn loop
+        print(f"fleet failed to start: {e}", file=sys.stderr)
+        return 1
     sup.start_monitor(interval=args.heartbeat_s)
     rc = 0
     completed = []

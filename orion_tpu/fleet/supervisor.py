@@ -161,7 +161,14 @@ class Supervisor:
     # -- lifecycle ------------------------------------------------------------
 
     def start(self) -> "Supervisor":
-        self.replicas = [self._spawn(i) for i in range(self.n)]
+        try:
+            for i in range(self.n):
+                self.replicas.append(self._spawn(i))
+        except BaseException:
+            # a fleet that cannot reach its size does not start: reap the
+            # replicas already up rather than orphan them
+            self.kill_all()
+            raise
         self.router = Router(
             self.replicas, max_inflight=self._max_inflight,
             clock=self._clock, tracer=self._tracer,

@@ -164,9 +164,14 @@ LM_1B3 = ModelConfig(
     max_seq_len=2048,
     dtype="bfloat16",
     remat=True,
-    # 4 un-rematted blocks fit the 16GB v5e at batch 16 x T 2048 once the
-    # fused-CE loss stops materializing fp32 logits; 6 no longer compile
-    # there. Worth +2.7% step time on-chip (BASELINE.md round-3 rows).
+    # un-rematted blocks: a trade of HBM for recompute, chosen by earlier
+    # rounds' sweeps on another compiler. Under the installed one, for one
+    # 16GB v5e at T 2048 with adafactor and the Pallas kernels
+    # (tests/test_chip_compile.py, PERF.md "Cells"): b12 x skip6 x
+    # bfloat16_sr compiles AND runs on the chip (chip_smoke.py's train
+    # phase); b16 x skip4 and b16 x skip6 (bfloat16_sr), b16 x skip4 and
+    # b12 x skip6 (float32) compile for the chip, not run. Which is
+    # fastest: not measured on the current installation.
     remat_skip=4,
 )
 
@@ -198,8 +203,8 @@ HYBRID_1B3 = ModelConfig(
     max_seq_len=2048,
     dtype="bfloat16",
     remat=True,
-    # fits b16 x T2048 on the 16GB chip with fused CE; 6 fails to compile
-    # there (same sweep as LM_1B3's — BASELINE.md "batch x remat_skip")
+    # chosen by the same earlier sweep as LM_1B3's; which points fit under
+    # the installed compiler: see PERF.md "Cells" (not run on the chip)
     remat_skip=4,
 )
 

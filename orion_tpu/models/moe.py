@@ -499,7 +499,7 @@ class MoEMLP(nn.Module):
         COUNTED (sown into "moe_stats"/"dropless_overflow"), never silent.
         The capacity path remains the bounded-activation alternative.
         """
-        from orion_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         cfg = self.cfg
@@ -618,7 +618,7 @@ class MoEMLP(nn.Module):
         tests/test_moe.py (interpret mode); the real-Mosaic compile is
         covered by the fsdp x ep topology-AOT artifact and the driver
         dryrun line."""
-        from orion_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from orion_tpu.ops.pallas.gmm import gmm, pad_group_sizes
@@ -692,9 +692,9 @@ class MoEMLP(nn.Module):
                 # cast's transpose psum trips the variant check — the
                 # legacy spec-based transpose handles the replicated
                 # input there instead.
-                from orion_tpu.utils.compat import pvary
-
-                ws = tuple(pvary(w, row_axes) for w in ws)
+                ws = tuple(
+                    jax.lax.pcast(w, row_axes, to="varying") for w in ws
+                )
             if cfg.mlp == "swiglu":
                 wgl, wul, wdl = ws
                 mid = jax.nn.silu(

@@ -16,21 +16,15 @@ import jax
 _VALID = ("auto", "xla", "pallas", "pallas_interpret", "eager")
 
 
-def _pallas_available() -> bool:
-    try:
-        from orion_tpu.ops.pallas import causal_dot  # noqa: F401
-
-        return True
-    except ImportError:
-        return False
-
-
 def default_backend() -> str:
-    try:
-        plat = jax.devices()[0].platform
-    except RuntimeError:
-        plat = "cpu"
-    return "pallas" if plat == "tpu" and _pallas_available() else "xla"
+    """What ``auto`` means on this process's device: the Pallas kernel on a
+    TPU (imported here, so a kernel module that cannot load is an error on
+    the chip, never a silent XLA scan), the XLA scan elsewhere."""
+    if jax.devices()[0].platform != "tpu":
+        return "xla"
+    from orion_tpu.ops.pallas import causal_dot  # noqa: F401
+
+    return "pallas"
 
 
 def resolve(backend: str) -> str:

@@ -123,7 +123,7 @@ def _sp_fused_ce(
     transpose. The [B, T, V] logits now never materialize on sp meshes
     either, which is exactly the memory that T=64k sp runs need back
     (r3 VERDICT #2)."""
-    from orion_tpu.utils.compat import pvary, shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     sp = mesh.shape["sp"]
@@ -135,7 +135,7 @@ def _sp_fused_ce(
         # over sp that the (sp-varying) dw cotangent needs on its way back
         # to the unvarying P(None) input — the same idiom pipeline.py uses
         # for its pp-replicated microbatch input
-        wl = pvary(wl, ("sp",))
+        wl = jax.lax.pcast(wl, ("sp",), to="varying")
         return _padded_fused_ce(xs, wl, ys, w_is_vd)
 
     fn = shard_map(

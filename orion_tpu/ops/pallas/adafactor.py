@@ -323,11 +323,7 @@ def apply_updates(
     finite guard exactly like Trainer._train_step's safe_grads; ``finite``
     keeps params AND stats untouched on a bad step (the skip policy)."""
     if backend == "auto":
-        try:
-            plat = jax.devices()[0].platform
-        except RuntimeError:
-            plat = "cpu"
-        backend = "pallas" if plat == "tpu" else "jnp"
+        backend = "pallas" if jax.devices()[0].platform == "tpu" else "jnp"
     use_kernel = backend in ("pallas", "interpret")
     interpret = backend == "interpret"
 

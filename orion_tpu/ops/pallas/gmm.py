@@ -54,8 +54,8 @@ def _vma_union_like(a: Array, b: Array) -> Array:
     return a.reshape(-1)[:1] * b.reshape(-1)[:1].astype(a.dtype)
 
 
-# dw-kernel output tile (see _gmm_bwd): chip-swept at the flagship
-# dropless shapes (exp_r5gmm.py -> R5GMM.jsonl)
+# dw-kernel output tile (see _gmm_bwd): tuned by an earlier round's sweep
+# (exp_r5gmm.py); not measured on the current installation
 _DW_BLOCK_D = 1024
 _DW_BLOCK_H = 1024
 
@@ -213,7 +213,6 @@ def _gmm_bwd(tile_rows, block_h, interpret, res, dy):
     # HBM bill; the (1, bd, bh) fp32 dw block is the VMEM bound
     # (1024x1024 = 4MB, well under the 16MB stack — the r4 OOM note was
     # the FWD kernel's [d, block_h] weight blocks, not these).
-    # R5GMM.jsonl: dw-block sweep at the flagship dropless shapes.
     dw = _dw_call(
         x, dyc, te, e, tile_rows,
         min(_DW_BLOCK_D, x.shape[1]), min(_DW_BLOCK_H, dy.shape[1]),

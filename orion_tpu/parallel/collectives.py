@@ -23,7 +23,6 @@ from typing import Union
 import jax
 from jax import lax
 
-from orion_tpu.utils import compat
 
 Array = jax.Array
 Axis = Union[str, tuple]
@@ -33,7 +32,7 @@ def ppermute_shift(x: Array, axis: str, shift: int = 1) -> Array:
     """Rotate shards around the ring: device i -> device (i+shift) % n —
     the neighbor-to-neighbor ICI hop ring attention (ring.py) runs on.
     (pipeline.py's stage rotation builds the same perm inline.)"""
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis, perm)
 

@@ -116,6 +116,33 @@ def place_decode_carry(carry: Any, mesh) -> Any:
     )
 
 
+def mesh_backend(backend: str, tp: int) -> str:
+    """The kernel backend a tp footprint serves with. The serving programs
+    are mesh-aware by PLACEMENT alone — no shard_map — and a Mosaic kernel
+    inside a GSPMD-partitioned program is refused by the TPU compiler
+    ("Mosaic kernels cannot be automatically partitioned"; seen compiling
+    the ``lm_1b3`` in-scan prefill for a v5e:2x2). So on tp > 1 the
+    prefill's attention is pinned to the XLA forms, the rule the pp
+    pipeline already follows (models/transformer.py); the decode step has
+    no kernel to lose. Manualizing the prefill kernels over tp
+    (parallel/kernel_shard.py) is the faster repair, not made yet."""
+    from orion_tpu.ops.dispatch import resolve
+
+    if int(tp) > 1 and resolve(backend) == "pallas":
+        return "xla"
+    return backend
+
+
+def mesh_model(model: Any, mesh) -> Any:
+    """``model`` with :func:`mesh_backend` applied for ``mesh``."""
+    import dataclasses
+
+    backend = mesh_backend(model.cfg.backend, _mesh_axis(mesh, "tp"))
+    if backend == model.cfg.backend:
+        return model
+    return model.clone(cfg=dataclasses.replace(model.cfg, backend=backend))
+
+
 def place_replicated(x: Any, mesh) -> Any:
     """Replicate a host/device value over the mesh (rng table, staged
     prompt buffer, prompt-length vectors)."""
@@ -285,6 +312,8 @@ __all__ = [
     "place_decode_params",
     "decode_state_shardings",
     "place_decode_carry",
+    "mesh_backend",
+    "mesh_model",
     "place_replicated",
     "serving_mesh",
     "bytes_per_device",

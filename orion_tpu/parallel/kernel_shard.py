@@ -25,7 +25,7 @@ softmax/swa).
 from __future__ import annotations
 
 import jax
-from orion_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 _BH_AXES = ("dp", "fsdp", "tp")

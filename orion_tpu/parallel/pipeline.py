@@ -36,9 +36,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from orion_tpu.utils.compat import pvary, shard_map
 
 Array = jax.Array
 
@@ -187,7 +186,7 @@ def pipeline_apply(
         # the scan carry is device-varying (each stage holds different
         # activations); mark the replicated initializers/input accordingly
         # so shard_map's varying-mesh-axes check can verify the body
-        micro = pvary(micro, (axis,))
+        micro = lax.pcast(micro, (axis,), to="varying")
 
         n_steps = n_micro + pp - 1
         zeros = jnp.zeros_like(micro[0])
@@ -196,7 +195,7 @@ def pipeline_apply(
         aux_axes = (axis,) + tuple(extra_manual_axes)
         if full_manual:
             aux_axes = aux_axes + ("dp", "fsdp")
-        aux0 = pvary(aux0, aux_axes)
+        aux0 = lax.pcast(aux0, aux_axes, to="varying")
 
         def step(carry, s):
             buf, outs, aux_tot = carry

@@ -36,9 +36,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from orion_tpu.utils.compat import axis_size, shard_map
 
 from orion_tpu.parallel.collectives import ppermute_shift
 
@@ -105,7 +104,7 @@ def swa_halo_attention_local(
         scale = q.shape[-1] ** -0.5
     from orion_tpu.ops.pallas.flash_attention import flash_attention_lse
 
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     i = lax.axis_index(axis)
     t_loc = q.shape[-2]
     # a query reaches back window-1 tokens, so the deepest halo block is
@@ -193,7 +192,7 @@ def ring_attention_local(
     is the einsum online-softmax fold."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     i = lax.axis_index(axis)
     t_loc = q.shape[-2]
     if striped:

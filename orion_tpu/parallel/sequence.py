@@ -26,9 +26,8 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from orion_tpu.utils.compat import shard_map
 
 from orion_tpu.ops.dispatch import causal_dot_product
 

@@ -243,7 +243,7 @@ class Int4Dense(nn.Module):
         # which prefill's B*T rows overflow (prefill is MXU-bound anyway,
         # the split form below serves it fine)
         if (
-            jax.default_backend() != "cpu"
+            jax.default_backend() == "tpu"
             and (self.mesh is None or self.mesh.devices.size == 1)
             and x2.shape[0] <= 64
         ):

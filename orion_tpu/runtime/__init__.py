@@ -14,6 +14,7 @@ import ctypes
 import json
 import os
 import subprocess
+import sys
 from typing import Optional
 
 import numpy as np
@@ -22,6 +23,7 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO_PATH = os.path.join(_DIR, "liborion_runtime.so")
 
 _lib: Optional[ctypes.CDLL] = None
+_unloadable = False  # a present .so refused to load: don't retry, don't repeat
 
 
 def build(quiet: bool = True) -> bool:
@@ -38,8 +40,8 @@ def build(quiet: bool = True) -> bool:
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib
-    if _lib is not None:
+    global _lib, _unloadable
+    if _lib is not None or _unloadable:
         return _lib
     if not os.path.exists(_SO_PATH) and os.environ.get("ORION_TPU_BUILD_RUNTIME"):
         build()
@@ -47,7 +49,12 @@ def _load() -> Optional[ctypes.CDLL]:
         return None
     try:
         lib = ctypes.CDLL(_SO_PATH)
-    except OSError:
+    except OSError as e:
+        # present but unloadable (built for another machine): the same
+        # Python twins as a missing .so, said once
+        print(f"orion_tpu.runtime: {_SO_PATH} cannot load ({e}); using the "
+              "Python implementations", file=sys.stderr)
+        _unloadable = True
         return None
     lib.orion_loader_open.restype = ctypes.c_void_p
     lib.orion_loader_open.argtypes = [ctypes.c_char_p, ctypes.c_int64, ctypes.c_int]

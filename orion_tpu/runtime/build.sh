@@ -3,7 +3,7 @@
 # Plain C ABI — loaded via ctypes (orion_tpu/runtime/__init__.py).
 set -e
 cd "$(dirname "$0")"
-g++ -O3 -march=native -fPIC -shared -std=c++17 -pthread \
+g++ -O3 -fPIC -shared -std=c++17 -pthread \
     loader.cc tokenizer.cc bpe.cc corpusgen.cc \
     -o liborion_runtime.so
 echo "built $(pwd)/liborion_runtime.so"

@@ -373,8 +373,12 @@ class SlotEngine:
             _TP_EXEC_LOCK if mesh is not None else contextlib.nullcontext()
         )
         if mesh is not None:
-            from orion_tpu.parallel.decode import place_decode_params
+            from orion_tpu.parallel.decode import (
+                mesh_model,
+                place_decode_params,
+            )
 
+            self.model = model = mesh_model(model, mesh)
             params = place_decode_params(params, mesh)
         self.params = params
         self.slots = int(slots)

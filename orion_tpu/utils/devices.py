@@ -1,14 +1,13 @@
 """Virtual-device provisioning for CPU hosts (one copy, five callers).
 
 A tp footprint needs ``tp`` devices in the process. On a CPU host those
-are XLA's virtual host devices, requested via
-``--xla_force_host_platform_device_count`` — an XLA_FLAGS entry the
-backend reads ONCE at initialization (the installed jax predates
-``jax_num_cpu_devices``), so every caller must run before anything
-touches a device. The serving/fleet/bench/aot CLIs and the fleet child
-all share this helper instead of five hand-rolled env mutations;
-``analysis/spmd_audit.ensure_cpu_devices`` layers platform forcing and
-audit-error reporting on top for the analysis CLI.
+are XLA's virtual host devices, requested through ``jax_num_cpu_devices``
+— a setting the backend reads ONCE at initialization, so every caller must
+run before anything touches a device. The serving/fleet/bench/aot CLIs and
+the fleet child all share this helper; ``analysis/spmd_audit
+.ensure_cpu_devices`` layers platform forcing and audit-error reporting on
+top for the analysis CLI. On an accelerator host the setting is inert: the
+mesh is built from the real devices.
 """
 
 from __future__ import annotations
@@ -18,37 +17,30 @@ import warnings
 
 
 def ensure_virtual_devices(n: int) -> None:
-    """Append ``--xla_force_host_platform_device_count=n`` to XLA_FLAGS
-    unless some count is already pinned there (an operator's explicit
-    choice wins — also how nested callers compose: the first provisioner
-    sets it, later ones no-op). ``n <= 1`` never touches the env. If the
-    process's jax backend is ALREADY initialized with too few devices,
-    the flag would be silently unread — warn instead of mutating env
-    state that can no longer matter."""
+    """Ask for ``n`` virtual CPU devices unless some count is already
+    pinned (an operator's ``--xla_force_host_platform_device_count`` in
+    XLA_FLAGS wins, and so does an earlier, larger request — also how
+    nested callers compose). ``n <= 1`` never touches anything. If the
+    process's jax backend is ALREADY initialized jax refuses the update —
+    warn when that leaves too few devices."""
     if n is None or int(n) <= 1:
         return
+    if "xla_force_host_platform_device_count" in os.environ.get("XLA_FLAGS", ""):
+        return
+    import jax
+
+    if jax.config.jax_num_cpu_devices >= int(n):
+        return
     try:
-        from jax._src import xla_bridge
-
-        if xla_bridge.backends_are_initialized():
-            import jax
-
-            if jax.device_count() < int(n):
-                warnings.warn(
-                    f"ensure_virtual_devices({n}) called after the jax "
-                    f"backend initialized with {jax.device_count()} "
-                    "device(s) — XLA_FLAGS can no longer take effect; "
-                    "provision before the first device op",
-                    stacklevel=2,
-                )
-            return
-    except Exception:
-        pass  # can't tell — set the flag; worst case it goes unread
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={int(n)}"
-        ).strip()
+        jax.config.update("jax_num_cpu_devices", int(n))
+    except RuntimeError:  # backend already initialized
+        if jax.device_count() < int(n):
+            warnings.warn(
+                f"ensure_virtual_devices({n}) called after the jax "
+                f"backend initialized with {jax.device_count()} "
+                "device(s) — provision before the first device op",
+                stacklevel=2,
+            )
 
 
 __all__ = ["ensure_virtual_devices"]

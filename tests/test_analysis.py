@@ -1419,8 +1419,9 @@ def test_extract_collectives_scope_and_dtype():
 
 
 def test_budget_dtype_checks_every_operand():
-    # one psum eqn over a (bf16, f32) tuple: the f32 payload must not hide
-    # behind the first operand's dtype
+    # a psum over a (bf16, f32) tuple — one eqn with two operands, or (the
+    # installed jax) one eqn per dtype: either way the f32 payload must not
+    # hide behind the first operand's dtype
     def fn(a, b):
         return jax.lax.psum((a, b), "i")
 
@@ -1428,9 +1429,7 @@ def test_budget_dtype_checks_every_operand():
         jnp.ones((4,), jnp.bfloat16), jnp.ones((4,), jnp.float32)
     )
     sites = spmd_audit.extract_collectives(jx, "toy")
-    assert len(sites) == 1 and set(sites[0].dtypes) == {
-        "bfloat16", "float32"
-    }
+    assert {d for s in sites for d in s.dtypes} == {"bfloat16", "float32"}
     findings = spmd_audit.check_budget(
         sites, _toy_budget(dtypes=("bfloat16",)), "toy"
     )

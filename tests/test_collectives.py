@@ -17,7 +17,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from orion_tpu.parallel.collectives import exclusive_prefix_sum, ppermute_shift
 from orion_tpu.parallel.mesh import MeshConfig, make_mesh
-from orion_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 def _sp_mesh(sp):

@@ -302,6 +302,59 @@ class ScriptedReplica(FakeReplica):
         self._alive = False
 
 
+def test_fleet_that_cannot_reach_its_size_does_not_start():
+    """One process per chip: the second process replica on a one-chip host
+    cannot get a device. Its death must end the start with ONE error that
+    carries the child's own last words, reap the replicas already up, and
+    not be retried (the same wall stands behind every respawn)."""
+    from orion_tpu.fleet.replica import ReplicaGone
+
+    made = []
+
+    def factory(name):
+        if made:  # every replica after the first dies the same way
+            r = ProcessReplica(ReplicaSpec(config="tiny"), name=name)
+            r._dispatch({"event": "fatal",
+                         "message": "no device for this replica: busy"})
+            r._eof = True
+            r._ready.set()
+            r.kill = lambda: None
+            r.join = lambda timeout=None: True
+        else:
+            r = ScriptedReplica(name)
+        made.append(r)
+        return r
+
+    with pytest.raises(ReplicaGone, match="no device for this replica"):
+        Supervisor(factory, 2, spawn_retry=FAST_RETRY).start()
+    assert len(made) == 2  # ReplicaGone is not a transient: no retry
+    assert made[0].killed  # the survivor was reaped, not orphaned
+
+
+@pytest.mark.slow
+def test_process_fleet_without_a_device_fails_with_one_error(tmp_path):
+    """The real thing, end to end: children that cannot initialize their
+    backend (here: a TPU asked for on a box without one) make the fleet
+    CLI exit non-zero, in bounded time, with the cause on stderr — no
+    hang, no respawn loop."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="tpu", TPU_LOG_DIR="disabled")
+    proc = subprocess.run(
+        [sys.executable, "-m", "orion_tpu.fleet", "--replicas", "2",
+         "--config", "tiny", "--max-new-tokens", "4"],
+        input="hello\n", env=env, text=True, capture_output=True,
+        timeout=240,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert proc.returncode == 1, proc.stderr[-2000:]
+    assert proc.stderr.count("fleet failed to start") == 1
+    assert "no device for this replica" in proc.stderr
+    assert "respawning" not in proc.stderr
+
+
 def _scripted_fleet(n, pol):
     made = []
 

@@ -93,7 +93,7 @@ def quantize_all(params):
                      table=_table())
     )
     sm = """
-from orion_tpu.utils.compat import shard_map
+from jax import shard_map
 
 def my_launcher(f, mesh, specs):
     return shard_map(f, mesh=mesh, in_specs=specs, out_specs=specs)

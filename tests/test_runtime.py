@@ -214,3 +214,21 @@ def test_corpusgen_adjacent_seeds_decorrelated(so_built, small_corpus):
                 xs = x[max(0, shift):4000 + min(0, shift)]
                 ys = y[max(0, -shift):4000 - max(0, shift)]
                 assert (xs == ys).mean() < 0.5, (i, j, shift)
+
+
+def test_unloadable_so_falls_back_like_a_missing_one(tmp_path, monkeypatch,
+                                                     capsys):
+    """A .so that is present but cannot load (built for another machine)
+    takes the same Python twins as a missing one, saying so once."""
+    bad = tmp_path / "liborion_runtime.so"
+    bad.write_bytes(b"not an ELF object")
+    monkeypatch.setattr(runtime, "_SO_PATH", str(bad))
+    monkeypatch.setattr(runtime, "_lib", None)
+    monkeypatch.setattr(runtime, "_unloadable", False)
+    assert runtime.native_available() is False
+    assert runtime.native_available() is False  # no retry, no second line
+    err = capsys.readouterr().err
+    assert err.count("cannot load") == 1 and "Python implementations" in err
+    src = tmp_path / "t.txt"
+    src.write_text("hello")
+    assert runtime.byte_encode_file(str(src), str(tmp_path / "t.bin")) == 5
