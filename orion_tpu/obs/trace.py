@@ -26,6 +26,14 @@ Event model (Chrome trace-event format, ``ts``/``dur`` in microseconds):
   per-slot split does not exist on the device and is not invented here).
 - **instants** (``ph`` ``i``) — point events: staging, ladder rungs,
   eviction, suspension, dispatch.
+- **phase spans** (``ph`` ``X``, ``cat`` ``phase``) — what the scheduler
+  thread does inside one served boundary (``serving.PHASES``), written
+  through :meth:`Tracer.span`: ONE call site records the interval in
+  this ring and, while a ``jax.profiler`` capture runs, as a profiler
+  annotation of the same name — the same span on the program's clock
+  (every boundary) and on the device trace's clock (the captured ones).
+  This module never imports jax: the ``Server`` hands the annotation
+  factory in (:attr:`Tracer.annotate`) for the length of a capture.
 
 Wire format: one JSON object per line (JSONL), appended live — files
 from several processes (fleet parent + children) concatenate trivially.
@@ -54,6 +62,81 @@ from typing import Callable, List, Optional
 _EVENT_FIELDS = ("name", "cat", "ph", "ts", "id", "args")
 
 
+class Span:
+    """A closed interval of the calling thread, written twice from one
+    ``with``: a complete event in the tracer's ring (when the tracer is
+    enabled and ``record`` is still true at exit) and an annotation in
+    the profiler's capture (when the tracer holds an annotation factory
+    at entry). ``record=False`` leaves the ring write to :meth:`write` —
+    the serve loop learns only later whether an iteration was a boundary."""
+
+    __slots__ = ("_tracer", "name", "cat", "args", "record", "start", "dur",
+                 "_ann", "_open")
+
+    def __init__(self, tracer, name, cat, record, args):
+        self._tracer = tracer
+        self.name = name
+        self.cat = cat
+        self.args = args
+        self.record = record
+        self.start = self.dur = 0.0
+        self._ann = None
+        self._open = False
+
+    def __enter__(self):
+        annotate = self._tracer.annotate
+        if annotate is not None:
+            self._ann = annotate(self.name)
+            self._ann.__enter__()
+        self._open = True
+        self.start = self._tracer._clock()
+        return self
+
+    def __exit__(self, *exc):
+        self.dur = self._tracer._clock() - self.start
+        self._open = False
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self.record:
+            self.write()
+        return False
+
+    def note(self, **args) -> None:
+        """Arguments known only once the span's work is done."""
+        self.args.update(args)
+
+    def write(self) -> None:
+        """Into the ring: now if the span has closed, at its exit if it
+        is still open."""
+        if self._open:
+            self.record = True
+            return
+        self._tracer.complete(self.name, self.start, self.dur, cat=self.cat,
+                              **self.args)
+
+
+class _NullSpan:
+    """What a caller holds instead of a :class:`Span` when nothing would
+    be written: no ring, no capture (one shared instance)."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def note(self, **args) -> None:
+        pass
+
+    def write(self) -> None:
+        pass
+
+
+NULL_SPAN = _NullSpan()
+
+
 class Tracer:
     """One per process (or per Server in tests). ``path=None`` keeps
     events in the bounded in-memory ring only (tests read them via
@@ -74,6 +157,10 @@ class Tracer:
         self._lock = threading.Lock()
         self._buf: deque = deque(maxlen=capacity)
         self.dropped = 0  # events that aged out before a flush
+        # name -> context manager that writes the span into a running
+        # profiler capture; set by the owner of the capture for its
+        # length, None otherwise (this module stays free of jax)
+        self.annotate: Optional[Callable[[str], object]] = None
         if path:
             d = os.path.dirname(os.path.abspath(path))
             os.makedirs(d, exist_ok=True)
@@ -101,14 +188,25 @@ class Tracer:
         same (cat, id, name)."""
         self._emit(name, cat, "b", id=id, args=args or None)
 
-    def end(self, name: str, id: str, cat: str = "request", **args) -> None:
-        self._emit(name, cat, "e", id=id, args=args or None)
+    def end(self, name: str, id: str, cat: str = "request", at=None,
+            **args) -> None:
+        """Close an async span; ``at`` (seconds on the tracer's clock)
+        stamps the end where the caller already holds the moment."""
+        self._emit(name, cat, "e", id=id, args=args or None,
+                   ts=None if at is None else at * 1e6)
 
     def complete(self, name: str, start_s, dur_s, cat: str = "chunk",
                  **args) -> None:
         """A closed interval (``ph`` ``X``) from host timestamps."""
         self._emit(name, cat, "X", args=args or None,
                    ts=start_s * 1e6, dur=dur_s * 1e6)
+
+    def span(self, name: str, cat: str = "phase", record: bool = True,
+             **args) -> Span:
+        """``with tracer.span(name):`` — see :class:`Span`. Callers on a
+        hot path check ``enabled``/``annotate`` first and hold
+        :data:`NULL_SPAN` when both are off."""
+        return Span(self, name, cat, record, args)
 
     def instant(self, name: str, cat: str = "event", id=None, **args) -> None:
         self._emit(name, cat, "i", id=id, args=args or None)
@@ -248,5 +346,5 @@ if __name__ == "__main__":
 
 
 __all__ = [
-    "Tracer", "read_jsonl", "merge_traces", "span_pairs",
+    "Tracer", "Span", "NULL_SPAN", "read_jsonl", "merge_traces", "span_pairs",
 ]

@@ -155,6 +155,7 @@ def _pallas_sums(g: Array, s2: Array, eps: float, interpret: bool):
     bm = _row_block(m, n)
     s0, s1 = pl.pallas_call(
         functools.partial(_sums_kernel, eps),
+        name="adafactor_sums",
         grid=(m // bm,),
         in_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
@@ -178,6 +179,7 @@ def _pallas_rms(g: Array, r: Array, c: Array, interpret: bool) -> Array:
     bm = _row_block(m, n)
     acc = pl.pallas_call(
         _rms_kernel,
+        name="adafactor_rms",
         grid=(m // bm,),
         in_specs=[
             pl.BlockSpec((bm, n), lambda i: (i, 0)),
@@ -197,6 +199,7 @@ def _pallas_apply(g: Array, p: Array, r: Array, c: Array, finite: Array,
     bm = _row_block(m, n)
     return pl.pallas_call(
         _apply_kernel,
+        name="adafactor_apply",
         grid=(m // bm,),
         in_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0)),

@@ -122,6 +122,7 @@ def _cdp_flat(
     grid = (bh, nc)
     out, sf = pl.pallas_call(
         _kernel,
+        name="causal_dot_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, chunk, dk), lambda b, c: (b, c, 0), memory_space=pltpu.VMEM),
@@ -297,6 +298,7 @@ def _cdp_dq_den_flat(g, v, k, s0t, gden, z0, chunk, interpret):
 
     (dq,) = pl.pallas_call(
         _bwd_dq_den_kernel,
+        name="causal_dot_dq",
         grid=(bh, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, dv), lambda b, c: (b, c, 0), memory_space=pltpu.VMEM),
@@ -334,6 +336,7 @@ def _cdp_rev_den_flat(q, k, v, g, gden, rinit, zr0, chunk, interpret):
 
     dk_out, dv_out, rfin, zrfin = pl.pallas_call(
         _bwd_rev_den_kernel,
+        name="causal_dot_norm_dkv",
         grid=(bh, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, dk), rev, memory_space=pltpu.VMEM),
@@ -376,6 +379,7 @@ def _cdp_rev_flat(q, k, v, g, rinit, chunk, interpret):
 
     dk_out, dv_out, rfin = pl.pallas_call(
         _bwd_rev_kernel,
+        name="causal_dot_dkv",
         grid=(bh, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, dk), rev, memory_space=pltpu.VMEM),
@@ -536,6 +540,7 @@ def _cdpn_flat(q, k, v, s0, z0, chunk, interpret):
 
     num, den, sf, zf = pl.pallas_call(
         _kernel_norm,
+        name="causal_dot_norm_fwd",
         grid=(bh, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, dk), lambda b, c: (b, c, 0), memory_space=pltpu.VMEM),

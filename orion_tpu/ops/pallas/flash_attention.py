@@ -210,6 +210,7 @@ def _flash_fwd_flat(q, k, v, scale, causal, window, bq, bk, interpret, shift=0,
     )
     out, lse = pl.pallas_call(
         kern,
+        name="flash_attn_fwd",
         grid=(bh, nq, grid_k),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM),
@@ -383,6 +384,7 @@ def _flash_bwd_flat(q, k, v, out, lse, g, scale, causal, window, bq, bk, interpr
     )
     dq = pl.pallas_call(
         dq_kern,
+        name="flash_attn_dq",
         grid=(bh, nq, grid_k),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM),
@@ -417,6 +419,7 @@ def _flash_bwd_flat(q, k, v, out, lse, g, scale, causal, window, bq, bk, interpr
     )
     dk, dv_ = pl.pallas_call(
         dkv_kern,
+        name="flash_attn_dkv",
         grid=(bh, nk, grid_q),
         in_specs=[
             pl.BlockSpec((1, bq, d), qmap, memory_space=pltpu.VMEM),

@@ -103,6 +103,7 @@ def _gmm_call(x, w, tile_expert, tile_rows, block_h, interpret):
     )
     out = pl.pallas_call(
         _fwd_kernel,
+        name="gmm_fwd",
         out_shape=_sds((m, hp), x.dtype, _vma_union_like(x, w)),
         grid_spec=grid_spec,
         interpret=interpret,
@@ -156,6 +157,7 @@ def _dw_call(x, g, tile_expert, n_experts, tile_rows, block_d, block_h,
     )
     dw = pl.pallas_call(
         _dw_kernel,
+        name="gmm_dw",
         out_shape=_sds(
             (n_experts, nd * block_d, nh * block_h), jnp.float32,
             _vma_union_like(x, g),

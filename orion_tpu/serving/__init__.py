@@ -35,6 +35,7 @@ and tests/test_batching.py under the ``chaos`` marker.
 from orion_tpu.serving.batching import SlotEngine, parse_buckets
 from orion_tpu.serving.health import Health, HealthMachine, InvalidTransition
 from orion_tpu.serving.server import (
+    PHASES,
     OverloadError,
     Pending,
     RejectedError,
@@ -58,7 +59,7 @@ from orion_tpu.serving.session_store import (
 __all__ = [
     "Health", "HealthMachine", "InvalidTransition",
     "Server", "ServeConfig", "Pending", "OverloadError", "RejectedError",
-    "load_tokenizer", "SlotEngine", "parse_buckets",
+    "load_tokenizer", "SlotEngine", "parse_buckets", "PHASES",
     "DecodeRequest", "DecodeResult", "DecodeSession", "LadderExhausted",
     "SessionStore", "SessionState", "SessionIntegrityError",
     "PrefixStore", "PrefixEntry",

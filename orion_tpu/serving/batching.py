@@ -1141,29 +1141,35 @@ class SlotEngine:
             spec = jnp.asarray(self._spec_on_np)
         snap = self._snapshot()
         carry, toks, accepted = self._attempt(snap, active_dev, unified, spec)
+        # the boundary's two inner edges, for whoever times its phases
+        # (the Server's spans): everything up to here only ENQUEUED work;
+        # the probe below is where the host waits for the device
+        self._emit("phase", name="probe")
         bad = self._probe_bad(carry, active, accepted)
+        ladder = bool(bad)
         if bad:
             carry, toks, bad = self._ladder(
                 snap, active_dev, active, carry, toks, bad, unified, spec
             )
-            for i in sorted(bad):  # ladder exhausted: fail those requests
-                slot = self._slots[i]
-                # the failed slot's boundary work still ran — bill it by
-                # its class so attribution stays conservative. Mid-prefill
-                # failures weigh zero (the host cannot know which replay
-                # fed their piece); nothing was EMITTED either way.
-                self.last_boundary.append({
-                    "slot": i, "tag": slot.tag, "failed": True,
-                    "frozen": spec is None and slot.prompt_remaining > 0,
-                    "spec_round": spec is not None,
-                    "decode_steps": (
-                        0 if spec is not None or slot.prompt_remaining > 0
-                        else self.chunk
-                    ),
-                    "prefill_tokens": 0, "decode_tokens": 0,
-                })
-                finished.append((slot.tag, self._finish(i, "failed")))
-                active[i] = False
+        self._emit("phase", name="finish", ladder=ladder)
+        for i in sorted(bad):  # ladder exhausted: fail those requests
+            slot = self._slots[i]
+            # the failed slot's boundary work still ran — bill it by
+            # its class so attribution stays conservative. Mid-prefill
+            # failures weigh zero (the host cannot know which replay
+            # fed their piece); nothing was EMITTED either way.
+            self.last_boundary.append({
+                "slot": i, "tag": slot.tag, "failed": True,
+                "frozen": spec is None and slot.prompt_remaining > 0,
+                "spec_round": spec is not None,
+                "decode_steps": (
+                    0 if spec is not None or slot.prompt_remaining > 0
+                    else self.chunk
+                ),
+                "prefill_tokens": 0, "decode_tokens": 0,
+            })
+            finished.append((slot.tag, self._finish(i, "failed")))
+            active[i] = False
         self._carry = carry
         done_np = self._done_np
         piece = self._piece_tokens()

@@ -65,7 +65,7 @@ from orion_tpu.obs import slo as obs_slo
 from orion_tpu.obs.flight import FlightRecorder
 from orion_tpu.obs.http import ObsHTTPServer
 from orion_tpu.obs.metrics import MetricsRegistry
-from orion_tpu.obs.trace import Tracer
+from orion_tpu.obs.trace import NULL_SPAN, Tracer
 from orion_tpu.resilience.breaker import CircuitBreaker, StoreUnavailableError
 from orion_tpu.resilience.inject import fire
 from orion_tpu.resilience.preempt import PreemptionGuard
@@ -84,6 +84,29 @@ _STAT_KEYS = (
     "rewinds", "reprefills", "stalls",
     "chunks", "slot_steps_active", "slot_steps_total",
     "suspended", "resumed", "session_saves",
+)
+
+
+# What the scheduler thread does in one served boundary, as span names:
+# ``serve.boundary`` runs from the top of a serve-loop iteration that
+# steps the engine to the top of the next; the others are its children,
+# in this order, none overlapping (``serve.idle_wait`` lies outside any
+# boundary: the queue poll of an idle engine). ``serve.probe`` is the one
+# phase in which the host waits for the device; the time in all the
+# others is time the device's next program is held back. Each is written
+# by :meth:`Server._phase` to the Tracer ring (``cat="phase"``, every
+# boundary) and, while ``arm_profile``'s capture runs, to the profiler's
+# trace under the same name.
+PHASES = (
+    "serve.boundary", "serve.tick", "serve.admit", "serve.dispatch",
+    "serve.probe", "serve.finish", "serve.complete", "serve.idle_wait",
+)
+# slot_steps_active split by what the slot did at the boundary (their sum
+# IS slot_steps_active): consumed the boundary's prefill piece / emitted
+# tokens without consuming it / neither (resident mid-prefill but not
+# selected — and the rare slot evicted by its deadline or the ladder)
+_SLOT_CLASS_KEYS = (
+    "slot_steps_prefilling", "slot_steps_decoding", "slot_steps_frozen",
 )
 
 
@@ -267,11 +290,16 @@ class Pending:
     """A submitted request's handle; ``done`` is set exactly once, with
     either ``result`` or ``error`` filled. ``admitted_at`` anchors the
     request's deadline: queue wait counts against the budget;
-    ``done_at`` records completion (the serving bench's latency stamp)."""
+    ``done_at`` records completion (the serving bench's latency stamp).
+    ``first_token_at`` (0.0 until set) is the end of the boundary whose
+    scan emitted the request's first tokens — the earliest moment a
+    streaming client could have seen one; it stays 0.0 for a request that
+    ended before any."""
 
     request: DecodeRequest
     done: threading.Event
     admitted_at: float = 0.0
+    first_token_at: float = 0.0
     result: Optional[DecodeResult] = None
     error: Optional[Exception] = None
     done_at: float = 0.0
@@ -378,7 +406,7 @@ class Server:
         # chunk boundaries — no device syncs, no new compiles (lint rule
         # obs-device-sync + the cache-stat asserts in tests/test_obs.py)
         self.metrics = MetricsRegistry(clock=clock, lock=self._stats_lock)
-        for key in _STAT_KEYS:
+        for key in _STAT_KEYS + _SLOT_CLASS_KEYS:
             self.metrics.counter(key)  # the legacy stats dict's cells
         self.trace = tracer if tracer is not None else Tracer(
             path=cfg.trace_path, clock=clock, enabled=bool(cfg.trace_path),
@@ -664,6 +692,14 @@ class Server:
         self._slo_shedding = False
         self._slo_slow_prev = False
         self._chunk_seq = 0  # serve.chunk_delay's step address
+        # the engine phase span open on the scheduler thread (dispatch ->
+        # probe -> finish, switched by the engine's "phase" events), and
+        # the first poll of the idle stretch in progress
+        self._engine_phase = NULL_SPAN
+        self._idle_first = None
+        # the boundary the running serve-loop iteration steps (or would):
+        # what every phase span carries as ``boundary``
+        self._boundary = 1
         # -- live exposition (obs/http.py): /metrics /healthz /statusz
         # /slo on a daemon thread; stays up across serve() calls (a
         # balancer must see DRAINING/DEAD as 503, not connection
@@ -692,6 +728,18 @@ class Server:
 
     def _bump(self, key: str, n: int = 1) -> None:
         self.metrics.counter(key).inc(n)
+
+    def _phase(self, name: str, record: bool = True, **args):
+        """The span helper of the served boundary: ``with
+        self._phase("serve.admit"):`` writes the interval into the Tracer
+        ring and, while a profiler capture runs, as an annotation of the
+        same name into the device trace (obs/trace.py ``Span``). With
+        the tracer disabled and no capture running it hands back the one
+        shared null span: nothing is built, nothing is timed."""
+        tr = self.trace
+        if not tr.enabled and tr.annotate is None:
+            return NULL_SPAN
+        return tr.span(name, "phase", record, boundary=self._boundary, **args)
 
     # -- telemetry hooks (all host-only; see obs-device-sync) -----------------
 
@@ -1104,6 +1152,16 @@ class Server:
         self._profile_path = path
         self.flight.record("profile", event="start", chunks=chunks,
                            dir=path)
+        # from here to the stop every phase span is ALSO a host event of
+        # the capture, on the device trace's clock; the instant says which
+        # boundaries of the ring the capture holds (the first is the one
+        # about to step), so one span present in both gives the offset
+        # between the two clocks
+        from orion_tpu.utils.profiling import annotate
+
+        self.trace.annotate = annotate
+        self.trace.instant("profile_start", chunk_seq=self._chunk_seq + 1,
+                           chunks=chunks, path=path)
 
     def _profile_maybe_stop(self, force: bool = False) -> None:
         """Scheduler thread, after a boundary (or on drain with
@@ -1127,6 +1185,9 @@ class Server:
             self._profile_left = 0
         import jax.profiler as _profiler
 
+        self.trace.annotate = None
+        self.trace.instant("profile_stop", chunk_seq=self._chunk_seq,
+                           path=self._profile_path)
         try:
             _profiler.stop_trace()
         except Exception as e:
@@ -1158,6 +1219,17 @@ class Server:
         """SlotEngine tap: admissions, resumes, prefill pieces, ladder
         rungs, evictions — recorded to the flight ring (tag swapped for
         the request's trace id) and folded into the registry."""
+        if kind == "phase":
+            # an edge inside engine.step: close the engine phase that is
+            # open, open the next (the engine knows no tracer; these two
+            # events are its whole part in the spans)
+            if self._engine_phase is not NULL_SPAN:
+                if fields.get("ladder"):
+                    self._engine_phase.note(ladder=True)
+                self._engine_phase.__exit__(None, None, None)
+                self._engine_phase = self._phase(
+                    "serve." + fields["name"]).__enter__()
+            return
         tag = fields.pop("tag", None)
         rid = getattr(tag, "rid", None)
         if rid is not None:
@@ -1209,6 +1281,11 @@ class Server:
             self.trace.instant(kind, id=rid,
                                session=fields.get("session"),
                                slot=fields.get("slot"))
+            if (kind == "admit" and not fields.get("staged")
+                    and isinstance(tag, Pending)):
+                # host-side prefill (prefill_chunk=0): the solo prefill
+                # the engine just ran sampled the request's first token
+                self._first_token(tag, self._clock())
         elif kind == "prefix_hit":
             self._c_prefix_hits.inc()
             self.trace.instant("prefix_hit", id=rid,
@@ -1258,12 +1335,14 @@ class Server:
             # open BEFORE the enqueue: the serve loop may pop the
             # Pending (and emit the matching end events) the instant
             # put_nowait returns — begins recorded after that would
-            # timestamp after their own ends. A shed request closes
-            # both spans right here, so pairing stays complete on every
-            # path.
+            # timestamp after their own ends. ``first_token`` (submit ->
+            # the end of the boundary that emitted the first tokens)
+            # opens with them. A shed request closes all three right
+            # here, so pairing stays complete on every path.
             self.trace.begin("request", pending.rid,
                              session=request.session_id)
             self.trace.begin("queue", pending.rid)
+            self.trace.begin("first_token", pending.rid)
             try:
                 # SLO actuation, admission half: while the fast-burn
                 # alert is sustained the effective queue bound HALVES —
@@ -1278,6 +1357,7 @@ class Server:
             except queue.Full:
                 self._bump("shed")
                 self.trace.end("queue", pending.rid)
+                self.trace.end("first_token", pending.rid, status="shed")
                 self.trace.end("request", pending.rid, status="shed")
                 why = (
                     "slo fast burn: shedding at half the admission bound"
@@ -1334,61 +1414,8 @@ class Server:
                 # still admits the already-queued backlog (PR 4's drain
                 # contract: in-flight AND admitted requests complete);
                 # only submit() is closed.
-                while True:
-                    self._maybe_drain(guard)
-                    draining = self.health.state is Health.DRAINING
-                    if draining:
-                        # durable sessions don't hold the drain hostage:
-                        # every resident session slot is SUSPENDED at this
-                        # boundary (one O(1) snapshot each, persisted
-                        # before the result is released) instead of
-                        # decoding its remaining tokens; sessionless
-                        # slots drain to completion as always
-                        for pending, result in self.engine.suspend_sessions():
-                            self._complete(pending, result)
-                    self._tick_sessions()
-                    self._tick_store_health()
-                    self._tick_metrics()
-                    self._tick_slo()
-                    self._tick_cost()
-                    self._admit_from_queue(wd)
-                    if (self.prefix_store is not None
-                            and self.engine.has_pending_prefixes):
-                        # miss-path declarations: prefill + publish the
-                        # queued shared prefixes (one-time per novel
-                        # prefix, outside the admission path). Beat the
-                        # watchdog first — the publish is a solo prefill
-                        # plus possibly a first-time bucket compile, the
-                        # same cost class the admission beat covers; a
-                        # healthy replica must not read as stalled for
-                        # caching a prefix.
-                        if wd is not None:
-                            wd.beat("prefix publish")
-                        self.engine.publish_pending_prefixes()
-                    if not self.engine.busy:
-                        if (draining or drain_when_idle) and self._q.empty():
-                            if not (draining and self._dirty_sessions
-                                    and self._clock()
-                                    < self._drain_deadline):
-                                break
-                            # drain mid-outage: DIRTY sessions are the
-                            # ONLY up-to-date copy of their conversations
-                            # — hold them resident through the grace
-                            # window, retrying saves via the breaker's
-                            # half-open probes (_tick_sessions above),
-                            # instead of silently dropping turns. The
-                            # deadline bounds the hold; whatever is
-                            # still dirty then is reported loudly on
-                            # the way out.
-                            time.sleep(min(max(cfg.poll, 0.001), 0.05))
-                            continue
-                        try:
-                            pending = self._q.get(timeout=cfg.poll)
-                        except queue.Empty:
-                            continue
-                        self._admit(pending, wd)
-                        continue
-                    self._step_chunk(wd, guard)
+                while self._serve_once(wd, guard, drain_when_idle):
+                    pass
                 clean_exit = True
             finally:
                 if not clean_exit:
@@ -1447,6 +1474,87 @@ class Server:
                 self._tick_metrics(force=True)
                 self.trace.flush()
         return 0
+
+    def _serve_once(self, wd, guard, drain_when_idle: bool) -> bool:
+        """One iteration of the serve loop (False ends it): tick, admit,
+        then either step the engine one chunk or, with nothing resident,
+        wait on the queue. An iteration that steps is one BOUNDARY; its
+        ``serve.boundary`` span and the tick and admit phases inside it
+        reach the Tracer ring only then, so an idle server polling its
+        queue writes nothing that could age requests out of the ring."""
+        cfg = self.cfg
+        self._boundary = self._chunk_seq + 1
+        with self._phase("serve.boundary", record=False) as boundary:
+            with self._phase("serve.tick", record=False) as tick:
+                self._maybe_drain(guard)
+                draining = self.health.state is Health.DRAINING
+                if draining:
+                    # durable sessions don't hold the drain hostage:
+                    # every resident session slot is SUSPENDED at this
+                    # boundary (one O(1) snapshot each, persisted
+                    # before the result is released) instead of
+                    # decoding its remaining tokens; sessionless
+                    # slots drain to completion as always
+                    for pending, result in self.engine.suspend_sessions():
+                        self._complete(pending, result)
+                self._tick_sessions()
+                self._tick_store_health()
+                self._tick_metrics()
+                self._tick_slo()
+                self._tick_cost()
+            with self._phase("serve.admit", record=False) as admit:
+                admitted = self._admit_from_queue(wd)
+                admit.note(n=admitted)
+                if (self.prefix_store is not None
+                        and self.engine.has_pending_prefixes):
+                    # miss-path declarations: prefill + publish the
+                    # queued shared prefixes (one-time per novel
+                    # prefix, outside the admission path). Beat the
+                    # watchdog first — the publish is a solo prefill
+                    # plus possibly a first-time bucket compile, the
+                    # same cost class the admission beat covers; a
+                    # healthy replica must not read as stalled for
+                    # caching a prefix.
+                    if wd is not None:
+                        wd.beat("prefix publish")
+                    self.engine.publish_pending_prefixes()
+            if self.engine.busy:
+                tick.write()
+                admit.write()
+                self._step_chunk(wd, guard, boundary, admitted)
+                return True
+        if (draining or drain_when_idle) and self._q.empty():
+            if not (draining and self._dirty_sessions
+                    and self._clock() < self._drain_deadline):
+                return False
+            # drain mid-outage: DIRTY sessions are the ONLY up-to-date
+            # copy of their conversations — hold them resident through
+            # the grace window, retrying saves via the breaker's
+            # half-open probes (_tick_sessions above), instead of
+            # silently dropping turns. The deadline bounds the hold;
+            # whatever is still dirty then is reported loudly on the
+            # way out.
+            time.sleep(min(max(cfg.poll, 0.001), 0.05))
+            return True
+        wait = self._phase("serve.idle_wait", record=False)
+        try:
+            with wait:
+                pending = self._q.get(timeout=cfg.poll)
+        except queue.Empty:
+            if self._idle_first is None:
+                self._idle_first = wait
+            return True
+        first, self._idle_first = self._idle_first or wait, None
+        if self.trace.enabled:
+            # ONE ring event for the idle stretch, however many polls
+            # it took (each poll is its own annotation in a capture)
+            self.trace.complete(
+                "serve.idle_wait", first.start,
+                wait.start + wait.dur - first.start, cat="phase",
+            )
+        with self._phase("serve.admit", n=1):
+            self._admit(pending, wd)
+        return True
 
     def _tick_slo(self) -> None:
         """Chunk-boundary SLO evaluation + actuation. Evaluation always
@@ -1528,15 +1636,19 @@ class Server:
 
     # -- scheduler internals --------------------------------------------------
 
-    def _admit_from_queue(self, wd=None) -> None:
+    def _admit_from_queue(self, wd=None) -> int:
         """Move queued requests into free slots (called at every chunk
-        boundary — this is where a late arrival joins the running batch)."""
+        boundary — this is where a late arrival joins the running batch);
+        returns how many left the queue."""
+        n = 0
         while self.engine.has_free_slot:
             try:
                 pending = self._q.get_nowait()
             except queue.Empty:
-                return
+                break
             self._admit(pending, wd)
+            n += 1
+        return n
 
     def _admit(self, pending: Pending, wd=None) -> None:
         """Place one Pending into a slot: solo prefill + row insert. A
@@ -1837,14 +1949,18 @@ class Server:
                 self._sessions.pop(sid, None)
                 self._session_last_use.pop(sid, None)
 
-    def _step_chunk(self, wd, guard) -> None:
+    def _step_chunk(self, wd, guard, boundary=NULL_SPAN,
+                    admitted: int = 0) -> None:
         """One engine boundary: watchdog beat, advance all slots a chunk,
         complete whatever finished, refresh the occupancy gauges. The
         boundary's wall time becomes one ``chunk_ms`` observation and —
         with tracing on — one per-resident-slot complete event (the
         per-slot host mirrors say which slots were mid-prefill vs
         decoding; the duration is the shared batched scan's, because the
-        per-slot split does not exist on the device)."""
+        per-slot split does not exist on the device). Around them the
+        phase spans: ``serve.dispatch`` opens at ``engine.step``'s entry,
+        the engine's two edges switch it to ``serve.probe`` and
+        ``serve.finish``, and ``serve.complete`` covers the rest."""
         if wd is not None:
             wd.beat("decode chunk")
         self._maybe_drain(guard)
@@ -1853,36 +1969,70 @@ class Server:
         occupied = self.engine.active_count
         infos = self.engine.slot_info() if self.trace.enabled else ()
         t0 = self._clock()
-        finished = self.engine.step()
+        finished = ()
+        self._engine_phase = self._phase("serve.dispatch").__enter__()
+        try:
+            finished = self.engine.step()
+        finally:
+            # whichever phase the engine left open (``serve.finish``,
+            # or ``serve.dispatch`` still when nothing was resident
+            # after the deadline scan, or the step raised)
+            last, self._engine_phase = self._engine_phase, NULL_SPAN
+            last.note(n=len(finished))
+            last.__exit__(None, None, None)
         self._chunk_seq += 1
         # INSIDE the timed window: injected latency lands in chunk_ms
         # (and every resident turn's latency) exactly like a slow scan
         # would — the deterministic address for latency-shaped chaos
         fire("serve.chunk_delay", step=self._chunk_seq)
-        dt = self._clock() - t0
+        t_end = self._clock()
+        dt = t_end - t0
         if self.cfg.profile_dir:
             self._profile_maybe_stop()
-        with self._stats_lock:
-            self._bump("chunks")
-            self._bump("slot_steps_active", occupied)
-            self._bump("slot_steps_total", self.engine.slots)
-            # the tp label makes a fleet's per-footprint boundary cost
-            # separable at the aggregated endpoint (a tp=4 replica's
-            # chunks cost collectives a tp=1 replica's don't)
-            self._h_chunk_ms.observe(dt * 1e3, labels={"tp": str(self.tp)})
-        if self.cost_enabled:
-            # attribution BEFORE completing the finished results, so a
-            # request's final boundary still lands on its accumulators;
-            # dt*1e3 is the SAME value chunk_ms observed — conservation
-            # is float-exact per boundary by construction
-            self._attribute_chunk(dt * 1e3)
-        for i, tag, phase, k in infos:
-            self.trace.complete(
-                "decode_chunk" if phase == "decode" else "prefill_piece",
-                t0, dt, req=getattr(tag, "rid", None), slot=i, chunk=k,
-            )
-        for pending, result in finished:
-            self._complete(pending, result)
+        with self._phase("serve.complete", n=len(finished)):
+            prefilling = decoding = 0
+            for entry in self.engine.last_boundary:
+                emitted = entry.get("decode_tokens", 0) > 0
+                if entry.get("prefill_tokens", 0) > 0:
+                    prefilling += 1
+                elif emitted:
+                    decoding += 1
+                tag = entry.get("tag")
+                if (emitted and isinstance(tag, Pending)
+                        and not tag.first_token_at):
+                    # this boundary's scan emitted the request's first
+                    # tokens; its end is when a client could see one
+                    self._first_token(tag, t_end)
+            with self._stats_lock:
+                self._bump("chunks")
+                self._bump("slot_steps_active", occupied)
+                self._bump("slot_steps_total", self.engine.slots)
+                self._bump("slot_steps_prefilling", prefilling)
+                self._bump("slot_steps_decoding", decoding)
+                self._bump("slot_steps_frozen",
+                           occupied - prefilling - decoding)
+                # the tp label makes a fleet's per-footprint boundary cost
+                # separable at the aggregated endpoint (a tp=4 replica's
+                # chunks cost collectives a tp=1 replica's don't)
+                self._h_chunk_ms.observe(dt * 1e3,
+                                         labels={"tp": str(self.tp)})
+            if self.cost_enabled:
+                # attribution BEFORE completing the finished results, so
+                # a request's final boundary still lands on its
+                # accumulators; dt*1e3 is the SAME value chunk_ms observed
+                # — conservation is float-exact per boundary by
+                # construction
+                self._attribute_chunk(dt * 1e3)
+            for i, tag, phase, k in infos:
+                self.trace.complete(
+                    "decode_chunk" if phase == "decode" else "prefill_piece",
+                    t0, dt, req=getattr(tag, "rid", None), slot=i, chunk=k,
+                )
+            for pending, result in finished:
+                self._complete(pending, result)
+        boundary.note(steps=self.engine.chunk, resident=occupied,
+                      admitted=admitted, finished=len(finished))
+        boundary.write()
 
     def _complete(self, pending: Pending, result: DecodeResult) -> None:
         if result.session is not None:
@@ -1932,11 +2082,25 @@ class Server:
             self.health.to(Health.SERVING, "clean request completed")
         self._finalize(pending, result.status)
 
+    def _first_token(self, pending: Pending, at: float) -> None:
+        pending.first_token_at = at
+        self.trace.end("first_token", pending.rid, at=at)
+
     def _finalize(self, pending: Pending, status: str) -> None:
         """The one place a Pending's done event fires: stamps done_at,
         closes the request's trace span, releases the waiter, and runs
         the ``on_done`` tap (the fleet router's root-span close)."""
         pending.done_at = self._clock()
+        if not pending.first_token_at:
+            # no boundary gave this request a token: it ended first
+            # (shed at admission, its deadline gone in the queue,
+            # refused, failed, rejected at shutdown) and the stamp stays
+            # 0.0 — or a session's buffer answered it without a slot,
+            # and its tokens exist as of now
+            if pending.result is not None and pending.result.new_tokens > 0:
+                pending.first_token_at = pending.done_at
+            self.trace.end("first_token", pending.rid, at=pending.done_at,
+                           status=status)
         cost_args = {}
         if pending.result is not None:
             # per-turn latency (admission -> release, queue wait

@@ -38,6 +38,7 @@ from orion_tpu.parallel.mesh import MeshConfig, make_mesh
 from orion_tpu.parallel.sharding import batch_sharding, param_shardings
 from orion_tpu.resilience import inject as _inject
 from orion_tpu.utils import rng as rngs
+from orion_tpu.utils.profiling import annotate, annotated_steps
 
 Array = jax.Array
 
@@ -801,17 +802,21 @@ class Trainer:
         tokens_per_step = cfg.batch_size * cfg.seq_len
         last: Dict[str, float] = {}
         start_step = int(self.state.step)
-        for step in range(start_step + 1, cfg.steps + 1):
+        # host spans for a profiler capture (utils/profiling.py): what the
+        # loop itself does between two dispatches of the step program
+        for step in annotated_steps("train", range(start_step + 1, cfg.steps + 1)):
             if watchdog is not None:
                 watchdog.beat(f"train step {step}")
-            batch = next(data_iter)
+            with annotate("train.next_batch"):
+                batch = next(data_iter)
             metrics = self.step(batch)
             # only materialize metrics on the host at log cadence — reading a
             # device scalar every step would serialize the pipeline
             if step % cfg.log_every == 0 or step == cfg.steps:
                 # cumulative device-side counter: catches non-finite steps
                 # that happened *between* log points too
-                nf_total = int(metrics["nonfinite_total"])
+                with annotate("train.log_readback"):  # waits for the step
+                    nf_total = int(metrics["nonfinite_total"])
                 if nf_total > self.nonfinite_steps:
                     # black-box the non-finite step window (the flight
                     # recorder is the training run's post-mortem ring,
@@ -852,16 +857,19 @@ class Trainer:
                     # a hung EVAL DATA read is still caught by the eval
                     # loader's own stall_timeout (train.py)
                     watchdog.disarm()
-                ev = self.evaluate(
-                    eval_factory(step) if eval_factory is not None else eval_iter
-                )
+                with annotate("train.eval"):
+                    ev = self.evaluate(
+                        eval_factory(step) if eval_factory is not None
+                        else eval_iter
+                    )
                 last.update(ev)
                 if logger:
                     logger.log(step, ev)
                 if watchdog is not None:
                     watchdog.arm(f"train step {step} (post-eval)")
             if ckpt is not None:
-                ckpt.maybe_save(step, self.state)
+                with annotate("train.checkpoint"):
+                    ckpt.maybe_save(step, self.state)
             if hook is not None:
                 hook(step, metrics)
             # chaos harness: simulated preemption delivers a real signal

@@ -1,66 +1,32 @@
-"""Profiling/tracing hooks (SURVEY.md A1).
+"""Host spans in a ``jax.profiler`` capture (SURVEY.md A1).
 
-The reference exposes torch-profiler hooks around its training loop
-(BASELINE.json; reference checkout never mounted — SURVEY.md §0). The TPU
-equivalents: ``trace(logdir)`` wraps a region in a ``jax.profiler`` trace
-viewable in TensorBoard/Perfetto (device timelines, HLO cost, HBM usage);
-``StepTimer`` gives cheap host-side per-step wall times + tokens/sec
-percentiles without any device sync beyond what the caller already does.
+The one place the package constructs profiler annotations. A capture is
+taken elsewhere — ``Server.arm_profile`` / ``/profilez`` for serving, the
+benchmark's train kind for ``Trainer.train`` — and read by
+``benchmark/readers/xplane.py``; these names are what such a capture
+shows on its host lines, on the same clock as the device's operations,
+so an idle gap of the device can be given to what the host was doing.
+Outside a capture an annotation costs a check of one flag.
 """
 
 from __future__ import annotations
 
-import contextlib
-import time
-from typing import Dict, List, Optional
-
 import jax
 
 
-@contextlib.contextmanager
-def trace(logdir: str, with_memory: bool = True):
-    """Profile a region: `with trace("/tmp/tb"): trainer.step(batch)`."""
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
 def annotate(name: str):
-    """Named sub-region inside a trace (shows up on the TraceMe timeline)."""
+    """``with annotate("serve.admit"):`` — a named host span."""
     return jax.profiler.TraceAnnotation(name)
 
 
-class StepTimer:
-    """Host-side step timing; call mark() once per step (after any sync the
-    loop already performs)."""
-
-    def __init__(self, tokens_per_step: int = 0):
-        self.tokens_per_step = tokens_per_step
-        self._times: List[float] = []
-        self._last: Optional[float] = None
-
-    def mark(self):
-        now = time.perf_counter()
-        if self._last is not None:
-            self._times.append(now - self._last)
-        self._last = now
-
-    def summary(self) -> Dict[str, float]:
-        if not self._times:
-            return {}
-        ts = sorted(self._times)
-        n = len(ts)
-        out = {
-            "steps": float(n),
-            "p50_ms": 1000 * ts[n // 2],
-            "p90_ms": 1000 * ts[min(n - 1, int(n * 0.9))],
-            "mean_ms": 1000 * sum(ts) / n,
-        }
-        if self.tokens_per_step:
-            out["tokens_per_sec"] = self.tokens_per_step / (sum(ts) / n)
-        return out
+def annotated_steps(name: str, steps):
+    """``for step in annotated_steps("train", range(a, b)):`` — each
+    iteration's body runs inside a ``StepTraceAnnotation`` (the profiler
+    groups device work by these); it closes when the loop asks for the next
+    step or leaves."""
+    for step in steps:
+        with jax.profiler.StepTraceAnnotation(name, step_num=step):
+            yield step
 
 
-__all__ = ["trace", "annotate", "StepTimer"]
+__all__ = ["annotate", "annotated_steps"]

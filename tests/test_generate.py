@@ -226,16 +226,6 @@ def test_chunked_decode_matches_monolithic_bitwise():
         np.testing.assert_array_equal(np.asarray(out), ref, err_msg=f"chunk={chunk}")
 
 
-def test_profiling_step_timer():
-    from orion_tpu.utils.profiling import StepTimer
-
-    t = StepTimer(tokens_per_step=100)
-    for _ in range(5):
-        t.mark()
-    s = t.summary()
-    assert s["steps"] == 4 and s["p50_ms"] >= 0 and "tokens_per_sec" in s
-
-
 def test_sharded_generate_parity():
     """Mesh-sharded decode (VERDICT r1 item 7): dp=4 batch sharding and
     dp=2/tp=2 head sharding must reproduce single-device greedy decode

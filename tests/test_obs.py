@@ -479,7 +479,8 @@ def test_chaos_run_trace_complete_and_flight_dumps(mp, tmp_path):
         )
     # chunk events nest inside their request's span
     by_rid = {key[1]: pair for key, pair in req_spans.items()}
-    chunk_events = [e for e in events if e["ph"] == "X"]
+    chunk_events = [e for e in events
+                    if e["ph"] == "X" and e["cat"] == "chunk"]
     assert chunk_events, "chunk boundaries must leave complete events"
     for ev in chunk_events:
         rid = ev["args"]["req"]
