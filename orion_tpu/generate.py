@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from orion_tpu.models.configs import ModelConfig, get_config
 from orion_tpu.models.transformer import TransformerLM, init_decode_state
+from orion_tpu.ops.dispatch import decode_live_rows
 
 Array = jax.Array
 
@@ -343,7 +344,7 @@ def _sample_rows(logits: Array, keys: Array, cfg: SampleConfig) -> Array:
 
 
 def _decode_batched_body(
-    model, params, sample_cfg: SampleConfig, rngs, active, carry, _
+    model, params, sample_cfg: SampleConfig, rngs, active, rows, carry, _
 ):
     """One slot-multiplexed decode step. carry = (token [S], states,
     t [S], emit [S], done [S]); ``rngs`` [S, 2] are per-slot PRNG keys
@@ -351,9 +352,14 @@ def _decode_batched_body(
     parity), ``emit`` the per-slot absolute emitted-token index (each
     slot's rng fold_in key, the vector form of _decode_body's ``i``),
     ``active`` [S] masks free slots (their rows still compute — the scan
-    shape is static — but emit PAD and hold their position)."""
+    shape is static — but emit PAD and hold their position). With
+    ``rows`` (``ops.dispatch.decode_live_rows`` of ``active``) the linear
+    layers skip the free rows' (S, z) altogether: admission overwrites
+    those rows."""
     token, states, t, emit, done = carry
-    logits, states = model.apply(params, token, states, t, method="decode_step")
+    logits, states = model.apply(
+        params, token, states, t, rows, method="decode_step"
+    )
     keys = jax.vmap(jax.random.fold_in)(rngs, emit + 1)
     nxt = _sample_rows(logits, keys, sample_cfg)
     if sample_cfg.eos_token >= 0:
@@ -377,7 +383,10 @@ def _decode_batched_chunk_jit(
     n_steps: int,
     sample_cfg: SampleConfig,
 ) -> Tuple[Any, Array]:
-    body = partial(_decode_batched_body, model, params, sample_cfg, rngs, active)
+    body = partial(
+        _decode_batched_body, model, params, sample_cfg, rngs, active,
+        decode_live_rows(active, backend=model.cfg.backend),
+    )
     carry, tokens = jax.lax.scan(body, carry, None, length=n_steps)
     return carry, jnp.moveaxis(tokens, 0, 1)  # [S, n_steps]
 
@@ -435,6 +444,21 @@ def _where_rows(mask: Array, new: Any, old: Any) -> Any:
     )
 
 
+def _freeze_rows(model, rows, mask: Array, new: Any, old: Any) -> Any:
+    """The per-layer states with rows outside ``mask`` held at ``old``.
+    Without a row list that is :func:`_where_rows` over every layer. With
+    one (``decode_live_rows`` under a Pallas backend) the linear layers'
+    kernel never touched those rows, and a select — which reads old and
+    new — would bring the full-width state traffic back: only softmax/swa
+    layers are selected."""
+    if rows is None:
+        return _where_rows(mask, new, old)
+    return [
+        n if lt == "linear" else _where_rows(mask, n, o)
+        for lt, n, o in zip(model.cfg.resolved_layer_types, new, old)
+    ]
+
+
 def _prefill_extend_row(
     model: TransformerLM,
     params: Any,
@@ -465,19 +489,21 @@ def _prefill_extend_row(
 
 
 def _decode_batched_prefill_body(
-    model, params, sample_cfg: SampleConfig, rngs, emitting, carry, _
+    model, params, sample_cfg: SampleConfig, rngs, emitting, rows, carry, _
 ):
     """The slot-multiplexed decode step with still-prefilling rows FROZEN:
     ``emitting`` [S] is ``active & (t >= prompt_len)`` — rows past their
     prompt decode exactly as in :func:`_decode_batched_body` (every op on
     an emitting row computes the identical value, so the pure-decode walk
     is reproduced bitwise), while mid-prefill rows hold their state,
-    position, emit index, and done flag, and emit PAD. The pure body
-    itself is untouched — its compiled program must stay byte-identical
-    (golden ``decode_batched_tiny``)."""
+    position, emit index, and done flag, and emit PAD. With ``rows``
+    (``decode_live_rows`` of ``emitting``) the linear layers never touch a
+    frozen row's (S, z) in the first place (:func:`_freeze_rows`). The
+    pure body's compiled program must stay byte-identical on the XLA
+    path (golden ``decode_batched_tiny``)."""
     token, states, t, emit, done = carry
     logits, new_states = model.apply(
-        params, token, states, t, method="decode_step"
+        params, token, states, t, rows, method="decode_step"
     )
     keys = jax.vmap(jax.random.fold_in)(rngs, emit + 1)
     nxt = _sample_rows(logits, keys, sample_cfg)
@@ -489,7 +515,7 @@ def _decode_batched_prefill_body(
     else:
         emitted = token
     emitted = jnp.where(emitting, emitted, sample_cfg.pad_token)
-    states = _where_rows(emitting, new_states, states)
+    states = _freeze_rows(model, rows, emitting, new_states, states)
     token = jnp.where(emitting, nxt, token)
     t = jnp.where(emitting, t + 1, t)
     emit = jnp.where(emitting, emit + 1, emit)
@@ -554,7 +580,7 @@ def _decode_batched_prefill_chunk_jit(
     emitting = active & (t >= plen)
     body = partial(
         _decode_batched_prefill_body, model, params, sample_cfg, rngs,
-        emitting,
+        emitting, decode_live_rows(emitting, backend=model.cfg.backend),
     )
     carry, tokens = jax.lax.scan(
         body, (token, states, t, emit, done), None, length=n_steps

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from orion_tpu.models.configs import ModelConfig
+from orion_tpu.ops.dispatch import decode_state_step
 from orion_tpu.ops.feature_maps import make_feature_map
 from orion_tpu.ops.linear_attention import (
     linear_attention,
@@ -649,18 +650,27 @@ class Attention(nn.Module):
 
     # -- one-token decode ---------------------------------------------------
 
-    def decode_step(self, x: Array, state: State, t: Array) -> Tuple[Array, State]:
+    def decode_step(
+        self, x: Array, state: State, t: Array, rows: Optional[Any] = None
+    ) -> Tuple[Array, State]:
         """x: [B, D] one token; t: int32 absolute position — a scalar
         (whole batch at one position: generate()'s lockstep scan) or a
         per-sequence [B] vector (slot-multiplexed serving: each batch row
-        is an independent request at its own position)."""
+        is an independent request at its own position). ``rows``: the
+        slot-multiplexed programs' compacted list of the rows live in
+        this chunk (``ops.dispatch.decode_state_step``): under a Pallas
+        backend a linear layer then steps only those rows' (S, z), in
+        place, and returns the others untouched; softmax/swa layers
+        ignore it."""
         cfg = self.cfg
         t = jnp.asarray(t)
         per_seq = t.ndim == 1
         q, k, v = self._heads(x)  # [B, H, Dh]
         if self.layer_type == "linear":
             qf, kf = self._phi_map(q), self._phi_map(k)
-            out, (s, z) = recurrent_step(qf, kf, v, (state["s"], state["z"]))
+            out, (s, z) = decode_state_step(
+                qf, kf, v, (state["s"], state["z"]), rows, backend=cfg.backend
+            )
             new_state = {"s": s, "z": z}
         else:
             # per-seq positions: angles gather [B, 1, Dh/2] broadcasts over
@@ -844,8 +854,8 @@ class Block(nn.Module):
         x = x + self.mlp(self.norm2(x))
         return x, state
 
-    def decode_step(self, x, state, t):
-        h, state = self.attn.decode_step(self.norm1(x), state, t)
+    def decode_step(self, x, state, t, rows=None):
+        h, state = self.attn.decode_step(self.norm1(x), state, t, rows)
         x = x + h
         x = x + self.mlp(self.norm2(x))
         return x, state
@@ -1045,13 +1055,15 @@ class TransformerLM(nn.Module):
         return self._head(x[:, -1:, :])[:, 0], states
 
     def decode_step(
-        self, token: Array, states: List[State], t: Array
+        self, token: Array, states: List[State], t: Array,
+        rows: Optional[Any] = None,
     ) -> Tuple[Array, List[State]]:
-        """token [B] -> (logits [B, V], updated states). t: scalar position."""
+        """token [B] -> (logits [B, V], updated states). t: scalar position,
+        or [B] per-slot positions; ``rows``: see Attention.decode_step."""
         x = self._embed(token, t)
         new_states = []
         for blk, st in zip(self.blocks, states):
-            x, st = blk.decode_step(x, st, t)
+            x, st = blk.decode_step(x, st, t, rows)
             new_states.append(st)
         return self._head(x), new_states
 

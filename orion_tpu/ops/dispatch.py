@@ -5,6 +5,12 @@ Mirrors the reference's CUDA-vs-CPU dispatch for ``causal_dot_product``
 backend='xla' dispatch"). "auto" picks Pallas on TPU and the pure-XLA
 chunked scan elsewhere (CPU/GPU and unit tests). The Pallas kernel can also
 run anywhere via interpret mode (used by the parity tests).
+
+Two ops dispatch here: ``causal_dot_product`` (the parallel forward:
+training, prefill) and ``decode_state_step`` (the slot-multiplexed decode
+programs' ``(S, z)`` step: under Pallas a row-sparse in-place kernel that
+touches only the rows live in the chunk, ``ops/pallas/decode_state.py``;
+``recurrent_step`` over every row otherwise).
 """
 
 from __future__ import annotations
@@ -107,4 +113,48 @@ def causal_dot_product(
     )
 
 
-__all__ = ["causal_dot_product", "default_backend", "resolve", "resolve_chunk"]
+def _row_sparse(backend: str) -> bool:
+    return resolve(backend) in ("pallas", "pallas_interpret")
+
+
+def decode_live_rows(mask, *, backend: str = "auto"):
+    """The row list :func:`decode_state_step` takes for a chunk whose live
+    rows are ``mask`` [B] — built once per chunk, outside the scan — or
+    None where ``backend`` steps every row (XLA: the CPU, tp meshes). A
+    caller that gets a list must not select the old state back over the
+    unlisted rows of the linear layers: the kernel never touched them."""
+    if not _row_sparse(backend):
+        return None
+    from orion_tpu.ops.pallas.decode_state import live_rows
+
+    return live_rows(mask)
+
+
+def decode_state_step(q, k, v, state, rows=None, *, backend: str = "auto"):
+    """One decode step of the linear layers' ``(S, z)`` state.
+
+    ``rows`` is :func:`decode_live_rows` of the chunk's row mask, or None
+    when every row steps (the lockstep programs, and every program where
+    the backend is not Pallas). With a row list under a Pallas backend
+    only the listed rows are read, updated and written, in place;
+    otherwise this is ``recurrent_step`` on all rows."""
+    if rows is not None and _row_sparse(backend):
+        from orion_tpu.ops.pallas import decode_state as pds
+
+        return pds.decode_state_step(
+            q, k, v, state, rows,
+            interpret=(resolve(backend) == "pallas_interpret"),
+        )
+    from orion_tpu.ops.linear_attention import recurrent_step
+
+    return recurrent_step(q, k, v, state)
+
+
+__all__ = [
+    "causal_dot_product",
+    "decode_live_rows",
+    "decode_state_step",
+    "default_backend",
+    "resolve",
+    "resolve_chunk",
+]

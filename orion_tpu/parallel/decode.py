@@ -123,9 +123,12 @@ def mesh_backend(backend: str, tp: int) -> str:
     ("Mosaic kernels cannot be automatically partitioned"; seen compiling
     the ``lm_1b3`` in-scan prefill for a v5e:2x2). So on tp > 1 the
     prefill's attention is pinned to the XLA forms, the rule the pp
-    pipeline already follows (models/transformer.py); the decode step has
-    no kernel to lose. Manualizing the prefill kernels over tp
-    (parallel/kernel_shard.py) is the faster repair, not made yet."""
+    pipeline already follows (models/transformer.py), and so is the
+    decode step: the slot-multiplexed programs' row-sparse (S, z) kernel
+    (ops/pallas/decode_state.py) is not reached under a mesh, every slot's
+    state steps through ``recurrent_step`` and the select. Manualizing
+    the kernels over tp (parallel/kernel_shard.py) is the faster repair,
+    not made yet."""
     from orion_tpu.ops.dispatch import resolve
 
     if int(tp) > 1 and resolve(backend) == "pallas":

@@ -119,7 +119,17 @@ def _gmm(x, w, sizes):
     return gmm(x, w, sizes, tile_rows=128, block_h=512)
 
 
+def _decode_state(s, z, q, k, v, live):
+    from orion_tpu.ops.pallas.decode_state import decode_state_step, live_rows
+
+    return decode_state_step(q, k, v, (s, z), live_rows(live))
+
+
 _QKV = [(BHTD, jnp.bfloat16)] * 3
+# the serve cells' decode carry: 64 slots of lm_1b3's fp32 (S, z), one
+# token's bf16 q, k, v a slot, and the chunk's row mask
+_STATE = [((64, 16, 128, 128), jnp.float32), ((64, 16, 128), jnp.float32),
+          *[((64, 16, 128), jnp.bfloat16)] * 3, ((64,), jnp.bool_)]
 _MLP = (2048, 5504)  # lm_1b3's largest factored leaf besides the embedding
 # moe_1b3_4e dropless: 24576 padded rows through 4 experts of 2048 x 5504
 _GMM = [((24576, 2048), jnp.bfloat16), ((4, 2048, 5504), jnp.bfloat16),
@@ -140,6 +150,7 @@ KERNELS = [
          ((5504,), jnp.float32)],
         id="q4_matmul",
     ),
+    pytest.param(_decode_state, _STATE, id="decode_state-64slots"),
     # -- the rest of the main path's kernels ---------------------------------
     pytest.param(_plain, _QKV, id="causal_dot-plain-fwd", marks=slow),
     pytest.param(_grad3(_plain), _QKV, id="causal_dot-plain-bwd", marks=slow),
