@@ -34,8 +34,11 @@ class ModelConfig:
     head_dim: Optional[int] = None  # default d_model // n_heads
     mlp_hidden: Optional[int] = None  # default 4*d_model (gelu) / 8/3 (swiglu)
     mlp: str = "swiglu"  # "swiglu" | "gelu"
-    norm: str = "rmsnorm"  # "rmsnorm" | "layernorm"
-    layer_types: Optional[Tuple[str, ...]] = None  # default all "linear"
+    # "rmsnorm_zero": zero-centred RMSNorm, x * rsqrt(mean x^2 + 1e-6) *
+    # (1 + w) with w initialised 0
+    norm: str = "rmsnorm"  # "rmsnorm" | "layernorm" | "rmsnorm_zero"
+    # per layer one of LAYER_TYPES; default all "linear"
+    layer_types: Optional[Tuple[str, ...]] = None
     window: int = 512  # swa window
     # flash-attention tile sizes for the SINGLE-SHARD causal softmax/swa
     # flash paths (train __call__ and prefill; the sp ring/halo bodies
@@ -48,6 +51,23 @@ class ModelConfig:
     feature_map: str = "elu1"  # linear-attn phi
     max_seq_len: int = 2048
     tie_embeddings: bool = True
+    # "learned": absolute position embeddings added at the input; "none":
+    # no position term there (the gated layers carry position themselves:
+    # rotary in gated_softmax, decay and the short conv in gated_delta)
+    pos_embed: str = "learned"
+    # -- "gated_softmax" layers (models/gated_mixers.py): grouped KV heads,
+    # rotary on the first rotary_dims of each head (halves rotated, base
+    # rotary_base), per-head RMSNorm of q and k, a sigmoid output gate
+    n_kv_heads: Optional[int] = None  # default n_heads
+    rotary_dims: Optional[int] = None  # default the whole head
+    rotary_base: float = 10000.0
+    # -- "gated_delta" layers (ops/gated_delta.py): key heads are repeated
+    # to the value heads; q, k and v pass a causal depthwise conv + SiLU
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0  # per head
+    gdn_value_dim: int = 0  # per head
+    gdn_conv_width: int = 4
     dropout: float = 0.0
     # numerics / execution
     dtype: str = "bfloat16"  # activation/compute dtype
@@ -97,6 +117,18 @@ class ModelConfig:
     moe_group_size: int = 512  # GShard local-group length (0 = whole row)
     moe_aux_weight: float = 1e-2  # load-balance loss weight
     moe_zloss_weight: float = 1e-3  # router z-loss weight
+    # one chip's share of an expert-parallel layer (dropless path only):
+    # the router keeps its published width and top-k over all of it, while
+    # n_experts counts the experts HELD here, ids [moe_expert_offset,
+    # moe_expert_offset + n_experts). The layer computes its own experts'
+    # part of the result; what the absent ones would add is left out (their
+    # chips compute it). 0 = the router is n_experts wide, all are held.
+    # The held rows ride a static budget of moe_ep_buffer x their even share.
+    moe_router_width: int = 0
+    moe_expert_offset: int = 0
+    # > 0 adds a shared expert of this width to every MoE layer, scaled by
+    # sigmoid(w . x)
+    moe_shared_hidden: int = 0
     # classifier-only
     n_classes: int = 0  # >0 => LRA classifier head
 
@@ -119,12 +151,29 @@ class ModelConfig:
         return self.n_experts > 0 and (layer + 1) % self.moe_period == 0
 
     @property
+    def resolved_router_width(self) -> int:
+        return self.moe_router_width or self.n_experts
+
+    @property
     def resolved_layer_types(self) -> Tuple[str, ...]:
         lt = self.layer_types or ("linear",) * self.n_layers
         assert len(lt) == self.n_layers, (lt, self.n_layers)
         for t in lt:
-            assert t in ("linear", "softmax", "swa"), t
+            assert t in LAYER_TYPES, t
         return lt
+
+
+LAYER_TYPES = ("linear", "softmax", "swa", "gated_delta", "gated_softmax")
+# layer types with no decode state yet: serving them raises
+TRAIN_ONLY_LAYER_TYPES = ("gated_delta", "gated_softmax")
+
+
+def gated_pattern(n_layers: int, period: int = 4) -> Tuple[str, ...]:
+    """gated_delta x (period - 1) then gated_softmax, repeating."""
+    return tuple(
+        "gated_softmax" if (i + 1) % period == 0 else "gated_delta"
+        for i in range(n_layers)
+    )
 
 
 # Source scopes whose fp32 matmuls are SANCTIONED under the bf16 compute
@@ -141,6 +190,7 @@ F32_MATMUL_SCOPES = (
     "causal_dot.py",                # pallas state init/carry helpers
     "sequence.py",                  # sp exclusive-prefix fp32 state math
     "transformer.py::_phi_map",     # FAVOR+ fp32 random-feature projection
+    "gated_delta.py",               # delta-rule fp32 state + triangular inverse
 )
 
 
@@ -234,6 +284,46 @@ MOE_1B3_4E = dataclasses.replace(
     MOE_1B3_8E, name="moe_1b3_4e", n_experts=4, moe_period=4,
 )
 
+QWEN3_NEXT_80B = ModelConfig(
+    # Qwen3-Next-80B-A3B at its published widths, as ONE chip's share of an
+    # 8-way expert- and vocabulary-parallel deployment, one period deep
+    # (benchmark/configs/qwen3_next_80b.json states the source, the cut and
+    # what is assumed): 3 gated delta-rule layers then 1 gated GQA softmax
+    # layer, every MLP a 512-way top-10 MoE with a gated shared expert; 64
+    # of the 512 experts and 18,992 of the 151,936 vocabulary rows are held.
+    name="qwen3_next_80b",
+    vocab_size=18992,
+    d_model=2048,
+    n_layers=4,
+    layer_types=gated_pattern(4, period=4),
+    n_heads=16,
+    n_kv_heads=2,
+    head_dim=256,
+    rotary_dims=64,
+    rotary_base=1e7,
+    gdn_key_heads=16,
+    gdn_value_heads=32,
+    gdn_key_dim=128,
+    gdn_value_dim=128,
+    gdn_conv_width=4,
+    norm="rmsnorm_zero",
+    pos_embed="none",
+    tie_embeddings=False,
+    mlp="swiglu",
+    mlp_hidden=512,  # one routed expert's width
+    n_experts=64,
+    moe_router_width=512,
+    moe_expert_offset=0,
+    moe_top_k=10,
+    moe_period=1,
+    moe_dropless=True,
+    moe_ep_buffer=1.5,  # the held rows' buffer: 1.5 x their even share
+    moe_shared_hidden=512,
+    max_seq_len=8192,
+    dtype="bfloat16",
+    remat=True,
+)
+
 LRA_LISTOPS_LINEAR = ModelConfig(
     name="lra_listops_linear",
     vocab_size=32,  # digits + operators + specials
@@ -279,6 +369,7 @@ CONFIGS = {
         HYBRID_7B,
         MOE_1B3_8E,
         MOE_1B3_4E,
+        QWEN3_NEXT_80B,
         LRA_LISTOPS_LINEAR,
         LRA_LISTOPS_SOFTMAX,
         LRA_TEXT_LINEAR,
@@ -296,5 +387,6 @@ def get_config(name: str, **overrides) -> ModelConfig:
 
 __all__ = [
     "ModelConfig", "CONFIGS", "get_config", "hybrid_pattern",
-    "F32_MATMUL_SCOPES",
+    "gated_pattern", "F32_MATMUL_SCOPES", "LAYER_TYPES",
+    "TRAIN_ONLY_LAYER_TYPES",
 ]

@@ -57,6 +57,16 @@ asymmetry. On ep meshes ``_dropless_ep`` shards the experts: each shard
 serves its local experts out of a rotated-sort prefix under a static row
 budget and the outputs meet in one psum (drops only past the budget,
 counted in "moe_stats", never silent).
+
+One chip's share of an expert-parallel layer (``moe_router_width`` wider
+than ``n_experts``): ``_dropless_held`` routes over the router's whole
+published width, computes the part of the result that the ``n_experts``
+experts held here give (ids from ``moe_expert_offset``; ``_held_rows_ffn``,
+the body an ep shard of ``_dropless_ep_gmm`` runs) and leaves out what
+the absent experts would add — their chips compute that, and no code here
+stands in for them. ``moe_shared_hidden`` adds a shared expert scaled by
+``sigmoid(w . x)`` to whichever routed path ran. The held path counts its
+rows into "moe_stats" (``rows_routed``, ``rows_held``, ``rows_max_expert``).
 """
 
 from __future__ import annotations
@@ -69,6 +79,7 @@ import jax
 import jax.numpy as jnp
 
 from orion_tpu.models.configs import ModelConfig
+from orion_tpu.utils.profiling import scope
 
 Array = jax.Array
 
@@ -151,6 +162,31 @@ class MoEMLP(nn.Module):
 
     @nn.compact
     def __call__(self, x: Array) -> Array:
+        cfg = self.cfg
+        if cfg.resolved_router_width != cfg.n_experts or cfg.moe_expert_offset:
+            y = self._dropless_held(x)
+        else:
+            y = self._routed(x)
+        if cfg.moe_shared_hidden:
+            y = y + self._shared(x)
+        return y
+
+    def _shared(self, x: Array) -> Array:
+        """The shared expert every token passes, scaled by sigmoid(w . x)."""
+        cfg = self.cfg
+        assert not self.quant and cfg.mlp == "swiglu", (self.quant, cfg.mlp)
+        dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
+        dense = lambda n, feats: nn.Dense(  # noqa: E731
+            feats, use_bias=False, dtype=dt, param_dtype=pdt, name=n
+        )
+        with scope("moe_shared"):
+            mid = jax.nn.silu(dense("shared_gate", cfg.moe_shared_hidden)(x)) * dense(
+                "shared_up", cfg.moe_shared_hidden
+            )(x)
+            gate = jax.nn.sigmoid(dense("shared_scale", 1)(x).astype(jnp.float32))
+            return dense("shared_down", x.shape[-1])(mid) * gate.astype(dt)
+
+    def _routed(self, x: Array) -> Array:
         cfg = self.cfg
         dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
         e, k, h = cfg.n_experts, cfg.moe_top_k, cfg.resolved_mlp_hidden
@@ -259,7 +295,7 @@ class MoEMLP(nn.Module):
         single-host and ep-sharded forms can never diverge."""
         cfg = self.cfg
         router = nn.Dense(
-            cfg.n_experts, use_bias=False, dtype=jnp.float32,
+            cfg.resolved_router_width, use_bias=False, dtype=jnp.float32,
             param_dtype=_dtype(cfg.param_dtype), name="router"
         )
         logits = router(x2.astype(jnp.float32))  # [N, E]
@@ -273,7 +309,7 @@ class MoEMLP(nn.Module):
         cfg = self.cfg
         if self.is_initializing():
             return
-        e = cfg.n_experts
+        e = cfg.resolved_router_width
         f = jax.nn.one_hot(ids, e, dtype=jnp.float32).mean(axis=(0, 1))
         p = probs.mean(axis=0)
         aux = e * jnp.sum(f * p)
@@ -428,6 +464,59 @@ class MoEMLP(nn.Module):
 
         y = jnp.take(ys, inv, axis=0).reshape(n, k, d)
         y = jnp.sum(y * gates[..., None].astype(dt), axis=1)
+        return y.reshape(x.shape).astype(dt)
+
+    def _dropless_held(self, x: Array) -> Array:
+        """The dropless layer of ONE chip of an expert-parallel group: the
+        router is ``moe_router_width`` wide and picks its top-k over all of
+        it, renormalised over all k as published; of the chosen (token,
+        expert) rows only those whose expert is held here — ids
+        ``[moe_expert_offset, moe_expert_offset + n_experts)`` — are
+        computed (``_held_rows_ffn``, the body an ep shard runs too), and
+        the others add nothing (their chips add them). Single-device only:
+        on one chip the layer runs without its exchange.
+
+        The buffer is ``moe_ep_buffer x`` the held experts' even share of
+        the rows (an even router fills ``1 / moe_ep_buffer`` of it; one of
+        ``router_width / n_experts`` times the share holds every row there
+        can be); rows past it are dropped and COUNTED."""
+        cfg = self.cfg
+        assert cfg.moe_dropless and not self.quant and cfg.mlp == "swiglu"
+        assert self.mesh is None or self.mesh.devices.size == 1, (
+            "the held-experts layer is one chip's share; it has no exchange"
+        )
+        dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
+        e, k, h = cfg.n_experts, cfg.moe_top_k, cfg.resolved_mlp_hidden
+        r, lo = cfg.resolved_router_width, cfg.moe_expert_offset
+        assert 1 <= k <= r and 0 <= lo and lo + e <= r, (k, r, lo, e)
+        d = x.shape[-1]
+        x2 = x.reshape(-1, d)
+        m = x2.shape[0] * k
+        budget = min(m, -(-int(math.ceil(cfg.moe_ep_buffer * m * e / r)) // 8) * 8)
+
+        from orion_tpu.ops.dispatch import resolve
+
+        b = resolve(cfg.backend)
+        with scope("moe_route"):
+            logits, probs, ids, gates = self._route_flat(x2)
+            self._sow_flat_aux(logits, probs, ids)
+        ws = tuple(
+            self.param(name, _expert_init(), shape, pdt)
+            for name, shape in (("experts_gate", (e, d, h)), ("experts_up", (e, d, h)),
+                                ("experts_down", (e, h, d)))
+        )
+        if b.startswith("pallas") and budget >= 1024:
+            matmul = _gmm_matmul(128, 512, b == "pallas_interpret")
+        else:
+            matmul = _ragged_matmul
+        y, held_counts, dropped = _held_rows_ffn(
+            x2, ids.reshape(-1), gates.reshape(-1), ws, lo, budget, matmul, dt
+        )
+        if not self.is_initializing():
+            self.sow("moe_stats", "dropless_overflow", dropped)
+            self.sow("moe_stats", "rows_routed", jnp.asarray(m, jnp.int32))
+            self.sow("moe_stats", "rows_held", held_counts.sum())
+            self.sow("moe_stats", "rows_max_expert", held_counts.max())
         return y.reshape(x.shape).astype(dt)
 
     def _dropless_gmm(
@@ -604,11 +693,12 @@ class MoEMLP(nn.Module):
           the static budget applies per (data-shard, ep-shard):
           ``ceil(moe_ep_buffer * m_local / ep)``, the same proportion of
           local traffic the global budget gave;
-        - local rows scatter into TILE-ALIGNED per-expert segments (the
-          gmm contract) instead of a sorted prefix: in-budget local rows
-          go to ``seg_start[expert] + rank_within_expert``; remote and
-          over-budget rows collapse onto one trash row in a trailing
-          tile whose output is never gathered — no zero-expert
+        - local rows sit in TILE-ALIGNED per-expert segments (the gmm
+          contract) instead of a sorted prefix, and each token sums its
+          local experts' rows, gate-weighted, BEFORE the psum
+          (``_held_rows_ffn``, the body ``_dropless_held`` runs too):
+          the exchange carries [n_loc, d], not [m_loc, d]; remote and
+          over-budget rows never enter the buffer — no zero-expert
           augmentation needed;
         - expert weights are pcast data-axis-varying inside the body so
           the shard_map transpose psums dw over the data axes (the same
@@ -620,8 +710,6 @@ class MoEMLP(nn.Module):
         dryrun line."""
         from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        from orion_tpu.ops.pallas.gmm import gmm, pad_group_sizes
 
         cfg = self.cfg
         dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
@@ -646,11 +734,7 @@ class MoEMLP(nn.Module):
         else:
             budget = int(math.ceil(cfg.moe_ep_buffer * m_loc / ep))
             budget = min(m_loc, max(el, (budget + 7) // 8 * 8))
-        tm, bh = (8, 128) if interpret else (128, 512)
-        # static scatter buffer: every in-budget row + <tm pad per local
-        # expert, tile-rounded, + one trailing trash tile for the rest
-        m2 = -(-(budget + el * tm) // tm) * tm
-        m2p = m2 + tm
+        matmul = _gmm_matmul(*((8, 128) if interpret else (128, 512)), interpret)
 
         logits, probs, ids, gates = self._route_flat(x2)
 
@@ -662,30 +746,8 @@ class MoEMLP(nn.Module):
             wu = self.param("experts_up", _expert_init(), (e, d, h), pdt)
         wdn = self.param("experts_down", _expert_init(), (e, h, d), pdt)
 
-        def body(xl, flat, *ws):
-            r = jax.lax.axis_index("ep")
-            lo = r * el
-            rot = (flat - lo) % e  # local experts become classes 0..el-1
-            _, rank, counts_rot = _counting_sort_perm(rot, e)
-            counts_local = counts_rot[:el]
-            cum = jnp.cumsum(counts_local)
-            cumc = jnp.minimum(cum, budget)
-            gs_local = jnp.diff(cumc, prepend=0)  # in-budget local counts
-            seg, seg_starts = pad_group_sizes(gs_local, tm)
-            offs_all = jnp.cumsum(counts_rot) - counts_rot  # class starts
-            within = rank - offs_all[rot]  # rank within own class
-            gs_all = jnp.concatenate(
-                [gs_local, jnp.zeros((e - el,), gs_local.dtype)]
-            )
-            starts_all = jnp.concatenate(
-                [seg_starts, jnp.zeros((e - el,), seg_starts.dtype)]
-            )
-            is_in = (rot < el) & (within < gs_all[rot])
-            pos = jnp.where(is_in, starts_all[rot] + within, m2)
-            xs = jnp.zeros((m2p, d), dt).at[pos].set(
-                jnp.take(xl.astype(dt), jnp.arange(m_loc) // k, axis=0)
-            )
-
+        def body(xl, flat, gl, *ws):
+            lo = jax.lax.axis_index("ep") * el
             if row_axes and not interpret:
                 # dw transpose -> psum over the data axes (the fused_ce
                 # idiom). Interpret mode runs check_vma=False, where the
@@ -695,29 +757,20 @@ class MoEMLP(nn.Module):
                 ws = tuple(
                     jax.lax.pcast(w, row_axes, to="varying") for w in ws
                 )
-            if cfg.mlp == "swiglu":
-                wgl, wul, wdl = ws
-                mid = jax.nn.silu(
-                    gmm(xs, wgl.astype(dt), seg, tm, bh, interpret)
-                ) * gmm(xs, wul.astype(dt), seg, tm, bh, interpret)
-            else:
-                wul, wdl = ws
-                mid = jax.nn.gelu(gmm(xs, wul.astype(dt), seg, tm, bh, interpret))
-            ys = gmm(mid, wdl.astype(dt), seg, tm, bh, interpret)  # [M2p, d]
-
-            part = jnp.take(ys, pos, axis=0) * is_in[:, None].astype(dt)
-            part = jax.lax.psum(part, "ep")  # [m_loc, d]
-            dropped = jax.lax.psum(
-                cum[-1] - cumc[-1], ("ep",) + row_axes
+            y, _, dropped = _held_rows_ffn(
+                xl, flat, gl, ws, lo, budget, matmul, dt
             )
-            return part, dropped
+            return (
+                jax.lax.psum(y, "ep"),  # [n_loc, d]
+                jax.lax.psum(dropped, ("ep",) + row_axes),
+            )
 
         ws = tuple(w for w in (wg, wu, wdn) if w is not None)
         rs = row_axes if row_axes else None
         fn = shard_map(
             body,
             mesh=mesh,
-            in_specs=(P(rs, None), P(rs))
+            in_specs=(P(rs, None), P(rs), P(rs))
             + (P("ep", None, None),) * len(ws),
             out_specs=(P(rs, None), P()),
             axis_names=frozenset(mesh.axis_names),  # fully manual (Mosaic)
@@ -727,14 +780,11 @@ class MoEMLP(nn.Module):
             # sequence.py/ring.py)
             check_vma=not interpret,
         )
-        part, dropped = fn(x2, ids.reshape(-1), *ws)
+        y, dropped = fn(x2, ids.reshape(-1), gates.reshape(-1), *ws)
 
         self._sow_flat_aux(logits, probs, ids)
         if not self.is_initializing():
             self.sow("moe_stats", "dropless_overflow", dropped)
-
-        y = part.reshape(n, k, d)
-        y = jnp.sum(y * gates[..., None].astype(dt), axis=1)
         return y.reshape(x.shape).astype(dt)
 
     def _ep_constraint(self, t: Array) -> Array:
@@ -771,6 +821,87 @@ def _data_shards(mesh) -> int:
     for a in _data_axes(mesh):
         out *= s.get(a, 1)
     return out
+
+
+def _gmm_matmul(tm: int, bh: int, interpret: bool):
+    """``_held_rows_ffn``'s matmul through the grouped-matmul kernel, which
+    wants every expert's rows in whole tiles of ``tm``."""
+    from orion_tpu.ops.pallas.gmm import gmm
+
+    def matmul(lhs, w, seg, gs):
+        return gmm(lhs, w, seg.astype(jnp.int32), tm, bh, interpret)
+
+    matmul.tile = tm
+    return matmul
+
+
+def _ragged_matmul(lhs, w, seg, gs):
+    """``_held_rows_ffn``'s matmul as ``ragged_dot``: tight segments, and one
+    zero expert that absorbs the spare rows of the buffer."""
+    w = jnp.concatenate([w, jnp.zeros((1,) + w.shape[1:], w.dtype)], axis=0)
+    gs = jnp.concatenate([gs, (lhs.shape[0] - gs.sum())[None]]).astype(jnp.int32)
+    return jax.lax.ragged_dot(lhs, w, gs)
+
+
+_ragged_matmul.tile = 1
+
+
+def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt):
+    """What the experts held here add to each token: the ONE sort-and-matmul
+    body of the expert-parallel forms (an ep shard of ``_dropless_ep_gmm``,
+    where ``lo`` is the shard's first expert, and ``_dropless_held``, one
+    chip's share with ``lo`` fixed and no exchange).
+
+    ``x2 [N, d]``; ``flat``, ``gates`` ``[M = N k]`` token-major (pair ``p``
+    is slot ``p % k`` of token ``p // k``); ``ws`` the held experts' stacks
+    ``[El, ...]`` (gate, up, down or up, down), experts ``[lo, lo + El)``.
+    Returns (``y [N, d]`` fp32, rows per held expert ``[El]``, rows dropped).
+
+    Held rows are counting-sorted by local expert (every other expert is one
+    class more, sorted last) into a buffer of static size: ``budget`` rows
+    plus, for a tiled matmul, a tile's padding an expert. Rows past the
+    budget — the busiest tail of the last experts — are dropped and
+    counted. Everything M-sized is an index: the buffer's row ``r`` belongs
+    to local expert ``c`` at offset ``off`` of its segment, so it is pair
+    ``order[tight[c] + off]``, and only buffer-sized arrays are d wide (at a
+    router 8 x the held experts, [M, d] is 8 x the rows that do work)."""
+    el, tm = ws[0].shape[0], matmul.tile
+    (n, d), m = x2.shape, flat.shape[0]
+    k = m // n
+    m2 = -(-(budget + (el * tm if tm > 1 else 0)) // tm) * tm
+    with scope("moe_route"):
+        loc = flat - lo
+        cls = jnp.where((loc >= 0) & (loc < el), loc, el)  # el: held elsewhere
+        order, _, counts = _counting_sort_perm(cls, el + 1)
+        held = counts[:el]
+        cum = jnp.cumsum(held)
+        cumc = jnp.minimum(cum, budget)
+        gs = jnp.diff(cumc, prepend=0)  # in-budget rows per held expert
+        tight = cum - held  # held classes sort first: their starts in order
+        seg = -(-gs // tm) * tm
+        starts = jnp.cumsum(seg) - seg
+        row = jnp.arange(m2, dtype=jnp.int32)
+        c = jnp.sum(row[:, None] >= starts[None, :], axis=1) - 1
+        off = row - starts[c]
+        valid = off < gs[c]  # else tile padding / spare buffer
+        pair = order[jnp.clip(tight[c] + off, 0, m - 1)]
+        token = pair // k
+        gate_row = jnp.where(valid, gates[pair], 0.0)
+
+    with scope("moe_experts"):
+        # pad rows are zeros: they flow through the FFN as zeros
+        xs = jnp.where(valid[:, None], jnp.take(x2.astype(dt), token, axis=0), 0)
+        mm = lambda lhs, w: matmul(lhs, w.astype(dt), seg, gs)  # noqa: E731
+        if len(ws) == 3:
+            mid = jax.nn.silu(mm(xs, ws[0])) * mm(xs, ws[1])
+        else:
+            mid = jax.nn.gelu(mm(xs, ws[0]))
+        ys = mm(mid, ws[-1])  # [M2, d]
+        # each token gathers its held experts' rows, weighted
+        y = jnp.zeros((n, d), jnp.float32).at[token].add(
+            ys.astype(jnp.float32) * gate_row[:, None]
+        )
+    return y, held, cum[-1] - cumc[-1]
 
 
 def _counting_sort_perm(flat: Array, n_classes: int):

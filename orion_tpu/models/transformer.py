@@ -26,7 +26,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from orion_tpu.models.configs import ModelConfig
+from orion_tpu.models.configs import TRAIN_ONLY_LAYER_TYPES, ModelConfig
 from orion_tpu.ops.dispatch import decode_state_step
 from orion_tpu.ops.feature_maps import make_feature_map
 from orion_tpu.ops.linear_attention import (
@@ -74,7 +74,31 @@ def _qdense_factory(quant: str, dt, mesh=None):
 def _norm(cfg: ModelConfig, name: str):
     if cfg.norm == "rmsnorm":
         return nn.RMSNorm(dtype=_dtype(cfg.dtype), name=name)
+    if cfg.norm == "rmsnorm_zero":
+        from orion_tpu.models.gated_mixers import ZeroCentredRMSNorm
+
+        return ZeroCentredRMSNorm(
+            _dtype(cfg.dtype), _dtype(cfg.param_dtype), name=name
+        )
     return nn.LayerNorm(dtype=_dtype(cfg.dtype), name=name)
+
+
+def kernel_bh(cfg: ModelConfig, mesh, fn, *args):
+    """Kernel dispatch for per-(batch, head)-parallel attention: on a
+    GSPMD mesh whose data axes split, a Mosaic kernel must be
+    manualized (XLA cannot auto-partition tpu_custom_call) — shard_map
+    over (dp, fsdp, tp) via parallel/kernel_shard.py; everywhere else
+    the call goes straight through."""
+    from orion_tpu.ops.dispatch import resolve
+    from orion_tpu.parallel.kernel_shard import needs_manual, shard_map_bh
+
+    b = resolve(cfg.backend)
+    if needs_manual(mesh, b):
+        # vma ON for real Mosaic (its lowering requires it in a
+        # partial-manual region), OFF for interpret kernels (which
+        # cannot trace under the check) — kernel_shard.py docstring
+        return shard_map_bh(mesh, fn, *args, check_vma=(b != "pallas_interpret"))
+    return fn(*args)
 
 
 class Attention(nn.Module):
@@ -164,23 +188,7 @@ class Attention(nn.Module):
         return self.wo(out.reshape(*out.shape[:-2], -1))
 
     def _kernel_bh(self, fn, *args):
-        """Kernel dispatch for per-(batch, head)-parallel attention: on a
-        GSPMD mesh whose data axes split, a Mosaic kernel must be
-        manualized (XLA cannot auto-partition tpu_custom_call) — shard_map
-        over (dp, fsdp, tp) via parallel/kernel_shard.py; everywhere else
-        the call goes straight through."""
-        from orion_tpu.ops.dispatch import resolve
-        from orion_tpu.parallel.kernel_shard import needs_manual, shard_map_bh
-
-        b = resolve(self.cfg.backend)
-        if needs_manual(self.mesh, b):
-            # vma ON for real Mosaic (its lowering requires it in a
-            # partial-manual region), OFF for interpret kernels (which
-            # cannot trace under the check) — kernel_shard.py docstring
-            return shard_map_bh(
-                self.mesh, fn, *args, check_vma=(b != "pallas_interpret")
-            )
-        return fn(*args)
+        return kernel_bh(self.cfg, self.mesh, fn, *args)
 
     # -- parallel forward ---------------------------------------------------
 
@@ -817,11 +825,19 @@ class Block(nn.Module):
 
     def setup(self):
         self.norm1 = _norm(self.cfg, "norm1")
-        self.attn = Attention(
-            self.cfg, self.layer_type, self.causal, self.mesh,
-            self.sp_local, quant=self.quant,
-            sp_local_kernels=self.sp_local_kernels, name="attn"
-        )
+        if self.layer_type in TRAIN_ONLY_LAYER_TYPES:
+            from orion_tpu.models.gated_mixers import MIXERS
+
+            assert self.causal and not self.sp_local and not self.quant
+            self.attn = MIXERS[self.layer_type](
+                self.cfg, mesh=self.mesh, name="attn"
+            )
+        else:
+            self.attn = Attention(
+                self.cfg, self.layer_type, self.causal, self.mesh,
+                self.sp_local, quant=self.quant,
+                sp_local_kernels=self.sp_local_kernels, name="attn"
+            )
         self.norm2 = _norm(self.cfg, "norm2")
         if self.use_moe:
             from orion_tpu.models.moe import MoEMLP
@@ -883,7 +899,12 @@ class TransformerLM(nn.Module):
             self.embed = Int8Embed(cfg.vocab_size, cfg.d_model)
         else:
             self.embed = nn.Embed(cfg.vocab_size, cfg.d_model, param_dtype=pdt)
-        self.pos_embed = nn.Embed(cfg.max_seq_len, cfg.d_model, param_dtype=pdt)
+        if cfg.pos_embed == "learned":
+            self.pos_embed = nn.Embed(
+                cfg.max_seq_len, cfg.d_model, param_dtype=pdt
+            )
+        else:
+            assert cfg.pos_embed == "none", cfg.pos_embed
         block_cls = Block
         if cfg.remat:
             block_cls = nn.remat(
@@ -927,7 +948,9 @@ class TransformerLM(nn.Module):
             # the int8 table is 4x smaller and the sharding rules store
             # embedding_q REPLICATED (parallel/sharding.py), so the gather
             # never touches an fsdp-sharded table
-            x = self.embed(tokens) + self.pos_embed(positions)
+            x = self.embed(tokens)
+            if self.cfg.pos_embed == "learned":
+                x = x + self.pos_embed(positions)
             return x.astype(_dtype(self.cfg.dtype))
         # FSDP-style lookup: the tables are *stored* feature-sharded over
         # fsdp (parallel/sharding.py), but gather/scatter on a sharded table
@@ -940,8 +963,10 @@ class TransformerLM(nn.Module):
 
         rep = NamedSharding(self.mesh, P(None, None))
         wt = jax.lax.with_sharding_constraint(self.embed.embedding, rep)
-        wp = jax.lax.with_sharding_constraint(self.pos_embed.embedding, rep)
-        x = jnp.take(wt, tokens, axis=0) + jnp.take(wp, positions, axis=0)
+        x = jnp.take(wt, tokens, axis=0)
+        if self.cfg.pos_embed == "learned":
+            wp = jax.lax.with_sharding_constraint(self.pos_embed.embedding, rep)
+            x = x + jnp.take(wp, positions, axis=0)
         x = x.astype(_dtype(self.cfg.dtype))
         if x.ndim == 3:
             # sequence-parallel runs keep activations token-sharded over sp
@@ -1257,6 +1282,12 @@ def init_decode_state(
     b = batch_size
     states: List[State] = []
     for lt in cfg.resolved_layer_types:
+        if lt in TRAIN_ONLY_LAYER_TYPES:
+            raise NotImplementedError(
+                f"layer type {lt!r} has a training forward only: no decode "
+                "state (delta-rule state, conv state, grouped-KV cache) is "
+                "built for it"
+            )
         if lt == "linear":
             states.append(
                 {

@@ -6,7 +6,9 @@ backend='xla' dispatch"). "auto" picks Pallas on TPU and the pure-XLA
 chunked scan elsewhere (CPU/GPU and unit tests). The Pallas kernel can also
 run anywhere via interpret mode (used by the parity tests).
 
-Two ops dispatch here: ``causal_dot_product`` (the parallel forward:
+Three ops dispatch here: ``gated_delta_rule`` (the gated delta-rule
+layers' parallel forward; no Mosaic kernel yet, so both Pallas backends run
+the XLA chunked form), ``causal_dot_product`` (the parallel forward:
 training, prefill) and ``decode_state_step`` (the slot-multiplexed decode
 programs' ``(S, z)`` step: under Pallas a row-sparse in-place kernel that
 touches only the rows live in the chunk, ``ops/pallas/decode_state.py``;
@@ -113,6 +115,21 @@ def causal_dot_product(
     )
 
 
+def gated_delta_rule(q, k, v, beta, g, *, backend: str = "auto",
+                     chunk: Optional[int] = None):
+    """Dispatch the gated delta rule (``ops/gated_delta.py``): ``eager`` is
+    the token-by-token recurrence, every other backend the chunked WY form
+    in XLA (chunk 64 unless given) — the one form there is on the chip
+    until a kernel is written."""
+    from orion_tpu.ops import gated_delta as gd
+
+    if resolve(backend) == "eager":
+        return gd.gated_delta_recurrent(q, k, v, beta, g)
+    return gd.gated_delta_by_rows(
+        q, k, v, beta, g, chunk=chunk or gd.DEFAULT_CHUNK
+    )
+
+
 def _row_sparse(backend: str) -> bool:
     return resolve(backend) in ("pallas", "pallas_interpret")
 
@@ -155,6 +172,7 @@ __all__ = [
     "decode_live_rows",
     "decode_state_step",
     "default_backend",
+    "gated_delta_rule",
     "resolve",
     "resolve_chunk",
 ]

@@ -41,4 +41,18 @@ def apply_rotary_at(x: Array, angles_table: Array, positions: Array) -> Array:
     return _rotate(x, angles_table[positions])
 
 
-__all__ = ["rotary_freqs", "apply_rotary", "apply_rotary_at"]
+def apply_rotary_half(x: Array, rotary_dims: int, base: float) -> Array:
+    """The rotate-half convention on the first ``rotary_dims`` of x ``[...,
+    T, Dh]`` at positions 0..T-1: dim ``j`` is paired with ``j +
+    rotary_dims / 2`` (not with its neighbour) and rotated by ``t *
+    base^(-2j / rotary_dims)``; the rest of the head is untouched."""
+    half = rotary_dims // 2
+    ang = rotary_freqs(rotary_dims, x.shape[-2], base)  # [T, half]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    x1, x2, rest = xf[..., :half], xf[..., half:rotary_dims], xf[..., rotary_dims:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], -1)
+    return out.astype(x.dtype)
+
+
+__all__ = ["rotary_freqs", "apply_rotary", "apply_rotary_at", "apply_rotary_half"]

@@ -271,6 +271,19 @@ def make_optimizer(
 from orion_tpu.ops.fused_ce import fused_ce_ok as _fused_ce_ok  # shared gate
 
 
+# name sown into "moe_stats" (models/moe.py) -> step metric
+MOE_STATS = {
+    "dropless_overflow": "moe_overflow",
+    "rows_routed": "moe_rows_routed",
+    "rows_held": "moe_rows_held",
+    "rows_max_expert": "moe_rows_max_expert",
+}
+
+
+def zero_moe_stats() -> Dict[str, Array]:
+    return {name: jnp.zeros((), jnp.int32) for name in MOE_STATS.values()}
+
+
 def lm_loss(
     model: TransformerLM, params, batch: Array, dropout_rng=None,
     fused_ce: Optional[bool] = None, return_stats: bool = False,
@@ -282,13 +295,15 @@ def lm_loss(
     ``fused_ce``: None = auto (_fused_ce_ok); the fused path computes the
     identical loss without materializing [B, T, V] fp32 logits.
 
-    ``return_stats``: also return a fixed-structure diagnostics dict —
-    currently ``{"moe_overflow": int32}``, the summed "moe_stats"
-    collection (dropless-ep rows dropped past the static budget,
-    models/moe.py::_dropless_ep; 0 whenever nothing sowed). The structure
-    is static so it can ride a grad-accumulation scan carry (ADVICE r4:
-    the counter existed but had no consumer — "counted, never silent"
-    requires a reader)."""
+    ``return_stats``: also return a fixed-structure diagnostics dict, the
+    "moe_stats" collection summed over layers BY NAME (``MOE_STATS``):
+    ``moe_overflow`` (rows dropped past a static budget,
+    models/moe.py::_dropless_ep / _dropless_held) and the held-experts
+    layer's row counters ``moe_rows_routed`` / ``moe_rows_held`` /
+    ``moe_rows_max_expert``; 0 whenever nothing sowed. The structure is
+    static so it can ride a grad-accumulation scan carry (ADVICE r4: the
+    counter existed but had no consumer — "counted, never silent" requires
+    a reader)."""
     x, y = batch[:, :-1], batch[:, 1:]
     kwargs = {}
     if dropout_rng is not None:
@@ -311,10 +326,15 @@ def lm_loss(
         loss = loss + leaf
     if not return_stats:
         return loss
-    overflow = jnp.zeros((), jnp.int32)
-    for leaf in jax.tree.leaves(variables.get("moe_stats", {})):
-        overflow = overflow + leaf.astype(jnp.int32)
-    return loss, {"moe_overflow": overflow}
+    stats = zero_moe_stats()
+    for path, leaf in jax.tree_util.tree_leaves_with_path(
+        variables.get("moe_stats", {})
+    ):
+        sown = next(
+            p.key for p in reversed(path) if isinstance(p, jax.tree_util.DictKey)
+        )
+        stats[MOE_STATS[sown]] = stats[MOE_STATS[sown]] + leaf.astype(jnp.int32)
+    return loss, stats
 
 
 class Trainer:
@@ -553,7 +573,7 @@ class Trainer:
                     n_micro=self.pp_n_micro,
                     dropout_rng=r if use_dropout else None,
                     full_manual=cfg.pp_full_manual,
-                ), {"moe_overflow": jnp.zeros((), jnp.int32)}
+                ), zero_moe_stats()
             return lm_loss(
                 self.model, params, b, r if use_dropout else None,
                 return_stats=True,
@@ -577,7 +597,7 @@ class Trainer:
             zeros = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state.params
             )
-            stats0 = {"moe_overflow": jnp.zeros((), jnp.int32)}
+            stats0 = zero_moe_stats()
             (loss, stats, grads, _), _ = jax.lax.scan(
                 body,
                 (jnp.zeros((), jnp.float32), stats0, zeros,
@@ -672,6 +692,12 @@ class Trainer:
             # an absent metric says "not measured" where 0 would say "no
             # drops" (r5 review).
             metrics["moe_overflow"] = stats["moe_overflow"]
+            if cfg.model.resolved_router_width != cfg.model.n_experts:
+                # one chip's share of an expert-parallel layer: the rows
+                # the router sent out, those whose expert is held here,
+                # and the busiest held expert's (summed over layers)
+                for name in ("moe_rows_routed", "moe_rows_held", "moe_rows_max_expert"):
+                    metrics[name] = stats[name]
         return new_state, metrics
 
     def _sr_apply(self, params, updates, step_rng: Array):

@@ -1,6 +1,7 @@
 """Host spans in a ``jax.profiler`` capture (SURVEY.md A1).
 
-The one place the package constructs profiler annotations. A capture is
+The one place the package constructs profiler annotations and named
+scopes. A capture is
 taken elsewhere — ``Server.arm_profile`` / ``/profilez`` for serving, the
 benchmark's train kind for ``Trainer.train`` — and read by
 ``benchmark/readers/xplane.py``; these names are what such a capture
@@ -19,6 +20,14 @@ def annotate(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
+def scope(name: str):
+    """``with scope("gated_delta"):`` — a ``jax.named_scope``: the name
+    goes on the name stack of every XLA operation traced inside, forward
+    and backward, where the benchmark's scope reader finds it
+    (``benchmark/readers/scope_share.py``). Costs nothing at run time."""
+    return jax.named_scope(name)
+
+
 def annotated_steps(name: str, steps):
     """``for step in annotated_steps("train", range(a, b)):`` — each
     iteration's body runs inside a ``StepTraceAnnotation`` (the profiler
@@ -29,4 +38,4 @@ def annotated_steps(name: str, steps):
             yield step
 
 
-__all__ = ["annotate", "annotated_steps"]
+__all__ = ["annotate", "annotated_steps", "scope"]

@@ -125,7 +125,23 @@ def _decode_state(s, z, q, k, v, live):
     return decode_state_step(q, k, v, (s, z), live_rows(live))
 
 
+def _gated_delta(q, k, v, beta, g):
+    from orion_tpu.ops.dispatch import gated_delta_rule
+
+    return gated_delta_rule(q, k, v, beta, g, backend="pallas")
+
+
 _QKV = [(BHTD, jnp.bfloat16)] * 3
+# qwen3_next_80b's train point (batch 8, T 8192): its softmax layer's 16
+# heads of 256 after the KV heads are repeated; its held experts' buffer
+# (1.5 x 81,920 rows + a tile an expert) through 64 experts of 2048 x 512;
+# two rows of its delta-rule layer (32 heads of 128, bf16 q/k/v, fp32 beta
+# and log-decay), which the op runs a row at a time
+_QKV_GQA = [((8, 16, 8192, 256), jnp.bfloat16)] * 3
+_GMM_HELD = [((131072, 2048), jnp.bfloat16), ((64, 2048, 512), jnp.bfloat16),
+             ((64,), jnp.int32)]
+_DELTA = [*[((2, 32, 8192, 128), jnp.bfloat16)] * 3,
+          *[((2, 32, 8192), jnp.float32)] * 2]
 # the serve cells' decode carry: 64 slots of lm_1b3's fp32 (S, z), one
 # token's bf16 q, k, v a slot, and the chunk's row mask
 _STATE = [((64, 16, 128, 128), jnp.float32), ((64, 16, 128), jnp.float32),
@@ -151,6 +167,14 @@ KERNELS = [
         id="q4_matmul",
     ),
     pytest.param(_decode_state, _STATE, id="decode_state-64slots"),
+    pytest.param(_flash(None), _QKV_GQA, id="flash-causal-d256-T8192-fwd"),
+    pytest.param(_grad3(_flash(None)), _QKV_GQA,
+                 id="flash-causal-d256-T8192-bwd"),
+    pytest.param(_gmm, _GMM_HELD, id="gmm-held64-fwd"),
+    pytest.param(
+        jax.grad(lambda x, w, g: _f32sum(_gmm(x, w, g)), argnums=(0, 1)),
+        _GMM_HELD, id="gmm-held64-bwd",
+    ),
     # -- the rest of the main path's kernels ---------------------------------
     pytest.param(_plain, _QKV, id="causal_dot-plain-fwd", marks=slow),
     pytest.param(_grad3(_plain), _QKV, id="causal_dot-plain-bwd", marks=slow),
@@ -174,6 +198,17 @@ def test_kernel_compiles_for_v5e(v5e, fn, shapes):
     is really in the program (no silent XLA form)."""
     compiled = _compile(v5e, fn, *shapes)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_gated_delta_rule_compiles_for_v5e(v5e):
+    """The chunked delta rule (plain XLA: no Mosaic kernel for it yet),
+    forward and backward, a batch row at a time, fits and compiles."""
+    grad = jax.grad(
+        lambda *a: _f32sum(_gated_delta(*a)), argnums=(0, 1, 2, 3, 4)
+    )
+    compiled = _compile(v5e, grad, *_DELTA)
+    assert "while" in compiled.as_text()  # the scan over rows and chunks
+    assert compiled.memory_analysis().temp_size_in_bytes < 8e9
 
 
 # -- whole programs (slow: ~20 s to ~4 min each) -----------------------------
@@ -218,6 +253,34 @@ def test_smoke_train_step_compiles_and_fits(v5e, layout, collective):
     if layout == "fsdp4":
         one_chip = 2 * 1284083712  # bf16 params, unsharded
         assert rep["param_bytes_per_device"] < 0.3 * one_chip, rep
+
+
+@slow
+def test_qwen3_next_train_step_compiles_and_fits(v5e):
+    """benchmark/workloads/qwen3_next_80b.train.json's step — b8 x T8192,
+    adafactor, bfloat16_sr, every block rematted — fits one chip with the
+    flash and grouped-matmul kernels in it (the memory point known before
+    a chip call: 2.07 GB of arguments + 14.3 GB of temporaries by the
+    compiler's count, of which the donated state's 2.07 GB is counted
+    twice; the chip holds 15.6 GB while it runs, PERF.md s5)."""
+    from orion_tpu.aot import plan
+    from orion_tpu.models.configs import get_config
+    from orion_tpu.parallel.mesh import MeshConfig, make_mesh
+    from orion_tpu.training.trainer import TrainConfig
+
+    mc = MeshConfig(dp=1)
+    model = dataclasses.replace(
+        get_config("qwen3_next_80b"), backend="pallas", remat_skip=0,
+        max_seq_len=8192,
+    )
+    cfg = TrainConfig(
+        model=model, batch_size=8, seq_len=8192, optimizer="adafactor",
+        param_storage="bfloat16_sr", mesh=mc,
+    )
+    mesh = make_mesh(mc.resolve(1), devices=v5e[:1])
+    rep = plan(cfg, compile_step=True, mesh=mesh)
+    assert rep["compiled"] and rep["n_params"] == 1028320320, rep
+    assert rep["collectives"]["mosaic_kernels"] > 0, rep["collectives"]
 
 
 @slow
