@@ -190,7 +190,9 @@ F32_MATMUL_SCOPES = (
     "causal_dot.py",                # pallas state init/carry helpers
     "sequence.py",                  # sp exclusive-prefix fp32 state math
     "transformer.py::_phi_map",     # FAVOR+ fp32 random-feature projection
-    "gated_delta.py",               # delta-rule fp32 state + triangular inverse
+    # delta-rule fp32 state + triangular inverse: ops/gated_delta.py and its
+    # kernels' wrapper ops/pallas/gated_delta.py (frames match by file name)
+    "gated_delta.py",
 )
 
 

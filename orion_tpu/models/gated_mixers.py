@@ -171,13 +171,14 @@ class GatedDeltaNet(_TrainOnly, nn.Module):
             )
             q = (_l2norm(q) * dk ** -0.5).astype(dt)
             k = _l2norm(k).astype(dt)
-            # key head j serves value heads j * (hv / hk) ... + hv / hk - 1
-            q, k = (jnp.repeat(y, hv // hk, axis=2) for y in (q, k))
             heads_first = lambda y: jnp.swapaxes(y, 1, 2)  # noqa: E731
-            o = gated_delta_rule(
-                heads_first(q), heads_first(k), heads_first(v),
-                heads_first(beta), heads_first(g),
-                backend=cfg.backend,  # chunks of 64: cfg.chunk is linear attention's knob
+            # key head j serves value heads j * (hv / hk) ... + hv / hk - 1:
+            # the op repeats q and k, or its kernel reads them in place. Its
+            # chunking is its own: cfg.chunk is linear attention's knob
+            o = kernel_bh(
+                cfg, self.mesh,
+                lambda *a: gated_delta_rule(*a, backend=cfg.backend),
+                *(heads_first(y) for y in (q, k, v, beta, g)),
             )  # [B, Hv, T, Dv]
             o = heads_first(o)  # [B, T, Hv, Dv]
             w_n = self.param("out_norm", nn.initializers.ones_init(), (dv,), pdt)

@@ -7,12 +7,14 @@ chunked scan elsewhere (CPU/GPU and unit tests). The Pallas kernel can also
 run anywhere via interpret mode (used by the parity tests).
 
 Three ops dispatch here: ``gated_delta_rule`` (the gated delta-rule
-layers' parallel forward; no Mosaic kernel yet, so both Pallas backends run
-the XLA chunked form), ``causal_dot_product`` (the parallel forward:
-training, prefill) and ``decode_state_step`` (the slot-multiplexed decode
-programs' ``(S, z)`` step: under Pallas a row-sparse in-place kernel that
-touches only the rows live in the chunk, ``ops/pallas/decode_state.py``;
-``recurrent_step`` over every row otherwise).
+layers' parallel forward: under Pallas the chunked form as Mosaic kernels,
+forward and backward, ``ops/pallas/gated_delta.py``; the same equations as
+XLA fusions and a scan otherwise), ``causal_dot_product`` (the parallel
+forward: training, prefill) and ``decode_state_step`` (the
+slot-multiplexed decode programs' ``(S, z)`` step: under Pallas a
+row-sparse in-place kernel that touches only the rows live in the chunk,
+``ops/pallas/decode_state.py``; ``recurrent_step`` over every row
+otherwise).
 """
 
 from __future__ import annotations
@@ -115,19 +117,36 @@ def causal_dot_product(
     )
 
 
-def gated_delta_rule(q, k, v, beta, g, *, backend: str = "auto",
-                     chunk: Optional[int] = None):
-    """Dispatch the gated delta rule (``ops/gated_delta.py``): ``eager`` is
-    the token-by-token recurrence, every other backend the chunked WY form
-    in XLA (chunk 64 unless given) — the one form there is on the chip
-    until a kernel is written."""
+def gated_delta_rule(q, k, v, beta, g, *, backend: str = "auto"):
+    """Dispatch the gated delta rule (``ops/gated_delta.py``) on q, k
+    ``[..., Hk, T, Dk]``, v ``[..., Hv, T, Dv]``, beta, g ``[..., Hv, T]``;
+    key head ``j`` serves value heads ``j * (Hv / Hk) ...``.
+
+    ``eager`` is the token-by-token recurrence. ``pallas`` and
+    ``pallas_interpret`` run the chunked WY form as Mosaic kernels
+    (``ops/pallas/gated_delta.py``: forward and backward, its own tiling,
+    the key heads read in place). ``xla`` — the CPU, and on the chip any
+    width the kernels do not take (Dk or Dv off a multiple of 128) — runs
+    the same form as XLA fusions and a scan, a block of batch rows at a
+    time in chunks of 64, on key heads repeated to the value heads."""
     from orion_tpu.ops import gated_delta as gd
 
-    if resolve(backend) == "eager":
+    b = resolve(backend)
+    if b.startswith("pallas"):
+        from orion_tpu.ops.pallas import gated_delta as pgd
+
+        if b == "pallas_interpret" or pgd.supports(q.shape[-1], v.shape[-1]):
+            return pgd.gated_delta_rule_pallas(
+                q, k, v, beta, g, interpret=(b == "pallas_interpret")
+            )
+    group = v.shape[-3] // q.shape[-3]
+    if group > 1:
+        import jax.numpy as jnp
+
+        q, k = (jnp.repeat(y, group, axis=-3) for y in (q, k))
+    if b == "eager":
         return gd.gated_delta_recurrent(q, k, v, beta, g)
-    return gd.gated_delta_by_rows(
-        q, k, v, beta, g, chunk=chunk or gd.DEFAULT_CHUNK
-    )
+    return gd.gated_delta_by_rows(q, k, v, beta, g)
 
 
 def _row_sparse(backend: str) -> bool:

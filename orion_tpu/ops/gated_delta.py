@@ -23,6 +23,14 @@ Per head, with a ``[Dk, Dv]`` state ``S`` (``S_0 = 0``), a log-decay
    strongly negative ``g`` underflows to 0 and never overflows.
    Differentiable by autodiff; the scan keeps one ``S`` per chunk.
 
+Which form runs where (``ops/dispatch.py::gated_delta_rule``): backend
+``eager`` is form 1; ``xla`` (the CPU; on the chip, head widths off a
+multiple of 128) is form 2 through ``gated_delta_by_rows``; ``pallas`` and
+``pallas_interpret`` run form 2's equations as Mosaic kernels, forward and
+backward (``ops/pallas/gated_delta.py``), which keep a chunk's ``A``, ``T``
+and ``S`` in VMEM and take the whole batch at once. This file is their
+specification and the parity form of their tests.
+
 ``causal_short_conv`` is the depthwise causal convolution (+ SiLU) that
 feeds the layer's q, k and v.
 

@@ -1,0 +1,429 @@
+"""Pallas TPU kernels for the chunked gated delta rule, forward and backward.
+
+The mathematics is ``ops/gated_delta.py``'s chunked (WY) form, unchanged;
+its module docstring is the specification. What changes is where a chunk's
+arrays live. The XLA form carries some twenty chunk-local arrays (``A``,
+``T``, ``u0``, ``w``, the decayed q and k, ...) through HBM between its
+fusions and a scan; here a grid step loads a block of up to
+``BLOCK_CHUNKS`` chunks of q, k, v, beta and the in-chunk cumulative
+log-decay ``G`` of one value head, forms everything else in VMEM and writes
+only ``o``.
+
+- *Forward* (``gated_delta_fwd``). Grid ``(batch x value heads, blocks of
+  chunks)``, the second axis sequential with the fp32 state ``S`` in a VMEM
+  scratch (as ``causal_dot.py::_kernel`` walks its chunks). Per chunk:
+  ``decay``, ``A``, ``T = (I + A)^-1`` (``_unit_lower_inverses``), ``u = T
+  diag(beta) (v - k_dec S)``, ``o = q_dec S + (q k^T * decay) u``, ``S <-
+  e^{G_last} S + k_end^T u``. What does not wait on ``S`` is formed for the
+  whole block first, a level of the solve at a time across its chunks, so
+  that the MXU has independent products to take while one chunk's wait on
+  each other. Under ``jax.grad`` it also writes each chunk's ``T`` and
+  ``u`` in the input dtype (the operands they are used as) and the state
+  that enters each block (fp32): the custom VJP's residuals besides its
+  inputs, 1.2 GB a layer at 8 x 32 heads x T 8192 against the ~2 GB of
+  chunk-local arrays the XLA form kept per batch row.
+- *Backward* (``gated_delta_bwd``). The blocks walked last to first with
+  ``dS`` in an fp32 scratch. A grid step replays its chunks' incoming
+  states from the block's saved one (one product a chunk, from the saved
+  ``u``), then walks the chunks in reverse. No solve is repeated, and the
+  inverse's VJP ``dA = -T^T dT T^T`` collapses, with ``dT = dU R^T`` and
+  ``U = T R``, to ``dA = -(T^T dU) U^T``: no product of ``T``'s. It emits
+  ``dq``, ``dk``, ``dv``, ``dbeta`` and ``dG``; the in-chunk cumulative sum
+  ``g -> G`` stays outside, differentiated by autodiff.
+- *Grouped heads.* q and k keep their ``Hk`` key heads: the value head
+  ``hv`` reads key head ``hv // (Hv / Hk)`` through the ``BlockSpec``
+  index map, so nothing is repeated in HBM; ``dq`` / ``dk`` come out per
+  value head and are summed over each group right after.
+- *Precision.* Matmul operands in the input dtype with fp32 accumulation,
+  rounded where the XLA form rounds them (``T diag(beta)`` once, from the
+  fp32 ``T``); decays, ``A``, the solve and ``S`` in fp32. The solve's
+  products are three bf16 passes of a hi/lo split (~2^-17 relative: what
+  ``Precision.HIGH`` means) for every input dtype. Every decay is ``exp``
+  of a non-positive difference. In the solve's VJP (``dr = T^T du``,
+  ``dA = -dr u^T``) ``du`` and ``dr`` enter as hi + lo operands; ``T`` is
+  read as saved, in the input dtype: saved in fp32 and multiplied in three
+  passes it moved no gradient's error (0.268 against 0.269% of ``dv``,
+  the others equal; my chip run, PR 31) for 0.54 GB more a layer.
+- *Shapes.* ``CHUNK`` and ``BLOCK_CHUNKS`` are this file's tiling (chosen
+  on the chip: chunks of 128 against 64, 39 against 49 ms a layer forward;
+  8 chunks a step against 4, 16.1 against 17.1), not a knob. T off a
+  multiple of a block is zero-padded (k = v = beta = g = 0: the state passes
+  through); a T shorter than a block is one smaller block. Compiled for a
+  TPU the kernels need Dk and Dv to be multiples of 128 (``supports``);
+  ``ops/dispatch.py`` sends other widths to the XLA form. Interpret mode
+  takes any width.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from orion_tpu.ops.pallas.causal_dot import _sds
+
+Array = jax.Array
+
+CHUNK = 128  # tokens a chunk: the solve is C x C, a whole MXU tile
+BLOCK_CHUNKS = 8  # chunks a grid step (fewer when T is short); one saved state a block
+_BASE = 16  # diagonal blocks inverted by a Neumann product (A^16 = 0)
+_F32 = jnp.float32
+_NT = (((1,), (1,)), ((), ()))  # x @ y^T
+_TN = (((0,), (0,)), ((), ()))  # x^T @ y
+
+
+def supports(dk: int, dv: int) -> bool:
+    """Widths the compiled kernels take: whole 128-lane tiles."""
+    return dk % 128 == 0 and dv % 128 == 0
+
+
+def _dot(x, y, dims=None):
+    if dims is None:
+        return jnp.dot(x, y, preferred_element_type=_F32)
+    return jax.lax.dot_general(x, y, dims, preferred_element_type=_F32)
+
+
+def _parts(x, dtype=jnp.bfloat16):
+    """fp32 ``x`` as matmul operands of ``dtype`` that sum to it: itself
+    for fp32, else its rounding and what the rounding lost."""
+    if dtype == _F32:
+        return (x,)
+    hi = x.astype(dtype)
+    return hi, (x - hi.astype(_F32)).astype(dtype)
+
+
+def _solve_dot(x, y):
+    """An fp32 x fp32 product of the triangular solve: three bf16 passes of
+    a hi/lo split (~2^-17 relative), what ``Precision.HIGH`` asks for in
+    ``ops/gated_delta.py``, for every input dtype."""
+    (xh, xl), (yh, yl) = _parts(x), _parts(y)
+    return (_dot(xh, yl) + _dot(xl, yh)) + _dot(xh, yh)
+
+
+def _unit_lower_inverses(mats):
+    """``(I + A)^-1`` for each strictly lower-triangular ``A [C, C]`` fp32
+    of ``mats``.
+
+    ``A = [[A11, 0], [A21, A22]]`` in halves of ``h = C / 2``. The two
+    diagonal halves are inverted side by side, lane-packed as ``[A11 | A22]``
+    (``h x C``), by the products of ``ops/gated_delta.py::
+    _unit_lower_inverse_fwd`` (Neumann over the 16 x 16 diagonal blocks, then
+    over what couples them): a packed product ``[X1 Y1 | X2 Y2]`` is ``X``
+    times the block-diagonal of ``Y``, one ``h``-row pass through a full MXU
+    tile where two half-filled ones were. Then the exact merge
+    ``[[T1, 0], [-T2 A21 T1, T2]]``. Every product is taken a level at a
+    time across ``mats``: one matrix's products wait on each other, its
+    neighbours' fill the MXU meanwhile (a chunk alone, unpacked, ran the
+    solve at a quarter of this pace: 29 against 7.4 ms a layer at the
+    cell's shape, my chip runs, PR 31)."""
+    c = mats[0].shape[-1]
+    h = c // 2
+    assert h % _BASE == 0 and h & (h - 1) == 0, c
+    prow = jax.lax.broadcasted_iota(jnp.int32, (h, c), 0)
+    pcol = jax.lax.broadcasted_iota(jnp.int32, (h, c), 1)
+    left, inner = pcol < h, pcol & (h - 1)  # a lane's half, its column there
+    eye = (inner == prow).astype(_F32)
+    stack = lambda top, bottom: jnp.concatenate([top, bottom], axis=0)  # noqa: E731
+
+    def pmm(x, y):  # [x1 y1 | x2 y2] of two lane-packed pairs
+        return _solve_dot(x, stack(jnp.where(left, y, 0.0), jnp.where(left, 0.0, y)))
+
+    def nilpotent_inverses(ns, order):
+        """(I + N)^-1 for N^order = 0, order a power of two."""
+        invs, powers, reach = [eye - n for n in ns], ns, 2
+        while reach < order:
+            powers = [pmm(p, p) for p in powers]
+            invs = [pmm(i, eye + p) for i, p in zip(invs, powers)]
+            reach *= 2
+        return invs
+
+    shift = _BASE.bit_length() - 1
+    diag = (prow >> shift) == (inner >> shift)
+    packed = [jnp.where(left, a[:h], a[h:]) for a in mats]
+    ds = [jnp.where(diag, p, 0.0) for p in packed]
+    dinvs = nilpotent_inverses(ds, _BASE)
+    ms = [pmm(dinv, p - d) for dinv, p, d in zip(dinvs, packed, ds)]
+    ts = [pmm(inv, dinv) for inv, dinv in zip(nilpotent_inverses(ms, h // _BASE), dinvs)]
+    # [A21 | 0] [[T1, 0], [T1, 0]] = [A21 T1 | 0] = y;  [0 | T2] [[y], [y]] = [T2 A21 T1 | 0]
+    t1s = [jnp.where(left, t, 0.0) for t in ts]
+    ys = [_solve_dot(jnp.where(left, a[h:], 0.0), stack(t1, t1)) for a, t1 in zip(mats, t1s)]
+    xs = [_solve_dot(jnp.where(left, 0.0, t), stack(y, y)) for t, y in zip(ts, ys)]
+    return [stack(t1, jnp.where(left, -x, t)) for t1, t, x in zip(t1s, ts, xs)]
+
+
+def _decays(gr, br):
+    """A chunk's masks and decays from its ``[1, C]`` rows of ``G`` and
+    beta. A ``[C, 1]`` column is the row masked to the diagonal and summed
+    along lanes (a transpose of the broadcast row measured slower)."""
+    c = gr.shape[-1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    eye = row == col
+    to_col = lambda r: jnp.sum(jnp.where(eye, r, 0.0), axis=1, keepdims=True)  # noqa: E731
+    gc = to_col(gr)
+    last = col[:1] == c - 1
+    gl = jnp.sum(jnp.where(last, gr, 0.0), axis=1, keepdims=True)  # G_last [1, 1]
+    return dict(
+        row=row, col=col, eye=eye, last=last, bc=to_col(br),
+        decay=jnp.exp(jnp.where(row >= col, gc - gr, -jnp.inf)),  # 0 above the diagonal
+        eg=jnp.exp(gc), rho=jnp.exp(gl - gc), egl=jnp.exp(gl),
+    )
+
+
+def _block_fwd(chunks, s):
+    """A block's chunks ``(q, k, v, G row, beta row)`` from the state ``s``
+    (fp32 ``[Dk, Dv]``): ``(S_out, [(o, T, u)])``, ``T`` and ``u`` in v's
+    dtype, the matmul operands the backward reads them as. Everything that
+    does not wait on the state is formed for the whole block first."""
+    cdt = chunks[0][2].dtype
+    xs = [_decays(gr, br) for _, _, _, gr, br in chunks]
+    ts = _unit_lower_inverses(
+        [jnp.where(x["row"] > x["col"], _dot(k, k, _NT) * x["decay"] * x["bc"], 0.0)
+         for (_, k, _, _, _), x in zip(chunks, xs)]
+    )
+    ready = []
+    for (q, k, v, _, br), x, t in zip(chunks, xs, ts):
+        qf, kf, eg = q.astype(_F32), k.astype(_F32), x["eg"]
+        tb = (t * br).astype(cdt)  # (I + A)^-1 diag(beta), rounded once from the fp32 T
+        kdec, kend, qdec = (y.astype(cdt) for y in (kf * eg, kf * x["rho"], qf * eg))
+        qk = (_dot(q, k, _NT) * x["decay"]).astype(cdt)  # causal, decayed in-chunk scores
+        ready.append((t.astype(cdt), _dot(tb, v), _dot(tb, kdec).astype(cdt), qk, qdec, kend, x["egl"]))
+    outs = []
+    for t, u0, w, qk, qdec, kend, egl in ready:
+        sc = s.astype(cdt)  # the state as a matmul operand; it accumulates in fp32
+        u = (u0 - _dot(w, sc)).astype(cdt)
+        outs.append((_dot(qdec, sc) + _dot(qk, u), t, u))
+        s = s * egl + _dot(kend, u, _TN)
+    return s, outs
+
+
+def _chunk_bwd(q, k, v, gr, br, do, t, u, s, ds):
+    """One chunk's gradients given the cotangents ``do`` of its output and
+    ``ds`` of its outgoing state: ``(dq, dk, dv, dbeta row, dG row,
+    dS_in)``. ``t``, ``u`` are the forward's, ``s`` its incoming state."""
+    cdt = v.dtype
+    x = _decays(gr, br)
+    rowsum = lambda y: jnp.sum(y, axis=1, keepdims=True)  # noqa: E731
+    colsum = lambda y: jnp.sum(y, axis=0, keepdims=True)  # noqa: E731
+    to_row = lambda y: colsum(jnp.where(x["eye"], y, 0.0))  # noqa: E731
+    bc, eg, rho, egl, decay = (x[n] for n in ("bc", "eg", "rho", "egl", "decay"))
+    strict = x["row"] > x["col"]
+    qf, kf = q.astype(_F32), k.astype(_F32)
+    kdec, qdec, kend = (y.astype(cdt) for y in (kf * eg, qf * eg, kf * rho))
+    qkf = _dot(q, k, _NT) * decay
+    sc, dsc = s.astype(cdt), ds.astype(cdt)
+    # The three products that carry dS from chunk to chunk (du's second,
+    # dr, dS_in's last) each wait on the one before; the MXU takes work in
+    # program order, so what does not wait on them is placed between them
+    # (1.4 ms of 18.6 a layer at the cell's shape, my chip run, PR 31).
+    # o = q_dec S + qk u;  S_out = e^{G_last} S + k_end^T u
+    du = _dot(qkf.astype(cdt), do, _TN) + _dot(kend, dsc)
+    kkd = _dot(k, k, _NT) * decay  # A = strict lower of beta_i * kkd
+    p = v.astype(_F32) - _dot(kdec, sc)  # u = T diag(beta) p
+    dqk = _dot(do, u, _NT)
+    dqdec = _dot(do, sc, _NT)
+    # u = T r, r = beta * p:  dr = T^T du;  dA = -T^T (du r^T) T^T = -dr u^T.
+    # The solve's VJP: du and dr enter whole (hi + lo), T and u as saved.
+    dr = sum(_dot(t, part, _TN) for part in _parts(du, cdt))
+    dkend = _dot(u, dsc, _NT)
+    dqkd = (dqk * decay).astype(cdt)
+    dq = eg * dqdec + _dot(dqkd, k)
+    dk = rho * dkend + _dot(dqkd, q, _TN)
+    dp = (bc * dr).astype(cdt)
+    ds_in = ds * egl + _dot(qdec, do, _TN) - _dot(kdec, dp, _TN)
+    dkdec = -_dot(dp, sc, _NT)
+    da = jnp.where(strict, -sum(_dot(part, u, _NT) for part in _parts(dr, cdt)), 0.0)
+    dbeta = rowsum(dr * p) + rowsum(da * kkd)
+    dkk = (da * decay * bc).astype(cdt)
+    dk = dk + eg * dkdec + _dot(dkk, k) + _dot(dkk, k, _TN)
+    # every decay is exp(G_i - G_j), exp(G_i) or exp(G_last - G_i)
+    e = dqk * qkf + da * bc * kkd
+    drho = rho * rowsum(dkend * kf)
+    dg_col = rowsum(e) + eg * (rowsum(dqdec * qf) + rowsum(dkdec * kf)) - drho
+    dgl = colsum(drho) + egl * colsum(rowsum(ds * s))
+    dg = to_row(dg_col) - colsum(e) + jnp.where(x["last"], dgl, 0.0)
+    return dq, dk, dp, to_row(dbeta), dg, ds_in
+
+
+def _chunks(*refs):
+    """``(token slice, each ref's part)`` for a block's chunks in order;
+    refs are ``[1, tokens, D]`` or, one row a chunk, ``[1, 1, chunks, C]``."""
+    for i in range(refs[0].shape[1] // CHUNK):
+        tok = slice(i * CHUNK, (i + 1) * CHUNK)
+        yield tok, [r[0, tok, :] if len(r.shape) == 3 else r[0, 0, i:i + 1, :] for r in refs]
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest):
+    s_scr = rest[-1]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        s_scr[:] = jnp.zeros_like(s_scr)
+
+    s = s_scr[:]
+    if len(rest) > 1:  # for the backward: the state entering this block
+        rest[0][0, 0] = s
+    toks, chunks = zip(*_chunks(q_ref, k_ref, v_ref, g_ref, b_ref))
+    s_scr[:], outs = _block_fwd(chunks, s)
+    for tok, (o, t, u) in zip(toks, outs):
+        o_ref[0, tok, :] = o.astype(o_ref.dtype)
+        if len(rest) > 1:  # ... and each chunk's T and u
+            rest[1][0, tok, :], rest[2][0, tok, :] = t, u
+
+
+def _bwd_kernel(
+    q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, t_ref, u_ref,
+    dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_scr, sin_scr,
+):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        ds_scr[:] = jnp.zeros_like(ds_scr)
+
+    s = s_ref[0, 0]
+    chunks = list(_chunks(q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, t_ref, u_ref))
+    for i, (_, (_, k, _, gr, br, _, _, u)) in enumerate(chunks):
+        sin_scr[i] = s  # each chunk's incoming state, replayed from the block's
+        x = _decays(gr, br)
+        kend = (k.astype(_F32) * x["rho"]).astype(u.dtype)
+        s = s * x["egl"] + _dot(kend, u, _TN)
+    ds = ds_scr[:]
+    for i, (tok, args) in reversed(list(enumerate(chunks))):
+        dq, dk, dv, db, dg, ds = _chunk_bwd(*args, sin_scr[i], ds)
+        dq_ref[0, tok, :] = dq.astype(dq_ref.dtype)
+        dk_ref[0, tok, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, tok, :] = dv.astype(dv_ref.dtype)
+        db_ref[0, 0, i:i + 1, :] = db
+        dg_ref[0, 0, i:i + 1, :] = dg
+    ds_scr[:] = ds
+
+
+def _specs(group, beta, dk, dv, reverse):
+    """Block specs of (q or k, v, a chunk's T, a per-token scalar, a saved
+    state) for ``beta [BH, blocks, chunks a block, C]``; with ``reverse``
+    the block axis is walked last to first."""
+    nblk, chunks = beta.shape[1:3]
+    blk = (lambda c: nblk - 1 - c) if reverse else (lambda c: c)
+    tokens = chunks * CHUNK
+    rows = lambda d, of=lambda b: b: pl.BlockSpec(  # noqa: E731
+        (1, tokens, d), lambda b, c: (of(b), blk(c), 0), memory_space=pltpu.VMEM
+    )
+    per_block = lambda *shape: pl.BlockSpec(  # noqa: E731
+        (1, 1) + shape, lambda b, c: (b, blk(c), 0, 0), memory_space=pltpu.VMEM
+    )
+    return (
+        rows(dk, lambda b: b // group), rows(dv), rows(CHUNK),
+        per_block(chunks, CHUNK), per_block(dk, dv),
+    )
+
+
+_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+
+
+def _forward(q, k, v, beta, gcum, save, interpret):
+    """``o``; with ``save`` also ``(states, T, u)`` for the backward."""
+    bh, tp, dv = v.shape
+    dk = q.shape[-1]
+    nblk = beta.shape[1]
+    qk_spec, v_spec, t_spec, tok_spec, s_spec = _specs(bh // q.shape[0], beta, dk, dv, False)
+    sds = lambda shape, dtype: _sds(shape, dtype, v)  # noqa: E731
+    outs = pl.pallas_call(
+        _fwd_kernel,
+        name="gated_delta_fwd",
+        grid=(bh, nblk),
+        in_specs=[qk_spec, qk_spec, v_spec, tok_spec, tok_spec],
+        out_specs=[v_spec] + [s_spec, t_spec, v_spec] * save,
+        out_shape=[sds(v.shape, v.dtype)] + [
+            sds((bh, nblk, dk, dv), _F32), sds((bh, tp, CHUNK), v.dtype),
+            sds(v.shape, v.dtype),
+        ] * save,
+        scratch_shapes=[pltpu.VMEM((dk, dv), _F32)],
+        compiler_params=_PARAMS,
+        interpret=interpret,
+    )(q, k, v, gcum, beta)
+    return (outs[0], outs[1:]) if save else outs[0]
+
+
+def _backward(q, k, v, beta, gcum, do, saved, interpret):
+    bh, tp, dv = v.shape
+    dk = q.shape[-1]
+    nblk = beta.shape[1]
+    qk_spec, v_spec, t_spec, tok_spec, s_spec = _specs(bh // q.shape[0], beta, dk, dv, True)
+    dqk_spec = _specs(1, beta, dk, dv, True)[0]  # dq, dk: one a value head
+    sds = lambda shape, dtype: _sds(shape, dtype, v)  # noqa: E731
+    return pl.pallas_call(
+        _bwd_kernel,
+        name="gated_delta_bwd",
+        grid=(bh, nblk),
+        in_specs=[qk_spec, qk_spec, v_spec, tok_spec, tok_spec, v_spec,
+                  s_spec, t_spec, v_spec],
+        out_specs=[dqk_spec, dqk_spec, v_spec, tok_spec, tok_spec],
+        out_shape=[
+            sds((bh, tp, dk), q.dtype), sds((bh, tp, dk), k.dtype),
+            sds(v.shape, v.dtype), sds(beta.shape, _F32), sds(beta.shape, _F32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((dk, dv), _F32), pltpu.VMEM((beta.shape[2], dk, dv), _F32),
+        ],
+        compiler_params=_PARAMS,
+        interpret=interpret,
+    )(q, k, v, gcum, beta, do, *saved)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rule(q, k, v, beta, gcum, interpret):
+    return _forward(q, k, v, beta, gcum, False, interpret)
+
+
+def _rule_fwd(q, k, v, beta, gcum, interpret):
+    out, saved = _forward(q, k, v, beta, gcum, True, interpret)
+    return out, (q, k, v, beta, gcum, saved)
+
+
+def _rule_bwd(interpret, res, do):
+    q, k, v, beta, gcum, saved = res
+    dq, dk, dv, dg, db = _backward(
+        q, k, v, beta, gcum, do.astype(v.dtype), saved, interpret
+    )
+    group = v.shape[0] // q.shape[0]
+    if group > 1:  # a key head's gradient: the sum over the value heads it serves
+        dq, dk = (
+            y.reshape(q.shape[0], group, *y.shape[1:]).astype(_F32).sum(1).astype(y.dtype)
+            for y in (dq, dk)
+        )
+    return dq, dk, dv, db, dg
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
+def gated_delta_rule_pallas(
+    q: Array, k: Array, v: Array, beta: Array, g: Array, *, interpret: bool = False
+) -> Array:
+    """The gated delta rule on q, k ``[..., Hk, T, Dk]``, v ``[..., Hv, T,
+    Dv]``, beta, g ``[..., Hv, T]`` with ``Hv`` a multiple of ``Hk`` (value
+    head ``h`` reads key head ``h // (Hv / Hk)``). Differentiable in all
+    five. Output ``[..., Hv, T, Dv]`` in v's dtype."""
+    lead, (hv, t, dv) = v.shape[:-3], v.shape[-3:]
+    hk, dk = q.shape[-3], q.shape[-1]
+    assert q.shape == k.shape and q.shape[:-3] == lead and hv % hk == 0, (q.shape, k.shape, v.shape)
+    assert beta.shape == g.shape == lead + (hv, t), (beta.shape, g.shape)
+    chunks = min(BLOCK_CHUNKS, -(-t // CHUNK))  # a short T is one smaller block
+    pad = (-t) % (chunks * CHUNK)
+    nblk = (t + pad) // (chunks * CHUNK)
+
+    def flat(x, *tail):  # [B * H, T (padded), ...]
+        x = x.reshape((-1, t) + tail)
+        return jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * len(tail)) if pad else x
+
+    per_chunk = lambda x: flat(x.astype(_F32)).reshape(-1, nblk, chunks, CHUNK)  # noqa: E731
+    out = _rule(
+        flat(q.astype(v.dtype), dk), flat(k.astype(v.dtype), dk), flat(v, dv),
+        per_chunk(beta), jnp.cumsum(per_chunk(g), axis=-1), interpret,
+    )
+    return out[:, :t].reshape(lead + (hv, t, dv))
+
+
+__all__ = ["BLOCK_CHUNKS", "CHUNK", "gated_delta_rule_pallas", "supports"]

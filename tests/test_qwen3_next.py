@@ -119,12 +119,13 @@ def test_chunked_delta_rule_gradients_equal_the_recurrences(t, g_scale):
         assert float(jnp.abs(got - want).max()) < 1e-4 * scale
 
 
-# batch, rows a block: one row at a time (the benchmark cell's case: 8 rows
-# of 32 heads x T 8192 against a bound of one), two at a time, and a batch
-# the bound does not divide (3 rows, bound 2: one at a time)
+# batch, rows a block: one row at a time (the XLA form at the benchmark
+# cell's shape: 8 rows of 32 heads x T 8192 against a bound of one; on the
+# chip that cell runs the kernels, tests/test_pallas_gated_delta.py), two at
+# a time, and a batch the bound does not divide (3 rows, bound 2: one at a time)
 @pytest.mark.parametrize("b,rows", [(2, 1), (4, 2), (3, 2)])
 def test_delta_rule_by_rows_equals_the_recurrence_forward_and_grad(b, rows, monkeypatch):
-    """The branch a training step at batch > 1 takes: ``lax.map`` over
+    """The branch the XLA form takes at batch > 1: ``lax.map`` over
     blocks of rows, each under ``jax.checkpoint`` that keeps only the
     triangular inverses, with the inverse's own backward. Reached by
     shrinking the bound on heads x tokens of a block."""
@@ -210,7 +211,10 @@ def test_gated_softmax_matches_reference(over):
     assert float(jnp.abs(got - want).max()) < TOL
 
 
-@pytest.mark.parametrize("backend,t", [("xla", 70), ("xla", 128), ("eager", 70)])
+@pytest.mark.parametrize("backend,t", [
+    ("xla", 70), ("xla", 128), ("eager", 70),
+    ("pallas_interpret", 70), ("pallas_interpret", 128),  # the Mosaic kernels' body
+])
 def test_gated_delta_mixer_matches_reference(backend, t):
     from orion_tpu.models.gated_mixers import GatedDeltaNet
 
