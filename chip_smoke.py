@@ -61,8 +61,9 @@ SLOTS, CHUNK, PREFILL_CHUNK = 8, 16, 64
 SERVE_FLAGS = ["--slots", str(SLOTS), "--chunk", str(CHUNK),
                "--prefill-chunk", str(PREFILL_CHUNK), "--temperature", "0",
                "--max-new-tokens", str(NEW_TOKENS)]
-# the operating point bench.py ships (bench._build): b12 x T2048, adafactor,
-# bf16 stochastic-rounding storage, 6 un-rematted blocks
+# lm_1b3.train's operating point (benchmark/workloads/lm_1b3.train.json):
+# b12 x T2048, adafactor, bf16 stochastic-rounding storage, 6 un-rematted
+# blocks
 TRAIN_SETS = ["--set", "optimizer=adafactor", "--set",
               "param_storage=bfloat16_sr", "--set", "model.remat_skip=6",
               # few steps: warm up in two, hold a gentle rate (at the

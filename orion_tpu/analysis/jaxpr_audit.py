@@ -104,13 +104,17 @@ def _where(eqn, target: str) -> Tuple[str, int]:
 
 
 def _scope_names(eqn) -> List[str]:
-    """'file.py' and 'file.py::function' labels for every user frame."""
+    """'file.py', 'dir/file.py' and 'file.py::function' labels for every
+    user frame."""
     out = []
     for fr in _user_frames(eqn):
         base = fr.file_name.rsplit("/", 1)[-1]
         fn = fr.function_name
         # scopes are declared by bare function name; frames carry qualnames
-        out.extend((base, f"{base}::{fn}", f"{base}::{fn.rsplit('.', 1)[-1]}"))
+        out.extend((
+            base, f"{base}::{fn}", f"{base}::{fn.rsplit('.', 1)[-1]}",
+            "/".join(fr.file_name.rsplit("/", 2)[-2:]),
+        ))
     return out
 
 

@@ -485,7 +485,7 @@ def decode_plan(
             if store is not None and store.has(ident, sample_fp):
                 # content-hash short-circuit: a COMMITTED executable is
                 # the proof this program lowers and compiles — repeated
-                # preflights (bench.py runs --verify before real work)
+                # preflights (a caller running --verify before real work)
                 # cost one listdir per program instead of a lowering
                 entry["warm"] = True
                 entry["lowered"] = True

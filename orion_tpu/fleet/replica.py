@@ -87,8 +87,8 @@ class ReplicaSpec:
     CPU). With N replicas on one box the default means N pools × ncpu
     threads fighting for ncpu cores — ONE replica silently eats the
     whole machine and replication measures as noise. One distinct core
-    per replica is the production deployment shape and what ``bench.py
-    --fleet`` uses so replicas=2 measures real process parallelism (see
+    per replica is the production deployment shape, and what makes
+    replicas=2 real process parallelism on one box (see
     :func:`pin_compute_pool`).
 
     ``tp``: the replica's device-mesh FOOTPRINT (ISSUE 14) — 0/1 serves

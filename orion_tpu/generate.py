@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from orion_tpu.models.configs import ModelConfig, get_config
+from orion_tpu.models.mixers import MIXERS
 from orion_tpu.models.transformer import TransformerLM, init_decode_state
 from orion_tpu.ops.dispatch import decode_live_rows
 
@@ -162,7 +163,7 @@ def _prefill_carry_bucketed_jit(
     instead of one per novel prompt length (the compile-cache leak real
     traffic would otherwise hit). The decode state and the first sampled
     token are bitwise-identical to the unpadded compile's (masking
-    contract: transformer.Attention.prefill)."""
+    contract: mixers.Mixer.prefill)."""
     logits, states = model.apply(params, tokens, length, method="prefill_last")
     nxt = sample_logits(
         logits, jax.random.fold_in(rng, sample_index), sample_cfg
@@ -447,14 +448,14 @@ def _where_rows(mask: Array, new: Any, old: Any) -> Any:
 def _freeze_rows(model, rows, mask: Array, new: Any, old: Any) -> Any:
     """The per-layer states with rows outside ``mask`` held at ``old``.
     Without a row list that is :func:`_where_rows` over every layer. With
-    one (``decode_live_rows`` under a Pallas backend) the linear layers'
-    kernel never touched those rows, and a select — which reads old and
-    new — would bring the full-width state traffic back: only softmax/swa
-    layers are selected."""
+    one (``decode_live_rows`` under a Pallas backend) a mixer with
+    ``rows_in_place`` (the linear layers' kernel) never touched those
+    rows, and a select — which reads old and new — would bring the
+    full-width state traffic back: only the other layers are selected."""
     if rows is None:
         return _where_rows(mask, new, old)
     return [
-        n if lt == "linear" else _where_rows(mask, n, o)
+        n if MIXERS[lt].rows_in_place else _where_rows(mask, n, o)
         for lt, n, o in zip(model.cfg.resolved_layer_types, new, old)
     ]
 

@@ -45,7 +45,7 @@ class ModelConfig:
     # carry their own block constants in parallel/ring.py). With the
     # banded swa grid (ops/pallas/flash_attention.py, r5) smaller
     # attn_block_k trims boundary-tile mask padding without growing the
-    # sweep; chip-swept in exp_r5swa.py
+    # sweep; chip-swept in round 5 (BASELINE.md)
     attn_block_q: int = 512
     attn_block_k: int = 512
     feature_map: str = "elu1"  # linear-attn phi
@@ -55,14 +55,15 @@ class ModelConfig:
     # no position term there (the gated layers carry position themselves:
     # rotary in gated_softmax, decay and the short conv in gated_delta)
     pos_embed: str = "learned"
-    # -- "gated_softmax" layers (models/gated_mixers.py): grouped KV heads,
-    # rotary on the first rotary_dims of each head (halves rotated, base
-    # rotary_base), per-head RMSNorm of q and k, a sigmoid output gate
+    # -- "gated_softmax" layers (models/mixers/gated_softmax.py): grouped KV
+    # heads, rotary on the first rotary_dims of each head (halves rotated,
+    # base rotary_base), per-head RMSNorm of q and k, a sigmoid output gate
     n_kv_heads: Optional[int] = None  # default n_heads
     rotary_dims: Optional[int] = None  # default the whole head
     rotary_base: float = 10000.0
-    # -- "gated_delta" layers (ops/gated_delta.py): key heads are repeated
-    # to the value heads; q, k and v pass a causal depthwise conv + SiLU
+    # -- "gated_delta" layers (models/mixers/gated_delta.py): key heads are
+    # repeated to the value heads; q, k and v pass a causal depthwise conv +
+    # SiLU
     gdn_key_heads: int = 0
     gdn_value_heads: int = 0
     gdn_key_dim: int = 0  # per head
@@ -75,7 +76,6 @@ class ModelConfig:
     backend: str = "auto"  # kernel dispatch for attention ops
     chunk: Optional[int] = None  # linear-attn chunk size (None = tuned default)
     remat: bool = False  # per-block activation checkpointing
-    remat_policy: str = "full"  # "full" | "dots" (save matmul outputs)
     # leave the last remat_skip blocks UN-rematted (identical math, they
     # keep their activations instead of recomputing the forward in the
     # backward pass). Each skipped flagship block trades ~1.6GB of saved
@@ -163,9 +163,8 @@ class ModelConfig:
         return lt
 
 
+# one mixer class each: models/mixers/__init__.py::MIXERS
 LAYER_TYPES = ("linear", "softmax", "swa", "gated_delta", "gated_softmax")
-# layer types with no decode state yet: serving them raises
-TRAIN_ONLY_LAYER_TYPES = ("gated_delta", "gated_softmax")
 
 
 def gated_pattern(n_layers: int, period: int = 4) -> Tuple[str, ...]:
@@ -180,7 +179,8 @@ def gated_pattern(n_layers: int, period: int = 4) -> Tuple[str, ...]:
 # policy — the declared exceptions the jaxpr contract auditor
 # (orion_tpu/analysis/jaxpr_audit.py::audit_matmul_bf16) checks the traced
 # train step against. Entries are 'file.py' or 'file.py::function', matched
-# against each dot_general's source frames. Everything here is the fp32
+# against each dot_general's source frames ('dir/file.py' where another
+# directory holds a file of the same name). Everything here is the fp32
 # (S, z) kv-state accumulation contract: linear attention keeps its running
 # state in fp32 regardless of the activation dtype (the chunked scan, the
 # pallas state carries, the sp exclusive-prefix exchange, and the FAVOR+
@@ -189,10 +189,11 @@ F32_MATMUL_SCOPES = (
     "linear_attention.py",          # chunked-scan fp32 state accumulation
     "causal_dot.py",                # pallas state init/carry helpers
     "sequence.py",                  # sp exclusive-prefix fp32 state math
-    "transformer.py::_phi_map",     # FAVOR+ fp32 random-feature projection
-    # delta-rule fp32 state + triangular inverse: ops/gated_delta.py and its
-    # kernels' wrapper ops/pallas/gated_delta.py (frames match by file name)
-    "gated_delta.py",
+    "linear.py::_phi_map",          # FAVOR+ fp32 random-feature projection
+    # delta-rule fp32 state + triangular inverse, and its kernels' wrapper
+    # (not the mixer of the same file name, models/mixers/gated_delta.py)
+    "ops/gated_delta.py",
+    "pallas/gated_delta.py",
 )
 
 
@@ -281,8 +282,8 @@ MOE_1B3_8E = ModelConfig(
 
 MOE_1B3_4E = dataclasses.replace(
     # chip-scale sparse config (1.893B total, same 1.284B active/token):
-    # every 4th MLP routed over 4 experts — what bench.py --moe measures
-    # on the single 16GB chip
+    # every 4th MLP routed over 4 experts — sized for the single 16GB chip
+    # (rounds 1-5 measured it, BASELINE.md; no cell of the benchmark does)
     MOE_1B3_8E, name="moe_1b3_4e", n_experts=4, moe_period=4,
 )
 
@@ -390,5 +391,4 @@ def get_config(name: str, **overrides) -> ModelConfig:
 __all__ = [
     "ModelConfig", "CONFIGS", "get_config", "hybrid_pattern",
     "gated_pattern", "F32_MATMUL_SCOPES", "LAYER_TYPES",
-    "TRAIN_ONLY_LAYER_TYPES",
 ]

@@ -235,7 +235,7 @@ class MoEMLP(nn.Module):
         # -- expert FFNs (stacked [E, ...], ep-sharded) ----------------------
         # quant mode: int8 stacks + per-(expert, out-channel) scales applied
         # post-einsum (exact for per-out-channel; orion_tpu/quant.py)
-        if self.quant:  # expert stacks stay int8 in BOTH quant modes (transformer._qdense_factory)
+        if self.quant:  # expert stacks stay int8 in BOTH quant modes (mixers._dense_factory)
             zi, so = nn.initializers.zeros_init(), nn.initializers.ones_init()
 
             def qparam(name, shape, out):
@@ -428,7 +428,7 @@ class MoEMLP(nn.Module):
         xs = jnp.take(x2.astype(dt), order // k, axis=0)  # [N*k, d]
         sorted_ids = jnp.take(flat, order, axis=0)  # for quant scale rows
 
-        if self.quant:  # expert stacks stay int8 in BOTH quant modes (transformer._qdense_factory)
+        if self.quant:  # expert stacks stay int8 in BOTH quant modes (mixers._dense_factory)
             zi, so = nn.initializers.zeros_init(), nn.initializers.ones_init()
 
             def qrd(name, shape, out, lhs):

@@ -43,8 +43,7 @@ replica whose fast burn persists.
 Tooling: ``python -m orion_tpu.obs.slo check --objectives obj.json
 metrics.prom.json`` evaluates a dumped registry snapshot
 (:meth:`MetricsRegistry.dump`'s ``.json`` sibling) against declared
-objectives and exits nonzero on violation — the CI gate for
-BENCH_SERVE-producing runs.
+objectives and exits nonzero on violation — a CI gate for serving runs.
 
 Metric-name conventions (what the readers look for): latency objectives
 read the ``turn_latency_ms`` (``source="turn"``) or ``chunk_ms``

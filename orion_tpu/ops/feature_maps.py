@@ -150,8 +150,8 @@ def register_feature_map(name: str, fn=None):
     """
 
     def install(f):
-        # "favor" and "learnable" are special-cased inside the Attention
-        # module (random features / learned projection) — registering them
+        # "favor" and "learnable" are special-cased inside LinearAttention
+        # (random features / learned projection) — registering them
         # here would be silently shadowed there, so reserve the names too
         if name in _BUILTIN or name in ("favor", "learnable"):
             raise ValueError(f"feature map {name!r} is built-in; pick a new name")

@@ -80,11 +80,6 @@ def _rowscol(qi, ki, bq, bk):
     return rows, cols
 
 
-# benchmark switch (exp_r5swa.py): False restores the full quadratic grid
-# so the clip-vs-mask delta is measurable on the SAME build
-_BANDED_ENABLED = True
-
-
 def _banded_ok(causal, window, shift, q_offset, t_q, t_k) -> bool:
     """Use the BANDED grid (VERDICT r4 #6 — clip, don't mask): the k sweep
     per q-tile covers only tiles intersecting the (window, causal) band
@@ -96,8 +91,7 @@ def _banded_ok(causal, window, shift, q_offset, t_q, t_k) -> bool:
     (shift/q_offset) keep the classic grid, whose skip predicate already
     serves their offset geometry."""
     return (
-        _BANDED_ENABLED
-        and causal and window is not None and shift == 0 and q_offset == 0
+        causal and window is not None and shift == 0 and q_offset == 0
         and t_q == t_k and window < t_k
     )
 

@@ -55,7 +55,7 @@ def _vma_union_like(a: Array, b: Array) -> Array:
 
 
 # dw-kernel output tile (see _gmm_bwd): tuned by an earlier round's sweep
-# (exp_r5gmm.py); not measured on the current installation
+# (BASELINE.md); not measured on the current installation
 _DW_BLOCK_D = 1024
 _DW_BLOCK_H = 1024
 

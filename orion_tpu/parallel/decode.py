@@ -123,7 +123,7 @@ def mesh_backend(backend: str, tp: int) -> str:
     ("Mosaic kernels cannot be automatically partitioned"; seen compiling
     the ``lm_1b3`` in-scan prefill for a v5e:2x2). So on tp > 1 the
     prefill's attention is pinned to the XLA forms, the rule the pp
-    pipeline already follows (models/transformer.py), and so is the
+    pipeline already follows (models/mixers/), and so is the
     decode step: the slot-multiplexed programs' row-sparse (S, z) kernel
     (ops/pallas/decode_state.py) is not reached under a mesh, every slot's
     state steps through ``recurrent_step`` and the select. Manualizing

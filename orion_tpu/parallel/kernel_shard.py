@@ -8,7 +8,7 @@ cannot be automatically partitioned. Please wrap the call in a shard_map"
 covers the plain GSPMD meshes; sp-without-pp is covered by the fully-
 manual shard_maps in parallel/sequence.py and parallel/ring.py
 (SP_PALLAS_AOT.json), and the pp pipeline is partial-manual by design so
-its body pins attention to the XLA forms (models/transformer.py);
+its body pins attention to the XLA forms (models/mixers/);
 reference checkout never mounted — SURVEY.md §0).
 
 Causal attention is embarrassingly parallel over batch and heads, so the

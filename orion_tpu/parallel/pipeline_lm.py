@@ -202,15 +202,11 @@ def pp_lm_logits(
         return (h, aux) if return_aux else h
 
     if cfg.remat:
-        from orion_tpu.models.transformer import REMAT_POLICIES
-
         # NB remat granularity here is per GROUP of g blocks (the pipeline's
         # unit of work), not per block like the non-pp model — for g>1 the
         # backward recomputes g blocks as one unit, so peak recompute memory
         # is ~g blocks of activations
-        layer_fn = jax.checkpoint(
-            layer_fn, policy=REMAT_POLICIES[cfg.remat_policy]
-        )
+        layer_fn = jax.checkpoint(layer_fn)
 
     from jax.sharding import PartitionSpec as P
 

@@ -90,6 +90,7 @@ from orion_tpu.models.transformer import (
     extract_decode_slot,
     init_decode_state,
     insert_decode_slot,
+    linear_layer_indices,
     snapshot_decode_state,
 )
 from orion_tpu.resilience import inject
@@ -399,9 +400,7 @@ class SlotEngine:
             cfg_ = model.cfg
             if self.spec_depth < 1:
                 raise ValueError(f"spec_depth must be >= 0: {spec_depth}")
-            if not any(
-                lt == "linear" for lt in cfg_.resolved_layer_types
-            ):
+            if not linear_layer_indices(cfg_):
                 raise ValueError(
                     "self-speculative decode drafts with the model's "
                     "global-linear layers; this config has none "

@@ -50,7 +50,7 @@ class TrainConfig:
     batch_size: int = 8  # global
     seq_len: int = 256
     # optimizer
-    optimizer: str = "adamw"  # "adamw" | "lion"
+    optimizer: str = "adamw"  # "adamw" | "lion" | "adafactor"
     mu_dtype: Optional[str] = None  # e.g. "bfloat16": halve first-moment HBM
     lr: float = 3e-4
     b1: float = 0.9
@@ -235,18 +235,11 @@ def make_optimizer(
             sched, b1=cfg.b1, b2=cfg.b2,
             weight_decay=cfg.weight_decay, mask=_wd_mask, mu_dtype=mu_dtype,
         )
-    elif cfg.optimizer in ("adafactor", "adafactor_fused"):
+    elif cfg.optimizer == "adafactor":
         # factored second moment (O(n+m) state per matrix): the single-chip
         # memory-headroom option for 1.3B+ (SURVEY §7 "bigger-batch").
         # No decoupled weight decay — standard adafactor usage; its
         # update-clipping plays the stabilizing role.
-        # "adafactor_fused" runs the Pallas fused update inside Trainer
-        # (ops/pallas/adafactor.py) and never touches this chain; this
-        # optax twin serves the OTHER make_optimizer callers (train_lra's
-        # shim). A multi-device Trainer mesh does NOT fall back — it
-        # rejects the fused option loudly (see __init__), because a silent
-        # downgrade would change the opt_state checkpoint pytree with mesh
-        # size.
         opt = optax.adafactor(
             sched, min_dim_size_to_factor=128,
             multiply_by_parameter_scale=False,
@@ -456,45 +449,13 @@ class Trainer:
                 f"pp_microbatches={self.pp_n_micro} must divide the "
                 f"{'per-shard ' if fm else ''}per-accumulation batch {base}"
             )
-        # Pallas fused adafactor (ops/pallas/adafactor.py): single-device
-        # meshes only — GSPMD cannot auto-partition a Mosaic custom call
-        # (parallel/kernel_shard.py), and the factored stats would need
-        # psums. Multi-device meshes are REJECTED below, not silently
-        # downgraded: the opt_state pytree must not depend on mesh size.
         if cfg.param_storage not in ("float32", "bfloat16_sr"):
             raise ValueError(
                 f"param_storage={cfg.param_storage!r}; expected 'float32' "
                 "or 'bfloat16_sr'"
             )
         self._sr = cfg.param_storage == "bfloat16_sr"
-        self._fused_opt = cfg.optimizer == "adafactor_fused"
-        if self._sr and self._fused_opt:
-            raise ValueError(
-                "param_storage='bfloat16_sr' composes with the optax "
-                "optimizers only; the fused adafactor kernel reads/writes "
-                "fp32 params (use optimizer='adafactor')"
-            )
-        if self._fused_opt and (self.mesh.devices.size > 1 or self.pp > 1):
-            # a silent optax fallback would make the opt_state checkpoint
-            # pytree depend on mesh size (FusedAdafactorState vs the optax
-            # chain tuple), breaking restore across mesh changes — the one
-            # thing the cross-mesh restore tests guarantee. Fail loudly;
-            # multi-chip runs use optimizer="adafactor".
-            raise ValueError(
-                "optimizer='adafactor_fused' runs on single-device meshes "
-                "only (Mosaic custom calls cannot be auto-partitioned by "
-                "GSPMD); use optimizer='adafactor' on multi-device meshes"
-            )
-        if self._fused_opt:
-            from orion_tpu.ops.pallas import adafactor as _fused_af
-
-            self._fused_af = _fused_af
-            self.tx = optax.GradientTransformation(
-                init=_fused_af.init,
-                update=None,  # the fused path never calls tx.update
-            )
-        else:
-            self.tx = make_optimizer(cfg, include_clip=False)
+        self.tx = make_optimizer(cfg, include_clip=False)
         self.sched = make_schedule(cfg)
         self.batch_shd = batch_sharding(self.mesh)
 
@@ -634,36 +595,24 @@ class Trainer:
         # where (not *): a NaN gnorm must select 0, not propagate
         scale = jnp.where(finite, clip, 0.0)
         bad = (~finite).astype(jnp.int32)
-        if self._fused_opt:
-            # the fused kernels fold the scale, the lr, the update clip,
-            # AND the skip-policy select (ops/pallas/adafactor.py)
-            # lr indexed by the GOOD-step count (state.opt_state.count),
-            # matching the optax twin whose schedule count is rolled back
-            # with the rest of the state on non-finite steps
-            new_params, new_opt = self._fused_af.apply_updates(
-                grads, state.params, state.opt_state,
-                lr=self.sched(state.opt_state.count), scale=scale,
-                finite=finite,
-            )
+        # astype is a no-op for the fp32 path; in SR mode it upcasts
+        # the bf16 grads inside the same elementwise pass as the scale
+        safe_grads = jax.tree.map(
+            lambda g: g.astype(jnp.float32) * scale, grads
+        )
+        updates, new_opt = self.tx.update(
+            safe_grads, state.opt_state, state.params
+        )
+        if self._sr:
+            new_params = self._sr_apply(state.params, updates, step_rng)
         else:
-            # astype is a no-op for the fp32 path; in SR mode it upcasts
-            # the bf16 grads inside the same elementwise pass as the scale
-            safe_grads = jax.tree.map(
-                lambda g: g.astype(jnp.float32) * scale, grads
-            )
-            updates, new_opt = self.tx.update(
-                safe_grads, state.opt_state, state.params
-            )
-            if self._sr:
-                new_params = self._sr_apply(state.params, updates, step_rng)
-            else:
-                new_params = optax.apply_updates(state.params, updates)
-            # skip-policy: on a non-finite step keep the old params & state
-            sel = lambda new, old: jax.tree.map(  # noqa: E731
-                lambda n, o: jnp.where(finite, n, o), new, old
-            )
-            new_params = sel(new_params, state.params)
-            new_opt = sel(new_opt, state.opt_state)
+            new_params = optax.apply_updates(state.params, updates)
+        # skip-policy: on a non-finite step keep the old params & state
+        sel = lambda new, old: jax.tree.map(  # noqa: E731
+            lambda n, o: jnp.where(finite, n, o), new, old
+        )
+        new_params = sel(new_params, state.params)
+        new_opt = sel(new_opt, state.opt_state)
         new_state = TrainState(
             step=state.step + 1,
             params=new_params,
@@ -674,7 +623,7 @@ class Trainer:
         metrics = {
             "loss": loss,
             "grad_norm": gnorm,
-            # the lr actually applied this step: both optimizer paths index
+            # the lr actually applied this step: the optimizer indexes
             # the schedule by the GOOD-step count (non-finite steps roll the
             # opt state — and with it the inner schedule count — back), and
             # that count is exactly step - nonfinite, so sched(state.step)

@@ -1,4 +1,4 @@
-"""Persistent XLA compilation cache (shared by the CLIs and bench.py).
+"""Persistent XLA compilation cache (shared by the CLIs and the benchmark).
 
 The 1.3B train step takes over a minute to compile; caching it on disk
 makes every later invocation start in seconds. The directory is part of

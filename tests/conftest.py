@@ -128,7 +128,6 @@ _SLOW = {
     "test_pipeline.py::test_pipeline_forward_parity[2-4]",
     "test_pipeline.py::test_pipeline_forward_parity[4-4]",
     "test_bpe.py::test_prepare_data_bpe_and_train",
-    "test_models.py::test_remat_policy_dots_matches",
     "test_models.py::test_classifier_padding_invariance",
     "test_models.py::test_parallel_vs_prefill_decode_parity[elu1]",
     "test_pipeline.py::test_trainer_pp_sp_composition_parity[xla]",
@@ -151,7 +150,6 @@ _SLOW = {
     "test_storage_domains.py::test_store_outage_zero_failures_bitwise[sampled]",
     # all measured >=10s on this box
     "test_training.py::test_eval_factory_batches_deterministic_per_step",
-    "test_fused_adafactor.py::test_trainer_fused_matches_optax_adafactor",
     "test_training.py::test_fused_clip_matches_optax_chain",
     "test_moe.py::TestMoEMLP::test_dropless_decode_matches_parallel_argmax",
     "test_quant.py::test_int4_decode_quality_bar",

@@ -99,15 +99,6 @@ def _flash(window):
     return fn
 
 
-def _adafactor(g, p):
-    from orion_tpu.ops.pallas import adafactor as af
-
-    return af.apply_updates(
-        {"w": g}, {"w": p}, af.init({"w": p}), lr=1e-3, scale=1.0,
-        finite=True, backend="pallas",
-    )
-
-
 def _q4(x, p, s):
     from orion_tpu.quant import q4_matmul
 
@@ -148,7 +139,6 @@ _DELTA = [*[((2, 16, 8192, 128), jnp.bfloat16)] * 2,
 # token's bf16 q, k, v a slot, and the chunk's row mask
 _STATE = [((64, 16, 128, 128), jnp.float32), ((64, 16, 128), jnp.float32),
           *[((64, 16, 128), jnp.bfloat16)] * 3, ((64,), jnp.bool_)]
-_MLP = (2048, 5504)  # lm_1b3's largest factored leaf besides the embedding
 # moe_1b3_4e dropless: 24576 padded rows through 4 experts of 2048 x 5504
 _GMM = [((24576, 2048), jnp.bfloat16), ((4, 2048, 5504), jnp.bfloat16),
         ((4,), jnp.int32)]
@@ -160,8 +150,6 @@ KERNELS = [
     pytest.param(_grad3(_fused), _QKV, id="causal_dot-fused-bwd"),
     pytest.param(_flash(1024), _QKV, id="flash-w1024-fwd"),
     pytest.param(_grad3(_flash(1024)), _QKV, id="flash-w1024-bwd"),
-    pytest.param(_adafactor, [(_MLP, jnp.float32)] * 2,
-                 id="adafactor-fused"),
     pytest.param(
         _q4,
         [((8, 2048), jnp.bfloat16), ((1024, 5504), jnp.int8),

@@ -161,22 +161,3 @@ def test_remat_skip_matches():
         lambda a, b: np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5),
         jax.grad(loss(m))(params), jax.grad(loss(ms))(params),
     )
-
-
-def test_remat_policy_dots_matches():
-    cfg = dataclasses.replace(MIXED, remat=True, remat_policy="dots")
-    toks = jax.random.randint(jax.random.PRNGKey(12), (1, 10), 0, cfg.vocab_size)
-    m = TransformerLM(dataclasses.replace(MIXED, remat=False))
-    mr = TransformerLM(cfg)
-    params = m.init(jax.random.PRNGKey(13), toks)
-    np.testing.assert_allclose(
-        m.apply(params, toks), mr.apply(params, toks), atol=1e-6, rtol=1e-6
-    )
-    # grads flow identically
-    def loss(mod):
-        return lambda p: jnp.sum(mod.apply(p, toks) ** 2)
-    ga = jax.grad(loss(m))(params)
-    gb = jax.grad(loss(mr))(params)
-    jax.tree.map(
-        lambda a, b: np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5), ga, gb
-    )

@@ -782,27 +782,46 @@ class TestMoEDecode:
             seq = jnp.concatenate([seq, want[:, None]], axis=1)
 
 
-def test_bench_active_param_accounting():
-    """bench.py's MFU denominator: expert stacks count only their routed
-    share (top_k/E); dense models are unchanged."""
-    import bench as bench_mod
-    from orion_tpu.training.trainer import TrainConfig, Trainer
+def test_bench_active_param_accounting(monkeypatch):
+    """The denominator of the ledger's ``mfu_6n``
+    (``benchmark/kinds/train.py::active_params``, loaded by file path as
+    ``kinds/train_ref.py`` loads it): expert stacks count only their routed
+    share (top_k/E); a dense model counts every parameter."""
+    import importlib.util
+    import os
 
-    cfg = TrainConfig(
-        model=_moe_model(n_layers=2), steps=1, batch_size=2, seq_len=8,
-        mesh=MeshConfig(dp=1),
+    from orion_tpu.models.configs import get_config
+    from orion_tpu.models.transformer import TransformerLM
+
+    bench_dir = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark"
     )
-    tr = Trainer(cfg)
-    total = bench_mod._n_params(tr)
-    active = bench_mod._n_active_params(tr)
-    expert = sum(
-        x.size
-        for p, x in jax.tree_util.tree_leaves_with_path(tr.state.params)
-        if "experts_" in jax.tree_util.keystr(p)
+    monkeypatch.syspath_prepend(bench_dir)  # kinds/train.py imports harness
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_kinds_train_for_test",
+        os.path.join(bench_dir, "kinds", "train.py"),
     )
-    k, e = cfg.model.moe_top_k, cfg.model.n_experts
-    assert active == total - expert + expert * k / e
+    kind = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(kind)
+
+    def count(cfg):
+        params = jax.eval_shape(
+            TransformerLM(cfg).init, jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
+        )
+        total = sum(x.size for x in jax.tree.leaves(params))
+        expert = sum(
+            x.size for p, x in jax.tree_util.tree_leaves_with_path(params)
+            if "experts_" in jax.tree_util.keystr(p)
+        )
+        return kind.active_params(cfg, params), total, expert
+
+    cfg = _moe_model(n_layers=2)
+    active, total, expert = count(cfg)
+    k, e = cfg.moe_top_k, cfg.n_experts
+    assert expert > 0 and active == total - expert + expert * k / e
     assert 0 < active < total
+    active, total, expert = count(get_config("tiny"))
+    assert expert == 0 and active == total
 
 
 @pytest.mark.parametrize(

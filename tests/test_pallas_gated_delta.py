@@ -154,7 +154,7 @@ def test_mixer_on_a_mesh_runs_the_kernel_on_each_shard():
     import dataclasses
 
     from orion_tpu.models.configs import get_config
-    from orion_tpu.models.gated_mixers import GatedDeltaNet
+    from orion_tpu.models.mixers import GatedDeltaNet
     from orion_tpu.parallel.mesh import MeshConfig, make_mesh
 
     def cfg(backend):

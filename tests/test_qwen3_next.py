@@ -196,7 +196,7 @@ def _layer(cfg, index):
     dict(n_kv_heads=4, rotary_dims=16),           # no grouping
 ], ids=["gqa2-rot8", "mqa-rot32", "mha-rot16"])
 def test_gated_softmax_matches_reference(over):
-    from orion_tpu.models.gated_mixers import GatedSoftmaxAttention
+    from orion_tpu.models.mixers import GatedSoftmaxAttention
 
     cfg = tiny(**over)
     _, _, blk = _layer(cfg, 3)
@@ -216,7 +216,7 @@ def test_gated_softmax_matches_reference(over):
     ("pallas_interpret", 70), ("pallas_interpret", 128),  # the Mosaic kernels' body
 ])
 def test_gated_delta_mixer_matches_reference(backend, t):
-    from orion_tpu.models.gated_mixers import GatedDeltaNet
+    from orion_tpu.models.mixers import GatedDeltaNet
 
     cfg = tiny(backend=backend)
     _, _, blk = _layer(cfg, 0)

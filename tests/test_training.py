@@ -565,9 +565,9 @@ def test_bf16_sr_nan_guard_skips_update():
     )
 
 
-def test_bf16_sr_rejects_fused_optimizer():
-    with pytest.raises(ValueError, match="bfloat16_sr"):
-        Trainer(small_cfg(optimizer="adafactor_fused",
+def test_unknown_optimizer_and_param_storage_raise():
+    with pytest.raises(ValueError, match="unknown optimizer"):
+        Trainer(small_cfg(optimizer="no_such_optimizer",
                           param_storage="bfloat16_sr"))
     with pytest.raises(ValueError, match="param_storage"):
         Trainer(small_cfg(param_storage="float16"))
