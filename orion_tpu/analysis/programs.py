@@ -159,6 +159,15 @@ PROGRAMS: Tuple[ProgramDecl, ...] = (
         note="bare jax.jit over the whole-tree quantization: runs once "
              "per (model, params) at engine construction",
     ),
+    ProgramDecl(
+        "prefill_extend_row", GENERATE, "_prefill_extend_row", "setup",
+        static_args=("model", "pchunk"),
+        note="never an executable of its own: called only while "
+             "unified_prefill is traced, which holds the piece twice "
+             "(inline and in its loop) — the inner jit makes that one "
+             "trace and one lowering of the model's forward, and XLA "
+             "inlines both calls",
+    ),
     ProgramDecl("slot_flags", BATCHING, "_slot_flags", "setup",
                 note="per-chunk host readback probe; no static args"),
     ProgramDecl("spec_flags", BATCHING, "_spec_flags", "setup",

@@ -260,10 +260,10 @@ def _snap_decode_batched_prefill_tiny() -> Tuple[Any, Any, Dict[str, Any]]:
     pbuf = jax.ShapeDtypeStruct((slots, bucket), jnp.int32)
     args = (
         model, params, carry, rngs, active, pbuf, vec(jnp.int32),
-        vec(jnp.int32), chunk, pchunk, SampleConfig(),
+        vec(jnp.int32), vec(jnp.int32), chunk, pchunk, SampleConfig(),
     )
     jaxpr = jax.make_jaxpr(
-        _decode_batched_prefill_chunk_jit, static_argnums=(0, 8, 9, 10)
+        _decode_batched_prefill_chunk_jit, static_argnums=(0, 9, 10, 11)
     )(*args)
     lowered = _decode_batched_prefill_chunk_jit.lower(*args)
     meta = {

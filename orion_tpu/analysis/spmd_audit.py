@@ -388,10 +388,10 @@ def trace_decode_batched_prefill_tp():
         (8, 16), jnp.int32, sharding=NamedSharding(shardings[2], P())
     )
     return jax.make_jaxpr(
-        _decode_batched_prefill_chunk_jit, static_argnums=(0, 8, 9, 10)
+        _decode_batched_prefill_chunk_jit, static_argnums=(0, 9, 10, 11)
     )(
         model, params, carry, rngs, vec(jnp.bool_), pbuf, vec(jnp.int32),
-        vec(jnp.int32), 8, 16, SampleConfig(),
+        vec(jnp.int32), vec(jnp.int32), 8, 16, SampleConfig(),
     )
 
 

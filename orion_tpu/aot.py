@@ -312,7 +312,7 @@ def decode_cost_entries(
             dict(base, bucket=int(bucket), prefill_chunk=pchunk),
             lambda: _decode_batched_prefill_chunk_jit.lower(
                 model, params, carry, rngs, active, pbuf,
-                vec(jnp.int32), vec(jnp.int32), int(chunk),
+                vec(jnp.int32), vec(jnp.int32), vec(jnp.int32), int(chunk),
                 min(pchunk, int(bucket)), sample,
             ),
         )
@@ -419,6 +419,7 @@ def decode_plan(
                         env["rngs"], env["active"],
                         env["shaped"]((slots, int(bucket)), env["i32"]),
                         env["vec"](env["i32"]), env["vec"](env["i32"]),
+                        env["vec"](env["i32"]),
                         int(chunk), pchunk, env["sample"],
                     )
                 ),

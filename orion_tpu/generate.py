@@ -418,20 +418,19 @@ def decode_batched_chunk(
 # (head-of-line blocking; Orca/Sarathi-Serve territory). Because prefill
 # and decode share the same recurrent carry, a prefilling request can
 # instead OCCUPY a slot and consume its prompt inside the batched
-# program: each unified chunk first spends a ``prefill_chunk``-token
-# prompt budget on ONE selected slot as a parallel-forward PIECE
+# program: each unified chunk first runs one ``prefill_chunk``-token
+# parallel-forward PIECE for each waiting slot, up to a cap a boundary
 # (transformer.prefill_extend_step — chunk-aligned pieces replay the
 # monolithic prefill's exact op sequence, so the carry is BITWISE what
 # host-side prefill_carry builds), then runs the decode scan with the
-# still-prefilling rows frozen (state/position/emit held, PAD emitted).
-# The budget is TOTAL, not per-slot (Sarathi's token-budget semantics):
-# a boundary's piece is one batch-1 forward however many slots are
-# mid-prefill, so the boundary tax co-resident decoders pay stays flat
-# in the slot count. Token-by-token prompt feeding inside the scan body
-# can NOT deliver the bitwise contract — a single-row matvec accumulates
-# differently from the prefill gemm — which is why the prompt is
-# consumed as parallel pieces at the top of the chunk rather than as
-# masked scan steps.
+# rows still mid-prompt frozen (state/position/emit held, PAD emitted).
+# ``prefill_chunk`` is the width of ONE slot's piece; each piece is a
+# batch-1 forward, so a boundary pays for the slots it serves and for no
+# other, and its co-resident decoders wait for at most ``cap`` pieces.
+# Token-by-token prompt feeding inside the scan body can NOT deliver the
+# bitwise contract — a single-row matvec accumulates differently from
+# the prefill gemm — which is why the prompt is consumed as parallel
+# pieces at the top of the chunk rather than as masked scan steps.
 
 
 def _where_rows(mask: Array, new: Any, old: Any) -> Any:
@@ -460,6 +459,7 @@ def _freeze_rows(model, rows, mask: Array, new: Any, old: Any) -> Any:
     ]
 
 
+@partial(jax.jit, static_argnums=(0, 7))
 def _prefill_extend_row(
     model: TransformerLM,
     params: Any,
@@ -478,8 +478,10 @@ def _prefill_extend_row(
     point: the piece costs one slot's forward, not slots x one — a
     vmapped all-rows piece was measured 2-4x a pure-decode boundary on
     the tiny config, which is exactly the co-resident latency tax this
-    path exists to kill. Returns (last-real-row logits [V], the advanced
-    state row)."""
+    path exists to kill. Jitted so that the unified program, which holds
+    the piece twice (inline and in its loop), traces and lowers the
+    model's forward once. Returns (last-real-row logits [V], the
+    advanced state row)."""
     idx = jnp.clip(offset + jnp.arange(pchunk), 0, pbuf.shape[1] - 1)
     piece = jnp.take(pbuf[sel], idx)[None]
     st1 = jax.tree.map(lambda x: x[sel][None], states)
@@ -523,7 +525,39 @@ def _decode_batched_prefill_body(
     return (token, states, t, emit, done), emitted
 
 
-@partial(jax.jit, static_argnums=(0, 8, 9, 10))
+def prefill_piece_cap(slots: int, chunk: int) -> int:
+    """How many prompt pieces one boundary of the unified program may run:
+    ``slots // chunk``, at least 1. A full server finishes about
+    ``slots / (mean output / chunk)`` requests a boundary, so a cap of
+    that order keeps up with steady state while it bounds what a burst
+    of admissions adds to the boundary its co-resident decoders share."""
+    return max(1, slots // chunk)
+
+
+def prefill_overdue_after(slots: int, chunk: int) -> int:
+    """Boundaries a waiting slot may be passed over before it is OVERDUE
+    and goes ahead of every slot that is not:
+    ``ceil(slots / prefill_piece_cap)``, the boundaries a full house of
+    waiting slots needs at the cap."""
+    return -(-slots // prefill_piece_cap(slots, chunk))
+
+
+def _prefill_selection(active: Array, rem: Array, pwait: Array, chunk: int):
+    """Stage 1's schedule: (``order`` [S], the slot indices in serving
+    order, waiting slots first; ``n``, how many of them this boundary
+    serves). See :func:`_decode_batched_prefill_chunk_jit`."""
+    slots = active.shape[0]
+    waiting = active & (rem > 0)
+    overdue = jnp.where(
+        pwait >= prefill_overdue_after(slots, chunk), pwait, 0
+    )
+    order = jnp.lexsort(  # the last key is the first compared
+        (jnp.arange(slots), rem, -overdue, (~waiting).astype(jnp.int32))
+    )
+    return order, jnp.minimum(waiting.sum(), prefill_piece_cap(slots, chunk))
+
+
+@partial(jax.jit, static_argnums=(0, 9, 10, 11))
 def _decode_batched_prefill_chunk_jit(
     model: TransformerLM,
     params: Any,
@@ -533,51 +567,82 @@ def _decode_batched_prefill_chunk_jit(
     pbuf: Array,
     plen: Array,
     pfold: Array,
+    pwait: Array,
     n_steps: int,
     pchunk: int,
     sample_cfg: SampleConfig,
 ) -> Tuple[Any, Array]:
-    """One UNIFIED chunk: the prompt-budget piece, then the decode scan.
+    """One UNIFIED chunk: a prompt piece for each waiting slot, then the
+    decode scan.
 
-    Stage 1 — the boundary's ``pchunk``-token prompt budget goes to ONE
-    slot with prompt left (``t < plen``): shortest remaining first, ties
-    to the lowest index — the slot closest to emitting frees its output
-    stream soonest, and the rule is deterministic from carry-resident
-    inputs so the host scheduler mirrors it without any readback
-    (``SlotEngine._selected_prefill_slot``). The piece is a batch-1
-    parallel forward (:func:`_prefill_extend_row`); a slot whose prompt
-    completes samples its first token from the piece's last-real-row
-    logits at rng-fold ``pfold`` (bitwise what host-side
-    ``prefill_carry`` samples). Stage 2 — the chunk's decode scan, with
-    rows still mid-prefill frozen. Everything per-slot rides traced, so
-    mixed prefill/decode traffic costs ONE compile per
+    Stage 1 — every slot with prompt left (``active & t < plen``) is
+    WAITING; the boundary serves the first ``n = min(waiting, cap)`` of
+    them in this order, one batch-1 piece of at most ``pchunk`` tokens
+    each (:func:`_prefill_extend_row`), and a slot gets at most one
+    piece a boundary, so piece boundaries stay chunk-aligned:
+
+    - *order*: overdue slots first, the longest passed over first; then
+      shortest remaining prompt first; ties to the lowest slot index
+      (the slot closest to emitting frees its output stream soonest).
+      ``pwait`` [S] is the number of boundaries each slot has been
+      passed over since it was admitted or last served, and a slot is
+      overdue from ``pwait >= prefill_overdue_after(slots, n_steps)``.
+    - *cap*: ``prefill_piece_cap(slots, n_steps)`` pieces a boundary.
+    - *the wait's bound*: slots that are overdue leave in the order they
+      became so, ``cap`` a boundary, and a slot admitted later cannot
+      overtake them; at most ``slots - 1`` others are ahead, so no slot
+      is passed over more than ``prefill_overdue_after + (slots - 1) //
+      cap`` boundaries in a row.
+
+    The order is a function of carry-resident values and ``pwait``, which
+    the host itself counts, so the scheduler mirrors it with no readback
+    (``SlotEngine._selected_prefill_slots``). ``n`` is traced: the first
+    piece runs inline and a loop of ``n - 1`` trips the others, so a
+    boundary with one waiting slot does exactly the work of the
+    one-piece program this replaced, and one with none (a rung-3 replay
+    can mask the only one out) discards its piece as that program did.
+    A slot whose prompt completes samples its first token from its
+    piece's last-real-row logits at rng-fold ``pfold`` (bitwise what
+    host-side ``prefill_carry`` samples). Stage 2 — the chunk's decode
+    scan, with rows still mid-prompt frozen. Everything per-slot rides
+    traced, so mixed prefill/decode traffic costs ONE compile per
     (slots, chunk, prompt_bucket) — ``prompt_bucket`` being the staged
-    buffer's width. The effective piece never exceeds that width (a
-    single piece covers any prompt the buffer can hold, keeping piece
-    boundaries trivially chunk-aligned)."""
+    buffer's width. A piece never exceeds that width (a single piece
+    covers any prompt the buffer can hold)."""
     token, states, t, emit, done = carry
     piece = min(pchunk, pbuf.shape[1])  # both static: piece <= the bucket
     rem = jnp.maximum(plen - t, 0)
-    prefilling = active & (rem > 0)
-    has = prefilling.any()
-    sel = jnp.argmin(
-        jnp.where(prefilling, rem, jnp.iinfo(jnp.int32).max)
+    order, n = _prefill_selection(active, rem, pwait, n_steps)
+
+    def serve(k, served):
+        token, states, t, emit = served
+        sel = order[k]
+        # false only for the inline first piece when no slot waits: its
+        # garbage is then discarded bitwise, as a replay needs it to be
+        live = k < n
+        cons = jnp.where(live, jnp.minimum(rem[sel], piece), 0)
+        logits1, fed = _prefill_extend_row(
+            model, params, pbuf, states, sel, t[sel], cons, piece
+        )
+        states = jax.tree.map(
+            lambda x, new: x.at[sel].set(jnp.where(live, new, x[sel])),
+            states, fed,
+        )
+        completed = live & (rem[sel] <= piece)
+        key = jax.random.fold_in(rngs[sel], pfold[sel])
+        first = _sample_rows(logits1[None], key[None], sample_cfg)[0]
+        token = token.at[sel].set(jnp.where(completed, first, token[sel]))
+        emit = emit.at[sel].set(jnp.where(completed, pfold[sel], emit[sel]))
+        return token, states, t.at[sel].set(t[sel] + cons), emit
+
+    # the first piece runs inline, where XLA schedules it among the
+    # program's opening copies and casts as it did the one piece this
+    # program used to run (inside the loop a piece measured 9.5 ms on
+    # the chip against 5.2 ms inline); the loop runs the pieces after it
+    served = serve(0, (token, states, t, emit))
+    token, states, t, emit = jax.lax.fori_loop(
+        1, jnp.maximum(n, 1), serve, served
     )
-    cons = jnp.where(has, jnp.minimum(rem[sel], piece), 0)
-    logits1, fed = _prefill_extend_row(
-        model, params, pbuf, states, sel, t[sel], cons, piece
-    )
-    # guarded row write-back: with no slot prefilling (rung-3 replays can
-    # mask the only one out) the garbage piece is discarded bitwise
-    states = jax.tree.map(
-        lambda x, n: x.at[sel].set(jnp.where(has, n, x[sel])), states, fed
-    )
-    completed = has & (rem[sel] <= piece)
-    key = jax.random.fold_in(rngs[sel], pfold[sel])
-    first = _sample_rows(logits1[None], key[None], sample_cfg)[0]
-    token = token.at[sel].set(jnp.where(completed, first, token[sel]))
-    emit = emit.at[sel].set(jnp.where(completed, pfold[sel], emit[sel]))
-    t = t.at[sel].set(t[sel] + cons)
     emitting = active & (t >= plen)
     body = partial(
         _decode_batched_prefill_body, model, params, sample_cfg, rngs,
@@ -598,6 +663,7 @@ def decode_batched_prefill_chunk(
     pbuf: Array,
     plen: Array,
     pfold: Array,
+    pwait: Array,
     n_steps: int,
     pchunk: int,
     sample_cfg: SampleConfig,
@@ -608,7 +674,7 @@ def decode_batched_prefill_chunk(
     boundaries stay on :func:`decode_batched_chunk`, whose compiled
     program this addition must not perturb."""
     return _decode_batched_prefill_chunk_jit(
-        model, params, carry, rngs, active, pbuf, plen, pfold,
+        model, params, carry, rngs, active, pbuf, plen, pfold, pwait,
         int(n_steps), int(pchunk), sample_cfg,
     )
 

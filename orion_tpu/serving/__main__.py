@@ -58,11 +58,13 @@ def build_argparser() -> argparse.ArgumentParser:
                         "(one prefill compile per novel prompt length; "
                         "host-prefill only — requires --prefill-chunk 0)")
     p.add_argument("--prefill-chunk", type=int, default=64,
-                   help="in-scan chunked prefill: prompt tokens consumed "
-                        "per chunk boundary INSIDE the batched scan, so a "
-                        "long prompt never stalls co-resident decoders "
-                        "(admission becomes an O(1) slot insert); 0 = "
-                        "legacy host-thread prefill at admission")
+                   help="in-scan chunked prefill: prompt tokens ONE slot "
+                        "consumes per chunk boundary INSIDE the batched "
+                        "program (up to slots // chunk slots a boundary), "
+                        "so a long prompt never stalls co-resident "
+                        "decoders (admission becomes an O(1) slot "
+                        "insert); 0 = legacy host-thread prefill at "
+                        "admission")
     p.add_argument("--prompt-overflow", choices=["error", "clamp"],
                    default="error",
                    help="prompts longer than the largest prefill bucket: "
@@ -432,7 +434,7 @@ def _run(args, guard) -> int:
         tag = "" if r.status == "ok" else f" [{r.status}]"
         print(line + tok.decode(ids) + tag)
     print(f"stats: {server.stats}", file=sys.stderr)
-    mode = (f"in-scan prefill, {server.engine.prefill_chunk} tok/boundary"
+    mode = (f"in-scan prefill, {server.engine.prefill_chunk} tok/piece"
             if args.prefill_chunk else "host prefill")
     print(f"slot occupancy: {server.occupancy_lifetime():.3f} "
           f"({args.slots} slot(s), chunk {args.chunk}, {mode}"

@@ -17,10 +17,10 @@ row evictions on one carry.
 - **admission** — at chunk boundaries only, and since ISSUE 7 an O(1)
   row insert: the prompt is STAGED into the carry (padded to its bucket)
   and consumed INSIDE the batched scan
-  (``generate.decode_batched_prefill_chunk``) — each boundary spends a
-  ``prefill_chunk``-token prompt budget on ONE slot (shortest remaining
-  first; the budget is total, not per-slot, so the boundary tax stays
-  flat in the slot count) as a chunk-aligned parallel-forward piece that
+  (``generate.decode_batched_prefill_chunk``) — each boundary runs one
+  ``prefill_chunk``-token piece for each waiting slot, up to
+  ``slots // chunk`` of them (shortest remaining first; a slot passed
+  over too long goes first), each a batch-1 parallel-forward piece that
   replays the monolithic prefill's exact op sequence, so the carry a
   staged slot reaches is BITWISE what host-side prefill built, while
   co-resident decoders never wait behind a long prompt (the
@@ -83,6 +83,8 @@ from orion_tpu.generate import (
     decode_batched_prefill_chunk,
     decode_batched_spec_round,
     prefill_carry,
+    prefill_overdue_after,
+    prefill_piece_cap,
     reprefill_carry,
 )
 from orion_tpu.models.transformer import (
@@ -310,6 +312,9 @@ class _Slot:
     # device-side ``plen - t`` — deterministic, so no readback is needed
     # to know when a slot starts emitting.
     prompt_remaining: int = 0
+    # boundaries this slot waited mid-prompt and was passed over, since
+    # admission or its last piece: the unified program's ``pwait`` row
+    passed_over: int = 0
     rewinds: int = 0
     reprefills: int = 0
     # -- durable-session bookkeeping (all inert for sessionless requests) --
@@ -438,10 +443,10 @@ class SlotEngine:
         self.prompt_overflow = prompt_overflow
         cfg = model.cfg
         # in-scan chunked prefill (prefill_chunk > 0): admission stages
-        # the prompt into the carry and the unified chunk program spends
-        # a prefill_chunk-token budget per boundary on one prefilling
-        # slot — no host-side prefill call, no head-of-line stall. 0 =
-        # the legacy host-prefill admission (the bench's comparison path).
+        # the prompt into the carry and the unified chunk program runs a
+        # piece of at most prefill_chunk tokens for each waiting slot,
+        # up to slots // chunk a boundary — no host-side prefill call,
+        # no head-of-line stall. 0 = the legacy host-prefill admission.
         self.prefill_chunk = 0
         # the linear-attention chunk the in-scan piece boundaries align
         # to — also the prefix store's entry alignment (a cached state at
@@ -1172,11 +1177,11 @@ class SlotEngine:
         self._carry = carry
         done_np = self._done_np
         piece = self._piece_tokens()
-        # host mirror of the in-scan piece: deterministic, no readback —
+        # host mirror of the in-scan pieces: deterministic, no readback —
         # the ACCEPTED attempt's selection (same rule over the same
-        # host-mirrored inputs) tells which slot consumed the boundary's
-        # prompt budget and hence the boundary each slot starts emitting
-        sel = self._selected_prefill_slot(active)
+        # host-mirrored inputs) tells which slots consumed a piece at
+        # this boundary and hence the boundary each starts emitting
+        served = self._selected_prefill_slots(active)
         spec_stats = None if spec is None else {"accepted": 0, "rejected": 0,
                                                 "slots": 0}
         for i, slot in enumerate(self._slots):
@@ -1184,13 +1189,15 @@ class SlotEngine:
                 continue
             if slot.prompt_remaining > 0:
                 slot.chunks += 1
-                if i != sel:
+                if i not in served:
+                    slot.passed_over += 1
                     self.last_boundary.append({
                         "slot": i, "tag": slot.tag, "frozen": True,
                         "decode_steps": 0, "prefill_tokens": 0,
                         "decode_tokens": 0,
                     })
-                    continue  # frozen: another slot had the budget
+                    continue  # frozen: the boundary's pieces went elsewhere
+                slot.passed_over = 0
                 consumed = min(piece, slot.prompt_remaining)
                 slot.prompt_remaining -= consumed
                 self._emit("prefill_piece", slot=i, tag=slot.tag,
@@ -1291,31 +1298,41 @@ class SlotEngine:
         return out
 
     def _piece_tokens(self) -> int:
-        """The boundary's TOTAL prompt-token budget (Sarathi-style
-        rate-limit knob), capped at the staged buffer's width (a single
-        piece then covers any prompt the buffer holds — which also keeps
-        piece boundaries trivially chunk-aligned)."""
+        """The width of ONE slot's piece: ``prefill_chunk``, capped at
+        the staged buffer's width (a single piece then covers any prompt
+        the buffer holds — which also keeps piece boundaries trivially
+        chunk-aligned). How many pieces a boundary runs is
+        ``generate.prefill_piece_cap(slots, chunk)``."""
         if not self.prefill_chunk or self._pbuf is None:
             return self.prefill_chunk
         return min(self.prefill_chunk, self._pbuf.shape[1])
 
-    def _selected_prefill_slot(self, active) -> Optional[int]:
-        """Host mirror of the unified program's stage-1 selection:
-        shortest remaining prompt first, ties to the lowest slot index —
-        computed from the same inputs the device argmin sees (the
-        host-tracked remaining counts), so the schedule is known without
-        a device round-trip. Must be evaluated against the mask of the
-        ACCEPTED attempt (ladder rung 3 can mask a prefilling slot out,
-        moving the budget to its neighbour in the replay)."""
-        best = None
-        for i, slot in enumerate(self._slots):
-            if slot is None or not active[i] or slot.prompt_remaining <= 0:
-                continue
-            if (best is None
-                    or slot.prompt_remaining
-                    < self._slots[best].prompt_remaining):
-                best = i
-        return best
+    def _selected_prefill_slots(self, active) -> List[int]:
+        """Host mirror of the unified program's stage-1 schedule, in the
+        order the device loop walks it: overdue slots first (the longest
+        passed over first), then shortest remaining prompt, ties to the
+        lowest slot index; the first ``prefill_piece_cap`` of them —
+        computed from the same inputs ``generate._prefill_selection``
+        sees (the host-tracked remaining and passed-over counts), so the
+        schedule is known without a device round-trip. Must be evaluated
+        against the mask of the ACCEPTED attempt (ladder rung 3 can mask
+        a prefilling slot out, which moves the next slot up in the
+        replay)."""
+        overdue_after = prefill_overdue_after(self.slots, self.chunk)
+
+        def key(i):
+            slot = self._slots[i]
+            overdue = slot.passed_over >= overdue_after
+            return (-slot.passed_over if overdue else 0,
+                    slot.prompt_remaining, i)
+
+        waiting = [
+            i for i, slot in enumerate(self._slots)
+            if slot is not None and active[i] and slot.prompt_remaining > 0
+        ]
+        return sorted(waiting, key=key)[
+            :prefill_piece_cap(self.slots, self.chunk)
+        ]
 
     def _snapshot(self):
         """Container-fresh snapshot of the batched carry (O(1): jax arrays
@@ -1370,15 +1387,19 @@ class SlotEngine:
                     spec, self.spec_depth, self._sample,
                 )
         elif unified:
+            pwait = jnp.asarray(np.array(
+                [0 if s is None else s.passed_over for s in self._slots],
+                np.int32,
+            ))
             if warm is not None:
                 out, toks = warm(
                     self.params, carry, self._rngs, active_dev,
-                    self._pbuf, self._plen, self._pfold,
+                    self._pbuf, self._plen, self._pfold, pwait,
                 )
             else:
                 out, toks = decode_batched_prefill_chunk(
                     self.model, self.params, carry, self._rngs, active_dev,
-                    self._pbuf, self._plen, self._pfold, self.chunk,
+                    self._pbuf, self._plen, self._pfold, pwait, self.chunk,
                     self.prefill_chunk, self._sample,
                 )
         else:

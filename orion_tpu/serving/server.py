@@ -102,9 +102,10 @@ PHASES = (
     "serve.probe", "serve.finish", "serve.complete", "serve.idle_wait",
 )
 # slot_steps_active split by what the slot did at the boundary (their sum
-# IS slot_steps_active): consumed the boundary's prefill piece / emitted
-# tokens without consuming it / neither (resident mid-prefill but not
-# selected — and the rare slot evicted by its deadline or the ladder)
+# IS slot_steps_active): consumed one of the boundary's prefill pieces /
+# emitted tokens without consuming one / neither (resident mid-prompt
+# and passed over — and the rare slot evicted by its deadline or the
+# ladder); slot_steps_prefilling / chunks is pieces per boundary
 _SLOT_CLASS_KEYS = (
     "slot_steps_prefilling", "slot_steps_decoding", "slot_steps_frozen",
 )
@@ -128,11 +129,11 @@ class ServeConfig:
     grace: float = 30.0  # SIGTERM drain budget, as in training
     poll: float = 0.05  # idle queue poll cadence (seconds)
     prefill_buckets: str = "pow2"  # pad-to-bucket prompt lengths ("" = off)
-    # in-scan chunked prefill: prompt tokens consumed per chunk boundary
-    # inside the batched scan (rate-limits prefill against resident
-    # decoders; rounded up to the linear-attention chunk). 0 = legacy
-    # host-thread prefill at admission (the head-of-line-blocking path,
-    # kept for comparison benches).
+    # in-scan chunked prefill: the width of ONE slot's prompt piece
+    # (rounded up to the linear-attention chunk); a boundary runs a piece
+    # for each waiting slot, up to slots // chunk of them, before its
+    # decode scan. 0 = legacy host-thread prefill at admission (the
+    # head-of-line-blocking path, kept for comparison).
     prefill_chunk: int = 64
     # prompts longer than the largest prefill bucket: "error" refuses the
     # request cleanly; "clamp" serves the newest bucket-sized context

@@ -578,3 +578,54 @@ def test_server_occupancy_gauges(mp):
     assert snap["stats"]["ok"] == 3
     assert snap["occupancy"] == srv.occupancy_lifetime()
     srv.close()
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 33: the boundary's bookkeeping with several slots served at once
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("k", [1, 3, 4, 6])
+def test_boundary_books_every_served_slot_and_only_those(mp, k):
+    """K staged admissions before one boundary of an 8-slot, chunk-2
+    engine (cap 4): each served slot gets its ``prefill_piece`` event and
+    a ``last_boundary`` entry with prompt tokens and this boundary's
+    chunk of output; the slots over the cap are the frozen ones, and
+    ``passed_over`` counts the boundary they waited."""
+    from orion_tpu.generate import prefill_piece_cap
+
+    model, params = mp
+    events = []
+    eng = SlotEngine(model, params, slots=8, chunk=2,
+                     prefill_buckets=(8, 16), prefill_chunk=8,
+                     on_event=lambda kind, f: events.append((kind, f)))
+    cap = prefill_piece_cap(8, 2)
+    prompts = _prompts(k)  # lengths 3..7: one piece each, shortest first
+    for i, p in enumerate(prompts):
+        eng.admit(DecodeRequest(prompt=p, max_new_tokens=6, sample=GREEDY,
+                                seed=i), tag=i)
+    assert eng.prefilling_count == k
+    assert eng.step() == []
+    order = sorted(range(k), key=lambda i: (prompts[i].shape[1], i))
+    served, waiting = order[:cap], order[cap:]
+    pieces = [f["slot"] for kind, f in events if kind == "prefill_piece"]
+    assert sorted(pieces) == sorted(served)
+    by_slot = {e["slot"]: e for e in eng.last_boundary}
+    assert sorted(by_slot) == list(range(k))
+    for i in served:
+        assert by_slot[i]["prefill_tokens"] == prompts[i].shape[1]
+        assert by_slot[i]["decode_tokens"] == 2 and "frozen" not in by_slot[i]
+        assert eng._slots[i].passed_over == 0
+        assert eng._slots[i].n_emitted == 2
+    for i in waiting:
+        assert by_slot[i]["frozen"] and by_slot[i]["prefill_tokens"] == 0
+        assert eng._slots[i].passed_over == 1
+        assert eng._slots[i].n_emitted == 0
+    assert eng.prefilling_count == len(waiting)
+    done = {}
+    while eng.busy:
+        done.update(dict(eng.step()))
+    for i, p in enumerate(prompts):
+        ref = np.asarray(generate(model, params, p, 6, GREEDY,
+                                  rng=jax.random.PRNGKey(i)))
+        np.testing.assert_array_equal(done[i].tokens, ref)

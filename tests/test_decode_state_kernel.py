@@ -282,10 +282,10 @@ def _program_jaxprs(backend):
             model, p, c, r, a, 4, GREEDY)
     )(eng.params, eng._carry, eng._rngs, active)
     unified = jax.make_jaxpr(
-        lambda p, c, r, a, pb, pl, pf: _decode_batched_prefill_chunk_jit(
-            model, p, c, r, a, pb, pl, pf, 4, 8, GREEDY)
+        lambda p, c, r, a, pb, pl, pf, pw: _decode_batched_prefill_chunk_jit(
+            model, p, c, r, a, pb, pl, pf, pw, 4, 8, GREEDY)
     )(eng.params, eng._carry, eng._rngs, active, eng._pbuf, eng._plen,
-      eng._pfold)
+      eng._pfold, jnp.zeros_like(eng._plen))
     return str(pure), str(unified)
 
 

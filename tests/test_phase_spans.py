@@ -274,10 +274,19 @@ def test_first_token_at_admission_with_host_prefill(mp):
 # -- slot classes --------------------------------------------------------------
 
 
-def test_slot_classes_sum_to_active_after_every_boundary(mp):
-    srv = _server(mp, tracer=Tracer(enabled=False))
+@pytest.mark.parametrize("slots,chunk,pieces", [
+    (2, 4, [1, 1, 1, 0]),  # cap 1: one piece a boundary, as before ISSUE 33
+    (4, 2, [2, 2, 0, 0]),  # cap 2: four admitted at once, two boundaries
+    (8, 2, [4, 2, 0, 0]),  # cap 4: six admitted at once, the cap binds once
+], ids=["cap1", "cap2", "cap4"])
+def test_slot_classes_sum_to_active_after_every_boundary(
+        mp, slots, chunk, pieces):
+    from orion_tpu.generate import prefill_piece_cap
+
+    srv = _server(mp, tracer=Tracer(enabled=False), slots=slots, chunk=chunk)
+    cap = prefill_piece_cap(slots, chunk)
     ps = _mixed(srv, n=6)
-    seen = []
+    seen = [(0, 0, 0)]
     step = srv._step_chunk
 
     def checked(*a, **kw):
@@ -286,18 +295,20 @@ def test_slot_classes_sum_to_active_after_every_boundary(mp):
         seen.append((c["slot_steps_prefilling"], c["slot_steps_decoding"],
                      c["slot_steps_frozen"]))
         assert sum(seen[-1]) == c["slot_steps_active"]
-        assert c["slot_steps_prefilling"] <= c["chunks"], (
-            "one slot at most consumes a boundary's piece")
+        assert seen[-1][0] - seen[-2][0] <= cap, (
+            "a boundary serves at most prefill_piece_cap slots")
 
     srv._step_chunk = checked
     srv.serve(drain_when_idle=True)
     assert all(p.result.status == "ok" for p in ps)
     prefilling, decoding, frozen = seen[-1]
+    # slots served at the first boundaries: every waiting slot up to the cap
+    assert [b[0] - a[0] for a, b in zip(seen, seen[1:])][:4] == pieces
     # every prompt went through whole pieces: 5, 20, 9, 17, 12, 5 tokens
     piece = srv.engine.prefill_chunk
     assert prefilling == sum(-(-n // piece) for n in (5, 20, 9, 17, 12, 5))
     assert decoding > 0 and frozen > 0, (
-        "two slots, multi-piece prompts: some slot waited its turn")
+        "more waiting slots than the cap: some slot waited its turn")
     srv.close()
 
 

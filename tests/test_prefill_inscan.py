@@ -1,5 +1,11 @@
 """In-scan chunked prefill suite (ISSUE 7): admission without the stall.
 
+Since ISSUE 33 a boundary runs one piece for EACH waiting slot, up to
+``prefill_piece_cap`` of them; the last section pins that schedule: K
+slots admitted before one boundary, the host's mirror of the device's
+order, the bound on a passed-over slot's wait, and the one-waiting-slot
+boundary against the one-piece program it replaced.
+
 The two acceptance proofs live here — (1) a request admitted by STAGING
 its prompt into the carry and consuming it ``prefill_chunk`` tokens per
 boundary inside the batched scan emits tokens BITWISE-identical to the
@@ -14,21 +20,31 @@ any jit, mid-prefill deadline/drain behaviour, and a PR 6 session
 suspended and resumed across an in-scan-admitted turn.
 """
 
+from functools import partial
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+import orion_tpu.serving.batching as batching
 from orion_tpu.generate import (
     SampleConfig,
     _decode_batched_chunk_jit,
+    _decode_batched_prefill_body,
     _decode_batched_prefill_chunk_jit,
     _prefill_carry_bucketed_jit,
     _prefill_carry_jit,
+    _prefill_extend_row,
+    _prefill_selection,
+    _sample_rows,
     generate,
+    prefill_overdue_after,
+    prefill_piece_cap,
 )
 from orion_tpu.models.configs import ModelConfig
+from orion_tpu.ops.dispatch import decode_live_rows
 from orion_tpu.models.transformer import TransformerLM, init_decode_state
 from orion_tpu.resilience import inject
 from orion_tpu.serving import (
@@ -452,3 +468,299 @@ def test_occupancy_distinguishes_prefilling_from_decoding(mp):
     _drain(eng)
     occ = eng.occupancy()
     assert occ["prefilling"] == 0 and occ["active"] == 0
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 33: a piece for every waiting slot, up to the cap, at one boundary
+# ---------------------------------------------------------------------------
+
+# 8 slots over chunks of 2: four pieces a boundary, overdue after two
+WIDE = dict(slots=8, chunk=2)
+CAP = prefill_piece_cap(**WIDE)
+LENGTHS = [17, 3, 21, 8, 9, 16, 5, 30]  # one to four pieces of 8
+
+
+def _solo(mp, prompt, n, sample, seed):
+    model, params = mp
+    return np.asarray(generate(model, params, prompt, n, sample,
+                               rng=jax.random.PRNGKey(seed)))
+
+
+def _pieces_by_boundary():
+    """(the list an ``on_event`` tap fills, the tap): the slots that
+    consumed a piece, one list per boundary (closed by :func:`_step`)."""
+    log = [[]]
+
+    def tap(kind, fields):
+        if kind == "prefill_piece":
+            log[-1].append(fields["slot"])
+
+    return log, tap
+
+
+def _step(eng, log):
+    out = dict(eng.step())
+    log.append([])
+    return out
+
+
+def test_cap_and_overdue_threshold_come_from_slots_and_chunk():
+    assert prefill_piece_cap(64, 16) == 4 and prefill_overdue_after(64, 16) == 16
+    assert prefill_piece_cap(8, 2) == 4 and prefill_overdue_after(8, 2) == 2
+    assert prefill_piece_cap(2, 4) == 1 and prefill_overdue_after(2, 4) == 2
+    assert prefill_piece_cap(5, 2) == 2 and prefill_overdue_after(5, 2) == 3
+
+
+@pytest.mark.parametrize("k", [2, 3, CAP, CAP + 2])
+@pytest.mark.parametrize("sample", [GREEDY, SAMPLED], ids=["greedy", "sampled"])
+def test_k_slots_admitted_before_one_boundary_bitwise(mp, k, sample):
+    """K requests admitted before ONE boundary: it serves min(K, cap) of
+    them, and every request's tokens are what the one-piece-a-boundary
+    schedule (the next request admitted only once nobody waits) and the
+    solo scan emit."""
+    prompts = [_prompt(200 + i, ln) for i, ln in enumerate(LENGTHS[:k])]
+    refs = [_solo(mp, p, 8, sample, 500 + i) for i, p in enumerate(prompts)]
+
+    def request(i):
+        return DecodeRequest(prompt=prompts[i], max_new_tokens=8,
+                             sample=sample, seed=500 + i)
+
+    log, tap = _pieces_by_boundary()
+    eng = _engine(mp, "inscan", **WIDE, on_event=tap)
+    for i in range(k):
+        eng.admit(request(i), tag=i)
+    together = _step(eng, log)
+    assert len(log[0]) == min(k, CAP)
+    while eng.busy:
+        together.update(_step(eng, log))
+    assert all(len(b) <= CAP for b in log)
+
+    eng = _engine(mp, "inscan", **WIDE)
+    one_by_one, pending = {}, list(range(k))
+    while pending or eng.busy:
+        if pending and eng.prefilling_count == 0:
+            i = pending.pop(0)
+            eng.admit(request(i), tag=i)
+        assert eng.prefilling_count <= 1
+        one_by_one.update(dict(eng.step()))
+    for i, ref in enumerate(refs):
+        for name, done in (("together", together), ("one", one_by_one)):
+            assert done[i].status == "ok", (name, i)
+            np.testing.assert_array_equal(
+                done[i].tokens, ref, err_msg=f"{name} k={k} request {i}"
+            )
+
+
+def test_selection_order_overdue_then_shortest_then_index():
+    """The device's order from crafted rows: overdue slots first, the
+    longest passed over first; then the shortest remainder; ties to the
+    lowest index; free and decoding rows last and never counted."""
+    active = jnp.asarray([1, 1, 1, 1, 1, 1, 0, 1], bool)
+    rem = jnp.asarray([9, 4, 4, 30, 0, 12, 3, 25], jnp.int32)
+    pwait = jnp.asarray([0, 1, 0, 2, 7, 3, 9, 1], jnp.int32)
+    order, n = _prefill_selection(active, rem, pwait, WIDE["chunk"])
+    # overdue (pwait >= 2): slot 5 (3) before slot 3 (2); slot 4 decodes
+    # and slot 6 is free, whatever their pwait; then rem 4, 4, 9, 25
+    assert list(np.asarray(order)[:6]) == [5, 3, 1, 2, 0, 7]
+    assert int(n) == CAP
+    order, n = _prefill_selection(
+        active & (jnp.arange(8) < 2), rem, pwait, WIDE["chunk"])
+    assert list(np.asarray(order)[:2]) == [1, 0] and int(n) == 2
+
+
+def _recording_unified(monkeypatch):
+    """Record what the DEVICE's rule selects in every attempt the engine
+    launches: the program's own ``_prefill_selection`` on the attempt's
+    operands, read back (the program itself returns no schedule)."""
+    attempts = []
+    real = batching.decode_batched_prefill_chunk
+
+    def spy(model, params, carry, rngs, active, pbuf, plen, pfold, pwait,
+            n_steps, pchunk, sample):
+        order, n = _prefill_selection(
+            active, jnp.maximum(plen - carry[2], 0), pwait, n_steps)
+        attempts.append([int(i) for i in np.asarray(order)[:int(n)]])
+        return real(model, params, carry, rngs, active, pbuf, plen, pfold,
+                    pwait, n_steps, pchunk, sample)
+
+    monkeypatch.setattr(batching, "decode_batched_prefill_chunk", spy)
+    return attempts
+
+
+def _recording_host(eng):
+    picks = []
+    real = eng._selected_prefill_slots
+
+    def spy(active):
+        picks.append(real(active))
+        return picks[-1]
+
+    eng._selected_prefill_slots = spy
+    return picks
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_host_selection_equals_device_every_boundary(mp, monkeypatch, seed):
+    """A seeded mixed run, more requests than slots, bursts of
+    admissions: at every boundary the ordered list the host books is the
+    list the device's rule walks, and every request comes out solo-equal
+    (a host that booked another schedule would release tokens that were
+    never computed)."""
+    rng = np.random.default_rng(seed)
+    n = 12
+    lengths = rng.integers(2, 31, n)
+    news = rng.choice([2, 4, 6], n)
+    prompts = [_prompt(300 + 20 * seed + i, int(ln))
+               for i, ln in enumerate(lengths)]
+    attempts = _recording_unified(monkeypatch)
+    eng = _engine(mp, "inscan", slots=5, chunk=2)
+    picks = _recording_host(eng)
+    done, pending, unified = {}, list(range(n)), 0
+    while pending or eng.busy:
+        for _ in range(int(rng.integers(0, 4))):
+            if pending and eng.has_free_slot:
+                i = pending.pop(0)
+                eng.admit(DecodeRequest(prompt=prompts[i],
+                                        max_new_tokens=int(news[i]),
+                                        sample=SAMPLED, seed=700 + i), tag=i)
+        if not eng.busy:
+            continue
+        before = len(attempts)
+        done.update(dict(eng.step()))
+        if len(attempts) > before:
+            unified += 1
+            assert picks[-1] == attempts[-1]
+        else:
+            assert picks[-1] == []
+    assert unified > 4 and max(len(a) for a in attempts) == 2
+    for i in range(n):
+        assert done[i].status == "ok"
+        np.testing.assert_array_equal(
+            done[i].tokens,
+            _solo(mp, prompts[i], int(news[i]), SAMPLED, 700 + i),
+            err_msg=f"seed {seed} request {i}",
+        )
+
+
+def test_rung3_masks_a_prefilling_slot_and_the_next_moves_up(mp, monkeypatch):
+    """Four waiting slots, cap 2: the first attempt serves slots 1 and 2
+    (the shortest). Slot 1's ladder is exhausted, rung 3 replays with it
+    masked out, and slot 0 moves up: the host books the REPLAY's list."""
+    lengths = [20, 5, 9, 30]
+    prompts = [_prompt(400 + i, ln) for i, ln in enumerate(lengths)]
+    attempts = _recording_unified(monkeypatch)
+    eng = _engine(mp, "inscan", slots=4, chunk=2)
+    picks = _recording_host(eng)
+    for i, p in enumerate(prompts):
+        eng.admit(DecodeRequest(prompt=p, max_new_tokens=6, sample=GREEDY,
+                                seed=800 + i), tag=i)
+    plan = inject.FaultPlan().poison_decode_slot_at(1, chunk=0, times=3)
+    with inject.inject(plan):
+        done = dict(eng.step())
+    assert attempts == [[1, 2], [1, 2], [1, 2], [2, 0]]
+    assert picks == [[2, 0]]
+    assert done[1].status == "failed" and done[1].new_tokens == 0
+    assert {e["slot"]: e["prefill_tokens"] for e in eng.last_boundary
+            if not e.get("failed")} == {0: 8, 2: 8, 3: 0}
+    done.update(_drain(eng))
+    for i in (0, 2, 3):
+        assert done[i].status == "ok", i
+        np.testing.assert_array_equal(
+            done[i].tokens, _solo(mp, prompts[i], 6, GREEDY, 800 + i))
+
+
+def test_long_prompt_beside_a_stream_of_short_ones_is_served_in_bound(mp):
+    """Shortest-first under a cap would pass a long prompt over for as
+    long as shorter ones keep arriving. The bound the program states:
+    no slot is passed over more than ``prefill_overdue_after +
+    (slots - 1) // cap`` boundaries in a row."""
+    slots, chunk = 4, 2
+    cap = prefill_piece_cap(slots, chunk)
+    bound = prefill_overdue_after(slots, chunk) + (slots - 1) // cap
+    long_prompt = _prompt(450, 30)  # four pieces of 8
+    log, tap = _pieces_by_boundary()
+    eng = _engine(mp, "inscan", slots=slots, chunk=chunk, on_event=tap)
+    eng.admit(DecodeRequest(prompt=long_prompt, max_new_tokens=4,
+                            sample=GREEDY, seed=900), tag="long")
+    done, shorts, worst, boundaries = {}, 0, 0, 0
+    while "long" not in done:
+        while eng.has_free_slot:  # a short prompt for every free slot
+            eng.admit(DecodeRequest(prompt=_prompt(460 + shorts, 3),
+                                    max_new_tokens=2, sample=GREEDY,
+                                    seed=shorts), tag=shorts)
+            shorts += 1
+        assert eng.prefilling_count > cap, "the cap binds every boundary"
+        done.update(_step(eng, log))
+        boundaries += 1
+        worst = max([worst] + [s.passed_over for s in eng._slots
+                               if s is not None])
+        assert boundaries < 40, "the long prompt starved"
+    assert worst <= bound
+    assert worst >= prefill_overdue_after(slots, chunk), (
+        "the stream never made a slot overdue: the test lost its point")
+    assert all(len(b) == cap for b in log[:boundaries])
+    assert done["long"].status == "ok"
+    np.testing.assert_array_equal(
+        done["long"].tokens, _solo(mp, long_prompt, 4, GREEDY, 900))
+    assert all(r.status == "ok" for r in done.values())
+
+
+@partial(jax.jit, static_argnums=(0, 8, 9, 10))
+def _one_piece_chunk(model, params, carry, rngs, active, pbuf, plen, pfold,
+                     n_steps, pchunk, sample_cfg):
+    """The unified program as it was before ISSUE 33: the boundary's ONE
+    piece goes to the waiting slot with the shortest remainder (a
+    discarded garbage piece when none waits), then the same scan."""
+    token, states, t, emit, done = carry
+    piece = min(pchunk, pbuf.shape[1])
+    rem = jnp.maximum(plen - t, 0)
+    prefilling = active & (rem > 0)
+    has = prefilling.any()
+    sel = jnp.argmin(jnp.where(prefilling, rem, jnp.iinfo(jnp.int32).max))
+    cons = jnp.where(has, jnp.minimum(rem[sel], piece), 0)
+    logits1, fed = _prefill_extend_row(
+        model, params, pbuf, states, sel, t[sel], cons, piece)
+    states = jax.tree.map(
+        lambda x, n: x.at[sel].set(jnp.where(has, n, x[sel])), states, fed)
+    completed = has & (rem[sel] <= piece)
+    key = jax.random.fold_in(rngs[sel], pfold[sel])
+    first = _sample_rows(logits1[None], key[None], sample_cfg)[0]
+    token = token.at[sel].set(jnp.where(completed, first, token[sel]))
+    emit = emit.at[sel].set(jnp.where(completed, pfold[sel], emit[sel]))
+    t = t.at[sel].set(t[sel] + cons)
+    emitting = active & (t >= plen)
+    body = partial(
+        _decode_batched_prefill_body, model, params, sample_cfg, rngs,
+        emitting, decode_live_rows(emitting, backend=model.cfg.backend))
+    carry, tokens = jax.lax.scan(
+        body, (token, states, t, emit, done), None, length=n_steps)
+    return carry, jnp.moveaxis(tokens, 0, 1)
+
+
+@pytest.mark.parametrize("waiting", [1, 0], ids=["one-waiting", "none"])
+@pytest.mark.parametrize("sample", [GREEDY, SAMPLED], ids=["greedy", "sampled"])
+def test_one_waiting_slot_leaves_every_carry_leaf_as_the_one_piece_program(
+        mp, waiting, sample):
+    """With one waiting slot the loop runs once and every leaf of the
+    carry, and every emitted token, is what the one-piece program gives;
+    with the only waiting slot masked out (a rung-3 replay) it runs no
+    piece where the old program ran one and discarded it."""
+    model, params = mp
+    eng = _engine(mp, "inscan", slots=4, chunk=2)
+    eng.admit(DecodeRequest(prompt=_prompt(480, 5), max_new_tokens=12,
+                            sample=sample, seed=1), tag=0)
+    eng.step()  # slot 0 decodes from here on
+    eng.admit(DecodeRequest(prompt=_prompt(481, 21), max_new_tokens=12,
+                            sample=sample, seed=2), tag=1)
+    eng.step()  # slot 1: one piece of three consumed, mid-prompt
+    assert eng.prefilling_count == 1
+    active = jnp.asarray([True, bool(waiting), False, False])
+    dyn = (params, eng._carry, eng._rngs, active, eng._pbuf, eng._plen,
+           eng._pfold)
+    new = _decode_batched_prefill_chunk_jit(
+        model, *dyn, jnp.zeros((4,), jnp.int32), 2, 8, sample)
+    old = _one_piece_chunk(model, *dyn, 2, 8, sample)
+    for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(old), strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    moved = int(new[0][2][1]) - int(eng._carry[2][1])
+    assert moved == (8 if waiting else 0)
