@@ -121,6 +121,26 @@ PROGRAMS: Tuple[ProgramDecl, ...] = (
         goldens=("decode_batched_prefill_tiny",),
     ),
     ProgramDecl(
+        "prefill_piece_donated", GENERATE, "_prefill_piece_donated_jit",
+        "decode", static_args=("model", "pchunk", "sample_cfg"),
+        donate_argnums=(2,), plan="never",
+        note="one slot's prompt piece on a DONATED carry, for an engine "
+             "whose decode state does not fit the device twice "
+             "(SlotEngine.donate_carry): unified_prefill's stage 1 for a "
+             "slot the host chose. The plan, the goldens and the "
+             "warm-start store keep the undonated programs; this one is "
+             "compiled where it runs",
+    ),
+    ProgramDecl(
+        "decode_scan_donated", GENERATE, "_decode_scan_donated_jit",
+        "decode", static_args=_DECODE_STATICS, donate_argnums=(2,),
+        plan="never",
+        note="the chunk's decode scan on a donated carry (rows still "
+             "mid-prompt frozen): the scan carries each mixer's "
+             "chunk_split part and reads the rest; see "
+             "prefill_piece_donated",
+    ),
+    ProgramDecl(
         "spec_round", GENERATE, "_decode_batched_spec_round_jit", "decode",
         static_args=("model", "depth", "sample_cfg"), plan="spec",
         goldens=("decode_batched_spec_tiny",),
@@ -176,15 +196,22 @@ PROGRAMS: Tuple[ProgramDecl, ...] = (
                 note="slot admission row write; traced slot index — one "
                      "compile ever per engine shape"),
     ProgramDecl("stage_prompt_carry", BATCHING, "_stage_prompt_carry",
-                "setup",
+                "setup", donate_argnums=(0, 1, 2, 3, 4),
                 note="in-scan admission staging; one compile per staged "
-                     "buffer width"),
+                     "buffer width. The carry and the staging vectors "
+                     "are donated: a row write in place, never a copy "
+                     "of the whole decode state"),
     ProgramDecl("stage_prefix_carry", BATCHING, "_stage_prefix_carry",
-                "setup",
-                note="prefix-cache-hit admission staging"),
+                "setup", donate_argnums=(0, 1, 2, 3, 4),
+                note="prefix-cache-hit admission staging; donated like "
+                     "stage_prompt_carry"),
     ProgramDecl("restart_prefill_row", BATCHING, "_restart_prefill_row",
                 "setup",
-                note="chaos-ladder rung 2 row rewind"),
+                note="chaos-ladder rung 2 row rewind; NOT donated: it "
+                     "writes into the boundary's snapshot while the "
+                     "engine's carry still aliases it (an engine that "
+                     "donates its carry has no snapshot and never "
+                     "reaches this rung)"),
     ProgramDecl("extract_carry", BATCHING, "_extract_carry", "setup",
                 note="durable-session suspend row read"),
     # -- training: shard_map launchers (train-side key spaces) -----------

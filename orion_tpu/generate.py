@@ -654,6 +654,133 @@ def _decode_batched_prefill_chunk_jit(
     return carry, jnp.moveaxis(tokens, 0, 1)  # [S, n_steps]
 
 
+# -- the carry held once (ISSUE 35) -------------------------------------------
+# A decode state of GBs (a KV cache per slot) fits the device once, not
+# twice. The two programs above return a NEW carry beside the one they were
+# given, and XLA copies whatever a loop carries at the loop's entry, so a
+# program that walks the cache through its piece loop or its scan needs a
+# second cache even when its operands are donated. An engine whose state
+# does not fit twice (``SlotEngine.donate_carry``) therefore runs a boundary
+# as separate DONATED programs, dispatched back to back (the device runs
+# them in order while the host enqueues the next): one
+# ``_prefill_piece_donated_jit`` for each slot the boundary serves — the
+# host knows the schedule, ``SlotEngine._selected_prefill_slots`` — whose
+# row writes are plain in-place slice updates, then one
+# ``_decode_scan_donated_jit`` whose scan carries only what each mixer's
+# ``chunk_split`` says it must (the delta rule's state, a chunk's new cache
+# rows) and closes over the rest, merged back after the scan in place.
+# Same mathematics, same schedule; what it gives up is the boundary
+# snapshot the ladder rewinds to.
+
+
+@partial(jax.jit, static_argnums=(0, 8, 9), donate_argnums=(2,))
+def _prefill_piece_donated_jit(
+    model: TransformerLM,
+    params: Any,
+    carry: Any,
+    rngs: Array,
+    pbuf: Array,
+    plen: Array,
+    pfold: Array,
+    sel: Array,
+    pchunk: int,
+    sample_cfg: SampleConfig,
+) -> Any:
+    """Slot ``sel`` consumes its next prompt piece, in place: stage 1 of
+    :func:`_decode_batched_prefill_chunk_jit` for one slot the host chose.
+    The carry is donated and comes back with that row advanced; a slot
+    whose prompt completes samples its first token as there."""
+    token, states, t, emit, done = carry
+    piece = min(pchunk, pbuf.shape[1])
+    rem = jnp.maximum(plen[sel] - t[sel], 0)
+    cons = jnp.minimum(rem, piece)
+    logits1, fed = _prefill_extend_row(
+        model, params, pbuf, states, sel, t[sel], cons, piece
+    )
+    states = jax.tree.map(lambda x, new: x.at[sel].set(new), states, fed)
+    completed = (rem > 0) & (rem <= piece)
+    key = jax.random.fold_in(rngs[sel], pfold[sel])
+    first = _sample_rows(logits1[None], key[None], sample_cfg)[0]
+    token = token.at[sel].set(jnp.where(completed, first, token[sel]))
+    emit = emit.at[sel].set(jnp.where(completed, pfold[sel], emit[sel]))
+    return token, states, t.at[sel].set(t[sel] + cons), emit, done
+
+
+@partial(jax.jit, static_argnums=(0, 6, 7), donate_argnums=(2,))
+def _decode_scan_donated_jit(
+    model: TransformerLM,
+    params: Any,
+    carry: Any,
+    rngs: Array,
+    active: Array,
+    plen: Array,
+    n_steps: int,
+    sample_cfg: SampleConfig,
+) -> Tuple[Any, Array]:
+    """Stage 2 of :func:`_decode_batched_prefill_chunk_jit`, the chunk's
+    decode scan with rows still mid-prompt frozen, on a donated carry. The
+    scan carries each layer's ``Mixer.chunk_split`` part and reads the
+    rest; ``Mixer.chunk_merge`` puts the two together after it."""
+    token, states, t, emit, done = carry
+    kinds = model.cfg.resolved_layer_types
+    emitting = active & (t >= plen)
+    split = [
+        MIXERS[lt].chunk_split(model.cfg, lt, st, n_steps, t)
+        for lt, st in zip(kinds, states)
+    ]
+    held = [h for h, _ in split]
+    step = partial(
+        _decode_batched_prefill_body, model, params, sample_cfg, rngs,
+        emitting, decode_live_rows(emitting, backend=model.cfg.backend),
+    )
+
+    def body(c, _):
+        token, carried, t, emit, done = c
+        whole = [{**h, **cc} for h, cc in zip(held, carried)]
+        (token, new, t, emit, done), emitted = step(
+            (token, whole, t, emit, done), None
+        )
+        carried = [{k: st[k] for k in cc} for st, cc in zip(new, carried)]
+        return (token, carried, t, emit, done), emitted
+
+    (token, carried, t, emit, done), tokens = jax.lax.scan(
+        body, (token, [c for _, c in split], t, emit, done), None,
+        length=n_steps,
+    )
+    states = [
+        MIXERS[lt].chunk_merge(model.cfg, lt, h, c, emitting)
+        for lt, h, c in zip(kinds, held, carried)
+    ]
+    return (token, states, t, emit, done), jnp.moveaxis(tokens, 0, 1)
+
+
+def decode_boundary_donated(
+    model: TransformerLM,
+    params: Any,
+    carry: Any,
+    rngs: Array,
+    active: Array,
+    pbuf: Optional[Array],
+    plen: Array,
+    pfold: Array,
+    served: Tuple[int, ...],
+    n_steps: int,
+    pchunk: int,
+    sample_cfg: SampleConfig,
+):
+    """One boundary on a donated carry: a prompt piece for each slot of
+    ``served`` (the host's schedule, in its order), then the decode scan.
+    ``carry`` is consumed. Returns (carry, tokens [S, n_steps])."""
+    for sel in served:
+        carry = _prefill_piece_donated_jit(
+            model, params, carry, rngs, pbuf, plen, pfold, jnp.int32(sel),
+            int(pchunk), sample_cfg,
+        )
+    return _decode_scan_donated_jit(
+        model, params, carry, rngs, active, plen, int(n_steps), sample_cfg
+    )
+
+
 def decode_batched_prefill_chunk(
     model: TransformerLM,
     params: Any,
@@ -838,6 +965,8 @@ def decode_batched_spec_round(
 DECODE_PROGRAMS = {
     "decode_batched": _decode_batched_chunk_jit,
     "unified_prefill": _decode_batched_prefill_chunk_jit,
+    "prefill_piece_donated": _prefill_piece_donated_jit,
+    "decode_scan_donated": _decode_scan_donated_jit,
     "spec_round": _decode_batched_spec_round_jit,
     "prefill": _prefill_carry_jit,
     "prefill_bucketed": _prefill_carry_bucketed_jit,

@@ -69,6 +69,16 @@ class ModelConfig:
     gdn_key_dim: int = 0  # per head
     gdn_value_dim: int = 0  # per head
     gdn_conv_width: int = 4
+    # write strength beta = 2 sigmoid(b) in (0, 2) instead of (0, 1): the
+    # state's transition I - beta k k^T may then have a negative eigenvalue
+    gdn_allow_neg_eigval: bool = False
+    # "projection": RMSNorm (own weight) of the whole q and k projections,
+    # before the split into heads (linear / softmax / swa layers)
+    qk_norm: str = "none"  # "none" | "projection"
+    rotary: bool = True  # softmax / swa layers rotate q and k by position
+    # "pre": x + f(norm(x)); "post": x + norm(f(x)), the sublayer's OUTPUT
+    # normalised before the residual add
+    norm_placement: str = "pre"  # "pre" | "post"
     dropout: float = 0.0
     # numerics / execution
     dtype: str = "bfloat16"  # activation/compute dtype
@@ -327,6 +337,48 @@ QWEN3_NEXT_80B = ModelConfig(
     remat=True,
 )
 
+def delta_full_pattern(n_layers: int, period: int = 4) -> Tuple[str, ...]:
+    """gated_delta x (period - 1) then full softmax attention, repeating."""
+    return tuple(
+        "softmax" if (i + 1) % period == 0 else "gated_delta"
+        for i in range(n_layers)
+    )
+
+
+OLMO_HYBRID_7B = ModelConfig(
+    # Olmo-Hybrid-7B at its published widths, two of its eight periods deep:
+    # one of four pipeline stages of 8 layers (benchmark/configs/
+    # olmo_hybrid_7b.json states the source, the cut and what is assumed).
+    # 3 gated delta-rule layers (30 heads of 96 x 192, beta in (0, 2)) then
+    # 1 full-attention layer (30 heads x 128, q/k norm over the projection,
+    # no rotary), the sublayer's output normalised, dense SwiGLU; served in
+    # bfloat16, parameters included.
+    name="olmo_hybrid_7b",
+    vocab_size=100352,
+    d_model=3840,
+    n_layers=8,
+    layer_types=delta_full_pattern(8, period=4),
+    n_heads=30,
+    head_dim=128,
+    gdn_key_heads=30,
+    gdn_value_heads=30,
+    gdn_key_dim=96,
+    gdn_value_dim=192,
+    gdn_conv_width=4,
+    gdn_allow_neg_eigval=True,
+    qk_norm="projection",
+    rotary=False,
+    norm_placement="post",
+    norm="rmsnorm",
+    pos_embed="none",
+    tie_embeddings=False,
+    mlp="swiglu",
+    mlp_hidden=11008,
+    max_seq_len=4096,
+    dtype="bfloat16",
+    param_dtype="bfloat16",
+)
+
 LRA_LISTOPS_LINEAR = ModelConfig(
     name="lra_listops_linear",
     vocab_size=32,  # digits + operators + specials
@@ -373,6 +425,7 @@ CONFIGS = {
         MOE_1B3_8E,
         MOE_1B3_4E,
         QWEN3_NEXT_80B,
+        OLMO_HYBRID_7B,
         LRA_LISTOPS_LINEAR,
         LRA_LISTOPS_SOFTMAX,
         LRA_TEXT_LINEAR,
@@ -390,5 +443,5 @@ def get_config(name: str, **overrides) -> ModelConfig:
 
 __all__ = [
     "ModelConfig", "CONFIGS", "get_config", "hybrid_pattern",
-    "gated_pattern", "F32_MATMUL_SCOPES", "LAYER_TYPES",
+    "gated_pattern", "delta_full_pattern", "F32_MATMUL_SCOPES", "LAYER_TYPES",
 ]

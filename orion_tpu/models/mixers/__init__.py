@@ -111,8 +111,10 @@ class Mixer(nn.Module):
     **Training** — ``__call__(x [B, T, D], mask [B, T] | None) -> [B, T, D]``.
 
     **Serving** — a mixer that can be served declares its zero state in
-    the static :meth:`decode_state` and overrides the five entry points
-    below; one that cannot inherits them, and they raise. The state is a
+    the static :meth:`decode_state` and overrides the entry points below
+    (``prefill``, ``prefill_extend``, ``decode_step``; the speculative pair
+    ``verify_extend`` / ``advance_verified`` where it is built); what a
+    mixer does not override is inherited, and raises. The state is a
     dict of arrays with the batch on axis 0: the planner, the AOT listing
     and the slot engine take its shapes from
     ``eval_shape(init_decode_state)``, and insert / extract / snapshot are
@@ -144,14 +146,38 @@ class Mixer(nn.Module):
         (fp32 accumulators ignore it)."""
         raise NotImplementedError(
             f"layer type {layer_type!r} has a training forward only: no decode "
-            "state (delta-rule state, conv state, grouped-KV cache) is "
-            "built for it"
+            "state (a grouped-KV cache, for gated_softmax) is built for it"
         )
+
+    @staticmethod
+    def chunk_split(
+        cfg: ModelConfig, layer_type: str, state: State, n_steps: int, t: Array
+    ) -> Tuple[State, State]:
+        """``(held, carried)`` for a decode scan of ``n_steps`` that starts
+        at positions ``t`` [B]: the leaves the scan only READS, and the
+        leaves it carries and updates. The decode programs that hold the
+        carry once (``generate._decode_scan_donated_jit``) give
+        :meth:`decode_step` the two merged and keep what it returns under
+        the carried names; XLA copies a scan's carry at its entry, which a
+        KV cache of GBs cannot afford, and does not copy what the scan
+        closes over. Default: everything is carried."""
+        return {}, state
+
+    @staticmethod
+    def chunk_merge(
+        cfg: ModelConfig, layer_type: str, held: State, carried: State,
+        live: Array,
+    ) -> State:
+        """The state after the scan, from :meth:`chunk_split`'s two parts;
+        rows outside ``live`` [B] keep their held leaves' bits."""
+        return carried
 
     def _train_only(self):
         raise NotImplementedError(
-            f"layer type {self.layer_type!r} has a training forward only: "
-            "prefill / decode state for it is not built"
+            f"layer type {self.layer_type!r} does not build this serving "
+            "entry point: gated_softmax has a training forward only; "
+            "gated_delta serves (prefill, its pieces, the decode step) but "
+            "has no speculative verify_extend / advance_verified"
         )
 
     def prefill(
@@ -247,6 +273,10 @@ class Mixer(nn.Module):
         self.wk = dense("wk", h * dh)
         self.wv = dense("wv", h * dh)
         self.wo = dense("wo", cfg.d_model)
+        assert cfg.qk_norm in ("none", "projection"), cfg.qk_norm
+        if cfg.qk_norm == "projection":
+            self.q_norm = nn.RMSNorm(dtype=_dtype(cfg.dtype), name="q_norm")
+            self.k_norm = nn.RMSNorm(dtype=_dtype(cfg.dtype), name="k_norm")
 
     def _heads(self, x: Array) -> Tuple[Array, Array, Array]:
         """x [..., T, D] (or [..., D]) -> q,k,v [..., H, T, Dh] ([..., H, Dh])."""
@@ -254,6 +284,8 @@ class Mixer(nn.Module):
         h, dh = cfg.n_heads, cfg.resolved_head_dim
         single = x.ndim == 2  # decode: [B, D]
         q, k, v = self.wq(x), self.wk(x), self.wv(x)
+        if cfg.qk_norm == "projection":  # over all heads' columns at once
+            q, k = self.q_norm(q), self.k_norm(k)
 
         def split(y):
             if single:

@@ -3,31 +3,44 @@ write strength and a decay from another; q, k and v pass a causal depthwise
 convolution + SiLU; q and k are l2-normalised and each key head serves
 ``value_heads / key_heads`` value heads; the gated delta rule
 (``ops/gated_delta.py``) mixes along time; the output is RMS-normalised per
-head, gated by ``silu(z)`` and projected back.
+head, gated by ``silu(z)`` and projected back. ``beta = sigmoid(b)``, or
+``2 sigmoid(b)`` under ``cfg.gdn_allow_neg_eigval``.
 
-A training forward only: a recurrent ``[Hv, dk, dv]`` state and the conv's
-last inputs in the slot carry are serving work not done yet (PERF.md s7), so
-the serving entry points are the base class's, which raise. The plain
-reference it is tested against is ``benchmark/reference/plain_gdn_moe.py``,
-which reads the same parameter layout: ``in_qkvz`` columns are
-``[q | k | v | z]`` (key_heads x key_dim, the same, value_heads x value_dim
-twice), ``in_ba`` columns ``[b | a]``, ``conv`` is ``[width, channels]``
-over the ``[q | k | v]`` channels with row ``width - 1`` on the current
-token.
+Served, the decode state is ``{"s": [B, Hv, dk, dv] fp32, "conv": [B, (W - 1)
+x channels]}``: the rule's state and the conv's last ``W - 1`` PRE-conv
+``[q | k | v]`` rows, oldest first, side by side (a ``[B, W - 1, channels]``
+array would put 3 rows on tiles of 8 or 16: 43x its size on the chip); a
+piece takes them at its real ``length``. A padded
+piece masks its pad rows to k = v = beta = g = 0, which passes the state
+through. The one-token step is ``ops.dispatch.gated_delta_step`` (under a
+Pallas backend the row-sparse in-place kernel, hence ``rows_in_place``; the
+conv tail of an unlisted row is selected back, 3 rows of channels).
+Speculative decode (``verify_extend`` / ``advance_verified``) is not built
+for this mixer: the base class's raise.
+
+The plain references it is tested against are ``benchmark/reference/
+plain_gdn_moe.py`` and ``plain_olmo_hybrid.py``, which read the same
+parameter layout: ``in_qkvz`` columns are ``[q | k | v | z]`` (key_heads x
+key_dim, the same, value_heads x value_dim twice), ``in_ba`` columns
+``[b | a]``, ``conv`` is ``[width, channels]`` over the ``[q | k | v]``
+channels with row ``width - 1`` on the current token.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from orion_tpu.models.configs import ModelConfig
 from orion_tpu.models.mixers import (
-    NORM_EPS, Mixer, _dense_factory, _dtype, _rms, kernel_bh,
+    NORM_EPS, Mixer, State, _dense_factory, _dtype, _rms, kernel_bh,
 )
-from orion_tpu.ops.dispatch import gated_delta_rule
+from orion_tpu.ops.dispatch import (
+    decode_rows_mask, gated_delta_rule, gated_delta_step,
+)
 from orion_tpu.ops.gated_delta import causal_short_conv
 from orion_tpu.utils.profiling import scope
 
@@ -39,58 +52,177 @@ def _l2norm(x: Array) -> Array:
     return xf * jax.lax.rsqrt(jnp.sum(jnp.square(xf), -1, keepdims=True) + NORM_EPS)
 
 
+def _widths(cfg: ModelConfig) -> Tuple[int, int, int, int]:
+    hk, hv = cfg.gdn_key_heads, cfg.gdn_value_heads
+    dk, dv = cfg.gdn_key_dim, cfg.gdn_value_dim
+    assert hk > 0 and hv % hk == 0 and dk > 0 and dv > 0, (hk, hv, dk, dv)
+    return hk, hv, dk, dv
+
+
 class GatedDeltaNet(Mixer):
     layer_type: str = "gated_delta"
 
-    @nn.compact
-    def __call__(self, x: Array, mask: Optional[Array] = None) -> Array:
-        assert mask is None and self.causal, "gated_delta is causal-LM only"
-        assert not self.sp_local and not self.quant, (self.sp_local, self.quant)
+    rows_in_place = True
+
+    def setup(self):
         cfg = self.cfg
-        dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
-        hk, hv = cfg.gdn_key_heads, cfg.gdn_value_heads
-        dk, dv = cfg.gdn_key_dim, cfg.gdn_value_dim
-        assert hk > 0 and hv % hk == 0 and dk > 0 and dv > 0, (hk, hv, dk, dv)
-        kd, vd = hk * dk, hv * dv
-        b, t, _ = x.shape
+        assert self.causal, "gated_delta is causal-LM only"
+        assert not self.sp_local and not self.quant, (self.sp_local, self.quant)
+        pdt = _dtype(cfg.param_dtype)
+        hk, hv, dk, dv = _widths(cfg)
         dense = _dense_factory(cfg)
+        self.in_qkvz = dense("in_qkvz", 2 * hk * dk + 2 * hv * dv)
+        self.in_ba = dense("in_ba", 2 * hv)
+        self.conv = self.param(
+            "conv",
+            nn.initializers.variance_scaling(1.0, "fan_in", "uniform", in_axis=0, out_axis=1),
+            (cfg.gdn_conv_width, 2 * hk * dk + hv * dv), pdt,
+        )
+        self.a_log = self.param(
+            "A_log",
+            lambda rng, shape: jnp.log(jax.random.uniform(rng, shape, minval=1.0, maxval=16.0)),
+            (hv,),
+        )
+        self.dt_bias = self.param("dt_bias", nn.initializers.ones_init(), (hv,))
+        self.out_norm = self.param("out_norm", nn.initializers.ones_init(), (dv,), pdt)
+        self.wo = dense("wo", cfg.d_model)
+
+    @staticmethod
+    def decode_state(
+        cfg: ModelConfig, layer_type: str, batch: int, dtype: Any
+    ) -> State:
+        hk, hv, dk, dv = _widths(cfg)
+        return {
+            "s": jnp.zeros((batch, hv, dk, dv), jnp.float32),
+            "conv": jnp.zeros(
+                (batch, (cfg.gdn_conv_width - 1) * (2 * hk * dk + hv * dv)), dtype
+            ),
+        }
+
+    # -- what every entry point shares ---------------------------------------
+
+    def _project(self, x: Array) -> Tuple[Array, Array, Array]:
+        """x [..., D] -> (pre-conv [q | k | v] channels, z, [b | a] fp32)."""
+        hk, hv, dk, dv = _widths(self.cfg)
+        p = self.in_qkvz(x)
+        c = 2 * hk * dk + hv * dv
+        return p[..., :c], p[..., c:], self.in_ba(x).astype(jnp.float32)
+
+    def _operands(self, qkv: Array, ba: Array):
+        """Post-conv channels [..., C] and [b | a] [..., 2 Hv] -> q, k
+        [..., Hk, dk], v [..., Hv, dv] in the compute dtype, beta, g
+        [..., Hv] fp32."""
+        cfg = self.cfg
+        dt = _dtype(cfg.dtype)
+        hk, hv, dk, dv = _widths(cfg)
+        kd, lead = hk * dk, qkv.shape[:-1]
+        q = qkv[..., :kd].reshape(lead + (hk, dk))
+        k = qkv[..., kd: 2 * kd].reshape(lead + (hk, dk))
+        v = qkv[..., 2 * kd:].reshape(lead + (hv, dv))
+        beta = jax.nn.sigmoid(ba[..., :hv])  # fp32
+        if cfg.gdn_allow_neg_eigval:
+            beta = 2.0 * beta
+        g = -jnp.exp(self.a_log.astype(jnp.float32)) * jax.nn.softplus(
+            ba[..., hv:] + self.dt_bias.astype(jnp.float32)
+        )
+        return (_l2norm(q) * dk ** -0.5).astype(dt), _l2norm(k).astype(dt), v, beta, g
+
+    def _output(self, o: Array, z: Array) -> Array:
+        """o [..., Hv, dv], z [..., Hv dv] -> the layer's output [..., D]."""
+        o = _rms(o) * self.out_norm.astype(jnp.float32)
+        o = o * jax.nn.silu(z.reshape(o.shape).astype(jnp.float32))
+        return self.wo(o.reshape(z.shape).astype(_dtype(self.cfg.dtype)))
+
+    def _rule(self, q, k, v, beta, g, **state):
+        """The rule over time on [B, T, H, ...] operands -> o [B, T, Hv, dv]
+        (and the final state with ``return_state``)."""
+        cfg = self.cfg
+        heads_first = lambda y: jnp.swapaxes(y, 1, 2)  # noqa: E731
+        # key head j serves value heads j * (hv / hk) ... + hv / hk - 1:
+        # the op repeats q and k, or its kernel reads them in place. Its
+        # chunking is its own: cfg.chunk is linear attention's knob
+        out = kernel_bh(
+            cfg, self.mesh,
+            lambda *a: gated_delta_rule(*a, backend=cfg.backend, **state),
+            *(heads_first(y) for y in (q, k, v, beta, g)),
+        )
+        if state:
+            return heads_first(out[0]), out[1]
+        return heads_first(out)  # [B, T, Hv, Dv]
+
+    # -- parallel forward ---------------------------------------------------
+
+    def __call__(self, x: Array, mask: Optional[Array] = None) -> Array:
+        assert mask is None, "gated_delta is causal-LM only"
         with scope("gated_delta"):
-            p = dense("in_qkvz", 2 * kd + 2 * vd)(x)
-            qkv, z = p[..., : 2 * kd + vd], p[..., 2 * kd + vd:]
-            ba = dense("in_ba", 2 * hv)(x).astype(jnp.float32)
-            conv = self.param(
-                "conv",
-                nn.initializers.variance_scaling(1.0, "fan_in", "uniform", in_axis=0, out_axis=1),
-                (cfg.gdn_conv_width, 2 * kd + vd), pdt,
-            )
-            a_log = self.param(
-                "A_log",
-                lambda rng, shape: jnp.log(jax.random.uniform(rng, shape, minval=1.0, maxval=16.0)),
-                (hv,),
-            )
-            dt_bias = self.param("dt_bias", nn.initializers.ones_init(), (hv,))
+            pre, z, ba = self._project(x)
             with scope("short_conv"):
-                qkv = causal_short_conv(qkv, conv)
-            q = qkv[..., :kd].reshape(b, t, hk, dk)
-            k = qkv[..., kd: 2 * kd].reshape(b, t, hk, dk)
-            v = qkv[..., 2 * kd:].reshape(b, t, hv, dv)
-            beta = jax.nn.sigmoid(ba[..., :hv])  # [B, T, Hv] fp32
-            g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
-                ba[..., hv:] + dt_bias.astype(jnp.float32)
+                qkv = causal_short_conv(pre, self.conv)
+            return self._output(self._rule(*self._operands(qkv, ba)), z)
+
+    # -- prefill and its pieces -----------------------------------------------
+
+    def prefill(self, x: Array, length: Optional[Array] = None) -> Tuple[Array, State]:
+        zero = self.decode_state(self.cfg, self.layer_type, x.shape[0], x.dtype)
+        n = x.shape[1] if length is None else length
+        return self.prefill_extend(x, zero, 0, n)
+
+    def prefill_extend(
+        self, x: Array, state: State, offset: Array, length: Array
+    ) -> Tuple[Array, State]:
+        """The piece's conv reads the tail the pieces before left; pad rows
+        (``>= length``) leave the rule's state as it is; the new tail is the
+        last ``W - 1`` pre-conv rows before ``length`` (the old tail's, where
+        the piece is shorter than that)."""
+        del offset  # position enters through the state alone
+        w1 = self.cfg.gdn_conv_width - 1
+        with scope("gated_delta"):
+            pre, z, ba = self._project(x)
+            old = state["conv"].reshape(x.shape[0], w1, -1)
+            with scope("short_conv"):
+                qkv = causal_short_conv(pre, self.conv, tail=old)
+            q, k, v, beta, g = self._operands(qkv, ba)
+            real = jnp.arange(x.shape[1]) < length  # [P]
+            pad0 = lambda y: jnp.where(  # noqa: E731
+                real.reshape((1, -1) + (1,) * (y.ndim - 2)), y, jnp.zeros_like(y)
             )
-            q = (_l2norm(q) * dk ** -0.5).astype(dt)
-            k = _l2norm(k).astype(dt)
-            heads_first = lambda y: jnp.swapaxes(y, 1, 2)  # noqa: E731
-            # key head j serves value heads j * (hv / hk) ... + hv / hk - 1:
-            # the op repeats q and k, or its kernel reads them in place. Its
-            # chunking is its own: cfg.chunk is linear attention's knob
-            o = kernel_bh(
-                cfg, self.mesh,
-                lambda *a: gated_delta_rule(*a, backend=cfg.backend),
-                *(heads_first(y) for y in (q, k, v, beta, g)),
-            )  # [B, Hv, T, Dv]
-            o = heads_first(o)  # [B, T, Hv, Dv]
-            w_n = self.param("out_norm", nn.initializers.ones_init(), (dv,), pdt)
-            o = _rms(o) * w_n.astype(jnp.float32)
-            o = o * jax.nn.silu(z.reshape(b, t, hv, dv).astype(jnp.float32))
-            return dense("wo", cfg.d_model)(o.reshape(b, t, vd).astype(dt))
+            o, s = self._rule(
+                q, pad0(k), pad0(v), pad0(beta), pad0(g),
+                initial_state=state["s"], return_state=True,
+            )
+            seen = jnp.concatenate([old, pre.astype(old.dtype)], axis=1)
+            tail = jax.lax.dynamic_slice_in_dim(seen, length, w1, axis=1)
+            return self._output(o, z), {"s": s, "conv": tail.reshape(state["conv"].shape)}
+
+    # -- one-token decode ---------------------------------------------------
+
+    def decode_step(
+        self, x: Array, state: State, t: Array, rows: Optional[Any] = None
+    ) -> Tuple[Array, State]:
+        """Given ``rows``, under a Pallas backend only those rows' state is
+        stepped, in place; the others keep their ``s`` and their conv tail."""
+        with scope("gated_delta"):
+            pre, z, ba = self._project(x)  # [B, C]
+            c = pre.shape[-1]
+            seen = jnp.concatenate(
+                [state["conv"], pre.astype(state["conv"].dtype)], axis=1
+            )  # [B, W x C]: the window's rows side by side
+            with scope("short_conv"):
+                wf = self.conv.astype(jnp.float32)
+                y = sum(
+                    seen[:, j * c:(j + 1) * c].astype(jnp.float32) * wf[j]
+                    for j in range(wf.shape[0])
+                )
+                qkv = jax.nn.silu(y).astype(pre.dtype)
+            tail = seen[:, c:]
+            if rows is not None:
+                live = decode_rows_mask(rows, x.shape[0])
+                tail = jnp.where(live[:, None], tail, state["conv"])
+            q, k, v, beta, g = self._operands(qkv, ba)
+            group = v.shape[1] // q.shape[1]
+            if group > 1:
+                q, k = (jnp.repeat(y, group, axis=1) for y in (q, k))
+            o, s = gated_delta_step(
+                q, k, v, beta, g, state["s"], rows, backend=self.cfg.backend
+            )
+            return self._output(o, z), {"s": s, "conv": tail}

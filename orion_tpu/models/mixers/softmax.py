@@ -1,12 +1,19 @@
-"""``softmax`` and ``swa``: causal softmax attention with rotary positions,
-over the whole prefix or over a sliding window of ``cfg.window`` tokens.
+"""``softmax`` and ``swa``: causal softmax attention over the whole prefix
+or over a sliding window of ``cfg.window`` tokens; q and k are rotated by
+position unless ``cfg.rotary`` is off, and RMS-normalised over the whole
+projection first under ``cfg.qk_norm`` (``Mixer._heads``).
 The decode state is a KV cache ``{"k", "v"}`` of [B, H, cap, Dh] each:
 ``cap`` is ``max_seq_len`` rows written at their position, or, for the
-window, a ring of ``window`` rows written at position % window.
+window, a ring of ``window`` rows written at position % window. The
+slot-multiplexed decode step, given a row list, writes one cache row per
+LISTED sequence and nothing for the others (``rows_in_place``): a dense
+``[B, H, cap, Dh]`` select over the unlisted rows would read and write the
+whole cache a step.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional, Tuple
 
 import jax
@@ -15,9 +22,23 @@ import jax.numpy as jnp
 from orion_tpu.models.configs import ModelConfig
 from orion_tpu.models.mixers import Mixer, State
 from orion_tpu.ops.rotary import apply_rotary, apply_rotary_at, rotary_freqs
-from orion_tpu.ops.softmax_attention import cached_attention, softmax_attention
+from orion_tpu.ops.softmax_attention import (
+    _NEG, cached_attention, softmax_attention,
+)
+from orion_tpu.utils.profiling import scope
 
 Array = jax.Array
+
+
+def _scoped(method):
+    """Run a mixer method under the ``full_attention`` named scope."""
+
+    @functools.wraps(method)
+    def wrapper(self, *args, **kwargs):
+        with scope("full_attention"):
+            return method(self, *args, **kwargs)
+
+    return wrapper
 
 
 def _window(cfg: ModelConfig, layer_type: str) -> Optional[int]:
@@ -26,6 +47,8 @@ def _window(cfg: ModelConfig, layer_type: str) -> Optional[int]:
 
 class SoftmaxAttention(Mixer):
     layer_type: str = "softmax"
+
+    rows_in_place = True
 
     def setup(self):
         cfg = self.cfg
@@ -36,6 +59,12 @@ class SoftmaxAttention(Mixer):
     @property
     def window(self) -> Optional[int]:
         return _window(self.cfg, self.layer_type)
+
+    def _rot(self, x: Array, ang: Array) -> Array:
+        return apply_rotary(x, ang) if self.cfg.rotary else x
+
+    def _rot_at(self, x: Array, pos: Array) -> Array:
+        return apply_rotary_at(x, self.freqs, pos) if self.cfg.rotary else x
 
     @staticmethod
     def decode_state(
@@ -48,8 +77,48 @@ class SoftmaxAttention(Mixer):
             "v": jnp.zeros((batch, h, cap, dh), dtype),
         }
 
+    @staticmethod
+    def chunk_split(
+        cfg: ModelConfig, layer_type: str, state: State, n_steps: int, t: Array
+    ) -> Tuple[State, State]:
+        """The full cache is held (read-only in the scan); the scan carries
+        the chunk's own rows ``kn``, ``vn`` [B, H, n_steps, Dh] and the
+        positions ``t0`` it started at. A window's ring wraps inside a
+        chunk and is small: carried whole."""
+        if _window(cfg, layer_type) is not None:
+            return {}, state
+        b, h, _, dh = state["k"].shape
+        new = {
+            n + "n": jnp.zeros((b, h, n_steps, dh), state[n].dtype)
+            for n in ("k", "v")
+        }
+        return dict(state), {**new, "t0": t}
+
+    @staticmethod
+    def chunk_merge(
+        cfg: ModelConfig, layer_type: str, held: State, carried: State,
+        live: Array,
+    ) -> State:
+        """Each live row's chunk of new rows written into its cache at
+        ``t0``, one in-place slice update a row, outside any loop (a loop
+        that carried the cache would copy it at its entry)."""
+        if _window(cfg, layer_type) is not None:
+            return carried
+        out = {}
+        for n in ("k", "v"):
+            cache, new = held[n], carried[n + "n"]
+            for b in range(cache.shape[0]):
+                at = (b, 0, carried["t0"][b], 0)
+                old = jax.lax.dynamic_slice(cache, at, (1,) + new.shape[1:])
+                cache = jax.lax.dynamic_update_slice(
+                    cache, jnp.where(live[b], new[b][None], old), at
+                )
+            out[n] = cache
+        return out
+
     # -- parallel forward ---------------------------------------------------
 
+    @_scoped
     def __call__(self, x: Array, mask: Optional[Array] = None) -> Array:
         cfg = self.cfg
         q, k, v = self._heads(x)
@@ -64,8 +133,8 @@ class SoftmaxAttention(Mixer):
             ang = jax.lax.dynamic_slice_in_dim(self.freqs, i * t, t, axis=0)
         else:
             ang = self.freqs[:t]
-        q = apply_rotary(q, ang)
-        k = apply_rotary(k, ang)
+        q = self._rot(q, ang)
+        k = self._rot(k, ang)
         window = self.window
         # striped = the load-balanced ring (parallel/ring.py): full-
         # causal softmax only; swa keeps the contiguous ring (striping
@@ -138,6 +207,7 @@ class SoftmaxAttention(Mixer):
 
     # -- prefill: forward + decode state ------------------------------------
 
+    @_scoped
     def prefill(self, x: Array, length: Optional[Array] = None) -> Tuple[Array, State]:
         """With ``length``: the full cache needs no masking — the padded
         KV rows land at cache slots >= length, which decode never reads:
@@ -149,8 +219,8 @@ class SoftmaxAttention(Mixer):
         q, k, v = self._heads(x)
         t = x.shape[-2]
         ang = self.freqs[:t]
-        qr = apply_rotary(q, ang)
-        kr = apply_rotary(k, ang)
+        qr = self._rot(q, ang)
+        kr = self._rot(k, ang)
         if self.window is not None:
             out = self._kernel_bh(
                 lambda a, b, c: softmax_attention(
@@ -181,6 +251,7 @@ class SoftmaxAttention(Mixer):
 
     # -- chunked prefill: advance decode state by one prompt piece -----------
 
+    @_scoped
     def prefill_extend(
         self, x: Array, state: State, offset: Array, length: Array
     ) -> Tuple[Array, State]:
@@ -206,8 +277,8 @@ class SoftmaxAttention(Mixer):
         # always sit at in-range positions
         pos = jnp.clip(offset + jnp.arange(p), 0, self.freqs.shape[0] - 1)
         ang = jnp.take(self.freqs, pos, axis=0)
-        qr = apply_rotary(q, ang)
-        kr = apply_rotary(k, ang)
+        qr = self._rot(q, ang)
+        kr = self._rot(k, ang)
         if self.window is not None:
             out, new_state = self._swa_extend(
                 qr, kr, v, state, offset, length, self.window
@@ -284,8 +355,8 @@ class SoftmaxAttention(Mixer):
             kc, vc, tj = carry
             qj, kj, vj = qkv
             # the decode_step per-seq path, one token at a time
-            qr = apply_rotary_at(qj, self.freqs, tj[:, None])
-            kr = apply_rotary_at(kj, self.freqs, tj[:, None])
+            qr = self._rot_at(qj, tj[:, None])
+            kr = self._rot_at(kj, tj[:, None])
             slot = tj % cap if self.window is not None else tj
             kc = kc.at[b_idx, :, slot, :].set(kr.astype(kc.dtype))
             vc = vc.at[b_idx, :, slot, :].set(vj.astype(vc.dtype))
@@ -334,6 +405,22 @@ class SoftmaxAttention(Mixer):
 
     # -- one-token decode ---------------------------------------------------
 
+    def _chunk_local_step(self, qr, kr, v, state, t):
+        """The decode step inside a scan that holds the cache read-only
+        (:meth:`chunk_split`): this token's k and v go to row ``t - t0`` of
+        the chunk's own rows. A sequence that is not emitting holds its
+        ``t``, so it rewrites one row of its ``kn`` / ``vn``, which
+        :meth:`chunk_merge` then never reads."""
+        b_idx = jnp.arange(qr.shape[0])
+        j = t - state["t0"]
+        new = dict(
+            state,
+            kn=state["kn"].at[b_idx, :, j, :].set(kr.astype(state["kn"].dtype)),
+            vn=state["vn"].at[b_idx, :, j, :].set(v.astype(state["vn"].dtype)),
+        )
+        return _chunk_local_attention(qr, new, t), new
+
+    @_scoped
     def decode_step(
         self, x: Array, state: State, t: Array, rows: Optional[Any] = None
     ) -> Tuple[Array, State]:
@@ -343,11 +430,36 @@ class SoftmaxAttention(Mixer):
         # per-seq positions: angles gather [B, 1, Dh/2] broadcasts over
         # heads the way the scalar gather's [Dh/2] row does
         pos = t[:, None] if per_seq else t
-        qr = apply_rotary_at(q, self.freqs, pos)
-        kr = apply_rotary_at(k, self.freqs, pos)
+        qr = self._rot_at(q, pos)
+        kr = self._rot_at(k, pos)
         cap = state["k"].shape[-2]  # window W or max_seq_len
+        if "kn" in state:
+            out, new = self._chunk_local_step(qr, kr, v, state, t)
+            return self._merge(out, single=True), new
         slot = t % cap if self.window is not None else t
-        if per_seq:
+        if per_seq and rows is not None:
+            # one cache row per LISTED sequence, each an in-place slice
+            # update at its own slot; an unlisted sequence writes nothing
+            # (rows_in_place). Not a scatter: the TPU compiler lays a
+            # scattered cache out heads-minor, and converting the carried
+            # [B, H, cap, Dh] buffers to that costs a second copy of them
+            idx, count = rows
+
+            def write(i, caches):
+                b = idx[i]
+                return tuple(
+                    jax.lax.dynamic_update_slice(
+                        c, new[b][None, :, None, :].astype(c.dtype),
+                        (b, 0, slot[b], 0),
+                    )
+                    for c, new in zip(caches, (kr, v))
+                )
+
+            kc, vc = jax.lax.fori_loop(
+                0, count[0], write, (state["k"], state["v"])
+            )
+            valid = jnp.arange(cap)[None, None, :] <= t[:, None, None]
+        elif per_seq:
             # one scatter row per sequence at its own slot
             b_idx = jnp.arange(x.shape[0])
             kc = state["k"].at[b_idx, :, slot, :].set(
@@ -371,6 +483,24 @@ class SoftmaxAttention(Mixer):
             valid = (jnp.arange(cap) <= t)[None, None, :]
         out = cached_attention(qr, kc, vc, valid)
         return self._merge(out, single=True), {"k": kc, "v": vc}
+
+
+def _chunk_local_attention(q, state, t):
+    """One query per sequence over the held cache's rows before ``t0`` and
+    the chunk's own rows up to ``t - t0``: the softmax of
+    :func:`cached_attention` over the two key sets side by side."""
+    f32 = jnp.float32
+    j = t - state["t0"]  # [B]: this step's row in the chunk
+    qf = q.astype(f32) * q.shape[-1] ** -0.5
+    old = jnp.einsum("bhd,bhsd->bhs", qf, state["k"].astype(f32))
+    new = jnp.einsum("bhd,bhsd->bhs", qf, state["kn"].astype(f32))
+    cap, n = old.shape[-1], new.shape[-1]
+    old = jnp.where(jnp.arange(cap)[None, None] < state["t0"][:, None, None], old, _NEG)
+    new = jnp.where(jnp.arange(n)[None, None] <= j[:, None, None], new, _NEG)
+    p = jax.nn.softmax(jnp.concatenate([old, new], axis=-1), axis=-1)
+    out = jnp.einsum("bhs,bhsd->bhd", p[..., :cap], state["v"].astype(f32))
+    out = out + jnp.einsum("bhs,bhsd->bhd", p[..., cap:], state["vn"].astype(f32))
+    return out.astype(q.dtype)
 
 
 def _window_write(

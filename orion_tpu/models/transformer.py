@@ -69,8 +69,9 @@ class MLP(nn.Module):
 
 
 class Block(nn.Module):
-    """Pre-norm residual block: x + attn(norm(x)); x + mlp(norm(x)), where
-    ``attn`` is the layer type's token mixer (models/mixers/).
+    """Residual block: x + attn(norm(x)); x + mlp(norm(x)) (pre-norm), or
+    with ``cfg.norm_placement == "post"`` x + norm(attn(x)); x +
+    norm(mlp(x)); ``attn`` is the layer type's token mixer (models/mixers/).
 
     ``use_moe`` swaps the dense MLP for the routed-expert MoEMLP
     (models/moe.py, ep-sharded); same name "mlp" so one sharding rule set
@@ -105,36 +106,52 @@ class Block(nn.Module):
             )
         self.drop = nn.Dropout(self.cfg.dropout)
 
+    def _sublayer(self, norm, f, x):
+        """``f(norm(x))``, or ``norm(f(x))`` where the configuration
+        normalises a sublayer's output; ``f`` may return ``(y, extra)``."""
+        if self.cfg.norm_placement == "pre":
+            return f(norm(x))
+        assert self.cfg.norm_placement == "post", self.cfg.norm_placement
+        y = f(x)
+        return (norm(y[0]),) + tuple(y[1:]) if isinstance(y, tuple) else norm(y)
+
+    def _mlp_residual(self, x):
+        return x + self._sublayer(self.norm2, self.mlp, x)
+
     def __call__(self, x, mask=None, deterministic=True):
-        x = x + self.drop(self.attn(self.norm1(x), mask), deterministic=deterministic)
-        x = x + self.drop(self.mlp(self.norm2(x)), deterministic=deterministic)
+        x = x + self.drop(
+            self._sublayer(self.norm1, lambda y: self.attn(y, mask), x),
+            deterministic=deterministic,
+        )
+        x = x + self.drop(
+            self._sublayer(self.norm2, self.mlp, x), deterministic=deterministic
+        )
         return x
 
     def prefill(self, x, length=None):
-        h, state = self.attn.prefill(self.norm1(x), length)
-        x = x + h
-        x = x + self.mlp(self.norm2(x))
-        return x, state
+        h, state = self._sublayer(
+            self.norm1, lambda y: self.attn.prefill(y, length), x
+        )
+        return self._mlp_residual(x + h), state
 
     def prefill_extend(self, x, state, offset, length):
-        h, state = self.attn.prefill_extend(
-            self.norm1(x), state, offset, length
+        h, state = self._sublayer(
+            self.norm1,
+            lambda y: self.attn.prefill_extend(y, state, offset, length), x,
         )
-        x = x + h
-        x = x + self.mlp(self.norm2(x))
-        return x, state
+        return self._mlp_residual(x + h), state
 
     def decode_step(self, x, state, t, rows=None):
-        h, state = self.attn.decode_step(self.norm1(x), state, t, rows)
-        x = x + h
-        x = x + self.mlp(self.norm2(x))
-        return x, state
+        h, state = self._sublayer(
+            self.norm1, lambda y: self.attn.decode_step(y, state, t, rows), x
+        )
+        return self._mlp_residual(x + h), state
 
     def verify_extend(self, x, state, t):
-        h, upd = self.attn.verify_extend(self.norm1(x), state, t)
-        x = x + h
-        x = x + self.mlp(self.norm2(x))
-        return x, upd
+        h, upd = self._sublayer(
+            self.norm1, lambda y: self.attn.verify_extend(y, state, t), x
+        )
+        return self._mlp_residual(x + h), upd
 
 
 class TransformerLM(nn.Module):

@@ -6,15 +6,16 @@ backend='xla' dispatch"). "auto" picks Pallas on TPU and the pure-XLA
 chunked scan elsewhere (CPU/GPU and unit tests). The Pallas kernel can also
 run anywhere via interpret mode (used by the parity tests).
 
-Three ops dispatch here: ``gated_delta_rule`` (the gated delta-rule
-layers' parallel forward: under Pallas the chunked form as Mosaic kernels,
-forward and backward, ``ops/pallas/gated_delta.py``; the same equations as
-XLA fusions and a scan otherwise), ``causal_dot_product`` (the parallel
-forward: training, prefill) and ``decode_state_step`` (the
-slot-multiplexed decode programs' ``(S, z)`` step: under Pallas a
-row-sparse in-place kernel that touches only the rows live in the chunk,
-``ops/pallas/decode_state.py``; ``recurrent_step`` over every row
-otherwise).
+Four ops dispatch here: ``gated_delta_rule`` (the gated delta-rule
+layers' parallel forward, with or without a state carried in and out:
+under Pallas the chunked form as Mosaic kernels, forward and backward,
+``ops/pallas/gated_delta.py``; the same equations as XLA fusions and a scan
+otherwise), ``causal_dot_product`` (the parallel forward: training,
+prefill), and the slot-multiplexed decode programs' two state steps,
+``decode_state_step`` (the linear layers' ``(S, z)``) and
+``gated_delta_step`` (the delta rule's ``S``): under Pallas row-sparse
+in-place kernels that touch only the rows live in the chunk
+(``ops/pallas/decode_state.py``), every row in XLA otherwise.
 """
 
 from __future__ import annotations
@@ -117,27 +118,37 @@ def causal_dot_product(
     )
 
 
-def gated_delta_rule(q, k, v, beta, g, *, backend: str = "auto"):
+def gated_delta_rule(
+    q, k, v, beta, g, *, backend: str = "auto", initial_state=None,
+    return_state: bool = False,
+):
     """Dispatch the gated delta rule (``ops/gated_delta.py``) on q, k
     ``[..., Hk, T, Dk]``, v ``[..., Hv, T, Dv]``, beta, g ``[..., Hv, T]``;
     key head ``j`` serves value heads ``j * (Hv / Hk) ...``.
+    ``initial_state`` ``[..., Hv, Dk, Dv]`` is the state the sequence starts
+    from (zeros if None); with ``return_state`` the result is ``(out, final
+    state)``, the state in fp32 (serving's prefill and its pieces).
 
     ``eager`` is the token-by-token recurrence. ``pallas`` and
     ``pallas_interpret`` run the chunked WY form as Mosaic kernels
     (``ops/pallas/gated_delta.py``: forward and backward, its own tiling,
-    the key heads read in place). ``xla`` — the CPU, and on the chip any
-    width the kernels do not take (Dk or Dv off a multiple of 128) — runs
-    the same form as XLA fusions and a scan, a block of batch rows at a
-    time in chunks of 64, on key heads repeated to the value heads."""
+    the key heads read in place; the forward with a state in or out zero-
+    pads widths to whole lane tiles, the backward needs them whole). ``xla``
+    (the CPU, and on the chip a training width the kernels do not take)
+    runs the same form as XLA fusions and a scan in chunks of 64, on key
+    heads repeated to the value heads; training, a block of batch rows at a
+    time."""
     from orion_tpu.ops import gated_delta as gd
 
     b = resolve(backend)
+    stateful = initial_state is not None or return_state
+    state = dict(initial_state=initial_state, return_state=return_state) if stateful else {}
     if b.startswith("pallas"):
         from orion_tpu.ops.pallas import gated_delta as pgd
 
-        if b == "pallas_interpret" or pgd.supports(q.shape[-1], v.shape[-1]):
+        if b == "pallas_interpret" or stateful or pgd.supports(q.shape[-1], v.shape[-1]):
             return pgd.gated_delta_rule_pallas(
-                q, k, v, beta, g, interpret=(b == "pallas_interpret")
+                q, k, v, beta, g, interpret=(b == "pallas_interpret"), **state
             )
     group = v.shape[-3] // q.shape[-3]
     if group > 1:
@@ -145,7 +156,9 @@ def gated_delta_rule(q, k, v, beta, g, *, backend: str = "auto"):
 
         q, k = (jnp.repeat(y, group, axis=-3) for y in (q, k))
     if b == "eager":
-        return gd.gated_delta_recurrent(q, k, v, beta, g)
+        return gd.gated_delta_recurrent(q, k, v, beta, g, **state)
+    if stateful:
+        return gd.gated_delta_chunked(q, k, v, beta, g, **state)
     return gd.gated_delta_by_rows(q, k, v, beta, g)
 
 
@@ -164,6 +177,34 @@ def decode_live_rows(mask, *, backend: str = "auto"):
     from orion_tpu.ops.pallas.decode_state import live_rows
 
     return live_rows(mask)
+
+
+def decode_rows_mask(rows, batch: int):
+    """``[batch]`` bool: the rows a :func:`decode_live_rows` list names."""
+    import jax.numpy as jnp
+
+    idx, count = rows
+    listed = jnp.where(jnp.arange(idx.shape[0]) < count[0], idx, batch)
+    return jnp.zeros((batch,), bool).at[listed].set(True, mode="drop")
+
+
+def gated_delta_step(q, k, v, beta, g, state, rows=None, *, backend: str = "auto"):
+    """One decode step of the gated delta rule's state ``S [B, H, Dk, Dv]``
+    (fp32): q, k ``[B, H, Dk]``, v ``[B, H, Dv]``, beta, g ``[B, H]`` ->
+    ``(out [B, H, Dv], S)``. ``rows`` as for :func:`decode_state_step`:
+    with a row list under a Pallas backend only the listed rows are read,
+    updated and written, in place (``ops/pallas/decode_state.py``);
+    otherwise every row steps (``ops/gated_delta.py::gated_delta_step``)."""
+    if rows is not None and _row_sparse(backend):
+        from orion_tpu.ops.pallas import decode_state as pds
+
+        return pds.gated_delta_step(
+            q, k, v, beta, g, state, rows,
+            interpret=(resolve(backend) == "pallas_interpret"),
+        )
+    from orion_tpu.ops.gated_delta import gated_delta_step as step
+
+    return step(q, k, v, beta, g, state)
 
 
 def decode_state_step(q, k, v, state, rows=None, *, backend: str = "auto"):
@@ -189,7 +230,9 @@ def decode_state_step(q, k, v, state, rows=None, *, backend: str = "auto"):
 __all__ = [
     "causal_dot_product",
     "decode_live_rows",
+    "decode_rows_mask",
     "decode_state_step",
+    "gated_delta_step",
     "default_backend",
     "gated_delta_rule",
     "resolve",

@@ -31,8 +31,19 @@ backward (``ops/pallas/gated_delta.py``), which keep a chunk's ``A``, ``T``
 and ``S`` in VMEM and take the whole batch at once. This file is their
 specification and the parity form of their tests.
 
+3. ``gated_delta_step`` — one token of the recurrence on a decode state
+   ``S [B, H, Dk, Dv]`` (serving's decode step): the XLA form, every row;
+   under a Pallas backend ``ops/dispatch.py::gated_delta_step`` runs the
+   row-sparse in-place kernel (``ops/pallas/decode_state.py``) instead.
+
+The chunked forms take an ``initial_state`` and return the final one
+(``return_state``): a prompt consumed in pieces carries ``S`` from piece to
+piece, and a piece boundary on a multiple of the chunk replays the
+monolithic pass's op sequence.
+
 ``causal_short_conv`` is the depthwise causal convolution (+ SiLU) that
-feeds the layer's q, k and v.
+feeds the layer's q, k and v; with ``tail`` it continues a sequence whose
+last ``W - 1`` inputs the caller kept.
 
 Conventions: q, k ``[..., T, Dk]``; v ``[..., T, Dv]``; beta, g
 ``[..., T]``. Matmul operands stay in the input dtype with fp32
@@ -249,12 +260,29 @@ def gated_delta_by_rows(q, k, v, beta, g, *, chunk: int = DEFAULT_CHUNK):
     return out.reshape((b,) + out.shape[2:])
 
 
-def causal_short_conv(x: Array, w: Array, activation: bool = True) -> Array:
-    """Depthwise causal convolution over time, left zero padding, no bias:
-    ``y_t = sum_j w[j] * x_{t - (W - 1) + j}`` (``w[W - 1]`` weighs the
-    current token), then SiLU. x ``[..., T, C]``, w ``[W, C]``."""
+def gated_delta_step(q: Array, k: Array, v: Array, beta: Array, g: Array, s: Array):
+    """One token of the recurrence for every row, fp32: q, k ``[B, H, Dk]``,
+    v ``[B, H, Dv]``, beta, g ``[B, H]``, ``s [B, H, Dk, Dv]`` fp32 ->
+    ``(o [B, H, Dv] in v's dtype, s)``."""
+    qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
+    s = s * jnp.exp(g.astype(jnp.float32))[..., None, None]
+    u = beta.astype(jnp.float32)[..., None] * (vf - jnp.sum(s * kf[..., None], axis=-2))
+    s = s + kf[..., :, None] * u[..., None, :]
+    return jnp.sum(s * qf[..., None], axis=-2).astype(v.dtype), s
+
+
+def causal_short_conv(
+    x: Array, w: Array, activation: bool = True, tail: Optional[Array] = None
+) -> Array:
+    """Depthwise causal convolution over time, no bias: ``y_t = sum_j w[j] *
+    x_{t - (W - 1) + j}`` (``w[W - 1]`` weighs the current token), then
+    SiLU. x ``[..., T, C]``, w ``[W, C]``. What precedes ``x`` is zeros, or
+    ``tail [..., W - 1, C]``: the inputs just before it."""
     width, t = w.shape[0], x.shape[-2]
-    xp = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(width - 1, 0), (0, 0)])
+    if tail is None:
+        xp = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(width - 1, 0), (0, 0)])
+    else:
+        xp = jnp.concatenate([tail.astype(x.dtype), x], axis=-2)
     wf = w.astype(jnp.float32)
     y = sum(
         jax.lax.slice_in_dim(xp, j, j + t, axis=-2).astype(jnp.float32) * wf[j]
@@ -269,4 +297,5 @@ __all__ = [
     "gated_delta_by_rows",
     "gated_delta_chunked",
     "gated_delta_recurrent",
+    "gated_delta_step",
 ]

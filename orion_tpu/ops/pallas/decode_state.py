@@ -27,6 +27,17 @@ on the VPU (no MXU: a rank-1 update and a matvec per head move 2 MiB for
 ~1 MFLOP). Only the reduction ORDER of ``q . S`` differs from XLA's
 einsum, so results agree to fp32 rounding, not bitwise.
 
+``gated_delta_step`` is the same walk for the gated delta rule's state
+(``ops/gated_delta.py``): per live row and head, in fp32,
+
+    S <- e^g S;  u = beta (v - S^T k);  S <- S + k (x) u;  out = S^T q
+
+with ``S [B, H, Dk, Dv]`` aliased in place and the output aliased onto
+``v``. The decay and the write strength arrive broadcast to the widths they
+multiply (``e^g`` as ``[B, H, Dk]``, ``beta`` as ``[B, H, Dv]``: a few KB a
+row), so every block is one whole row and nothing in the kernel moves a
+per-head scalar between lanes and sublanes.
+
 reference: none (the reference's decode is a Python loop over
 ``recurrent_step``; checkout never mounted, SURVEY.md s0).
 """
@@ -140,4 +151,74 @@ def decode_state_step(
     return out, (s, z)
 
 
-__all__ = ["decode_state_step", "live_rows"]
+def check_delta_operands(q, k, v, beta, g, s, idx) -> None:
+    """As :func:`check_operands`: every block is one whole row, so nothing
+    has to divide anything; the state is fp32 and one row count runs
+    through every operand (the kernel casts q, k, v up itself)."""
+    if s.dtype != jnp.float32:
+        raise ValueError(f"decode state must be float32, got {s.dtype}")
+    b, h, dk, dv = s.shape
+    shapes = (q.shape, k.shape, v.shape, beta.shape, g.shape, idx.shape)
+    if shapes != ((b, h, dk), (b, h, dk), (b, h, dv), (b, h), (b, h), (b,)):
+        raise ValueError(f"operands do not fit S {s.shape}: {shapes}")
+
+
+def _delta_kernel(rows_ref, s_ref, q_ref, k_ref, eg_ref, v_ref, b_ref, s_out, o_ref):
+    del rows_ref  # consumed by the index maps
+    k = k_ref[0]  # [H, Dk]
+    s = s_ref[0] * eg_ref[0][:, :, None]
+    u = b_ref[0] * (v_ref[0] - jnp.sum(s * k[:, :, None], axis=1))  # [H, Dv]
+    s = s + k[:, :, None] * u[:, None, :]
+    s_out[0] = s
+    o_ref[0] = jnp.sum(s * q_ref[0][:, :, None], axis=1)
+
+
+def gated_delta_step(
+    q: Array, k: Array, v: Array, beta: Array, g: Array, s: Array,
+    rows: Tuple[Array, Array], *, interpret: bool = False,
+) -> Tuple[Array, Array]:
+    """``ops.gated_delta.gated_delta_step`` for the rows ``rows`` lists.
+
+    q, k: [B, H, Dk]; v: [B, H, Dv]; beta, g: [B, H]; ``s`` [B, H, Dk, Dv]
+    fp32; rows = :func:`live_rows` of the row mask. Returns (out [B, H, Dv]
+    in v's dtype, s): listed rows updated, every other row of ``s`` bitwise
+    the input's (never touched) and of ``out`` its ``v`` row."""
+    idx, count = rows
+    check_delta_operands(q, k, v, beta, g, s, idx)
+    b, h, dk, dv = s.shape
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    eg = jnp.broadcast_to(jnp.exp(f32(g))[..., None], (b, h, dk))
+    bv = jnp.broadcast_to(f32(beta)[..., None], (b, h, dv))
+    row3 = lambda i, rows: (rows[i], 0, 0)  # noqa: E731
+    row4 = lambda i, rows: (rows[i], 0, 0, 0)  # noqa: E731
+    key, val = pl.BlockSpec((1, h, dk), row3), pl.BlockSpec((1, h, dv), row3)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(count[0],),
+        in_specs=[pl.BlockSpec((1, h, dk, dv), row4), key, key, key, val, val],
+        out_specs=[pl.BlockSpec((1, h, dk, dv), row4), val],
+    )
+    s, out = pl.pallas_call(
+        _delta_kernel,
+        name="gated_delta_step",
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct(s.shape, s.dtype),
+            jax.ShapeDtypeStruct(v.shape, jnp.float32),
+        ],
+        # operand numbering counts the scalar-prefetch list: S and v are
+        # operands 1 and 5
+        input_output_aliases={1: 0, 5: 1},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_DELTA_VMEM_BYTES),
+        interpret=interpret,
+    )(idx, s, f32(q), f32(k), eg, f32(v), bv)
+    return out.astype(v.dtype), s
+
+
+# a row's S block at 30 heads x 96 x 192 is 2.2 MB (2.9 with its lanes padded
+# to whole tiles), double-buffered in and out, beside the kernel's own
+# intermediates of that size: past the compiler's default scoped limit
+_DELTA_VMEM_BYTES = 64 << 20
+
+
+__all__ = ["decode_state_step", "gated_delta_step", "live_rows"]

@@ -49,9 +49,17 @@ only ``o``.
   8 chunks a step against 4, 16.1 against 17.1), not a knob. T off a
   multiple of a block is zero-padded (k = v = beta = g = 0: the state passes
   through); a T shorter than a block is one smaller block. Compiled for a
-  TPU the kernels need Dk and Dv to be multiples of 128 (``supports``);
-  ``ops/dispatch.py`` sends other widths to the XLA form. Interpret mode
-  takes any width.
+  TPU the differentiable kernels need Dk and Dv to be multiples of 128
+  (``supports``); ``ops/dispatch.py`` sends a training call at other widths
+  to the XLA form. Interpret mode takes any width.
+- *A state in and out* (``gated_delta_fwd_state``, serving's prefill and its
+  pieces; forward only). The same block walk, with ``S`` loaded from
+  ``initial_state`` at a row's first block and written out after its last.
+  Widths off a multiple of 128 are zero-padded to the next one in the
+  wrapper (96 x 192 runs as 128 x 256): zero key columns add nothing to
+  ``k k^T`` or ``q k^T`` and their rows of ``S`` stay zero; zero value columns
+  stay zero in ``u``, ``S`` and ``o``. The call without a state is the
+  program it was: the training path compiles as before.
 """
 
 from __future__ import annotations
@@ -274,6 +282,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest):
             rest[1][0, tok, :], rest[2][0, tok, :] = t, u
 
 
+def _fwd_state_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, o_ref, sf_ref, s_scr):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        s_scr[:] = s0_ref[0]
+
+    toks, chunks = zip(*_chunks(q_ref, k_ref, v_ref, g_ref, b_ref))
+    s, outs = _block_fwd(chunks, s_scr[:])
+    s_scr[:] = s
+    sf_ref[0] = s  # the row's block stays resident: what its last step leaves is written back
+    for tok, (o, _, _) in zip(toks, outs):
+        o_ref[0, tok, :] = o.astype(o_ref.dtype)
+
+
 def _bwd_kernel(
     q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, t_ref, u_ref,
     dq_ref, dk_ref, dv_ref, dg_ref, db_ref, ds_scr, sin_scr,
@@ -346,6 +367,26 @@ def _forward(q, k, v, beta, gcum, save, interpret):
     return (outs[0], outs[1:]) if save else outs[0]
 
 
+def _forward_state(q, k, v, beta, gcum, s0, interpret):
+    """``(o, final state)`` from the state ``s0 [BH, Dk, Dv]`` fp32."""
+    bh, _, dv = v.shape
+    dk = q.shape[-1]
+    nblk = beta.shape[1]
+    qk_spec, v_spec, _, tok_spec, _ = _specs(bh // q.shape[0], beta, dk, dv, False)
+    s_spec = pl.BlockSpec((1, dk, dv), lambda b, c: (b, 0, 0), memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        _fwd_state_kernel,
+        name="gated_delta_fwd_state",
+        grid=(bh, nblk),
+        in_specs=[qk_spec, qk_spec, v_spec, tok_spec, tok_spec, s_spec],
+        out_specs=[v_spec, s_spec],
+        out_shape=[_sds(v.shape, v.dtype, v), _sds(s0.shape, _F32, v)],
+        scratch_shapes=[pltpu.VMEM((dk, dv), _F32)],
+        compiler_params=_PARAMS,
+        interpret=interpret,
+    )(q, k, v, gcum, beta, s0)
+
+
 def _backward(q, k, v, beta, gcum, do, saved, interpret):
     bh, tp, dv = v.shape
     dk = q.shape[-1]
@@ -400,12 +441,16 @@ _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
 def gated_delta_rule_pallas(
-    q: Array, k: Array, v: Array, beta: Array, g: Array, *, interpret: bool = False
-) -> Array:
+    q: Array, k: Array, v: Array, beta: Array, g: Array, *, interpret: bool = False,
+    initial_state=None, return_state: bool = False,
+):
     """The gated delta rule on q, k ``[..., Hk, T, Dk]``, v ``[..., Hv, T,
     Dv]``, beta, g ``[..., Hv, T]`` with ``Hv`` a multiple of ``Hk`` (value
-    head ``h`` reads key head ``h // (Hv / Hk)``). Differentiable in all
-    five. Output ``[..., Hv, T, Dv]`` in v's dtype."""
+    head ``h`` reads key head ``h // (Hv / Hk)``). Output ``[..., Hv, T,
+    Dv]`` in v's dtype, differentiable in all five. With ``initial_state``
+    ``[..., Hv, Dk, Dv]`` or ``return_state`` (-> ``(out, final state)``,
+    fp32) the forward-only kernel that carries the state runs, at any
+    width."""
     lead, (hv, t, dv) = v.shape[:-3], v.shape[-3:]
     hk, dk = q.shape[-3], q.shape[-1]
     assert q.shape == k.shape and q.shape[:-3] == lead and hv % hk == 0, (q.shape, k.shape, v.shape)
@@ -413,17 +458,34 @@ def gated_delta_rule_pallas(
     chunks = min(BLOCK_CHUNKS, -(-t // CHUNK))  # a short T is one smaller block
     pad = (-t) % (chunks * CHUNK)
     nblk = (t + pad) // (chunks * CHUNK)
+    stateful = initial_state is not None or return_state
+    # the state-carrying kernel takes every width, as whole lane tiles
+    wk, wv = ((-dk) % 128, (-dv) % 128) if stateful else (0, 0)
 
-    def flat(x, *tail):  # [B * H, T (padded), ...]
-        x = x.reshape((-1, t) + tail)
-        return jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * len(tail)) if pad else x
+    def flat(x, d, wide):  # [B * H, T (padded), D (padded)]
+        x = x.reshape((-1, t, d))
+        return jnp.pad(x, [(0, 0), (0, pad), (0, wide)]) if pad or wide else x
 
-    per_chunk = lambda x: flat(x.astype(_F32)).reshape(-1, nblk, chunks, CHUNK)  # noqa: E731
-    out = _rule(
-        flat(q.astype(v.dtype), dk), flat(k.astype(v.dtype), dk), flat(v, dv),
-        per_chunk(beta), jnp.cumsum(per_chunk(g), axis=-1), interpret,
+    def per_chunk(x):
+        x = x.astype(_F32).reshape((-1, t))
+        x = jnp.pad(x, [(0, 0), (0, pad)]) if pad else x
+        return x.reshape(-1, nblk, chunks, CHUNK)
+
+    args = (
+        flat(q.astype(v.dtype), dk, wk), flat(k.astype(v.dtype), dk, wk), flat(v, dv, wv),
+        per_chunk(beta), jnp.cumsum(per_chunk(g), axis=-1),
     )
-    return out[:, :t].reshape(lead + (hv, t, dv))
+    if not stateful:
+        return _rule(*args, interpret)[:, :t].reshape(lead + (hv, t, dv))
+    s0 = (
+        jnp.zeros((args[2].shape[0], dk, dv), _F32) if initial_state is None
+        else initial_state.astype(_F32).reshape((-1, dk, dv))
+    )
+    if wk or wv:
+        s0 = jnp.pad(s0, [(0, 0), (0, wk), (0, wv)])
+    out, s = _forward_state(*args, s0, interpret)
+    out = out[:, :t, :dv].reshape(lead + (hv, t, dv))
+    return (out, s[:, :dk, :dv].reshape(lead + (hv, dk, dv))) if return_state else out
 
 
 __all__ = ["BLOCK_CHUNKS", "CHUNK", "gated_delta_rule_pallas", "supports"]

@@ -81,6 +81,7 @@ from orion_tpu.generate import (
     bucket_for,
     decode_batched_chunk,
     decode_batched_prefill_chunk,
+    decode_boundary_donated,
     decode_batched_spec_round,
     prefill_carry,
     prefill_overdue_after,
@@ -180,14 +181,20 @@ def _insert_carry(carry, rngs, plen, pfold, sub_carry, rng, i, n_emitted):
     )
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4))
 def _stage_prompt_carry(carry, rngs, plen, pfold, pbuf, row, rng, i,
                         length, fold):
     """O(1) in-scan admission: zero slot ``i``'s carry row and park its
     padded prompt in the staging buffer — NO prefill runs here and no
     host sync happens; the unified chunk program consumes the prompt
     ``prefill_chunk`` tokens per boundary from inside the batched scan.
-    One fused dispatch per admit, one compile per staged-buffer width."""
+    One fused dispatch per admit, one compile per staged-buffer width.
+    The carry, the per-slot vectors and the staging buffer are DONATED:
+    the row is written in place and the caller's buffers are gone (an
+    undonated call copied the whole decode state, 1.6 GB at 64 slots of
+    lm_1b3, and cannot run at all beside a KV cache of GBs). Every leaf
+    of the row is zeroed, a KV cache's too: rows past a slot's position
+    are masked, but the per-slot finite probe reads them."""
     token, states, t, emit, done = carry
     states = jax.tree.map(
         lambda x: x.at[i].set(jnp.zeros(x.shape[1:], x.dtype)), states
@@ -205,7 +212,7 @@ def _stage_prompt_carry(carry, rngs, plen, pfold, pbuf, row, rng, i,
     )
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4))
 def _stage_prefix_carry(carry, rngs, plen, pfold, pbuf, st1, row, rng, i,
                         length, fold, t0):
     """O(suffix) in-scan admission on a prefix-cache HIT: slot ``i`` gets
@@ -216,7 +223,7 @@ def _stage_prefix_carry(carry, rngs, plen, pfold, pbuf, st1, row, rng, i,
     chunk program consumes from ``t`` onward, i.e. exactly the uncached
     suffix ``prompt[t0:]`` — no new device program, no host sync, one
     fused row write (the same shape as :func:`_stage_prompt_carry` plus
-    the state insert)."""
+    the state insert), donated like it."""
     token, states, t, emit, done = carry
     states = insert_decode_slot(states, st1, i)
     new_carry = (
@@ -264,6 +271,19 @@ def _extract_carry(carry, i):
         jax.lax.dynamic_index_in_dim(emit, i, keepdims=False),
         jax.lax.dynamic_slice_in_dim(done, i, 1),
     )
+
+
+def fits_once_only(carry, params, device) -> bool:
+    """Does ``device`` hold the carry once beside the weights, but not
+    twice? From its ``memory_stats()["bytes_limit"]``; False where the
+    backend reports none. Arrays or their shapes."""
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        return False
+    nbytes = lambda tree: sum(  # noqa: E731
+        x.size * x.dtype.itemsize for x in jax.tree.leaves(tree)
+    )
+    return 2 * nbytes(carry) + nbytes(params) > limit
 
 
 def parse_buckets(spec: str, max_seq_len: int) -> Tuple[int, ...]:
@@ -502,6 +522,18 @@ class SlotEngine:
             jnp.zeros((self.slots,), jnp.int32),
             jnp.ones((self.slots,), bool),  # free slots are "done"
         )
+        # A decode state that the device cannot hold twice beside the
+        # weights is DONATED to the boundary programs
+        # (``generate.decode_boundary_donated``): updated in place, held
+        # once. The price is the ladder:
+        # a rewind needs the boundary's snapshot, which donation gives
+        # up, so a slot whose state turns non-finite fails its request
+        # at that boundary and the others stream on. Decided once, from
+        # what the device reports; a device that reports no limit (the
+        # CPU) never donates.
+        self.donate_carry = fits_once_only(
+            self._carry, params, next(iter(self._carry[0].devices()))
+        )
         self._rngs = jnp.tile(
             jax.random.PRNGKey(0)[None], (self.slots, 1)
         )
@@ -675,6 +707,23 @@ class SlotEngine:
             "prefilling": prefilling,
             "decoding": self.active_count - prefilling,
         }
+
+    def kv_rows(self) -> Tuple[int, int]:
+        """(live, reserved) rows of the KV caches, summed over slots: a
+        slot reserves the cache's rows (``max_seq_len``, or the window of
+        a ring) and holds as many live as its position, from the host's
+        mirror of positions (prompt consumed + tokens emitted), no
+        readback. (0, 0) for a model without a cached layer."""
+        cfg = self.model.cfg
+        kinds = set(cfg.resolved_layer_types)
+        cap = (cfg.max_seq_len if "softmax" in kinds
+               else cfg.window if "swa" in kinds else 0)
+        live = sum(
+            min(cap, s.prompt.shape[1] - s.prompt_remaining
+                + sum(p.shape[1] for p in s.prior) + s.n_emitted)
+            for s in self._slots if s is not None
+        )
+        return live, cap * self.slots
 
     def slot_info(self) -> List[Tuple[int, Any, str, int]]:
         """Per-resident-slot (index, tag, phase, request-local chunk
@@ -1143,18 +1192,27 @@ class SlotEngine:
             np.any(active & self._spec_on_np)
         ):
             spec = jnp.asarray(self._spec_on_np)
-        snap = self._snapshot()
+        # with the carry donated the attempt consumes it: there is no
+        # snapshot to rewind to, and ``self._carry`` is dead until the
+        # boundary's result replaces it below
+        snap = self._carry if self.donate_carry else self._snapshot()
         carry, toks, accepted = self._attempt(snap, active_dev, unified, spec)
         # the boundary's two inner edges, for whoever times its phases
         # (the Server's spans): everything up to here only ENQUEUED work;
         # the probe below is where the host waits for the device
         self._emit("phase", name="probe")
         bad = self._probe_bad(carry, active, accepted)
-        ladder = bool(bad)
-        if bad:
+        ladder = bool(bad) and not self.donate_carry
+        if ladder:
             carry, toks, bad = self._ladder(
                 snap, active_dev, active, carry, toks, bad, unified, spec
             )
+        for i in sorted(bad) if self.donate_carry else ():
+            self._emit("ladder", rung="exhausted", slot=i,
+                       chunk=self._slots[i].chunks, tag=self._slots[i].tag)
+        # a failed slot may not read the carry it no longer has (``failed``
+        # never suspends; the other evictions below come after)
+        self._carry = carry
         self._emit("phase", name="finish", ladder=ladder)
         for i in sorted(bad):  # ladder exhausted: fail those requests
             slot = self._slots[i]
@@ -1174,7 +1232,6 @@ class SlotEngine:
             })
             finished.append((slot.tag, self._finish(i, "failed")))
             active[i] = False
-        self._carry = carry
         done_np = self._done_np
         piece = self._piece_tokens()
         # host mirror of the in-scan pieces: deterministic, no readback —
@@ -1360,6 +1417,7 @@ class SlotEngine:
         # boundaries skip even the cache-size read.
         kind = ("spec_round" if spec is not None
                 else "unified_prefill" if unified else "decode_batched")
+        donate = self.donate_carry and spec is None
         seen_key = (
             (kind, self._pbuf.shape[1]) if kind == "unified_prefill"
             else kind
@@ -1368,15 +1426,24 @@ class SlotEngine:
         if seen_key not in self._compile_seen:
             from orion_tpu.generate import DECODE_PROGRAMS
 
-            jf = DECODE_PROGRAMS[kind]
+            jf = DECODE_PROGRAMS["decode_scan_donated" if donate else kind]
             watch = (jf, jf._cache_size(), time.monotonic())
         # AOT warm start: a stored executable (same program, same
         # compiler) replaces the jit dispatch — statics are baked into
         # the artifact, so the warm calls pass only the dynamic operands
-        # in the wrapper's positional order
-        warm = self._warm_boundary_exec(kind, seen_key)
+        # in the wrapper's positional order. The store holds the
+        # undonated programs only.
+        warm = None if donate else self._warm_boundary_exec(kind, seen_key)
         accepted = None
-        if spec is not None:
+        if donate:
+            live = np.array([s is not None for s in self._slots])
+            out, toks = decode_boundary_donated(
+                self.model, self.params, carry, self._rngs, active_dev,
+                self._pbuf, self._plen, self._pfold,
+                tuple(self._selected_prefill_slots(live)) if unified else (),
+                self.chunk, self.prefill_chunk, self._sample,
+            )
+        elif spec is not None:
             if warm is not None:
                 out, toks, accepted = warm(
                     self.params, carry, self._rngs, active_dev, spec
@@ -1672,4 +1739,4 @@ class SlotEngine:
         return out
 
 
-__all__ = ["SlotEngine", "parse_buckets"]
+__all__ = ["SlotEngine", "fits_once_only", "parse_buckets"]

@@ -109,6 +109,11 @@ PHASES = (
 _SLOT_CLASS_KEYS = (
     "slot_steps_prefilling", "slot_steps_decoding", "slot_steps_frozen",
 )
+# KV-cache rows at each boundary, summed over slots (``SlotEngine.kv_rows``):
+# what the slots hold live / what they reserve (both stay 0 for a model
+# without a cached layer); and the slots that emitted tokens at the
+# boundary, which is the rows the decode scan's state kernels stepped
+_KV_ROW_KEYS = ("kv_rows_live", "kv_rows_reserved", "slot_steps_emitting")
 
 
 class OverloadError(RuntimeError):
@@ -407,7 +412,7 @@ class Server:
         # chunk boundaries — no device syncs, no new compiles (lint rule
         # obs-device-sync + the cache-stat asserts in tests/test_obs.py)
         self.metrics = MetricsRegistry(clock=clock, lock=self._stats_lock)
-        for key in _STAT_KEYS + _SLOT_CLASS_KEYS:
+        for key in _STAT_KEYS + _SLOT_CLASS_KEYS + _KV_ROW_KEYS:
             self.metrics.counter(key)  # the legacy stats dict's cells
         self.trace = tracer if tracer is not None else Tracer(
             path=cfg.trace_path, clock=clock, enabled=bool(cfg.trace_path),
@@ -1968,6 +1973,7 @@ class Server:
         if self.cfg.profile_dir:
             self._profile_maybe_start()
         occupied = self.engine.active_count
+        kv_rows = self.engine.kv_rows()
         infos = self.engine.slot_info() if self.trace.enabled else ()
         t0 = self._clock()
         finished = ()
@@ -1991,9 +1997,10 @@ class Server:
         if self.cfg.profile_dir:
             self._profile_maybe_stop()
         with self._phase("serve.complete", n=len(finished)):
-            prefilling = decoding = 0
+            prefilling = decoding = emitting = 0
             for entry in self.engine.last_boundary:
                 emitted = entry.get("decode_tokens", 0) > 0
+                emitting += emitted
                 if entry.get("prefill_tokens", 0) > 0:
                     prefilling += 1
                 elif emitted:
@@ -2012,6 +2019,8 @@ class Server:
                 self._bump("slot_steps_decoding", decoding)
                 self._bump("slot_steps_frozen",
                            occupied - prefilling - decoding)
+                for key, rows in zip(_KV_ROW_KEYS, kv_rows + (emitting,)):
+                    self._bump(key, rows)
                 # the tp label makes a fleet's per-footprint boundary cost
                 # separable at the aggregated endpoint (a tp=4 replica's
                 # chunks cost collectives a tp=1 replica's don't)

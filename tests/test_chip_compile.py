@@ -123,6 +123,20 @@ def _gated_delta(q, k, v, beta, g):
     return gated_delta_rule(q, k, v, beta, g, backend="pallas")
 
 
+def _gated_delta_state(q, k, v, beta, g, s0):
+    from orion_tpu.ops.dispatch import gated_delta_rule
+
+    return gated_delta_rule(
+        q, k, v, beta, g, backend="pallas", initial_state=s0, return_state=True
+    )
+
+
+def _delta_step(s, q, k, v, beta, g, live):
+    from orion_tpu.ops.pallas.decode_state import gated_delta_step, live_rows
+
+    return gated_delta_step(q, k, v, beta, g, s, live_rows(live))
+
+
 _QKV = [(BHTD, jnp.bfloat16)] * 3
 # qwen3_next_80b's train point (batch 8, T 8192): its softmax layer's 16
 # heads of 256 after the KV heads are repeated; its held experts' buffer
@@ -139,6 +153,17 @@ _DELTA = [*[((2, 16, 8192, 128), jnp.bfloat16)] * 2,
 # token's bf16 q, k, v a slot, and the chunk's row mask
 _STATE = [((64, 16, 128, 128), jnp.float32), ((64, 16, 128), jnp.float32),
           *[((64, 16, 128), jnp.bfloat16)] * 3, ((64,), jnp.bool_)]
+# olmo_hybrid_7b served: one slot's 1,024-token prompt piece through the
+# state-carrying delta-rule kernel at 30 heads of 96 x 192 (zero-padded to
+# 128 x 256 inside), and the 64-slot decode step of its fp32 state
+_DELTA_PIECE = [*[((1, 30, 1024, 96), jnp.bfloat16)] * 2,
+                ((1, 30, 1024, 192), jnp.bfloat16),
+                *[((1, 30, 1024), jnp.float32)] * 2,
+                ((1, 30, 96, 192), jnp.float32)]
+_DELTA_STATE = [((64, 30, 96, 192), jnp.float32),
+                *[((64, 30, 96), jnp.bfloat16)] * 2,
+                ((64, 30, 192), jnp.bfloat16),
+                *[((64, 30), jnp.float32)] * 2, ((64,), jnp.bool_)]
 # moe_1b3_4e dropless: 24576 padded rows through 4 experts of 2048 x 5504
 _GMM = [((24576, 2048), jnp.bfloat16), ((4, 2048, 5504), jnp.bfloat16),
         ((4,), jnp.int32)]
@@ -170,6 +195,9 @@ KERNELS = [
         jax.grad(lambda *a: _f32sum(_gated_delta(*a)), argnums=(0, 1, 2, 3, 4)),
         _DELTA, id="gated_delta-T8192-bwd",
     ),
+    pytest.param(_gated_delta_state, _DELTA_PIECE,
+                 id="gated_delta-state-96x192-piece1024"),
+    pytest.param(_delta_step, _DELTA_STATE, id="gated_delta_step-64slots"),
     # -- the rest of the main path's kernels ---------------------------------
     pytest.param(_plain, _QKV, id="causal_dot-plain-fwd", marks=slow),
     pytest.param(_grad3(_plain), _QKV, id="causal_dot-plain-bwd", marks=slow),
@@ -271,6 +299,53 @@ def test_qwen3_next_train_step_compiles_and_fits(v5e):
     rep = plan(cfg, compile_step=True, mesh=mesh)
     assert rep["compiled"] and rep["n_params"] == 1028320320, rep
     assert rep["collectives"]["mosaic_kernels"] > 0, rep["collectives"]
+
+
+def test_olmo_hybrid_boundary_programs_hold_the_carry_once(v5e):
+    """``olmo_hybrid_7b.serve_batch``'s programs at 64 slots x 4,096 for the
+    chip, the carry donated: admission staging, one slot's prompt piece and
+    the decode scan. Each fits 16 GB with its arguments (weights 4.87 GB +
+    carry 9.2 GB) and holds the KV cache once: no temporary of a cache's
+    size. A compile, not a chip run."""
+    from orion_tpu import generate as gen
+    from orion_tpu.generate import SampleConfig
+    from orion_tpu.models.configs import get_config
+    from orion_tpu.models.transformer import TransformerLM, init_decode_state
+    from orion_tpu.serving import batching
+
+    slots, chunk, piece, width = 64, 8, 1024, 4096
+    cfg = dataclasses.replace(get_config("olmo_hybrid_7b"), backend="pallas")
+    model = TransformerLM(cfg)
+    one = SingleDeviceSharding(v5e[0])
+    put = lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one)  # noqa: E731
+    arr = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    params = jax.tree.map(put, jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 16), jnp.int32))))
+    states = jax.tree.map(put, jax.eval_shape(lambda: init_decode_state(cfg, slots)))
+    ints, flags = arr((slots,), jnp.int32), arr((slots,), jnp.bool_)
+    carry = (ints, states, ints, ints, flags)
+    rngs, pbuf = arr((slots, 2), jnp.uint32), arr((slots, width), jnp.int32)
+    scalar, sample = arr((), jnp.int32), SampleConfig(temperature=0.0)
+    programs = {
+        "stage": batching._stage_prompt_carry.lower(
+            carry, rngs, ints, ints, pbuf, arr((width,), jnp.int32),
+            arr((2,), jnp.uint32), scalar, scalar, scalar),
+        "piece": gen._prefill_piece_donated_jit.lower(
+            model, params, carry, rngs, pbuf, ints, ints, scalar, piece, sample),
+        "scan": gen._decode_scan_donated_jit.lower(
+            model, params, carry, rngs, flags, ints, chunk, sample),
+    }
+    cache_bytes = 64 * 30 * 4096 * 128 * 2
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        m = compiled.memory_analysis()
+        live = (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes)
+        assert live < 16e9, (name, live)
+        assert m.temp_size_in_bytes < cache_bytes, (name, m.temp_size_in_bytes)
+        assert m.alias_size_in_bytes > 9e9, (name, m.alias_size_in_bytes)
+        if name != "stage":
+            assert "gated_delta_" in compiled.as_text(), name
 
 
 @slow

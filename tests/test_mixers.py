@@ -18,8 +18,8 @@ from orion_tpu.models.configs import LAYER_TYPES, get_config, hybrid_pattern
 from orion_tpu.models.mixers import MIXERS, Mixer
 from orion_tpu.models.transformer import TransformerLM, init_decode_state
 
-SERVED = ("linear", "softmax", "swa")
-TRAIN_ONLY = ("gated_delta", "gated_softmax")
+SERVED = ("linear", "softmax", "swa", "gated_delta")
+TRAIN_ONLY = ("gated_softmax",)
 
 # benchmark/configs/qwen3_next_80b.json's ``rehearse`` sizes
 QWEN_CUT = dict(
@@ -33,14 +33,17 @@ QWEN_CUT = dict(
 
 def one_layer(lt):
     return get_config(
-        "tiny", n_layers=1, layer_types=(lt,), window=8, max_seq_len=32
+        "tiny", n_layers=1, layer_types=(lt,), window=8, max_seq_len=32,
+        gdn_key_heads=2, gdn_value_heads=4, gdn_key_dim=8, gdn_value_dim=16,
     )
 
 
 def test_registry_has_one_mixer_per_layer_type():
     assert set(MIXERS) == set(LAYER_TYPES)
     assert all(issubclass(m, Mixer) for m in MIXERS.values())
-    assert {lt for lt, m in MIXERS.items() if m.rows_in_place} == {"linear"}
+    assert {lt for lt, m in MIXERS.items() if m.rows_in_place} == {
+        "linear", "softmax", "swa", "gated_delta"
+    }
 
 
 @pytest.mark.parametrize("lt", SERVED)
@@ -89,6 +92,20 @@ def test_train_only_mixer_refuses_every_serving_entry_point(lt):
         init_decode_state(cfg, 1)
 
 
+def test_gated_delta_has_no_speculative_pair():
+    """Served (prefill, pieces, the decode step), but the speculative
+    verify / advance entry points stay the base class's, which raise."""
+    cfg = one_layer("gated_delta")
+    mixer = MIXERS["gated_delta"](cfg, "gated_delta")
+    x = jnp.zeros((1, 8, cfg.d_model))
+    params = mixer.init(jax.random.key(0), x)
+    t, keep = jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32)
+    for method, args in {"verify_extend": (x, {}, t),
+                         "advance_verified": ({}, {}, t, keep)}.items():
+        with pytest.raises(NotImplementedError, match="gated_delta"):
+            mixer.apply(params, *args, method=method)
+
+
 def test_freeze_rows_skips_exactly_the_rows_in_place_layers():
     """With a row list the decode programs select back only the layers
     whose step touched every row: a ``rows_in_place`` layer's new state is
@@ -102,13 +119,13 @@ def test_freeze_rows_skips_exactly_the_rows_in_place_layers():
     new = jax.tree.map(lambda a: a + 1, old)
     mask = jnp.array([True, False])
     out = _freeze_rows(model, object(), mask, new, old)
-    assert out[1] is new[1]
-    for i in (0, 2):
-        for k in ("k", "v"):
-            assert bool(jnp.all(out[i][k][0] == 1)) and not bool(jnp.any(out[i][k][1]))
+    assert all(out[i] is new[i] for i in range(3))  # every served mixer is in place
     # without a row list every layer is selected
     out = _freeze_rows(model, None, mask, new, old)
     assert not bool(jnp.any(out[1]["s"][1])) and bool(jnp.all(out[1]["s"][0] == 1))
+    for i in (0, 2):
+        for k in ("k", "v"):
+            assert bool(jnp.all(out[i][k][0] == 1)) and not bool(jnp.any(out[i][k][1]))
 
 
 # -- the checkpoint-compatibility pin ----------------------------------------

@@ -339,7 +339,7 @@ def test_server_stats_ride_the_registry(mp, tmp_path):
         # one gauge per entry of generate.DECODE_PROGRAMS (ISSUE 15
         # made that registry the single naming source)
         "decode_batched", "unified_prefill", "prefill", "prefill_bucketed",
-        "spec_round",
+        "spec_round", "prefill_piece_donated", "decode_scan_donated",
     }
     assert any(g["value"] > 0 for g in caches), "the engine compiled SOMETHING"
     hists = {h["name"]: h for h in m["histograms"]}
