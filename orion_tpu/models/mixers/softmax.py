@@ -8,7 +8,11 @@ window, a ring of ``window`` rows written at position % window. The
 slot-multiplexed decode step, given a row list, writes one cache row per
 LISTED sequence and nothing for the others (``rows_in_place``): a dense
 ``[B, H, cap, Dh]`` select over the unlisted rows would read and write the
-whole cache a step.
+whole cache a step. With that list, per-sequence positions and the full
+cache, decode attention reads a listed sequence's cache up to its position
+and nothing of the others (``ops.dispatch.cache_attention``: a kernel under
+a Pallas backend); a ring, a scalar position and every XLA backend take
+``cached_attention`` over the whole reservation under a mask.
 """
 
 from __future__ import annotations
@@ -21,6 +25,7 @@ import jax.numpy as jnp
 
 from orion_tpu.models.configs import ModelConfig
 from orion_tpu.models.mixers import Mixer, State
+from orion_tpu.ops.dispatch import cache_attention
 from orion_tpu.ops.rotary import apply_rotary, apply_rotary_at, rotary_freqs
 from orion_tpu.ops.softmax_attention import (
     _NEG, cached_attention, softmax_attention,
@@ -405,7 +410,7 @@ class SoftmaxAttention(Mixer):
 
     # -- one-token decode ---------------------------------------------------
 
-    def _chunk_local_step(self, qr, kr, v, state, t):
+    def _chunk_local_step(self, qr, kr, v, state, t, rows):
         """The decode step inside a scan that holds the cache read-only
         (:meth:`chunk_split`): this token's k and v go to row ``t - t0`` of
         the chunk's own rows. A sequence that is not emitting holds its
@@ -418,7 +423,8 @@ class SoftmaxAttention(Mixer):
             kn=state["kn"].at[b_idx, :, j, :].set(kr.astype(state["kn"].dtype)),
             vn=state["vn"].at[b_idx, :, j, :].set(v.astype(state["vn"].dtype)),
         )
-        return _chunk_local_attention(qr, new, t), new
+        out = _chunk_local_attention(qr, new, t, rows, self.cfg.backend)
+        return out, new
 
     @_scoped
     def decode_step(
@@ -434,7 +440,7 @@ class SoftmaxAttention(Mixer):
         kr = self._rot_at(k, pos)
         cap = state["k"].shape[-2]  # window W or max_seq_len
         if "kn" in state:
-            out, new = self._chunk_local_step(qr, kr, v, state, t)
+            out, new = self._chunk_local_step(qr, kr, v, state, t, rows)
             return self._merge(out, single=True), new
         slot = t % cap if self.window is not None else t
         if per_seq and rows is not None:
@@ -458,7 +464,6 @@ class SoftmaxAttention(Mixer):
             kc, vc = jax.lax.fori_loop(
                 0, count[0], write, (state["k"], state["v"])
             )
-            valid = jnp.arange(cap)[None, None, :] <= t[:, None, None]
         elif per_seq:
             # one scatter row per sequence at its own slot
             b_idx = jnp.arange(x.shape[0])
@@ -468,7 +473,6 @@ class SoftmaxAttention(Mixer):
             vc = state["v"].at[b_idx, :, slot, :].set(
                 v.astype(state["v"].dtype)
             )
-            valid = jnp.arange(cap)[None, None, :] <= t[:, None, None]
         else:
             kc = jax.lax.dynamic_update_slice_in_dim(
                 state["k"], kr[:, :, None, :].astype(state["k"].dtype), slot, axis=2
@@ -476,31 +480,47 @@ class SoftmaxAttention(Mixer):
             vc = jax.lax.dynamic_update_slice_in_dim(
                 state["v"], v[:, :, None, :].astype(state["v"].dtype), slot, axis=2
             )
+        if per_seq and self.window is None:
+            # a growing cache at per-sequence positions: rows [0, t] are
+            # live, and with a row list under a Pallas backend only they
+            # are read (ops.dispatch.cache_attention)
+            out, _ = cache_attention(
+                qr, kc, vc, t + 1, rows, backend=self.cfg.backend
+            )
+            out = out.astype(qr.dtype)
+        else:
             # ring slots hold positions (t-W, t] once warm; before that,
             # slots (t, W) are still unwritten — in both cases exactly the
             # slots with index <= t are valid (softmax is permutation-
             # invariant over keys, so rotation needs no unrotation).
-            valid = (jnp.arange(cap) <= t)[None, None, :]
-        out = cached_attention(qr, kc, vc, valid)
+            bound = t[:, None, None] if per_seq else t
+            valid = jnp.arange(cap)[None, None, :] <= bound
+            out = cached_attention(qr, kc, vc, valid)
         return self._merge(out, single=True), {"k": kc, "v": vc}
 
 
-def _chunk_local_attention(q, state, t):
-    """One query per sequence over the held cache's rows before ``t0`` and
-    the chunk's own rows up to ``t - t0``: the softmax of
-    :func:`cached_attention` over the two key sets side by side."""
+def _chunk_local_attention(q, state, t, rows, backend):
+    """One query per sequence over the held cache's rows before ``t0``
+    (:func:`ops.dispatch.cache_attention`) and the chunk's own rows up to
+    ``t - t0`` (4 MB at the served widths: scored here), merged by their
+    log-sum-exps: the softmax over the two key sets side by side. A
+    sequence with no held rows, or one the row list leaves out, weighs its
+    held part ``sigmoid(-1e30 - lse) = 0``: the chunk's part alone."""
     f32 = jnp.float32
     j = t - state["t0"]  # [B]: this step's row in the chunk
+    held, lse_held = cache_attention(
+        q, state["k"], state["v"], state["t0"], rows, backend=backend
+    )
     qf = q.astype(f32) * q.shape[-1] ** -0.5
-    old = jnp.einsum("bhd,bhsd->bhs", qf, state["k"].astype(f32))
-    new = jnp.einsum("bhd,bhsd->bhs", qf, state["kn"].astype(f32))
-    cap, n = old.shape[-1], new.shape[-1]
-    old = jnp.where(jnp.arange(cap)[None, None] < state["t0"][:, None, None], old, _NEG)
-    new = jnp.where(jnp.arange(n)[None, None] <= j[:, None, None], new, _NEG)
-    p = jax.nn.softmax(jnp.concatenate([old, new], axis=-1), axis=-1)
-    out = jnp.einsum("bhs,bhsd->bhd", p[..., :cap], state["v"].astype(f32))
-    out = out + jnp.einsum("bhs,bhsd->bhd", p[..., cap:], state["vn"].astype(f32))
-    return out.astype(q.dtype)
+    s = jnp.einsum("bhd,bhsd->bhs", qf, state["kn"].astype(f32))
+    s = jnp.where(jnp.arange(s.shape[-1])[None, None] <= j[:, None, None], s, _NEG)
+    lse_own = jax.nn.logsumexp(s, axis=-1)
+    own = jnp.einsum(
+        "bhs,bhsd->bhd", jnp.exp(s - lse_own[..., None]), state["vn"].astype(f32)
+    )
+    # the held part's share of the joint softmax's mass
+    w = jax.nn.sigmoid(lse_held - lse_own)[..., None]
+    return (own + w * (held - own)).astype(q.dtype)
 
 
 def _window_write(

@@ -6,16 +6,18 @@ backend='xla' dispatch"). "auto" picks Pallas on TPU and the pure-XLA
 chunked scan elsewhere (CPU/GPU and unit tests). The Pallas kernel can also
 run anywhere via interpret mode (used by the parity tests).
 
-Four ops dispatch here: ``gated_delta_rule`` (the gated delta-rule
+Five ops dispatch here: ``gated_delta_rule`` (the gated delta-rule
 layers' parallel forward, with or without a state carried in and out:
 under Pallas the chunked form as Mosaic kernels, forward and backward,
 ``ops/pallas/gated_delta.py``; the same equations as XLA fusions and a scan
 otherwise), ``causal_dot_product`` (the parallel forward: training,
-prefill), and the slot-multiplexed decode programs' two state steps,
-``decode_state_step`` (the linear layers' ``(S, z)``) and
-``gated_delta_step`` (the delta rule's ``S``): under Pallas row-sparse
-in-place kernels that touch only the rows live in the chunk
-(``ops/pallas/decode_state.py``), every row in XLA otherwise.
+prefill), and the slot-multiplexed decode programs' three row-list steps,
+``decode_state_step`` (the linear layers' ``(S, z)``),
+``gated_delta_step`` (the delta rule's ``S``) and ``cache_attention`` (the
+full-attention layers' query over a held KV cache): under Pallas
+row-sparse kernels that touch only the rows live in the chunk
+(``ops/pallas/decode_state.py``, in place; ``ops/pallas/cache_attention.py``,
+only a row's live cache blocks), every row in XLA otherwise.
 """
 
 from __future__ import annotations
@@ -162,7 +164,8 @@ def gated_delta_rule(
     return gd.gated_delta_by_rows(q, k, v, beta, g)
 
 
-def _row_sparse(backend: str) -> bool:
+def row_sparse(backend: str) -> bool:
+    """Whether ``backend`` runs the decode programs' row-list kernels."""
     return resolve(backend) in ("pallas", "pallas_interpret")
 
 
@@ -172,7 +175,7 @@ def decode_live_rows(mask, *, backend: str = "auto"):
     None where ``backend`` steps every row (XLA: the CPU, tp meshes). A
     caller that gets a list must not select the old state back over the
     unlisted rows of the linear layers: the kernel never touched them."""
-    if not _row_sparse(backend):
+    if not row_sparse(backend):
         return None
     from orion_tpu.ops.pallas.decode_state import live_rows
 
@@ -195,7 +198,7 @@ def gated_delta_step(q, k, v, beta, g, state, rows=None, *, backend: str = "auto
     with a row list under a Pallas backend only the listed rows are read,
     updated and written, in place (``ops/pallas/decode_state.py``);
     otherwise every row steps (``ops/gated_delta.py::gated_delta_step``)."""
-    if rows is not None and _row_sparse(backend):
+    if rows is not None and row_sparse(backend):
         from orion_tpu.ops.pallas import decode_state as pds
 
         return pds.gated_delta_step(
@@ -207,6 +210,32 @@ def gated_delta_step(q, k, v, beta, g, state, rows=None, *, backend: str = "auto
     return step(q, k, v, beta, g, state)
 
 
+def cache_attention(q, k_cache, v_cache, lengths, rows=None, *, backend: str = "auto"):
+    """Decode attention of one query a sequence over the first ``lengths``
+    [B] rows of its KV cache: q ``[B, H, Dh]``, caches ``[B, H, cap, Dh]``
+    -> ``(out [B, H, Dh], lse [B, H])`` in fp32, the softmax over those
+    rows applied to V and the log-sum-exp of their scaled scores. ``rows``
+    as for :func:`decode_state_step`: with a row list under a Pallas
+    backend a listed sequence reads only its live cache blocks and an
+    unlisted one nothing (``ops/pallas/cache_attention.py``: its ``out`` is
+    0 and its ``lse`` -1e30, the weight of an empty key set); otherwise
+    every sequence multiplies and reduces over its whole reservation under
+    a mask (``ops/softmax_attention.py::cached_attention``)."""
+    if rows is not None and row_sparse(backend):
+        from orion_tpu.ops.pallas import cache_attention as pca
+
+        return pca.cache_attention(
+            q, k_cache, v_cache, lengths, rows,
+            interpret=(resolve(backend) == "pallas_interpret"),
+        )
+    import jax.numpy as jnp
+
+    from orion_tpu.ops.softmax_attention import cached_attention
+
+    valid = jnp.arange(k_cache.shape[-2])[None, None, :] < lengths[:, None, None]
+    return cached_attention(q, k_cache, v_cache, valid, with_lse=True)
+
+
 def decode_state_step(q, k, v, state, rows=None, *, backend: str = "auto"):
     """One decode step of the linear layers' ``(S, z)`` state.
 
@@ -215,7 +244,7 @@ def decode_state_step(q, k, v, state, rows=None, *, backend: str = "auto"):
     the backend is not Pallas). With a row list under a Pallas backend
     only the listed rows are read, updated and written, in place;
     otherwise this is ``recurrent_step`` on all rows."""
-    if rows is not None and _row_sparse(backend):
+    if rows is not None and row_sparse(backend):
         from orion_tpu.ops.pallas import decode_state as pds
 
         return pds.decode_state_step(
@@ -228,6 +257,7 @@ def decode_state_step(q, k, v, state, rows=None, *, backend: str = "auto"):
 
 
 __all__ = [
+    "cache_attention",
     "causal_dot_product",
     "decode_live_rows",
     "decode_rows_mask",
@@ -237,4 +267,5 @@ __all__ = [
     "gated_delta_rule",
     "resolve",
     "resolve_chunk",
+    "row_sparse",
 ]

@@ -1,0 +1,231 @@
+"""Decode attention over a slot's LIVE cache rows: one query a sequence
+against the first ``lengths[b]`` rows of its reserved KV cache, for the
+sequences a row list names, and nothing at all for the others.
+
+The slot-multiplexed decode programs (generate.py) reserve ``max_seq_len``
+cache rows a slot. XLA's form of the step (``ops/softmax_attention.py::
+cached_attention``) multiplies and reduces over the whole reservation and
+masks what lies past the position: at a third of the reservation live it
+streams three times the bytes that hold a token (PERF.md, PR 36).
+
+This kernel walks the row list of ``decode_state.live_rows`` as the two
+state kernels do: the grid is listed rows x KV blocks, and both the list
+and the per-row lengths are scalar-prefetched, so the K and V index maps
+take the row from the list and CLAMP the block index to the row's last
+live block. A block past it repeats the index before it, which the
+pipeline does not fetch again, and its step computes nothing
+(``pl.when``); an unlisted row is never visited: its output is the zero
+row and its log-sum-exp ``-1e30`` the caller handed in (both aliased
+input to output), finite and the same on every replay.
+
+Mathematics per listed row and head, an online softmax in fp32 scratch::
+
+    s = (q * Dh^-1/2) . K[:length];  out = softmax(s) V[:length]
+    lse = log sum exp s
+
+with the last live block masked at ``position < length``, V too (a dead
+position may hold anything: 0 x NaN is NaN). A row of length 0 comes out
+as ``out = 0, lse = -1e30``: merged by log-sum-exps with another key set
+it weighs exactly nothing.
+
+Both products run on the MXU with every product and sum in fp32, and no
+operand is rounded that the XLA form does not round: the cache's bf16
+values are exact, and the fp32 side (q, then p) is SPLIT into three bf16
+terms that sum to it exactly (8 + 8 + 8 mantissa bits), stacked as three
+rows of one matmul. A bf16 x bf16 product is exact in fp32 and the MXU
+accumulates in fp32, so this is the fp32 dot product up to summation
+order, at one pass over the cache block instead of the six an
+fp32 x fp32 matmul takes. A cache in another dtype takes that matmul
+(``Precision.HIGHEST``).
+
+reference: none (the reference has no cache; checkout never mounted,
+SURVEY.md s0).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from orion_tpu.ops.softmax_attention import _NEG
+
+Array = jax.Array
+
+# KV rows a grid step, all heads of the row at once: K and V blocks of
+# [H, 256, Dh] (2 MB each in bf16 at 30 heads x 128), double-buffered. On
+# the v5e at 64 x 30 x 4,096 x 128 and the served cell's lengths, alone:
+# 2.32 ms a call at 256 rows, 2.55 at 512, 3.04 at 1,024 (a row's last
+# block is read whole) against 7.02 for the XLA form (PR 36)
+BLOCK_KV = 256
+# the split fp32 operand's rows, padded to one bf16 tile of sublanes
+_SPLIT_ROWS = 16
+_VMEM_BYTES = 64 << 20
+
+
+def kv_block(cap: int) -> int:
+    """Rows of a KV block for a cache of ``cap`` rows: :data:`BLOCK_KV`
+    where it divides ``cap``, the whole cache where that is smaller."""
+    if cap <= BLOCK_KV:
+        return cap
+    bk = math.gcd(cap, BLOCK_KV)
+    if bk % 16:
+        raise ValueError(
+            f"a cache of {cap} rows does not tile: rows past {BLOCK_KV} "
+            "must come in multiples of 16"
+        )
+    return bk
+
+
+def rows_read(length: int, cap: int) -> int:
+    """Cache rows the kernel streams for a listed row of ``length`` live
+    rows: its live blocks, and one block at least (the pipeline fetches
+    the block an index map names whether or not the step computes)."""
+    bk = kv_block(cap)
+    return min(cap, max(1, -(-length // bk)) * bk)
+
+
+def _rows_dot(a: Array, b: Array, dims) -> Array:
+    """``a`` fp32 [H, 1, X] against a cache block ``b`` [H, ., .] as a
+    per-head matmul contracting ``dims``, products and sums in fp32 (the
+    module docstring's split) -> fp32 [H, 1, N]."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    dims = (dims, ((0,), (0,)))
+    if b.dtype != bf16:
+        return jax.lax.dot_general(
+            a, b.astype(f32), dims, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=f32,
+        )
+    hi = a.astype(bf16).astype(f32)
+    rest = a - hi
+    mid = rest.astype(bf16).astype(f32)
+    low = rest - mid
+    h, _, x = a.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (h, _SPLIT_ROWS, x), 1)
+    parts = jnp.where(
+        row == 0, hi, jnp.where(row == 1, mid, jnp.where(row == 2, low, 0.0))
+    )
+    out = jax.lax.dot_general(
+        parts.astype(bf16), b, dims, preferred_element_type=f32
+    )
+    return jnp.sum(out, axis=1, keepdims=True)  # rows 3.. are zero
+
+
+_QK = ((2,), (2,))  # [H, 1, Dh] x [H, bk, Dh] -> [H, 1, bk]
+_PV = ((2,), (1,))  # [H, 1, bk] x [H, bk, Dh] -> [H, 1, Dh]
+
+
+def _kernel(bk, nblk, idx_ref, len_ref, q_ref, k_ref, v_ref, o_in, lse_in,
+            o_ref, lse_ref, m_scr, l_scr, acc_scr):
+    del o_in, lse_in  # aliased onto the outputs: what an unlisted row keeps
+    i, j = pl.program_id(0), pl.program_id(1)
+    length = len_ref[idx_ref[i]]
+
+    @pl.when(j == 0)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def block(partial):
+        s = _rows_dot(q_ref[0], k_ref[0], _QK)  # [H, 1, bk]
+        v = v_ref[0]
+        if partial:
+            at = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            s = jnp.where(at < length, s, _NEG)
+            at = j * bk + jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+            v = jnp.where(at < length, v, jnp.zeros_like(v))
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + _rows_dot(p, v, _PV)
+        m_scr[...] = m_new
+
+    # a block wholly below the length needs no mask; the row's last live
+    # block does, unless the length ends it
+    pl.when((j + 1) * bk <= length)(lambda: block(False))
+    pl.when((j * bk < length) & (length < (j + 1) * bk))(lambda: block(True))
+
+    @pl.when(j == nblk - 1)
+    def _():
+        l = l_scr[...]
+        safe = jnp.where(l == 0.0, 1.0, l)  # no live row: out 0, lse _NEG
+        o_ref[0] = acc_scr[...] / safe
+        lse_ref[0] = m_scr[...] + jnp.log(safe)
+
+
+def cache_attention(
+    q: Array, k_cache: Array, v_cache: Array, lengths: Array,
+    rows: Tuple[Array, Array], *, interpret: bool = False,
+) -> Tuple[Array, Array]:
+    """q ``[B, H, Dh]``; caches ``[B, H, cap, Dh]`` (one dtype); lengths
+    ``[B]`` int32, the live rows of each sequence's cache; rows =
+    ``decode_state.live_rows`` of the row mask. Returns (out ``[B, H, Dh]``,
+    lse ``[B, H]``), both fp32: for a listed row the softmax of its scaled
+    scores over cache rows ``[0, length)`` applied to V, and their
+    log-sum-exp; ``(0, -1e30)`` for a listed row of length 0 and for every
+    unlisted row, whose cache is never read."""
+    idx, count = rows
+    b, h, cap, d = k_cache.shape
+    shapes = (q.shape, v_cache.shape, lengths.shape, idx.shape)
+    if shapes != ((b, h, d), k_cache.shape, (b,), (b,)):
+        raise ValueError(f"operands do not fit K {k_cache.shape}: {shapes}")
+    if k_cache.dtype != v_cache.dtype:
+        raise ValueError(f"one cache dtype: {k_cache.dtype}/{v_cache.dtype}")
+    bk = kv_block(cap)
+    nblk = cap // bk
+    f32 = jnp.float32
+    # every block's last two dims are whole dims of its array: a head's
+    # query, output and statistics sit one row a head, [B, H, 1, .]
+    qf = (q.astype(f32) * d ** -0.5)[:, :, None, :]
+
+    def row(i, j, idx, lens):
+        return (idx[i], 0, 0, 0)
+
+    def kv(i, j, idx, lens):
+        r = idx[i]
+        last = jnp.maximum((lens[r] + bk - 1) // bk - 1, 0)
+        return (r, 0, jnp.minimum(j, last), 0)
+
+    vec = pl.BlockSpec((1, h, 1, d), row)
+    one = pl.BlockSpec((1, h, 1, 1), row)
+    blk = pl.BlockSpec((1, h, bk, d), kv)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(count[0], nblk),
+        in_specs=[vec, blk, blk, vec, one],
+        out_specs=[vec, one],
+        scratch_shapes=[
+            pltpu.VMEM((h, 1, 1), f32),
+            pltpu.VMEM((h, 1, 1), f32),
+            pltpu.VMEM((h, 1, d), f32),
+        ],
+    )
+    out, lse = pl.pallas_call(
+        functools.partial(_kernel, bk, nblk),
+        name="cache_attention",
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, 1, d), f32),
+            jax.ShapeDtypeStruct((b, h, 1, 1), f32),
+        ],
+        # operand numbering counts the two scalar-prefetch lists: the
+        # unlisted rows' output and log-sum-exp are operands 5 and 6
+        input_output_aliases={5: 0, 6: 1},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_BYTES),
+        interpret=interpret,
+    )(
+        idx, lengths.astype(jnp.int32), qf, k_cache, v_cache,
+        jnp.zeros((b, h, 1, d), f32), jnp.full((b, h, 1, 1), _NEG, f32),
+    )
+    return out[:, :, 0, :], lse[:, :, 0, 0]
+
+
+__all__ = ["BLOCK_KV", "cache_attention", "kv_block", "rows_read"]

@@ -129,13 +129,16 @@ def cached_attention(
     valid: Array,
     *,
     scale: Optional[float] = None,
-) -> Array:
+    with_lse: bool = False,
+):
     """Decode-step attention of a single query over a KV cache.
 
     q: [..., D]; caches: [..., S, D]; valid: boolean [..., S] marking filled
     slots (works for both the growing full cache and the sliding-window ring
     buffer, where slot order ≠ time order — softmax is permutation-invariant
-    over keys, so ring-buffer rotation needs no unrotation).
+    over keys, so ring-buffer rotation needs no unrotation). ``with_lse``:
+    (the output in fp32, the log-sum-exp of the valid scores [...]), for a
+    caller that merges this key set with another.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -144,6 +147,8 @@ def cached_attention(
     scores = jnp.where(valid, scores, _NEG)
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("...s,...sd->...d", p, v_cache.astype(jnp.float32))
+    if with_lse:
+        return out, jax.nn.logsumexp(scores, axis=-1)
     return out.astype(q.dtype)
 
 

@@ -96,6 +96,7 @@ from orion_tpu.models.transformer import (
     linear_layer_indices,
     snapshot_decode_state,
 )
+from orion_tpu.ops.dispatch import row_sparse
 from orion_tpu.resilience import inject
 from orion_tpu.resilience.breaker import StoreUnavailableError
 from orion_tpu.serving.session import DecodeRequest, DecodeResult
@@ -708,22 +709,49 @@ class SlotEngine:
             "decoding": self.active_count - prefilling,
         }
 
-    def kv_rows(self) -> Tuple[int, int]:
-        """(live, reserved) rows of the KV caches, summed over slots: a
-        slot reserves the cache's rows (``max_seq_len``, or the window of
+    def kv_rows(self) -> Tuple[int, int, int]:
+        """(live, reserved, read) rows of the KV caches, summed over slots:
+        a slot reserves the cache's rows (``max_seq_len``, or the window of
         a ring) and holds as many live as its position, from the host's
         mirror of positions (prompt consumed + tokens emitted), no
-        readback. (0, 0) for a model without a cached layer."""
+        readback. ``read`` is what a layer's decode attention streams a
+        step at the boundary about to run: every slot's reservation in
+        the XLA form; under a row-list backend
+        (``ops.dispatch.cache_attention``) the live KV blocks of each slot
+        that will emit, at the position its last step attends from, and
+        nothing for the others. (0, 0, 0) for a model without a cached
+        layer."""
         cfg = self.model.cfg
         kinds = set(cfg.resolved_layer_types)
         cap = (cfg.max_seq_len if "softmax" in kinds
                else cfg.window if "swa" in kinds else 0)
+        ends = {  # slot -> its position once its staged prompt is consumed
+            i: s.prompt.shape[1] + sum(p.shape[1] for p in s.prior) + s.n_emitted
+            for i, s in enumerate(self._slots) if s is not None
+        }
         live = sum(
-            min(cap, s.prompt.shape[1] - s.prompt_remaining
-                + sum(p.shape[1] for p in s.prior) + s.n_emitted)
-            for s in self._slots if s is not None
+            min(cap, end - self._slots[i].prompt_remaining)
+            for i, end in ends.items()
         )
-        return live, cap * self.slots
+        read = cap * self.slots
+        if "softmax" in kinds and row_sparse(cfg.backend):
+            from orion_tpu.ops.pallas.cache_attention import rows_read
+
+            # a slot emits once its prompt is consumed, at this boundary
+            # if a piece of it ends the prompt. The donated scan reads the
+            # cache as it stood at the scan's start; the scan that carries
+            # the cache reads the rows it wrote too
+            served = self._selected_prefill_slots(
+                [s is not None for s in self._slots]
+            )
+            piece = self._piece_tokens()
+            grown = 0 if self.donate_carry else self.chunk
+            read = sum(
+                rows_read(min(cap, end + grown), cap)
+                for i, end in ends.items()
+                if self._slots[i].prompt_remaining <= (piece if i in served else 0)
+            )
+        return live, cap * self.slots, read
 
     def slot_info(self) -> List[Tuple[int, Any, str, int]]:
         """Per-resident-slot (index, tag, phase, request-local chunk

@@ -110,10 +110,15 @@ _SLOT_CLASS_KEYS = (
     "slot_steps_prefilling", "slot_steps_decoding", "slot_steps_frozen",
 )
 # KV-cache rows at each boundary, summed over slots (``SlotEngine.kv_rows``):
-# what the slots hold live / what they reserve (both stay 0 for a model
-# without a cached layer); and the slots that emitted tokens at the
-# boundary, which is the rows the decode scan's state kernels stepped
-_KV_ROW_KEYS = ("kv_rows_live", "kv_rows_reserved", "slot_steps_emitting")
+# what the slots hold live / what they reserve / what a layer's decode
+# attention streams a step (the reservation in the XLA form, the emitting
+# slots' live KV blocks under ``ops.dispatch.cache_attention``'s kernel; all
+# three stay 0 for a model without a cached layer); and the slots that
+# emitted tokens at the boundary, which is the rows the decode scan's state
+# kernels stepped
+_KV_ROW_KEYS = (
+    "kv_rows_live", "kv_rows_reserved", "kv_rows_read", "slot_steps_emitting",
+)
 
 
 class OverloadError(RuntimeError):

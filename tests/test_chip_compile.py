@@ -137,6 +137,13 @@ def _delta_step(s, q, k, v, beta, g, live):
     return gated_delta_step(q, k, v, beta, g, s, live_rows(live))
 
 
+def _cache_attention(q, k, v, lengths, live):
+    from orion_tpu.ops.pallas.cache_attention import cache_attention
+    from orion_tpu.ops.pallas.decode_state import live_rows
+
+    return cache_attention(q, k, v, lengths, live_rows(live))
+
+
 _QKV = [(BHTD, jnp.bfloat16)] * 3
 # qwen3_next_80b's train point (batch 8, T 8192): its softmax layer's 16
 # heads of 256 after the KV heads are repeated; its held experts' buffer
@@ -164,6 +171,11 @@ _DELTA_STATE = [((64, 30, 96, 192), jnp.float32),
                 *[((64, 30, 96), jnp.bfloat16)] * 2,
                 ((64, 30, 192), jnp.bfloat16),
                 *[((64, 30), jnp.float32)] * 2, ((64,), jnp.bool_)]
+# and of one full-attention layer's held KV cache: a token's bf16 query a
+# slot against 64 x 30 heads x 4,096 reserved rows of 128, the positions
+_KV_CACHE = [((64, 30, 128), jnp.bfloat16),
+             *[((64, 30, 4096, 128), jnp.bfloat16)] * 2,
+             ((64,), jnp.int32), ((64,), jnp.bool_)]
 # moe_1b3_4e dropless: 24576 padded rows through 4 experts of 2048 x 5504
 _GMM = [((24576, 2048), jnp.bfloat16), ((4, 2048, 5504), jnp.bfloat16),
         ((4,), jnp.int32)]
@@ -198,6 +210,7 @@ KERNELS = [
     pytest.param(_gated_delta_state, _DELTA_PIECE,
                  id="gated_delta-state-96x192-piece1024"),
     pytest.param(_delta_step, _DELTA_STATE, id="gated_delta_step-64slots"),
+    pytest.param(_cache_attention, _KV_CACHE, id="cache_attention-64slots"),
     # -- the rest of the main path's kernels ---------------------------------
     pytest.param(_plain, _QKV, id="causal_dot-plain-fwd", marks=slow),
     pytest.param(_grad3(_plain), _QKV, id="causal_dot-plain-bwd", marks=slow),
@@ -311,6 +324,7 @@ def test_olmo_hybrid_boundary_programs_hold_the_carry_once(v5e):
     from orion_tpu.generate import SampleConfig
     from orion_tpu.models.configs import get_config
     from orion_tpu.models.transformer import TransformerLM, init_decode_state
+    from orion_tpu.ops.pallas.cache_attention import _VMEM_BYTES as _KERNEL_VMEM
     from orion_tpu.serving import batching
 
     slots, chunk, piece, width = 64, 8, 1024, 4096
@@ -346,6 +360,12 @@ def test_olmo_hybrid_boundary_programs_hold_the_carry_once(v5e):
         assert m.alias_size_in_bytes > 9e9, (name, m.alias_size_in_bytes)
         if name != "stage":
             assert "gated_delta_" in compiled.as_text(), name
+        if name == "scan":
+            # decode attention is the row-list kernel over the held cache,
+            # and beside the 0.07 GB the scan held before it nothing grew
+            # but what the kernel stages in VMEM (its K and V blocks)
+            assert "cache_attention" in compiled.as_text()
+            assert m.temp_size_in_bytes < 0.08e9 + _KERNEL_VMEM, m.temp_size_in_bytes
 
 
 @slow

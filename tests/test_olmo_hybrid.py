@@ -214,27 +214,49 @@ def serve(cfg, params, prompts, max_new, slots=4, chunk=4, prefill_chunk=128, do
     for i, p in enumerate(prompts):
         engine.admit(DecodeRequest(prompt=p, max_new_tokens=max_new, sample=GREEDY, seed=i), tag=i)
     done = {}
+    engine.kv_seen = []  # (live, reserved, read) before each boundary
     while engine.busy:
+        engine.kv_seen.append(engine.kv_rows())
         for tag, res in engine.step():
             assert res.status == "ok", res.status
             done[tag] = np.asarray(res.tokens).reshape(-1)
     return [done[i] for i in range(len(prompts))], engine
 
 
-@pytest.mark.parametrize("backend,donate", [("xla", False), ("xla", True), ("pallas_interpret", True)])
+@pytest.mark.parametrize("backend,donate", [
+    ("xla", False), ("xla", True), ("pallas_interpret", True), ("pallas_interpret", False)])
 def test_engine_serves_three_requests_as_alone(model_params, backend, donate):
     """(b) through ``SlotEngine``: three requests of different lengths
     resident (prompts of one, two and three pieces), 2 x chunk + 1 decoded
     tokens each; every request's ids are what it gets served alone (XLA:
     exactly), and teacher-forced through the reference's full forward each
     served id is the reference's choice or within tolerance of it; with the
-    carry donated the boundary programs give the same ids."""
+    carry donated the boundary programs give the same ids. Under the
+    kernels (the donated scan's attention over the held cache, and the
+    carried cache's per-sequence step with a row list) the ids are the XLA
+    engine's, and decode attention streams the emitting slots' live KV
+    blocks where XLA's form streams every slot's reservation."""
+    from orion_tpu.ops.pallas.cache_attention import kv_block
+
     cfg, params, toks, _ = model_params
     cfg = dataclasses.replace(cfg, backend=backend, chunk=tiny_cfg(backend).chunk)
     prompts = [np.asarray(toks[0, :100]), np.asarray(toks[1, :250]), np.asarray(toks[0, 30:330])]
     n_new = 9
     together, engine = serve(cfg, params, prompts, n_new, donate=donate)
-    assert engine.kv_rows()[1] == 4 * cfg.max_seq_len
+    reserved = 4 * cfg.max_seq_len
+    assert engine.kv_rows()[1] == reserved
+    reads = [read for _, _, read in engine.kv_seen]
+    if backend == "xla":
+        assert set(reads) == {reserved} and engine.kv_rows()[2] == reserved
+    else:
+        block = kv_block(cfg.max_seq_len)
+        assert block < cfg.max_seq_len and all(r % block == 0 for r in reads)
+        # the last boundary: the longest request alone, ~340 of 384 rows live
+        assert reads[-1] == 3 * block and max(reads) < reserved
+        assert engine.kv_rows()[2] == 0  # nothing resident, nothing read
+        xla_ids, _ = serve(dataclasses.replace(cfg, backend="xla"), params, prompts, n_new)
+        for ids, want in zip(together, xla_ids):
+            np.testing.assert_array_equal(ids, want)
     spec = {**SPEC, "layer_types": cfg.resolved_layer_types}
     for p, ids in zip(prompts, together):
         if backend == "xla":
