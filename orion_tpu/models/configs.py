@@ -73,12 +73,38 @@ class ModelConfig:
     # state's transition I - beta k k^T may then have a negative eigenvalue
     gdn_allow_neg_eigval: bool = False
     # "projection": RMSNorm (own weight) of the whole q and k projections,
-    # before the split into heads (linear / softmax / swa layers)
-    qk_norm: str = "none"  # "none" | "projection"
+    # before the split into heads (linear / softmax / swa layers); "head":
+    # RMSNorm over each head's own width, one learned [head_dim] weight
+    # (decay_linear / block_sparse layers)
+    qk_norm: str = "none"  # "none" | "projection" | "head"
     rotary: bool = True  # softmax / swa layers rotate q and k by position
     # "pre": x + f(norm(x)); "post": x + norm(f(x)), the sublayer's OUTPUT
     # normalised before the residual add
     norm_placement: str = "pre"  # "pre" | "post"
+    # -- "decay_linear" layers (models/mixers/decay_linear.py): linear
+    # attention with no feature map and no normaliser, S_t = lam_h S_{t-1} +
+    # k_t^T v_t, the per-head decay fixed: lam_h = exp(-2^(-decay_exponent
+    # h / n_heads)), h = 1..n_heads
+    decay_exponent: float = 8.0
+    # -- "block_sparse" layers (models/mixers/block_sparse.py): softmax
+    # attention over n_kv_heads grouped KV heads; past sparse_dense_len
+    # positions a query attends to sparse_topk key blocks of sparse_block
+    # tokens, chosen per KV head from keys mean-pooled over sparse_kernel
+    # tokens every sparse_stride; the first sparse_init_blocks blocks and
+    # those of the last sparse_window tokens are always among them
+    sparse_kernel: int = 32
+    sparse_stride: int = 16
+    sparse_block: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window: int = 2048
+    sparse_topk: int = 64
+    sparse_dense_len: int = 8192
+    # the embedding's output, each residual branch (h = x + residual_scale *
+    # f(norm(x))) and the final-normed hidden state before the head are
+    # multiplied by these; 1.0 leaves the program as it was
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
     dropout: float = 0.0
     # numerics / execution
     dtype: str = "bfloat16"  # activation/compute dtype
@@ -174,7 +200,10 @@ class ModelConfig:
 
 
 # one mixer class each: models/mixers/__init__.py::MIXERS
-LAYER_TYPES = ("linear", "softmax", "swa", "gated_delta", "gated_softmax")
+LAYER_TYPES = (
+    "linear", "softmax", "swa", "gated_delta", "gated_softmax",
+    "decay_linear", "block_sparse",
+)
 
 
 def gated_pattern(n_layers: int, period: int = 4) -> Tuple[str, ...]:
@@ -379,6 +408,47 @@ OLMO_HYBRID_7B = ModelConfig(
     param_dtype="bfloat16",
 )
 
+def decay_sparse_pattern(n_layers: int, period: int = 4) -> Tuple[str, ...]:
+    """decay_linear x (period - 1) then block_sparse, repeating."""
+    return tuple(
+        "block_sparse" if (i + 1) % period == 0 else "decay_linear"
+        for i in range(n_layers)
+    )
+
+
+MINICPM_SALA = ModelConfig(
+    # MiniCPM-SALA at its published widths, one period deep: the published
+    # layers 13-16 (0-based), one of eight pipeline stages of 4 layers
+    # (benchmark/configs/minicpm_sala.json states the source, the cut and
+    # what is assumed). 3 decayed linear-attention layers (32 heads x 128,
+    # rotary, no normaliser, output norm and gate) then 1 block-sparse
+    # layer (32 query heads over 2 KV heads x 128, no rotary, top-64 blocks
+    # of 64 past 8,192 tokens, output gate); per-head q / k norm; the
+    # embedding x 12, residual branches x 1.4 / sqrt(32) (the PUBLISHED
+    # depth), logits from h / 16; dense SwiGLU; served in bfloat16.
+    name="minicpm_sala",
+    vocab_size=73448,
+    d_model=4096,
+    n_layers=4,
+    layer_types=decay_sparse_pattern(4, period=4),
+    n_heads=32,
+    n_kv_heads=2,
+    head_dim=128,
+    qk_norm="head",
+    rotary_base=10000.0,
+    embed_scale=12.0,
+    residual_scale=1.4 / 32 ** 0.5,
+    logit_scale=256 / 4096,
+    norm="rmsnorm",
+    pos_embed="none",
+    tie_embeddings=False,
+    mlp="swiglu",
+    mlp_hidden=16384,
+    max_seq_len=16896,
+    dtype="bfloat16",
+    param_dtype="bfloat16",
+)
+
 LRA_LISTOPS_LINEAR = ModelConfig(
     name="lra_listops_linear",
     vocab_size=32,  # digits + operators + specials
@@ -426,6 +496,7 @@ CONFIGS = {
         MOE_1B3_4E,
         QWEN3_NEXT_80B,
         OLMO_HYBRID_7B,
+        MINICPM_SALA,
         LRA_LISTOPS_LINEAR,
         LRA_LISTOPS_SOFTMAX,
         LRA_TEXT_LINEAR,
@@ -443,5 +514,5 @@ def get_config(name: str, **overrides) -> ModelConfig:
 
 __all__ = [
     "ModelConfig", "CONFIGS", "get_config", "hybrid_pattern",
-    "gated_pattern", "delta_full_pattern", "F32_MATMUL_SCOPES", "LAYER_TYPES",
+    "gated_pattern", "delta_full_pattern", "decay_sparse_pattern", "F32_MATMUL_SCOPES", "LAYER_TYPES",
 ]

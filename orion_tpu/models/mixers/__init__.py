@@ -176,8 +176,9 @@ class Mixer(nn.Module):
         raise NotImplementedError(
             f"layer type {self.layer_type!r} does not build this serving "
             "entry point: gated_softmax has a training forward only; "
-            "gated_delta serves (prefill, its pieces, the decode step) but "
-            "has no speculative verify_extend / advance_verified"
+            "gated_delta, decay_linear and block_sparse serve (prefill, its "
+            "pieces, the decode step) but have no speculative verify_extend "
+            "/ advance_verified"
         )
 
     def prefill(
@@ -265,35 +266,40 @@ class Mixer(nn.Module):
 
     # -- helpers of the mixers with n_heads x head_dim q / k / v / o ----------
 
-    def _setup_qkvo(self):
+    def _setup_qkvo(self, kv_heads: Optional[int] = None):
+        """``kv_heads``: how many heads k and v have (default: as many as
+        q; fewer is a grouped KV)."""
         cfg = self.cfg
         h, dh = cfg.n_heads, cfg.resolved_head_dim
         dense = _dense_factory(cfg, self.quant, self.mesh)
         self.wq = dense("wq", h * dh)
-        self.wk = dense("wk", h * dh)
-        self.wv = dense("wv", h * dh)
+        self.wk = dense("wk", (kv_heads or h) * dh)
+        self.wv = dense("wv", (kv_heads or h) * dh)
         self.wo = dense("wo", cfg.d_model)
-        assert cfg.qk_norm in ("none", "projection"), cfg.qk_norm
-        if cfg.qk_norm == "projection":
+        assert cfg.qk_norm in ("none", "projection", "head"), cfg.qk_norm
+        if cfg.qk_norm != "none":
             self.q_norm = nn.RMSNorm(dtype=_dtype(cfg.dtype), name="q_norm")
             self.k_norm = nn.RMSNorm(dtype=_dtype(cfg.dtype), name="k_norm")
 
     def _heads(self, x: Array) -> Tuple[Array, Array, Array]:
         """x [..., T, D] (or [..., D]) -> q,k,v [..., H, T, Dh] ([..., H, Dh])."""
         cfg = self.cfg
-        h, dh = cfg.n_heads, cfg.resolved_head_dim
+        dh = cfg.resolved_head_dim
         single = x.ndim == 2  # decode: [B, D]
         q, k, v = self.wq(x), self.wk(x), self.wv(x)
         if cfg.qk_norm == "projection":  # over all heads' columns at once
             q, k = self.q_norm(q), self.k_norm(k)
 
         def split(y):
+            y = y.reshape(*y.shape[:-1], y.shape[-1] // dh, dh)
             if single:
-                return y.reshape(*y.shape[:-1], h, dh)  # [B, H, Dh]
-            y = y.reshape(*y.shape[:-1], h, dh)  # [B, T, H, Dh]
-            return jnp.swapaxes(y, -3, -2)  # [B, H, T, Dh]
+                return y  # [B, H, Dh]
+            return jnp.swapaxes(y, -3, -2)  # [B, T, H, Dh] -> [B, H, T, Dh]
 
-        return split(q), split(k), split(v)
+        q, k, v = split(q), split(k), split(v)
+        if cfg.qk_norm == "head":  # over each head's own width, one weight
+            q, k = self.q_norm(q), self.k_norm(k)
+        return q, k, v
 
     def _merge(self, out: Array, single: bool) -> Array:
         if not single:
@@ -313,6 +319,12 @@ class Mixer(nn.Module):
 
 
 # the mixer files import the names above, so the registry comes last
+from orion_tpu.models.mixers.block_sparse import (  # noqa: E402
+    BlockSparseAttention,
+)
+from orion_tpu.models.mixers.decay_linear import (  # noqa: E402
+    DecayLinearAttention,
+)
 from orion_tpu.models.mixers.gated_delta import GatedDeltaNet  # noqa: E402
 from orion_tpu.models.mixers.gated_softmax import (  # noqa: E402
     GatedSoftmaxAttention,
@@ -326,10 +338,13 @@ MIXERS = {
     "swa": SoftmaxAttention,
     "gated_delta": GatedDeltaNet,
     "gated_softmax": GatedSoftmaxAttention,
+    "decay_linear": DecayLinearAttention,
+    "block_sparse": BlockSparseAttention,
 }
 assert set(MIXERS) == set(LAYER_TYPES), (sorted(MIXERS), LAYER_TYPES)
 
 __all__ = [
     "MIXERS", "Mixer", "LinearAttention", "SoftmaxAttention", "GatedDeltaNet",
-    "GatedSoftmaxAttention", "ZeroCentredRMSNorm", "kernel_bh",
+    "GatedSoftmaxAttention", "DecayLinearAttention", "BlockSparseAttention",
+    "ZeroCentredRMSNorm", "kernel_bh",
 ]

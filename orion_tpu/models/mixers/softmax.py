@@ -17,7 +17,6 @@ a Pallas backend); a ring, a scalar position and every XLA backend take
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Optional, Tuple
 
 import jax
@@ -30,20 +29,12 @@ from orion_tpu.ops.rotary import apply_rotary, apply_rotary_at, rotary_freqs
 from orion_tpu.ops.softmax_attention import (
     _NEG, cached_attention, softmax_attention,
 )
-from orion_tpu.utils.profiling import scope
+from orion_tpu.utils.profiling import scoped
 
 Array = jax.Array
 
 
-def _scoped(method):
-    """Run a mixer method under the ``full_attention`` named scope."""
-
-    @functools.wraps(method)
-    def wrapper(self, *args, **kwargs):
-        with scope("full_attention"):
-            return method(self, *args, **kwargs)
-
-    return wrapper
+_scoped = scoped("full_attention")
 
 
 def _window(cfg: ModelConfig, layer_type: str) -> Optional[int]:
@@ -109,17 +100,10 @@ class SoftmaxAttention(Mixer):
         that carried the cache would copy it at its entry)."""
         if _window(cfg, layer_type) is not None:
             return carried
-        out = {}
-        for n in ("k", "v"):
-            cache, new = held[n], carried[n + "n"]
-            for b in range(cache.shape[0]):
-                at = (b, 0, carried["t0"][b], 0)
-                old = jax.lax.dynamic_slice(cache, at, (1,) + new.shape[1:])
-                cache = jax.lax.dynamic_update_slice(
-                    cache, jnp.where(live[b], new[b][None], old), at
-                )
-            out[n] = cache
-        return out
+        return {
+            n: merge_chunk_rows(held[n], carried[n + "n"], carried["t0"], live)
+            for n in ("k", "v")
+        }
 
     # -- parallel forward ---------------------------------------------------
 
@@ -423,7 +407,7 @@ class SoftmaxAttention(Mixer):
             kn=state["kn"].at[b_idx, :, j, :].set(kr.astype(state["kn"].dtype)),
             vn=state["vn"].at[b_idx, :, j, :].set(v.astype(state["vn"].dtype)),
         )
-        out = _chunk_local_attention(qr, new, t, rows, self.cfg.backend)
+        out = chunk_local_attention(qr, new, t, rows, self.cfg.backend)
         return out, new
 
     @_scoped
@@ -499,27 +483,44 @@ class SoftmaxAttention(Mixer):
         return self._merge(out, single=True), {"k": kc, "v": vc}
 
 
-def _chunk_local_attention(q, state, t, rows, backend):
+def merge_chunk_rows(cache: Array, new: Array, t0: Array, live: Array) -> Array:
+    """A chunk's own rows ``new`` [B, H, n, Dh] into ``cache`` [B, H, cap,
+    Dh] at each sequence's ``t0``, the rows of a sequence outside ``live``
+    keeping their bits: one in-place slice update a sequence."""
+    for b in range(cache.shape[0]):
+        at = (b, 0, t0[b], 0)
+        old = jax.lax.dynamic_slice(cache, at, (1,) + new.shape[1:])
+        cache = jax.lax.dynamic_update_slice(
+            cache, jnp.where(live[b], new[b][None], old), at
+        )
+    return cache
+
+
+def chunk_local_attention(q, state, t, rows, backend, blocks=None):
     """One query per sequence over the held cache's rows before ``t0``
-    (:func:`ops.dispatch.cache_attention`) and the chunk's own rows up to
-    ``t - t0`` (4 MB at the served widths: scored here), merged by their
-    log-sum-exps: the softmax over the two key sets side by side. A
-    sequence with no held rows, or one the row list leaves out, weighs its
-    held part ``sigmoid(-1e30 - lse) = 0``: the chunk's part alone."""
+    (:func:`ops.dispatch.cache_attention`; of the listed ``blocks`` only,
+    where given) and the chunk's own rows up to ``t - t0`` (4 MB at the
+    served widths: scored here), merged by their log-sum-exps: the softmax
+    over the two key sets side by side. q is ``[B, H, Dh]`` and the caches
+    hold ``KV`` heads, ``H / KV`` query heads to each. A sequence with no
+    held rows, or one the row list leaves out, weighs its held part
+    ``sigmoid(-1e30 - lse) = 0``: the chunk's part alone."""
     f32 = jnp.float32
+    b, h, d = q.shape
+    kvh = state["kn"].shape[1]
     j = t - state["t0"]  # [B]: this step's row in the chunk
     held, lse_held = cache_attention(
-        q, state["k"], state["v"], state["t0"], rows, backend=backend
+        q, state["k"], state["v"], state["t0"], rows, backend=backend, blocks=blocks
     )
-    qf = q.astype(f32) * q.shape[-1] ** -0.5
-    s = jnp.einsum("bhd,bhsd->bhs", qf, state["kn"].astype(f32))
-    s = jnp.where(jnp.arange(s.shape[-1])[None, None] <= j[:, None, None], s, _NEG)
+    qg = (q.astype(f32) * d ** -0.5).reshape(b, kvh, h // kvh, d)
+    s = jnp.einsum("bkgd,bksd->bkgs", qg, state["kn"].astype(f32))
+    s = jnp.where(jnp.arange(s.shape[-1]) <= j[:, None, None, None], s, _NEG)
     lse_own = jax.nn.logsumexp(s, axis=-1)
     own = jnp.einsum(
-        "bhs,bhsd->bhd", jnp.exp(s - lse_own[..., None]), state["vn"].astype(f32)
-    )
+        "bkgs,bksd->bkgd", jnp.exp(s - lse_own[..., None]), state["vn"].astype(f32)
+    ).reshape(b, h, d)
     # the held part's share of the joint softmax's mass
-    w = jax.nn.sigmoid(lse_held - lse_own)[..., None]
+    w = jax.nn.sigmoid(lse_held - lse_own.reshape(b, h))[..., None]
     return (own + w * (held - own)).astype(q.dtype)
 
 

@@ -115,16 +115,23 @@ class Block(nn.Module):
         y = f(x)
         return (norm(y[0]),) + tuple(y[1:]) if isinstance(y, tuple) else norm(y)
 
+    def _branch(self, y):
+        """A residual branch's output, scaled by ``cfg.residual_scale``
+        where the configuration sets one (1.0: the program as it was)."""
+        a = self.cfg.residual_scale
+        return y if a == 1.0 else (y.astype(jnp.float32) * a).astype(y.dtype)
+
     def _mlp_residual(self, x):
-        return x + self._sublayer(self.norm2, self.mlp, x)
+        return x + self._branch(self._sublayer(self.norm2, self.mlp, x))
 
     def __call__(self, x, mask=None, deterministic=True):
         x = x + self.drop(
-            self._sublayer(self.norm1, lambda y: self.attn(y, mask), x),
+            self._branch(self._sublayer(self.norm1, lambda y: self.attn(y, mask), x)),
             deterministic=deterministic,
         )
         x = x + self.drop(
-            self._sublayer(self.norm2, self.mlp, x), deterministic=deterministic
+            self._branch(self._sublayer(self.norm2, self.mlp, x)),
+            deterministic=deterministic,
         )
         return x
 
@@ -132,26 +139,26 @@ class Block(nn.Module):
         h, state = self._sublayer(
             self.norm1, lambda y: self.attn.prefill(y, length), x
         )
-        return self._mlp_residual(x + h), state
+        return self._mlp_residual(x + self._branch(h)), state
 
     def prefill_extend(self, x, state, offset, length):
         h, state = self._sublayer(
             self.norm1,
             lambda y: self.attn.prefill_extend(y, state, offset, length), x,
         )
-        return self._mlp_residual(x + h), state
+        return self._mlp_residual(x + self._branch(h)), state
 
     def decode_step(self, x, state, t, rows=None):
         h, state = self._sublayer(
             self.norm1, lambda y: self.attn.decode_step(y, state, t, rows), x
         )
-        return self._mlp_residual(x + h), state
+        return self._mlp_residual(x + self._branch(h)), state
 
     def verify_extend(self, x, state, t):
         h, upd = self._sublayer(
             self.norm1, lambda y: self.attn.verify_extend(y, state, t), x
         )
-        return self._mlp_residual(x + h), upd
+        return self._mlp_residual(x + self._branch(h)), upd
 
 
 class TransformerLM(nn.Module):
@@ -212,6 +219,18 @@ class TransformerLM(nn.Module):
                 )
 
     def _embed(self, tokens: Array, positions: Array) -> Array:
+        """The input embedding, times ``cfg.embed_scale`` where set."""
+        x = self._embed_rows(tokens, positions)
+        a = self.cfg.embed_scale
+        return x if a == 1.0 else (x.astype(jnp.float32) * a).astype(x.dtype)
+
+    def _final(self, x: Array) -> Array:
+        """The head's input: the final norm, times ``cfg.logit_scale``."""
+        x = self.final_norm(x)
+        a = self.cfg.logit_scale
+        return x if a == 1.0 else (x.astype(jnp.float32) * a).astype(x.dtype)
+
+    def _embed_rows(self, tokens: Array, positions: Array) -> Array:
         if self.mesh is None or self.quant:
             # quant mode skips the fsdp replicated-constraint trick below:
             # the int8 table is 4x smaller and the sharding rules store
@@ -258,7 +277,7 @@ class TransformerLM(nn.Module):
     def _head(self, x: Array) -> Array:
         """final_norm + head matmul (prefill/decode call this on raw block
         output)."""
-        return self._head_matmul(self.final_norm(x))
+        return self._head_matmul(self._final(x))
 
     def _head_matmul(self, x: Array) -> Array:
         """Logits in fp32, but the matmul itself runs in the compute dtype
@@ -301,7 +320,7 @@ class TransformerLM(nn.Module):
         x = self._embed(tokens, jnp.arange(t))
         for blk in self.blocks:
             x = blk(x, None, deterministic)
-        return self.final_norm(x)
+        return self._final(x)
 
     def head_weight(self, params) -> Tuple[Array, bool]:
         """(head weight array, w_is_vd) for ops/fused_ce.py — the tied

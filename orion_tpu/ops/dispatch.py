@@ -69,11 +69,26 @@ def causal_dot_product(
     chunk: Optional[int] = None,
     return_state: bool = False,
     initial_state=None,
+    decay=None,
+    length=None,
 ):
     """Dispatch ``out[t] = sum_{s<=t}(q_t.k_s) v_s`` to the chosen backend.
 
     ``return_state`` additionally returns the final S = sum k_s ⊗ v_s (fp32).
+
+    ``decay`` [H] (fp32 slopes ``-log lam_h`` over the heads axis -3) makes
+    it the decayed form, ``out[t] = sum_{s<=t} lam^(t-s) (q_t.k_s) v_s`` with
+    ``S_t = lam S_{t-1} + k_t ⊗ v_t`` (``ops/linear_attention.py`` section
+    4): always ``(out, S)``, S after the first ``length`` rows (a traced
+    count of real rows before right-padding; default all) from
+    ``initial_state``; a Mosaic kernel under a Pallas backend, forward only,
+    the chunked ``jnp`` form otherwise. ``decay=None`` is the path above,
+    untouched.
     """
+    if decay is not None:
+        return _decayed_causal_dot(
+            q, k, v, decay, backend, chunk, initial_state, length
+        )
     # NB: `from orion_tpu.ops import linear_attention` would resolve to the
     # *function* re-exported by ops/__init__, which shadows the submodule of
     # the same name — import the callables by full dotted path instead.
@@ -117,6 +132,28 @@ def causal_dot_product(
         )
     return causal_dot_product_chunked(
         q, k, v, chunk=chunk, return_state=return_state, initial_state=initial_state
+    )
+
+
+def _decayed_causal_dot(q, k, v, decay, backend, chunk, initial_state, length):
+    b = resolve(backend)
+    chunk = resolve_chunk(chunk, q.shape[-2], b)
+    if b.startswith("pallas"):
+        from orion_tpu.ops.pallas.causal_dot import decayed_causal_dot_pallas
+
+        return decayed_causal_dot_pallas(
+            q, k, v, decay, chunk=chunk, initial_state=initial_state,
+            length=length, interpret=(b == "pallas_interpret"),
+        )
+    from orion_tpu.ops.linear_attention import (
+        decayed_causal_dot_chunked,
+        decayed_causal_dot_eager,
+    )
+
+    if b == "eager" and length is None:
+        return decayed_causal_dot_eager(q, k, v, decay, initial_state)
+    return decayed_causal_dot_chunked(
+        q, k, v, decay, chunk=chunk, initial_state=initial_state, length=length
     )
 
 
@@ -210,7 +247,9 @@ def gated_delta_step(q, k, v, beta, g, state, rows=None, *, backend: str = "auto
     return step(q, k, v, beta, g, state)
 
 
-def cache_attention(q, k_cache, v_cache, lengths, rows=None, *, backend: str = "auto"):
+def cache_attention(
+    q, k_cache, v_cache, lengths, rows=None, *, backend: str = "auto", blocks=None
+):
     """Decode attention of one query a sequence over the first ``lengths``
     [B] rows of its KV cache: q ``[B, H, Dh]``, caches ``[B, H, cap, Dh]``
     -> ``(out [B, H, Dh], lse [B, H])`` in fp32, the softmax over those
@@ -220,7 +259,20 @@ def cache_attention(q, k_cache, v_cache, lengths, rows=None, *, backend: str = "
     unlisted one nothing (``ops/pallas/cache_attention.py``: its ``out`` is
     0 and its ``lse`` -1e30, the weight of an empty key set); otherwise
     every sequence multiplies and reduces over its whole reservation under
-    a mask (``ops/softmax_attention.py::cached_attention``)."""
+    a mask (``ops/softmax_attention.py::cached_attention``).
+
+    ``blocks`` = (list ``[B, KV, L]`` int32, counts ``[B, KV]``, block rows)
+    restricts each (sequence, KV head) to the first ``counts`` cache blocks
+    its list names, of which rows ``< lengths`` count; the caches are then
+    ``[B, KV, cap, Dh]`` and the ``H / KV`` query heads of a group share a
+    list. Under a Pallas backend with a row list the kernel
+    ``ops/pallas/cache_attention.py::block_attention`` fetches the listed
+    blocks only; otherwise they are gathered
+    (``ops/softmax_attention.py::cached_block_attention``)."""
+    if blocks is not None:
+        return _block_list_attention(
+            q, k_cache, v_cache, lengths, rows, blocks, backend
+        )
     if rows is not None and row_sparse(backend):
         from orion_tpu.ops.pallas import cache_attention as pca
 
@@ -236,14 +288,52 @@ def cache_attention(q, k_cache, v_cache, lengths, rows=None, *, backend: str = "
     return cached_attention(q, k_cache, v_cache, valid, with_lse=True)
 
 
-def decode_state_step(q, k, v, state, rows=None, *, backend: str = "auto"):
+def _block_list_attention(q, k_cache, v_cache, lengths, rows, blocks, backend):
+    lists, counts, size = blocks
+    b, kvh, cap, d = k_cache.shape
+    qg = q.reshape(b, kvh, q.shape[1] // kvh, d)
+    if rows is not None and row_sparse(backend):
+        from orion_tpu.ops.pallas import cache_attention as pca
+
+        out, lse = pca.block_attention(
+            qg, k_cache, v_cache, lengths, lists, counts, rows, block=size,
+            interpret=(resolve(backend) == "pallas_interpret"),
+        )
+    else:
+        from orion_tpu.ops.softmax_attention import cached_block_attention
+
+        out, lse = cached_block_attention(
+            qg, k_cache, v_cache, lengths, lists, counts, size
+        )
+    return out.reshape(b, -1, d), lse.reshape(b, -1)
+
+
+def decode_state_step(
+    q, k, v, state, rows=None, *, backend: str = "auto", decay=None
+):
     """One decode step of the linear layers' ``(S, z)`` state.
 
     ``rows`` is :func:`decode_live_rows` of the chunk's row mask, or None
     when every row steps (the lockstep programs, and every program where
     the backend is not Pallas). With a row list under a Pallas backend
     only the listed rows are read, updated and written, in place;
-    otherwise this is ``recurrent_step`` on all rows."""
+    otherwise this is ``recurrent_step`` on all rows.
+
+    ``decay`` [H] (slopes ``-log lam_h``) makes it the decayed step with no
+    normaliser: ``state`` is ``S`` alone, ``S <- lam S + k ⊗ v; out = q . S``
+    -> ``(out, S)``, through the same row list (``ops/pallas/
+    decode_state.py::decay_state_step``) or on every row."""
+    if decay is not None:
+        if rows is not None and row_sparse(backend):
+            from orion_tpu.ops.pallas import decode_state as pds
+
+            return pds.decay_state_step(
+                q, k, v, state, decay, rows,
+                interpret=(resolve(backend) == "pallas_interpret"),
+            )
+        from orion_tpu.ops.linear_attention import decayed_recurrent_step
+
+        return decayed_recurrent_step(q, k, v, state, decay)
     if rows is not None and row_sparse(backend):
         from orion_tpu.ops.pallas import decode_state as pds
 

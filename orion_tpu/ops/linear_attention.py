@@ -351,7 +351,131 @@ def linear_attention_noncausal(
     return (num / den).astype(q.dtype)
 
 
+# ---------------------------------------------------------------------------
+# 4. Decayed linear attention: a per-head scalar decay, no normaliser
+# ---------------------------------------------------------------------------
+# S_t = lam_h S_{t-1} + k_t^T v_t,  out_t = q_t S_t,  lam_h = exp(-slope_h).
+# Every power of lam is built as exp(-slope * n) with n >= 0, never as a
+# ratio of powers (lam^t underflows long before a sequence ends).
+
+
+def decay_slopes(n_heads: int, exponent: float = 8.0) -> Array:
+    """[H] fp32 ``-log lam_h`` of the fixed per-head decays ``lam_h =
+    exp(-2^(-exponent h / H))``, h = 1..H: head 1 forgets fastest."""
+    h = jnp.arange(1, n_heads + 1, dtype=jnp.float32)
+    return jnp.exp2(-exponent * h / n_heads)
+
+
+def decayed_causal_dot_eager(
+    q: Array, k: Array, v: Array, slopes: Array, initial_state=None,
+) -> Tuple[Array, Array]:
+    """The token recurrence itself, a scan over T: q, k ``[..., H, T, Dk]``,
+    v ``[..., H, T, Dv]``, ``slopes`` [H] -> (out, final S fp32)."""
+    qf, kf, vf = _f32(q, k, v)
+    lam = jnp.exp(-slopes.astype(jnp.float32))[:, None, None]
+    s0 = (
+        jnp.zeros(qf.shape[:-2] + (qf.shape[-1], vf.shape[-1]), jnp.float32)
+        if initial_state is None else initial_state.astype(jnp.float32)
+    )
+
+    def body(s, qkv):
+        qi, ki, vi = qkv
+        s = lam * s + ki[..., :, None] * vi[..., None, :]
+        return s, jnp.einsum("...d,...de->...e", qi, s)
+
+    steps = tuple(jnp.moveaxis(x, -2, 0) for x in (qf, kf, vf))
+    s, out = jax.lax.scan(body, s0, steps)
+    return jnp.moveaxis(out, 0, -2).astype(q.dtype), s
+
+
+def decay_chunk_terms(slopes: Array, chunk: int, real: Array):
+    """What one chunk of the decayed form multiplies by, from ``slopes``
+    [H] and the number ``real`` (traced, 0..chunk) of the chunk's rows that
+    are not padding: (``within`` [H, C, C], ``exp(-a (i - s))`` for ``s <=
+    i`` else 0; ``carried`` [H, C], ``exp(-a (i + 1))``, what the state
+    carried in has decayed by at row i; ``into`` [H, C], ``exp(-a (real -
+    1 - s))`` for ``s < real`` else 0, a row's weight in the state carried
+    out; ``through`` [H], ``exp(-a real)``)."""
+    a = slopes.astype(jnp.float32)[:, None]
+    i = jnp.arange(chunk, dtype=jnp.float32)
+    gap = i[:, None] - i[None, :]
+    within = jnp.where(gap >= 0, jnp.exp(-a[..., None] * jnp.maximum(gap, 0)), 0.0)
+    carried = jnp.exp(-a * (i + 1))
+    left = real.astype(jnp.float32) - 1 - i
+    into = jnp.where(left >= 0, jnp.exp(-a * jnp.maximum(left, 0)), 0.0)
+    through = jnp.exp(-a[:, 0] * real.astype(jnp.float32))
+    return within, carried, into, through
+
+
+@partial(jax.jit, static_argnames=("chunk",))
+def decayed_causal_dot_chunked(
+    q: Array, k: Array, v: Array, slopes: Array, chunk: int = 128,
+    initial_state: Optional[Array] = None, length: Optional[Array] = None,
+) -> Tuple[Array, Array]:
+    """Chunked form with a state in and out: per chunk of C rows, ``i``,
+    ``s`` counted from its first row,
+
+        out_i = lam^(i+1) q_i S + sum_{s<=i} lam^(i-s) (q_i . k_s) v_s
+        S    <- lam^n S + sum_{s<n} lam^(n-1-s) k_s^T v_s
+
+    with ``n`` the chunk's real rows: ``length`` (traced scalar; default T)
+    is how many of the T rows are real, the rest right-padding whose
+    outputs mean nothing and which leave the state as the last real row
+    left it. Returns (out in q's dtype, S after ``length`` rows, fp32)."""
+    orig_dtype = q.dtype
+    qf, kf, vf = _f32(q, k, v)
+    qf, t = _pad_chunks(qf, chunk)
+    kf, _ = _pad_chunks(kf, chunk)
+    vf, _ = _pad_chunks(vf, chunk)
+    batch_shape = qf.shape[:-2]
+    n = qf.shape[-2] // chunk
+    dk, dv = qf.shape[-1], vf.shape[-1]
+    length = jnp.asarray(t if length is None else length, jnp.int32)
+
+    def to_chunks(x, d):
+        return jnp.moveaxis(x.reshape(*batch_shape, n, chunk, d), -3, 0)
+
+    s0 = (
+        jnp.zeros(batch_shape + (dk, dv), jnp.float32)
+        if initial_state is None else initial_state.astype(jnp.float32)
+    )
+
+    def body(s, xs):
+        qi, ki, vi, c = xs
+        real = jnp.clip(length - c * chunk, 0, chunk)
+        within, carried, into, through = decay_chunk_terms(slopes, chunk, real)
+        scores = jnp.einsum("...td,...sd->...ts", qi, ki) * within
+        intra = jnp.einsum("...ts,...sd->...td", scores, vi)
+        inter = jnp.einsum("...td,...de->...te", qi * carried[..., None], s)
+        s = through[:, None, None] * s + jnp.einsum(
+            "...td,...te->...de", ki * into[..., None], vi
+        )
+        return s, intra + inter
+
+    s, out = jax.lax.scan(
+        body, s0,
+        (to_chunks(qf, dk), to_chunks(kf, dk), to_chunks(vf, dv), jnp.arange(n)),
+    )
+    out = jnp.moveaxis(out, 0, -3).reshape(*batch_shape, n * chunk, dv)
+    return out[..., :t, :].astype(orig_dtype), s
+
+
+def decayed_recurrent_step(
+    q: Array, k: Array, v: Array, s: Array, slopes: Array
+) -> Tuple[Array, Array]:
+    """One decode step: ``S <- lam S + k (x) v; out = q . S`` for q, k
+    ``[..., H, Dk]``, v ``[..., H, Dv]``, S ``[..., H, Dk, Dv]`` fp32."""
+    qf, kf, vf = _f32(q, k, v)
+    lam = jnp.exp(-slopes.astype(jnp.float32))[:, None, None]
+    sf = lam * s.astype(jnp.float32) + kf[..., :, None] * vf[..., None, :]
+    return jnp.einsum("...d,...de->...e", qf, sf).astype(q.dtype), sf
+
+
 __all__ = [
+    "decay_slopes",
+    "decayed_causal_dot_eager",
+    "decayed_causal_dot_chunked",
+    "decayed_recurrent_step",
     "causal_dot_product_eager",
     "causal_dot_product_chunked",
     "kv_state",

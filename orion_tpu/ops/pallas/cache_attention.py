@@ -228,4 +228,149 @@ def cache_attention(
     return out[:, :, 0, :], lse[:, :, 0, 0]
 
 
-__all__ = ["BLOCK_KV", "cache_attention", "kv_block", "rows_read"]
+# ---------------------------------------------------------------------------
+# Decode attention over a LIST of cache blocks per (listed row, KV head): the
+# block-sparse layers' query group against the blocks their selector chose.
+# ---------------------------------------------------------------------------
+
+
+def _group_dot(a: Array, b: Array, dims) -> Array:
+    """``a`` fp32 [G, X] against a cache block ``b`` [N, X'] contracting
+    ``dims``, products and sums in fp32: the module docstring's split of
+    the fp32 side into three bf16 terms, stacked as 3 G rows of one matmul."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    dims = (dims, ((), ()))
+    if b.dtype != bf16:
+        return jax.lax.dot_general(
+            a, b.astype(f32), dims, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=f32,
+        )
+    hi = a.astype(bf16)
+    rest = a - hi.astype(f32)
+    mid = rest.astype(bf16)
+    low = (rest - mid.astype(f32)).astype(bf16)
+    g = a.shape[0]
+    out = jax.lax.dot_general(
+        jnp.concatenate([hi, mid, low], axis=0), b, dims,
+        preferred_element_type=f32,
+    )
+    return out[:g] + out[g:2 * g] + out[2 * g:]
+
+
+def _block_kernel(bs, width, kvh, idx_ref, len_ref, cnt_ref, blk_ref,
+                  q_ref, k_ref, v_ref, o_in, lse_in, o_ref, lse_ref,
+                  m_scr, l_scr, acc_scr):
+    del o_in, lse_in  # aliased onto the outputs: what an unlisted row keeps
+    i, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    r = idx_ref[i]
+    length = len_ref[r]
+    n = cnt_ref[r * kvh + g]
+
+    @pl.when(j == 0)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j < n)
+    def _():
+        first = blk_ref[(r * kvh + g) * width + j] * bs
+        s = _group_dot(q_ref[0, 0], k_ref[0, 0], ((1,), (1,)))  # [G, bs]
+        at = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(at < length, s, _NEG)
+        v = v_ref[0, 0]
+        at = first + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+        v = jnp.where(at < length, v, jnp.zeros_like(v))
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # a block wholly past the length leaves the running maximum at
+        # _NEG: its weights are then exp(0), so zero them outright
+        p = jnp.where(s > 0.5 * _NEG, jnp.exp(s - m_new), 0.0)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + _group_dot(p, v, ((1,), (0,)))
+        m_scr[...] = m_new
+
+    @pl.when(j == width - 1)
+    def _():
+        l = l_scr[...]
+        safe = jnp.where(l == 0.0, 1.0, l)  # no live key: out 0, lse _NEG
+        o_ref[0, 0] = acc_scr[...] / safe
+        lse_ref[0, 0] = m_scr[...] + jnp.log(safe)
+
+
+def block_attention(
+    q: Array, k_cache: Array, v_cache: Array, lengths: Array, blocks: Array,
+    counts: Array, rows: Tuple[Array, Array], *, block: int,
+    interpret: bool = False,
+) -> Tuple[Array, Array]:
+    """q ``[B, KV, G, Dh]`` (G query heads a KV head); caches ``[B, KV, cap,
+    Dh]``; ``lengths`` [B] int32, each sequence's live cache rows;
+    ``blocks`` ``[B, KV, L]`` int32, for each (sequence, KV head) the cache
+    blocks of ``block`` rows to attend to, of which the first ``counts``
+    ``[B, KV]`` count (distinct, any order); rows = ``decode_state.
+    live_rows`` of the row mask. Returns (out ``[B, KV, G, Dh]``, lse ``[B,
+    KV, G]``), both fp32: for a listed row the softmax of its scaled scores
+    over the rows ``< length`` of its listed blocks applied to V, and their
+    log-sum-exp; ``(0, -1e30)`` where that key set is empty and for every
+    unlisted row, whose cache is never read. One grid step a listed block:
+    the K and V index maps take the block from the scalar-prefetched list,
+    and past the count repeat the last one, which is not fetched again."""
+    idx, count = rows
+    b, kvh, cap, d = k_cache.shape
+    g, width = q.shape[2], blocks.shape[-1]
+    shapes = (q.shape, v_cache.shape, lengths.shape, idx.shape, blocks.shape, counts.shape)
+    if shapes != ((b, kvh, g, d), k_cache.shape, (b,), (b,), (b, kvh, width), (b, kvh)):
+        raise ValueError(f"operands do not fit K {k_cache.shape}: {shapes}")
+    if k_cache.dtype != v_cache.dtype:
+        raise ValueError(f"one cache dtype: {k_cache.dtype}/{v_cache.dtype}")
+    if cap % block:
+        raise ValueError(f"a cache of {cap} rows is not whole blocks of {block}")
+    f32 = jnp.float32
+    qf = q.astype(f32) * d ** -0.5
+
+    def row(i, gg, j, idx, lens, cnt, blk):
+        return (idx[i], gg, 0, 0)
+
+    def kv(i, gg, j, idx, lens, cnt, blk):
+        r = idx[i]
+        at = r * kvh + gg
+        last = jnp.maximum(cnt[at] - 1, 0)
+        return (r, gg, blk[at * width + jnp.minimum(j, last)], 0)
+
+    vec = pl.BlockSpec((1, 1, g, d), row)
+    one = pl.BlockSpec((1, 1, g, 1), row)
+    tile = pl.BlockSpec((1, 1, block, d), kv)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(count[0], kvh, width),
+        in_specs=[vec, tile, tile, vec, one],
+        out_specs=[vec, one],
+        scratch_shapes=[
+            pltpu.VMEM((g, 1), f32), pltpu.VMEM((g, 1), f32), pltpu.VMEM((g, d), f32),
+        ],
+    )
+    i32 = jnp.int32
+    out, lse = pl.pallas_call(
+        functools.partial(_block_kernel, block, width, kvh),
+        name="block_attention",
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, kvh, g, d), f32),
+            jax.ShapeDtypeStruct((b, kvh, g, 1), f32),
+        ],
+        # operand numbering counts the four scalar-prefetch lists: the
+        # unlisted rows' output and log-sum-exp are operands 7 and 8
+        input_output_aliases={7: 0, 8: 1},
+        interpret=interpret,
+    )(
+        idx, lengths.astype(i32), counts.astype(i32).reshape(-1),
+        blocks.astype(i32).reshape(-1), qf, k_cache, v_cache,
+        jnp.zeros((b, kvh, g, d), f32), jnp.full((b, kvh, g, 1), _NEG, f32),
+    )
+    return out, lse[..., 0]
+
+
+__all__ = [
+    "BLOCK_KV", "block_attention", "cache_attention", "kv_block", "rows_read",
+]

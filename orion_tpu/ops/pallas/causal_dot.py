@@ -760,3 +760,106 @@ __all__ = [
     "linear_attention_pallas_fused",
     "linear_attention_pallas_parts",
 ]
+
+
+# ---------------------------------------------------------------------------
+# Decayed causal dot product: a per-head scalar decay on the carried state,
+# no normaliser, a state in and out (ops/linear_attention.py section 4 has
+# the equations; serving's prompt pieces run this, nothing trains it).
+# ---------------------------------------------------------------------------
+
+
+def _decay_kernel(len_ref, a_ref, q_ref, k_ref, v_ref, s0_ref, out_ref, sf_ref, s_scr):
+    """One chunk of C rows of one (batch, head): ``a`` is the head's slope
+    ``-log lam``; every power of lam is ``exp(-a n)`` with ``n >= 0``."""
+    c = pl.program_id(1)
+    f32 = jnp.float32
+
+    @pl.when(c == 0)
+    def _():
+        s_scr[:] = s0_ref[0].astype(f32)
+
+    qi, ki, vi = q_ref[0], k_ref[0], v_ref[0]
+    cdim = qi.shape[0]
+    a = a_ref[0]  # (1, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (cdim, cdim), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (cdim, cdim), 1)
+    gap = (row - col).astype(f32)
+    within = jnp.where(gap >= 0, jnp.exp(-a * jnp.maximum(gap, 0.0)), 0.0)
+    i = jax.lax.broadcasted_iota(jnp.int32, (cdim, 1), 0).astype(f32)
+    # rows of this chunk that are not right-padding
+    real = jnp.clip(len_ref[0] - c * cdim, 0, cdim).astype(f32)
+    left = real - 1.0 - i
+    into = jnp.where(left >= 0, jnp.exp(-a * jnp.maximum(left, 0.0)), 0.0)
+
+    scores = jax.lax.dot_general(
+        qi, ki, (((1,), (1,)), ((), ())), preferred_element_type=f32
+    ) * within
+    intra = jnp.dot(scores.astype(vi.dtype), vi, preferred_element_type=f32)
+    inter = jnp.dot(
+        qi.astype(f32) * jnp.exp(-a * (i + 1.0)), s_scr[:],
+        preferred_element_type=f32,
+    )
+    out_ref[0] = (intra + inter).astype(out_ref.dtype)
+    s_scr[:] = jnp.exp(-a * real) * s_scr[:] + jax.lax.dot_general(
+        (ki.astype(f32) * into).astype(vi.dtype), vi,
+        (((0,), (0,)), ((), ())), preferred_element_type=f32,
+    )
+    sf_ref[0] = s_scr[:]
+
+
+def decayed_causal_dot_pallas(
+    q: Array, k: Array, v: Array, slopes: Array, *, chunk: Optional[int] = None,
+    initial_state: Optional[Array] = None, length=None, interpret: bool = False,
+) -> Tuple[Array, Array]:
+    """``ops.linear_attention.decayed_causal_dot_chunked`` as a Mosaic
+    kernel: q, k ``[..., H, T, Dk]``, v ``[..., H, T, Dv]``, ``slopes`` [H]
+    fp32, ``initial_state`` ``[..., H, Dk, Dv]`` (zeros if None), ``length``
+    the traced number of real rows (default T) -> (out in q's dtype, the
+    state after ``length`` rows in fp32). The products against v take v's
+    dtype on the MXU with fp32 sums; the carried state and what multiplies
+    it stay fp32. Forward only."""
+    batch_shape = q.shape[:-2]
+    t, dk = q.shape[-2], q.shape[-1]
+    dv, h = v.shape[-1], q.shape[-3]
+    chunk = _auto_chunk(chunk, t)
+    bh = 1
+    for s in batch_shape:
+        bh *= s
+    qf, kf, vf = q.reshape(bh, t, dk), k.reshape(bh, t, dk), v.reshape(bh, t, dv)
+    rem = (-t) % chunk
+    if rem:
+        pad = ((0, 0), (0, rem), (0, 0))
+        qf, kf, vf = jnp.pad(qf, pad), jnp.pad(kf, pad), jnp.pad(vf, pad)
+    s0 = (
+        jnp.zeros((bh, dk, dv), jnp.float32) if initial_state is None
+        else initial_state.astype(jnp.float32).reshape(bh, dk, dv)
+    )
+    a = jnp.broadcast_to(
+        slopes.astype(jnp.float32), batch_shape[:-1] + (h,)
+    ).reshape(bh, 1, 1)
+    n = jnp.asarray(t if length is None else length, jnp.int32).reshape(1)
+    tp = t + rem
+    blk = lambda d: pl.BlockSpec((1, chunk, d), lambda b, c, n: (b, c, 0))  # noqa: E731
+    state = pl.BlockSpec((1, dk, dv), lambda b, c, n: (b, 0, 0))
+    out, sf = pl.pallas_call(
+        _decay_kernel,
+        name="causal_dot_decay_fwd",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, tp // chunk),
+            in_specs=[
+                pl.BlockSpec((1, 1, 1), lambda b, c, n: (b, 0, 0)),
+                blk(dk), blk(dk), blk(dv), state,
+            ],
+            out_specs=[blk(dv), state],
+            scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, tp, dv), q.dtype),
+            jax.ShapeDtypeStruct((bh, dk, dv), jnp.float32),
+        ],
+        interpret=interpret,
+    )(n, a, qf, kf, vf, s0)
+    out = out[:, :t, :].reshape(*batch_shape, t, dv)
+    return out, sf.reshape(*batch_shape, dk, dv)

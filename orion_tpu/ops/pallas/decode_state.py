@@ -221,4 +221,59 @@ def gated_delta_step(
 _DELTA_VMEM_BYTES = 64 << 20
 
 
-__all__ = ["decode_state_step", "gated_delta_step", "live_rows"]
+def _decay_kernel(rows_ref, s_ref, lam_ref, q_ref, k_ref, v_ref, s_out, o_ref):
+    del rows_ref  # consumed by the index maps
+    qf = q_ref[0].astype(jnp.float32)  # [H, Dk]
+    kf = k_ref[0].astype(jnp.float32)
+    vf = v_ref[0].astype(jnp.float32)  # [H, Dv]
+    sf = s_ref[0] * lam_ref[...][:, :, None] + kf[:, :, None] * vf[:, None, :]
+    s_out[0] = sf
+    o_ref[0] = jnp.sum(qf[:, :, None] * sf, axis=1).astype(o_ref.dtype)
+
+
+def decay_state_step(
+    q: Array, k: Array, v: Array, s: Array, slopes: Array,
+    rows: Tuple[Array, Array], *, interpret: bool = False,
+) -> Tuple[Array, Array]:
+    """``ops.linear_attention.decayed_recurrent_step`` for the rows ``rows``
+    lists: ``S <- lam S + k (x) v; out = q . S`` with ``lam = exp(-slopes)``
+    per head and no normaliser. q, k: [B, H, Dk]; v: [B, H, Dv] (one
+    dtype); ``s`` [B, H, Dk, Dv] fp32; ``slopes`` [H]. Returns (out [B, H,
+    Dv] in v's dtype, s): listed rows updated, every other row of ``s``
+    bitwise the input's (never touched) and of ``out`` its ``v`` row."""
+    idx, count = rows
+    b, h, dk, dv = s.shape
+    check_operands(q, k, v, s, jnp.zeros((b, h, dk), jnp.float32), idx)
+    lam = jnp.broadcast_to(
+        jnp.exp(-slopes.astype(jnp.float32))[:, None], (h, dk)
+    )
+    row3 = lambda i, rows: (rows[i], 0, 0)  # noqa: E731
+    row4 = lambda i, rows: (rows[i], 0, 0, 0)  # noqa: E731
+    key, val = pl.BlockSpec((1, h, dk), row3), pl.BlockSpec((1, h, dv), row3)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(count[0],),
+        in_specs=[
+            pl.BlockSpec((1, h, dk, dv), row4),
+            pl.BlockSpec((h, dk), lambda i, rows: (0, 0)),
+            key, key, val,
+        ],
+        out_specs=[pl.BlockSpec((1, h, dk, dv), row4), val],
+    )
+    s, out = pl.pallas_call(
+        _decay_kernel,
+        name="decay_state_step",
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct(s.shape, s.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        # operand numbering counts the scalar-prefetch list: S and v are
+        # operands 1 and 5
+        input_output_aliases={1: 0, 5: 1},
+        interpret=interpret,
+    )(idx, s, lam, q, k, v)
+    return out, s
+
+
+__all__ = ["decay_state_step", "decode_state_step", "gated_delta_step", "live_rows"]

@@ -152,8 +152,43 @@ def cached_attention(
     return out.astype(q.dtype)
 
 
+def cached_block_attention(
+    q: Array, k_cache: Array, v_cache: Array, lengths: Array, lists: Array,
+    counts: Array, size: int,
+):
+    """Decode-step attention of a query GROUP over listed cache blocks: q
+    ``[B, KV, G, Dh]``, caches ``[B, KV, cap, Dh]``, ``lists`` ``[B, KV, L]``
+    the blocks of ``size`` rows each (sequence, KV head) attends to, of
+    which the first ``counts`` ``[B, KV]`` count and rows ``< lengths`` [B]
+    are live. Gathers the listed blocks ``[B, KV, L, size, Dh]``, masks what
+    lies past the count or the length -> (out ``[B, KV, G, Dh]``, lse ``[B,
+    KV, G]``) in fp32; ``(0, -1e30)`` where the key set is empty."""
+    f32 = jnp.float32
+    b, kvh, cap, d = k_cache.shape
+    width = lists.shape[-1]
+
+    def tiles(c):
+        return jnp.take_along_axis(
+            c.reshape(b, kvh, cap // size, size, d), lists[..., None, None], axis=2
+        ).astype(f32)
+
+    at = lists[..., None] * size + jnp.arange(size)  # [B, KV, L, size]
+    live = (jnp.arange(width)[None, None, :, None] < counts[..., None, None]) & (
+        at < lengths[:, None, None, None]
+    )
+    s = jnp.einsum("bkgd,bklsd->bkgls", q.astype(f32), tiles(k_cache)) * d ** -0.5
+    s = jnp.where(live[:, :, None], s, _NEG).reshape(*s.shape[:3], -1)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    p = jnp.where(live[:, :, None].reshape(b, kvh, 1, -1), jnp.exp(s - lse[..., None]), 0.0)
+    v = jnp.where(live[..., None], tiles(v_cache), 0.0).reshape(b, kvh, -1, d)
+    empty = ~jnp.any(live, axis=(-2, -1))[..., None]
+    out = jnp.where(empty[..., None], 0.0, jnp.einsum("bkgs,bksd->bkgd", p, v))
+    return out, jnp.where(empty, _NEG, lse)
+
+
 __all__ = [
     "softmax_attention",
     "softmax_attention_xla",
     "cached_attention",
+    "cached_block_attention",
 ]

@@ -709,6 +709,25 @@ class SlotEngine:
             "decoding": self.active_count - prefilling,
         }
 
+    def _slot_ends(self) -> Dict[int, int]:
+        """slot -> its position once its staged prompt is consumed, from the
+        host's mirror (prompt consumed + tokens emitted), no readback."""
+        return {
+            i: s.prompt.shape[1] + sum(p.shape[1] for p in s.prior) + s.n_emitted
+            for i, s in enumerate(self._slots) if s is not None
+        }
+
+    def _emitting_ends(self, ends: Dict[int, int]) -> List[int]:
+        """The positions of the slots that will emit at the boundary about
+        to run: a slot emits once its prompt is consumed, at this boundary
+        if a piece of it ends the prompt."""
+        served = self._selected_prefill_slots([s is not None for s in self._slots])
+        piece = self._piece_tokens()
+        return [
+            end for i, end in ends.items()
+            if self._slots[i].prompt_remaining <= (piece if i in served else 0)
+        ]
+
     def kv_rows(self) -> Tuple[int, int, int]:
         """(live, reserved, read) rows of the KV caches, summed over slots:
         a slot reserves the cache's rows (``max_seq_len``, or the window of
@@ -718,40 +737,58 @@ class SlotEngine:
         step at the boundary about to run: every slot's reservation in
         the XLA form; under a row-list backend
         (``ops.dispatch.cache_attention``) the live KV blocks of each slot
-        that will emit, at the position its last step attends from, and
-        nothing for the others. (0, 0, 0) for a model without a cached
+        that will emit, at the position its last step attends from (for a
+        ``block_sparse`` layer the blocks its list holds, :meth:`kv_blocks`),
+        and nothing for the others. (0, 0, 0) for a model without a cached
         layer."""
         cfg = self.model.cfg
         kinds = set(cfg.resolved_layer_types)
-        cap = (cfg.max_seq_len if "softmax" in kinds
+        cap = (cfg.max_seq_len if kinds & {"softmax", "block_sparse"}
                else cfg.window if "swa" in kinds else 0)
-        ends = {  # slot -> its position once its staged prompt is consumed
-            i: s.prompt.shape[1] + sum(p.shape[1] for p in s.prior) + s.n_emitted
-            for i, s in enumerate(self._slots) if s is not None
-        }
+        ends = self._slot_ends()
         live = sum(
             min(cap, end - self._slots[i].prompt_remaining)
             for i, end in ends.items()
         )
         read = cap * self.slots
+        # the donated scan reads the cache as it stood at the scan's start;
+        # the scan that carries the cache reads the rows it wrote too
+        grown = 0 if self.donate_carry else self.chunk
         if "softmax" in kinds and row_sparse(cfg.backend):
             from orion_tpu.ops.pallas.cache_attention import rows_read
 
-            # a slot emits once its prompt is consumed, at this boundary
-            # if a piece of it ends the prompt. The donated scan reads the
-            # cache as it stood at the scan's start; the scan that carries
-            # the cache reads the rows it wrote too
-            served = self._selected_prefill_slots(
-                [s is not None for s in self._slots]
-            )
-            piece = self._piece_tokens()
-            grown = 0 if self.donate_carry else self.chunk
             read = sum(
                 rows_read(min(cap, end + grown), cap)
-                for i, end in ends.items()
-                if self._slots[i].prompt_remaining <= (piece if i in served else 0)
+                for end in self._emitting_ends(ends)
             )
+        elif "block_sparse" in kinds and row_sparse(cfg.backend):
+            read = cfg.sparse_block * self.kv_blocks()[1]
         return live, cap * self.slots, read
+
+    def kv_blocks(self) -> Tuple[int, int, int, int]:
+        """(live, read, sparse, dense) for ONE ``block_sparse`` layer at the
+        boundary about to run, over the slots that will emit, each at the
+        position its last step attends from: the cache blocks the slot holds
+        live, the blocks its decode attention lists (all of them under
+        ``sparse_dense_len``, ``sparse_topk`` past it), and how many of
+        those slots are past / under the switch. Zeros for a model without
+        such a layer."""
+        cfg = self.model.cfg
+        if "block_sparse" not in cfg.resolved_layer_types:
+            return 0, 0, 0, 0
+        from orion_tpu.models.mixers.block_sparse import blocks_read
+
+        grown = 0 if self.donate_carry else self.chunk
+        lengths = [
+            min(cfg.max_seq_len, end + grown)
+            for end in self._emitting_ends(self._slot_ends())
+        ]
+        sparse = sum(n > cfg.sparse_dense_len for n in lengths)
+        return (
+            sum(-(-n // cfg.sparse_block) for n in lengths),
+            sum(blocks_read(cfg, n) for n in lengths),
+            sparse, len(lengths) - sparse,
+        )
 
     def slot_info(self) -> List[Tuple[int, Any, str, int]]:
         """Per-resident-slot (index, tag, phase, request-local chunk

@@ -119,6 +119,13 @@ _SLOT_CLASS_KEYS = (
 _KV_ROW_KEYS = (
     "kv_rows_live", "kv_rows_reserved", "kv_rows_read", "slot_steps_emitting",
 )
+# the block-sparse layers' cache blocks at each boundary, per emitting slot
+# and sparse layer (``SlotEngine.kv_blocks``): blocks the slot holds live /
+# blocks its decode attention lists; and the emitting slots past / under
+# ``sparse_dense_len`` (0 for a model without such a layer)
+_KV_BLOCK_KEYS = (
+    "kv_blocks_live", "kv_blocks_read", "sparse_steps", "dense_steps",
+)
 
 
 class OverloadError(RuntimeError):
@@ -417,7 +424,7 @@ class Server:
         # chunk boundaries — no device syncs, no new compiles (lint rule
         # obs-device-sync + the cache-stat asserts in tests/test_obs.py)
         self.metrics = MetricsRegistry(clock=clock, lock=self._stats_lock)
-        for key in _STAT_KEYS + _SLOT_CLASS_KEYS + _KV_ROW_KEYS:
+        for key in _STAT_KEYS + _SLOT_CLASS_KEYS + _KV_ROW_KEYS + _KV_BLOCK_KEYS:
             self.metrics.counter(key)  # the legacy stats dict's cells
         self.trace = tracer if tracer is not None else Tracer(
             path=cfg.trace_path, clock=clock, enabled=bool(cfg.trace_path),
@@ -1979,6 +1986,7 @@ class Server:
             self._profile_maybe_start()
         occupied = self.engine.active_count
         kv_rows = self.engine.kv_rows()
+        kv_blocks = self.engine.kv_blocks()
         infos = self.engine.slot_info() if self.trace.enabled else ()
         t0 = self._clock()
         finished = ()
@@ -2026,6 +2034,14 @@ class Server:
                            occupied - prefilling - decoding)
                 for key, rows in zip(_KV_ROW_KEYS, kv_rows + (emitting,)):
                     self._bump(key, rows)
+                layers = self.engine.model.cfg.resolved_layer_types.count(
+                    "block_sparse"
+                )
+                live, read, sparse, dense = kv_blocks
+                for key, n in zip(
+                    _KV_BLOCK_KEYS, (live * layers, read * layers, sparse, dense)
+                ):
+                    self._bump(key, n)
                 # the tp label makes a fleet's per-footprint boundary cost
                 # separable at the aggregated endpoint (a tp=4 replica's
                 # chunks cost collectives a tp=1 replica's don't)

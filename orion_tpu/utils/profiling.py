@@ -12,6 +12,8 @@ Outside a capture an annotation costs a check of one flag.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 
 
@@ -28,6 +30,21 @@ def scope(name: str):
     return jax.named_scope(name)
 
 
+def scoped(name: str):
+    """Decorator: run a method under ``scope(name)`` (a mixer's serving
+    methods, so the scope reader finds them as it finds ``__call__``)."""
+
+    def decorate(method):
+        @functools.wraps(method)
+        def wrapper(*args, **kwargs):
+            with scope(name):
+                return method(*args, **kwargs)
+
+        return wrapper
+
+    return decorate
+
+
 def annotated_steps(name: str, steps):
     """``for step in annotated_steps("train", range(a, b)):`` — each
     iteration's body runs inside a ``StepTraceAnnotation`` (the profiler
@@ -38,4 +55,4 @@ def annotated_steps(name: str, steps):
             yield step
 
 
-__all__ = ["annotate", "annotated_steps", "scope"]
+__all__ = ["annotate", "annotated_steps", "scope", "scoped"]

@@ -7,7 +7,7 @@ differs), whatever lies at or past ``length`` (NaN planted there changes
 nothing: a dead block is never read and the last live one is masked); an
 unlisted row comes out as ``(0, -1e30)`` and its cache is never read.
 Merged with the chunk's own rows (``models/mixers/softmax.py::
-_chunk_local_attention``) that is the concatenated form the mixer used to
+chunk_local_attention``) that is the concatenated form the mixer used to
 compute over the whole reservation.
 """
 
@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from orion_tpu.models.mixers.softmax import _chunk_local_attention
+from orion_tpu.models.mixers.softmax import chunk_local_attention
 from orion_tpu.ops import dispatch
 from orion_tpu.ops.pallas.cache_attention import (
     BLOCK_KV, cache_attention, kv_block, rows_read,
@@ -84,7 +84,7 @@ def test_kernel_and_merge_equal_the_concatenated_form(length, dtype):
     state = dict(clean, k=_poison(k, dead), v=_poison(v, dead))
     rows = live_rows(mask)
     got = jax.jit(
-        lambda q, s, t: _chunk_local_attention(q, s, t, rows, "pallas_interpret")
+        lambda q, s, t: chunk_local_attention(q, s, t, rows, "pallas_interpret")
     )(q, state, t0 + j)
     assert got.dtype == q.dtype
     got = np.asarray(got, np.float32)

@@ -144,6 +144,25 @@ def _cache_attention(q, k, v, lengths, live):
     return cache_attention(q, k, v, lengths, live_rows(live))
 
 
+def _decay_step(s, q, k, v, slopes, live):
+    from orion_tpu.ops.pallas.decode_state import decay_state_step, live_rows
+
+    return decay_state_step(q, k, v, s, slopes, live_rows(live))
+
+
+def _decay_piece(q, k, v, slopes, s0, n):
+    from orion_tpu.ops.pallas.causal_dot import decayed_causal_dot_pallas
+
+    return decayed_causal_dot_pallas(q, k, v, slopes, chunk=CHUNK, initial_state=s0, length=n)
+
+
+def _block_attention(q, k, v, lengths, lists, counts, live):
+    from orion_tpu.ops.pallas.cache_attention import block_attention
+    from orion_tpu.ops.pallas.decode_state import live_rows
+
+    return block_attention(q, k, v, lengths, lists, counts, live_rows(live), block=64)
+
+
 _QKV = [(BHTD, jnp.bfloat16)] * 3
 # qwen3_next_80b's train point (batch 8, T 8192): its softmax layer's 16
 # heads of 256 after the KV heads are repeated; its held experts' buffer
@@ -176,6 +195,19 @@ _DELTA_STATE = [((64, 30, 96, 192), jnp.float32),
 _KV_CACHE = [((64, 30, 128), jnp.bfloat16),
              *[((64, 30, 4096, 128), jnp.bfloat16)] * 2,
              ((64,), jnp.int32), ((64,), jnp.bool_)]
+# minicpm_sala served (32 slots x 16,896): the decayed step of 32 heads'
+# fp32 128 x 128 state; one slot's 1,024-token prompt piece through the
+# decayed chunk kernel with a state in and out; a token's 16-head query
+# group a KV head against the 128 listed 64-row blocks of a 2-head cache
+_DECAY_STATE = [((32, 32, 128, 128), jnp.float32),
+                *[((32, 32, 128), jnp.bfloat16)] * 3,
+                ((32,), jnp.float32), ((32,), jnp.bool_)]
+_DECAY_PIECE = [*[((1, 32, 1024, 128), jnp.bfloat16)] * 3, ((32,), jnp.float32),
+                ((1, 32, 128, 128), jnp.float32), ((), jnp.int32)]
+_BLOCK_LIST = [((32, 2, 16, 128), jnp.bfloat16),
+               *[((32, 2, 16896, 128), jnp.bfloat16)] * 2,
+               ((32,), jnp.int32), ((32, 2, 128), jnp.int32),
+               ((32, 2), jnp.int32), ((32,), jnp.bool_)]
 # moe_1b3_4e dropless: 24576 padded rows through 4 experts of 2048 x 5504
 _GMM = [((24576, 2048), jnp.bfloat16), ((4, 2048, 5504), jnp.bfloat16),
         ((4,), jnp.int32)]
@@ -211,6 +243,9 @@ KERNELS = [
                  id="gated_delta-state-96x192-piece1024"),
     pytest.param(_delta_step, _DELTA_STATE, id="gated_delta_step-64slots"),
     pytest.param(_cache_attention, _KV_CACHE, id="cache_attention-64slots"),
+    pytest.param(_decay_step, _DECAY_STATE, id="decay_state_step-32slots"),
+    pytest.param(_decay_piece, _DECAY_PIECE, id="causal_dot_decay-piece1024"),
+    pytest.param(_block_attention, _BLOCK_LIST, id="block_attention-32slots-128blocks"),
     # -- the rest of the main path's kernels ---------------------------------
     pytest.param(_plain, _QKV, id="causal_dot-plain-fwd", marks=slow),
     pytest.param(_grad3(_plain), _QKV, id="causal_dot-plain-bwd", marks=slow),
@@ -366,6 +401,57 @@ def test_olmo_hybrid_boundary_programs_hold_the_carry_once(v5e):
             # but what the kernel stages in VMEM (its K and V blocks)
             assert "cache_attention" in compiled.as_text()
             assert m.temp_size_in_bytes < 0.08e9 + _KERNEL_VMEM, m.temp_size_in_bytes
+
+
+def test_minicpm_sala_boundary_programs_compile_and_fit(v5e):
+    """``minicpm_sala.serve_long``'s programs at 32 slots x 16,896 for the
+    chip: the unified prefill + decode boundary and the decode-only one (the
+    carry, 0.8 GB, fits twice beside 3.4 GB of weights, so the cell donates
+    nothing), and the donated scan an engine with more or longer slots
+    would run, which holds K and V once (``chunk_split``: no temporary of a
+    cache's size). Each fits 16 GB and holds the new kernels. A compile,
+    not a chip run."""
+    from orion_tpu import generate as gen
+    from orion_tpu.generate import SampleConfig
+    from orion_tpu.models.configs import get_config
+    from orion_tpu.models.transformer import TransformerLM, init_decode_state
+
+    slots, chunk, piece, width = 32, 4, 1024, 16384
+    cfg = dataclasses.replace(get_config("minicpm_sala"), backend="pallas")
+    model = TransformerLM(cfg)
+    one = SingleDeviceSharding(v5e[0])
+    put = lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one)  # noqa: E731
+    arr = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    params = jax.tree.map(put, jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 16), jnp.int32))))
+    states = jax.tree.map(put, jax.eval_shape(lambda: init_decode_state(cfg, slots)))
+    ints, flags = arr((slots,), jnp.int32), arr((slots,), jnp.bool_)
+    carry = (ints, states, ints, ints, flags)
+    rngs, pbuf = arr((slots, 2), jnp.uint32), arr((slots, width), jnp.int32)
+    sample = SampleConfig(temperature=0.0)
+    programs = {
+        "unified": gen._decode_batched_prefill_chunk_jit.lower(
+            model, params, carry, rngs, flags, pbuf, ints, ints, ints, chunk, piece, sample),
+        "decode": gen._decode_batched_chunk_jit.lower(
+            model, params, carry, rngs, flags, chunk, sample),
+        "donated scan": gen._decode_scan_donated_jit.lower(
+            model, params, carry, rngs, flags, ints, chunk, sample),
+    }
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        m = compiled.memory_analysis()
+        live = (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes)
+        assert live < 16e9, (name, live)
+        text = compiled.as_text()
+        assert "decay_state_step" in text and "block_attention" in text, name
+        if name == "unified":
+            assert "causal_dot_decay_fwd" in text
+        if name == "donated scan":
+            # K or V alone is 0.277 GB; a gather of the held keys had the
+            # compiler copy K (0.2835 GB of temporaries), slices do not
+            assert m.temp_size_in_bytes < 0.03e9, m.temp_size_in_bytes
+            assert m.alias_size_in_bytes > 0.77e9, m.alias_size_in_bytes
 
 
 @slow

@@ -18,7 +18,7 @@ from orion_tpu.models.configs import LAYER_TYPES, get_config, hybrid_pattern
 from orion_tpu.models.mixers import MIXERS, Mixer
 from orion_tpu.models.transformer import TransformerLM, init_decode_state
 
-SERVED = ("linear", "softmax", "swa", "gated_delta")
+SERVED = ("linear", "softmax", "swa", "gated_delta", "decay_linear", "block_sparse")
 TRAIN_ONLY = ("gated_softmax",)
 
 # benchmark/configs/qwen3_next_80b.json's ``rehearse`` sizes
@@ -35,6 +35,8 @@ def one_layer(lt):
     return get_config(
         "tiny", n_layers=1, layer_types=(lt,), window=8, max_seq_len=32,
         gdn_key_heads=2, gdn_value_heads=4, gdn_key_dim=8, gdn_value_dim=16,
+        n_kv_heads=2, sparse_kernel=4, sparse_stride=2, sparse_block=8,
+        sparse_window=8, sparse_topk=2, sparse_dense_len=16,
     )
 
 
@@ -42,7 +44,7 @@ def test_registry_has_one_mixer_per_layer_type():
     assert set(MIXERS) == set(LAYER_TYPES)
     assert all(issubclass(m, Mixer) for m in MIXERS.values())
     assert {lt for lt, m in MIXERS.items() if m.rows_in_place} == {
-        "linear", "softmax", "swa", "gated_delta"
+        "linear", "softmax", "swa", "gated_delta", "decay_linear", "block_sparse"
     }
 
 
@@ -92,17 +94,18 @@ def test_train_only_mixer_refuses_every_serving_entry_point(lt):
         init_decode_state(cfg, 1)
 
 
-def test_gated_delta_has_no_speculative_pair():
+@pytest.mark.parametrize("lt", ["gated_delta", "decay_linear", "block_sparse"])
+def test_served_without_a_speculative_pair(lt):
     """Served (prefill, pieces, the decode step), but the speculative
     verify / advance entry points stay the base class's, which raise."""
-    cfg = one_layer("gated_delta")
-    mixer = MIXERS["gated_delta"](cfg, "gated_delta")
+    cfg = one_layer(lt)
+    mixer = MIXERS[lt](cfg, lt)
     x = jnp.zeros((1, 8, cfg.d_model))
     params = mixer.init(jax.random.key(0), x)
     t, keep = jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32)
     for method, args in {"verify_extend": (x, {}, t),
                          "advance_verified": ({}, {}, t, keep)}.items():
-        with pytest.raises(NotImplementedError, match="gated_delta"):
+        with pytest.raises(NotImplementedError, match=repr(lt)):
             mixer.apply(params, *args, method=method)
 
 
