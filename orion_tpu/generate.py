@@ -388,7 +388,7 @@ def _decode_batched_chunk_jit(
         _decode_batched_body, model, params, sample_cfg, rngs, active,
         decode_live_rows(active, backend=model.cfg.backend),
     )
-    carry, tokens = jax.lax.scan(body, carry, None, length=n_steps)
+    carry, tokens = _scan_chunk(model, body, carry, n_steps, active, False)
     return carry, jnp.moveaxis(tokens, 0, 1)  # [S, n_steps]
 
 
@@ -457,6 +457,47 @@ def _freeze_rows(model, rows, mask: Array, new: Any, old: Any) -> Any:
         n if MIXERS[lt].rows_in_place else _where_rows(mask, n, o)
         for lt, n, o in zip(model.cfg.resolved_layer_types, new, old)
     ]
+
+
+def _scan_chunk(model, step, carry, n_steps: int, live: Array, donated: bool):
+    """The chunk's decode scan, shared by the three slot-multiplexed
+    programs: ``n_steps`` of ``step`` (a decode body with everything but
+    ``(carry, _)`` bound) from ``carry``. Each layer's state is split by
+    its ``Mixer.chunk_split``: the scan carries one part and closes over
+    the other, which it only reads (the linear layers' ``(S, z)`` under a
+    row-list backend, written once a chunk; a KV cache where the program's
+    carry is ``donated`` and held once), and ``Mixer.chunk_merge`` puts the
+    two together after it for the rows of ``live`` [S]. Where no layer
+    holds anything this is the plain scan over the whole carry. Returns
+    (carry, tokens [n_steps, S])."""
+    token, states, t, emit, done = carry
+    kinds = model.cfg.resolved_layer_types
+    split = [
+        MIXERS[lt].chunk_split(model.cfg, lt, st, n_steps, t, donated)
+        for lt, st in zip(kinds, states)
+    ]
+    held = [h for h, _ in split]
+    if not any(held):
+        return jax.lax.scan(step, carry, None, length=n_steps)
+
+    def body(c, _):
+        token, carried, t, emit, done = c
+        whole = [{**h, **cc} for h, cc in zip(held, carried)]
+        (token, new, t, emit, done), emitted = step(
+            (token, whole, t, emit, done), None
+        )
+        carried = [{k: st[k] for k in cc} for st, cc in zip(new, carried)]
+        return (token, carried, t, emit, done), emitted
+
+    (token, carried, t, emit, done), tokens = jax.lax.scan(
+        body, (token, [c for _, c in split], t, emit, done), None,
+        length=n_steps,
+    )
+    states = [
+        MIXERS[lt].chunk_merge(model.cfg, lt, h, c, live)
+        for lt, h, c in zip(kinds, held, carried)
+    ]
+    return (token, states, t, emit, done), tokens
 
 
 @partial(jax.jit, static_argnums=(0, 7))
@@ -648,8 +689,8 @@ def _decode_batched_prefill_chunk_jit(
         _decode_batched_prefill_body, model, params, sample_cfg, rngs,
         emitting, decode_live_rows(emitting, backend=model.cfg.backend),
     )
-    carry, tokens = jax.lax.scan(
-        body, (token, states, t, emit, done), None, length=n_steps
+    carry, tokens = _scan_chunk(
+        model, body, (token, states, t, emit, done), n_steps, emitting, False
     )
     return carry, jnp.moveaxis(tokens, 0, 1)  # [S, n_steps]
 
@@ -718,40 +759,17 @@ def _decode_scan_donated_jit(
     sample_cfg: SampleConfig,
 ) -> Tuple[Any, Array]:
     """Stage 2 of :func:`_decode_batched_prefill_chunk_jit`, the chunk's
-    decode scan with rows still mid-prompt frozen, on a donated carry. The
-    scan carries each layer's ``Mixer.chunk_split`` part and reads the
-    rest; ``Mixer.chunk_merge`` puts the two together after it."""
+    decode scan with rows still mid-prompt frozen, on a donated carry: the
+    scan reads the KV caches and carries a chunk's new rows
+    (:func:`_scan_chunk`), so nothing of a cache is copied."""
     token, states, t, emit, done = carry
-    kinds = model.cfg.resolved_layer_types
     emitting = active & (t >= plen)
-    split = [
-        MIXERS[lt].chunk_split(model.cfg, lt, st, n_steps, t)
-        for lt, st in zip(kinds, states)
-    ]
-    held = [h for h, _ in split]
     step = partial(
         _decode_batched_prefill_body, model, params, sample_cfg, rngs,
         emitting, decode_live_rows(emitting, backend=model.cfg.backend),
     )
-
-    def body(c, _):
-        token, carried, t, emit, done = c
-        whole = [{**h, **cc} for h, cc in zip(held, carried)]
-        (token, new, t, emit, done), emitted = step(
-            (token, whole, t, emit, done), None
-        )
-        carried = [{k: st[k] for k in cc} for st, cc in zip(new, carried)]
-        return (token, carried, t, emit, done), emitted
-
-    (token, carried, t, emit, done), tokens = jax.lax.scan(
-        body, (token, [c for _, c in split], t, emit, done), None,
-        length=n_steps,
-    )
-    states = [
-        MIXERS[lt].chunk_merge(model.cfg, lt, h, c, emitting)
-        for lt, h, c in zip(kinds, held, carried)
-    ]
-    return (token, states, t, emit, done), jnp.moveaxis(tokens, 0, 1)
+    carry, tokens = _scan_chunk(model, step, carry, n_steps, emitting, True)
+    return carry, jnp.moveaxis(tokens, 0, 1)
 
 
 def decode_boundary_donated(

@@ -151,16 +151,21 @@ class Mixer(nn.Module):
 
     @staticmethod
     def chunk_split(
-        cfg: ModelConfig, layer_type: str, state: State, n_steps: int, t: Array
+        cfg: ModelConfig, layer_type: str, state: State, n_steps: int,
+        t: Array, donated: bool,
     ) -> Tuple[State, State]:
         """``(held, carried)`` for a decode scan of ``n_steps`` that starts
         at positions ``t`` [B]: the leaves the scan only READS, and the
-        leaves it carries and updates. The decode programs that hold the
-        carry once (``generate._decode_scan_donated_jit``) give
-        :meth:`decode_step` the two merged and keep what it returns under
-        the carried names; XLA copies a scan's carry at its entry, which a
-        KV cache of GBs cannot afford, and does not copy what the scan
-        closes over. Default: everything is carried."""
+        leaves it carries and updates. The slot-multiplexed decode programs
+        (``generate._scan_chunk``) give :meth:`decode_step` the two merged
+        and keep what it returns under the carried names. ``donated`` says
+        which program asks: one that holds its carry once
+        (``generate._decode_scan_donated_jit``), where a KV cache of GBs
+        cannot afford the copy XLA makes of a scan's carry at its entry
+        (it does not copy what the scan closes over), or one that returns
+        a new carry beside the one it was given. A layer that splits for
+        another reason (the linear layers' state, written once a chunk)
+        does so in both. Default: everything is carried."""
         return {}, state
 
     @staticmethod

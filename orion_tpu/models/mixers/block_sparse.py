@@ -145,13 +145,17 @@ class BlockSparseAttention(Mixer):
 
     @staticmethod
     def chunk_split(
-        cfg: ModelConfig, layer_type: str, state: State, n_steps: int, t: Array
+        cfg: ModelConfig, layer_type: str, state: State, n_steps: int,
+        t: Array, donated: bool,
     ) -> Tuple[State, State]:
-        """K and V are held (read-only in the scan); the scan carries the
-        chunk's own rows ``kn``, ``vn`` [B, KV, n_steps, Dh], the positions
-        ``t0`` it started at and the pooled keys whole (1 / 32 of K + V).
-        The chunk's rows lie in the blocks every one of its steps is forced
-        to select."""
+        """Where the carry is ``donated`` K and V are held (read-only in the
+        scan); the scan carries the chunk's own rows ``kn``, ``vn`` [B, KV,
+        n_steps, Dh], the positions ``t0`` it started at and the pooled
+        keys whole (1 / 32 of K + V). The chunk's rows lie in the blocks
+        every one of its steps is forced to select. A program that returns
+        a new carry carries everything."""
+        if not donated:
+            return {}, state
         assert n_steps <= cfg.sparse_window, (n_steps, cfg.sparse_window)
         b, kvh, _, dh = state["k"].shape
         new = {
@@ -166,6 +170,8 @@ class BlockSparseAttention(Mixer):
         cfg: ModelConfig, layer_type: str, held: State, carried: State,
         live: Array,
     ) -> State:
+        if not held:
+            return carried
         merged = {
             n: merge_chunk_rows(held[n], carried[n + "n"], carried["t0"], live)
             for n in ("k", "v")

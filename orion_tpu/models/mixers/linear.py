@@ -1,8 +1,10 @@
 """``linear``: causal linear attention, phi(q) (phi(k)^T v) normalised by
 phi(q) . sum phi(k). The decode state is the fp32 kv-cumsum ``(S, z)`` —
 [B, H, Dh, Dh] and [B, H, Dh], constant in the sequence length — and the
-one-token step is ``ops.dispatch.decode_state_step`` (under a Pallas
-backend the row-sparse in-place kernel, hence ``rows_in_place``).
+one-token step is ``ops.dispatch.decode_state_step``. Under a Pallas
+backend the slot-multiplexed scans only READ ``(S, z)``, for the rows a
+row list names (hence ``rows_in_place``), beside the chunk's own k, v rows,
+and write it once a chunk (``chunk_split`` / ``chunk_merge``).
 """
 
 from __future__ import annotations
@@ -13,8 +15,13 @@ import jax
 import jax.numpy as jnp
 
 from orion_tpu.models.configs import ModelConfig
-from orion_tpu.models.mixers import Mixer, State, _dense_factory
-from orion_tpu.ops.dispatch import decode_state_step
+from orion_tpu.models.mixers import Mixer, State, _dense_factory, _dtype
+from orion_tpu.ops.dispatch import (
+    decode_live_rows,
+    decode_state_flush,
+    decode_state_step,
+    row_sparse,
+)
 from orion_tpu.ops.feature_maps import make_feature_map
 from orion_tpu.ops.linear_attention import (
     linear_attention,
@@ -73,6 +80,42 @@ class LinearAttention(Mixer):
             "s": jnp.zeros((batch, h, dh, dh), jnp.float32),
             "z": jnp.zeros((batch, h, dh), jnp.float32),
         }
+
+    @staticmethod
+    def chunk_split(
+        cfg: ModelConfig, layer_type: str, state: State, n_steps: int,
+        t: Array, donated: bool,
+    ) -> Tuple[State, State]:
+        """Where the decode step takes the row-list kernel, in every
+        program: ``(S, z)`` is held and only read; the scan carries the
+        chunk's own rows ``kc`` (phi(k)) and ``vc`` [B, n_steps, H, Dh] in
+        the compute dtype and the positions ``t0`` it started at. On the
+        XLA path ``recurrent_step`` carries the state as ever."""
+        if not row_sparse(cfg.backend):
+            return {}, state
+        b, h, dk, dv = state["s"].shape
+        dt = _dtype(cfg.dtype)
+        return dict(state), {
+            "kc": jnp.zeros((b, n_steps, h, dk), dt),
+            "vc": jnp.zeros((b, n_steps, h, dv), dt),
+            "t0": t,
+        }
+
+    @staticmethod
+    def chunk_merge(
+        cfg: ModelConfig, layer_type: str, held: State, carried: State,
+        live: Array,
+    ) -> State:
+        """The chunk's rows added into each live row's ``(S, z)``, once and
+        in place: a live row stepped at every step of the scan, so all of
+        its ``kc``, ``vc`` rows are this chunk's."""
+        if not held:
+            return carried
+        s, z = decode_state_flush(
+            (held["s"], held["z"]), (carried["kc"], carried["vc"]),
+            decode_live_rows(live, backend=cfg.backend), backend=cfg.backend,
+        )
+        return {"s": s, "z": z}
 
     # -- parallel forward ---------------------------------------------------
 
@@ -226,10 +269,19 @@ class LinearAttention(Mixer):
     def decode_step(
         self, x: Array, state: State, t: Array, rows: Optional[Any] = None
     ) -> Tuple[Array, State]:
-        """Given ``rows``, under a Pallas backend only those rows' (S, z)
-        are stepped, in place, and the others are returned untouched."""
+        """Inside a scan that holds ``(S, z)`` (:meth:`chunk_split`) the
+        listed rows read it and this token's k and v go to row ``t - t0``
+        of the chunk's own rows; the state of an unlisted row is not
+        touched. Otherwise every row steps ``recurrent_step``."""
         q, k, v = self._heads(x)  # [B, H, Dh]
         qf, kf = self._phi_map(q), self._phi_map(k)
+        if "kc" in state:
+            out, (kc, vc) = decode_state_step(
+                qf, kf, v, (state["s"], state["z"]), rows,
+                backend=self.cfg.backend,
+                chunk=(state["kc"], state["vc"], t - state["t0"]),
+            )
+            return self._merge(out, single=True), dict(state, kc=kc, vc=vc)
         out, (s, z) = decode_state_step(
             qf, kf, v, (state["s"], state["z"]), rows,
             backend=self.cfg.backend,

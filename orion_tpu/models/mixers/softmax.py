@@ -75,13 +75,15 @@ class SoftmaxAttention(Mixer):
 
     @staticmethod
     def chunk_split(
-        cfg: ModelConfig, layer_type: str, state: State, n_steps: int, t: Array
+        cfg: ModelConfig, layer_type: str, state: State, n_steps: int,
+        t: Array, donated: bool,
     ) -> Tuple[State, State]:
-        """The full cache is held (read-only in the scan); the scan carries
-        the chunk's own rows ``kn``, ``vn`` [B, H, n_steps, Dh] and the
-        positions ``t0`` it started at. A window's ring wraps inside a
-        chunk and is small: carried whole."""
-        if _window(cfg, layer_type) is not None:
+        """Where the carry is ``donated`` the full cache is held (read-only
+        in the scan); the scan carries the chunk's own rows ``kn``, ``vn``
+        [B, H, n_steps, Dh] and the positions ``t0`` it started at. A
+        window's ring wraps inside a chunk and is small: carried whole, as
+        is every cache of a program that returns a new carry."""
+        if not donated or _window(cfg, layer_type) is not None:
             return {}, state
         b, h, _, dh = state["k"].shape
         new = {
@@ -98,7 +100,7 @@ class SoftmaxAttention(Mixer):
         """Each live row's chunk of new rows written into its cache at
         ``t0``, one in-place slice update a row, outside any loop (a loop
         that carried the cache would copy it at its entry)."""
-        if _window(cfg, layer_type) is not None:
+        if not held:
             return carried
         return {
             n: merge_chunk_rows(held[n], carried[n + "n"], carried["t0"], live)

@@ -12,12 +12,14 @@ under Pallas the chunked form as Mosaic kernels, forward and backward,
 ``ops/pallas/gated_delta.py``; the same equations as XLA fusions and a scan
 otherwise), ``causal_dot_product`` (the parallel forward: training,
 prefill), and the slot-multiplexed decode programs' three row-list steps,
-``decode_state_step`` (the linear layers' ``(S, z)``),
-``gated_delta_step`` (the delta rule's ``S``) and ``cache_attention`` (the
-full-attention layers' query over a held KV cache): under Pallas
-row-sparse kernels that touch only the rows live in the chunk
-(``ops/pallas/decode_state.py``, in place; ``ops/pallas/cache_attention.py``,
-only a row's live cache blocks), every row in XLA otherwise.
+``decode_state_step`` (the linear layers' ``(S, z)``, with
+``decode_state_flush``), ``gated_delta_step`` (the delta rule's ``S``) and
+``cache_attention`` (the full-attention layers' query over a held KV
+cache): under Pallas row-sparse kernels that touch only the rows live in
+the chunk (``ops/pallas/decode_state.py``: ``(S, z)`` read at every step
+and written once a chunk, the delta rule's and the decayed ``S`` in place;
+``ops/pallas/cache_attention.py``, only a row's live cache blocks), every
+row in XLA otherwise.
 """
 
 from __future__ import annotations
@@ -309,20 +311,24 @@ def _block_list_attention(q, k_cache, v_cache, lengths, rows, blocks, backend):
 
 
 def decode_state_step(
-    q, k, v, state, rows=None, *, backend: str = "auto", decay=None
+    q, k, v, state, rows=None, *, backend: str = "auto", decay=None, chunk=None
 ):
     """One decode step of the linear layers' ``(S, z)`` state.
 
     ``rows`` is :func:`decode_live_rows` of the chunk's row mask, or None
     when every row steps (the lockstep programs, and every program where
-    the backend is not Pallas). With a row list under a Pallas backend
-    only the listed rows are read, updated and written, in place;
-    otherwise this is ``recurrent_step`` on all rows.
+    the backend is not Pallas): that is ``recurrent_step`` on all rows ->
+    ``(out, (S, z))``. With a row list under a Pallas backend the state is
+    only READ, for the listed rows: ``chunk`` = (kc, vc, j) holds the k and
+    v rows of the scan's earlier steps and each row's step ``j`` [B] in it
+    (``LinearAttention.chunk_split``), this step's k, v go to row ``j`` ->
+    ``(out, (kc, vc))``, and :func:`decode_state_flush` writes the state
+    once, after the scan (``ops/pallas/decode_state.py``).
 
     ``decay`` [H] (slopes ``-log lam_h``) makes it the decayed step with no
     normaliser: ``state`` is ``S`` alone, ``S <- lam S + k ⊗ v; out = q . S``
-    -> ``(out, S)``, through the same row list (``ops/pallas/
-    decode_state.py::decay_state_step``) or on every row."""
+    -> ``(out, S)``, written at every step, in place for the listed rows
+    (``ops/pallas/decode_state.py::decay_state_step``) or on every row."""
     if decay is not None:
         if rows is not None and row_sparse(backend):
             from orion_tpu.ops.pallas import decode_state as pds
@@ -334,11 +340,17 @@ def decode_state_step(
         from orion_tpu.ops.linear_attention import decayed_recurrent_step
 
         return decayed_recurrent_step(q, k, v, state, decay)
-    if rows is not None and row_sparse(backend):
+    if row_sparse(backend) and (rows is not None or chunk is not None):
         from orion_tpu.ops.pallas import decode_state as pds
 
+        if rows is None or chunk is None:
+            raise ValueError(
+                "the row-list (S, z) step reads the chunk's own k, v rows: "
+                "a row list and Mixer.chunk_split's rows go together"
+            )
+        kc, vc, j = chunk
         return pds.decode_state_step(
-            q, k, v, state, rows,
+            q, k, v, state, (kc, vc), j, rows,
             interpret=(resolve(backend) == "pallas_interpret"),
         )
     from orion_tpu.ops.linear_attention import recurrent_step
@@ -346,11 +358,23 @@ def decode_state_step(
     return recurrent_step(q, k, v, state)
 
 
+def decode_state_flush(state, chunk, rows, *, backend: str = "auto"):
+    """``(S, z)`` after a scan of :func:`decode_state_step` over a row
+    list: the listed rows' ``chunk`` = (kc, vc) added in, in place, every
+    other row untouched (``ops/pallas/decode_state.py``)."""
+    from orion_tpu.ops.pallas import decode_state as pds
+
+    return pds.decode_state_flush(
+        state, chunk, rows, interpret=(resolve(backend) == "pallas_interpret")
+    )
+
+
 __all__ = [
     "cache_attention",
     "causal_dot_product",
     "decode_live_rows",
     "decode_rows_mask",
+    "decode_state_flush",
     "decode_state_step",
     "gated_delta_step",
     "default_backend",

@@ -1,6 +1,6 @@
-"""Row-sparse, in-place decode-state step: ``recurrent_step`` for the rows
-of a slot-multiplexed carry that are live in this chunk, and nothing at
-all for the others.
+"""Row-sparse decode-state kernels: ``recurrent_step`` for the rows of a
+slot-multiplexed carry that are live in this chunk, and nothing at all for
+the others.
 
 The slot-multiplexed decode programs (generate.py) run every step of
 every linear layer over ALL slots of the carry and then select the old
@@ -9,23 +9,32 @@ reads and writes the whole fp32 ``S [B, H, Dk, Dv]`` (1 MiB a row at
 lm_1b3 widths) whatever the occupancy: half of the served programs'
 device time with a third of 64 slots decoding (PERF.md, PR 29).
 
-This kernel walks a COMPACTED list of live rows instead: one grid step a
+These kernels walk a COMPACTED list of live rows instead: one grid step a
 live row, the grid's bound is the live count (a dynamic grid: zero live
 rows run zero steps), and the row of each block comes from the
-scalar-prefetched list through the BlockSpec index maps. ``S`` and ``z``
-are aliased input to output, so a row that is not listed is neither read
-nor written and keeps its bits; inside a ``lax.scan`` the carry is
-updated in place. The attention output is aliased onto ``v``: a dead
-row's output is its ``v`` row, finite and the same on every replay (it
-feeds row-independent matmuls whose results the caller discards).
+scalar-prefetched list through the BlockSpec index maps, so a row that is
+not listed is neither read nor written and keeps its bits. The attention
+output is aliased onto ``v``: a dead row's output is its ``v`` row, finite
+and the same on every replay (it feeds row-independent matmuls whose
+results the caller discards).
 
-Mathematics per live row, all in fp32, exactly ``recurrent_step``'s::
+The linear layers' ``(S, z)`` is WRITTEN once a chunk, not once a step
+(PERF.md, PR 38). Inside a chunk of ``n`` steps that starts from ``(S0,
+z0)``, step ``j`` of a row is, all in fp32,
 
-    S += k (x) v;  z += k;  out = (q . S) / (q . z + eps)
+    out_j = (q_j . S0 + sum_{s<=j} (q_j . k_s) v_s)
+            / (q_j . z0 + sum_{s<=j} q_j . k_s + eps)
 
-on the VPU (no MXU: a rank-1 update and a matvec per head move 2 MiB for
-~1 MFLOP). Only the reduction ORDER of ``q . S`` differs from XLA's
-einsum, so results agree to fp32 rounding, not bitwise.
+which is ``recurrent_step``'s ``q . S_j / (q . z_j + eps)`` with ``S_j = S0
++ sum_{s<=j} k_s (x) v_s`` multiplied out: the same products, another
+order of the sums, so results agree to fp32 rounding, not bitwise.
+``decode_state_step`` only READS ``(S0, z0)`` and the chunk's own rows
+``kc``, ``vc`` ``[B, n, H, D]`` (the model's compute dtype, so a row's
+buffer is 1/16 of its ``S`` at 16 steps) and writes the output and row
+``j`` of ``kc``, ``vc``, on the VPU (no MXU: a matvec per head moves 1
+MiB for ~0.5 MFLOP); ``decode_state_flush`` (end of the file), after the
+scan, adds the chunk's ``n`` rank-1 terms to ``S`` and its keys to ``z``
+in place, as one ``K^T V`` a head on the MXU.
 
 ``gated_delta_step`` is the same walk for the gated delta rule's state
 (``ops/gated_delta.py``): per live row and head, in fp32,
@@ -79,76 +88,121 @@ def check_operands(q, k, v, s, z, idx) -> None:
         raise ValueError(f"operands do not fit S {s.shape}: {shapes}")
 
 
-def _kernel(eps, rows_ref, s_ref, z_ref, q_ref, k_ref, v_ref,
-            s_out, z_out, o_ref):
-    del rows_ref  # consumed by the index maps
+def check_chunk_operands(s, z, kc, vc, idx) -> None:
+    """The chunk's own rows fit the state: ``kc`` [B, n, H, Dk] and ``vc``
+    [B, n, H, Dv] of one dtype, the state fp32."""
+    if s.dtype != jnp.float32 or z.dtype != jnp.float32:
+        raise ValueError(f"decode state must be float32, got {s.dtype}/{z.dtype}")
+    if kc.dtype != vc.dtype:
+        raise ValueError(f"kc, vc must share a dtype: {kc.dtype}/{vc.dtype}")
+    b, h, dk, dv = s.shape
+    n = kc.shape[1]
+    shapes = (z.shape, kc.shape, vc.shape, idx.shape)
+    if shapes != ((b, h, dk), (b, n, h, dk), (b, n, h, dv), (b,)):
+        raise ValueError(f"operands do not fit S {s.shape}: {shapes}")
+
+
+def _step_kernel(eps, rows_ref, j_ref, s_ref, z_ref, q_ref, k_ref, kc_ref,
+                 v_ref, vc_ref, kc_out, o_ref, vc_out):
+    j = j_ref[rows_ref[pl.program_id(0)]]
+    k, v = k_ref[0], v_ref[0]
+    # before the output is written: ``out`` is aliased onto ``v``, and on
+    # the chip a read of ``v_ref`` after that write returns the output
+    kc_out[0, 0] = k.astype(kc_out.dtype)
+    vc_out[0, 0] = v.astype(vc_out.dtype)
     qf = q_ref[0].astype(jnp.float32)  # [H, Dk]
-    kf = k_ref[0].astype(jnp.float32)
-    vf = v_ref[0].astype(jnp.float32)  # [H, Dv]
-    sf = s_ref[0] + kf[:, :, None] * vf[:, None, :]
-    zf = z_ref[0] + kf
-    s_out[0] = sf
-    z_out[0] = zf
-    num = jnp.sum(qf[:, :, None] * sf, axis=1)
-    den = jnp.sum(qf * zf, axis=-1, keepdims=True) + eps
+    kf = k.astype(jnp.float32)
+    vf = v.astype(jnp.float32)  # [H, Dv]
+    kcf = kc_ref[0].astype(jnp.float32)  # [n, H, Dk]
+    vcf = vc_ref[0].astype(jnp.float32)
+    # q . k_s of the chunk's earlier steps, [n, H, 1]; rows from j on hold
+    # nothing of this chunk yet
+    a = jnp.sum(kcf * qf[None], axis=-1, keepdims=True)
+    step = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+    a = jnp.where(step < j, a, 0.0)
+    now = jnp.sum(qf * kf, axis=-1, keepdims=True)  # [H, 1]
+    num = (
+        jnp.sum(qf[:, :, None] * s_ref[0], axis=1)
+        + jnp.sum(a * vcf, axis=0) + now * vf
+    )
+    den = (
+        jnp.sum(qf * z_ref[0], axis=-1, keepdims=True)
+        + jnp.sum(a, axis=0) + now + eps
+    )
     o_ref[0] = (num / den).astype(o_ref.dtype)
 
 
+# jitted, as the flush is: a decode program calls each once a layer, and an
+# inner jit is traced and lowered once a program, not once a layer (24 x 2
+# kernels one by one added 8 s to a server's warm-up: PERF.md, PR 38)
+@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
 def decode_state_step(
     q: Array,
     k: Array,
     v: Array,
     state: Tuple[Array, Array],
+    chunk: Tuple[Array, Array],
+    j: Array,
     rows: Tuple[Array, Array],
     *,
     eps: float = _DEFAULT_EPS,
     interpret: bool = False,
 ) -> Tuple[Array, Tuple[Array, Array]]:
-    """``recurrent_step(q, k, v, state, eps)`` for the rows ``rows`` lists.
+    """Step ``j`` of a chunk's decode scan for the rows ``rows`` lists, the
+    state read and not written.
 
     q, k: [B, H, Dk]; v: [B, H, Dv] (one dtype, the model's compute
-    dtype); state = (S [B, H, Dk, Dv], z [B, H, Dk]) in fp32; rows =
-    :func:`live_rows` of the row mask. Returns (out [B, H, Dv], (S, z)):
-    listed rows updated, every other row of S and z bitwise the input's
-    (never touched) and of ``out`` its ``v`` row.
-    """
+    dtype); state = (S [B, H, Dk, Dv], z [B, H, Dk]) in fp32 as the chunk
+    found it; chunk = (kc [B, n, H, Dk], vc [B, n, H, Dv]), whose rows
+    ``< j`` hold the k and v of the chunk's earlier steps; ``j`` [B] int32,
+    each row's step in the chunk; rows = :func:`live_rows` of the row
+    mask. Returns (out [B, H, Dv], (kc, vc)): a listed row's output is
+    ``recurrent_step``'s after its ``j + 1`` steps and row ``j`` of its kc,
+    vc now holds this k, v; every other row of kc, vc is bitwise the
+    input's (never touched) and of ``out`` its ``v`` row."""
     s, z = state
+    kc, vc = chunk
     idx, count = rows
     check_operands(q, k, v, s, z, idx)
+    check_chunk_operands(s, z, kc, vc, idx)
+    if j.shape != idx.shape:
+        raise ValueError(f"one step index a row: {j.shape} against {idx.shape}")
     b, h, dk, dv = s.shape
-    row3 = lambda i, rows: (rows[i], 0, 0)  # noqa: E731
-    row4 = lambda i, rows: (rows[i], 0, 0, 0)  # noqa: E731
+    n = kc.shape[1]
+    row3 = lambda i, rows, j: (rows[i], 0, 0)  # noqa: E731
+    row4 = lambda i, rows, j: (rows[i], 0, 0, 0)  # noqa: E731
+    at_j = lambda i, rows, j: (rows[i], j[rows[i]], 0, 0)  # noqa: E731
+    key, val = pl.BlockSpec((1, h, dk), row3), pl.BlockSpec((1, h, dv), row3)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(count[0],),
         in_specs=[
-            pl.BlockSpec((1, h, dk, dv), row4),
-            pl.BlockSpec((1, h, dk), row3),
-            pl.BlockSpec((1, h, dk), row3),
-            pl.BlockSpec((1, h, dk), row3),
-            pl.BlockSpec((1, h, dv), row3),
+            pl.BlockSpec((1, h, dk, dv), row4), key, key, key,
+            pl.BlockSpec((1, n, h, dk), row4), val,
+            pl.BlockSpec((1, n, h, dv), row4),
         ],
+        # row j of a listed row's buffers is a block of its own: the rest
+        # of the 2 x n x H x D buffer is not written back
         out_specs=[
-            pl.BlockSpec((1, h, dk, dv), row4),
-            pl.BlockSpec((1, h, dk), row3),
-            pl.BlockSpec((1, h, dv), row3),
+            pl.BlockSpec((1, 1, h, dk), at_j), val,
+            pl.BlockSpec((1, 1, h, dv), at_j),
         ],
     )
-    s, z, out = pl.pallas_call(
-        functools.partial(_kernel, eps),
+    kc, out, vc = pl.pallas_call(
+        functools.partial(_step_kernel, eps),
         name="decode_state_step",
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct(s.shape, s.dtype),
-            jax.ShapeDtypeStruct(z.shape, z.dtype),
+            jax.ShapeDtypeStruct(kc.shape, kc.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct(vc.shape, vc.dtype),
         ],
-        # operand numbering counts the scalar-prefetch list: S, z and v
-        # are operands 1, 2 and 5
-        input_output_aliases={1: 0, 2: 1, 5: 2},
+        # operand numbering counts the two scalar-prefetch operands: kc, v
+        # and vc are operands 6, 7 and 8
+        input_output_aliases={6: 0, 7: 1, 8: 2},
         interpret=interpret,
-    )(idx, s, z, q, k, v)
-    return out, (s, z)
+    )(idx, jnp.clip(j, 0, n - 1).astype(jnp.int32), s, z, q, k, kc, v, vc)
+    return out, (kc, vc)
 
 
 def check_delta_operands(q, k, v, beta, g, s, idx) -> None:
@@ -276,4 +330,73 @@ def decay_state_step(
     return out, s
 
 
-__all__ = ["decay_state_step", "decode_state_step", "gated_delta_step", "live_rows"]
+def _flush_kernel(precision, rows_ref, s_ref, z_ref, kt_ref, vt_ref, s_out, z_out):
+    del rows_ref  # consumed by the index maps
+    for h in range(s_ref.shape[1]):
+        s_out[0, h] = s_ref[0, h] + jax.lax.dot_general(
+            kt_ref[0, h], vt_ref[0, h], (((0,), (0,)), ((), ())),
+            precision=precision, preferred_element_type=jnp.float32,
+        )
+    z_out[0] = z_ref[0] + jnp.sum(kt_ref[0].astype(jnp.float32), axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def decode_state_flush(
+    state: Tuple[Array, Array],
+    chunk: Tuple[Array, Array],
+    rows: Tuple[Array, Array],
+    *,
+    interpret: bool = False,
+) -> Tuple[Array, Array]:
+    """The chunk's rows into the state, once, after the scan: for the rows
+    ``rows`` lists ``S += sum_s k_s (x) v_s`` and ``z += sum_s k_s`` over
+    ALL ``n`` rows of ``chunk`` = (kc [B, n, H, Dk], vc [B, n, H, Dv]) (a
+    listed row stepped at every step of the scan), in fp32 and in place.
+    Returns (S, z): an unlisted row is never touched and keeps its bits.
+
+    ``K^T V`` a head on the MXU with fp32 accumulation: bf16 products are
+    exact in fp32, so only the order of the ``n`` adds differs from ``n``
+    in-place steps (which, as rank-1 adds on the VPU, measured 9.4 us a row
+    against 2.7: PERF.md, PR 38). The rows arrive head-major ``[B, H, n,
+    D]``, ``n`` zero-padded to whole sublane tiles: XLA transposes 1/16 of
+    the state's bytes once a chunk."""
+    s, z = state
+    kc, vc = chunk
+    idx, count = rows
+    check_chunk_operands(s, z, kc, vc, idx)
+    b, h, dk, dv = s.shape
+    tile = 32 // kc.dtype.itemsize  # sublanes of a tile: 8 fp32, 16 bf16
+    pad = ((0, 0), (0, 0), (0, -kc.shape[1] % tile), (0, 0))
+    kt, vt = (jnp.pad(jnp.swapaxes(x, 1, 2), pad) for x in (kc, vc))
+    n = kt.shape[2]
+    exact = jax.lax.Precision.HIGHEST if kc.dtype == jnp.float32 else None
+    row3 = lambda i, rows: (rows[i], 0, 0)  # noqa: E731
+    row4 = lambda i, rows: (rows[i], 0, 0, 0)  # noqa: E731
+    state_specs = [pl.BlockSpec((1, h, dk, dv), row4), pl.BlockSpec((1, h, dk), row3)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(count[0],),
+        in_specs=state_specs + [
+            pl.BlockSpec((1, h, n, dk), row4), pl.BlockSpec((1, h, n, dv), row4),
+        ],
+        out_specs=state_specs,
+    )
+    return tuple(pl.pallas_call(
+        functools.partial(_flush_kernel, exact),
+        name="decode_state_flush",
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct(s.shape, s.dtype),
+            jax.ShapeDtypeStruct(z.shape, z.dtype),
+        ],
+        # operand numbering counts the scalar-prefetch list: S and z are
+        # operands 1 and 2
+        input_output_aliases={1: 0, 2: 1},
+        interpret=interpret,
+    )(idx, s, z, kt, vt))
+
+
+__all__ = [
+    "decay_state_step", "decode_state_flush", "decode_state_step",
+    "gated_delta_step", "live_rows",
+]

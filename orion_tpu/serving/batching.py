@@ -88,6 +88,7 @@ from orion_tpu.generate import (
     prefill_piece_cap,
     reprefill_carry,
 )
+from orion_tpu.models.mixers import MIXERS
 from orion_tpu.models.transformer import (
     decode_state_finite_per_slot,
     extract_decode_slot,
@@ -535,6 +536,7 @@ class SlotEngine:
         self.donate_carry = fits_once_only(
             self._carry, params, next(iter(self._carry[0].devices()))
         )
+        self.state_writes_per_chunk = self._state_writes_per_chunk()
         self._rngs = jnp.tile(
             jax.random.PRNGKey(0)[None], (self.slots, 1)
         )
@@ -764,6 +766,23 @@ class SlotEngine:
         elif "block_sparse" in kinds and row_sparse(cfg.backend):
             read = cfg.sparse_block * self.kv_blocks()[1]
         return live, cap * self.slots, read
+
+    def _state_writes_per_chunk(self) -> int:
+        """How many times ONE ``linear`` layer writes an emitting slot's
+        ``(S, z)`` row at a boundary's decode scan: once where the layer's
+        ``chunk_split`` holds the state (the scan reads it and one flush
+        writes it), ``chunk`` times where every step writes (the XLA
+        form), 0 for a model without such a layer."""
+        kinds = self.model.cfg.resolved_layer_types
+        if "linear" not in kinds:
+            return 0
+        held, _ = jax.eval_shape(
+            lambda st, t: MIXERS["linear"].chunk_split(
+                self.model.cfg, "linear", st, self.chunk, t, self.donate_carry
+            ),
+            self._carry[1][kinds.index("linear")], self._carry[2],
+        )
+        return 1 if "s" in held else self.chunk
 
     def kv_blocks(self) -> Tuple[int, int, int, int]:
         """(live, read, sparse, dense) for ONE ``block_sparse`` layer at the

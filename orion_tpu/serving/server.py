@@ -113,11 +113,14 @@ _SLOT_CLASS_KEYS = (
 # what the slots hold live / what they reserve / what a layer's decode
 # attention streams a step (the reservation in the XLA form, the emitting
 # slots' live KV blocks under ``ops.dispatch.cache_attention``'s kernel; all
-# three stay 0 for a model without a cached layer); and the slots that
-# emitted tokens at the boundary, which is the rows the decode scan's state
-# kernels stepped
+# three stay 0 for a model without a cached layer); the slots that emitted
+# tokens at the boundary, which is the rows the decode scan's state kernels
+# stepped; and the times ONE ``linear`` layer wrote those rows' ``(S, z)``
+# in it (``SlotEngine.state_writes_per_chunk`` each: 1 where the scan reads
+# the state and one flush writes it, ``chunk`` where every step writes)
 _KV_ROW_KEYS = (
     "kv_rows_live", "kv_rows_reserved", "kv_rows_read", "slot_steps_emitting",
+    "state_row_writes",
 )
 # the block-sparse layers' cache blocks at each boundary, per emitting slot
 # and sparse layer (``SlotEngine.kv_blocks``): blocks the slot holds live /
@@ -2032,7 +2035,8 @@ class Server:
                 self._bump("slot_steps_decoding", decoding)
                 self._bump("slot_steps_frozen",
                            occupied - prefilling - decoding)
-                for key, rows in zip(_KV_ROW_KEYS, kv_rows + (emitting,)):
+                writes = emitting * self.engine.state_writes_per_chunk
+                for key, rows in zip(_KV_ROW_KEYS, kv_rows + (emitting, writes)):
                     self._bump(key, rows)
                 layers = self.engine.model.cfg.resolved_layer_types.count(
                     "block_sparse"

@@ -111,10 +111,16 @@ def _gmm(x, w, sizes):
     return gmm(x, w, sizes, tile_rows=128, block_h=512)
 
 
-def _decode_state(s, z, q, k, v, live):
+def _decode_state(s, z, q, k, v, kc, vc, j, live):
     from orion_tpu.ops.pallas.decode_state import decode_state_step, live_rows
 
-    return decode_state_step(q, k, v, (s, z), live_rows(live))
+    return decode_state_step(q, k, v, (s, z), (kc, vc), j, live_rows(live))
+
+
+def _decode_state_flush(s, z, kc, vc, live):
+    from orion_tpu.ops.pallas.decode_state import decode_state_flush, live_rows
+
+    return decode_state_flush((s, z), (kc, vc), live_rows(live))
 
 
 def _gated_delta(q, k, v, beta, g):
@@ -176,9 +182,13 @@ _DELTA = [*[((2, 16, 8192, 128), jnp.bfloat16)] * 2,
           ((2, 32, 8192, 128), jnp.bfloat16),
           *[((2, 32, 8192), jnp.float32)] * 2]
 # the serve cells' decode carry: 64 slots of lm_1b3's fp32 (S, z), one
-# token's bf16 q, k, v a slot, and the chunk's row mask
-_STATE = [((64, 16, 128, 128), jnp.float32), ((64, 16, 128), jnp.float32),
-          *[((64, 16, 128), jnp.bfloat16)] * 3, ((64,), jnp.bool_)]
+# token's bf16 q, k, v a slot, a 16-step chunk's own bf16 k, v rows with
+# each slot's step in it, and the chunk's row mask
+_STATE = [((64, 16, 128, 128), jnp.float32), ((64, 16, 128), jnp.float32)]
+_CHUNK_ROWS = [((64, 16, 16, 128), jnp.bfloat16)] * 2
+_STATE_STEP = [*_STATE, *[((64, 16, 128), jnp.bfloat16)] * 3, *_CHUNK_ROWS,
+               ((64,), jnp.int32), ((64,), jnp.bool_)]
+_STATE_FLUSH = [*_STATE, *_CHUNK_ROWS, ((64,), jnp.bool_)]
 # olmo_hybrid_7b served: one slot's 1,024-token prompt piece through the
 # state-carrying delta-rule kernel at 30 heads of 96 x 192 (zero-padded to
 # 128 x 256 inside), and the 64-slot decode step of its fp32 state
@@ -225,7 +235,9 @@ KERNELS = [
          ((5504,), jnp.float32)],
         id="q4_matmul",
     ),
-    pytest.param(_decode_state, _STATE, id="decode_state-64slots"),
+    pytest.param(_decode_state, _STATE_STEP, id="decode_state_step-64slots-chunk16"),
+    pytest.param(_decode_state_flush, _STATE_FLUSH,
+                 id="decode_state_flush-64slots-chunk16"),
     pytest.param(_flash(None), _QKV_GQA, id="flash-causal-d256-T8192-fwd"),
     pytest.param(_grad3(_flash(None)), _QKV_GQA,
                  id="flash-causal-d256-T8192-bwd"),

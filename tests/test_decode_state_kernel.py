@@ -1,14 +1,16 @@
-"""The row-sparse, in-place decode-state kernel (ops/pallas/decode_state.py)
-in interpret mode on the CPU, and the slot-multiplexed decode programs that
-call it.
+"""The row-sparse decode-state kernel pair (ops/pallas/decode_state.py:
+``decode_state_step`` reads ``(S, z)`` and the chunk's own k, v rows,
+``decode_state_flush`` writes the state once after the scan) in interpret
+mode on the CPU, and the slot-multiplexed decode programs that call it.
 
-Kernel level: rows the list names step exactly as ``recurrent_step`` does
-(fp32 rounding apart: the reduction order of ``q . S`` differs), every
-other row's ``(S, z)`` keeps its bits, and a dead row's output is finite
-and the same on every call. Engine level: a ``SlotEngine`` under
-``backend="pallas_interpret"`` serves the tokens the XLA engine serves,
-and under ``backend="xla"`` neither program holds a ``pallas_call`` (what
-keeps the CPU goldens under orion_tpu/analysis/golden/ as they are).
+Kernel level: rows the list names give ``recurrent_step``'s outputs and,
+flushed, its state (fp32 rounding apart: the sums run in another order, the
+flush's as one ``K^T V`` a head); every other row's ``(S, z)`` keeps its
+bits, and a dead row's output is its ``v`` row. Engine
+level: a ``SlotEngine`` under ``backend="pallas_interpret"`` serves the
+tokens the XLA engine serves, and under ``backend="xla"`` neither program
+holds a ``pallas_call`` (what keeps the CPU goldens under
+orion_tpu/analysis/golden/ as they are).
 """
 
 import dataclasses
@@ -27,7 +29,11 @@ from orion_tpu.models.configs import get_config
 from orion_tpu.models.transformer import TransformerLM
 from orion_tpu.ops.dispatch import decode_state_step as dispatch_step
 from orion_tpu.ops.linear_attention import recurrent_step
-from orion_tpu.ops.pallas.decode_state import decode_state_step, live_rows
+from orion_tpu.ops.pallas.decode_state import (
+    decode_state_flush,
+    decode_state_step,
+    live_rows,
+)
 from orion_tpu.serving import DecodeRequest, SlotEngine
 
 H, DK, DV = 2, 16, 24
@@ -55,102 +61,127 @@ def _mask(b, pattern):
     return m
 
 
+def _steps(b, n, dtype=jnp.float32):
+    """``n`` steps' q, k, v, each stacked on a leading step axis."""
+    qkv = [_inputs(b, dtype, seed=10 + i)[2:] for i in range(n)]
+    return tuple(jnp.stack(x) for x in zip(*qkv))
+
+
 @jax.jit
-def _step(s, z, q, k, v, mask):
-    return decode_state_step(q, k, v, (s, z), live_rows(mask), interpret=True)
+def _chunk(s, z, xs, mask, t0):
+    """The decode programs' shape: the row list built once, a ``lax.scan``
+    of read-only steps that carries the chunk's own rows and each row's
+    position (a live row's advances), one flush after it."""
+    rows = live_rows(mask)
+    (b, h, dk, dv), n = s.shape, xs[0].shape[0]
+    dtype = xs[0].dtype
+
+    def body(c, qkv):
+        kc, vc, t = c
+        out, (kc, vc) = decode_state_step(
+            *qkv, (s, z), (kc, vc), t - t0, rows, interpret=True
+        )
+        return (kc, vc, jnp.where(mask, t + 1, t)), out
+
+    rows0 = jnp.zeros((b, n, h, dk), dtype), jnp.zeros((b, n, h, dv), dtype)
+    (kc, vc, _), outs = jax.lax.scan(body, (*rows0, t0), xs)
+    return outs, decode_state_flush((s, z), (kc, vc), rows, interpret=True)
+
+
+@jax.jit
+def _reference(s, z, xs):
+    def body(state, qkv):
+        out, state = recurrent_step(*qkv, state)
+        return state, out
+
+    state, outs = jax.lax.scan(body, (s, z), xs)
+    return outs, state
+
+
+def _check_chunk(slots, n, live, dtype=jnp.float32, t0=None, out_tol=None):
+    s, z = _inputs(slots)[:2]
+    xs = _steps(slots, n, dtype)
+    t0 = jnp.zeros((slots,), jnp.int32) if t0 is None else t0
+    outs, (s1, z1) = _chunk(s, z, xs, jnp.asarray(live), t0)
+    ref_outs, (ref_s, ref_z) = _reference(s, z, xs)
+    assert s1.dtype == z1.dtype == jnp.float32 and outs.dtype == dtype
+    f32 = lambda x: np.asarray(x, np.float32)  # noqa: E731
+    np.testing.assert_allclose(
+        f32(outs)[:, live], f32(ref_outs)[:, live],
+        **(out_tol or dict(rtol=1e-5, atol=1e-5)),
+    )
+    # a dead row's output is its v row, at every step
+    np.testing.assert_array_equal(f32(outs)[:, ~live], f32(xs[2])[:, ~live])
+    for got, ref, old in ((s1, ref_s, s), (z1, ref_z, z)):
+        np.testing.assert_allclose(
+            f32(got)[live], f32(ref)[live], rtol=1e-5, atol=1e-5
+        )
+        np.testing.assert_array_equal(f32(got)[~live], f32(old)[~live])
 
 
 @pytest.mark.parametrize("pattern", list(PATTERNS))
 @pytest.mark.parametrize("slots", [4, 8, 64])
 def test_live_rows_step_dead_rows_keep_their_bits(slots, pattern):
-    s, z, q, k, v = _inputs(slots)
-    live = _mask(slots, pattern)
-    out, (s1, z1) = _step(s, z, q, k, v, jnp.asarray(live))
-    ref_out, (ref_s, ref_z) = recurrent_step(q, k, v, (s, z))
-    assert s1.dtype == z1.dtype == jnp.float32 and out.dtype == q.dtype
-    for got, ref in ((s1, ref_s), (z1, ref_z), (out, ref_out)):
-        np.testing.assert_allclose(
-            np.asarray(got)[live], np.asarray(ref)[live], rtol=1e-5, atol=1e-5
-        )
-    np.testing.assert_array_equal(np.asarray(s1)[~live], np.asarray(s)[~live])
-    np.testing.assert_array_equal(np.asarray(z1)[~live], np.asarray(z)[~live])
-    dead_out = np.asarray(out)[~live]
-    assert np.isfinite(dead_out).all()
-    again, _ = _step(s, z, q, k, v, jnp.asarray(live))
-    np.testing.assert_array_equal(np.asarray(again)[~live], dead_out)
+    """One step and its flush: ``recurrent_step`` on the listed rows."""
+    _check_chunk(slots, 1, _mask(slots, pattern))
 
 
 @pytest.mark.parametrize("pattern", list(PATTERNS))
-def test_three_steps_inside_a_scan_update_in_place(pattern):
-    """The decode programs' shape: the row list built once outside a
-    ``lax.scan`` whose carry is the aliased state."""
-    slots, steps = 8, 3
-    s, z, _, _, _ = _inputs(slots)
-    qkv = [_inputs(slots, seed=10 + i)[2:] for i in range(steps)]
-    xs = tuple(jnp.stack(x) for x in zip(*qkv))
-    live = _mask(slots, pattern)
+def test_three_steps_inside_a_scan_then_one_flush(pattern):
+    _check_chunk(8, 3, _mask(8, pattern))
 
-    @jax.jit
-    def run(s, z, xs, mask):
-        rows = live_rows(mask)
 
-        def body(state, qkv):
-            out, state = decode_state_step(*qkv, state, rows, interpret=True)
-            return state, out
+@pytest.mark.parametrize("n_steps", [1, 4, 16])
+def test_a_chunk_of_n_steps_is_n_recurrent_steps(n_steps):
+    _check_chunk(8, n_steps, _mask(8, "scattered"))
 
-        return jax.lax.scan(body, (s, z), xs)
 
-    (s1, z1), outs = run(s, z, xs, jnp.asarray(live))
-    state = (s, z)
-    for i, (q, k, v) in enumerate(qkv):
-        ref_out, state = recurrent_step(q, k, v, state)
-        np.testing.assert_allclose(
-            np.asarray(outs[i])[live], np.asarray(ref_out)[live],
-            rtol=1e-5, atol=1e-5,
-        )
-        np.testing.assert_array_equal(
-            np.asarray(outs[i])[~live], np.asarray(v)[~live]
-        )
-    np.testing.assert_allclose(
-        np.asarray(s1)[live], np.asarray(state[0])[live], rtol=1e-5, atol=1e-5
+def test_rows_live_from_their_own_positions():
+    """``j = t - t0`` a row: rows that enter the chunk at different,
+    non-zero positions walk the same chunk."""
+    _check_chunk(8, 4, _mask(8, "scattered"), t0=jnp.arange(8, dtype=jnp.int32) * 7 + 3)
+
+
+def test_the_flush_alone_leaves_unlisted_rows_their_bits():
+    slots, n = 8, 4
+    s, z = _inputs(slots)[:2]
+    _, k, v = _steps(slots, n)
+    kc, vc = jnp.moveaxis(k, 0, 1), jnp.moveaxis(v, 0, 1)  # [B, n, H, D]
+    live = _mask(slots, "scattered")
+    flush = jax.jit(
+        lambda *a: decode_state_flush(a[:2], a[2:4], live_rows(a[4]), interpret=True)
     )
-    np.testing.assert_allclose(
-        np.asarray(z1)[live], np.asarray(state[1])[live], rtol=1e-5, atol=1e-5
-    )
+    s1, z1 = flush(s, z, kc, vc, jnp.asarray(live))
     np.testing.assert_array_equal(np.asarray(s1)[~live], np.asarray(s)[~live])
     np.testing.assert_array_equal(np.asarray(z1)[~live], np.asarray(z)[~live])
+    want_z = np.asarray(z) + np.asarray(kc).sum(axis=1)
+    np.testing.assert_allclose(np.asarray(z1)[live], want_z[live], rtol=1e-5, atol=1e-5)
+    assert not (np.asarray(s1)[live] == np.asarray(s)[live]).all()
+    # no listed row: nothing runs, nothing moves
+    s2, z2 = flush(s, z, kc, vc, jnp.zeros(slots, bool))
+    np.testing.assert_array_equal(np.asarray(s2), np.asarray(s))
+    np.testing.assert_array_equal(np.asarray(z2), np.asarray(z))
 
 
 def test_bf16_qkv_as_the_model_sends_them():
-    slots = 8
-    s, z, q, k, v = _inputs(slots, dtype=jnp.bfloat16)
-    live = _mask(slots, "scattered")
-    out, (s1, z1) = _step(s, z, q, k, v, jnp.asarray(live))
-    ref_out, (ref_s, ref_z) = recurrent_step(q, k, v, (s, z))
-    assert out.dtype == jnp.bfloat16 and s1.dtype == jnp.float32
-    np.testing.assert_allclose(
-        np.asarray(s1)[live], np.asarray(ref_s)[live], rtol=1e-5, atol=1e-5
-    )
-    np.testing.assert_allclose(
-        np.asarray(z1)[live], np.asarray(ref_z)[live], rtol=1e-5, atol=1e-5
-    )
-    # one bf16 ulp: the two reduction orders may round the quotient apart
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32)[live], np.asarray(ref_out, np.float32)[live],
-        rtol=2 ** -7, atol=1e-5,
-    )
-    np.testing.assert_array_equal(np.asarray(s1)[~live], np.asarray(s)[~live])
+    # one bf16 ulp: the two orders of the sums may round the quotient apart
+    _check_chunk(8, 4, _mask(8, "scattered"), dtype=jnp.bfloat16,
+                 out_tol=dict(rtol=2 ** -7, atol=1e-5))
 
 
 def test_state_must_be_fp32_and_qkv_one_dtype():
     s, z, q, k, v = _inputs(4)
     rows = live_rows(jnp.ones(4, bool))
+    kc, vc = jnp.zeros((4, 2, H, DK)), jnp.zeros((4, 2, H, DV))
+    j = jnp.zeros((4,), jnp.int32)
     with pytest.raises(ValueError, match="float32"):
-        decode_state_step(q, k, v, (s.astype(jnp.bfloat16), z), rows,
-                          interpret=True)
+        decode_state_step(q, k, v, (s.astype(jnp.bfloat16), z), (kc, vc), j,
+                          rows, interpret=True)
     with pytest.raises(ValueError, match="share a dtype"):
-        decode_state_step(q, k, v.astype(jnp.bfloat16), (s, z), rows,
-                          interpret=True)
+        decode_state_step(q, k, v.astype(jnp.bfloat16), (s, z), (kc, vc), j,
+                          rows, interpret=True)
+    with pytest.raises(ValueError, match="do not fit"):
+        decode_state_flush((s, z), (kc[:, :, :1], vc), rows, interpret=True)
 
 
 @pytest.mark.parametrize("backend,rows_given", [
@@ -167,6 +198,15 @@ def test_dispatch_steps_every_row_without_a_list_or_a_pallas_backend(
     ref_out, (ref_s, ref_z) = recurrent_step(q, k, v, (s, z))
     for got, ref in ((out, ref_out), (s1, ref_s), (z1, ref_z)):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+def test_dispatch_refuses_a_row_list_without_the_chunks_rows():
+    """Nothing writes ``(S, z)`` at a step under a Pallas backend any
+    more: a row list with no ``chunk`` would leave the state behind."""
+    s, z, q, k, v = _inputs(4)
+    rows = live_rows(jnp.ones(4, bool))
+    with pytest.raises(ValueError, match="go together"):
+        dispatch_step(q, k, v, (s, z), rows, backend="pallas_interpret")
 
 
 # -- the slot-multiplexed programs -------------------------------------------
@@ -295,5 +335,7 @@ def test_xla_programs_hold_no_pallas_call():
 
 
 def test_pallas_programs_hold_the_kernel():
+    """Both undonated programs hold both kernels: the read-only step in
+    the scan, the flush after it."""
     for text in _program_jaxprs("pallas_interpret"):
-        assert "decode_state_step" in text
+        assert "decode_state_step" in text and "decode_state_flush" in text

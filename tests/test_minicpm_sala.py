@@ -207,7 +207,7 @@ def test_a_scan_that_holds_the_cache_walks_as_one_that_carries_it(model_params, 
         rows = dispatch.decode_live_rows(live, backend=backend)
         if rows is None and not all(mask):
             continue  # without a list the decode programs freeze rows themselves
-        split = [MIXERS[lt].chunk_split(cfg, lt, st, steps, jnp.full((2,), n))
+        split = [MIXERS[lt].chunk_split(cfg, lt, st, steps, jnp.full((2,), n), True)
                  for lt, st in zip(kinds, start)]
         held, carried, plain = [h for h, _ in split], [c for _, c in split], start
         assert set(held[-1]) == {"k", "v"} and set(carried[-1]) == {"kn", "vn", "kp", "t0"}
@@ -224,6 +224,32 @@ def test_a_scan_that_holds_the_cache_walks_as_one_that_carries_it(model_params, 
             np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=2e-5)
             assert bool((got[~live] == old[~live]).all())
         assert not bool((merged[-1]["kp"] == start[-1]["kp"]).all())
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
+def test_the_cache_is_split_by_the_program_the_linear_state_by_the_backend(backend):
+    """``chunk_split``: K and V are held only where the program's carry is
+    donated (the cell's unified program carries them whole, as before PR
+    38), whatever the backend; a ``linear`` layer's ``(S, z)`` is held where
+    the step takes the row-list kernel, in both kinds of program; the
+    decayed ``S`` is written at every step and is never split."""
+    cfg = tiny_cfg(backend, n_layers=3, layer_types=("decay_linear", "block_sparse", "linear"))
+    states, t = init_decode_state(cfg, 2), jnp.zeros((2,), jnp.int32)
+    for donated in (False, True):
+        (dh, dc), (bh, bc), (lh, lc) = (
+            MIXERS[lt].chunk_split(cfg, lt, st, 4, t, donated)
+            for lt, st in zip(cfg.resolved_layer_types, states)
+        )
+        assert dh == {} and dc is states[0]
+        if donated:
+            assert set(bh) == {"k", "v"} and set(bc) == {"kn", "vn", "kp", "t0"}
+        else:
+            assert bh == {} and bc is states[1]
+        if backend == "xla":
+            assert lh == {} and lc is states[2]
+        else:
+            assert set(lh) == {"s", "z"} and set(lc) == {"kc", "vc", "t0"}
+            assert lc["kc"].shape == (2, 4, cfg.n_heads, cfg.head_dim)
 
 
 def test_decode_step_with_a_row_list_touches_no_other_row(model_params):
@@ -298,9 +324,12 @@ def test_without_a_decay_the_ops_are_the_parents(op):
             got = dispatch.decode_state_step(*args, None, backend="xla")
             want = recurrent_step(*args)
         else:
+            # since PR 38 the row-list step reads (S, z) beside the chunk's own rows
             rows = dispatch.decode_live_rows(jnp.array([True, True, False]), backend="pallas_interpret")
-            got = dispatch.decode_state_step(*args, rows, backend="pallas_interpret")
-            want = pds.decode_state_step(*args, rows, interpret=True)
+            chunk = tuple(jnp.zeros((3, 2, *x.shape[1:])) for x in (args[1], args[2]))  # [B, n, H, D]
+            j = jnp.zeros((3,), jnp.int32)
+            got = dispatch.decode_state_step(*args, rows, backend="pallas_interpret", chunk=(*chunk, j))
+            want = pds.decode_state_step(*args, chunk, j, rows, interpret=True)
     elif op == "chunked-xla":
         got = dispatch.causal_dot_product(q, k, v, backend="xla", chunk=16, return_state=True, initial_state=s0)
         want = causal_dot_product_chunked(q, k, v, chunk=16, return_state=True, initial_state=s0)

@@ -207,6 +207,34 @@ def test_decode_step_with_a_row_list_touches_no_other_row(model_params):
         assert not bool((now[0] == old[0]).all())
 
 
+@pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
+def test_the_cache_is_split_by_the_program_the_linear_state_by_the_backend(backend):
+    """``chunk_split``: a full-attention cache is held only where the
+    program's carry is donated (this model's engine on the chip), whatever
+    the backend, and a window's ring never; a ``linear`` layer's ``(S, z)``
+    is held where the step takes the row-list kernel, in both kinds of
+    program; the delta rule's ``S`` is written at every step: never split."""
+    from orion_tpu.models.mixers import MIXERS
+
+    cfg = tiny_cfg(backend, n_layers=4, window=8,
+                   layer_types=("gated_delta", "softmax", "swa", "linear"))
+    states, t = init_decode_state(cfg, 2), jnp.zeros((2,), jnp.int32)
+    for donated in (False, True):
+        (gh, gc), (sh, sc), (wh, wc), (lh, lc) = (
+            MIXERS[lt].chunk_split(cfg, lt, st, 4, t, donated)
+            for lt, st in zip(cfg.resolved_layer_types, states)
+        )
+        assert gh == {} and gc is states[0] and wh == {} and wc is states[2]
+        if donated:
+            assert set(sh) == {"k", "v"} and set(sc) == {"kn", "vn", "t0"}
+        else:
+            assert sh == {} and sc is states[1]
+        if backend == "xla":
+            assert lh == {} and lc is states[3]
+        else:
+            assert set(lh) == {"s", "z"} and set(lc) == {"kc", "vc", "t0"}
+
+
 def serve(cfg, params, prompts, max_new, slots=4, chunk=4, prefill_chunk=128, donate=False):
     engine = SlotEngine(TransformerLM(cfg), params, slots=slots, chunk=chunk,
                         prefill_buckets=(128, 256, 384), prefill_chunk=prefill_chunk)
