@@ -16,9 +16,15 @@ the reference checkout itself was never mounted, see SURVEY.md §0):
   reference's NCCL wrapper), including routed-expert (MoE) models.
 """
 
+import time as _time
+
+# where ``setup.import`` starts (obs/trace.py); it ends where the import of
+# ``orion_tpu.serving`` or ``orion_tpu.training`` does
+IMPORT_STARTED = _time.monotonic()
+
 __version__ = "0.1.0"
 
-from orion_tpu import ops
+from orion_tpu import ops  # noqa: E402
 
 # Lazy top-level API: heavy submodules (training pulls optax/orbax, generate
 # pulls models) load on first use, keeping `import orion_tpu` light.

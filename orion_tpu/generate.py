@@ -1381,22 +1381,27 @@ def main(argv=None) -> int:
         tok = ByteTokenizer()
     prompt = jnp.asarray([tok.encode(args.prompt)], jnp.int32)
 
-    if args.ckpt_dir:
-        from orion_tpu.resilience.retry import RetryPolicy
+    from orion_tpu.obs.trace import PROCESS_TRACER
 
-        params, step = load_params(
-            args.ckpt_dir, retry=RetryPolicy(attempts=max(args.ckpt_attempts, 1))
-        )
-        cfg = adapt_config_to_params(cfg, params)
-        print(f"loaded step {step} from {args.ckpt_dir}", file=sys.stderr)
-        model = TransformerLM(cfg)
-        params, was_pp = unstack_if_pipeline(model, params)
-        if was_pp:
-            print("unstacked pipeline-layout checkpoint", file=sys.stderr)
-    else:
-        model = TransformerLM(cfg)
-        params = model.init(jax.random.PRNGKey(0), prompt)
-        print("no --ckpt-dir: random params (smoke test)", file=sys.stderr)
+    with PROCESS_TRACER.span("setup.weights", "setup",
+                             source="checkpoint" if args.ckpt_dir else "init"):
+        if args.ckpt_dir:
+            from orion_tpu.resilience.retry import RetryPolicy
+
+            params, step = load_params(
+                args.ckpt_dir,
+                retry=RetryPolicy(attempts=max(args.ckpt_attempts, 1)),
+            )
+            cfg = adapt_config_to_params(cfg, params)
+            print(f"loaded step {step} from {args.ckpt_dir}", file=sys.stderr)
+            model = TransformerLM(cfg)
+            params, was_pp = unstack_if_pipeline(model, params)
+            if was_pp:
+                print("unstacked pipeline-layout checkpoint", file=sys.stderr)
+        else:
+            model = TransformerLM(cfg)
+            params = model.init(jax.random.PRNGKey(0), prompt)
+            print("no --ckpt-dir: random params (smoke test)", file=sys.stderr)
 
     mesh = None
     if args.dp * args.fsdp * args.tp * args.sp > 1:

@@ -34,6 +34,31 @@ Event model (Chrome trace-event format, ``ts``/``dur`` in microseconds):
   (every boundary) and on the device trace's clock (the captured ones).
   This module never imports jax: the ``Server`` hands the annotation
   factory in (:attr:`Tracer.annotate`) for the length of a capture.
+- **set-up spans** (``ph`` ``X`` / ``i``, ``cat`` ``setup``) — a
+  process's life before its steady state, through the same
+  :meth:`Tracer.span`: ``setup.import`` (the first line of
+  ``orion_tpu/__init__.py`` to the end of the import of
+  ``orion_tpu.serving`` / ``.training``), ``setup.server`` around
+  ``Server.__init__`` with ``setup.quantize`` / ``.engine`` / ``.stores``
+  / ``.cost_harvest`` inside, ``setup.first_launch`` around the first
+  launch of each boundary program, ``setup.trainer`` with
+  ``setup.init_state`` inside, ``setup.restore`` / ``.loader`` /
+  ``.first_step`` / ``.first_eval``, the CLIs' ``setup.weights``, and an
+  instant ``setup.ready`` at the first boundary that emitted a token or
+  the first step whose loss is back.
+- **compile events** (``ph`` ``X``, ``cat`` ``compile``) — one per stage
+  of every program jax builds, at any time: ``compile.trace``,
+  ``compile.lower``, ``compile.backend`` with ``fun_name`` and, on the
+  last, ``source`` (``compiled`` or ``cache``) and ``cache_load_ms``.
+  ``utils/profiling.py`` hears them from ``jax.monitoring`` and hands
+  them to :func:`compile_event`; a ``Server`` that is serving hears them
+  from here (:func:`on_compile`) and writes them into its ring with the
+  boundary they fell in.
+
+Both of the last two categories are few (hundreds a process) and must
+outlive the ring's turnover, so they are ALSO kept in one bounded
+process-wide list, :func:`setup_record`, whatever ``Tracer`` wrote them
+and whether or not it is enabled.
 
 Wire format: one JSON object per line (JSONL), appended live — files
 from several processes (fleet parent + children) concatenate trivially.
@@ -55,11 +80,33 @@ import json
 import os
 import threading
 import time
+import weakref
 from collections import deque
-from typing import Callable, List, Optional
+from typing import Callable, Dict, List, Optional
 
 # (name, cat, ph, ts_us, id or None, args or None)
 _EVENT_FIELDS = ("name", "cat", "ph", "ts", "id", "args")
+
+# the categories kept in the process-wide record beside the ring, each in
+# a bounded list of its own: a process that goes on compiling (a prompt
+# length nobody warmed up, every hour) turns its compile events over and
+# keeps how it came up
+RECORD_CATS = ("setup", "compile")
+_RECORD: Dict[str, deque] = {
+    "setup": deque(maxlen=1 << 12), "compile": deque(maxlen=1 << 13),
+}
+# what the compile events add up to over the process's life: the five
+# counters a registry shows (``Server.metrics``, ``MetricsLogger.registry``)
+COMPILE_COUNTERS = (
+    "programs_traced", "programs_compiled", "programs_cache_loaded",
+    "compile_ms_total", "trace_lower_ms_total",
+)
+_COMPILE_TOTALS: Dict[str, float] = dict.fromkeys(COMPILE_COUNTERS, 0)
+_COMPILE_SINKS: List[weakref.WeakMethod] = []
+# entry packages whose import is under way (one may import the other), and
+# whether the process's first ``setup.import`` has been written
+_imports_open: List[float] = []
+_import_written = False
 
 
 class Span:
@@ -110,6 +157,10 @@ class Span:
         is still open."""
         if self._open:
             self.record = True
+            return
+        if self.cat in RECORD_CATS:
+            self._tracer.keep(self.name, self.cat, "X", self.start, self.dur,
+                              **self.args)
             return
         self._tracer.complete(self.name, self.start, self.dur, cat=self.cat,
                               **self.args)
@@ -209,7 +260,23 @@ class Tracer:
         return Span(self, name, cat, record, args)
 
     def instant(self, name: str, cat: str = "event", id=None, **args) -> None:
+        if cat in RECORD_CATS:
+            self.keep(name, cat, "i", self._clock(), **args)
+            return
         self._emit(name, cat, "i", id=id, args=args or None)
+
+    def keep(self, name: str, cat: str, ph: str, start_s, dur_s=None,
+             **args) -> None:
+        """A set-up or compile event: into the process-wide record
+        (:func:`setup_record`) whether or not this tracer is enabled, and
+        into the ring when it is. Off the hot path: it runs at set-up and
+        when jax builds a program, never in the steady state."""
+        row = (name, cat, ph, start_s * 1e6,
+               None if dur_s is None else dur_s * 1e6, None, args or None,
+               threading.get_ident() & 0xFFFF)
+        _RECORD[cat].append(self._to_dict(row))
+        if self.enabled:
+            self._buf.append(row)
 
     # -- draining -------------------------------------------------------------
 
@@ -277,6 +344,118 @@ class Tracer:
 
     def close(self) -> None:
         self.flush()
+
+
+# the process's own tracer: what writes set-up spans where no Server or
+# Trainer was handed one (the import stamps, the CLIs, a Trainer built
+# without ``tracer=``). Its ring is off; the record is always on.
+PROCESS_TRACER = Tracer(path=None, clock=time.monotonic, enabled=False)
+
+
+def setup_record() -> List[dict]:
+    """The ``setup`` and ``compile`` events of this process so far (the
+    newest 4,096 and 8,192), as Chrome-format dicts in the order they
+    ended (a complete event is written at its END, so a parent follows
+    its children)."""
+    events: List[dict] = []
+    for kept in _RECORD.values():
+        for _ in range(8):
+            try:
+                events += list(kept)
+                break
+            except RuntimeError:
+                continue  # an append on another thread landed mid-copy
+    events.sort(key=lambda e: e["ts"] + e.get("dur", 0.0))
+    return events
+
+
+def import_begin() -> None:
+    """The first line of an entry package (``orion_tpu.serving`` /
+    ``.training``); one may import the other."""
+    _imports_open.append(time.monotonic())
+
+
+def import_done(module: str, started_s: float) -> None:
+    """The last line of an entry package: ``setup.import``, written by
+    the outermost package of an import. The process's first runs from
+    ``started_s``, the stamp at the first line of ``orion_tpu/__init__.py``;
+    a package imported later adds its own stretch."""
+    global _import_written
+    began = _imports_open.pop()
+    if _imports_open:
+        return
+    if not _import_written:
+        _import_written, began = True, started_s
+    PROCESS_TRACER.keep("setup.import", "setup", "X", began,
+                        time.monotonic() - began, module=module)
+
+
+def on_compile(sink) -> None:
+    """``sink(name, start_s, dur_s, args)`` (a bound method, held weakly)
+    hears every compile event from now on, on the thread that compiled."""
+    _COMPILE_SINKS[:] = [r for r in _COMPILE_SINKS if r() is not None]
+    _COMPILE_SINKS.append(weakref.WeakMethod(sink))
+
+
+def compile_counts(name: str, dur_s: float, args: dict):
+    """What one compile event adds to which of :data:`COMPILE_COUNTERS`."""
+    if name == "compile.backend":
+        loaded = args.get("source") == "cache"
+        return (("programs_cache_loaded" if loaded else "programs_compiled", 1),
+                ("compile_ms_total", dur_s * 1e3))
+    return (("programs_traced", 1 if name == "compile.trace" else 0),
+            ("trace_lower_ms_total", dur_s * 1e3))
+
+
+def compile_event(name: str, start_s: float, dur_s: float, **args) -> None:
+    """One stage of one program jax built (``utils/profiling.py`` calls
+    this from its ``jax.monitoring`` listeners, on ``time.monotonic``):
+    kept in the record, added to the totals, handed to the sinks."""
+    PROCESS_TRACER.keep(name, "compile", "X", start_s, dur_s, **args)
+    for key, n in compile_counts(name, dur_s, args):
+        _COMPILE_TOTALS[key] += n
+    for ref in list(_COMPILE_SINKS):
+        sink = ref()
+        if sink is not None:  # a dead one goes at the next on_compile
+            sink(name, start_s, dur_s, args)
+
+
+def compile_totals() -> Dict[str, float]:
+    """The process's :data:`COMPILE_COUNTERS` so far."""
+    return dict(_COMPILE_TOTALS)
+
+
+def setup_summary(events: Optional[List[dict]] = None) -> dict:
+    """What an operator asks of a slow start: seconds by top-level
+    ``setup`` span (one not inside another), the programs jax compiled
+    and those it loaded from its cache with their seconds, and the
+    slowest compiled programs by name."""
+    events = setup_record() if events is None else events
+    spans = [e for e in events if e["cat"] == "setup" and e["ph"] == "X"]
+    by_span: Dict[str, float] = {}
+    for e in spans:
+        inside = any(
+            o is not e and o["ts"] <= e["ts"]
+            and e["ts"] + e["dur"] <= o["ts"] + o["dur"] for o in spans
+        )
+        if not inside:
+            by_span[e["name"]] = round(
+                by_span.get(e["name"], 0.0) + e["dur"] / 1e6, 3)
+    backend = [e for e in events if e["name"] == "compile.backend"]
+    compiled = [e for e in backend if e["args"].get("source") != "cache"]
+    staged = [e for e in events if e["name"] in ("compile.trace", "compile.lower")]
+    return {
+        "seconds_by_span": by_span,
+        "programs_compiled": len(compiled),
+        "programs_cache_loaded": len(backend) - len(compiled),
+        "compile_or_load_s": round(sum(e["dur"] for e in backend) / 1e6, 3),
+        "trace_lower_s": round(sum(e["dur"] for e in staged) / 1e6, 3),
+        "slowest_compiled": [
+            (e["args"].get("fun_name"), round(e["dur"] / 1e6, 3))
+            for e in sorted(compiled, key=lambda e: -e["dur"])[:5]
+        ],
+        "ready": any(e["name"] == "setup.ready" for e in events),
+    }
 
 
 def read_jsonl(path: str) -> List[dict]:
@@ -347,4 +526,8 @@ if __name__ == "__main__":
 
 __all__ = [
     "Tracer", "Span", "NULL_SPAN", "read_jsonl", "merge_traces", "span_pairs",
+    "PROCESS_TRACER", "setup_record", "setup_summary", "compile_event",
+    "compile_counts", "compile_totals", "on_compile", "import_begin",
+    "import_done",
+    "COMPILE_COUNTERS",
 ]

@@ -32,6 +32,11 @@ training resilience stack (orion_tpu/resilience/, PR 2).
 and tests/test_batching.py under the ``chaos`` marker.
 """
 
+import orion_tpu as _root
+from orion_tpu.obs import trace as _trace
+
+_trace.import_begin()  # setup.import ends at this file's last line
+
 from orion_tpu.serving.batching import SlotEngine, parse_buckets
 from orion_tpu.serving.health import Health, HealthMachine, InvalidTransition
 from orion_tpu.serving.server import (
@@ -55,6 +60,8 @@ from orion_tpu.serving.session_store import (
     SessionState,
     SessionStore,
 )
+
+_trace.import_done(__name__, _root.IMPORT_STARTED)
 
 __all__ = [
     "Health", "HealthMachine", "InvalidTransition",

@@ -25,6 +25,7 @@ from orion_tpu.generate import (
 )
 from orion_tpu.models.configs import get_config
 from orion_tpu.models.transformer import TransformerLM
+from orion_tpu.obs.trace import PROCESS_TRACER, setup_summary
 from orion_tpu.resilience.preempt import PreemptionGuard
 from orion_tpu.resilience.retry import RetryPolicy
 from orion_tpu.serving.health import Health
@@ -279,22 +280,24 @@ def _run(args, guard) -> int:
     from orion_tpu.utils.config import parse_set_overrides as _parse_ov
 
     ov = overrides_fingerprint(_parse_ov(args.set) if args.set else {})
-    if args.ckpt_dir:
-        params, step = load_params(args.ckpt_dir, retry=retry)
-        cfg = adapt_config_to_params(cfg, params)
-        print(f"serving step {step} from {args.ckpt_dir}", file=sys.stderr)
-        model = TransformerLM(cfg)
-        params, _ = unstack_if_pipeline(model, params)
-        params_id = (
-            f"{args.config}:ov={ov}:ckpt={args.ckpt_dir}:step={step}"
-        )
-    else:
-        model = TransformerLM(cfg)
-        params = model.init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
-        )
-        print("no --ckpt-dir: random params (smoke test)", file=sys.stderr)
-        params_id = f"{args.config}:ov={ov}:seed=0"
+    with PROCESS_TRACER.span("setup.weights", "setup",
+                             source="checkpoint" if args.ckpt_dir else "init"):
+        if args.ckpt_dir:
+            params, step = load_params(args.ckpt_dir, retry=retry)
+            cfg = adapt_config_to_params(cfg, params)
+            print(f"serving step {step} from {args.ckpt_dir}", file=sys.stderr)
+            model = TransformerLM(cfg)
+            params, _ = unstack_if_pipeline(model, params)
+            params_id = (
+                f"{args.config}:ov={ov}:ckpt={args.ckpt_dir}:step={step}"
+            )
+        else:
+            model = TransformerLM(cfg)
+            params = model.init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
+            )
+            print("no --ckpt-dir: random params (smoke test)", file=sys.stderr)
+            params_id = f"{args.config}:ov={ov}:seed=0"
     if args.tokenizer:
         # after cfg adaptation: out-of-vocab ids would be silently clamped
         # by the embedding gather — garbage served with status 'ok'
@@ -434,6 +437,16 @@ def _run(args, guard) -> int:
         tag = "" if r.status == "ok" else f" [{r.status}]"
         print(line + tok.decode(ids) + tag)
     print(f"stats: {server.stats}", file=sys.stderr)
+    up = setup_summary()
+    print("set-up: "
+          + ", ".join(f"{k.split('.', 1)[1]} {v:.2f} s"
+                      for k, v in up["seconds_by_span"].items())
+          + f"; {up['programs_compiled']} program(s) compiled, "
+          f"{up['programs_cache_loaded']} loaded from the cache "
+          f"({up['compile_or_load_s']:.2f} s; trace + lower "
+          f"{up['trace_lower_s']:.2f} s)"
+          + ("" if up["ready"] else "; no token was emitted"),
+          file=sys.stderr)
     mode = (f"in-scan prefill, {server.engine.prefill_chunk} tok/piece"
             if args.prefill_chunk else "host prefill")
     print(f"slot occupancy: {server.occupancy_lifetime():.3f} "

@@ -275,6 +275,10 @@ def _extract_carry(carry, i):
     )
 
 
+def tree_nbytes(tree) -> int:
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
 def fits_once_only(carry, params, device) -> bool:
     """Does ``device`` hold the carry once beside the weights, but not
     twice? From its ``memory_stats()["bytes_limit"]``; False where the
@@ -282,10 +286,7 @@ def fits_once_only(carry, params, device) -> bool:
     limit = (device.memory_stats() or {}).get("bytes_limit")
     if not limit:
         return False
-    nbytes = lambda tree: sum(  # noqa: E731
-        x.size * x.dtype.itemsize for x in jax.tree.leaves(tree)
-    )
-    return 2 * nbytes(carry) + nbytes(params) > limit
+    return 2 * tree_nbytes(carry) + tree_nbytes(params) > limit
 
 
 def parse_buckets(spec: str, max_seq_len: int) -> Tuple[int, ...]:
@@ -536,6 +537,11 @@ class SlotEngine:
         self.donate_carry = fits_once_only(
             self._carry, params, next(iter(self._carry[0].devices()))
         )
+        # what that decision weighed (host metadata of the two trees)
+        self.held_bytes = {
+            "carry_bytes": tree_nbytes(self._carry),
+            "params_bytes": tree_nbytes(params),
+        }
         self.state_writes_per_chunk = self._state_writes_per_chunk()
         self._rngs = jnp.tile(
             jax.random.PRNGKey(0)[None], (self.slots, 1)
@@ -554,8 +560,8 @@ class SlotEngine:
         # measured wall time across these entries (obs/cost.py); rebuilt
         # at every step(), read immediately after, never on the device.
         self.last_boundary: List[dict] = []
-        # program kinds whose first launch was timed (the observed
-        # compile time for the cost ledger); unified keys include the
+        # program kinds whose first launch was announced (the owner's
+        # ``setup.first_launch`` span); unified keys include the
         # staged-buffer width — a wider bucket is a new program
         self._compile_seen: set = set()
         # AOT warm start (serving/exec_store.py): per-key deserialized
@@ -1492,26 +1498,23 @@ class SlotEngine:
         (carry, emitted, accepted-or-None). Applies any armed per-slot
         (or legacy per-chunk) decode-state poisoning afterwards so each
         ladder rung is deterministically reachable per slot."""
-        # cost-ledger compile observation: the FIRST launch of each
-        # program kind (per staged-buffer width for the unified program —
-        # a wider bucket is a new executable) is timed against its jit
-        # cache size; growth means this call paid the compile, and the
-        # observed wall time lands in the ledger as that program's
-        # compile cost. One-time host bookkeeping per kind — later
-        # boundaries skip even the cache-size read.
+        # the FIRST launch of each program kind (per staged-buffer width
+        # for the unified program — a wider bucket is a new executable)
+        # is announced to the owner by two edges, so its
+        # ``setup.first_launch`` span surrounds whatever jax traces,
+        # lowers, compiles or loads for it (serving/server.py
+        # ``_first_launch``). One-time host bookkeeping per kind — later
+        # boundaries pay one set lookup.
         kind = ("spec_round" if spec is not None
                 else "unified_prefill" if unified else "decode_batched")
         donate = self.donate_carry and spec is None
-        seen_key = (
-            (kind, self._pbuf.shape[1]) if kind == "unified_prefill"
-            else kind
-        )
-        watch = None
-        if seen_key not in self._compile_seen:
-            from orion_tpu.generate import DECODE_PROGRAMS
-
-            jf = DECODE_PROGRAMS["decode_scan_donated" if donate else kind]
-            watch = (jf, jf._cache_size(), time.monotonic())
+        width = int(self._pbuf.shape[1]) if kind == "unified_prefill" else 0
+        seen_key = (kind, width) if width else kind
+        first = seen_key not in self._compile_seen
+        if first:
+            self._compile_seen.add(seen_key)
+            self._emit("first_launch", edge="begin", program=kind,
+                       width=width)
         # AOT warm start: a stored executable (same program, same
         # compiler) replaces the jit dispatch — statics are baked into
         # the artifact, so the warm calls pass only the dynamic operands
@@ -1519,54 +1522,56 @@ class SlotEngine:
         # undonated programs only.
         warm = None if donate else self._warm_boundary_exec(kind, seen_key)
         accepted = None
-        if donate:
-            live = np.array([s is not None for s in self._slots])
-            out, toks = decode_boundary_donated(
-                self.model, self.params, carry, self._rngs, active_dev,
-                self._pbuf, self._plen, self._pfold,
-                tuple(self._selected_prefill_slots(live)) if unified else (),
-                self.chunk, self.prefill_chunk, self._sample,
-            )
-        elif spec is not None:
-            if warm is not None:
-                out, toks, accepted = warm(
-                    self.params, carry, self._rngs, active_dev, spec
-                )
-            else:
-                out, toks, accepted = decode_batched_spec_round(
+        try:
+            if donate:
+                live = np.array([s is not None for s in self._slots])
+                out, toks = decode_boundary_donated(
                     self.model, self.params, carry, self._rngs, active_dev,
-                    spec, self.spec_depth, self._sample,
+                    self._pbuf, self._plen, self._pfold,
+                    tuple(self._selected_prefill_slots(live))
+                    if unified else (),
+                    self.chunk, self.prefill_chunk, self._sample,
                 )
-        elif unified:
-            pwait = jnp.asarray(np.array(
-                [0 if s is None else s.passed_over for s in self._slots],
-                np.int32,
-            ))
-            if warm is not None:
-                out, toks = warm(
-                    self.params, carry, self._rngs, active_dev,
-                    self._pbuf, self._plen, self._pfold, pwait,
-                )
+            elif spec is not None:
+                if warm is not None:
+                    out, toks, accepted = warm(
+                        self.params, carry, self._rngs, active_dev, spec
+                    )
+                else:
+                    out, toks, accepted = decode_batched_spec_round(
+                        self.model, self.params, carry, self._rngs, active_dev,
+                        spec, self.spec_depth, self._sample,
+                    )
+            elif unified:
+                pwait = jnp.asarray(np.array(
+                    [0 if s is None else s.passed_over for s in self._slots],
+                    np.int32,
+                ))
+                if warm is not None:
+                    out, toks = warm(
+                        self.params, carry, self._rngs, active_dev,
+                        self._pbuf, self._plen, self._pfold, pwait,
+                    )
+                else:
+                    out, toks = decode_batched_prefill_chunk(
+                        self.model, self.params, carry, self._rngs, active_dev,
+                        self._pbuf, self._plen, self._pfold, pwait, self.chunk,
+                        self.prefill_chunk, self._sample,
+                    )
             else:
-                out, toks = decode_batched_prefill_chunk(
-                    self.model, self.params, carry, self._rngs, active_dev,
-                    self._pbuf, self._plen, self._pfold, pwait, self.chunk,
-                    self.prefill_chunk, self._sample,
-                )
-        else:
-            if warm is not None:
-                out, toks = warm(self.params, carry, self._rngs, active_dev)
-            else:
-                out, toks = decode_batched_chunk(
-                    self.model, self.params, carry, self._rngs, active_dev,
-                    self.chunk, self._sample,
-                )
-        if watch is not None:
-            jf, before, t0 = watch
-            self._compile_seen.add(seen_key)
-            if jf._cache_size() > before:
-                self._emit("program_compile", program=kind,
-                           ms=round((time.monotonic() - t0) * 1e3, 3))
+                if warm is not None:
+                    out, toks = warm(
+                        self.params, carry, self._rngs, active_dev
+                    )
+                else:
+                    out, toks = decode_batched_chunk(
+                        self.model, self.params, carry, self._rngs, active_dev,
+                        self.chunk, self._sample,
+                    )
+        finally:
+            if first:
+                self._emit("first_launch", edge="end",
+                           warm=warm is not None)
         if inject.active():
             for i, slot in enumerate(self._slots):
                 if slot is None:

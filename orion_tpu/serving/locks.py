@@ -256,6 +256,9 @@ LOCKS: Dict[str, LockDecl] = {
                     "al. all take the registry lock)",
                 ),
             ),
+            # Server.__init__ runs its whole body as _build, inside the
+            # ``setup.server`` span
+            guard_exempt=("__init__", "_build"),
             bans=("wire", "disk-io", "subprocess", "sleep", "device-sync"),
         ),
         LockDecl(
@@ -274,6 +277,7 @@ LOCKS: Dict[str, LockDecl] = {
                     "submit threads",
                 ),
             ),
+            guard_exempt=("__init__", "_build"),
             bans=("disk-io", "subprocess", "sleep", "device-sync"),
         ),
         LockDecl(

@@ -65,7 +65,15 @@ from orion_tpu.obs import slo as obs_slo
 from orion_tpu.obs.flight import FlightRecorder
 from orion_tpu.obs.http import ObsHTTPServer
 from orion_tpu.obs.metrics import MetricsRegistry
-from orion_tpu.obs.trace import NULL_SPAN, Tracer
+from orion_tpu.obs.trace import (
+    COMPILE_COUNTERS,
+    NULL_SPAN,
+    Tracer,
+    compile_counts,
+    on_compile,
+    setup_record,
+    setup_summary,
+)
 from orion_tpu.resilience.breaker import CircuitBreaker, StoreUnavailableError
 from orion_tpu.resilience.inject import fire
 from orion_tpu.resilience.preempt import PreemptionGuard
@@ -387,6 +395,17 @@ class Server:
         tracer: Optional[Tracer] = None,
         flight: Optional[FlightRecorder] = None,
     ):
+        self.trace = tracer if tracer is not None else Tracer(
+            path=cfg.trace_path, clock=clock, enabled=bool(cfg.trace_path),
+        )
+        # the set-up tree (obs/trace.py, ``cat="setup"``): this span and
+        # its children reach the process-wide record whether or not the
+        # tracer is enabled
+        with self.trace.span("setup.server", "setup", slots=cfg.slots,
+                             chunk=cfg.chunk):
+            self._build(model, params, cfg, clock, flight)
+
+    def _build(self, model, params, cfg, clock, flight) -> None:
         from orion_tpu import generate as _gen
         from orion_tpu.serving.batching import SlotEngine, parse_buckets
 
@@ -403,9 +422,10 @@ class Server:
                 f"qmode must be one of off|int8|int4, got {cfg.qmode!r}"
             )
         if self.qmode != "off":
-            model, params = _gen.quantize_for_decode(
-                model, params, mode=self.qmode
-            )
+            with self.trace.span("setup.quantize", "setup", qmode=self.qmode):
+                model, params = _gen.quantize_for_decode(
+                    model, params, mode=self.qmode
+                )
         # the weights' identity stamps BOTH stores: prefix entries are
         # keyed by it (content addressing) and session generations carry
         # it (a suspended state resumed under different weights or qmode
@@ -429,9 +449,18 @@ class Server:
         self.metrics = MetricsRegistry(clock=clock, lock=self._stats_lock)
         for key in _STAT_KEYS + _SLOT_CLASS_KEYS + _KV_ROW_KEYS + _KV_BLOCK_KEYS:
             self.metrics.counter(key)  # the legacy stats dict's cells
-        self.trace = tracer if tracer is not None else Tracer(
-            path=cfg.trace_path, clock=clock, enabled=bool(cfg.trace_path),
-        )
+        # what jax built while this server lived (obs/trace.py
+        # ``compile_event``), by stage; the open ``setup.first_launch``
+        # and the backend stages that fell inside it
+        self._c_compile = {
+            key: self.metrics.counter(key) for key in COMPILE_COUNTERS
+        }
+        self._launch = None
+        self._setup_ready = False
+        # the boundary the running serve-loop iteration steps (or would):
+        # what every phase span and compile event carries as ``boundary``
+        self._boundary = 1
+        on_compile(self._on_compile)
         self.flight = flight if flight is not None else FlightRecorder(
             clock=clock, dump_dir=cfg.flight_dir,
         )
@@ -484,18 +513,21 @@ class Server:
                     "the footprint is suspect (/statusz mesh section)",
                     stacklevel=2,
                 )
-        self.engine = SlotEngine(
-            model, params, slots=cfg.slots, chunk=cfg.chunk, clock=clock,
-            prefill_buckets=parse_buckets(
-                cfg.prefill_buckets, model.cfg.max_seq_len
-            ),
-            prefill_chunk=cfg.prefill_chunk,
-            prompt_overflow=cfg.prompt_overflow,
-            on_event=self._on_engine_event,
-            spec_depth=cfg.spec_depth,
-            spec_min_accept=cfg.spec_min_accept,
-            mesh=self.mesh,
-        )
+        with self.trace.span("setup.engine", "setup") as built:
+            self.engine = SlotEngine(
+                model, params, slots=cfg.slots, chunk=cfg.chunk, clock=clock,
+                prefill_buckets=parse_buckets(
+                    cfg.prefill_buckets, model.cfg.max_seq_len
+                ),
+                prefill_chunk=cfg.prefill_chunk,
+                prompt_overflow=cfg.prompt_overflow,
+                on_event=self._on_engine_event,
+                spec_depth=cfg.spec_depth,
+                spec_min_accept=cfg.spec_min_accept,
+                mesh=self.mesh,
+            )
+            built.note(donate_carry=self.engine.donate_carry,
+                       **self.engine.held_bytes)
         # self-speculation telemetry (ISSUE 13): totals for the SLO
         # engine's rate views plus a per-turn acceptance-rate histogram
         # — when speculation stops paying, the acceptance collapse is
@@ -525,53 +557,67 @@ class Server:
         self._breakers: Dict[str, CircuitBreaker] = {}
         self._c_store_errors = self.metrics.counter("store_errors")
         self._c_prefix_drops = self.metrics.counter("prefix_publish_drops")
-        if cfg.prefix_dir:
-            from orion_tpu.serving.prefix_store import PrefixStore
-
-            self.prefix_store = PrefixStore(
-                cfg.prefix_dir, params_id=self.params_id, qmode=self.qmode,
-                align=max(self.engine.chunk_align, 1),
-                keep=cfg.prefix_keep,
-                should_abort=lambda: not self.health.accepting,
-                observer=self._on_prefix_io, clock=clock,
-                breaker=self._make_breaker("prefix"),
-            )
-            self.engine.attach_prefix_store(self.prefix_store)
-        # -- AOT executable store (ROADMAP item 1): the engine's first
-        # launch of each program consults it and a hit installs the
-        # deserialized executable — a warmed replica reaches its first
-        # token without one compile. Its breaker joins the failure-
-        # domain registry: an outage degrades to cold compiles (counted
-        # misses), never failed requests, and health reports
-        # store-outage:exec so the supervisor doesn't churn the replica.
+        self.session_store: Optional[SessionStore] = None
         self.exec_store = None
-        self._h_exec_load_ms = self.metrics.histogram("exec_load_ms")
-        self._h_exec_save_ms = self.metrics.histogram("exec_save_ms")
-        if cfg.exec_dir:
-            from orion_tpu.serving.exec_store import ExecStore
+        with self.trace.span("setup.stores", "setup") as opened:
+            if cfg.prefix_dir:
+                from orion_tpu.serving.prefix_store import PrefixStore
 
-            self.exec_store = ExecStore(
-                cfg.exec_dir, identity=self._weights_identity,
-                local_dir=cfg.exec_local_dir,
-                max_resident=cfg.exec_max_resident,
-                should_abort=lambda: not self.health.accepting,
-                observer=self._on_exec_io, clock=clock,
-                breaker=self._make_breaker("exec"),
-            )
-            self.engine.attach_exec_store(self.exec_store, qmode=self.qmode)
-            for stat in ("hits", "misses", "publishes",
-                         "fallback_compiles", "errors"):
-                # single-writer int reads (the scheduler owns the stats
-                # dict) — host-only, like every gauge_fn provider
-                self.metrics.gauge_fn(
-                    "exec_store_events",
-                    lambda s=stat: self.exec_store.stats[s],
-                    labels={"event": stat},
+                self.prefix_store = PrefixStore(
+                    cfg.prefix_dir, params_id=self.params_id, qmode=self.qmode,
+                    align=max(self.engine.chunk_align, 1),
+                    keep=cfg.prefix_keep,
+                    should_abort=lambda: not self.health.accepting,
+                    observer=self._on_prefix_io, clock=clock,
+                    breaker=self._make_breaker("prefix"),
                 )
-            self.metrics.gauge_fn(
-                "exec_store_resident",
-                lambda: self.exec_store.resident_count(),
-            )
+                self.engine.attach_prefix_store(self.prefix_store)
+            # -- AOT executable store (ROADMAP item 1): the engine's first
+            # launch of each program consults it and a hit installs the
+            # deserialized executable — a warmed replica reaches its first
+            # token without one compile. Its breaker joins the failure-
+            # domain registry: an outage degrades to cold compiles (counted
+            # misses), never failed requests, and health reports
+            # store-outage:exec so the supervisor doesn't churn the replica.
+            self._h_exec_load_ms = self.metrics.histogram("exec_load_ms")
+            self._h_exec_save_ms = self.metrics.histogram("exec_save_ms")
+            if cfg.exec_dir:
+                from orion_tpu.serving.exec_store import ExecStore
+
+                self.exec_store = ExecStore(
+                    cfg.exec_dir, identity=self._weights_identity,
+                    local_dir=cfg.exec_local_dir,
+                    max_resident=cfg.exec_max_resident,
+                    should_abort=lambda: not self.health.accepting,
+                    observer=self._on_exec_io, clock=clock,
+                    breaker=self._make_breaker("exec"),
+                )
+                self.engine.attach_exec_store(self.exec_store, qmode=self.qmode)
+                for stat in ("hits", "misses", "publishes",
+                             "fallback_compiles", "errors"):
+                    # single-writer int reads (the scheduler owns the stats
+                    # dict) — host-only, like every gauge_fn provider
+                    self.metrics.gauge_fn(
+                        "exec_store_events",
+                        lambda s=stat: self.exec_store.stats[s],
+                        labels={"event": stat},
+                    )
+                self.metrics.gauge_fn(
+                    "exec_store_resident",
+                    lambda: self.exec_store.resident_count(),
+                )
+            if cfg.session_dir:
+                self.session_store = SessionStore(
+                    cfg.session_dir, keep=cfg.session_keep,
+                    # a DRAINING/DEAD server must not burn its drain grace
+                    # backing off on session I/O (resilience/retry.py)
+                    should_abort=lambda: not self.health.accepting,
+                    observer=self._on_store_io, clock=clock,
+                    identity=self._weights_identity,
+                    breaker=self._make_breaker("session"),
+                )
+            opened.note(prefix=bool(cfg.prefix_dir), exec=bool(cfg.exec_dir),
+                        session=bool(cfg.session_dir))
         # the gauges we used to fly blind on — all callable (evaluated at
         # scrape time from live host state) and all free: queue depth,
         # per-slot prefill-vs-decode occupancy, compile-cache sizes
@@ -621,7 +667,8 @@ class Server:
                 fallback_flops_per_token=2.0 * n_params,
             )
             if cfg.cost_ledger:
-                self._harvest_cost_ledger(model)
+                with self.trace.span("setup.cost_harvest", "setup"):
+                    self._harvest_cost_ledger(model)
             self._h_req_device_ms = self.metrics.histogram(
                 "request_device_ms"
             )
@@ -662,17 +709,6 @@ class Server:
         # so idle/LRU eviction is pure cache management, and the race
         # "idle eviction at the same boundary a continuation re-admits"
         # degrades to a disk read, never a lost session)
-        self.session_store: Optional[SessionStore] = None
-        if cfg.session_dir:
-            self.session_store = SessionStore(
-                cfg.session_dir, keep=cfg.session_keep,
-                # a DRAINING/DEAD server must not burn its drain grace
-                # backing off on session I/O (resilience/retry.py)
-                should_abort=lambda: not self.health.accepting,
-                observer=self._on_store_io, clock=clock,
-                identity=self._weights_identity,
-                breaker=self._make_breaker("session"),
-            )
         self._sessions: "OrderedDict[str, SessionState]" = OrderedDict()
         self._session_last_use: Dict[str, float] = {}
         self._active_sessions: set = set()  # ids resident in engine slots
@@ -718,9 +754,6 @@ class Server:
         # the first poll of the idle stretch in progress
         self._engine_phase = NULL_SPAN
         self._idle_first = None
-        # the boundary the running serve-loop iteration steps (or would):
-        # what every phase span carries as ``boundary``
-        self._boundary = 1
         # -- live exposition (obs/http.py): /metrics /healthz /statusz
         # /slo on a daemon thread; stays up across serve() calls (a
         # balancer must see DRAINING/DEAD as 503, not connection
@@ -761,6 +794,65 @@ class Server:
         if not tr.enabled and tr.annotate is None:
             return NULL_SPAN
         return tr.span(name, "phase", record, boundary=self._boundary, **args)
+
+    def _on_compile(self, name: str, start_s: float, dur_s: float,
+                    args: dict) -> None:
+        """One stage of a program jax built somewhere in this process
+        (obs/trace.py ``compile_event``; it is in the process-wide record
+        already): into this server's counters and, with the tracer on,
+        its ring, under the boundary the scheduler thread is in — an
+        in-window compile then lies inside the ``serve.admit`` or
+        ``serve.dispatch`` span that caused it. jax reports a stage once
+        it is over, so a running capture gets a marker where it ENDED;
+        the enclosing phase span is the annotation that covers it."""
+        for key, n in compile_counts(name, dur_s, args):
+            self._c_compile[key].inc(n)
+        launch = self._launch
+        if (name == "compile.backend" and launch is not None
+                and launch[1] == threading.get_ident()):
+            launch[2].append((args.get("source"), dur_s))
+        tr = self.trace
+        if tr.enabled:
+            tr.complete(name, start_s, dur_s, cat="compile",
+                        boundary=self._boundary, **args)
+        if tr.annotate is not None:
+            with tr.annotate(name):
+                pass
+
+    def _first_launch(self, fields: dict) -> None:
+        """The engine's two edges around the FIRST launch of a boundary
+        program kind (and staged width): ``setup.first_launch``. What the
+        launch cost is learned from the backend stages that fell inside
+        it on this thread: jax ``compiled`` the program, loaded it from
+        its ``cache``, the ``exec_store`` handed a stored executable
+        over, or the process had built it already (``resident``). A
+        launch that built something is the program's observed compile
+        cost — into the ledger (the /costz "compile_ms" column) and the
+        black box (a mid-serve compile is always worth explaining)."""
+        if fields.pop("edge") == "begin":
+            span = self.trace.span("setup.first_launch", "setup", **fields)
+            self._launch = (span, threading.get_ident(), [])
+            span.__enter__()
+            return
+        if self._launch is None:
+            return
+        (span, _, built), self._launch = self._launch, None
+        sources = {source for source, _ in built}
+        source = ("exec_store" if fields.get("warm")
+                  else "compiled" if "compiled" in sources
+                  else "cache" if sources else "resident")
+        span.note(source=source, programs=len(built))
+        span.__exit__(None, None, None)
+        if not built:
+            return
+        program, ms = span.args["program"], round(span.dur * 1e3, 3)
+        if self.cost_ledger is not None:
+            self.cost_ledger.note_compile(program, ms)
+            self.metrics.gauge("cost_ledger_compile_ms").set(
+                ms, labels={"program": program},
+            )
+        self.flight.record("program_compile", program=program, ms=ms,
+                           source=source)
 
     # -- telemetry hooks (all host-only; see obs-device-sync) -----------------
 
@@ -976,6 +1068,11 @@ class Server:
                 "stats": dict(self.exec_store.stats),
                 "resident": self.exec_store.resident_count(),
             }
+        # why did this replica take so long to come up, and which program
+        # missed the cache: the process's set-up and compile events
+        # (obs/trace.py ``setup_record``), summed and in full
+        record = setup_record()
+        snap["setup"] = {"summary": setup_summary(record), "events": record}
         snap["flight_tail"] = self.flight.events()[-20:]
         return snap
 
@@ -1255,20 +1352,8 @@ class Server:
         rid = getattr(tag, "rid", None)
         if rid is not None:
             fields["req"] = rid
-        if kind == "program_compile":
-            # the engine observed a jit cache GROW on a program's first
-            # launch: that wall time is the program's compile cost — into
-            # the ledger (the /costz "compile_ms" column) and the black
-            # box (a mid-serve compile is always worth explaining)
-            if self.cost_ledger is not None:
-                self.cost_ledger.note_compile(
-                    fields.get("program", "?"), fields.get("ms", 0.0)
-                )
-                self.metrics.gauge("cost_ledger_compile_ms").set(
-                    fields.get("ms", 0.0),
-                    labels={"program": fields.get("program", "?")},
-                )
-            self.flight.record("program_compile", **fields)
+        if kind == "first_launch":
+            self._first_launch(fields)
             return
         if kind == "spec_round":
             # totals every round; the flight ring records only rounds
@@ -2027,6 +2112,11 @@ class Server:
                     # this boundary's scan emitted the request's first
                     # tokens; its end is when a client could see one
                     self._first_token(tag, t_end)
+            if emitting and not self._setup_ready:
+                # the first boundary that emitted a token ends set-up
+                self._setup_ready = True
+                self.trace.instant("setup.ready", "setup",
+                                   boundary=self._boundary)
             with self._stats_lock:
                 self._bump("chunks")
                 self._bump("slot_steps_active", occupied)

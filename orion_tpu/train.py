@@ -22,6 +22,7 @@ import sys
 from typing import Optional, Tuple
 
 from orion_tpu.models.configs import get_config
+from orion_tpu.obs.trace import PROCESS_TRACER
 from orion_tpu.parallel.mesh import MeshConfig, initialize_distributed
 from orion_tpu.resilience.preempt import PreemptionGuard
 from orion_tpu.resilience.watchdog import Watchdog
@@ -49,16 +50,19 @@ def train(
             "would silently never be evaluated; set eval_every > 0 "
             "(CLI: --eval-every N)"
         )
-    trainer = Trainer(cfg)
     ckpt = None
     start = 0
-    if cfg.ckpt_dir:
-        ckpt = Checkpointer(
-            cfg.ckpt_dir, max_to_keep=cfg.ckpt_keep, save_every=cfg.ckpt_every
-        )
-        if resume and ckpt.latest_step is not None:
-            start = trainer.restore(ckpt)
-            print(f"resumed from step {start}", file=sys.stderr)
+    with PROCESS_TRACER.span("setup.weights", "setup") as weights:
+        trainer = Trainer(cfg)
+        if cfg.ckpt_dir:
+            ckpt = Checkpointer(
+                cfg.ckpt_dir, max_to_keep=cfg.ckpt_keep,
+                save_every=cfg.ckpt_every,
+            )
+            if resume and ckpt.latest_step is not None:
+                start = trainer.restore(ckpt)
+                print(f"resumed from step {start}", file=sys.stderr)
+        weights.note(source="checkpoint" if start else "init")
 
     dataset = make_dataset(data, cfg.seq_len, cfg.model.vocab_size)
     assert dataset.vocab_size <= cfg.model.vocab_size, (

@@ -38,6 +38,7 @@ from orion_tpu.parallel.mesh import MeshConfig, make_mesh
 from orion_tpu.parallel.sharding import batch_sharding, param_shardings
 from orion_tpu.resilience import inject as _inject
 from orion_tpu.utils import rng as rngs
+from orion_tpu.obs.trace import NULL_SPAN, PROCESS_TRACER, compile_totals
 from orion_tpu.utils.profiling import annotate, annotated_steps
 
 Array = jax.Array
@@ -336,11 +337,28 @@ class Trainer:
         cfg: TrainConfig,
         mesh: Optional[Mesh] = None,
         materialize: bool = True,
+        tracer=None,
     ):
         """``materialize=False`` builds the mesh, shardings, and jitted step
         WITHOUT allocating params/optimizer state — the AOT planning path
         (orion_tpu/aot.py): a 7B step can be lowered and compiled on a
-        virtual CPU mesh whose host could never hold the weights."""
+        virtual CPU mesh whose host could never hold the weights.
+
+        ``tracer`` (obs/trace.py ``Tracer``, as ``Server`` takes one)
+        writes the set-up tree: ``setup.trainer`` around this constructor
+        with ``setup.init_state`` inside, ``setup.restore``, and in
+        :meth:`train` ``setup.loader`` / ``.first_step`` / ``.first_eval``
+        / ``.ready``; the process's own tracer where none is handed in.
+        They reach the process-wide record either way."""
+        self.trace = tracer if tracer is not None else PROCESS_TRACER
+        # the once-a-Trainer set-up spans already written, and the first
+        # step's loss until it is back
+        self._setup_done: set = set()
+        self._first_loss = None
+        with self.trace.span("setup.trainer", "setup"):
+            self._build(cfg, mesh, materialize)
+
+    def _build(self, cfg: TrainConfig, mesh, materialize: bool) -> None:
         # fail loudly: out-of-range positions would be silently clamped by
         # XLA gather, yielding wrong position embeddings (train.py's CLI
         # auto-bumps max_seq_len; the library path must not rely on that)
@@ -497,11 +515,14 @@ class Trainer:
         # one rule set shards the whole state: optimizer-moment paths end in
         # the same 'wq/kernel'-style suffixes the param rules match on
         self.state_shardings = param_shardings(self._abstract, self.mesh)
-        self.state = (
-            jax.jit(init_fn, out_shardings=self.state_shardings)(self._init_rng)
-            if materialize
-            else None
-        )
+        self.state = None
+        if materialize:
+            with self.trace.span("setup.init_state", "setup"):
+                self.state = jax.block_until_ready(
+                    jax.jit(init_fn, out_shardings=self.state_shardings)(
+                        self._init_rng
+                    )
+                )
 
         self._step_fn = jax.jit(
             self._train_step,
@@ -685,6 +706,23 @@ class Trainer:
 
     # -- host API -----------------------------------------------------------
 
+    def _once(self, name: str):
+        """The set-up span ``name`` the first time a Trainer asks for it,
+        the shared null span ever after."""
+        if name in self._setup_done:
+            return NULL_SPAN
+        self._setup_done.add(name)
+        return self.trace.span(name, "setup")
+
+    @staticmethod
+    def _show_compiles(registry) -> None:
+        """The process's compile counters (obs/trace.py
+        ``COMPILE_COUNTERS``) onto a logger's registry, at log cadence:
+        what jax built since the registry last showed them."""
+        for key, total in compile_totals().items():
+            cell = registry.counter(key)
+            cell.inc(total - cell.value())
+
     def step(self, batch: Array) -> Dict[str, float]:
         assert self.state is not None, (
             "Trainer was built with materialize=False (AOT planning only); "
@@ -782,9 +820,14 @@ class Trainer:
         for step in annotated_steps("train", range(start_step + 1, cfg.steps + 1)):
             if watchdog is not None:
                 watchdog.beat(f"train step {step}")
-            with annotate("train.next_batch"):
+            with annotate("train.next_batch"), self._once("setup.loader"):
                 batch = next(data_iter)
-            metrics = self.step(batch)
+            # the first call traces, lowers and compiles or loads the
+            # step: host time, no wait for the device
+            with self._once("setup.first_step") as first:
+                metrics = self.step(batch)
+            if first is not NULL_SPAN:
+                self._first_loss = metrics["loss"]
             # only materialize metrics on the host at log cadence — reading a
             # device scalar every step would serialize the pipeline
             if step % cfg.log_every == 0 or step == cfg.steps:
@@ -819,6 +862,7 @@ class Trainer:
                 last = {k: float(v) for k, v in metrics.items()}
                 last["ppl"] = float(jnp.exp(jnp.minimum(last["loss"], 20.0)))
                 if logger:
+                    self._show_compiles(logger.registry)
                     logger.log(step, last, tokens_per_step)
             if (
                 (eval_iter is not None or eval_factory is not None)
@@ -847,6 +891,11 @@ class Trainer:
                     ckpt.maybe_save(step, self.state)
             if hook is not None:
                 hook(step, metrics)
+            if self._first_loss is not None and self._first_loss.is_ready():
+                # the loop's own log or hook has waited for the first
+                # step (asked, not waited for, here): set-up is over
+                self._first_loss = None
+                self.trace.instant("setup.ready", "setup", step=step)
             # chaos harness: simulated preemption delivers a real signal
             # here; the installed guard's handler runs synchronously and
             # flips should_stop before the check below
@@ -883,7 +932,8 @@ class Trainer:
         total, count = 0.0, 0.0
         for _ in range(n):
             batch = next(data_iter)
-            s, c = self._eval_fn(self.state.params, batch)
+            with self._once("setup.first_eval"):
+                s, c = self._eval_fn(self.state.params, batch)
             total += float(s)
             count += float(c)
         loss = total / max(count, 1.0)
@@ -898,7 +948,8 @@ class Trainer:
         return jax.tree.map(leaf, self._abstract, self.state_shardings)
 
     def restore(self, ckpt, step: Optional[int] = None):
-        self.state = ckpt.restore(self.abstract_state(), step)
+        with self.trace.span("setup.restore", "setup"):
+            self.state = ckpt.restore(self.abstract_state(), step)
         # sync the host-side counter so halt-mode doesn't re-raise for bad
         # steps that happened (and were handled) before the checkpoint
         self.nonfinite_steps = int(self.state.nonfinite)
