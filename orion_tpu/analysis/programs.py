@@ -195,16 +195,17 @@ PROGRAMS: Tuple[ProgramDecl, ...] = (
     ProgramDecl("insert_carry", BATCHING, "_insert_carry", "setup",
                 note="slot admission row write; traced slot index — one "
                      "compile ever per engine shape"),
-    ProgramDecl("stage_prompt_carry", BATCHING, "_stage_prompt_carry",
+    ProgramDecl("stage_rows_carry", BATCHING, "_stage_rows_carry",
                 "setup", donate_argnums=(0, 1, 2, 3, 4),
-                note="in-scan admission staging; one compile per staged "
-                     "buffer width. The carry and the staging vectors "
-                     "are donated: a row write in place, never a copy "
-                     "of the whole decode state"),
+                note="in-scan admission staging, up to STAGE_ROWS prompts "
+                     "a dispatch; one compile per staged buffer width, "
+                     "none per prompt length or row count. The carry and "
+                     "the staging vectors are donated: row writes in "
+                     "place, never a copy of the whole decode state"),
     ProgramDecl("stage_prefix_carry", BATCHING, "_stage_prefix_carry",
                 "setup", donate_argnums=(0, 1, 2, 3, 4),
                 note="prefix-cache-hit admission staging; donated like "
-                     "stage_prompt_carry"),
+                     "stage_rows_carry"),
     ProgramDecl("restart_prefill_row", BATCHING, "_restart_prefill_row",
                 "setup",
                 note="chaos-ladder rung 2 row rewind; NOT donated: it "

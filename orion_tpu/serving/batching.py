@@ -15,8 +15,9 @@ row evictions on one carry.
   regardless of arrival order; per-slot positions (vector ``t``), per-slot
   rng streams, and the active mask all ride in traced.
 - **admission** — at chunk boundaries only, and since ISSUE 7 an O(1)
-  row insert: the prompt is STAGED into the carry (padded to its bucket)
-  and consumed INSIDE the batched scan
+  row insert: the prompt is STAGED into the carry (padded to its bucket
+  on the host; all of a boundary's admissions in ONE donated dispatch,
+  ``_stage_rows_carry``) and consumed INSIDE the batched scan
   (``generate.decode_batched_prefill_chunk``) — each boundary runs one
   ``prefill_chunk``-token piece for each waiting slot, up to
   ``slots // chunk`` of them (shortest remaining first; a slot passed
@@ -68,6 +69,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import operator
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -183,34 +185,76 @@ def _insert_carry(carry, rngs, plen, pfold, sub_carry, rng, i, n_emitted):
     )
 
 
+# How many admissions ONE staging dispatch writes (:func:`_stage_rows_carry`);
+# a boundary that admits more makes more calls of the same program.
+STAGE_ROWS = 8
+# a packed staging row's columns ahead of its padded prompt: slot (-1 = no
+# entry), prompt length, first-token rng-fold index, the rng key's two words
+_ROW_HEAD = 5
+
+
+def _seed_key(seed) -> np.ndarray:
+    """The two uint32 words of ``jax.random.PRNGKey(seed)`` (threefry2x32)
+    made on the host, where PRNGKey is an eager device program a request.
+    jax reads the seed as a C long (the same OverflowError beyond 64 bits,
+    the same TypeError for a non-integer) and keeps its low word, and the
+    high word too only in 64-bit mode."""
+    seed = operator.index(seed)
+    if not -(1 << 63) <= seed < 1 << 63:
+        raise OverflowError("Python int too large to convert to C long")
+    hi = seed >> 32 if jax.config.jax_enable_x64 else 0
+    return np.array([hi & 0xFFFFFFFF, seed & 0xFFFFFFFF], np.uint32)
+
+
+def _host_prompt(prompt) -> np.ndarray:
+    """A request's prompt as a host ``[B, T]`` int32 array. The Server's
+    requests hold host arrays already (normalised at submit, off the
+    scheduler thread), so this is a view; a device array handed in by a
+    direct caller is read back once, here."""
+    prompt = np.asarray(prompt, np.int32)
+    return prompt[None] if prompt.ndim == 1 else prompt
+
+
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4))
-def _stage_prompt_carry(carry, rngs, plen, pfold, pbuf, row, rng, i,
-                        length, fold):
-    """O(1) in-scan admission: zero slot ``i``'s carry row and park its
-    padded prompt in the staging buffer — NO prefill runs here and no
-    host sync happens; the unified chunk program consumes the prompt
-    ``prefill_chunk`` tokens per boundary from inside the batched scan.
-    One fused dispatch per admit, one compile per staged-buffer width.
+def _stage_rows_carry(carry, rngs, plen, pfold, pbuf, rows):
+    """O(1) in-scan admission of up to ``STAGE_ROWS`` prompts in ONE
+    dispatch: for each entry of ``rows`` (``[STAGE_ROWS, _ROW_HEAD +
+    width]`` int32, packed on the host by ``SlotEngine._flush_staged``)
+    zero the slot's carry row, set its rng key and park its padded prompt
+    in the staging buffer — NO prefill runs here and no host sync happens;
+    the unified chunk program consumes the prompt ``prefill_chunk`` tokens
+    per boundary from inside the batched scan. One compile per
+    staged-buffer width, none per prompt length or per count: the loop
+    visits the valid entries only (they come first; the rest hold slot -1
+    and are never written), in order, so device work is the rows admitted.
     The carry, the per-slot vectors and the staging buffer are DONATED:
-    the row is written in place and the caller's buffers are gone (an
+    the rows are written in place and the caller's buffers are gone (an
     undonated call copied the whole decode state, 1.6 GB at 64 slots of
     lm_1b3, and cannot run at all beside a KV cache of GBs). Every leaf
-    of the row is zeroed, a KV cache's too: rows past a slot's position
+    of a row is zeroed, a KV cache's too: rows past a slot's position
     are masked, but the per-slot finite probe reads them."""
-    token, states, t, emit, done = carry
-    states = jax.tree.map(
-        lambda x: x.at[i].set(jnp.zeros(x.shape[1:], x.dtype)), states
-    )
-    new_carry = (
-        token.at[i].set(0),
-        states,
-        t.at[i].set(0),
-        emit.at[i].set(fold),
-        done.at[i].set(False),
-    )
-    return (
-        new_carry, rngs.at[i].set(rng), plen.at[i].set(length),
-        pfold.at[i].set(fold), pbuf.at[i].set(row),
+    keys = jax.lax.bitcast_convert_type(rows[:, 3:_ROW_HEAD], jnp.uint32)
+
+    def write(k, staged):
+        (token, states, t, emit, done), rngs, plen, pfold, pbuf = staged
+        i, length, fold = rows[k, 0], rows[k, 1], rows[k, 2]
+        states = jax.tree.map(
+            lambda x: x.at[i].set(jnp.zeros(x.shape[1:], x.dtype)), states
+        )
+        new_carry = (
+            token.at[i].set(0),
+            states,
+            t.at[i].set(0),
+            emit.at[i].set(fold),
+            done.at[i].set(False),
+        )
+        return (
+            new_carry, rngs.at[i].set(keys[k]), plen.at[i].set(length),
+            pfold.at[i].set(fold), pbuf.at[i].set(rows[k, _ROW_HEAD:]),
+        )
+
+    return jax.lax.fori_loop(
+        0, jnp.sum(rows[:, 0] >= 0), write, (carry, rngs, plen, pfold, pbuf)
     )
 
 
@@ -224,8 +268,8 @@ def _stage_prefix_carry(carry, rngs, plen, pfold, pbuf, st1, row, rng, i,
     the staging buffer and ``plen`` the full prompt length. The unified
     chunk program consumes from ``t`` onward, i.e. exactly the uncached
     suffix ``prompt[t0:]`` — no new device program, no host sync, one
-    fused row write (the same shape as :func:`_stage_prompt_carry` plus
-    the state insert), donated like it."""
+    fused row write (one entry of :func:`_stage_rows_carry` plus the state
+    insert), donated like it."""
     token, states, t, emit, done = carry
     states = insert_decode_slot(states, st1, i)
     new_carry = (
@@ -317,7 +361,7 @@ class _Slot:
     request: DecodeRequest
     tag: Any
     deadline_at: Optional[float]
-    prompt: Array  # [1, T] int32 (kept for the re-prefill rung)
+    prompt: np.ndarray  # [1, T] int32, host (kept for the re-prefill rung)
     # per-boundary (tokens [S, W], my row, valid count) — the row is NOT
     # sliced at the boundary (that would cost O(slots) device calls per
     # chunk on the scheduler's hot path) but lazily at eviction/
@@ -553,6 +597,15 @@ class SlotEngine:
         self._plen = jnp.zeros((self.slots,), jnp.int32)
         self._pfold = jnp.zeros((self.slots,), jnp.int32)
         self._pbuf: Optional[Array] = None
+        # admissions whose row is not on the device yet: (slot, prompt
+        # [T] int32, first rng-fold index, the key's two words), all host
+        # values, written by the boundary's one staging dispatch
+        # (:meth:`_flush_staged`), which every reader of the carry calls
+        # first
+        self._staged: List[Tuple[int, np.ndarray, int, np.ndarray]] = []
+        # lifetime count of staging dispatches (the K-row program's and
+        # the prefix hit's); the Server's ``admit_dispatches`` follows it
+        self.staging_dispatches = 0
         self._done_np = np.ones((self.slots,), bool)
         # cost attribution (ISSUE 15): per-boundary host report of what
         # each resident slot DID — work class + token counts, all values
@@ -861,7 +914,11 @@ class SlotEngine:
         sample_index: int = 0,
         seed: Optional[int] = None,
     ) -> int:
-        """Prefill ``request`` solo and insert it into a free slot.
+        """Admit ``request`` into a free slot. With in-scan prefill and
+        no prefix hit this is the HOST half only: the slot is claimed and
+        the prompt joins the rows the boundary's one staging dispatch
+        writes (:meth:`_flush_staged`); a prefix hit stages its cached
+        row at once, and ``prefill_chunk=0`` prefills solo and inserts.
         Raises ValueError for requests the engine cannot multiplex (no
         free slot, batch != 1, over-capacity, or a SampleConfig differing
         from the resident batch's static config); the caller decides
@@ -873,9 +930,7 @@ class SlotEngine:
         full context (original prompt + everything emitted + new user
         tokens) of a conversation that already folded ``sample_index``
         draws from ``PRNGKey(seed)``."""
-        prompt = jnp.asarray(request.prompt, jnp.int32)
-        if prompt.ndim == 1:
-            prompt = prompt[None]
+        prompt = _host_prompt(request.prompt)
         if prompt.shape[0] != 1:
             raise ValueError(
                 f"slot-multiplexed serving takes one sequence per request; "
@@ -896,7 +951,7 @@ class SlotEngine:
         if session_id is None:
             session_id = request.session_id
         seed = request.seed if seed is None else seed
-        rng = jax.random.PRNGKey(seed)
+        key = _seed_key(seed)
         remaining = prompt.shape[1] if self.prefill_chunk else 0
         if self.prefill_chunk:
             # O(1) in-scan admission: no prefill here — the prompt is
@@ -906,12 +961,17 @@ class SlotEngine:
             # instead, so the scan consumes only the uncached suffix.
             entry = self._prefix_lookup(request, prompt, tag)
             if entry is not None:
-                self._stage_prefix(i, prompt, rng, sample_index, entry)
+                self._stage_prefix(i, prompt, jnp.asarray(key), sample_index,
+                                   entry)
                 remaining = prompt.shape[1] - entry.t
             else:
-                self._stage_inscan(i, prompt, rng, sample_index)
+                # the host half only: the row waits for the boundary's
+                # ONE staging dispatch (:meth:`_flush_staged`)
+                self._grow_staging(prompt.shape[1])
+                self._staged.append((i, prompt[0], sample_index, key))
                 self._queue_prefix_publish(request, int(prompt.shape[1]))
         else:
+            rng = jnp.asarray(key)
             sub = prefill_carry(
                 self.model, self.params, prompt, self._sample, rng,
                 sample_index=sample_index, buckets=self.buckets,
@@ -938,7 +998,7 @@ class SlotEngine:
         )
         return i
 
-    def _check_bucket(self, prompt: Array, max_new: int) -> Array:
+    def _check_bucket(self, prompt: np.ndarray, max_new: int) -> np.ndarray:
         """A prompt longer than the largest prefill bucket never reaches
         jit: it is REFUSED with a clean single-request error (default) or
         clamped to the newest tokens of context (``prompt_overflow=
@@ -968,40 +1028,61 @@ class SlotEngine:
             "newest bucket-sized context with prompt_overflow='clamp'"
         )
 
-    def _staged_row(self, prompt: Array) -> Array:
-        """Grow the staging buffer to the prompt's bucket if needed
-        (widths take bucket values only — the unified program's compile
-        key stays bounded) and return the prompt as a buffer-width row."""
-        b = bucket_for(prompt.shape[1], self.buckets)
+    def _grow_staging(self, length: int) -> None:
+        """Grow the staging buffer to the bucket of a ``length``-token
+        prompt if it is narrower (widths take bucket values only — the
+        unified program's compile key stays bounded). Rows still pending
+        are written at whatever width the buffer has when they flush."""
+        b = bucket_for(length, self.buckets)
         width = 0 if self._pbuf is None else self._pbuf.shape[1]
-        if b > width:
-            if self._pbuf is None:
-                self._pbuf = jnp.zeros((self.slots, b), jnp.int32)
-            else:
-                self._pbuf = jnp.pad(
-                    self._pbuf, ((0, 0), (0, b - width))
-                )
-            if self.mesh is not None:
-                # a freshly (re)allocated staging buffer lands on the
-                # default device; the unified program wants it replicated
-                # over the mesh like every other per-slot input
-                from orion_tpu.parallel.decode import place_replicated
+        if b <= width:
+            return
+        if self._pbuf is None:
+            self._pbuf = jnp.zeros((self.slots, b), jnp.int32)
+        else:
+            self._pbuf = jnp.pad(self._pbuf, ((0, 0), (0, b - width)))
+        if self.mesh is not None:
+            # a freshly (re)allocated staging buffer lands on the
+            # default device; the unified program wants it replicated
+            # over the mesh like every other per-slot input
+            from orion_tpu.parallel.decode import place_replicated
 
-                self._pbuf = place_replicated(self._pbuf, self.mesh)
-            width = b
-        return jnp.pad(prompt, ((0, 0), (0, width - prompt.shape[1])))[0]
+            self._pbuf = place_replicated(self._pbuf, self.mesh)
 
-    def _stage_inscan(self, i: int, prompt: Array, rng: Array,
-                      sample_index: int) -> None:
-        """Stage one prompt for in-scan consumption: one fused row write
-        (:func:`_stage_prompt_carry`)."""
-        row = self._staged_row(prompt)
-        (self._carry, self._rngs, self._plen, self._pfold,
-         self._pbuf) = _stage_prompt_carry(
-            self._carry, self._rngs, self._plen, self._pfold, self._pbuf,
-            row, rng, jnp.int32(i), jnp.int32(prompt.shape[1]),
-            jnp.int32(sample_index),
-        )
+    def _flush_staged(self) -> None:
+        """Write every pending admission into the carry: ONE dispatch of
+        :func:`_stage_rows_carry` for up to ``STAGE_ROWS`` of them, every
+        input packed here in numpy (no pad program a prompt length, no
+        scalar copies a request). Runs before anything reads or writes
+        the carry, the per-slot vectors or the staging buffer, in the
+        order of admission."""
+        staged, self._staged = self._staged, []
+        for at in range(0, len(staged), STAGE_ROWS):
+            rows = np.zeros(
+                (STAGE_ROWS, _ROW_HEAD + self._pbuf.shape[1]), np.int32
+            )
+            rows[:, 0] = -1
+            for row, (i, prompt, fold, key) in zip(
+                rows, staged[at : at + STAGE_ROWS]
+            ):
+                row[:3] = i, prompt.size, fold
+                row[3:_ROW_HEAD] = key.view(np.int32)
+                row[_ROW_HEAD : _ROW_HEAD + prompt.size] = prompt
+            (self._carry, self._rngs, self._plen, self._pfold,
+             self._pbuf) = _stage_rows_carry(
+                self._carry, self._rngs, self._plen, self._pfold,
+                self._pbuf, rows,
+            )
+            self.staging_dispatches += 1
+
+    @_serialized
+    def flush_admissions(self) -> None:
+        """Stage the prompts admitted since the last flush (the Server
+        calls this at the end of its admission loop, so the dispatch
+        lies inside ``serve.admit``). Every method that touches the
+        carry flushes first on its own; only a caller that reads the
+        engine's private device state right after ``admit`` needs it."""
+        self._flush_staged()
 
     # -- content-addressed prefix cache (serving/prefix_store.py) -------------
     # Everything on this side of the store boundary is hash + disk + one
@@ -1009,7 +1090,8 @@ class SlotEngine:
     # covers *prefix*-named functions of this module, so the store owns
     # any host<->device serialization (publish-side device_get).
 
-    def _prefix_lookup(self, request: DecodeRequest, prompt: Array, tag):
+    def _prefix_lookup(self, request: DecodeRequest, prompt: np.ndarray,
+                       tag):
         """Longest cached aligned prefix of this request's prompt, or
         None. The lookup keys off the REQUEST's host tokens (the Server
         normalizes prompts to host arrays at submit, off the scheduler
@@ -1042,13 +1124,16 @@ class SlotEngine:
                    key=entry.key, generation=int(entry.generation))
         return entry
 
-    def _stage_prefix(self, i: int, prompt: Array, rng: Array,
+    def _stage_prefix(self, i: int, prompt: np.ndarray, rng: Array,
                       sample_index: int, entry) -> None:
         """O(suffix) admission on a prefix hit: the FULL prompt is staged
         (so the ladder's restart rung can replay from scratch) but the
         carry row starts at ``t = entry.t`` with the cached state — one
         fused row write, the snapshot copy that IS the prefix cache."""
-        row = self._staged_row(prompt)
+        self._flush_staged()
+        self._grow_staging(prompt.shape[1])
+        row = np.zeros((self._pbuf.shape[1],), np.int32)
+        row[: prompt.shape[1]] = prompt[0]
         (self._carry, self._rngs, self._plen, self._pfold,
          self._pbuf) = _stage_prefix_carry(
             self._carry, self._rngs, self._plen, self._pfold, self._pbuf,
@@ -1056,6 +1141,7 @@ class SlotEngine:
             jnp.int32(prompt.shape[1]), jnp.int32(sample_index),
             jnp.int32(entry.t),
         )
+        self.staging_dispatches += 1
 
     def _queue_prefix_publish(self, request: DecodeRequest,
                               prompt_len: int) -> None:
@@ -1129,6 +1215,7 @@ class SlotEngine:
         scan's state never sits exactly at the declared aligned length
         to be extracted for free (and the publish must not change the
         piece schedule, which is part of the bitwise contract)."""
+        self._flush_staged()
         done = 0
         br = self.prefix_store.breaker
         if br is not None and br.blocked():
@@ -1243,6 +1330,7 @@ class SlotEngine:
     def _insert(self, i: int, sub_carry, rng: Array, n_emitted: int = 0) -> None:
         """Row-write a solo carry (batch 1) into slot ``i`` of the batched
         carry (one fused jitted dispatch; see :func:`_insert_carry`)."""
+        self._flush_staged()
         (self._carry, self._rngs, self._plen,
          self._pfold) = _insert_carry(
             self._carry, self._rngs, self._plen, self._pfold, sub_carry,
@@ -1269,6 +1357,7 @@ class SlotEngine:
         if not self.busy:
             self._chunk_counter += 1
             return finished
+        self._flush_staged()
         active = np.array([s is not None for s in self._slots])
         active_dev = jnp.asarray(active)
         unified = self.prefilling_count > 0
@@ -1676,6 +1765,7 @@ class SlotEngine:
         turns' emissions) precedes this turn's chunks, and the fold index
         is anchored at ``fold_base`` so the rebuilt rng walk matches the
         carry the snapshot held."""
+        self._flush_staged()
         slot = self._slots[i]
         if slot.prompt_remaining > 0:
             # mid-prefill: nothing emitted yet — the one known-good input
@@ -1711,6 +1801,7 @@ class SlotEngine:
         trimmed to max_new_tokens (the engine always runs whole chunks)
         and an early-EOS eviction PAD-fills the tail, exactly what the
         solo scan would have emitted."""
+        self._flush_staged()
         slot = self._slots[i]
         self._slots[i] = None
         req = slot.request
@@ -1768,6 +1859,7 @@ class SlotEngine:
         free the slot. The SessionState rides out on the DecodeResult so
         the server can persist it BEFORE releasing the result — a client
         must never see tokens a crash could unremember."""
+        self._flush_staged()
         slot = self._slots[i]
         token, state, t, emit, done = jax.device_get(
             _extract_carry(self._carry, jnp.int32(i))

@@ -295,8 +295,10 @@ LOCKS: Dict[str, LockDecl] = {
             guards=(
                 GuardedField(
                     _BATCHING, "SlotEngine",
-                    ("_slots", "_carry", "_rngs", "_plen", "_pfold"),
-                    note="slot table + the O(1) decode carry: every "
+                    ("_slots", "_carry", "_rngs", "_plen", "_pfold",
+                     "_staged"),
+                    note="slot table + the O(1) decode carry + the "
+                    "admissions not yet staged into it: every "
                     "mutation happens inside a @_serialized entry point "
                     "or a helper it calls",
                 ),

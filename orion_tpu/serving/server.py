@@ -130,6 +130,11 @@ _KV_ROW_KEYS = (
     "kv_rows_live", "kv_rows_reserved", "kv_rows_read", "slot_steps_emitting",
     "state_row_writes",
 )
+# the engine's staging dispatches (``SlotEngine.staging_dispatches``) since
+# the last boundary: admit_dispatches / chunks is device calls of admission
+# a boundary, at most one while no boundary admits more than
+# ``batching.STAGE_ROWS`` prompts
+_ADMIT_KEYS = ("admit_dispatches",)
 # the block-sparse layers' cache blocks at each boundary, per emitting slot
 # and sparse layer (``SlotEngine.kv_blocks``): blocks the slot holds live /
 # blocks its decode attention lists; and the emitting slots past / under
@@ -447,7 +452,8 @@ class Server:
         # chunk boundaries — no device syncs, no new compiles (lint rule
         # obs-device-sync + the cache-stat asserts in tests/test_obs.py)
         self.metrics = MetricsRegistry(clock=clock, lock=self._stats_lock)
-        for key in _STAT_KEYS + _SLOT_CLASS_KEYS + _KV_ROW_KEYS + _KV_BLOCK_KEYS:
+        for key in (_STAT_KEYS + _SLOT_CLASS_KEYS + _KV_ROW_KEYS
+                    + _KV_BLOCK_KEYS + _ADMIT_KEYS):
             self.metrics.counter(key)  # the legacy stats dict's cells
         # what jax built while this server lived (obs/trace.py
         # ``compile_event``), by stage; the open ``setup.first_launch``
@@ -528,6 +534,8 @@ class Server:
             )
             built.note(donate_carry=self.engine.donate_carry,
                        **self.engine.held_bytes)
+        # the engine's staging_dispatches as of the last boundary
+        self._staged_seen = 0
         # self-speculation telemetry (ISSUE 13): totals for the SLO
         # engine's rate views plus a per-turn acceptance-rate histogram
         # — when speculation stops paying, the acceptance collapse is
@@ -1754,6 +1762,9 @@ class Server:
                 break
             self._admit(pending, wd)
             n += 1
+        # ONE staging dispatch for everything admitted since the last
+        # boundary (this loop's and an idle wake-up's), inside serve.admit
+        self.engine.flush_admissions()
         return n
 
     def _admit(self, pending: Pending, wd=None) -> None:
@@ -2119,6 +2130,9 @@ class Server:
                                    boundary=self._boundary)
             with self._stats_lock:
                 self._bump("chunks")
+                staged = self.engine.staging_dispatches
+                self._bump("admit_dispatches", staged - self._staged_seen)
+                self._staged_seen = staged
                 self._bump("slot_steps_active", occupied)
                 self._bump("slot_steps_total", self.engine.slots)
                 self._bump("slot_steps_prefilling", prefilling)

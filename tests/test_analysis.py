@@ -917,7 +917,7 @@ def admit(engine, prompt):
     state = engine.prefill(prompt)
     return np.asarray(state)
 
-def _stage_prompt(engine, prompt):
+def _flush_staged(engine, prompt):
     return float(engine.park(prompt))
 """
     found = rule_ids(

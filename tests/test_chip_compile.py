@@ -388,9 +388,9 @@ def test_olmo_hybrid_boundary_programs_hold_the_carry_once(v5e):
     rngs, pbuf = arr((slots, 2), jnp.uint32), arr((slots, width), jnp.int32)
     scalar, sample = arr((), jnp.int32), SampleConfig(temperature=0.0)
     programs = {
-        "stage": batching._stage_prompt_carry.lower(
-            carry, rngs, ints, ints, pbuf, arr((width,), jnp.int32),
-            arr((2,), jnp.uint32), scalar, scalar, scalar),
+        "stage": batching._stage_rows_carry.lower(
+            carry, rngs, ints, ints, pbuf,
+            arr((batching.STAGE_ROWS, batching._ROW_HEAD + width), jnp.int32)),
         "piece": gen._prefill_piece_donated_jit.lower(
             model, params, carry, rngs, pbuf, ints, ints, scalar, piece, sample),
         "scan": gen._decode_scan_donated_jit.lower(

@@ -256,6 +256,7 @@ def _serve_mixed(eng):
             i = 4 - len(plan)
             eng.admit(DecodeRequest(prompt=_prompt(i, ln), max_new_tokens=new,
                                     sample=GREEDY, seed=900 + i), tag=i)
+        eng.flush_admissions()  # `before` reads the staged (zeroed) rows
         kinds.add("unified" if eng.prefilling_count else "pure")
         free = [i for i, s in enumerate(eng._slots) if s is None]
         before = [

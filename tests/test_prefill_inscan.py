@@ -39,6 +39,7 @@ from orion_tpu.generate import (
     _prefill_extend_row,
     _prefill_selection,
     _sample_rows,
+    bucket_for,
     generate,
     prefill_overdue_after,
     prefill_piece_cap,
@@ -764,3 +765,339 @@ def test_one_waiting_slot_leaves_every_carry_leaf_as_the_one_piece_program(
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     moved = int(new[0][2][1]) - int(eng._carry[2][1])
     assert moved == (8 if waiting else 0)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 40: one staging dispatch a boundary — admissions wait as host rows
+# and ONE donated K-row program writes them
+# ---------------------------------------------------------------------------
+
+K = batching.STAGE_ROWS
+# 0, 1, the int32 edge and past it, the uint32 edge and past it, negative,
+# and both ends of what ``jax.random.PRNGKey`` takes (a C long)
+SEEDS = (0, 1, 2**31 - 1, 2**31, 2**32 - 1, 2**32 + 5, -1, -(2**63), 2**63 - 1)
+
+
+def _device_state(eng):
+    """Every leaf the staging program writes, on the host."""
+    return [np.asarray(x) for x in jax.tree.leaves(
+        (eng._carry, eng._rngs, eng._plen, eng._pfold, eng._pbuf))]
+
+
+def _staged_by_hand(state, i, prompt, seed, fold):
+    """One admission as the one-row staging of before wrote it, in eager
+    jnp: the row padded by ``jnp.pad``, the key from ``PRNGKey``."""
+    (token, states, t, emit, done), rngs, plen, pfold, pbuf = state
+    width = max(pbuf.shape[1], bucket_for(prompt.shape[1], BUCKETS))
+    pbuf = jnp.pad(pbuf, ((0, 0), (0, width - pbuf.shape[1])))
+    row = jnp.pad(prompt, ((0, 0), (0, width - prompt.shape[1])))[0]
+    states = jax.tree.map(lambda x: x.at[i].set(0), states)
+    carry = (token.at[i].set(0), states, t.at[i].set(0), emit.at[i].set(fold),
+             done.at[i].set(False))
+    return (carry, rngs.at[i].set(jax.random.PRNGKey(seed)),
+            plen.at[i].set(prompt.shape[1]), pfold.at[i].set(fold),
+            pbuf.at[i].set(row))
+
+
+def _dirty(eng, n):
+    """Serve ``n`` requests to the end, so every row a later admission
+    zeroes holds something and the staging buffer exists (8 wide)."""
+    for i in range(n):
+        eng.admit(DecodeRequest(prompt=_prompt(600 + i, 3 + i % 5),
+                                max_new_tokens=5, sample=SAMPLED, seed=i),
+                  tag=("warm", i))
+    _drain(eng)
+
+
+@pytest.mark.parametrize("lens,folds,donate", [
+    ((5,), (0,), False),
+    (tuple(3 + i % 6 for i in range(K)), (0,) * K, False),
+    (tuple(2 + i % 7 for i in range(K + 3)), (0,) * (K + 3), False),
+    ((5, 12, 30, 7), (0, 0, 0, 0), False),
+    ((6, 13, 4), (0, 7, 2), False),
+    ((5, 9, 20), (0, 0, 0), True),
+], ids=["one-row", "K-rows", "more-than-K", "pbuf-grows-inside", "folds",
+        "donate-carry"])
+def test_one_flush_writes_what_single_row_stagings_wrote(mp, lens, folds,
+                                                         donate):
+    """N pending admissions flushed at once leave the carry, the rng keys,
+    ``plen``, ``pfold`` and the staging buffer BITWISE what N one-row
+    stagings left, in ceil(N / K) dispatches; and the slots then serve the
+    solo tokens."""
+    model, params = mp
+    n = len(lens)
+    eng = _engine(mp, "inscan", slots=max(n, 2), chunk=4)
+    eng.donate_carry = donate
+    _dirty(eng, n)
+    want = (eng._carry, eng._rngs, eng._plen, eng._pfold, eng._pbuf)
+    before = eng.staging_dispatches
+    prompts = [_prompt(700 + i, ln) for i, ln in enumerate(lens)]
+    for i, (prompt, fold) in enumerate(zip(prompts, folds)):
+        seed = SEEDS[i % len(SEEDS)]
+        slot = eng.admit(DecodeRequest(prompt=prompt, max_new_tokens=6,
+                                       sample=SAMPLED, seed=seed), tag=i,
+                         sample_index=fold)
+        want = _staged_by_hand(want, slot, prompt, seed, fold)
+    assert eng.staging_dispatches == before  # nothing staged yet
+    want = [np.asarray(x) for x in jax.tree.leaves(want)]
+    eng.flush_admissions()
+    assert eng.staging_dispatches - before == -(-n // K)
+    got = _device_state(eng)
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(a, b)
+    done = _drain(eng)
+    for i, (prompt, fold) in enumerate(zip(prompts, folds)):
+        assert done[i].status == "ok"
+        if fold == 0:
+            ref = generate(model, params, prompt, 6, SAMPLED,
+                           rng=jax.random.PRNGKey(SEEDS[i % len(SEEDS)]))
+            np.testing.assert_array_equal(done[i].tokens, np.asarray(ref))
+
+
+@pytest.mark.parametrize("seed", SEEDS + (np.int64(7), np.uint32(2**32 - 1),
+                                          True))
+def test_host_key_is_prngkey_bitwise(seed):
+    key = batching._seed_key(seed)
+    ref = np.asarray(jax.random.PRNGKey(seed))
+    assert key.dtype == ref.dtype and key.shape == ref.shape
+    np.testing.assert_array_equal(key, ref)
+
+
+@pytest.mark.parametrize("seed", [2**63, -(2**63) - 1, 1.5])
+def test_host_key_refuses_what_prngkey_refuses(seed):
+    with pytest.raises((OverflowError, TypeError)) as ours:
+        batching._seed_key(seed)
+    with pytest.raises(ours.type):
+        jax.random.PRNGKey(seed)
+
+
+def _counting_stage(monkeypatch):
+    """Count the staging program's calls and the valid rows of each."""
+    calls = []
+    real = batching._stage_rows_carry
+
+    def counted(carry, rngs, plen, pfold, pbuf, rows):
+        calls.append(int((rows[:, 0] >= 0).sum()))
+        return real(carry, rngs, plen, pfold, pbuf, rows)
+
+    monkeypatch.setattr(batching, "_stage_rows_carry", counted)
+    return calls
+
+
+def test_a_boundary_makes_one_staging_dispatch(mp, monkeypatch):
+    """However many prompts a boundary admits (up to K), ``step`` stages
+    them with ONE call; a boundary that admits nothing makes none."""
+    calls = _counting_stage(monkeypatch)
+    eng = _engine(mp, "inscan", slots=6, chunk=4)
+    for i, ln in enumerate((5, 11, 3)):
+        eng.admit(DecodeRequest(prompt=_prompt(800 + i, ln), max_new_tokens=9,
+                                sample=GREEDY, seed=i), tag=i)
+    assert calls == []
+    eng.step()
+    assert calls == [3] and eng.staging_dispatches == 1
+    eng.step()  # nothing admitted
+    assert calls == [3]
+    for i, ln in enumerate((7, 2)):
+        eng.admit(DecodeRequest(prompt=_prompt(810 + i, ln), max_new_tokens=5,
+                                sample=GREEDY, seed=i), tag=10 + i)
+    eng.step()
+    assert calls == [3, 2] and eng.staging_dispatches == 2
+    assert all(r.status == "ok" for r in _drain(eng).values())
+
+
+def test_server_counts_one_dispatch_a_boundary_and_isolates_refusals(
+        mp, monkeypatch):
+    """``admit_dispatches`` is the boundaries that admitted; an over-bucket
+    prompt and a ``SampleConfig`` mismatch among the admissions are their
+    own error results while the others are staged and served."""
+    model, params = mp
+    calls = _counting_stage(monkeypatch)
+    srv = Server(model, params, ServeConfig(
+        chunk=4, slots=4, max_inflight=16, prefill_buckets="8,16,32",
+        prefill_chunk=8))
+    good = [(i, _prompt(820 + i, 3 + 3 * i)) for i in range(7)]
+    pend = {}
+    for i, prompt in good[:2]:
+        pend[i] = srv.submit(DecodeRequest(prompt=prompt, max_new_tokens=6,
+                                           sample=GREEDY, seed=i))
+    too_long = srv.submit(DecodeRequest(prompt=_prompt(830, 40),
+                                        max_new_tokens=6, sample=GREEDY))
+    other = srv.submit(DecodeRequest(prompt=_prompt(831, 5), max_new_tokens=6,
+                                     sample=SAMPLED))
+    for i, prompt in good[2:]:
+        pend[i] = srv.submit(DecodeRequest(prompt=prompt, max_new_tokens=6,
+                                           sample=GREEDY, seed=i))
+    admitting = []
+    real = srv._admit_from_queue
+
+    def watched(wd=None):
+        resident = srv.engine.active_count
+        n = real(wd)
+        admitting.append(srv.engine.active_count - resident)
+        return n
+
+    monkeypatch.setattr(srv, "_admit_from_queue", watched)
+    assert srv.serve(drain_when_idle=True) == 0
+    for failed, kind in ((too_long, "bucket"), (other, "SampleConfig")):
+        assert failed.result is None and kind in str(failed.error)
+    for i, prompt in good:
+        ref = generate(model, params, prompt, 6, GREEDY,
+                       rng=jax.random.PRNGKey(i))
+        assert pend[i].result.status == "ok"
+        np.testing.assert_array_equal(pend[i].result.tokens, np.asarray(ref))
+    flat = srv.metrics.counters_flat()
+    boundaries_that_admitted = sum(n > 0 for n in admitting)
+    assert admitting[0] == 4 and boundaries_that_admitted >= 2
+    assert flat["admit_dispatches"] == boundaries_that_admitted == len(calls)
+    assert sum(calls) == len(good)
+    assert flat["admit_dispatches"] < flat["chunks"]
+    srv.close()
+
+
+def _both_orders(mp, act, **kw):
+    """Run ``act(engine, admit)`` on two engines: one whose admissions wait
+    for the flush, one that stages each admission at once (the order of
+    device writes before ISSUE 40). Returns what ``act`` returned and the
+    device state of each."""
+    out = []
+    for at_once in (False, True):
+        eng = _engine(mp, "inscan", slots=4, chunk=4, **kw)
+
+        def admit(*a, eng=eng, at_once=at_once, **k):
+            slot = eng.admit(*a, **k)
+            if at_once:
+                eng.flush_admissions()
+            return slot
+
+        out.append((act(eng, admit), _device_state(eng), eng))
+    return out
+
+
+def _same_device_state(runs):
+    (_, lazy, _), (_, eager, _) = runs
+    assert len(lazy) == len(eager)
+    for a, b in zip(lazy, eager):
+        np.testing.assert_array_equal(a, b)
+
+
+def _request(i, ln, new=6, **kw):
+    return DecodeRequest(prompt=_prompt(900 + i, ln), max_new_tokens=new,
+                         sample=SAMPLED, seed=40 + i, **kw)
+
+
+def _suspended_session(mp):
+    eng = _engine(mp, "inscan", slots=2, chunk=4)
+    eng.admit(_request(0, 9, new=8), tag="s", session_id="conv")
+    return _drain(eng)["s"].session
+
+
+def test_resume_sees_the_rows_still_pending(mp):
+    sess = _suspended_session(mp)
+    assert sess is not None
+
+    def act(eng, admit):
+        admit(_request(1, 12), tag="a")
+        admit(_request(2, 5), tag="b")
+        eng.resume(sess, DecodeRequest(
+            prompt=np.zeros((1, 0), np.int32), max_new_tokens=12,
+            sample=SAMPLED, seed=40, session_id="conv"), tag="s")
+        assert not eng._staged
+        return None
+
+    runs = _both_orders(mp, act)
+    _same_device_state(runs)
+    (_, _, lazy), (_, _, eager) = runs
+    a, b = _drain(lazy), _drain(eager)
+    assert sorted(a) == sorted(b) == ["a", "b", "s"]
+    for tag in a:
+        np.testing.assert_array_equal(a[tag].tokens, b[tag].tokens)
+
+
+def test_prefix_hit_sees_the_rows_still_pending(mp, tmp_path):
+    from orion_tpu.serving.prefix_store import PrefixStore
+
+    store = PrefixStore(str(tmp_path), params_id="inscan-test", align=8)
+    shared = np.asarray(_prompt(950, 16))
+    first = _engine(mp, "inscan", slots=2, chunk=4, prefix_store=store)
+    miss = np.concatenate([shared, np.asarray(_prompt(952, 5))], axis=1)
+    first.admit(DecodeRequest(prompt=miss, max_new_tokens=4, sample=SAMPLED,
+                              seed=1, prefix_len=16), tag=0)
+    assert first.publish_pending_prefixes() == 1
+    hit = np.concatenate([shared, np.asarray(_prompt(951, 7))], axis=1)
+
+    def act(eng, admit):
+        admit(_request(3, 6), tag="a")
+        before = eng.staging_dispatches
+        admit(DecodeRequest(prompt=hit, max_new_tokens=6, sample=SAMPLED,
+                            seed=9), tag="hit")
+        # the pending row went first, then the hit's own dispatch
+        assert not eng._staged and eng.staging_dispatches - before >= 1
+        return eng._slots[1].prompt_remaining
+
+    runs = _both_orders(mp, act, prefix_store=store)
+    assert runs[0][0] == runs[1][0] == 7  # the uncached suffix only
+    _same_device_state(runs)
+    model, params = mp
+    ref = generate(model, params, jnp.asarray(hit), 6, SAMPLED,
+                   rng=jax.random.PRNGKey(9))
+    np.testing.assert_array_equal(_drain(runs[0][2])["hit"].tokens,
+                                  np.asarray(ref))
+
+
+@pytest.mark.parametrize("how", ["suspend_sessions", "drain_evict_all"])
+def test_suspension_and_forced_eviction_see_the_rows_still_pending(mp, how):
+    def act(eng, admit):
+        admit(_request(4, 5, new=16), tag="s", session_id="conv")
+        eng.step()
+        eng.step()  # the session's slot is decoding
+        admit(_request(5, 11), tag="a")
+        admit(_request(6, 4), tag="b")
+        out = dict(getattr(eng, how)())
+        assert not eng._staged
+        return out
+
+    runs = _both_orders(mp, act)
+    _same_device_state(runs)
+    lazy, eager = runs[0][0], runs[1][0]
+    assert sorted(lazy) == sorted(eager)
+    assert set(lazy) == ({"s"} if how == "suspend_sessions" else {"s", "a", "b"})
+    for tag in lazy:
+        np.testing.assert_array_equal(lazy[tag].tokens, eager[tag].tokens)
+    if how == "suspend_sessions":
+        a, b = lazy["s"].session, eager["s"].session
+        for x, y in zip(jax.tree.leaves((a.token, a.state, a.t, a.emit)),
+                        jax.tree.leaves((b.token, b.state, b.t, b.emit))):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_a_never_seen_prompt_length_builds_no_program(mp):
+    """Padding is numpy's: once a staged width is warm, a prompt of a new
+    length inside it traces, lowers and compiles nothing."""
+    model, params = mp
+    srv = Server(model, params, ServeConfig(
+        chunk=4, slots=2, max_inflight=8, prefill_buckets="8,16,32",
+        prefill_chunk=8))
+
+    # made first: a server hears every compile of its process, this
+    # test's own ``randint`` of a new shape among them
+    prompts = [np.asarray(_prompt(970 + i, ln))
+               for i, ln in enumerate((9, 10, 11, 13, 16))]
+
+    def serve(i):
+        p = srv.submit(DecodeRequest(prompt=prompts[i], max_new_tokens=5,
+                                     sample=GREEDY, seed=i))
+        assert srv.serve(drain_when_idle=True) == 0
+        assert p.result.status == "ok"
+
+    serve(0)  # the 16-wide buffer, both boundary programs
+    before = srv.metrics.counters_flat()
+    for i in range(1, len(prompts)):
+        serve(i)
+    after = srv.metrics.counters_flat()
+    for key in ("programs_traced", "programs_compiled",
+                "programs_cache_loaded"):
+        assert after[key] == before[key], key
+    assert after["admit_dispatches"] - before["admit_dispatches"] == 4
+    srv.close()
