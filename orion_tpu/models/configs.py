@@ -105,6 +105,32 @@ class ModelConfig:
     embed_scale: float = 1.0
     residual_scale: float = 1.0
     logit_scale: float = 1.0
+    # std of the token embedding's normal initialiser; None: flax's own
+    # (d_model^-1/2). A TIED head under random weights scores the input
+    # token e . e ~ |e|^2 above every other: at flax's std a served model
+    # repeats its prompt's last token whatever its layers compute
+    embed_init_std: Optional[float] = None
+    # "float32": projections, the embedding, the head and the state-space
+    # conv DRAW their initial values in float32 and round them to
+    # param_dtype; None: drawn in param_dtype itself. jax draws bfloat16
+    # normals from 7 random bits: their mean is -0.018 sigma, so a
+    # 2,048-wide projection maps the all-ones direction to an offset of
+    # -0.8 of its output's size, and a deep stack of such weights answers
+    # every prompt with one token (PERF.md section 6, PR 41)
+    param_init_dtype: Optional[str] = None
+    # -- "ssm" layers (models/mixers/ssm.py): a state-space layer with a
+    # scalar decay per head that depends on the token, S_t = exp(dt_t A_h)
+    # S_{t-1} + dt_t x_t B_t^T, ssm_heads heads of ssm_head_dim x ssm_state;
+    # B_t and C_t are shared by the ssm_heads / ssm_groups heads of a group;
+    # x, B and C pass a causal depthwise conv (ssm_conv_width taps, a bias)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv_width: int = 4
+    # softmax layers scale q . k by this instead of head_dim^-1/2
+    attn_scale: Optional[float] = None
+    norm_eps: float = 1e-6  # of every "rmsnorm" the model builds
     dropout: float = 0.0
     # numerics / execution
     dtype: str = "bfloat16"  # activation/compute dtype
@@ -202,7 +228,7 @@ class ModelConfig:
 # one mixer class each: models/mixers/__init__.py::MIXERS
 LAYER_TYPES = (
     "linear", "softmax", "swa", "gated_delta", "gated_softmax",
-    "decay_linear", "block_sparse",
+    "decay_linear", "block_sparse", "ssm",
 )
 
 
@@ -233,6 +259,7 @@ F32_MATMUL_SCOPES = (
     # (not the mixer of the same file name, models/mixers/gated_delta.py)
     "ops/gated_delta.py",
     "pallas/gated_delta.py",
+    "ops/ssm.py",                   # the state-space layers' fp32 state
 )
 
 
@@ -449,6 +476,53 @@ MINICPM_SALA = ModelConfig(
     param_dtype="bfloat16",
 )
 
+def ssm_full_pattern(n_layers: int, period: int = 10, at: int = 5) -> Tuple[str, ...]:
+    """ssm everywhere but layers ``at``, ``at + period``, ..., which are
+    full softmax attention."""
+    return tuple(
+        "softmax" if i % period == at else "ssm" for i in range(n_layers)
+    )
+
+
+GRANITE_4_0_H_MICRO = ModelConfig(
+    # granite-4.0-h-micro at its published widths and depth (benchmark/
+    # configs/granite_4_0_h_micro.json states the source and what is
+    # assumed): 36 state-space layers (64 heads of 64 x 128, one group, a
+    # biased conv of 4 taps, the gate before the norm) and 4 full-attention
+    # layers at 5, 15, 25, 35 (32 query heads over 8 KV heads x 64, scale
+    # 1 / 64, no rotary); no position term; the embedding x 12, residual
+    # branches x 0.22, logits / 8; dense SwiGLU; tied head; bfloat16.
+    name="granite_4_0_h_micro",
+    vocab_size=100352,
+    d_model=2048,
+    n_layers=40,
+    layer_types=ssm_full_pattern(40),
+    n_heads=32,
+    n_kv_heads=8,
+    head_dim=64,
+    attn_scale=0.015625,
+    rotary=False,
+    ssm_heads=64,
+    ssm_head_dim=64,
+    ssm_state=128,
+    ssm_groups=1,
+    ssm_conv_width=4,
+    embed_scale=12.0,
+    residual_scale=0.22,
+    logit_scale=1 / 8,
+    embed_init_std=0.005,
+    param_init_dtype="float32",
+    norm="rmsnorm",
+    norm_eps=1e-5,
+    pos_embed="none",
+    tie_embeddings=True,
+    mlp="swiglu",
+    mlp_hidden=8192,
+    max_seq_len=2048,
+    dtype="bfloat16",
+    param_dtype="bfloat16",
+)
+
 LRA_LISTOPS_LINEAR = ModelConfig(
     name="lra_listops_linear",
     vocab_size=32,  # digits + operators + specials
@@ -497,6 +571,7 @@ CONFIGS = {
         QWEN3_NEXT_80B,
         OLMO_HYBRID_7B,
         MINICPM_SALA,
+        GRANITE_4_0_H_MICRO,
         LRA_LISTOPS_LINEAR,
         LRA_LISTOPS_SOFTMAX,
         LRA_TEXT_LINEAR,
@@ -514,5 +589,5 @@ def get_config(name: str, **overrides) -> ModelConfig:
 
 __all__ = [
     "ModelConfig", "CONFIGS", "get_config", "hybrid_pattern",
-    "gated_pattern", "delta_full_pattern", "decay_sparse_pattern", "F32_MATMUL_SCOPES", "LAYER_TYPES",
+    "gated_pattern", "delta_full_pattern", "decay_sparse_pattern", "ssm_full_pattern", "F32_MATMUL_SCOPES", "LAYER_TYPES",
 ]

@@ -27,6 +27,16 @@ def _dtype(name: str):
     return {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[name]
 
 
+def drawn_in(cfg: ModelConfig, init):
+    """``init`` drawing in ``cfg.param_init_dtype`` where set, its values
+    rounded to the dtype the parameter is held in; ``init`` itself
+    otherwise."""
+    if cfg.param_init_dtype is None:
+        return init
+    wide = _dtype(cfg.param_init_dtype)
+    return lambda key, shape, dtype=wide: init(key, shape, wide).astype(dtype)
+
+
 def _dense_factory(cfg: ModelConfig, quant: str = "", mesh=None):
     """``(name, features) -> module``: the bias-free projection every layer
     uses, or its weight-streamed form in the decode modes. "int8": every
@@ -38,8 +48,12 @@ def _dense_factory(cfg: ModelConfig, quant: str = "", mesh=None):
     a multi-device host must not silently lose the kernel)."""
     dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
     if not quant:
+        init = (
+            {} if cfg.param_init_dtype is None
+            else {"kernel_init": drawn_in(cfg, nn.linear.default_kernel_init)}
+        )
         return lambda n, feats: nn.Dense(
-            feats, use_bias=False, dtype=dt, param_dtype=pdt, name=n
+            feats, use_bias=False, dtype=dt, param_dtype=pdt, name=n, **init
         )
     from orion_tpu.quant import Int4Dense, Int8Dense
 
@@ -181,9 +195,9 @@ class Mixer(nn.Module):
         raise NotImplementedError(
             f"layer type {self.layer_type!r} does not build this serving "
             "entry point: gated_softmax has a training forward only; "
-            "gated_delta, decay_linear and block_sparse serve (prefill, its "
-            "pieces, the decode step) but have no speculative verify_extend "
-            "/ advance_verified"
+            "gated_delta, decay_linear, block_sparse and ssm serve (prefill, "
+            "its pieces, the decode step) but have no speculative "
+            "verify_extend / advance_verified"
         )
 
     def prefill(
@@ -336,6 +350,7 @@ from orion_tpu.models.mixers.gated_softmax import (  # noqa: E402
 )
 from orion_tpu.models.mixers.linear import LinearAttention  # noqa: E402
 from orion_tpu.models.mixers.softmax import SoftmaxAttention  # noqa: E402
+from orion_tpu.models.mixers.ssm import StateSpace  # noqa: E402
 
 MIXERS = {
     "linear": LinearAttention,
@@ -345,11 +360,12 @@ MIXERS = {
     "gated_softmax": GatedSoftmaxAttention,
     "decay_linear": DecayLinearAttention,
     "block_sparse": BlockSparseAttention,
+    "ssm": StateSpace,
 }
 assert set(MIXERS) == set(LAYER_TYPES), (sorted(MIXERS), LAYER_TYPES)
 
 __all__ = [
     "MIXERS", "Mixer", "LinearAttention", "SoftmaxAttention", "GatedDeltaNet",
     "GatedSoftmaxAttention", "DecayLinearAttention", "BlockSparseAttention",
-    "ZeroCentredRMSNorm", "kernel_bh",
+    "StateSpace", "ZeroCentredRMSNorm", "kernel_bh",
 ]

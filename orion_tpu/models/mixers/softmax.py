@@ -1,8 +1,13 @@
 """``softmax`` and ``swa``: causal softmax attention over the whole prefix
 or over a sliding window of ``cfg.window`` tokens; q and k are rotated by
 position unless ``cfg.rotary`` is off, and RMS-normalised over the whole
-projection first under ``cfg.qk_norm`` (``Mixer._heads``).
-The decode state is a KV cache ``{"k", "v"}`` of [B, H, cap, Dh] each:
+projection first under ``cfg.qk_norm`` (``Mixer._heads``). Either
+layer takes ``cfg.n_kv_heads`` KV heads where set, ``n_heads / n_kv_heads``
+query heads to each (head ``h`` reads KV head ``h // group``), and scales
+its scores by ``cfg.attn_scale`` where set instead of ``Dh^-1/2``: q is
+multiplied by ``attn_scale * Dh^1/2`` once, before every form below (a power
+of two at the served widths: exact).
+The decode state is a KV cache ``{"k", "v"}`` of [B, KV, cap, Dh] each:
 ``cap`` is ``max_seq_len`` rows written at their position, or, for the
 window, a ring of ``window`` rows written at position % window. The
 slot-multiplexed decode step, given a row list, writes one cache row per
@@ -41,6 +46,10 @@ def _window(cfg: ModelConfig, layer_type: str) -> Optional[int]:
     return cfg.window if layer_type == "swa" else None
 
 
+def _kv_heads(cfg: ModelConfig) -> int:
+    return cfg.n_kv_heads or cfg.n_heads
+
+
 class SoftmaxAttention(Mixer):
     layer_type: str = "softmax"
 
@@ -48,13 +57,26 @@ class SoftmaxAttention(Mixer):
 
     def setup(self):
         cfg = self.cfg
-        self._setup_qkvo()
+        self._setup_qkvo(kv_heads=cfg.n_kv_heads)
         # rotary angle table, a trace-time constant
         self.freqs = rotary_freqs(cfg.resolved_head_dim, cfg.max_seq_len)
 
     @property
     def window(self) -> Optional[int]:
         return _window(self.cfg, self.layer_type)
+
+    def _heads(self, x: Array) -> Tuple[Array, Array, Array]:
+        q, k, v = super()._heads(x)
+        a = self.cfg.attn_scale
+        if a is not None:  # every form below scales by Dh^-1/2
+            q = q * jnp.asarray(a * q.shape[-1] ** 0.5, q.dtype)
+        return q, k, v
+
+    def _per_query_head(self, kv: Array) -> Array:
+        """``[B, KV, T, Dh]`` -> ``[B, H, T, Dh]``: each KV head repeated for
+        its group (the parallel forms; a cache is never repeated)."""
+        group = self.cfg.n_heads // kv.shape[1]
+        return kv if group == 1 else jnp.repeat(kv, group, axis=1)
 
     def _rot(self, x: Array, ang: Array) -> Array:
         return apply_rotary(x, ang) if self.cfg.rotary else x
@@ -66,7 +88,7 @@ class SoftmaxAttention(Mixer):
     def decode_state(
         cfg: ModelConfig, layer_type: str, batch: int, dtype: Any
     ) -> State:
-        h, dh = cfg.n_heads, cfg.resolved_head_dim
+        h, dh = _kv_heads(cfg), cfg.resolved_head_dim
         cap = _window(cfg, layer_type) or cfg.max_seq_len
         return {
             "k": jnp.zeros((batch, h, cap, dh), dtype),
@@ -113,6 +135,7 @@ class SoftmaxAttention(Mixer):
     def __call__(self, x: Array, mask: Optional[Array] = None) -> Array:
         cfg = self.cfg
         q, k, v = self._heads(x)
+        k, v = self._per_query_head(k), self._per_query_head(v)
         t = x.shape[-2]
         sp = self._sp_active()
         if sp:
@@ -219,7 +242,7 @@ class SoftmaxAttention(Mixer):
                     backend=cfg.backend,
                     block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
                 ),
-                qr, kr, v,
+                qr, self._per_query_head(kr), self._per_query_head(v),
             )
             if length is not None:
                 state = _swa_cache_from_prefill_dynamic(
@@ -233,7 +256,7 @@ class SoftmaxAttention(Mixer):
                     a, b, c, causal=True, backend=cfg.backend,
                     block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
                 ),
-                qr, kr, v,
+                qr, self._per_query_head(kr), self._per_query_head(v),
             )
             smax = cfg.max_seq_len
             pad = ((0, 0), (0, 0), (0, smax - t), (0, 0))
@@ -279,9 +302,19 @@ class SoftmaxAttention(Mixer):
             vc = _window_write(state["v"], v, offset, real)
             row = jnp.arange(p)[:, None] + offset
             col = jnp.arange(kc.shape[-2])[None, :]
-            out = softmax_attention_xla(
-                qr, kc, vc, causal=False, mask=row >= col
-            )
+            b, h, _, d = qr.shape
+            group = h // kc.shape[1]
+            if group == 1:  # traced as it was: no reshape, no tile
+                out = softmax_attention_xla(
+                    qr, kc, vc, causal=False, mask=row >= col
+                )
+            else:
+                # a group's queries are rows of ONE product against its KV
+                # head's cache, which is never repeated
+                out = softmax_attention_xla(
+                    qr.reshape(b, h // group, group * p, d), kc, vc,
+                    causal=False, mask=jnp.tile(row >= col, (group, 1)),
+                ).reshape(b, h, p, d)
             new_state = {"k": kc, "v": vc}
         return self._merge(out, single=False), new_state
 
@@ -310,7 +343,10 @@ class SoftmaxAttention(Mixer):
             [pos_prev, offset + jnp.arange(p)]
         )[None, :]
         m = (row >= colpos) & (row - colpos < w) & (colpos >= 0)
-        out = softmax_attention_xla(qr, kctx, vctx, causal=False, mask=m)
+        out = softmax_attention_xla(
+            qr, self._per_query_head(kctx), self._per_query_head(vctx),
+            causal=False, mask=m,
+        )
         # rebuild the ring as the last W positions before offset+length:
         # rows from this piece where they cover, the previous ring where
         # they don't; slots (pos % W) of W consecutive positions are a

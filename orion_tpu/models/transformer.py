@@ -33,6 +33,7 @@ from orion_tpu.models.mixers import (
     ZeroCentredRMSNorm,
     _dense_factory,
     _dtype,
+    drawn_in,
 )
 
 Array = jax.Array
@@ -41,7 +42,9 @@ State = Dict[str, Array]
 
 def _norm(cfg: ModelConfig, name: str):
     if cfg.norm == "rmsnorm":
-        return nn.RMSNorm(dtype=_dtype(cfg.dtype), name=name)
+        return nn.RMSNorm(
+            epsilon=cfg.norm_eps, dtype=_dtype(cfg.dtype), name=name
+        )
     if cfg.norm == "rmsnorm_zero":
         return ZeroCentredRMSNorm(
             _dtype(cfg.dtype), _dtype(cfg.param_dtype), name=name
@@ -176,7 +179,14 @@ class TransformerLM(nn.Module):
 
             self.embed = Int8Embed(cfg.vocab_size, cfg.d_model)
         else:
-            self.embed = nn.Embed(cfg.vocab_size, cfg.d_model, param_dtype=pdt)
+            init = (
+                {} if cfg.embed_init_std is None
+                else {"embedding_init": drawn_in(
+                    cfg, nn.initializers.normal(cfg.embed_init_std))}
+            )
+            self.embed = nn.Embed(
+                cfg.vocab_size, cfg.d_model, param_dtype=pdt, **init
+            )
         if cfg.pos_embed == "learned":
             self.pos_embed = nn.Embed(
                 cfg.max_seq_len, cfg.d_model, param_dtype=pdt
@@ -213,7 +223,7 @@ class TransformerLM(nn.Module):
             else:
                 self.lm_head_kernel = self.param(
                     "lm_head_kernel",
-                    nn.initializers.lecun_normal(),
+                    drawn_in(cfg, nn.initializers.lecun_normal()),
                     (cfg.d_model, cfg.vocab_size),
                     pdt,
                 )

@@ -6,7 +6,9 @@ backend='xla' dispatch"). "auto" picks Pallas on TPU and the pure-XLA
 chunked scan elsewhere (CPU/GPU and unit tests). The Pallas kernel can also
 run anywhere via interpret mode (used by the parity tests).
 
-Five ops dispatch here: ``gated_delta_rule`` (the gated delta-rule
+Seven ops dispatch here (``ssm_scan`` and ``ssm_state_step``, the
+state-space layers' prompt pass and one-token step, are described at their
+definitions): ``gated_delta_rule`` (the gated delta-rule
 layers' parallel forward, with or without a state carried in and out:
 under Pallas the chunked form as Mosaic kernels, forward and backward,
 ``ops/pallas/gated_delta.py``; the same equations as XLA fusions and a scan
@@ -249,11 +251,54 @@ def gated_delta_step(q, k, v, beta, g, state, rows=None, *, backend: str = "auto
     return step(q, k, v, beta, g, state)
 
 
+def ssm_scan(
+    x, dt, a, bm, cm, *, backend: str = "auto", chunk: Optional[int] = None,
+    initial_state=None, length=None,
+):
+    """The state-space layers' parallel forward over a prompt or a piece of
+    one (``ops/ssm.py``): x ``[B, T, H, P]``, dt ``[B, T, H]``, ``a`` [H], bm,
+    cm ``[B, T, G, N]`` -> (y, S ``[B, H, P, N]`` fp32 after the first
+    ``length`` rows from ``initial_state``). ``eager`` is the token
+    recurrence (no ``length``); every other backend runs the chunked form
+    as XLA fusions and a scan over chunks: no Mosaic kernel is built for it
+    (PERF.md section 7)."""
+    from orion_tpu.ops import ssm
+
+    if resolve(backend) == "eager" and length is None:
+        return ssm.ssm_recurrent(x, dt, a, bm, cm, initial_state)
+    return ssm.ssm_chunked(
+        x, dt, a, bm, cm, chunk=chunk or ssm.DEFAULT_CHUNK,
+        initial_state=initial_state, length=length,
+    )
+
+
+def ssm_state_step(x, dt, a, bm, cm, state, pack, rows=None, *, backend: str = "auto"):
+    """One decode step of the state-space layers' held state ``S [B, H /
+    pack, N, pack P]`` (fp32, ``ops.ssm.pack_state``): x ``[B, H, P]``, dt
+    ``[B, H]``, bm, cm ``[B, G, N]`` -> ``(y [B, H, P], S)``. ``rows`` as
+    for :func:`decode_state_step`: with a row list under a Pallas backend
+    only the listed rows are read, updated and written, in place
+    (``ops/pallas/ssm.py``); otherwise every row steps
+    (``ops/ssm.py::ssm_step_packed``)."""
+    if rows is not None and row_sparse(backend):
+        from orion_tpu.ops.pallas import ssm as pssm
+
+        return pssm.ssm_state_step(
+            x, dt, a, bm, cm, state, pack, rows,
+            interpret=(resolve(backend) == "pallas_interpret"),
+        )
+    from orion_tpu.ops.ssm import ssm_step_packed
+
+    return ssm_step_packed(x, dt, a, bm, cm, state, pack)
+
+
 def cache_attention(
     q, k_cache, v_cache, lengths, rows=None, *, backend: str = "auto", blocks=None
 ):
     """Decode attention of one query a sequence over the first ``lengths``
-    [B] rows of its KV cache: q ``[B, H, Dh]``, caches ``[B, H, cap, Dh]``
+    [B] rows of its KV cache: q ``[B, H, Dh]``, caches ``[B, KV, cap, Dh]``
+    (``H / KV`` query heads to each KV head, head ``h`` reading ``h // (H /
+    KV)``; ``KV = H`` is a cache a query head)
     -> ``(out [B, H, Dh], lse [B, H])`` in fp32, the softmax over those
     rows applied to V and the log-sum-exp of their scaled scores. ``rows``
     as for :func:`decode_state_step`: with a row list under a Pallas
@@ -382,4 +427,6 @@ __all__ = [
     "resolve",
     "resolve_chunk",
     "row_sparse",
+    "ssm_scan",
+    "ssm_state_step",
 ]

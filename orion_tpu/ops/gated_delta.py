@@ -272,12 +272,14 @@ def gated_delta_step(q: Array, k: Array, v: Array, beta: Array, g: Array, s: Arr
 
 
 def causal_short_conv(
-    x: Array, w: Array, activation: bool = True, tail: Optional[Array] = None
+    x: Array, w: Array, activation: bool = True, tail: Optional[Array] = None,
+    bias: Optional[Array] = None,
 ) -> Array:
-    """Depthwise causal convolution over time, no bias: ``y_t = sum_j w[j] *
-    x_{t - (W - 1) + j}`` (``w[W - 1]`` weighs the current token), then
-    SiLU. x ``[..., T, C]``, w ``[W, C]``. What precedes ``x`` is zeros, or
-    ``tail [..., W - 1, C]``: the inputs just before it."""
+    """Depthwise causal convolution over time: ``y_t = sum_j w[j] *
+    x_{t - (W - 1) + j}`` (``w[W - 1]`` weighs the current token), plus
+    ``bias`` [C] where given, then SiLU. x ``[..., T, C]``, w ``[W, C]``.
+    What precedes ``x`` is zeros, or ``tail [..., W - 1, C]``: the inputs
+    just before it."""
     width, t = w.shape[0], x.shape[-2]
     if tail is None:
         xp = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(width - 1, 0), (0, 0)])
@@ -288,6 +290,8 @@ def causal_short_conv(
         jax.lax.slice_in_dim(xp, j, j + t, axis=-2).astype(jnp.float32) * wf[j]
         for j in range(width)
     )
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return (jax.nn.silu(y) if activation else y).astype(x.dtype)
 
 

@@ -116,12 +116,42 @@ def _rows_dot(a: Array, b: Array, dims) -> Array:
     return jnp.sum(out, axis=1, keepdims=True)  # rows 3.. are zero
 
 
+# query rows a KV head of a GROUPED cache: the group's heads, zero-padded
+# to one bf16 tile of sublanes, so that the split's three terms are three
+# whole tiles of one matmul
+_GROUP_ROWS = 16
+
+
+def _group_rows_dot(a: Array, b: Array, dims) -> Array:
+    """:func:`_rows_dot` for ``a`` fp32 [KV, R, X], R query rows (a group's
+    heads) to each KV head's cache block -> fp32 [KV, R, N]."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    dims = (dims, ((0,), (0,)))
+    if b.dtype != bf16:
+        return jax.lax.dot_general(
+            a, b.astype(f32), dims, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=f32,
+        )
+    hi = a.astype(bf16)
+    rest = a - hi.astype(f32)
+    mid = rest.astype(bf16)
+    low = (rest - mid.astype(f32)).astype(bf16)
+    r = a.shape[1]
+    out = jax.lax.dot_general(
+        jnp.concatenate([hi, mid, low], axis=1), b, dims,
+        preferred_element_type=f32,
+    )
+    return out[:, :r] + out[:, r:2 * r] + out[:, 2 * r:]
+
+
 _QK = ((2,), (2,))  # [H, 1, Dh] x [H, bk, Dh] -> [H, 1, bk]
 _PV = ((2,), (1,))  # [H, 1, bk] x [H, bk, Dh] -> [H, 1, Dh]
 
 
-def _kernel(bk, nblk, idx_ref, len_ref, q_ref, k_ref, v_ref, o_in, lse_in,
+def _kernel(dot, bk, nblk, idx_ref, len_ref, q_ref, k_ref, v_ref, o_in, lse_in,
             o_ref, lse_ref, m_scr, l_scr, acc_scr):
+    """``dot``: :func:`_rows_dot` (a query row a head) or
+    :func:`_group_rows_dot` (a group's rows a KV head)."""
     del o_in, lse_in  # aliased onto the outputs: what an unlisted row keeps
     i, j = pl.program_id(0), pl.program_id(1)
     length = len_ref[idx_ref[i]]
@@ -133,7 +163,7 @@ def _kernel(bk, nblk, idx_ref, len_ref, q_ref, k_ref, v_ref, o_in, lse_in,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def block(partial):
-        s = _rows_dot(q_ref[0], k_ref[0], _QK)  # [H, 1, bk]
+        s = dot(q_ref[0], k_ref[0], _QK)  # [H, 1, bk]
         v = v_ref[0]
         if partial:
             at = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
@@ -145,7 +175,7 @@ def _kernel(bk, nblk, idx_ref, len_ref, q_ref, k_ref, v_ref, o_in, lse_in,
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + _rows_dot(p, v, _PV)
+        acc_scr[...] = acc_scr[...] * alpha + dot(p, v, _PV)
         m_scr[...] = m_new
 
     # a block wholly below the length needs no mask; the row's last live
@@ -165,7 +195,8 @@ def cache_attention(
     q: Array, k_cache: Array, v_cache: Array, lengths: Array,
     rows: Tuple[Array, Array], *, interpret: bool = False,
 ) -> Tuple[Array, Array]:
-    """q ``[B, H, Dh]``; caches ``[B, H, cap, Dh]`` (one dtype); lengths
+    """q ``[B, H, Dh]``; caches ``[B, KV, cap, Dh]`` (one dtype; ``H / KV``
+    query heads to each KV head, ``KV = H`` a cache a query head); lengths
     ``[B]`` int32, the live rows of each sequence's cache; rows =
     ``decode_state.live_rows`` of the row mask. Returns (out ``[B, H, Dh]``,
     lse ``[B, H]``), both fp32: for a listed row the softmax of its scaled
@@ -174,8 +205,9 @@ def cache_attention(
     unlisted row, whose cache is never read."""
     idx, count = rows
     b, h, cap, d = k_cache.shape
+    group = q.shape[1] // h
     shapes = (q.shape, v_cache.shape, lengths.shape, idx.shape)
-    if shapes != ((b, h, d), k_cache.shape, (b,), (b,)):
+    if shapes != ((b, group * h, d), k_cache.shape, (b,), (b,)) or group > _GROUP_ROWS:
         raise ValueError(f"operands do not fit K {k_cache.shape}: {shapes}")
     if k_cache.dtype != v_cache.dtype:
         raise ValueError(f"one cache dtype: {k_cache.dtype}/{v_cache.dtype}")
@@ -183,8 +215,12 @@ def cache_attention(
     nblk = cap // bk
     f32 = jnp.float32
     # every block's last two dims are whole dims of its array: a head's
-    # query, output and statistics sit one row a head, [B, H, 1, .]
-    qf = (q.astype(f32) * d ** -0.5)[:, :, None, :]
+    # query, output and statistics sit one row a head, [B, H, 1, .]; a
+    # group's sit _GROUP_ROWS rows a KV head, the rows past the group zero
+    # queries whose results are dropped
+    qf = (q.astype(f32) * d ** -0.5).reshape(b, h, group, d)
+    r = 1 if group == 1 else _GROUP_ROWS
+    qf = jnp.pad(qf, ((0, 0), (0, 0), (0, r - group), (0, 0)))
 
     def row(i, j, idx, lens):
         return (idx[i], 0, 0, 0)
@@ -194,8 +230,8 @@ def cache_attention(
         last = jnp.maximum((lens[r] + bk - 1) // bk - 1, 0)
         return (r, 0, jnp.minimum(j, last), 0)
 
-    vec = pl.BlockSpec((1, h, 1, d), row)
-    one = pl.BlockSpec((1, h, 1, 1), row)
+    vec = pl.BlockSpec((1, h, r, d), row)
+    one = pl.BlockSpec((1, h, r, 1), row)
     blk = pl.BlockSpec((1, h, bk, d), kv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -203,18 +239,20 @@ def cache_attention(
         in_specs=[vec, blk, blk, vec, one],
         out_specs=[vec, one],
         scratch_shapes=[
-            pltpu.VMEM((h, 1, 1), f32),
-            pltpu.VMEM((h, 1, 1), f32),
-            pltpu.VMEM((h, 1, d), f32),
+            pltpu.VMEM((h, r, 1), f32),
+            pltpu.VMEM((h, r, 1), f32),
+            pltpu.VMEM((h, r, d), f32),
         ],
     )
     out, lse = pl.pallas_call(
-        functools.partial(_kernel, bk, nblk),
+        functools.partial(
+            _kernel, _rows_dot if group == 1 else _group_rows_dot, bk, nblk
+        ),
         name="cache_attention",
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, 1, d), f32),
-            jax.ShapeDtypeStruct((b, h, 1, 1), f32),
+            jax.ShapeDtypeStruct((b, h, r, d), f32),
+            jax.ShapeDtypeStruct((b, h, r, 1), f32),
         ],
         # operand numbering counts the two scalar-prefetch lists: the
         # unlisted rows' output and log-sum-exp are operands 5 and 6
@@ -223,9 +261,14 @@ def cache_attention(
         interpret=interpret,
     )(
         idx, lengths.astype(jnp.int32), qf, k_cache, v_cache,
-        jnp.zeros((b, h, 1, d), f32), jnp.full((b, h, 1, 1), _NEG, f32),
+        jnp.zeros((b, h, r, d), f32), jnp.full((b, h, r, 1), _NEG, f32),
     )
-    return out[:, :, 0, :], lse[:, :, 0, 0]
+    if group == 1:
+        return out[:, :, 0, :], lse[:, :, 0, 0]
+    return (
+        out[:, :, :group].reshape(b, h * group, d),
+        lse[:, :, :group, 0].reshape(b, h * group),
+    )
 
 
 # ---------------------------------------------------------------------------

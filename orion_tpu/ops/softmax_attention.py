@@ -138,15 +138,26 @@ def cached_attention(
     buffer, where slot order ≠ time order — softmax is permutation-invariant
     over keys, so ring-buffer rotation needs no unrotation). ``with_lse``:
     (the output in fp32, the log-sum-exp of the valid scores [...]), for a
-    caller that merges this key set with another.
+    caller that merges this key set with another. q ``[..., H, D]`` over
+    caches ``[..., KV, S, D]`` of fewer heads is a GROUPED cache: ``H / KV``
+    query heads to each KV head, head ``h`` reading ``h // (H / KV)``.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    shape = q.shape
+    grouped = k_cache.ndim == q.ndim + 1 and shape[-2] != k_cache.shape[-3]
+    if grouped:
+        kvh = k_cache.shape[-3]
+        q = q.reshape(shape[:-2] + (kvh, shape[-2] // kvh, shape[-1]))
+        k_cache, v_cache = k_cache[..., None, :, :], v_cache[..., None, :, :]
+        valid = valid[..., None, :]
     qf = q.astype(jnp.float32) * scale
     scores = jnp.einsum("...d,...sd->...s", qf, k_cache.astype(jnp.float32))
     scores = jnp.where(valid, scores, _NEG)
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("...s,...sd->...d", p, v_cache.astype(jnp.float32))
+    if grouped:
+        out, scores = out.reshape(shape), scores.reshape(shape[:-1] + scores.shape[-1:])
     if with_lse:
         return out, jax.nn.logsumexp(scores, axis=-1)
     return out.astype(q.dtype)

@@ -582,9 +582,17 @@ class SlotEngine:
             self._carry, params, next(iter(self._carry[0].devices()))
         )
         # what that decision weighed (host metadata of the two trees)
+        kv_bytes = sum(
+            tree_nbytes([st[n] for n in ("k", "v") if n in st])
+            for st in self._carry[1]
+        )
         self.held_bytes = {
             "carry_bytes": tree_nbytes(self._carry),
             "params_bytes": tree_nbytes(params),
+            # the carry by kind: the KV caches' leaves, and every other
+            # leaf of the per-layer states (recurrent states, conv tails)
+            "kv_bytes": kv_bytes,
+            "state_bytes": tree_nbytes(self._carry[1]) - kv_bytes,
         }
         self.state_writes_per_chunk = self._state_writes_per_chunk()
         self._rngs = jnp.tile(

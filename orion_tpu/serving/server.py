@@ -142,6 +142,11 @@ _ADMIT_KEYS = ("admit_dispatches",)
 _KV_BLOCK_KEYS = (
     "kv_blocks_live", "kv_blocks_read", "sparse_steps", "dense_steps",
 )
+# the state-space layers' work at each boundary, summed over ``ssm`` layers
+# (0 for a model without one): the row-steps their decode step ran (the
+# slots that emitted x the scan's steps: a listed row steps at every one) and
+# the real prompt rows their chunked scan consumed
+_SSM_KEYS = ("ssm_row_steps", "ssm_piece_rows")
 
 
 class OverloadError(RuntimeError):
@@ -453,7 +458,7 @@ class Server:
         # obs-device-sync + the cache-stat asserts in tests/test_obs.py)
         self.metrics = MetricsRegistry(clock=clock, lock=self._stats_lock)
         for key in (_STAT_KEYS + _SLOT_CLASS_KEYS + _KV_ROW_KEYS
-                    + _KV_BLOCK_KEYS + _ADMIT_KEYS):
+                    + _KV_BLOCK_KEYS + _ADMIT_KEYS + _SSM_KEYS):
             self.metrics.counter(key)  # the legacy stats dict's cells
         # what jax built while this server lived (obs/trace.py
         # ``compile_event``), by stage; the open ``setup.first_launch``
@@ -2109,10 +2114,11 @@ class Server:
         if self.cfg.profile_dir:
             self._profile_maybe_stop()
         with self._phase("serve.complete", n=len(finished)):
-            prefilling = decoding = emitting = 0
+            prefilling = decoding = emitting = piece_rows = 0
             for entry in self.engine.last_boundary:
                 emitted = entry.get("decode_tokens", 0) > 0
                 emitting += emitted
+                piece_rows += entry.get("prefill_tokens", 0)
                 if entry.get("prefill_tokens", 0) > 0:
                     prefilling += 1
                 elif emitted:
@@ -2142,9 +2148,11 @@ class Server:
                 writes = emitting * self.engine.state_writes_per_chunk
                 for key, rows in zip(_KV_ROW_KEYS, kv_rows + (emitting, writes)):
                     self._bump(key, rows)
-                layers = self.engine.model.cfg.resolved_layer_types.count(
-                    "block_sparse"
-                )
+                kinds = self.engine.model.cfg.resolved_layer_types
+                ssm = kinds.count("ssm")
+                self._bump("ssm_row_steps", emitting * self.engine.chunk * ssm)
+                self._bump("ssm_piece_rows", piece_rows * ssm)
+                layers = kinds.count("block_sparse")
                 live, read, sparse, dense = kv_blocks
                 for key, n in zip(
                     _KV_BLOCK_KEYS, (live * layers, read * layers, sparse, dense)

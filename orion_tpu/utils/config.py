@@ -25,6 +25,8 @@ def _coerce(old: Any, new: Any) -> Any:
         return float(new)
     if isinstance(old, tuple) and isinstance(new, (list, tuple)):
         return tuple(new)
+    if isinstance(old, tuple) and isinstance(new, str):
+        return tuple(new.split(","))  # --set layer_types=ssm,softmax
     return new
 
 

@@ -169,6 +169,13 @@ def _block_attention(q, k, v, lengths, lists, counts, live):
     return block_attention(q, k, v, lengths, lists, counts, live_rows(live), block=64)
 
 
+def _ssm_step(s, x, dt, a, bm, cm, live):
+    from orion_tpu.ops.pallas.decode_state import live_rows
+    from orion_tpu.ops.pallas.ssm import ssm_state_step
+
+    return ssm_state_step(x, dt, a, bm, cm, s, 2, live_rows(live))
+
+
 _QKV = [(BHTD, jnp.bfloat16)] * 3
 # qwen3_next_80b's train point (batch 8, T 8192): its softmax layer's 16
 # heads of 256 after the KV heads are repeated; its held experts' buffer
@@ -218,6 +225,16 @@ _BLOCK_LIST = [((32, 2, 16, 128), jnp.bfloat16),
                *[((32, 2, 16896, 128), jnp.bfloat16)] * 2,
                ((32,), jnp.int32), ((32, 2, 128), jnp.int32),
                ((32, 2), jnp.int32), ((32,), jnp.bool_)]
+# granite_4_0_h_micro served (64 slots x 2,048): the state-space step of 64
+# heads' fp32 64 x 128 state as it is held (two heads side by side on lanes),
+# one token's bf16 x, B, C and fp32 dt a slot; a token's 32-head query over
+# the first rows of an 8-head cache of 64-wide rows, four query heads a KV head
+_SSM_STATE = [((64, 32, 128, 128), jnp.float32), ((64, 64, 64), jnp.bfloat16),
+              ((64, 64), jnp.float32), ((64,), jnp.float32),
+              *[((64, 1, 128), jnp.bfloat16)] * 2, ((64,), jnp.bool_)]
+_KV_GROUPED = [((64, 32, 64), jnp.bfloat16),
+               *[((64, 8, 2048, 64), jnp.bfloat16)] * 2,
+               ((64,), jnp.int32), ((64,), jnp.bool_)]
 # moe_1b3_4e dropless: 24576 padded rows through 4 experts of 2048 x 5504
 _GMM = [((24576, 2048), jnp.bfloat16), ((4, 2048, 5504), jnp.bfloat16),
         ((4,), jnp.int32)]
@@ -258,6 +275,8 @@ KERNELS = [
     pytest.param(_decay_step, _DECAY_STATE, id="decay_state_step-32slots"),
     pytest.param(_decay_piece, _DECAY_PIECE, id="causal_dot_decay-piece1024"),
     pytest.param(_block_attention, _BLOCK_LIST, id="block_attention-32slots-128blocks"),
+    pytest.param(_ssm_step, _SSM_STATE, id="ssm_state_step-64slots"),
+    pytest.param(_cache_attention, _KV_GROUPED, id="cache_attention-grouped-64slots"),
     # -- the rest of the main path's kernels ---------------------------------
     pytest.param(_plain, _QKV, id="causal_dot-plain-fwd", marks=slow),
     pytest.param(_grad3(_plain), _QKV, id="causal_dot-plain-bwd", marks=slow),
@@ -413,6 +432,51 @@ def test_olmo_hybrid_boundary_programs_hold_the_carry_once(v5e):
             # but what the kernel stages in VMEM (its K and V blocks)
             assert "cache_attention" in compiled.as_text()
             assert m.temp_size_in_bytes < 0.08e9 + _KERNEL_VMEM, m.temp_size_in_bytes
+
+
+@slow
+def test_granite_boundary_programs_hold_the_carry_once(v5e):
+    """``granite_4_0_h_micro.serve_batch``'s programs at 64 slots x 2,048 for
+    the chip, all 40 layers, the carry donated: one slot's prompt piece and
+    the decode scan. Each fits 16 GB with its arguments (weights 6.38 GB +
+    the carry) and holds the state-space step's kernel; the scan attends
+    through the row-list kernel over the grouped cache. A compile, not a
+    chip run."""
+    from orion_tpu import generate as gen
+    from orion_tpu.generate import SampleConfig
+    from orion_tpu.models.configs import get_config
+    from orion_tpu.models.transformer import TransformerLM, init_decode_state
+
+    slots, chunk, piece, width = 64, 8, 512, 1024
+    cfg = dataclasses.replace(get_config("granite_4_0_h_micro"), backend="pallas")
+    model = TransformerLM(cfg)
+    one = SingleDeviceSharding(v5e[0])
+    put = lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one)  # noqa: E731
+    arr = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    params = jax.tree.map(put, jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 16), jnp.int32))))
+    assert sum(l.size for l in jax.tree.leaves(params)) == 3191396096
+    states = jax.tree.map(put, jax.eval_shape(lambda: init_decode_state(cfg, slots)))
+    ints, flags = arr((slots,), jnp.int32), arr((slots,), jnp.bool_)
+    carry = (ints, states, ints, ints, flags)
+    rngs, pbuf = arr((slots, 2), jnp.uint32), arr((slots, width), jnp.int32)
+    scalar, sample = arr((), jnp.int32), SampleConfig(temperature=0.0)
+    programs = {
+        "piece": gen._prefill_piece_donated_jit.lower(
+            model, params, carry, rngs, pbuf, ints, ints, scalar, piece, sample),
+        "scan": gen._decode_scan_donated_jit.lower(
+            model, params, carry, rngs, flags, ints, chunk, sample),
+    }
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        m = compiled.memory_analysis()
+        live = (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes)
+        assert live < 16e9, (name, live)
+        assert m.alias_size_in_bytes > 5.9e9, (name, m.alias_size_in_bytes)
+        if name == "scan":
+            text = compiled.as_text()
+            assert "ssm_state_step" in text and "cache_attention" in text
 
 
 def test_minicpm_sala_boundary_programs_compile_and_fit(v5e):

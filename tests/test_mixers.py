@@ -18,7 +18,7 @@ from orion_tpu.models.configs import LAYER_TYPES, get_config, hybrid_pattern
 from orion_tpu.models.mixers import MIXERS, Mixer
 from orion_tpu.models.transformer import TransformerLM, init_decode_state
 
-SERVED = ("linear", "softmax", "swa", "gated_delta", "decay_linear", "block_sparse")
+SERVED = ("linear", "softmax", "swa", "gated_delta", "decay_linear", "block_sparse", "ssm")
 TRAIN_ONLY = ("gated_softmax",)
 
 # benchmark/configs/qwen3_next_80b.json's ``rehearse`` sizes
@@ -37,6 +37,7 @@ def one_layer(lt):
         gdn_key_heads=2, gdn_value_heads=4, gdn_key_dim=8, gdn_value_dim=16,
         n_kv_heads=2, sparse_kernel=4, sparse_stride=2, sparse_block=8,
         sparse_window=8, sparse_topk=2, sparse_dense_len=16,
+        ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=2,
     )
 
 
@@ -44,7 +45,7 @@ def test_registry_has_one_mixer_per_layer_type():
     assert set(MIXERS) == set(LAYER_TYPES)
     assert all(issubclass(m, Mixer) for m in MIXERS.values())
     assert {lt for lt, m in MIXERS.items() if m.rows_in_place} == {
-        "linear", "softmax", "swa", "gated_delta", "decay_linear", "block_sparse"
+        "linear", "softmax", "swa", "gated_delta", "decay_linear", "block_sparse", "ssm"
     }
 
 
