@@ -81,6 +81,17 @@ def kernel_bh(cfg: ModelConfig, mesh, fn, *args):
     return fn(*args)
 
 
+def whole_array_backend(cfg: ModelConfig, mesh) -> str:
+    """The backend of a kernel that no shard_map is written for (the short
+    conv: its batch would split over (dp, fsdp) and its channels over tp,
+    not :func:`kernel_bh`'s heads): ``cfg.backend``, or the XLA form on a
+    mesh whose data axes split, where a bare Mosaic call is refused."""
+    from orion_tpu.ops.dispatch import resolve
+    from orion_tpu.parallel.kernel_shard import needs_manual
+
+    return "xla" if needs_manual(mesh, resolve(cfg.backend)) else cfg.backend
+
+
 NORM_EPS = 1e-6
 
 
@@ -367,5 +378,5 @@ assert set(MIXERS) == set(LAYER_TYPES), (sorted(MIXERS), LAYER_TYPES)
 __all__ = [
     "MIXERS", "Mixer", "LinearAttention", "SoftmaxAttention", "GatedDeltaNet",
     "GatedSoftmaxAttention", "DecayLinearAttention", "BlockSparseAttention",
-    "StateSpace", "ZeroCentredRMSNorm", "kernel_bh",
+    "StateSpace", "ZeroCentredRMSNorm", "kernel_bh", "whole_array_backend",
 ]

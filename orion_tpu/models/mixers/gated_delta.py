@@ -37,11 +37,11 @@ import jax.numpy as jnp
 from orion_tpu.models.configs import ModelConfig
 from orion_tpu.models.mixers import (
     NORM_EPS, Mixer, State, _dense_factory, _dtype, _rms, kernel_bh,
+    whole_array_backend,
 )
 from orion_tpu.ops.dispatch import (
-    decode_rows_mask, gated_delta_rule, gated_delta_step,
+    causal_short_conv, decode_rows_mask, gated_delta_rule, gated_delta_step,
 )
-from orion_tpu.ops.gated_delta import causal_short_conv
 from orion_tpu.utils.profiling import scope
 
 Array = jax.Array
@@ -157,7 +157,9 @@ class GatedDeltaNet(Mixer):
         with scope("gated_delta"):
             pre, z, ba = self._project(x)
             with scope("short_conv"):
-                qkv = causal_short_conv(pre, self.conv)
+                qkv = causal_short_conv(
+                    pre, self.conv, backend=whole_array_backend(self.cfg, self.mesh)
+                )
             return self._output(self._rule(*self._operands(qkv, ba)), z)
 
     # -- prefill and its pieces -----------------------------------------------
@@ -180,7 +182,10 @@ class GatedDeltaNet(Mixer):
             pre, z, ba = self._project(x)
             old = state["conv"].reshape(x.shape[0], w1, -1)
             with scope("short_conv"):
-                qkv = causal_short_conv(pre, self.conv, tail=old)
+                qkv = causal_short_conv(
+                    pre, self.conv, tail=old,
+                    backend=whole_array_backend(self.cfg, self.mesh),
+                )
             q, k, v, beta, g = self._operands(qkv, ba)
             real = jnp.arange(x.shape[1]) < length  # [P]
             pad0 = lambda y: jnp.where(  # noqa: E731

@@ -42,9 +42,12 @@ import jax
 import jax.numpy as jnp
 
 from orion_tpu.models.configs import ModelConfig
-from orion_tpu.models.mixers import Mixer, State, _dense_factory, _dtype, drawn_in
-from orion_tpu.ops.dispatch import decode_rows_mask, ssm_scan, ssm_state_step
-from orion_tpu.ops.gated_delta import causal_short_conv
+from orion_tpu.models.mixers import (
+    Mixer, State, _dense_factory, _dtype, drawn_in, whole_array_backend,
+)
+from orion_tpu.ops.dispatch import (
+    causal_short_conv, decode_rows_mask, ssm_scan, ssm_state_step,
+)
 from orion_tpu.ops.ssm import pack_state, state_pack, unpack_state
 from orion_tpu.utils.profiling import scope, scoped
 
@@ -157,7 +160,10 @@ class StateSpace(Mixer):
 
     def _conv(self, pre: Array, tail: Optional[Array]) -> Array:
         with scope("short_conv"):
-            return causal_short_conv(pre, self.conv, tail=tail, bias=self.conv_bias)
+            return causal_short_conv(
+                pre, self.conv, tail=tail, bias=self.conv_bias,
+                backend=whole_array_backend(self.cfg, self.mesh),
+            )
 
     # -- parallel forward ---------------------------------------------------
 

@@ -6,9 +6,11 @@ backend='xla' dispatch"). "auto" picks Pallas on TPU and the pure-XLA
 chunked scan elsewhere (CPU/GPU and unit tests). The Pallas kernel can also
 run anywhere via interpret mode (used by the parity tests).
 
-Seven ops dispatch here (``ssm_scan`` and ``ssm_state_step``, the
-state-space layers' prompt pass and one-token step, are described at their
-definitions): ``gated_delta_rule`` (the gated delta-rule
+Eight ops dispatch here (``ssm_scan`` and ``ssm_state_step``, the
+state-space layers' prompt pass and one-token step, and
+``causal_short_conv``, the delta-rule and state-space layers' short conv
+over a sequence or a prompt piece, are described at their definitions):
+``gated_delta_rule`` (the gated delta-rule
 layers' parallel forward, with or without a state carried in and out:
 under Pallas the chunked form as Mosaic kernels, forward and backward,
 ``ops/pallas/gated_delta.py``; the same equations as XLA fusions and a scan
@@ -203,6 +205,33 @@ def gated_delta_rule(
     if stateful:
         return gd.gated_delta_chunked(q, k, v, beta, g, **state)
     return gd.gated_delta_by_rows(q, k, v, beta, g)
+
+
+def causal_short_conv(
+    x, w, activation: bool = True, tail=None, bias=None, *, backend: str = "auto"
+):
+    """Dispatch the delta-rule and state-space layers' short causal
+    depthwise convolution + SiLU over a whole sequence or a prompt piece
+    (``ops/gated_delta.py::causal_short_conv``, the specification: x ``[...,
+    T, C]``, w ``[W, C]``, ``tail [..., W - 1, C]`` the inputs just before
+    ``x``, ``bias`` [C]). ``pallas`` and ``pallas_interpret`` run it as a
+    Mosaic kernel pair, forward and backward, that reads each row once and
+    makes the shifted views in VMEM (``ops/pallas/short_conv.py``), where
+    the input allows: ``C`` a multiple of 128 and ``T`` a whole number of
+    the kernel's time tiles (``short_conv.supports``). Anything else, and
+    every other backend, is the XLA form. The one-token decode steps sum
+    their window inline and do not come here."""
+    b = resolve(backend)
+    if b.startswith("pallas"):
+        from orion_tpu.ops.pallas import short_conv as psc
+
+        if psc.supports(x, w):
+            return psc.causal_short_conv_pallas(
+                x, w, activation, tail, bias, interpret=(b == "pallas_interpret")
+            )
+    from orion_tpu.ops.gated_delta import causal_short_conv as conv
+
+    return conv(x, w, activation, tail, bias)
 
 
 def row_sparse(backend: str) -> bool:
@@ -417,6 +446,7 @@ def decode_state_flush(state, chunk, rows, *, backend: str = "auto"):
 __all__ = [
     "cache_attention",
     "causal_dot_product",
+    "causal_short_conv",
     "decode_live_rows",
     "decode_rows_mask",
     "decode_state_flush",

@@ -176,6 +176,12 @@ def _ssm_step(s, x, dt, a, bm, cm, live):
     return ssm_state_step(x, dt, a, bm, cm, s, 2, live_rows(live))
 
 
+def _short_conv(x, w):
+    from orion_tpu.ops.dispatch import causal_short_conv
+
+    return causal_short_conv(x, w, backend="pallas")
+
+
 _QKV = [(BHTD, jnp.bfloat16)] * 3
 # qwen3_next_80b's train point (batch 8, T 8192): its softmax layer's 16
 # heads of 256 after the KV heads are repeated; its held experts' buffer
@@ -188,6 +194,8 @@ _GMM_HELD = [((131072, 2048), jnp.bfloat16), ((64, 2048, 512), jnp.bfloat16),
 _DELTA = [*[((2, 16, 8192, 128), jnp.bfloat16)] * 2,
           ((2, 32, 8192, 128), jnp.bfloat16),
           *[((2, 32, 8192), jnp.float32)] * 2]
+# and that layer's short conv over its [q | k | v] channels, window 4
+_CONV = [((8, 8192, 8192), jnp.bfloat16), ((4, 8192), jnp.bfloat16)]
 # the serve cells' decode carry: 64 slots of lm_1b3's fp32 (S, z), one
 # token's bf16 q, k, v a slot, a 16-step chunk's own bf16 k, v rows with
 # each slot's step in it, and the chunk's row mask
@@ -267,6 +275,11 @@ KERNELS = [
     pytest.param(
         jax.grad(lambda *a: _f32sum(_gated_delta(*a)), argnums=(0, 1, 2, 3, 4)),
         _DELTA, id="gated_delta-T8192-bwd",
+    ),
+    pytest.param(_short_conv, _CONV, id="short_conv-fwd"),
+    pytest.param(
+        jax.grad(lambda x, w: _f32sum(_short_conv(x, w)), argnums=(0, 1)),
+        _CONV, id="short_conv-bwd",
     ),
     pytest.param(_gated_delta_state, _DELTA_PIECE,
                  id="gated_delta-state-96x192-piece1024"),
@@ -355,11 +368,12 @@ def test_smoke_train_step_compiles_and_fits(v5e, layout, collective):
 def test_qwen3_next_train_step_compiles_and_fits(v5e):
     """benchmark/workloads/qwen3_next_80b.train.json's step — b8 x T8192,
     adafactor, bfloat16_sr, every block rematted — fits one chip with the
-    flash, grouped-matmul and delta-rule kernels in it (the memory point
-    known before a chip call: 2.07 GB of arguments + 13.4 GB of temporaries
-    by the compiler's count, of which the donated state's 2.07 GB is
-    counted twice; 14.3 GB with the delta rule in its XLA form; the chip
-    holds 15.2 GB while it runs, PERF.md s5)."""
+    flash, grouped-matmul, delta-rule and short-conv kernels in it (the
+    memory point known before a chip call: 2.07 GB of arguments + 13.1 GB
+    of temporaries by the compiler's count, of which the donated state's
+    2.07 GB is counted twice; 13.4 GB with the conv as XLA fusions, whose
+    backward held fp32 pads, 14.3 GB with the delta rule in its XLA form
+    too; the chip holds 15.1 GB while it runs, PERF.md s5)."""
     from orion_tpu.aot import plan
     from orion_tpu.models.configs import get_config
     from orion_tpu.parallel.mesh import MeshConfig, make_mesh
