@@ -192,6 +192,11 @@ PROGRAMS: Tuple[ProgramDecl, ...] = (
                 note="per-chunk host readback probe; no static args"),
     ProgramDecl("spec_flags", BATCHING, "_spec_flags", "setup",
                 note="speculative boundary readback probe; no static args"),
+    ProgramDecl("counted_flags", BATCHING, "_counted_flags", "setup",
+                note="the readback probe of a model whose MoE layers are one "
+                     "chip's share: finite mask, done flags and the "
+                     "boundary's row counters in one transfer; no static "
+                     "args, one tuple length per engine"),
     ProgramDecl("insert_carry", BATCHING, "_insert_carry", "setup",
                 note="slot admission row write; traced slot index — one "
                      "compile ever per engine shape"),

@@ -344,6 +344,35 @@ def _sample_rows(logits: Array, keys: Array, cfg: SampleConfig) -> Array:
     )
 
 
+def _counted(model, params, *args, method: str):
+    """``model.apply(params, *args, method=method)`` and, for a model whose
+    MoE layers are one chip's share (``cfg.moe_held``), the row counters they
+    sowed as ``[4]`` int32 (``models/moe.py::stats_vector``); None for every
+    other model, whose programs stay what they were."""
+    if not model.cfg.moe_held:
+        return model.apply(params, *args, method=method), None
+    from orion_tpu.models.moe import stats_vector
+
+    out, sown = model.apply(
+        params, *args, method=method, mutable=["moe_stats"]
+    )
+    return out, stats_vector(sown.get("moe_stats", {}))
+
+
+def _decode_step_counted(model, params, token, states, t, rows, live):
+    """One decode step; a ``moe_held`` model's layers get ``live`` [S], the
+    rows whose token counts."""
+    args = (token, states, t, rows) + ((live,) if model.cfg.moe_held else ())
+    return _counted(model, params, *args, method="decode_step")
+
+
+def _scan_outputs(model, ys):
+    """A chunk scan's stacked outputs -> (tokens [S, n_steps], the steps'
+    MoE row counters summed [4] or None)."""
+    tokens, stats = ys if model.cfg.moe_held else (ys, None)
+    return jnp.moveaxis(tokens, 0, 1), None if stats is None else stats.sum(0)
+
+
 def _decode_batched_body(
     model, params, sample_cfg: SampleConfig, rngs, active, rows, carry, _
 ):
@@ -358,8 +387,8 @@ def _decode_batched_body(
     layers skip the free rows' (S, z) altogether: admission overwrites
     those rows."""
     token, states, t, emit, done = carry
-    logits, states = model.apply(
-        params, token, states, t, rows, method="decode_step"
+    (logits, states), stats = _decode_step_counted(
+        model, params, token, states, t, rows, active
     )
     keys = jax.vmap(jax.random.fold_in)(rngs, emit + 1)
     nxt = _sample_rows(logits, keys, sample_cfg)
@@ -371,7 +400,8 @@ def _decode_batched_body(
     emitted = jnp.where(active, emitted, sample_cfg.pad_token)
     t = jnp.where(active, t + 1, t)  # free slots must not walk off the
     emit = emit + 1                  # positional/rotary tables
-    return (nxt, states, t, emit, done), emitted
+    out = emitted if stats is None else (emitted, stats)
+    return (nxt, states, t, emit, done), out
 
 
 @partial(jax.jit, static_argnums=(0, 5, 6))
@@ -388,8 +418,9 @@ def _decode_batched_chunk_jit(
         _decode_batched_body, model, params, sample_cfg, rngs, active,
         decode_live_rows(active, backend=model.cfg.backend),
     )
-    carry, tokens = _scan_chunk(model, body, carry, n_steps, active, False)
-    return carry, jnp.moveaxis(tokens, 0, 1)  # [S, n_steps]
+    carry, ys = _scan_chunk(model, body, carry, n_steps, active, False)
+    tokens, stats = _scan_outputs(model, ys)  # [S, n_steps]
+    return (carry, tokens) if stats is None else (carry, tokens, stats)
 
 
 def decode_batched_chunk(
@@ -406,7 +437,10 @@ def decode_batched_chunk(
     indices, rng keys, the active mask — rides in traced, so the engine's
     whole serving lifetime costs ONE compile per (slot count, chunk
     length) regardless of arrival order (asserted via jit cache stats in
-    tests/test_batching.py)."""
+    tests/test_batching.py). Returns (carry, tokens [S, n_steps]); for a
+    ``cfg.moe_held`` model also the boundary's MoE row counters [4] int32
+    (``models/moe.py::STAT_NAMES``), as every slot-multiplexed program
+    below does."""
     return _decode_batched_chunk_jit(
         model, params, carry, rngs, active, int(n_steps), sample_cfg
     )
@@ -522,14 +556,16 @@ def _prefill_extend_row(
     path exists to kill. Jitted so that the unified program, which holds
     the piece twice (inline and in its loop), traces and lowers the
     model's forward once. Returns (last-real-row logits [V], the
-    advanced state row)."""
+    advanced state row), and for a ``cfg.moe_held`` model the piece's MoE
+    row counters [4] (its ``length`` real rows route; padding does not)."""
     idx = jnp.clip(offset + jnp.arange(pchunk), 0, pbuf.shape[1] - 1)
     piece = jnp.take(pbuf[sel], idx)[None]
     st1 = jax.tree.map(lambda x: x[sel][None], states)
-    lg, st = model.apply(
-        params, piece, st1, offset, length, method="prefill_extend_step"
+    (lg, st), stats = _counted(
+        model, params, piece, st1, offset, length, method="prefill_extend_step"
     )
-    return lg[0], jax.tree.map(lambda x: x[0], st)
+    out = lg[0], jax.tree.map(lambda x: x[0], st)
+    return out if stats is None else out + (stats,)
 
 
 def _decode_batched_prefill_body(
@@ -546,8 +582,8 @@ def _decode_batched_prefill_body(
     pure body's compiled program must stay byte-identical on the XLA
     path (golden ``decode_batched_tiny``)."""
     token, states, t, emit, done = carry
-    logits, new_states = model.apply(
-        params, token, states, t, rows, method="decode_step"
+    (logits, new_states), stats = _decode_step_counted(
+        model, params, token, states, t, rows, emitting
     )
     keys = jax.vmap(jax.random.fold_in)(rngs, emit + 1)
     nxt = _sample_rows(logits, keys, sample_cfg)
@@ -563,7 +599,8 @@ def _decode_batched_prefill_body(
     token = jnp.where(emitting, nxt, token)
     t = jnp.where(emitting, t + 1, t)
     emit = jnp.where(emitting, emit + 1, emit)
-    return (token, states, t, emit, done), emitted
+    out = emitted if stats is None else (emitted, stats)
+    return (token, states, t, emit, done), out
 
 
 def prefill_piece_cap(slots: int, chunk: int) -> int:
@@ -655,16 +692,19 @@ def _decode_batched_prefill_chunk_jit(
     rem = jnp.maximum(plen - t, 0)
     order, n = _prefill_selection(active, rem, pwait, n_steps)
 
+    held = model.cfg.moe_held
+
     def serve(k, served):
-        token, states, t, emit = served
+        token, states, t, emit, *counted = served
         sel = order[k]
         # false only for the inline first piece when no slot waits: its
         # garbage is then discarded bitwise, as a replay needs it to be
         live = k < n
         cons = jnp.where(live, jnp.minimum(rem[sel], piece), 0)
-        logits1, fed = _prefill_extend_row(
+        logits1, fed, *stats = _prefill_extend_row(
             model, params, pbuf, states, sel, t[sel], cons, piece
         )
+        counted = [c + s for c, s in zip(counted, stats)]
         states = jax.tree.map(
             lambda x, new: x.at[sel].set(jnp.where(live, new, x[sel])),
             states, fed,
@@ -674,14 +714,15 @@ def _decode_batched_prefill_chunk_jit(
         first = _sample_rows(logits1[None], key[None], sample_cfg)[0]
         token = token.at[sel].set(jnp.where(completed, first, token[sel]))
         emit = emit.at[sel].set(jnp.where(completed, pfold[sel], emit[sel]))
-        return token, states, t.at[sel].set(t[sel] + cons), emit
+        return (token, states, t.at[sel].set(t[sel] + cons), emit, *counted)
 
     # the first piece runs inline, where XLA schedules it among the
     # program's opening copies and casts as it did the one piece this
     # program used to run (inside the loop a piece measured 9.5 ms on
     # the chip against 5.2 ms inline); the loop runs the pieces after it
-    served = serve(0, (token, states, t, emit))
-    token, states, t, emit = jax.lax.fori_loop(
+    zero = (jnp.zeros((4,), jnp.int32),) if held else ()
+    served = serve(0, (token, states, t, emit, *zero))
+    token, states, t, emit, *counted = jax.lax.fori_loop(
         1, jnp.maximum(n, 1), serve, served
     )
     emitting = active & (t >= plen)
@@ -689,10 +730,11 @@ def _decode_batched_prefill_chunk_jit(
         _decode_batched_prefill_body, model, params, sample_cfg, rngs,
         emitting, decode_live_rows(emitting, backend=model.cfg.backend),
     )
-    carry, tokens = _scan_chunk(
+    carry, ys = _scan_chunk(
         model, body, (token, states, t, emit, done), n_steps, emitting, False
     )
-    return carry, jnp.moveaxis(tokens, 0, 1)  # [S, n_steps]
+    tokens, stats = _scan_outputs(model, ys)  # [S, n_steps]
+    return (carry, tokens) if stats is None else (carry, tokens, stats + counted[0])
 
 
 # -- the carry held once (ISSUE 35) -------------------------------------------
@@ -735,7 +777,7 @@ def _prefill_piece_donated_jit(
     piece = min(pchunk, pbuf.shape[1])
     rem = jnp.maximum(plen[sel] - t[sel], 0)
     cons = jnp.minimum(rem, piece)
-    logits1, fed = _prefill_extend_row(
+    logits1, fed, *stats = _prefill_extend_row(
         model, params, pbuf, states, sel, t[sel], cons, piece
     )
     states = jax.tree.map(lambda x, new: x.at[sel].set(new), states, fed)
@@ -744,7 +786,8 @@ def _prefill_piece_donated_jit(
     first = _sample_rows(logits1[None], key[None], sample_cfg)[0]
     token = token.at[sel].set(jnp.where(completed, first, token[sel]))
     emit = emit.at[sel].set(jnp.where(completed, pfold[sel], emit[sel]))
-    return token, states, t.at[sel].set(t[sel] + cons), emit, done
+    carry = token, states, t.at[sel].set(t[sel] + cons), emit, done
+    return (carry, stats[0]) if stats else carry
 
 
 @partial(jax.jit, static_argnums=(0, 6, 7), donate_argnums=(2,))
@@ -768,8 +811,9 @@ def _decode_scan_donated_jit(
         _decode_batched_prefill_body, model, params, sample_cfg, rngs,
         emitting, decode_live_rows(emitting, backend=model.cfg.backend),
     )
-    carry, tokens = _scan_chunk(model, step, carry, n_steps, emitting, True)
-    return carry, jnp.moveaxis(tokens, 0, 1)
+    carry, ys = _scan_chunk(model, step, carry, n_steps, emitting, True)
+    tokens, stats = _scan_outputs(model, ys)
+    return (carry, tokens) if stats is None else (carry, tokens, stats)
 
 
 def decode_boundary_donated(
@@ -788,15 +832,24 @@ def decode_boundary_donated(
 ):
     """One boundary on a donated carry: a prompt piece for each slot of
     ``served`` (the host's schedule, in its order), then the decode scan.
-    ``carry`` is consumed. Returns (carry, tokens [S, n_steps])."""
+    ``carry`` is consumed. Returns (carry, tokens [S, n_steps]); for a
+    ``cfg.moe_held`` model also the TUPLE of its programs' MoE row counters
+    ([4] each, on the device: whoever syncs next sums them)."""
+    counted = []
     for sel in served:
         carry = _prefill_piece_donated_jit(
             model, params, carry, rngs, pbuf, plen, pfold, jnp.int32(sel),
             int(pchunk), sample_cfg,
         )
-    return _decode_scan_donated_jit(
+        if model.cfg.moe_held:
+            carry, stats = carry
+            counted.append(stats)
+    out = _decode_scan_donated_jit(
         model, params, carry, rngs, active, plen, int(n_steps), sample_cfg
     )
+    if not model.cfg.moe_held:
+        return out
+    return out[0], out[1], tuple(counted) + (out[2],)
 
 
 def decode_batched_prefill_chunk(

@@ -79,8 +79,20 @@ class ModelConfig:
     qk_norm: str = "none"  # "none" | "projection" | "head"
     rotary: bool = True  # softmax / swa layers rotate q and k by position
     # "pre": x + f(norm(x)); "post": x + norm(f(x)), the sublayer's OUTPUT
-    # normalised before the residual add
-    norm_placement: str = "pre"  # "pre" | "post"
+    # normalised before the residual add; "sandwich": x + norm_post(f(
+    # norm(x))), both, four norms a block (``post_norm1`` / ``post_norm2``)
+    norm_placement: str = "pre"  # "pre" | "post" | "sandwich"
+    # -- "latent" layers (models/mixers/latent.py): queries through a normed
+    # bottleneck of latent_q_rank, keys and values through a normed latent
+    # of latent_kv_rank plus ONE rotary key of latent_rope_dim shared by
+    # all heads (interleaved pairs, base rotary_base); a head's query and
+    # key are [latent_nope_dim | latent_rope_dim] wide, its value
+    # latent_value_dim; the decode state is the latent and the rotary key
+    latent_q_rank: int = 0
+    latent_kv_rank: int = 0
+    latent_nope_dim: int = 0
+    latent_rope_dim: int = 0
+    latent_value_dim: int = 0
     # -- "decay_linear" layers (models/mixers/decay_linear.py): linear
     # attention with no feature map and no normaliser, S_t = lam_h S_{t-1} +
     # k_t^T v_t, the per-head decay fixed: lam_h = exp(-2^(-decay_exponent
@@ -189,8 +201,19 @@ class ModelConfig:
     moe_router_width: int = 0
     moe_expert_offset: int = 0
     # > 0 adds a shared expert of this width to every MoE layer, scaled by
-    # sigmoid(w . x)
+    # sigmoid(w . x) unless moe_shared_gated is off (then added as it is)
     moe_shared_hidden: int = 0
+    moe_shared_gated: bool = True
+    # the router's scores (dropless paths): "softmax" over its whole width,
+    # or "sigmoid" of each logit; the top-k are chosen on the scores and
+    # renormalised over the k chosen, then multiplied by moe_route_scale
+    moe_score: str = "softmax"  # "softmax" | "sigmoid"
+    moe_route_scale: float = 1.0
+    # a routed expert's width where it differs from the dense layers'
+    # mlp_hidden (0: resolved_mlp_hidden serves both)
+    moe_hidden: int = 0
+    # the first moe_first_dense blocks keep a dense MLP whatever moe_period
+    moe_first_dense: int = 0
     # classifier-only
     n_classes: int = 0  # >0 => LRA classifier head
 
@@ -210,7 +233,24 @@ class ModelConfig:
 
     def moe_at(self, layer: int) -> bool:
         """Does block ``layer`` (0-based) carry a routed-expert MLP?"""
-        return self.n_experts > 0 and (layer + 1) % self.moe_period == 0
+        return (
+            self.n_experts > 0
+            and layer >= self.moe_first_dense
+            and (layer + 1) % self.moe_period == 0
+        )
+
+    @property
+    def moe_held(self) -> bool:
+        """Are the MoE layers one chip's share of an expert-parallel layer
+        (models/moe.py::_dropless_held)? Such a model's decode programs sum
+        its row counters (generate.py)."""
+        return self.n_experts > 0 and bool(
+            self.resolved_router_width != self.n_experts or self.moe_expert_offset
+        )
+
+    @property
+    def resolved_moe_hidden(self) -> int:
+        return self.moe_hidden or self.resolved_mlp_hidden
 
     @property
     def resolved_router_width(self) -> int:
@@ -228,7 +268,7 @@ class ModelConfig:
 # one mixer class each: models/mixers/__init__.py::MIXERS
 LAYER_TYPES = (
     "linear", "softmax", "swa", "gated_delta", "gated_softmax",
-    "decay_linear", "block_sparse", "ssm",
+    "decay_linear", "block_sparse", "ssm", "latent",
 )
 
 
@@ -523,6 +563,57 @@ GRANITE_4_0_H_MICRO = ModelConfig(
     param_dtype="bfloat16",
 )
 
+OPENPANGU_ULTRA_MOE_718B = ModelConfig(
+    # openPangu-Ultra-MoE-718B at its published widths, as ONE chip's share
+    # of a 16-way expert-parallel group, one leading dense layer and four
+    # expert layers deep (benchmark/configs/openpangu_ultra_moe_718b.json
+    # states the source, the cut and what is assumed): latent attention
+    # (128 heads, queries through a 1,536-wide normed bottleneck, keys and
+    # values through a 512-wide normed latent and one 64-wide rotary key
+    # shared by all heads), sandwich norms, a sigmoid top-8 router over 256
+    # experts of which 16 are held, an ungated shared expert; 19,200 of the
+    # 153,600 vocabulary rows; served in bfloat16.
+    name="openpangu_ultra_moe_718b",
+    vocab_size=19200,
+    d_model=7680,
+    n_layers=5,
+    layer_types=("latent",) * 5,
+    n_heads=128,
+    head_dim=192,
+    latent_q_rank=1536,
+    latent_kv_rank=512,
+    latent_nope_dim=128,
+    latent_rope_dim=64,
+    latent_value_dim=128,
+    rotary_base=25.6e6,
+    norm="rmsnorm",
+    norm_eps=1e-5,
+    norm_placement="sandwich",
+    pos_embed="none",
+    tie_embeddings=False,
+    mlp="swiglu",
+    mlp_hidden=18432,
+    moe_hidden=2048,
+    moe_shared_hidden=2048,
+    moe_shared_gated=False,
+    moe_first_dense=1,
+    moe_period=1,
+    n_experts=16,
+    moe_router_width=256,
+    moe_expert_offset=0,
+    moe_top_k=8,
+    moe_score="sigmoid",
+    moe_route_scale=2.5,
+    moe_dropless=True,
+    # the held rows' buffer holds every pair the router can send here
+    # (router width / experts held x their even share): nothing can drop
+    moe_ep_buffer=16.0,
+    param_init_dtype="float32",
+    max_seq_len=4608,
+    dtype="bfloat16",
+    param_dtype="bfloat16",
+)
+
 LRA_LISTOPS_LINEAR = ModelConfig(
     name="lra_listops_linear",
     vocab_size=32,  # digits + operators + specials
@@ -572,6 +663,7 @@ CONFIGS = {
         OLMO_HYBRID_7B,
         MINICPM_SALA,
         GRANITE_4_0_H_MICRO,
+        OPENPANGU_ULTRA_MOE_718B,
         LRA_LISTOPS_LINEAR,
         LRA_LISTOPS_SOFTMAX,
         LRA_TEXT_LINEAR,

@@ -1,7 +1,9 @@
 """Token mixers: one file per mechanism, one registry, one contract.
 
 ``MIXERS[layer_type]`` is the class ``models/transformer.py::Block`` builds
-as its ``attn`` submodule for every entry of ``configs.LAYER_TYPES``. A new
+as its ``attn`` submodule for every entry of ``configs.LAYER_TYPES``
+(``linear``, ``softmax`` / ``swa``, ``gated_delta``, ``gated_softmax``,
+``decay_linear``, ``block_sparse``, ``ssm``, ``latent``). A new
 mechanism is one file here with a :class:`Mixer` subclass, its entry in
 ``LAYER_TYPES`` and in ``MIXERS`` below, and nothing else: ``Block``,
 ``TransformerLM``, ``init_decode_state``, the decode programs and the
@@ -37,6 +39,14 @@ def drawn_in(cfg: ModelConfig, init):
     return lambda key, shape, dtype=wide: init(key, shape, wide).astype(dtype)
 
 
+def drawn_kernel_init(cfg: ModelConfig) -> dict:
+    """``nn.Dense``'s ``kernel_init`` keyword under ``cfg.param_init_dtype``
+    (:func:`drawn_in` of flax's own initialiser); nothing where it is unset."""
+    if cfg.param_init_dtype is None:
+        return {}
+    return {"kernel_init": drawn_in(cfg, nn.linear.default_kernel_init)}
+
+
 def _dense_factory(cfg: ModelConfig, quant: str = "", mesh=None):
     """``(name, features) -> module``: the bias-free projection every layer
     uses, or its weight-streamed form in the decode modes. "int8": every
@@ -48,10 +58,7 @@ def _dense_factory(cfg: ModelConfig, quant: str = "", mesh=None):
     a multi-device host must not silently lose the kernel)."""
     dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
     if not quant:
-        init = (
-            {} if cfg.param_init_dtype is None
-            else {"kernel_init": drawn_in(cfg, nn.linear.default_kernel_init)}
-        )
+        init = drawn_kernel_init(cfg)
         return lambda n, feats: nn.Dense(
             feats, use_bias=False, dtype=dt, param_dtype=pdt, name=n, **init
         )
@@ -159,6 +166,24 @@ class Mixer(nn.Module):
     sp_local_kernels: bool = False
 
     rows_in_place = False
+    # what the engine asks instead of the layer type's name
+    # (``SlotEngine.kv_rows``, ``held_bytes``): the state leaves that are a
+    # position-indexed cache, how many rows of it a slot reserves
+    # (``cache_rows``) and how many a decode step streams (``cache_rows_read``)
+    cache_leaves: Tuple[str, ...] = ()
+
+    @staticmethod
+    def cache_rows(cfg: ModelConfig, layer_type: str) -> int:
+        """Cache rows a slot reserves in this layer; 0: no cache."""
+        return 0
+
+    @staticmethod
+    def cache_rows_read(cfg: ModelConfig, layer_type: str, length: int):
+        """Cache rows one decode step streams for an EMITTING slot of
+        ``length`` live rows where the backend runs the layer's row-list
+        kernel (and nothing for a slot that is not emitting); None: the
+        layer has no such kernel, every slot's reservation is read."""
+        return None
 
     # -- serving: the defaults of a train-only mixer --------------------------
 
@@ -206,8 +231,8 @@ class Mixer(nn.Module):
         raise NotImplementedError(
             f"layer type {self.layer_type!r} does not build this serving "
             "entry point: gated_softmax has a training forward only; "
-            "gated_delta, decay_linear, block_sparse and ssm serve (prefill, "
-            "its pieces, the decode step) but have no speculative "
+            "gated_delta, decay_linear, block_sparse, ssm and latent serve "
+            "(prefill, its pieces, the decode step) but have no speculative "
             "verify_extend / advance_verified"
         )
 
@@ -359,6 +384,7 @@ from orion_tpu.models.mixers.gated_delta import GatedDeltaNet  # noqa: E402
 from orion_tpu.models.mixers.gated_softmax import (  # noqa: E402
     GatedSoftmaxAttention,
 )
+from orion_tpu.models.mixers.latent import LatentAttention  # noqa: E402
 from orion_tpu.models.mixers.linear import LinearAttention  # noqa: E402
 from orion_tpu.models.mixers.softmax import SoftmaxAttention  # noqa: E402
 from orion_tpu.models.mixers.ssm import StateSpace  # noqa: E402
@@ -372,11 +398,12 @@ MIXERS = {
     "decay_linear": DecayLinearAttention,
     "block_sparse": BlockSparseAttention,
     "ssm": StateSpace,
+    "latent": LatentAttention,
 }
 assert set(MIXERS) == set(LAYER_TYPES), (sorted(MIXERS), LAYER_TYPES)
 
 __all__ = [
     "MIXERS", "Mixer", "LinearAttention", "SoftmaxAttention", "GatedDeltaNet",
     "GatedSoftmaxAttention", "DecayLinearAttention", "BlockSparseAttention",
-    "StateSpace", "ZeroCentredRMSNorm", "kernel_bh", "whole_array_backend",
+    "StateSpace", "LatentAttention", "ZeroCentredRMSNorm", "kernel_bh", "whole_array_backend",
 ]

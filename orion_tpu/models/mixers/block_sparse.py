@@ -131,6 +131,16 @@ class BlockSparseAttention(Mixer):
             "wg", h * cfg.resolved_head_dim
         )
 
+    cache_leaves = ("k", "v")
+
+    @staticmethod
+    def cache_rows(cfg: ModelConfig, layer_type: str) -> int:
+        return cfg.max_seq_len
+
+    @staticmethod
+    def cache_rows_read(cfg: ModelConfig, layer_type: str, length: int):
+        return cfg.sparse_block * blocks_read(cfg, length)
+
     @staticmethod
     def decode_state(
         cfg: ModelConfig, layer_type: str, batch: int, dtype: Any

@@ -54,6 +54,19 @@ class SoftmaxAttention(Mixer):
     layer_type: str = "softmax"
 
     rows_in_place = True
+    cache_leaves = ("k", "v")
+
+    @staticmethod
+    def cache_rows(cfg: ModelConfig, layer_type: str) -> int:
+        return _window(cfg, layer_type) or cfg.max_seq_len
+
+    @staticmethod
+    def cache_rows_read(cfg: ModelConfig, layer_type: str, length: int):
+        if _window(cfg, layer_type) is not None:
+            return None  # the ring is read whole, under a mask
+        from orion_tpu.ops.pallas.cache_attention import rows_read
+
+        return rows_read(length, cfg.max_seq_len)
 
     def setup(self):
         cfg = self.cfg

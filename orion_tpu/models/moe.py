@@ -65,8 +65,20 @@ experts held here give (ids from ``moe_expert_offset``; ``_held_rows_ffn``,
 the body an ep shard of ``_dropless_ep_gmm`` runs) and leaves out what
 the absent experts would add — their chips compute that, and no code here
 stands in for them. ``moe_shared_hidden`` adds a shared expert scaled by
-``sigmoid(w . x)`` to whichever routed path ran. The held path counts its
-rows into "moe_stats" (``rows_routed``, ``rows_held``, ``rows_max_expert``).
+``sigmoid(w . x)`` (as it is where ``moe_shared_gated`` is off) to whichever
+routed path ran. The held path counts its rows into "moe_stats"
+(``rows_routed``, ``rows_held``, ``rows_max_expert``). ``moe_score:
+"sigmoid"`` scores each expert ``sigmoid(x W_r)`` instead of the softmax
+over the router's width; either way the top-k are renormalised over the k
+chosen and multiplied by ``moe_route_scale``.
+
+The SERVING methods hand the layer ``live`` (``[B]`` at a decode step, ``[B,
+T]`` in a prompt piece): a row outside it (a slot that is not emitting, a
+piece's padding) routes nowhere and counts nowhere. The held layer then runs
+its grouped product only over the tiles that hold a row, so a buffer that
+holds every pair the router can send here (``moe_ep_buffer >= router width /
+experts held``: ``dropless_overflow`` 0 by construction) costs what the held
+rows cost. :func:`stats_vector` is what the decode programs sum a boundary.
 """
 
 from __future__ import annotations
@@ -79,6 +91,7 @@ import jax
 import jax.numpy as jnp
 
 from orion_tpu.models.configs import ModelConfig
+from orion_tpu.models.mixers import drawn_in, drawn_kernel_init
 from orion_tpu.utils.profiling import scope
 
 Array = jax.Array
@@ -161,10 +174,12 @@ class MoEMLP(nn.Module):
     quant: str = ""  # "" | "int8": weight-streamed decode (orion_tpu/quant.py)
 
     @nn.compact
-    def __call__(self, x: Array) -> Array:
+    def __call__(self, x: Array, live: Optional[Array] = None) -> Array:
+        """``live``: the serving methods' row mask, ``x.shape[:-1]`` bool
+        (module docstring); only the held-experts layer is served."""
         cfg = self.cfg
-        if cfg.resolved_router_width != cfg.n_experts or cfg.moe_expert_offset:
-            y = self._dropless_held(x)
+        if cfg.moe_held:
+            y = self._dropless_held(x, live)
         else:
             y = self._routed(x)
         if cfg.moe_shared_hidden:
@@ -176,20 +191,23 @@ class MoEMLP(nn.Module):
         cfg = self.cfg
         assert not self.quant and cfg.mlp == "swiglu", (self.quant, cfg.mlp)
         dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
+        init = drawn_kernel_init(cfg)
         dense = lambda n, feats: nn.Dense(  # noqa: E731
-            feats, use_bias=False, dtype=dt, param_dtype=pdt, name=n
+            feats, use_bias=False, dtype=dt, param_dtype=pdt, name=n, **init
         )
         with scope("moe_shared"):
             mid = jax.nn.silu(dense("shared_gate", cfg.moe_shared_hidden)(x)) * dense(
                 "shared_up", cfg.moe_shared_hidden
             )(x)
+            if not cfg.moe_shared_gated:
+                return dense("shared_down", x.shape[-1])(mid)
             gate = jax.nn.sigmoid(dense("shared_scale", 1)(x).astype(jnp.float32))
             return dense("shared_down", x.shape[-1])(mid) * gate.astype(dt)
 
     def _routed(self, x: Array) -> Array:
         cfg = self.cfg
         dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
-        e, k, h = cfg.n_experts, cfg.moe_top_k, cfg.resolved_mlp_hidden
+        e, k, h = cfg.n_experts, cfg.moe_top_k, cfg.resolved_moe_hidden
         # k > E would silently re-pick masked experts (argmax over an
         # all -1 row) and leak combine weight — fail loudly instead
         assert 1 <= k <= e, f"moe_top_k={k} must be in [1, n_experts={e}]"
@@ -294,13 +312,20 @@ class MoEMLP(nn.Module):
         softmax / top-k choice on [N, d] input. ONE definition so the
         single-host and ep-sharded forms can never diverge."""
         cfg = self.cfg
+        init = drawn_kernel_init(cfg)
         router = nn.Dense(
             cfg.resolved_router_width, use_bias=False, dtype=jnp.float32,
-            param_dtype=_dtype(cfg.param_dtype), name="router"
+            param_dtype=_dtype(cfg.param_dtype), name="router", **init
         )
         logits = router(x2.astype(jnp.float32))  # [N, E]
-        probs = jax.nn.softmax(logits, axis=-1)
+        if cfg.moe_score == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)
+        else:
+            assert cfg.moe_score == "sigmoid", cfg.moe_score
+            probs = jax.nn.sigmoid(logits)
         ids, gates = top_k_choice(probs, cfg.moe_top_k)  # [N, k] x2
+        if cfg.moe_route_scale != 1.0:
+            gates = gates * cfg.moe_route_scale
         return logits, probs, ids, gates
 
     def _sow_flat_aux(self, logits: Array, probs: Array, ids: Array) -> None:
@@ -336,7 +361,7 @@ class MoEMLP(nn.Module):
         """
         cfg = self.cfg
         dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
-        e, k, h = cfg.n_experts, cfg.moe_top_k, cfg.resolved_mlp_hidden
+        e, k, h = cfg.n_experts, cfg.moe_top_k, cfg.resolved_moe_hidden
         d = x.shape[-1]
         ep = 1 if self.mesh is None else self.mesh.shape.get("ep", 1)
         if ep > 1:
@@ -466,7 +491,7 @@ class MoEMLP(nn.Module):
         y = jnp.sum(y * gates[..., None].astype(dt), axis=1)
         return y.reshape(x.shape).astype(dt)
 
-    def _dropless_held(self, x: Array) -> Array:
+    def _dropless_held(self, x: Array, live: Optional[Array] = None) -> Array:
         """The dropless layer of ONE chip of an expert-parallel group: the
         router is ``moe_router_width`` wide and picks its top-k over all of
         it, renormalised over all k as published; of the chosen (token,
@@ -479,14 +504,17 @@ class MoEMLP(nn.Module):
         The buffer is ``moe_ep_buffer x`` the held experts' even share of
         the rows (an even router fills ``1 / moe_ep_buffer`` of it; one of
         ``router_width / n_experts`` times the share holds every row there
-        can be); rows past it are dropped and COUNTED."""
+        can be); rows past it are dropped and COUNTED. ``live`` (the serving
+        methods' row mask): the pairs of a row outside it belong to no
+        expert and to no counter, and the grouped product visits only the
+        tiles that hold a row."""
         cfg = self.cfg
         assert cfg.moe_dropless and not self.quant and cfg.mlp == "swiglu"
         assert self.mesh is None or self.mesh.devices.size == 1, (
             "the held-experts layer is one chip's share; it has no exchange"
         )
         dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
-        e, k, h = cfg.n_experts, cfg.moe_top_k, cfg.resolved_mlp_hidden
+        e, k, h = cfg.n_experts, cfg.moe_top_k, cfg.resolved_moe_hidden
         r, lo = cfg.resolved_router_width, cfg.moe_expert_offset
         assert 1 <= k <= r and 0 <= lo and lo + e <= r, (k, r, lo, e)
         d = x.shape[-1]
@@ -501,20 +529,27 @@ class MoEMLP(nn.Module):
             logits, probs, ids, gates = self._route_flat(x2)
             self._sow_flat_aux(logits, probs, ids)
         ws = tuple(
-            self.param(name, _expert_init(), shape, pdt)
+            self.param(name, drawn_in(cfg, _expert_init()), shape, pdt)
             for name, shape in (("experts_gate", (e, d, h)), ("experts_up", (e, d, h)),
                                 ("experts_down", (e, h, d)))
         )
-        if b.startswith("pallas") and budget >= 1024:
+        flat, routed = ids.reshape(-1), jnp.asarray(m, jnp.int32)
+        if live is not None:
+            alive = jnp.repeat(live.reshape(-1), k)
+            flat = jnp.where(alive, flat, -1)  # no expert's id: held nowhere
+            routed = alive.sum().astype(jnp.int32)
+        if b.startswith("pallas") and live is not None:
+            matmul = _gmm_matmul(*_SERVE_TILES, b == "pallas_interpret", live_tiles=True)
+        elif b.startswith("pallas") and budget >= 1024:
             matmul = _gmm_matmul(128, 512, b == "pallas_interpret")
         else:
             matmul = _ragged_matmul
         y, held_counts, dropped = _held_rows_ffn(
-            x2, ids.reshape(-1), gates.reshape(-1), ws, lo, budget, matmul, dt
+            x2, flat, gates.reshape(-1), ws, lo, budget, matmul, dt
         )
         if not self.is_initializing():
             self.sow("moe_stats", "dropless_overflow", dropped)
-            self.sow("moe_stats", "rows_routed", jnp.asarray(m, jnp.int32))
+            self.sow("moe_stats", "rows_routed", routed)
             self.sow("moe_stats", "rows_held", held_counts.sum())
             self.sow("moe_stats", "rows_max_expert", held_counts.max())
         return y.reshape(x.shape).astype(dt)
@@ -532,7 +567,7 @@ class MoEMLP(nn.Module):
 
         cfg = self.cfg
         dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
-        e, k, h = cfg.n_experts, cfg.moe_top_k, cfg.resolved_mlp_hidden
+        e, k, h = cfg.n_experts, cfg.moe_top_k, cfg.resolved_moe_hidden
         d = x2.shape[-1]
         m = flat.shape[0]
         # (128, 512) is the VMEM-feasible optimum at flagship shapes: the
@@ -593,7 +628,7 @@ class MoEMLP(nn.Module):
 
         cfg = self.cfg
         dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
-        e, k, h = cfg.n_experts, cfg.moe_top_k, cfg.resolved_mlp_hidden
+        e, k, h = cfg.n_experts, cfg.moe_top_k, cfg.resolved_moe_hidden
         d = x.shape[-1]
         ep = self.mesh.shape["ep"]
         assert e % ep == 0, (e, ep)
@@ -713,7 +748,7 @@ class MoEMLP(nn.Module):
 
         cfg = self.cfg
         dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
-        e, k, h = cfg.n_experts, cfg.moe_top_k, cfg.resolved_mlp_hidden
+        e, k, h = cfg.n_experts, cfg.moe_top_k, cfg.resolved_moe_hidden
         d = x.shape[-1]
         mesh = self.mesh
         s = mesh.shape
@@ -823,15 +858,25 @@ def _data_shards(mesh) -> int:
     return out
 
 
-def _gmm_matmul(tm: int, bh: int, interpret: bool):
+# the serving path's row tile and output block of the grouped product
+_SERVE_TILES = (128, 512)
+
+
+def _gmm_matmul(tm: int, bh: int, interpret: bool, live_tiles: bool = False):
     """``_held_rows_ffn``'s matmul through the grouped-matmul kernel, which
-    wants every expert's rows in whole tiles of ``tm``."""
-    from orion_tpu.ops.pallas.gmm import gmm
+    wants every expert's rows in whole tiles of ``tm``. ``live_tiles``: the
+    forward-only form that visits the tiles up to the last segment's end and
+    leaves the rows past it unwritten (``matmul.unwritten_tail``: the caller
+    masks them)."""
+    from orion_tpu.ops.pallas.gmm import gmm, gmm_live
 
     def matmul(lhs, w, seg, gs):
+        if live_tiles:
+            return gmm_live(lhs, w, seg.astype(jnp.int32), tm, bh, interpret)
         return gmm(lhs, w, seg.astype(jnp.int32), tm, bh, interpret)
 
     matmul.tile = tm
+    matmul.unwritten_tail = live_tiles
     return matmul
 
 
@@ -844,6 +889,7 @@ def _ragged_matmul(lhs, w, seg, gs):
 
 
 _ragged_matmul.tile = 1
+_ragged_matmul.unwritten_tail = False
 
 
 def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt):
@@ -872,7 +918,7 @@ def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt):
     with scope("moe_route"):
         loc = flat - lo
         cls = jnp.where((loc >= 0) & (loc < el), loc, el)  # el: held elsewhere
-        order, _, counts = _counting_sort_perm(cls, el + 1)
+        order, rank, counts = _counting_sort_perm(cls, el + 1)
         held = counts[:el]
         cum = jnp.cumsum(held)
         cumc = jnp.minimum(cum, budget)
@@ -897,11 +943,35 @@ def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt):
         else:
             mid = jax.nn.gelu(mm(xs, ws[0]))
         ys = mm(mid, ws[-1])  # [M2, d]
+        if matmul.unwritten_tail:
+            return _gather_combine(
+                ys, cls, rank, gates, tight, gs, starts, n
+            ), held, cum[-1] - cumc[-1]
         # each token gathers its held experts' rows, weighted
         y = jnp.zeros((n, d), jnp.float32).at[token].add(
             ys.astype(jnp.float32) * gate_row[:, None]
         )
     return y, held, cum[-1] - cumc[-1]
+
+
+def _gather_combine(ys, cls, rank, gates, tight, gs, starts, n: int):
+    """``_held_rows_ffn``'s combine on the serving path, from the pairs' side:
+    pair ``p`` of class ``c`` sits at offset ``rank[p] - tight[c]`` of its
+    expert's segment, so each token GATHERS the buffer rows of its k pairs
+    and sums them under their gates (a pair held elsewhere or past the budget
+    adds nothing, and never reads a row the grouped product left unwritten).
+    A scatter-add of the buffer's rows into the tokens costs the chip a
+    serial update a row: 3 ms a layer at a 1,024-token piece's 10,240-row
+    buffer against 0.5 ms for this gather (PERF.md section 6, PR 43)."""
+    el = gs.shape[0]
+    k = cls.shape[0] // n
+    mine = jnp.minimum(cls, el - 1)
+    off = rank - tight[mine]
+    ok = (cls < el) & (off < gs[mine])
+    rows = jnp.where(ok, starts[mine] + off, 0)
+    picked = jnp.where(ok[:, None], jnp.take(ys, rows, axis=0), 0).astype(jnp.float32)
+    weighted = picked * jnp.where(ok, gates, 0.0)[:, None]
+    return weighted.reshape(n, k, -1).sum(axis=1)
 
 
 def _counting_sort_perm(flat: Array, n_classes: int):
@@ -956,4 +1026,20 @@ def _group_size(t: int, target: int) -> int:
     return t
 
 
-__all__ = ["MoEMLP", "top_k_routing", "top_k_choice"]
+# what a held layer sows into "moe_stats", in :func:`stats_vector`'s order
+STAT_NAMES = ("rows_routed", "rows_held", "rows_max_expert", "dropless_overflow")
+
+
+def stats_vector(collection) -> Array:
+    """The "moe_stats" collection of one ``apply`` as ``[4]`` int32 in
+    :data:`STAT_NAMES`' order, each summed over the layers that sowed it."""
+    total = {name: jnp.zeros((), jnp.int32) for name in STAT_NAMES}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(collection):
+        sown = next(
+            p.key for p in reversed(path) if isinstance(p, jax.tree_util.DictKey)
+        )
+        total[sown] = total[sown] + leaf.astype(jnp.int32)
+    return jnp.stack([total[name] for name in STAT_NAMES])
+
+
+__all__ = ["MoEMLP", "STAT_NAMES", "stats_vector", "top_k_routing", "top_k_choice"]

@@ -74,7 +74,9 @@ class MLP(nn.Module):
 class Block(nn.Module):
     """Residual block: x + attn(norm(x)); x + mlp(norm(x)) (pre-norm), or
     with ``cfg.norm_placement == "post"`` x + norm(attn(x)); x +
-    norm(mlp(x)); ``attn`` is the layer type's token mixer (models/mixers/).
+    norm(mlp(x)), or with ``"sandwich"`` x + post(attn(norm(x))); x +
+    post(mlp(norm(x))); ``attn`` is the layer type's token mixer
+    (models/mixers/).
 
     ``use_moe`` swaps the dense MLP for the routed-expert MoEMLP
     (models/moe.py, ep-sharded); same name "mlp" so one sharding rule set
@@ -97,6 +99,9 @@ class Block(nn.Module):
             sp_local_kernels=self.sp_local_kernels, name="attn"
         )
         self.norm2 = _norm(self.cfg, "norm2")
+        if self.cfg.norm_placement == "sandwich":
+            self.post_norm1 = _norm(self.cfg, "post_norm1")
+            self.post_norm2 = _norm(self.cfg, "post_norm2")
         if self.use_moe:
             from orion_tpu.models.moe import MoEMLP
 
@@ -111,11 +116,18 @@ class Block(nn.Module):
 
     def _sublayer(self, norm, f, x):
         """``f(norm(x))``, or ``norm(f(x))`` where the configuration
-        normalises a sublayer's output; ``f`` may return ``(y, extra)``."""
-        if self.cfg.norm_placement == "pre":
+        normalises a sublayer's output, or ``post(f(norm(x)))`` where it
+        does both (``"sandwich"``: ``post_norm1`` after the mixer,
+        ``post_norm2`` after the MLP); ``f`` may return ``(y, extra)``."""
+        place = self.cfg.norm_placement
+        if place == "pre":
             return f(norm(x))
-        assert self.cfg.norm_placement == "post", self.cfg.norm_placement
-        y = f(x)
+        if place == "sandwich":
+            y = f(norm(x))
+            norm = self.post_norm1 if norm is self.norm1 else self.post_norm2
+        else:
+            assert place == "post", place
+            y = f(x)
         return (norm(y[0]),) + tuple(y[1:]) if isinstance(y, tuple) else norm(y)
 
     def _branch(self, y):
@@ -124,8 +136,13 @@ class Block(nn.Module):
         a = self.cfg.residual_scale
         return y if a == 1.0 else (y.astype(jnp.float32) * a).astype(y.dtype)
 
-    def _mlp_residual(self, x):
-        return x + self._branch(self._sublayer(self.norm2, self.mlp, x))
+    def _mlp_residual(self, x, live=None):
+        """``live``: the serving methods' row mask, which a routed-expert MLP
+        takes (models/moe.py); a dense MLP has no use for it."""
+        mlp = self.mlp if live is None or not self.use_moe else (
+            lambda y: self.mlp(y, live)
+        )
+        return x + self._branch(self._sublayer(self.norm2, mlp, x))
 
     def __call__(self, x, mask=None, deterministic=True):
         x = x + self.drop(
@@ -138,24 +155,35 @@ class Block(nn.Module):
         )
         return x
 
+    def _real_rows(self, x, length):
+        """``[B, T]``: the rows of a right-padded prompt or piece that are
+        real, for a routed-expert MLP; None where no length says."""
+        if length is None or not self.use_moe:
+            return None
+        return jnp.broadcast_to(jnp.arange(x.shape[-2]) < length, x.shape[:-1])
+
     def prefill(self, x, length=None):
         h, state = self._sublayer(
             self.norm1, lambda y: self.attn.prefill(y, length), x
         )
-        return self._mlp_residual(x + self._branch(h)), state
+        return self._mlp_residual(
+            x + self._branch(h), self._real_rows(x, length)
+        ), state
 
     def prefill_extend(self, x, state, offset, length):
         h, state = self._sublayer(
             self.norm1,
             lambda y: self.attn.prefill_extend(y, state, offset, length), x,
         )
-        return self._mlp_residual(x + self._branch(h)), state
+        return self._mlp_residual(
+            x + self._branch(h), self._real_rows(x, length)
+        ), state
 
-    def decode_step(self, x, state, t, rows=None):
+    def decode_step(self, x, state, t, rows=None, live=None):
         h, state = self._sublayer(
             self.norm1, lambda y: self.attn.decode_step(y, state, t, rows), x
         )
-        return self._mlp_residual(x + self._branch(h)), state
+        return self._mlp_residual(x + self._branch(h), live), state
 
     def verify_extend(self, x, state, t):
         h, upd = self._sublayer(
@@ -379,14 +407,16 @@ class TransformerLM(nn.Module):
 
     def decode_step(
         self, token: Array, states: List[State], t: Array,
-        rows: Optional[Any] = None,
+        rows: Optional[Any] = None, live: Optional[Array] = None,
     ) -> Tuple[Array, List[State]]:
         """token [B] -> (logits [B, V], updated states). t: scalar position,
-        or [B] per-slot positions; ``rows``: see Mixer.decode_step."""
+        or [B] per-slot positions; ``rows``: see Mixer.decode_step; ``live``
+        [B]: the rows whose token counts (the slot-multiplexed programs'
+        emitting slots), for the layers that route rows (models/moe.py)."""
         x = self._embed(token, t)
         new_states = []
         for blk, st in zip(self.blocks, states):
-            x, st = blk.decode_step(x, st, t, rows)
+            x, st = blk.decode_step(x, st, t, rows, live)
             new_states.append(st)
         return self._head(x), new_states
 

@@ -6,10 +6,11 @@ backend='xla' dispatch"). "auto" picks Pallas on TPU and the pure-XLA
 chunked scan elsewhere (CPU/GPU and unit tests). The Pallas kernel can also
 run anywhere via interpret mode (used by the parity tests).
 
-Eight ops dispatch here (``ssm_scan`` and ``ssm_state_step``, the
-state-space layers' prompt pass and one-token step, and
-``causal_short_conv``, the delta-rule and state-space layers' short conv
-over a sequence or a prompt piece, are described at their definitions):
+Nine ops dispatch here (``ssm_scan`` and ``ssm_state_step``, the
+state-space layers' prompt pass and one-token step, ``causal_short_conv``,
+the delta-rule and state-space layers' short conv over a sequence or a
+prompt piece, and ``latent_cache_attention``, the latent layers' absorbed
+query over a held latent cache, are described at their definitions):
 ``gated_delta_rule`` (the gated delta-rule
 layers' parallel forward, with or without a state carried in and out:
 under Pallas the chunked form as Mosaic kernels, forward and backward,
@@ -364,6 +365,43 @@ def cache_attention(
     return cached_attention(q, k_cache, v_cache, valid, with_lse=True)
 
 
+def latent_cache_attention(
+    qt, qr, c_cache, kr_cache, lengths, rows=None, *, scale: float,
+    backend: str = "auto",
+):
+    """Decode attention of one token a sequence over the first ``lengths``
+    [B] rows of its LATENT cache (``models/mixers/latent.py``, the absorbed
+    step): qt ``[B, H, R]`` and qr ``[B, H, Dr]`` are every head's query
+    against the key ``[c | k_rope]`` that all heads share, caches ``[B, cap,
+    R]`` and ``[B, cap, Dr]`` -> ``(u [B, H, R], lse [B, H])`` in fp32, the
+    softmax of ``scale (qt . c + qr . k_rope)`` applied to ``c`` itself.
+    ``rows`` as for :func:`cache_attention`: with a row list under a Pallas
+    backend a listed sequence reads its live latent blocks once, for scores
+    and values, and an unlisted one nothing (``ops/pallas/cache_attention.py
+    ::latent_attention``; ``u`` 0 and ``lse`` -1e30 there); otherwise every
+    sequence reduces over its whole reservation under a mask."""
+    if rows is not None and row_sparse(backend):
+        from orion_tpu.ops.pallas import cache_attention as pca
+
+        return pca.latent_attention(
+            qt, qr, c_cache, kr_cache, lengths, rows, scale=scale,
+            interpret=(resolve(backend) == "pallas_interpret"),
+        )
+    import jax
+    import jax.numpy as jnp
+
+    from orion_tpu.ops.softmax_attention import _NEG
+
+    f32 = jnp.float32
+    c = c_cache.astype(f32)
+    s = jnp.einsum("bhc,bsc->bhs", qt.astype(f32), c)
+    s = s + jnp.einsum("bhr,bsr->bhs", qr.astype(f32), kr_cache.astype(f32))
+    valid = jnp.arange(c.shape[1])[None, None, :] < lengths[:, None, None]
+    s = jnp.where(valid, s * scale, _NEG)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    return jnp.einsum("bhs,bsc->bhc", jnp.exp(s - lse[..., None]), c), lse
+
+
 def _block_list_attention(q, k_cache, v_cache, lengths, rows, blocks, backend):
     lists, counts, size = blocks
     b, kvh, cap, d = k_cache.shape
@@ -454,6 +492,7 @@ __all__ = [
     "gated_delta_step",
     "default_backend",
     "gated_delta_rule",
+    "latent_cache_attention",
     "resolve",
     "resolve_chunk",
     "row_sparse",

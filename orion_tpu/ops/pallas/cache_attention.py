@@ -414,6 +414,150 @@ def block_attention(
     return out, lse[..., 0]
 
 
+# ---------------------------------------------------------------------------
+# Decode attention over a LATENT cache: every head's key is the cache row
+# ``[c | k_rope]`` and its value the same row's ``c`` (the latent layers'
+# absorbed step, models/mixers/latent.py).
+# ---------------------------------------------------------------------------
+
+# latent rows a grid step: one [512, 512 + 64] block serves all heads' scores
+# and, its first part again, their values
+LATENT_BLOCK = 512
+
+
+def latent_block(cap: int) -> int:
+    """:func:`kv_block` at :data:`LATENT_BLOCK` rows."""
+    if cap <= LATENT_BLOCK:
+        return cap
+    bk = math.gcd(cap, LATENT_BLOCK)
+    if bk % 16:
+        raise ValueError(f"a latent cache of {cap} rows does not tile by 16")
+    return bk
+
+
+def latent_rows_read(length: int, cap: int) -> int:
+    """:func:`rows_read` for the latent kernel's blocks."""
+    bk = latent_block(cap)
+    return min(cap, max(1, -(-length // bk)) * bk)
+
+
+def _latent_kernel(bk, nblk, idx_ref, len_ref, qt_ref, qr_ref, c_ref, kr_ref,
+                   o_in, lse_in, o_ref, lse_ref, m_scr, l_scr, acc_scr):
+    del o_in, lse_in  # aliased onto the outputs: what an unlisted row keeps
+    i, j = pl.program_id(0), pl.program_id(1)
+    length = len_ref[idx_ref[i]]
+    f32 = jnp.float32
+    nt = (((1,), (1,)), ((), ()))  # [H, X] x [bk, X] -> [H, bk]
+
+    @pl.when(j == 0)
+    def _():
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def block(partial):
+        c = c_ref[0]  # [bk, kv_rank]: the keys' first part AND the values
+        s = jax.lax.dot_general(qt_ref[0], c, nt, preferred_element_type=f32)
+        s = s + jax.lax.dot_general(qr_ref[0], kr_ref[0], nt, preferred_element_type=f32)
+        if partial:
+            at = j * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(at < length, s, _NEG)
+            at = j * bk + jax.lax.broadcasted_iota(jnp.int32, c.shape, 0)
+            c = jnp.where(at < length, c, jnp.zeros_like(c))
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
+            p.astype(c.dtype), c, preferred_element_type=f32
+        )
+        m_scr[...] = m_new
+
+    pl.when((j + 1) * bk <= length)(lambda: block(False))
+    pl.when((j * bk < length) & (length < (j + 1) * bk))(lambda: block(True))
+
+    @pl.when(j == nblk - 1)
+    def _():
+        l = l_scr[...]
+        safe = jnp.where(l == 0.0, 1.0, l)  # no live row: out 0, lse _NEG
+        o_ref[0] = acc_scr[...] / safe
+        lse_ref[0] = m_scr[...] + jnp.log(safe)
+
+
+def latent_attention(
+    qt: Array, qr: Array, c_cache: Array, kr_cache: Array, lengths: Array,
+    rows: Tuple[Array, Array], *, scale: float, interpret: bool = False,
+) -> Tuple[Array, Array]:
+    """qt ``[B, H, R]`` (the query with the key up-projection absorbed), qr
+    ``[B, H, Dr]`` (its rotary part); caches ``[B, cap, R]`` and ``[B, cap,
+    Dr]`` (one dtype); lengths ``[B]`` int32, each sequence's live rows; rows
+    = ``decode_state.live_rows`` of the row mask. Returns (u ``[B, H, R]``,
+    lse ``[B, H]``), fp32: for a listed row and each head the softmax of
+    ``scale (qt . c + qr . k_rope)`` over cache rows ``[0, length)`` applied
+    to ``c``, and its log-sum-exp; ``(0, -1e30)`` for a listed row of length
+    0 and for every unlisted row, whose cache is never read. All H heads are
+    the rows of ONE product a block: at 128 heads the MXU sees a [128, R + Dr]
+    x [R + Dr, 512] matmul and a [128, 512] x [512, R] one per 512 latent
+    rows, both operands in the cache's dtype (bf16 on the chip: one pass),
+    sums in fp32. Each live block is fetched once."""
+    idx, count = rows
+    b, cap, r = c_cache.shape
+    h, dr = qt.shape[1], qr.shape[-1]
+    shapes = (qt.shape, qr.shape, kr_cache.shape, lengths.shape, idx.shape)
+    if shapes != ((b, h, r), (b, h, dr), (b, cap, dr), (b,), (b,)):
+        raise ValueError(f"operands do not fit the latent {c_cache.shape}: {shapes}")
+    if c_cache.dtype != kr_cache.dtype:
+        raise ValueError(f"one cache dtype: {c_cache.dtype}/{kr_cache.dtype}")
+    bk = latent_block(cap)
+    nblk = cap // bk
+    f32 = jnp.float32
+    dt = c_cache.dtype
+    qt = (qt.astype(f32) * scale).astype(dt)
+    qr = (qr.astype(f32) * scale).astype(dt)
+
+    def row(i, j, idx, lens):
+        return (idx[i], 0, 0)
+
+    def kv(i, j, idx, lens):
+        r_ = idx[i]
+        last = jnp.maximum((lens[r_] + bk - 1) // bk - 1, 0)
+        return (r_, jnp.minimum(j, last), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(count[0], nblk),
+        in_specs=[
+            pl.BlockSpec((1, h, r), row), pl.BlockSpec((1, h, dr), row),
+            pl.BlockSpec((1, bk, r), kv), pl.BlockSpec((1, bk, dr), kv),
+            pl.BlockSpec((1, h, r), row), pl.BlockSpec((1, h, 1), row),
+        ],
+        out_specs=[pl.BlockSpec((1, h, r), row), pl.BlockSpec((1, h, 1), row)],
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), f32), pltpu.VMEM((h, 1), f32), pltpu.VMEM((h, r), f32),
+        ],
+    )
+    out, lse = pl.pallas_call(
+        functools.partial(_latent_kernel, bk, nblk),
+        name="latent_attention",
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, r), f32),
+            jax.ShapeDtypeStruct((b, h, 1), f32),
+        ],
+        # operand numbering counts the two scalar-prefetch lists: the
+        # unlisted rows' output and log-sum-exp are operands 6 and 7
+        input_output_aliases={6: 0, 7: 1},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_BYTES),
+        interpret=interpret,
+    )(
+        idx, lengths.astype(jnp.int32), qt, qr, c_cache, kr_cache,
+        jnp.zeros((b, h, r), f32), jnp.full((b, h, 1), _NEG, f32),
+    )
+    return out, lse[..., 0]
+
+
 __all__ = [
-    "BLOCK_KV", "block_attention", "cache_attention", "kv_block", "rows_read",
+    "BLOCK_KV", "LATENT_BLOCK", "block_attention", "cache_attention", "kv_block",
+    "latent_attention", "latent_block", "latent_rows_read", "rows_read",
 ]

@@ -231,6 +231,53 @@ def _gmm_bwd(tile_rows, block_h, interpret, res, dy):
 gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
+def gmm_live(
+    x: Array, w: Array, group_sizes: Array, tile_rows: int = 128,
+    block_h: int = 512, interpret: bool = False,
+) -> Array:
+    """:func:`gmm`'s forward over the row tiles that HOLD a segment only: the
+    grid's first dimension is ``sum(group_sizes) / tile_rows``, read at run
+    time, so a buffer sized for the worst router costs what the rows in it
+    cost (the serving path of ``models/moe.py::_dropless_held``). Rows past
+    the last segment are NOT written: the caller masks them. Row tiles are
+    the outer dimension (a dynamic bound leads the grid) and an x tile stays
+    put while its expert's output blocks sweep; with the one or two tiles an
+    expert has at serving sizes the weights are still read about once. No
+    backward."""
+    m, d = x.shape
+    _, _, h = w.shape
+    assert m % tile_rows == 0, (m, tile_rows)
+    te = tile_expert_table(group_sizes, m // tile_rows, tile_rows)
+    live = (jnp.sum(group_sizes) // tile_rows).astype(jnp.int32)
+    nh = -(-h // block_h)
+    hp = nh * block_h
+    wc = w.astype(x.dtype)
+    if hp != h:
+        wc = jnp.pad(wc, ((0, 0), (0, 0), (0, hp - h)))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(live, nh),
+        in_specs=[
+            pl.BlockSpec((tile_rows, d), lambda i, j, te: (i, 0)),
+            pl.BlockSpec((1, d, block_h), lambda i, j, te: (te[i], 0, j)),
+        ],
+        out_specs=pl.BlockSpec((tile_rows, block_h), lambda i, j, te: (i, j)),
+    )
+    out = pl.pallas_call(
+        _fwd_kernel,
+        name="gmm_live",
+        out_shape=jax.ShapeDtypeStruct((m, hp), x.dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_LIVE_VMEM_BYTES),
+        interpret=interpret,
+    )(te, x, wc)
+    return out[:, :h] if hp != h else out
+
+
+# [7,680, 512] bf16 weight blocks, double-buffered, pass the 16 MB default
+_LIVE_VMEM_BYTES = 64 << 20
+
+
 def pad_group_sizes(counts: Array, tile_rows: int) -> Tuple[Array, Array]:
     """(tile-aligned segment sizes, exclusive segment starts) for raw
     per-expert row counts."""
@@ -239,4 +286,4 @@ def pad_group_sizes(counts: Array, tile_rows: int) -> Tuple[Array, Array]:
     return seg.astype(jnp.int32), starts.astype(jnp.int32)
 
 
-__all__ = ["gmm", "pad_group_sizes", "tile_expert_table"]
+__all__ = ["gmm", "gmm_live", "pad_group_sizes", "tile_expert_table"]

@@ -148,6 +148,19 @@ def _slot_flags(states, done) -> Array:
 
 
 @jax.jit
+def _counted_flags(states, done, counted) -> Array:
+    """[2 slots + 4] int32: the finite mask, the done flags and the
+    boundary's MoE row counters (``counted``: the [4] vectors its programs
+    returned, ``models/moe.py::STAT_NAMES``, summed here) — a ``cfg.moe_held``
+    model's whole host readback, still ONE device transfer a boundary."""
+    return jnp.concatenate([
+        decode_state_finite_per_slot(states).astype(jnp.int32),
+        done.astype(jnp.int32),
+        sum(counted),
+    ])
+
+
+@jax.jit
 def _spec_flags(states, done, accepted) -> Array:
     """[3, slots] int32: the speculative boundary's whole host readback —
     finite mask, done flags, AND per-slot accepted-draft counts — still
@@ -323,14 +336,23 @@ def tree_nbytes(tree) -> int:
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
 
+# the share of a device's memory left to the boundary programs' own
+# temporaries when the engine decides whether the carry fits twice: they
+# took 0.8 to 2.3 GB of a v5e's 16.9 in the served configurations' compiles
+# (tests/test_chip_compile.py), and a carry that fits twice by 0.3 GB beside
+# the weights leaves them nothing (PERF.md section 6, PR 43)
+PROGRAM_RESERVE = 0.125
+
+
 def fits_once_only(carry, params, device) -> bool:
     """Does ``device`` hold the carry once beside the weights, but not
-    twice? From its ``memory_stats()["bytes_limit"]``; False where the
-    backend reports none. Arrays or their shapes."""
+    twice with :data:`PROGRAM_RESERVE` of it left for the programs? From its
+    ``memory_stats()["bytes_limit"]``; False where the backend reports
+    none. Arrays or their shapes."""
     limit = (device.memory_stats() or {}).get("bytes_limit")
     if not limit:
         return False
-    return 2 * tree_nbytes(carry) + tree_nbytes(params) > limit
+    return 2 * tree_nbytes(carry) + tree_nbytes(params) > (1 - PROGRAM_RESERVE) * limit
 
 
 def parse_buckets(spec: str, max_seq_len: int) -> Tuple[int, ...]:
@@ -558,6 +580,13 @@ class SlotEngine:
         # prefill, never correctness.
         self.max_pending_prefixes = 32
         self.dropped_prefixes = 0  # lifetime counted drops
+        # a ``cfg.moe_held`` model: the MoE row counters of the boundary just
+        # probed ([routed, held, busiest expert's, dropped], summed over its
+        # pieces, steps and layers; ``models/moe.py::STAT_NAMES``), read with
+        # the probe's own transfer; zeros for every other model
+        self.moe_rows = np.zeros((4,), np.int64)
+        self._moe_counted: Tuple[Array, ...] = ()
+        self._moe_zero: Optional[Array] = None
         self._sample: Optional[SampleConfig] = None  # set by first admit
         self._slots: List[Optional[_Slot]] = [None] * self.slots
         self._chunk_counter = 0  # global boundary index (serve.chunk hook)
@@ -583,8 +612,8 @@ class SlotEngine:
         )
         # what that decision weighed (host metadata of the two trees)
         kv_bytes = sum(
-            tree_nbytes([st[n] for n in ("k", "v") if n in st])
-            for st in self._carry[1]
+            tree_nbytes([st[n] for n in MIXERS[lt].cache_leaves])
+            for lt, st in zip(cfg.resolved_layer_types, self._carry[1])
         )
         self.held_bytes = {
             "carry_bytes": tree_nbytes(self._carry),
@@ -804,16 +833,19 @@ class SlotEngine:
         mirror of positions (prompt consumed + tokens emitted), no
         readback. ``read`` is what a layer's decode attention streams a
         step at the boundary about to run: every slot's reservation in
-        the XLA form; under a row-list backend
-        (``ops.dispatch.cache_attention``) the live KV blocks of each slot
-        that will emit, at the position its last step attends from (for a
-        ``block_sparse`` layer the blocks its list holds, :meth:`kv_blocks`),
-        and nothing for the others. (0, 0, 0) for a model without a cached
-        layer."""
+        the XLA form; under a row-list backend what the layer's class says
+        its kernel streams (``Mixer.cache_rows_read``: the live cache blocks
+        of each slot that will emit, at the position its last step attends
+        from; the blocks a ``block_sparse`` layer's list holds, :meth:
+        `kv_blocks`; a latent layer's live latent blocks) and nothing for
+        the others. (0, 0, 0) for a model without a cached layer."""
         cfg = self.model.cfg
-        kinds = set(cfg.resolved_layer_types)
-        cap = (cfg.max_seq_len if kinds & {"softmax", "block_sparse"}
-               else cfg.window if "swa" in kinds else 0)
+        # the cached layer with the longest reservation, asked of its class
+        cap, lt = max(
+            ((MIXERS[lt].cache_rows(cfg, lt), lt)
+             for lt in dict.fromkeys(cfg.resolved_layer_types)),
+            key=lambda c: c[0],
+        )
         ends = self._slot_ends()
         live = sum(
             min(cap, end - self._slots[i].prompt_remaining)
@@ -823,16 +855,26 @@ class SlotEngine:
         # the donated scan reads the cache as it stood at the scan's start;
         # the scan that carries the cache reads the rows it wrote too
         grown = 0 if self.donate_carry else self.chunk
-        if "softmax" in kinds and row_sparse(cfg.backend):
-            from orion_tpu.ops.pallas.cache_attention import rows_read
-
+        if (cap and row_sparse(cfg.backend)
+                and MIXERS[lt].cache_rows_read(cfg, lt, 0) is not None):
             read = sum(
-                rows_read(min(cap, end + grown), cap)
+                MIXERS[lt].cache_rows_read(cfg, lt, min(cap, end + grown))
                 for end in self._emitting_ends(ends)
             )
-        elif "block_sparse" in kinds and row_sparse(cfg.backend):
-            read = cfg.sparse_block * self.kv_blocks()[1]
         return live, cap * self.slots, read
+
+    def kv_rows_attended(self) -> int:
+        """The live cache rows the boundary about to run attends FROM a step,
+        summed over the slots that will emit and counted to the row (no
+        block rounding): what :meth:`kv_rows`' ``read`` would be if a kernel
+        fetched not one row past a slot's position."""
+        cfg = self.model.cfg
+        cap = max(MIXERS[lt].cache_rows(cfg, lt) for lt in cfg.resolved_layer_types)
+        grown = 0 if self.donate_carry else self.chunk
+        return sum(
+            min(cap, end + grown)
+            for end in self._emitting_ends(self._slot_ends())
+        )
 
     def _state_writes_per_chunk(self) -> int:
         """How many times ONE ``linear`` layer writes an emitting slot's
@@ -1356,6 +1398,7 @@ class SlotEngine:
         inject.fire("serve.chunk", step=self._chunk_counter)
         finished: List[Tuple[Any, DecodeResult]] = []
         self.last_boundary = []
+        self.moe_rows = np.zeros((4,), np.int64)
         # deadlines are checked BEFORE paying for the chunk, like the solo
         # session's boundary check
         now = self._clock()
@@ -1619,10 +1662,14 @@ class SlotEngine:
         # undonated programs only.
         warm = None if donate else self._warm_boundary_exec(kind, seen_key)
         accepted = None
+        # a ``cfg.moe_held`` model's programs also return their MoE row
+        # counters, [4] on the device (one vector, or the donated
+        # boundary's tuple of them): ``_probe_bad`` reads them
+        counted = ()
         try:
             if donate:
                 live = np.array([s is not None for s in self._slots])
-                out, toks = decode_boundary_donated(
+                out, toks, *counted = decode_boundary_donated(
                     self.model, self.params, carry, self._rngs, active_dev,
                     self._pbuf, self._plen, self._pfold,
                     tuple(self._selected_prefill_slots(live))
@@ -1645,23 +1692,23 @@ class SlotEngine:
                     np.int32,
                 ))
                 if warm is not None:
-                    out, toks = warm(
+                    out, toks, *counted = warm(
                         self.params, carry, self._rngs, active_dev,
                         self._pbuf, self._plen, self._pfold, pwait,
                     )
                 else:
-                    out, toks = decode_batched_prefill_chunk(
+                    out, toks, *counted = decode_batched_prefill_chunk(
                         self.model, self.params, carry, self._rngs, active_dev,
                         self._pbuf, self._plen, self._pfold, pwait, self.chunk,
                         self.prefill_chunk, self._sample,
                     )
             else:
                 if warm is not None:
-                    out, toks = warm(
+                    out, toks, *counted = warm(
                         self.params, carry, self._rngs, active_dev
                     )
                 else:
-                    out, toks = decode_batched_chunk(
+                    out, toks, *counted = decode_batched_chunk(
                         self.model, self.params, carry, self._rngs, active_dev,
                         self.chunk, self._sample,
                     )
@@ -1677,6 +1724,8 @@ class SlotEngine:
                     inject.decode_nan_armed(slot.chunks)
                 ):
                     out = self._poison_slot(out, i)
+        if counted:
+            self._moe_counted = counted[0] if donate else tuple(counted)
         return out, toks, accepted
 
     @staticmethod
@@ -1698,8 +1747,23 @@ class SlotEngine:
         done row is stashed for the eviction pass. At a speculative
         boundary the per-slot accepted counts ride the SAME transfer
         ([3, slots] int32 instead of [2, slots] bool) — the accept/
-        reject decision never costs a second readback."""
-        if accepted is None:
+        reject decision never costs a second readback. So do a
+        ``cfg.moe_held`` model's MoE row counters (``moe_rows``: the
+        boundary's [routed, held, busiest expert's, dropped], the programs'
+        vectors padded to one length so that one program sums them)."""
+        if self._moe_counted:
+            pad = 1 + prefill_piece_cap(self.slots, self.chunk) - len(self._moe_counted)
+            if self._moe_zero is None:
+                self._moe_zero = jnp.zeros((4,), jnp.int32)
+            flags = np.asarray(_counted_flags(
+                carry[1], carry[4], self._moe_counted + (self._moe_zero,) * pad
+            ))
+            self._moe_counted = ()
+            self.moe_rows = flags[2 * self.slots:]
+            self._done_np = flags[self.slots:2 * self.slots].astype(bool)
+            self._accept_np = None
+            finite = flags[:self.slots].astype(bool)
+        elif accepted is None:
             flags = np.asarray(_slot_flags(carry[1], carry[4]))
             self._done_np = flags[1]
             self._accept_np = None

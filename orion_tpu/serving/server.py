@@ -130,6 +130,10 @@ _KV_ROW_KEYS = (
     "kv_rows_live", "kv_rows_reserved", "kv_rows_read", "slot_steps_emitting",
     "state_row_writes",
 )
+# the emitting slots' live cache rows counted to the row, without the
+# kernel's block rounding (``SlotEngine.kv_rows_attended``): the work a
+# decode attention kernel cannot avoid, which its roofline share counts
+_KV_ATTENDED_KEYS = ("kv_rows_attended",)
 # the engine's staging dispatches (``SlotEngine.staging_dispatches``) since
 # the last boundary: admit_dispatches / chunks is device calls of admission
 # a boundary, at most one while no boundary admits more than
@@ -147,6 +151,15 @@ _KV_BLOCK_KEYS = (
 # slots that emitted x the scan's steps: a listed row steps at every one) and
 # the real prompt rows their chunked scan consumed
 _SSM_KEYS = ("ssm_row_steps", "ssm_piece_rows")
+# the held-experts layers' rows at each boundary, summed on the device over
+# its pieces, decode steps and layers and read with the probe's transfer
+# (``SlotEngine.moe_rows``; 0 for a model without such a layer): (token,
+# expert) pairs the router sent out for rows that count / those whose expert
+# is held here / the busiest held expert's, a layer and call / rows dropped
+# past the buffer (0 by construction where the buffer holds every pair)
+_MOE_KEYS = (
+    "moe_rows_routed", "moe_rows_held", "moe_rows_max_expert", "moe_rows_dropped",
+)
 
 
 class OverloadError(RuntimeError):
@@ -458,7 +471,8 @@ class Server:
         # obs-device-sync + the cache-stat asserts in tests/test_obs.py)
         self.metrics = MetricsRegistry(clock=clock, lock=self._stats_lock)
         for key in (_STAT_KEYS + _SLOT_CLASS_KEYS + _KV_ROW_KEYS
-                    + _KV_BLOCK_KEYS + _ADMIT_KEYS + _SSM_KEYS):
+                    + _KV_BLOCK_KEYS + _ADMIT_KEYS + _SSM_KEYS + _MOE_KEYS
+                    + _KV_ATTENDED_KEYS):
             self.metrics.counter(key)  # the legacy stats dict's cells
         # what jax built while this server lived (obs/trace.py
         # ``compile_event``), by stage; the open ``setup.first_launch``
@@ -2090,6 +2104,7 @@ class Server:
             self._profile_maybe_start()
         occupied = self.engine.active_count
         kv_rows = self.engine.kv_rows()
+        kv_attended = self.engine.kv_rows_attended()
         kv_blocks = self.engine.kv_blocks()
         infos = self.engine.slot_info() if self.trace.enabled else ()
         t0 = self._clock()
@@ -2152,6 +2167,9 @@ class Server:
                 ssm = kinds.count("ssm")
                 self._bump("ssm_row_steps", emitting * self.engine.chunk * ssm)
                 self._bump("ssm_piece_rows", piece_rows * ssm)
+                self._bump("kv_rows_attended", kv_attended)
+                for key, n in zip(_MOE_KEYS, self.engine.moe_rows):
+                    self._bump(key, int(n))
                 layers = kinds.count("block_sparse")
                 live, read, sparse, dense = kv_blocks
                 for key, n in zip(

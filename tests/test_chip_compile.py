@@ -176,6 +176,25 @@ def _ssm_step(s, x, dt, a, bm, cm, live):
     return ssm_state_step(x, dt, a, bm, cm, s, 2, live_rows(live))
 
 
+def _latent_attention(qt, qr, c, kr, lengths, live):
+    from orion_tpu.ops.pallas.cache_attention import latent_attention
+    from orion_tpu.ops.pallas.decode_state import live_rows
+
+    return latent_attention(qt, qr, c, kr, lengths, live_rows(live), scale=192 ** -0.5)
+
+
+def _gmm_live(x, w, sizes):
+    from orion_tpu.ops.pallas.gmm import gmm_live
+
+    return gmm_live(x, w, sizes, tile_rows=128, block_h=512)
+
+
+def _latent_flash(q, k, v):
+    from orion_tpu.ops.pallas.flash_attention import flash_attention_lse
+
+    return flash_attention_lse(q, k, v, causal=True, scale=192 ** -0.5)
+
+
 def _short_conv(x, w):
     from orion_tpu.ops.dispatch import causal_short_conv
 
@@ -243,6 +262,20 @@ _SSM_STATE = [((64, 32, 128, 128), jnp.float32), ((64, 64, 64), jnp.bfloat16),
 _KV_GROUPED = [((64, 32, 64), jnp.bfloat16),
                *[((64, 8, 2048, 64), jnp.bfloat16)] * 2,
                ((64,), jnp.int32), ((64,), jnp.bool_)]
+# openpangu_ultra_moe_718b served (128 slots x 4,608): a token's absorbed
+# 128-head query (512 + 64 wide) a slot over its held latent cache; the held
+# rows' buffer of a decode step (1,024 pairs + a tile an expert) through 16
+# experts of 7,680 x 2,048 and back; one 1,024-token piece's expanded
+# attention at q / k width 256 (192 + the mask column, padded) and v 128
+_LATENT = [((128, 128, 512), jnp.bfloat16), ((128, 128, 64), jnp.bfloat16),
+           ((128, 4608, 512), jnp.bfloat16), ((128, 4608, 64), jnp.bfloat16),
+           ((128,), jnp.int32), ((128,), jnp.bool_)]
+_GMM_LIVE_UP = [((3072, 7680), jnp.bfloat16), ((16, 7680, 2048), jnp.bfloat16),
+                ((16,), jnp.int32)]
+_GMM_LIVE_DOWN = [((3072, 2048), jnp.bfloat16), ((16, 2048, 7680), jnp.bfloat16),
+                  ((16,), jnp.int32)]
+_LATENT_PIECE = [*[((1, 128, 1024, 256), jnp.bfloat16)] * 2,
+                 ((1, 128, 1024, 128), jnp.bfloat16)]
 # moe_1b3_4e dropless: 24576 padded rows through 4 experts of 2048 x 5504
 _GMM = [((24576, 2048), jnp.bfloat16), ((4, 2048, 5504), jnp.bfloat16),
         ((4,), jnp.int32)]
@@ -290,6 +323,10 @@ KERNELS = [
     pytest.param(_block_attention, _BLOCK_LIST, id="block_attention-32slots-128blocks"),
     pytest.param(_ssm_step, _SSM_STATE, id="ssm_state_step-64slots"),
     pytest.param(_cache_attention, _KV_GROUPED, id="cache_attention-grouped-64slots"),
+    pytest.param(_latent_attention, _LATENT, id="latent_attention-128slots"),
+    pytest.param(_gmm_live, _GMM_LIVE_UP, id="gmm_live-held16-7680x2048"),
+    pytest.param(_gmm_live, _GMM_LIVE_DOWN, id="gmm_live-held16-2048x7680"),
+    pytest.param(_latent_flash, _LATENT_PIECE, id="flash-latent-piece1024-d256-v128"),
     # -- the rest of the main path's kernels ---------------------------------
     pytest.param(_plain, _QKV, id="causal_dot-plain-fwd", marks=slow),
     pytest.param(_grad3(_plain), _QKV, id="causal_dot-plain-bwd", marks=slow),
@@ -491,6 +528,51 @@ def test_granite_boundary_programs_hold_the_carry_once(v5e):
         if name == "scan":
             text = compiled.as_text()
             assert "ssm_state_step" in text and "cache_attention" in text
+
+
+@slow
+def test_openpangu_boundary_programs_hold_the_carry_once(v5e):
+    """``openpangu_ultra_moe_718b.serve_batch``'s programs at 128 slots x
+    4,608 for the chip, the carry donated: one slot's 1,024-token prompt
+    piece and the decode scan. Each fits 16 GB with its arguments (weights
+    9.84 GB + the latent cache 3.40 GB as counted) and aliases the carry;
+    the scan attends through the latent kernel and both run the held experts
+    through the grouped product over live tiles. A compile, not a chip run."""
+    from orion_tpu import generate as gen
+    from orion_tpu.generate import SampleConfig
+    from orion_tpu.models.configs import get_config
+    from orion_tpu.models.transformer import TransformerLM, init_decode_state
+
+    slots, chunk, piece, width = 128, 8, 1024, 4096
+    cfg = dataclasses.replace(get_config("openpangu_ultra_moe_718b"), backend="pallas")
+    model = TransformerLM(cfg)
+    one = SingleDeviceSharding(v5e[0])
+    put = lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one)  # noqa: E731
+    arr = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    params = jax.tree.map(put, jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 16), jnp.int32))))
+    assert sum(l.size for l in jax.tree.leaves(params)) == 4919139840
+    states = jax.tree.map(put, jax.eval_shape(lambda: init_decode_state(cfg, slots)))
+    ints, flags = arr((slots,), jnp.int32), arr((slots,), jnp.bool_)
+    carry = (ints, states, ints, ints, flags)
+    rngs, pbuf = arr((slots, 2), jnp.uint32), arr((slots, width), jnp.int32)
+    scalar, sample = arr((), jnp.int32), SampleConfig(temperature=0.0)
+    programs = {
+        "piece": gen._prefill_piece_donated_jit.lower(
+            model, params, carry, rngs, pbuf, ints, ints, scalar, piece, sample),
+        "scan": gen._decode_scan_donated_jit.lower(
+            model, params, carry, rngs, flags, ints, chunk, sample),
+    }
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        m = compiled.memory_analysis()
+        live = (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes)
+        assert live < 16e9, (name, live)
+        assert m.alias_size_in_bytes > 3.3e9, (name, m.alias_size_in_bytes)
+        text = compiled.as_text()
+        assert "gmm_live" in text, name
+        assert ("latent_attention" if name == "scan" else "flash_attn_fwd") in text
 
 
 def test_minicpm_sala_boundary_programs_compile_and_fit(v5e):

@@ -18,7 +18,8 @@ from orion_tpu.models.configs import LAYER_TYPES, get_config, hybrid_pattern
 from orion_tpu.models.mixers import MIXERS, Mixer
 from orion_tpu.models.transformer import TransformerLM, init_decode_state
 
-SERVED = ("linear", "softmax", "swa", "gated_delta", "decay_linear", "block_sparse", "ssm")
+SERVED = ("linear", "softmax", "swa", "gated_delta", "decay_linear", "block_sparse", "ssm",
+          "latent")
 TRAIN_ONLY = ("gated_softmax",)
 
 # benchmark/configs/qwen3_next_80b.json's ``rehearse`` sizes
@@ -38,6 +39,8 @@ def one_layer(lt):
         n_kv_heads=2, sparse_kernel=4, sparse_stride=2, sparse_block=8,
         sparse_window=8, sparse_topk=2, sparse_dense_len=16,
         ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=2,
+        latent_q_rank=16, latent_kv_rank=8, latent_nope_dim=8, latent_rope_dim=4,
+        latent_value_dim=8,
     )
 
 
@@ -45,7 +48,8 @@ def test_registry_has_one_mixer_per_layer_type():
     assert set(MIXERS) == set(LAYER_TYPES)
     assert all(issubclass(m, Mixer) for m in MIXERS.values())
     assert {lt for lt, m in MIXERS.items() if m.rows_in_place} == {
-        "linear", "softmax", "swa", "gated_delta", "decay_linear", "block_sparse", "ssm"
+        "linear", "softmax", "swa", "gated_delta", "decay_linear", "block_sparse", "ssm",
+        "latent",
     }
 
 

@@ -71,6 +71,17 @@ SAMPLED = SampleConfig(temperature=0.8, top_k=5, top_p=0.9, eos_token=3,
 BUCKETS = (8, 16, 32)
 
 
+@pytest.fixture(scope="module", autouse=True)
+def release_compiled_programs():
+    """This file leaves ~53,600 memory maps in its worker process (every
+    loaded XLA:CPU executable holds a few), and a worker that then runs
+    another engine-heavy file reaches ``vm.max_map_count`` (65,530), where the
+    next compile segfaults (PERF.md section 7, PR 43). Drop the programs when
+    the file is done."""
+    yield
+    jax.clear_caches()
+
+
 @pytest.fixture(scope="module")
 def mp():
     model = TransformerLM(CFG)
