@@ -180,6 +180,13 @@ PROGRAMS: Tuple[ProgramDecl, ...] = (
              "per (model, params) at engine construction",
     ),
     ProgramDecl(
+        "cast_serving_params", GENERATE, "_cast_leaves_jit", "setup",
+        static_args=("model",),
+        note="the serving tree's one cast program (serving_params): every "
+             "matmul weight held wider than the compute dtype, cast once "
+             "at Server construction; never built for a bf16 tree",
+    ),
+    ProgramDecl(
         "prefill_extend_row", GENERATE, "_prefill_extend_row", "setup",
         static_args=("model", "pchunk"),
         note="never an executable of its own: called only while "

@@ -157,9 +157,10 @@ def _decode_abstracts(model_cfg, slots: int, qmode: str, tp: int):
     """Abstract (model, params, carry, rngs, active, shaped) for lowering
     the serving decode programs — shared by :func:`decode_plan` and
     :func:`decode_cost_entries` so the two can never key off different
-    shapes. With ``tp > 1`` everything carries the serving mesh's
-    NamedShardings (params by the training rules, state head-sharded,
-    per-slot vectors replicated)."""
+    shapes. The params are the SERVING tree's (matmul weights in the
+    compute dtype, as ``Server`` casts them once at set-up). With ``tp > 1``
+    everything carries the serving mesh's NamedShardings (params by the
+    training rules, state head-sharded, per-slot vectors replicated)."""
     import jax
     import jax.numpy as jnp
 
@@ -176,6 +177,12 @@ def _decode_abstracts(model_cfg, slots: int, qmode: str, tp: int):
 
     prompt = jax.ShapeDtypeStruct((1, 8), jnp.int32)
     abstract = jax.eval_shape(model.init, jax.random.PRNGKey(0), prompt)
+    # the tree a Server hands its programs: the matmul weights already in
+    # the compute dtype (generate.serving_params), so plans, store keys and
+    # the cost harvest describe the programs that are run
+    from orion_tpu.generate import serving_params
+
+    abstract = serving_params(model, abstract)
     states = jax.eval_shape(lambda: init_decode_state(model_cfg, slots))
     if mesh is not None:
         from orion_tpu.parallel.decode import (

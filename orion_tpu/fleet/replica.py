@@ -901,6 +901,7 @@ def _child_main() -> int:
 
     model, params, params_id = build_model(spec)
     server = Server(model, params, serve_config(spec, params_id=params_id))
+    del params  # the server holds its own serving tree; free the loaded one
     watchers: List[threading.Thread] = []
 
     def watch(rid: int, pending) -> None:

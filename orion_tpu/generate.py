@@ -1075,22 +1075,127 @@ def generate_chunked(
     return jnp.concatenate(out, axis=1)
 
 
-def cast_params_for_inference(model: TransformerLM, params: Any) -> Any:
-    """fp32 master params -> the model's compute dtype (bf16 on the big
-    configs), halving weight HBM — what lets a bigger model or batch fit a
-    chip. NOT a latency win here: measured on the v5e (1.3B, prefill 512),
-    bf16 weights DECODE SLOWER than fp32 (b1: 10.2 vs 7.3 ms/tok; b8: 15.8
-    vs 10.2) — the per-token matvecs leave the MXU underfed and the fp32
-    VPU path streams better. Hence generate(cast_params=False) by default;
-    flip it on when memory, not latency, is the constraint."""
+def _compute_dtype_modules(model: TransformerLM, params: Any) -> set:
+    """The ``nn.Dense`` / ``DenseGeneral`` / ``Einsum`` modules a decode
+    step calls whose ``dtype`` is the model's compute dtype, by module path:
+    flax's ``promote_dtype`` casts their parameters to it before the
+    contraction. Seen, not named: one ``jax.eval_shape`` of ``decode_step``
+    under ``nn.intercept_methods``; a projection whose ``dtype`` is fp32
+    (the MoE router) is not in it, nor a parameter read by hand. Blocks
+    that are the same module but for their name (24 of ``lm_1b3``'s 24) are
+    observed ONCE: the others' steps are skipped and given the first one's
+    paths under their own name."""
+    import flax.linen as nn
+
+    from orion_tpu.models.transformer import Block, _dtype
+
+    cdt = jnp.dtype(_dtype(model.cfg.dtype))
+    seen, blocks = set(), {}
+
+    def watch(call, args, kwargs, context):
+        m = context.module
+        if isinstance(m, Block) and context.method_name == "decode_step":
+            kind = (type(m),) + tuple(
+                getattr(m, f.name) for f in dataclasses.fields(m)
+                if f.name not in ("name", "parent")
+            )
+            same = blocks.setdefault(kind, [])
+            same.append(tuple(m.path))
+            if len(same) > 1:
+                return args[:2]  # (x, state): a block's step keeps both shapes
+        elif (
+            context.method_name == "__call__"
+            and isinstance(m, (nn.Dense, nn.DenseGeneral, nn.Einsum))
+            and m.dtype is not None
+            and jnp.dtype(m.dtype) == cdt
+        ):
+            seen.add(tuple(m.path))
+        return call(*args, **kwargs)
+
+    def step(variables):
+        one = jnp.zeros((1,), jnp.int32)
+        states = init_decode_state(model.cfg, 1)
+        return model.apply(variables, one, states, one[0], method="decode_step")
+
+    abstract = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params
+    )
+    with nn.intercept_methods(watch):
+        jax.eval_shape(step, abstract)
+    for first, *others in blocks.values():
+        inside = [p[len(first):] for p in seen if p[:len(first)] == first]
+        seen.update(other + rest for other in others for rest in inside)
+    return seen
+
+
+@partial(jax.jit, static_argnums=(0,))
+def _cast_leaves_jit(model: TransformerLM, leaves):
     from orion_tpu.models.transformer import _dtype
 
-    cdt = _dtype(model.cfg.dtype)
-    if cdt == jnp.float32:
+    return [x.astype(_dtype(model.cfg.dtype)) for x in leaves]
+
+
+def serving_params(model: TransformerLM, params: Any) -> Any:
+    """The tree the serving programs are handed: ``params`` with every leaf
+    that ITS OWN MODULE would cast to a narrower compute dtype before its
+    first arithmetic use (:func:`_compute_dtype_modules`) replaced by that
+    cast, made once, in one jitted program. The programs then hold no cast
+    of it and compute the same values: ``promote_dtype`` does exactly this
+    cast inside them otherwise, once a CALL (XLA hoists it out of the step
+    scan: 169 converts in ``lm_1b3``'s ENTRY, 7.3 GB and 10.6 ms a decode
+    boundary on one v5e; the 16 steps themselves stream bf16 copies either
+    way, 59.56 ms over fp32 parameters and 58.96 over the copy made
+    beforehand; PERF.md section 6, PR 44).
+
+    Everything else comes back as handed in, the same objects: norm scales,
+    embedding and position tables, decay parameters, fp32 projections,
+    parameters a module reads by hand. A tree with no matmul weight to cast
+    (bf16 parameters, a quantized model, fp32 compute) comes back ``is`` the
+    same and traces nothing. Arrays or their ``ShapeDtypeStruct``s
+    (``aot.py`` keys and lowers the programs on the latter).
+
+    The answers are the handed tree's to the bit on the CPU
+    (``tests/test_serving_weights.py``) and, on the TPU, for a request
+    served alone (every layer's state at every boundary, 513 ids) and for
+    every module's output of a prompt piece. Among 48 co-resident requests
+    4 answer a near-tie differently (one bf16 ulp of a logit, downstream of
+    every state update, inside a ``unified_prefill`` call that XLA compiles
+    differently around bf16 arguments): the gap to the fp32 reference is the
+    same on both sides."""
+    from orion_tpu.models.transformer import _dtype
+
+    if model.quant:
         return params
-    return jax.tree.map(
-        lambda x: x.astype(cdt) if x.dtype == jnp.float32 else x, params
-    )
+    cdt = jnp.dtype(_dtype(model.cfg.dtype))
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    # a contraction's weight has two axes or more: a bf16 tree's fp32 leaves
+    # are vectors (norm scales, decay parameters), and it pays no abstract step
+    wide = [
+        i for i, (_, x) in enumerate(flat)
+        if x.ndim >= 2 and x.dtype.itemsize > cdt.itemsize
+    ]
+    if not wide:
+        return params
+    modules = _compute_dtype_modules(model, params)
+    picked = [
+        i for i in wide
+        if tuple(getattr(k, "key", None) for k in flat[i][0][1:-1]) in modules
+    ]
+    if not picked:
+        return params
+    leaves = [x for _, x in flat]
+    if isinstance(leaves[picked[0]], jax.ShapeDtypeStruct):
+        cast = [
+            jax.ShapeDtypeStruct(
+                leaves[i].shape, cdt, sharding=leaves[i].sharding
+            )
+            for i in picked
+        ]
+    else:
+        cast = _cast_leaves_jit(model, [leaves[i] for i in picked])
+    for i, y in zip(picked, cast):
+        leaves[i] = y
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 def quantize_for_decode(model: TransformerLM, params: Any, mode: str = "int8"):
@@ -1139,6 +1244,9 @@ def generate(
 
     ``quant="int8"``: quantize weights for this call (for repeated serving,
     call :func:`quantize_for_decode` once and pass its results instead).
+    ``cast_params``: decode from :func:`serving_params`'s tree (the matmul
+    weights in the compute dtype: half their bytes for an fp32 master, the
+    same answers); a caller that decodes more than once casts once itself.
 
     ``mesh``: decode over a device mesh (SURVEY.md P1–P4 applied to
     inference). Params are placed by the training sharding rules (fsdp
@@ -1189,11 +1297,10 @@ def generate(
                 f"model is already quantized as {model.quant!r}; "
                 f"requested quant={quant!r}"
             )
-    if cast_params and not (quant or model.quant):
-        # quantized trees are already minimal, and blanket-casting would
-        # round the fp32 *_s scale vectors to bf16, breaking the exact
-        # per-out-channel dequant contract for no memory win
-        params = cast_params_for_inference(model, params)
+    if cast_params:
+        # a quantized tree comes back as it is: already minimal, and its
+        # fp32 *_s scale vectors are the exact per-out-channel dequant
+        params = serving_params(model, params)
     if mesh is not None:
         from orion_tpu.parallel.sharding import (
             batch_sharding,

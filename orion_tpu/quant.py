@@ -2,10 +2,10 @@
 
 Decode at 1.3B is weight-HBM-bound: every step streams all 5.1GB of fp32
 weights, and the measured 7.16 ms/tok sits at ~87% of the v5e HBM roofline
-(BASELINE.md decode tables). Casting params to bf16 made decode SLOWER
-(generate.py::cast_params_for_inference) — the dot's lowering changed, not
-just its bytes. This module quarters the weight stream WITHOUT touching the
-dot's lowering:
+(BASELINE.md decode tables). Casting params to bf16 made decode SLOWER in
+rounds 1-5 (another jax and compiler; not so since PR 44:
+generate.py::serving_params). This module quarters the weight stream
+WITHOUT touching the dot's lowering:
 
 - weights are **stored int8** with per-out-channel symmetric scales
   (``q = round(w / s)``, ``s = max|w| / 127`` over the input axis);

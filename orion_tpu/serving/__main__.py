@@ -364,6 +364,10 @@ def _run(args, guard) -> int:
             profile_dir=args.profile_dir,
         ),
     )
+    # the server holds its own serving tree (quantized, or the matmul
+    # weights cast to the compute dtype): nothing here reads the loaded
+    # tree again, so its arrays are freed
+    del params
     if server.mesh_info is not None:
         print(
             f"tp mesh: tp={server.mesh_info['tp']} "

@@ -428,6 +428,30 @@ class Server:
                              chunk=cfg.chunk):
             self._build(model, params, cfg, clock, flight)
 
+    def _cast_once(self, model, params):
+        """Unquantized serving: the matmul weights in the dtype they are
+        multiplied in, cast ONCE at set-up and not once a call inside every
+        boundary program (``generate.serving_params``). Returns the tree
+        the engine serves and the bytes of the handed leaves it re-holds.
+        The server keeps no reference to the handed tree: whether it lives
+        on is the caller's business."""
+        import jax
+
+        from orion_tpu import generate as _gen
+        from orion_tpu.serving.batching import tree_nbytes
+
+        with self.trace.span("setup.cast", "setup") as cast:
+            t0 = self._clock()
+            served = _gen.serving_params(model, params)
+            recast = [
+                (a, b) for a, b in zip(jax.tree.leaves(params),
+                                       jax.tree.leaves(served)) if a is not b
+            ]
+            jax.block_until_ready([b for _, b in recast])
+            cast.note(leaves=len(recast),
+                      seconds=round(self._clock() - t0, 6))
+        return served, tree_nbytes([a for a, _ in recast])
+
     def _build(self, model, params, cfg, clock, flight) -> None:
         from orion_tpu import generate as _gen
         from orion_tpu.serving.batching import SlotEngine, parse_buckets
@@ -444,11 +468,14 @@ class Server:
             raise ValueError(
                 f"qmode must be one of off|int8|int4, got {cfg.qmode!r}"
             )
+        cast_bytes = 0
         if self.qmode != "off":
             with self.trace.span("setup.quantize", "setup", qmode=self.qmode):
                 model, params = _gen.quantize_for_decode(
                     model, params, mode=self.qmode
                 )
+        else:
+            params, cast_bytes = self._cast_once(model, params)
         # the weights' identity stamps BOTH stores: prefix entries are
         # keyed by it (content addressing) and session generations carry
         # it (a suspended state resumed under different weights or qmode
@@ -552,7 +579,11 @@ class Server:
                 mesh=self.mesh,
             )
             built.note(donate_carry=self.engine.donate_carry,
+                       params_cast_bytes=cast_bytes,
                        **self.engine.held_bytes)
+        self.metrics.gauge("params_bytes_held").set(
+            self.engine.held_bytes["params_bytes"]
+        )
         # the engine's staging_dispatches as of the last boundary
         self._staged_seen = 0
         # self-speculation telemetry (ISSUE 13): totals for the SLO
