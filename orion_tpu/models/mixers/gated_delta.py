@@ -36,11 +36,12 @@ import jax.numpy as jnp
 
 from orion_tpu.models.configs import ModelConfig
 from orion_tpu.models.mixers import (
-    NORM_EPS, Mixer, State, _dense_factory, _dtype, _rms, kernel_bh,
+    NORM_EPS, Mixer, State, _dense_factory, _dtype, kernel_bh,
     whole_array_backend,
 )
 from orion_tpu.ops.dispatch import (
     causal_short_conv, decode_rows_mask, gated_delta_rule, gated_delta_step,
+    gated_rms_norm,
 )
 from orion_tpu.utils.profiling import scope
 
@@ -128,27 +129,33 @@ class GatedDeltaNet(Mixer):
         return (_l2norm(q) * dk ** -0.5).astype(dt), _l2norm(k).astype(dt), v, beta, g
 
     def _output(self, o: Array, z: Array) -> Array:
-        """o [..., Hv, dv], z [..., Hv dv] -> the layer's output [..., D]."""
-        o = _rms(o) * self.out_norm.astype(jnp.float32)
-        o = o * jax.nn.silu(z.reshape(o.shape).astype(jnp.float32))
-        return self.wo(o.reshape(z.shape).astype(_dtype(self.cfg.dtype)))
+        """o [B, Hv, T, dv] as the rule leaves it, z [B, T, Hv dv] -> the
+        layer's output [B, T, D]: the gate (under a Pallas backend one
+        kernel that reads both where they lie), then ``wo``. A decode
+        step's o [B, Hv, dv] and z [B, Hv dv] are one row of that."""
+        single = o.ndim == 3
+        if single:
+            o, z = o[:, :, None], z[:, None]
+        y = gated_rms_norm(
+            o, z, self.out_norm, eps=NORM_EPS,
+            backend=whole_array_backend(self.cfg, self.mesh),
+        )
+        return self.wo(y[:, 0] if single else y)
 
     def _rule(self, q, k, v, beta, g, **state):
-        """The rule over time on [B, T, H, ...] operands -> o [B, T, Hv, dv]
-        (and the final state with ``return_state``)."""
+        """The rule over time on [B, T, H, ...] operands -> o [B, Hv, T, dv],
+        head-major as its kernel leaves it (and the final state with
+        ``return_state``)."""
         cfg = self.cfg
         heads_first = lambda y: jnp.swapaxes(y, 1, 2)  # noqa: E731
         # key head j serves value heads j * (hv / hk) ... + hv / hk - 1:
         # the op repeats q and k, or its kernel reads them in place. Its
         # chunking is its own: cfg.chunk is linear attention's knob
-        out = kernel_bh(
+        return kernel_bh(
             cfg, self.mesh,
             lambda *a: gated_delta_rule(*a, backend=cfg.backend, **state),
             *(heads_first(y) for y in (q, k, v, beta, g)),
         )
-        if state:
-            return heads_first(out[0]), out[1]
-        return heads_first(out)  # [B, T, Hv, Dv]
 
     # -- parallel forward ---------------------------------------------------
 

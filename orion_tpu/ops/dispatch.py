@@ -6,11 +6,12 @@ backend='xla' dispatch"). "auto" picks Pallas on TPU and the pure-XLA
 chunked scan elsewhere (CPU/GPU and unit tests). The Pallas kernel can also
 run anywhere via interpret mode (used by the parity tests).
 
-Nine ops dispatch here (``ssm_scan`` and ``ssm_state_step``, the
+Ten ops dispatch here (``ssm_scan`` and ``ssm_state_step``, the
 state-space layers' prompt pass and one-token step, ``causal_short_conv``,
 the delta-rule and state-space layers' short conv over a sequence or a
-prompt piece, and ``latent_cache_attention``, the latent layers' absorbed
-query over a held latent cache, are described at their definitions):
+prompt piece, ``gated_rms_norm``, the delta-rule layer's output gate, and
+``latent_cache_attention``, the latent layers' absorbed query over a held
+latent cache, are described at their definitions):
 ``gated_delta_rule`` (the gated delta-rule
 layers' parallel forward, with or without a state carried in and out:
 under Pallas the chunked form as Mosaic kernels, forward and backward,
@@ -233,6 +234,30 @@ def causal_short_conv(
     from orion_tpu.ops.gated_delta import causal_short_conv as conv
 
     return conv(x, w, activation, tail, bias)
+
+
+def gated_rms_norm(o, z, w, *, eps: float, backend: str = "auto"):
+    """Dispatch the delta-rule layer's output gate, ``rms(o) * w * silu(z)``
+    per head (``ops/gated_delta.py::gated_rms_norm``, the specification: o
+    ``[..., Hv, T, Dv]`` head-major as :func:`gated_delta_rule` leaves it, z
+    ``[..., T, Hv Dv]``, w ``[Dv]`` -> ``[..., T, Hv Dv]`` in z's dtype).
+    ``pallas`` and ``pallas_interpret`` run it as a Mosaic kernel pair,
+    forward and backward, that reads ``o`` and ``z`` once where they lie and
+    writes time-major (``ops/pallas/gated_norm.py``), where the operands
+    allow: ``Dv`` whole lane tiles and ``T`` at least a sublane tile
+    (``gated_norm.supports``). Anything else (a decode step's one row),
+    and every other backend, is the XLA form."""
+    b = resolve(backend)
+    if b.startswith("pallas"):
+        from orion_tpu.ops.pallas import gated_norm as pgn
+
+        if pgn.supports(o, z):
+            return pgn.gated_rms_norm_pallas(
+                o, z, w, eps=eps, interpret=(b == "pallas_interpret")
+            )
+    from orion_tpu.ops.gated_delta import gated_rms_norm as norm
+
+    return norm(o, z, w, eps)
 
 
 def row_sparse(backend: str) -> bool:
@@ -492,6 +517,7 @@ __all__ = [
     "gated_delta_step",
     "default_backend",
     "gated_delta_rule",
+    "gated_rms_norm",
     "latent_cache_attention",
     "resolve",
     "resolve_chunk",

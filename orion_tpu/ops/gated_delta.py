@@ -43,7 +43,8 @@ monolithic pass's op sequence.
 
 ``causal_short_conv`` is the depthwise causal convolution (+ SiLU) that
 feeds the layer's q, k and v; with ``tail`` it continues a sequence whose
-last ``W - 1`` inputs the caller kept.
+last ``W - 1`` inputs the caller kept; ``gated_rms_norm`` is the gate its
+output passes before ``wo``: an RMS norm per head times ``silu(z)``.
 
 Conventions: q, k ``[..., T, Dk]``; v ``[..., T, Dv]``; beta, g
 ``[..., T]``. Matmul operands stay in the input dtype with fp32
@@ -295,6 +296,17 @@ def causal_short_conv(
     return (jax.nn.silu(y) if activation else y).astype(x.dtype)
 
 
+def gated_rms_norm(o: Array, z: Array, w: Array, eps: float) -> Array:
+    """The layer's output gate: ``rms(o) * w * silu(z)`` per head, fp32
+    inside, rounded once to ``z``'s dtype. o ``[..., Hv, T, Dv]`` head-major,
+    as the rule leaves it; z ``[..., T, Hv Dv]``; w ``[Dv]`` -> ``[..., T, Hv
+    Dv]``."""
+    of = jnp.swapaxes(o, -3, -2).astype(jnp.float32)
+    n = of * jax.lax.rsqrt(jnp.mean(jnp.square(of), -1, keepdims=True) + eps)
+    y = n * w.astype(jnp.float32) * jax.nn.silu(z.reshape(of.shape).astype(jnp.float32))
+    return y.reshape(z.shape).astype(z.dtype)
+
+
 __all__ = [
     "DEFAULT_CHUNK",
     "causal_short_conv",
@@ -302,4 +314,5 @@ __all__ = [
     "gated_delta_chunked",
     "gated_delta_recurrent",
     "gated_delta_step",
+    "gated_rms_norm",
 ]

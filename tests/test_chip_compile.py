@@ -201,6 +201,12 @@ def _short_conv(x, w):
     return causal_short_conv(x, w, backend="pallas")
 
 
+def _gated_norm(o, z, w):
+    from orion_tpu.ops.dispatch import gated_rms_norm
+
+    return gated_rms_norm(o, z, w, eps=1e-6, backend="pallas")
+
+
 _QKV = [(BHTD, jnp.bfloat16)] * 3
 # qwen3_next_80b's train point (batch 8, T 8192): its softmax layer's 16
 # heads of 256 after the KV heads are repeated; its held experts' buffer
@@ -215,6 +221,9 @@ _DELTA = [*[((2, 16, 8192, 128), jnp.bfloat16)] * 2,
           *[((2, 32, 8192), jnp.float32)] * 2]
 # and that layer's short conv over its [q | k | v] channels, window 4
 _CONV = [((8, 8192, 8192), jnp.bfloat16), ((4, 8192), jnp.bfloat16)]
+# and its output gate: the rule's o head-major, z time-major, the norm's scale
+_GATE = [((8, 32, 8192, 128), jnp.bfloat16), ((8, 8192, 4096), jnp.bfloat16),
+         ((128,), jnp.float32)]
 # the serve cells' decode carry: 64 slots of lm_1b3's fp32 (S, z), one
 # token's bf16 q, k, v a slot, a 16-step chunk's own bf16 k, v rows with
 # each slot's step in it, and the chunk's row mask
@@ -314,6 +323,11 @@ KERNELS = [
         jax.grad(lambda x, w: _f32sum(_short_conv(x, w)), argnums=(0, 1)),
         _CONV, id="short_conv-bwd",
     ),
+    pytest.param(_gated_norm, _GATE, id="gated_norm-T8192-fwd"),
+    pytest.param(
+        jax.grad(lambda *a: _f32sum(_gated_norm(*a)), argnums=(0, 1, 2)),
+        _GATE, id="gated_norm-T8192-bwd",
+    ),
     pytest.param(_gated_delta_state, _DELTA_PIECE,
                  id="gated_delta-state-96x192-piece1024"),
     pytest.param(_delta_step, _DELTA_STATE, id="gated_delta_step-64slots"),
@@ -355,6 +369,38 @@ def test_kernel_compiles_for_v5e(v5e, fn, shapes):
     compiled = _compile(v5e, fn, *shapes)
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
+def test_delta_rule_block_gates_its_output_in_one_kernel(v5e):
+    """The compiled forward of one of ``qwen3_next_80b``'s delta-rule blocks
+    (two rows of the train point's 8,192): between the rule's kernel and
+    ``wo`` stands ``gated_norm_fwd`` alone, and under ``attn._output`` no
+    fp32 array the size of a head's slab or more (as XLA fusions the gate
+    wrote ``o`` and ``z`` out in fp32: ``f32[.., 4096]``, ``f32[.., 8192,
+    128]``, ``f32[.., 32, 128]``; PERF.md s5)."""
+    import math
+    import re
+
+    from orion_tpu.models.configs import get_config
+    from orion_tpu.models.transformer import Block
+
+    cfg = dataclasses.replace(get_config("qwen3_next_80b"), backend="pallas", remat=False)
+    block = Block(cfg, "gated_delta")
+    x = jax.ShapeDtypeStruct((2, 8192, cfg.d_model), jnp.bfloat16)
+    params = jax.eval_shape(block.init, jax.random.key(0), x)
+    one = SingleDeviceSharding(v5e[0])
+    on_chip = lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one)  # noqa: E731
+    text = jax.jit(block.apply).lower(
+        jax.tree.map(on_chip, params), on_chip(x)
+    ).compile().as_text()
+    gate = [line for line in text.splitlines() if "attn._output" in line]
+    assert sum("custom_call_target" in line and "gated_norm_fwd" in line for line in gate) == 1
+    wide = [
+        m.group(0) for line in gate
+        for m in re.finditer(r"= \(?f32\[([\d,]+)\]", line)
+        if math.prod(int(n) for n in m.group(1).split(",")) >= 8192 * 128
+    ]
+    assert not wide, wide
 
 
 # -- whole programs (slow: ~20 s to ~4 min each) -----------------------------

@@ -446,6 +446,12 @@ def test_cell_rehearses_on_the_cpu(tmp_path):
 # tree: with ``n_kv_heads``, ``attn_scale``, ``embed_init_std`` and the conv's
 # bias absent nothing new is traced. A PR that changes one of these programs
 # on purpose reads the new value from the assertion and replaces it here.
+# PR 45 did, for ``olmo_hybrid_7b``'s prefill, piece and step: the delta-rule
+# layer's gate is an op that takes ``o`` head-major, so the ``swapaxes`` that
+# ``_rule`` did now comes after the conv tail's equations (prefill and piece:
+# the same equations, in another order) and a decode step's one row passes it
+# as ``[B, Hv, 1, dv]`` (unit axes; the arithmetic is bit for bit the old
+# ``_output``'s: tests/test_gated_norm_kernel.py).
 _OLMO = dict(vocab_size=256, d_model=96, n_heads=3, head_dim=16, gdn_key_heads=3,
              gdn_value_heads=3, gdn_key_dim=8, gdn_value_dim=24, mlp_hidden=128,
              max_seq_len=256, dtype="float32", param_dtype="float32")
@@ -456,8 +462,8 @@ _PRESETS = {
     "hybrid_1b3": {**_LM, "n_layers": 4, "layer_types": ("swa", "swa", "swa", "linear"), "window": 32},
 }
 _TRACED = {
-    "olmo_hybrid_7b.forward": "1d0bffda123d623c", "olmo_hybrid_7b.prefill": "41c4c0d756adfbdd",
-    "olmo_hybrid_7b.piece": "86605e0bf23108a6", "olmo_hybrid_7b.step": "872cbf634fd7df39",
+    "olmo_hybrid_7b.forward": "1d0bffda123d623c", "olmo_hybrid_7b.prefill": "5e76eafde9c2cca9",
+    "olmo_hybrid_7b.piece": "8f805726bc5d85fb", "olmo_hybrid_7b.step": "2c5a2873d6be3918",
     "lm_1b3.forward": "91150cac1cbb2ee7", "lm_1b3.prefill": "55bfda62ea1cc771",
     "lm_1b3.piece": "067511f7d2e33163", "lm_1b3.step": "bf7be0cd0cf2078e",
     "hybrid_1b3.forward": "e12b2be89edde12c", "hybrid_1b3.prefill": "ff81ed1f94e804b7",
