@@ -302,7 +302,7 @@ def child_invariant(args, spec) -> int:
     import jax
     import jax.numpy as jnp
 
-    from orion_tpu.generate import SampleConfig, decode_chunk, prefill_carry
+    from orion_tpu.generate import SampleConfig, generate, prefill_carry
     from orion_tpu.models.configs import get_config
     from orion_tpu.models.transformer import TransformerLM
     from orion_tpu.utils.cache import enable_compile_cache
@@ -310,22 +310,20 @@ def child_invariant(args, spec) -> int:
     enable_compile_cache()
     cfg = get_config(spec["config"])
     model = TransformerLM(cfg)
-    total, n_prompt, batch, piece = 512, 256, 2, 64
+    total, n_prompt, batch = 512, 256, 2
     key = jax.random.PRNGKey(spec["seed"])
     params = model.init(key, jnp.zeros((1, 8), jnp.int32))
     prompt = jax.random.randint(
         jax.random.fold_in(key, 1), (batch, n_prompt), 0, cfg.vocab_size)
 
-    # the walk the CLIs make: prefill_carry, then decode_chunk pieces
+    # the prompt's state from the whole-prompt prefill (one bucket, the
+    # prompt's own length), and the tokens to walk from generate()'s
+    # greedy scan over the same prompt: tokens n_prompt .. total-1
     greedy = SampleConfig(temperature=0.0)
-    carry = prefill_carry(model, params, prompt, greedy, key)
-    states = carry[1]
-    walked = []
-    for start in range(0, total - n_prompt, piece):
-        carry, toks = decode_chunk(
-            model, params, carry, key, start, piece, greedy)
-        walked.append(toks)
-    walked = jnp.concatenate(walked, axis=1)  # tokens n_prompt .. total-1
+    states = prefill_carry(
+        model, params, prompt, greedy, key, buckets=(n_prompt,))[1]
+    walked = generate(
+        model, params, prompt, total - n_prompt, greedy, rng=key)
     seq = jnp.concatenate([prompt, walked], axis=1)
     # each loaded program reserves its scratch on the device (here a bf16
     # copy of the weights, ~2.5 GB): drop the walk's before the next two

@@ -51,7 +51,7 @@ class ProgramDecl:
     the declaration cannot silently drift. ``plan`` declares the
     program's per-footprint applicability in ``aot.decode_plan``:
     ``always`` / ``per_bucket`` / ``per_bucket_unified`` (one per bucket,
-    only when the in-scan prefill budget is on) / ``spec`` (only with
+    keyed by the aligned piece width too) / ``spec`` (only with
     spec_depth > 0) / ``never`` (reachable but deliberately unplanned —
     say why in ``note``) / ``unplanned`` (not a decode-section program).
     ``keyspace="open"`` exempts the row from the unbounded-static-key
@@ -146,32 +146,23 @@ PROGRAMS: Tuple[ProgramDecl, ...] = (
         goldens=("decode_batched_spec_tiny",),
     ),
     ProgramDecl(
-        "prefill", GENERATE, "_prefill_carry_jit", "decode",
-        static_args=("model", "sample_cfg"), plan="never",
-        note="exact-length host prefill: one compile per novel prompt "
-             "length BY DESIGN, reachable only with prefill_buckets off — "
-             "a bucketed replica never runs it, so the plan must not "
-             "list it (phantom entries would break the warm-start "
-             "'runs precisely these executables' contract)",
-    ),
-    ProgramDecl(
         "prefill_bucketed", GENERATE, "_prefill_carry_bucketed_jit",
         "decode",
         static_args=("model", "sample_cfg"), plan="per_bucket",
+        note="the whole-prompt prefill of the ladder's re-prefill rung and "
+             "the prefix store's publish, never of admission; a sequence "
+             "past the largest bucket pads to max_seq_len, one more key "
+             "and not one per length",
     ),
     # -- solo: the batch/CLI decode path ---------------------------------
     ProgramDecl(
         "generate", GENERATE, "_generate_jit", "solo",
         static_args=("model", "max_new_tokens", "sample_cfg"),
-        keyspace="open",
+        keyspace="open", goldens=("decode_tiny",),
         note="CLI batch generation: max_new_tokens is the invocation's "
              "token budget — one compile per run is the accepted cost; "
              "serving never calls this (the chunked programs exist "
              "precisely to avoid it)",
-    ),
-    ProgramDecl(
-        "decode_chunk", GENERATE, "_decode_chunk_jit", "solo",
-        static_args=_DECODE_STATICS, goldens=("decode_tiny",),
     ),
     # -- setup: one-shot construction-time programs ----------------------
     ProgramDecl(
@@ -205,7 +196,8 @@ PROGRAMS: Tuple[ProgramDecl, ...] = (
                      "boundary's row counters in one transfer; no static "
                      "args, one tuple length per engine"),
     ProgramDecl("insert_carry", BATCHING, "_insert_carry", "setup",
-                note="slot admission row write; traced slot index — one "
+                note="row write of a READY carry (session resume, the "
+                     "ladder's re-prefill); traced slot index — one "
                      "compile ever per engine shape"),
     ProgramDecl("stage_rows_carry", BATCHING, "_stage_rows_carry",
                 "setup", donate_argnums=(0, 1, 2, 3, 4),
@@ -263,20 +255,23 @@ PROGRAMS: Tuple[ProgramDecl, ...] = (
 # across the test suite so global jit-cache deltas are attributable.
 # ``expect_programs`` is the DECLARED per-footprint program count —
 # :func:`expected_decode_universe` must produce exactly that many rows.
+# ``prefill_chunk`` is a multiple of the tiny configs' linear-attention
+# chunk (128), so the knob IS the aligned width the plan lists.
 CHECK_FOOTPRINTS: Tuple[Dict[str, Any], ...] = (
-    {"slots": 3, "chunk": 6, "prefill_buckets": (12,), "prefill_chunk": 0,
-     "qmode": "off", "tp": 1, "spec_depth": 0, "expect_programs": 2},
-    {"slots": 5, "chunk": 7, "prefill_buckets": (12, 24),
-     "prefill_chunk": 0, "qmode": "off", "tp": 1, "spec_depth": 0,
+    {"slots": 3, "chunk": 6, "prefill_buckets": (12,),
+     "prefill_chunk": 128, "qmode": "off", "tp": 1, "spec_depth": 0,
      "expect_programs": 3},
+    {"slots": 5, "chunk": 7, "prefill_buckets": (12, 24),
+     "prefill_chunk": 128, "qmode": "off", "tp": 1, "spec_depth": 0,
+     "expect_programs": 5},
 )
 
 
 def expected_decode_universe(
     slots: int,
     chunk: int,
-    prefill_buckets=(),
-    prefill_chunk: int = 0,
+    prefill_buckets,
+    prefill_chunk: int,
     qmode: str = "off",
     tp: int = 1,
     spec_depth: int = 0,
@@ -296,14 +291,14 @@ def expected_decode_universe(
         if d.plan == "always":
             out.append({"kind": d.name, "slots": slots, "chunk": chunk,
                         "qmode": qmode, "tp": tp})
-        elif d.plan == "per_bucket_unified" and int(prefill_chunk) > 0:
-            for b in prefill_buckets or ():
+        elif d.plan == "per_bucket_unified":
+            for b in prefill_buckets:
                 out.append({"kind": d.name, "slots": slots, "chunk": chunk,
                             "bucket": int(b),
                             "prefill_chunk": int(prefill_chunk),
                             "qmode": qmode, "tp": tp})
         elif d.plan == "per_bucket":
-            for b in prefill_buckets or ():
+            for b in prefill_buckets:
                 out.append({"kind": d.name, "bucket": int(b),
                             "qmode": qmode, "tp": tp})
         elif d.plan == "spec" and int(spec_depth) > 0:

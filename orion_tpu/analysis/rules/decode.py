@@ -6,10 +6,9 @@ per-chunk decode loop stalls the device pipeline once per chunk: the next
 chunk's dispatch waits on the readback, turning the chunked serving walk
 into lockstep host-device ping-pong — the latency bug the chunked design
 exists to avoid. The serving layer has exactly ONE sanctioned sync per
-chunk — the all-finite probe (scalar for the solo DecodeSession, one
-[slots]-bool vector for the slot-multiplexed SlotEngine) — and it lives
-in a designated probe function (``DecodeSession._probe_finite``,
-``SlotEngine._probe_bad``), so the rule exempts any code lexically inside
+chunk — the all-finite probe (one [slots]-bool vector of the
+slot-multiplexed SlotEngine) — and it lives in a designated probe
+function (``SlotEngine._probe_bad``), so the rule exempts any code lexically inside
 a function whose name contains ``probe``. Everything else syncs once,
 after the loop.
 
@@ -175,7 +174,7 @@ class DecodeHostSyncRule:
                     f"{sync} inside a decode loop forces a device round-"
                     "trip every chunk; sync once after the loop, or move "
                     "it into the designated probe (a function named "
-                    "*probe*, e.g. DecodeSession._probe_finite)",
+                    "*probe*, e.g. SlotEngine._probe_bad)",
                 )
         # the admission budget: the engine's admit/insert/stage functions
         # are sync-free — O(1) admission must not pay a device round-trip

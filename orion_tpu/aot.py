@@ -239,12 +239,28 @@ def _lowered_cost(lowered) -> Dict[str, Any]:
 _COST_MEMO: Dict[tuple, list] = {}
 
 
+def _aligned_pchunk(model_cfg, prefill_chunk: int, tp: int) -> int:
+    """The piece width a replica compiles: the engine's in-scan piece
+    boundaries align to the linear-attention chunk (SlotEngine rounds the
+    knob up; batching.py ``chunk_align``), and an inventory must list that
+    width, not the raw knob."""
+    from orion_tpu.ops.dispatch import resolve, resolve_chunk
+    from orion_tpu.parallel.decode import mesh_backend
+
+    align = resolve_chunk(
+        model_cfg.chunk, model_cfg.max_seq_len,
+        resolve(mesh_backend(model_cfg.backend, tp)),
+    )
+    return -(-int(prefill_chunk) // align) * align
+
+
 def decode_cost_entries(
     model_cfg,
     slots: int = 8,
     chunk: int = 16,
-    bucket: int = 0,
-    prefill_chunk: int = 0,
+    *,
+    bucket: int,
+    prefill_chunk: int,
     qmode: str = "off",
     tp: int = 0,
     spec_depth: int = 0,
@@ -303,26 +319,17 @@ def decode_cost_entries(
             model, params, carry, rngs, active, int(chunk), sample
         )
     ))
-    pchunk = 0
-    if int(prefill_chunk) > 0 and int(bucket) > 0:
-        from orion_tpu.ops.dispatch import resolve, resolve_chunk
-        from orion_tpu.parallel.decode import mesh_backend
-
-        align = resolve_chunk(
-            model_cfg.chunk, model_cfg.max_seq_len,
-            resolve(mesh_backend(model_cfg.backend, tp)),
-        )
-        pchunk = -(-int(prefill_chunk) // align) * align
-        pbuf = shaped((slots, int(bucket)), jnp.int32)
-        harvest(
-            "unified_prefill",
-            dict(base, bucket=int(bucket), prefill_chunk=pchunk),
-            lambda: _decode_batched_prefill_chunk_jit.lower(
-                model, params, carry, rngs, active, pbuf,
-                vec(jnp.int32), vec(jnp.int32), vec(jnp.int32), int(chunk),
-                min(pchunk, int(bucket)), sample,
-            ),
-        )
+    pchunk = _aligned_pchunk(model_cfg, prefill_chunk, tp)
+    pbuf = shaped((slots, int(bucket)), jnp.int32)
+    harvest(
+        "unified_prefill",
+        dict(base, bucket=int(bucket), prefill_chunk=pchunk),
+        lambda: _decode_batched_prefill_chunk_jit.lower(
+            model, params, carry, rngs, active, pbuf,
+            vec(jnp.int32), vec(jnp.int32), vec(jnp.int32), int(chunk),
+            min(pchunk, int(bucket)), sample,
+        ),
+    )
     if int(spec_depth) > 0:
         harvest(
             "spec_round",
@@ -341,8 +348,9 @@ def decode_plan(
     model_cfg,
     slots: int = 8,
     chunk: int = 16,
-    prefill_buckets=(),
-    prefill_chunk: int = 0,
+    *,
+    prefill_buckets,
+    prefill_chunk: int,
     qmode: str = "off",
     tp: int = 0,
     spec_depth: int = 0,
@@ -401,38 +409,30 @@ def decode_plan(
             env["active"], int(chunk), env["sample"],
         )
     ))
-    # the engine's in-scan piece boundaries align to the linear-attention
-    # chunk (SlotEngine rounds the knob up; batching.py chunk_align) — the
-    # inventory must list the pchunk the replica actually compiles, and
-    # prefill_chunk=0 means host-side prefill: no unified program exists
-    pchunk = 0
-    if int(prefill_chunk) > 0:
-        from orion_tpu.ops.dispatch import resolve, resolve_chunk
-        from orion_tpu.parallel.decode import mesh_backend
-
-        align = resolve_chunk(
-            model_cfg.chunk, model_cfg.max_seq_len,
-            resolve(mesh_backend(model_cfg.backend, tp)),
+    if not prefill_buckets or int(prefill_chunk) <= 0:
+        raise ValueError(
+            "no engine has this footprint: admission is in-scan, which "
+            f"needs prompt buckets (got {tuple(prefill_buckets)}) and "
+            f"prefill_chunk > 0 (got {prefill_chunk})"
         )
-        pchunk = -(-int(prefill_chunk) // align) * align
-    for bucket in prefill_buckets or ():
-        if pchunk:
-            add(
-                "unified_prefill",
-                dict(base_key, bucket=int(bucket), prefill_chunk=pchunk),
-                lambda env, bucket=bucket, pchunk=pchunk: (
-                    env["unified_prefill"].lower(
-                        env["model"], env["params"], env["carry"],
-                        env["rngs"], env["active"],
-                        env["shaped"]((slots, int(bucket)), env["i32"]),
-                        env["vec"](env["i32"]), env["vec"](env["i32"]),
-                        env["vec"](env["i32"]),
-                        int(chunk), pchunk, env["sample"],
-                    )
-                ),
-            )
-        # the host-side bucketed prefill (admission with prefill_chunk=0,
-        # the ladder's re-prefill rung, prefix publishes): batch 1
+    pchunk = _aligned_pchunk(model_cfg, prefill_chunk, tp)
+    for bucket in prefill_buckets:
+        add(
+            "unified_prefill",
+            dict(base_key, bucket=int(bucket), prefill_chunk=pchunk),
+            lambda env, bucket=bucket: (
+                env["unified_prefill"].lower(
+                    env["model"], env["params"], env["carry"],
+                    env["rngs"], env["active"],
+                    env["shaped"]((slots, int(bucket)), env["i32"]),
+                    env["vec"](env["i32"]), env["vec"](env["i32"]),
+                    env["vec"](env["i32"]),
+                    int(chunk), pchunk, env["sample"],
+                )
+            ),
+        )
+        # the whole-prompt bucketed prefill (the ladder's re-prefill
+        # rung, prefix publishes): batch 1
         add(
             "prefill_bucketed",
             {"bucket": int(bucket), "qmode": qmode, "tp": tp},
@@ -552,7 +552,7 @@ def decode_plan(
         "tp": tp,
         "slots": slots,
         "chunk": chunk,
-        "prefill_buckets": list(prefill_buckets or ()),
+        "prefill_buckets": list(prefill_buckets),
         "prefill_chunk_aligned": pchunk,
         "spec_depth": int(spec_depth),
         "n_programs": len(programs),
@@ -565,8 +565,9 @@ def warm(
     store,
     slots: int = 8,
     chunk: int = 16,
-    prefill_buckets=(),
-    prefill_chunk: int = 0,
+    *,
+    prefill_buckets,
+    prefill_chunk: int,
     qmode: str = "off",
     tp: int = 0,
     spec_depth: int = 0,
@@ -671,7 +672,7 @@ def main(argv=None) -> int:
     p.add_argument("--chunk", type=int, default=16)
     p.add_argument("--prefill-chunk", type=int, default=64)
     p.add_argument("--prefill-buckets", default="pow2",
-                   help="bucket spec as in serving (pow2 | a,b,c | off)")
+                   help="bucket spec as in serving (pow2 | a,b,c)")
     p.add_argument("--qmode", default="off", choices=["off", "int8", "int4"])
     p.add_argument("--spec-depth", type=int, default=0)
     p.add_argument("--verify", action="store_true",

@@ -88,7 +88,7 @@ def build_argparser() -> argparse.ArgumentParser:
     p.add_argument("--prefix-dir", default=None,
                    help="SHARED content-addressed prefix cache: a system "
                         "prompt published by one replica admits O(suffix) "
-                        "on every replica (needs --prefill-chunk > 0)")
+                        "on every replica")
     p.add_argument("--prefix-len", type=int, default=0,
                    help="declare the first N tokens of every prompt as a "
                         "shared cacheable prefix (miss publishes to "

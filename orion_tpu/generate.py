@@ -78,11 +78,8 @@ def sample_logits(logits: Array, rng: Array, cfg: SampleConfig) -> Array:
 
 
 def _decode_body(model, params, sample_cfg: SampleConfig, rng, carry, i):
-    """One recurrent decode step: the SINGLE scan body shared by the
-    monolithic ``_generate_jit`` scan and the chunked ``decode_chunk``
-    scans, so chunked-vs-monolithic bitwise equivalence at a fixed rng is
-    by construction. ``i`` is the ABSOLUTE emitted-token index (the rng
-    fold_in key), regardless of which chunk is executing."""
+    """One recurrent decode step, the body of ``_generate_jit``'s scan.
+    ``i`` is the ABSOLUTE emitted-token index (the rng fold_in key)."""
     token, states, t, done = carry
     logits, states = model.apply(params, token, states, t, method="decode_step")
     nxt = sample_logits(logits, jax.random.fold_in(rng, i + 1), sample_cfg)
@@ -121,30 +118,18 @@ def _generate_jit(
     return jnp.moveaxis(tokens, 0, 1)  # [B, N]
 
 
-# -- chunked decode (serving) -------------------------------------------------
-# The serving layer (orion_tpu/serving/) decodes in bounded lax.scan chunks
-# instead of one monolithic scan: chunk boundaries are where deadlines are
-# enforced, decode state is snapshotted, the all-finite probe runs, and
-# SIGTERM/watchdog bookkeeping happens — none of which can live inside a
-# single N-step scan. The shared ``_decode_body`` keeps the chunked walk
-# bitwise-identical to ``generate()`` at the same rng.
-
-
-@partial(jax.jit, static_argnums=(0, 3))
-def _prefill_carry_jit(
-    model: TransformerLM,
-    params: Any,
-    tokens: Array,
-    sample_cfg: SampleConfig,
-    rng: Array,
-    sample_index: Array,
-    done: Array,
-) -> Tuple[Array, Any, Array, Array]:
-    logits, states = model.apply(params, tokens, method="prefill_last")
-    nxt = sample_logits(
-        logits, jax.random.fold_in(rng, sample_index), sample_cfg
-    )
-    return (nxt, states, jnp.int32(tokens.shape[1]), done)
+# -- monolithic prefill (serving's repair and publish paths) -------------------
+# Admission never prefills here: the SlotEngine stages a prompt into its carry
+# and the unified program consumes it in pieces (below). A whole-prompt
+# prefill has exactly two callers, both off the admission path: the
+# degradation ladder's re-prefill rung and the prefix store's publish
+# (serving/batching.py). Both need the piecewise and the monolithic state to
+# agree only to rounding: they are two XLA programs, and what holds between
+# them is equal tokens on every pinned seed and states equal to fp32 rounding
+# on XLA:CPU (tests/test_prefill_inscan.py states the bound); on the chip,
+# agreement is the cells' `correct` tolerance (ROADMAP C12). What is
+# bit-for-bit is what ONE program gives twice: a rewind's replay, a
+# suspend/resume row copy, a stored executable against its jit twin.
 
 
 @partial(jax.jit, static_argnums=(0, 3))
@@ -161,9 +146,8 @@ def _prefill_carry_bucketed_jit(
     """Bucketed prefill: ``tokens`` is right-padded to a bucket length and
     ``length`` (traced) is the real prompt length — ONE compile per bucket
     instead of one per novel prompt length (the compile-cache leak real
-    traffic would otherwise hit). The decode state and the first sampled
-    token are bitwise-identical to the unpadded compile's (masking
-    contract: mixers.Mixer.prefill)."""
+    traffic would otherwise hit). Padding is masked out of the state
+    (masking contract: mixers.Mixer.prefill)."""
     logits, states = model.apply(params, tokens, length, method="prefill_last")
     nxt = sample_logits(
         logits, jax.random.fold_in(rng, sample_index), sample_cfg
@@ -172,7 +156,7 @@ def _prefill_carry_bucketed_jit(
 
 
 def bucket_for(length: int, buckets: Tuple[int, ...]) -> Optional[int]:
-    """Smallest bucket >= length, or None (prefill at the exact length)."""
+    """Smallest bucket >= length, or None."""
     for b in buckets:
         if b >= length:
             return b
@@ -186,16 +170,16 @@ def reprefill_carry(
     emitted: List[Array],
     sample_cfg: SampleConfig,
     rng: Array,
-    buckets: Tuple[int, ...] = (),
+    buckets: Tuple[int, ...],
     sample_index: Optional[int] = None,
     exec_lookup: Optional[Callable[[int], Any]] = None,
 ):
     """Rebuild a decode carry from prompt + the tokens already emitted —
-    the degradation ladder's re-prefill rung, shared by the solo
-    DecodeSession and the SlotEngine so the rung's semantics cannot
-    diverge: ``sample_index = n`` keeps the rng fold_in sequence aligned
-    with the uninterrupted walk, and ``done`` is recomputed from the
-    emitted tokens (rows that already hit EOS stay done).
+    the degradation ladder's re-prefill rung: ``sample_index = n`` keeps
+    the rng fold_in sequence aligned with the uninterrupted walk, and
+    ``done`` is recomputed from the emitted tokens (rows that already hit
+    EOS stay done). The rebuilt state is the uninterrupted walk's to
+    rounding, not to the bit (see the section note above).
 
     ``sample_index`` overrides the default fold index (= the number of
     emitted tokens) for callers whose ``prompt`` is itself a rebased
@@ -203,10 +187,10 @@ def reprefill_carry(
     rng walk is anchored at the carry's absolute emit count, not at this
     segment's length (serving/session_store.py).
 
-    Caveat (both callers): rows that emitted EOS are rebuilt from their
-    PAD-filled tail rather than the post-EOS samples the uninterrupted
-    carry held — those rows keep emitting PAD either way, but their
-    dead-state contents differ from an uninterrupted run's."""
+    Caveat: rows that emitted EOS are rebuilt from their PAD-filled tail
+    rather than the post-EOS samples the uninterrupted carry held — those
+    rows keep emitting PAD either way, but their dead-state contents
+    differ from an uninterrupted run's."""
     seq = (
         jnp.concatenate([jnp.asarray(prompt, jnp.int32)]
                         + [jnp.asarray(e, jnp.int32) for e in emitted], axis=1)
@@ -218,9 +202,9 @@ def reprefill_carry(
     if sample_cfg.eos_token >= 0:
         done = (seq[:, prompt.shape[1]:] == sample_cfg.eos_token).any(axis=1)
     return prefill_carry(
-        model, params, seq, sample_cfg, rng,
+        model, params, seq, sample_cfg, rng, buckets,
         sample_index=n if sample_index is None else sample_index,
-        done=done, buckets=buckets, exec_lookup=exec_lookup,
+        done=done, exec_lookup=exec_lookup,
     )
 
 
@@ -230,22 +214,28 @@ def prefill_carry(
     tokens: Array,
     sample_cfg: SampleConfig,
     rng: Array,
+    buckets: Tuple[int, ...],
     sample_index: int = 0,
     done: Optional[Array] = None,
-    buckets: Tuple[int, ...] = (),
     exec_lookup: Optional[Callable[[int], Any]] = None,
 ):
-    """tokens [B, T] -> the decode carry (next_token, states, t, done).
+    """tokens [B, T] -> the decode carry (next_token, states, t, done), by
+    ONE whole-prompt forward. Not the admission path (see the section note):
+    its callers are the ladder's re-prefill rung and the prefix store's
+    publish, and both need it to agree with the in-scan pieces only to
+    rounding.
 
     ``sample_index`` is the rng fold_in key for the first sampled token —
     0 for a fresh prompt (matching ``generate()``), or ``n`` when
-    re-prefilling after ``n`` tokens were already emitted (the serving
-    degradation ladder's second rung).
+    re-prefilling after ``n`` tokens were already emitted.
 
-    ``buckets``: sorted pad-to lengths for bucketed prefill (empty = off).
-    The prompt is right-padded to the smallest bucket >= T and the real
-    length rides in traced, so the jit cache stays bounded by the bucket
-    count; a prompt longer than every bucket falls back to exact-length.
+    ``buckets``: sorted pad-to lengths. The prompt is right-padded to the
+    smallest bucket >= T and the real length rides in traced, so the jit
+    cache stays bounded by the bucket count: ONE cache entry per bucket,
+    a bucket-exact prompt included. A sequence longer than every bucket (a
+    re-prefill of prompt + emitted on an engine whose buckets stop short
+    of ``max_seq_len``) pads to ``max_seq_len``: one more entry, never one
+    per length.
 
     ``exec_lookup``: bucket width -> an AOT-deserialized executable of
     THIS program (serving/exec_store.py) or None. A hit replaces the jit
@@ -257,59 +247,16 @@ def prefill_carry(
     if done is None:
         done = jnp.zeros((tokens.shape[0],), bool)
     t = tokens.shape[1]
-    pad_to = bucket_for(t, buckets) if buckets else None
-    if pad_to is not None:
-        # a bucket-exact prompt still goes through the bucketed compile
-        # (length == pad_to): ONE cache entry per bucket, period
-        padded = jnp.pad(tokens, ((0, 0), (0, pad_to - t)))
-        exe = exec_lookup(pad_to) if exec_lookup is not None else None
-        if exe is not None:
-            return exe(
-                params, padded, rng, jnp.int32(sample_index), done,
-                jnp.int32(t),
-            )
-        return _prefill_carry_bucketed_jit(
-            model, params, padded, sample_cfg, rng,
-            jnp.int32(sample_index), done, jnp.int32(t),
+    pad_to = bucket_for(t, buckets) or model.cfg.max_seq_len
+    padded = jnp.pad(tokens, ((0, 0), (0, pad_to - t)))
+    exe = exec_lookup(pad_to) if exec_lookup is not None else None
+    if exe is not None:
+        return exe(
+            params, padded, rng, jnp.int32(sample_index), done, jnp.int32(t),
         )
-    return _prefill_carry_jit(
-        model, params, tokens, sample_cfg, rng, jnp.int32(sample_index), done
-    )
-
-
-@partial(jax.jit, static_argnums=(0, 4, 5))
-def _decode_chunk_jit(
-    model: TransformerLM,
-    params: Any,
-    carry: Any,
-    rng: Array,
-    n_steps: int,
-    sample_cfg: SampleConfig,
-    start: Array,
-) -> Tuple[Any, Array]:
-    body = partial(_decode_body, model, params, sample_cfg, rng)
-    carry, tokens = jax.lax.scan(
-        body, carry, start + jnp.arange(n_steps), length=n_steps
-    )
-    return carry, jnp.moveaxis(tokens, 0, 1)  # [B, n_steps]
-
-
-def decode_chunk(
-    model: TransformerLM,
-    params: Any,
-    carry: Any,
-    rng: Array,
-    start: int,
-    n_steps: int,
-    sample_cfg: SampleConfig,
-):
-    """Advance the decode carry by ``n_steps`` tokens (one bounded scan).
-    ``start`` is the absolute index of the first token this chunk emits;
-    it rides in as a traced scalar so every chunk of a given length shares
-    ONE compile."""
-    return _decode_chunk_jit(
-        model, params, carry, rng, int(n_steps), sample_cfg,
-        jnp.int32(start),
+    return _prefill_carry_bucketed_jit(
+        model, params, padded, sample_cfg, rng,
+        jnp.int32(sample_index), done, jnp.int32(t),
     )
 
 
@@ -447,24 +394,25 @@ def decode_batched_chunk(
 
 
 # -- in-scan chunked prefill (continuous batching, ISSUE 7) -------------------
-# Admission used to prefill each prompt SOLO on the host thread between
-# chunk boundaries — one long prompt stalled every resident slot
-# (head-of-line blocking; Orca/Sarathi-Serve territory). Because prefill
-# and decode share the same recurrent carry, a prefilling request can
-# instead OCCUPY a slot and consume its prompt inside the batched
-# program: each unified chunk first runs one ``prefill_chunk``-token
-# parallel-forward PIECE for each waiting slot, up to a cap a boundary
-# (transformer.prefill_extend_step — chunk-aligned pieces replay the
-# monolithic prefill's exact op sequence, so the carry is BITWISE what
-# host-side prefill_carry builds), then runs the decode scan with the
-# rows still mid-prompt frozen (state/position/emit held, PAD emitted).
+# A solo prefill on the host thread between chunk boundaries would stall
+# every resident slot behind one long prompt (head-of-line blocking;
+# Orca/Sarathi-Serve territory). Because prefill and decode share the same
+# recurrent carry, a prefilling request instead OCCUPIES a slot and consumes
+# its prompt inside the batched program: each unified chunk first runs one
+# ``prefill_chunk``-token parallel-forward PIECE for each waiting slot, up
+# to a cap a boundary (transformer.prefill_extend_step — chunk-aligned
+# pieces walk the monolithic prefill's left fold, so the carry is what
+# ``prefill_carry`` builds to fp32 rounding on XLA:CPU and the tokens are
+# the solo scan's on every pinned seed; see the note above
+# ``prefill_carry``), then runs the decode scan with the rows still
+# mid-prompt frozen (state/position/emit held, PAD emitted).
 # ``prefill_chunk`` is the width of ONE slot's piece; each piece is a
 # batch-1 forward, so a boundary pays for the slots it serves and for no
 # other, and its co-resident decoders wait for at most ``cap`` pieces.
-# Token-by-token prompt feeding inside the scan body can NOT deliver the
-# bitwise contract — a single-row matvec accumulates differently from
-# the prefill gemm — which is why the prompt is consumed as parallel
-# pieces at the top of the chunk rather than as masked scan steps.
+# Token-by-token prompt feeding inside the scan body would drift further —
+# a single-row matvec accumulates differently from the prefill gemm —
+# which is why the prompt is consumed as parallel pieces at the top of the
+# chunk rather than as masked scan steps.
 
 
 def _where_rows(mask: Array, new: Any, old: Any) -> Any:
@@ -680,8 +628,8 @@ def _decode_batched_prefill_chunk_jit(
     one-piece program this replaced, and one with none (a rung-3 replay
     can mask the only one out) discards its piece as that program did.
     A slot whose prompt completes samples its first token from its
-    piece's last-real-row logits at rng-fold ``pfold`` (bitwise what
-    host-side ``prefill_carry`` samples). Stage 2 — the chunk's decode
+    piece's last-real-row logits at rng-fold ``pfold`` (the token
+    ``generate()`` samples first at that seed). Stage 2 — the chunk's decode
     scan, with rows still mid-prompt frozen. Everything per-slot rides
     traced, so mixed prefill/decode traffic costs ONE compile per
     (slots, chunk, prompt_bucket) — ``prompt_bucket`` being the staged
@@ -1039,40 +987,8 @@ DECODE_PROGRAMS = {
     "prefill_piece_donated": _prefill_piece_donated_jit,
     "decode_scan_donated": _decode_scan_donated_jit,
     "spec_round": _decode_batched_spec_round_jit,
-    "prefill": _prefill_carry_jit,
     "prefill_bucketed": _prefill_carry_bucketed_jit,
 }
-
-
-def generate_chunked(
-    model: TransformerLM,
-    params: Any,
-    prompt: Array,
-    max_new_tokens: int,
-    chunk: int = 16,
-    sample: Optional[SampleConfig] = None,
-    rng: Optional[Array] = None,
-) -> Array:
-    """``generate()`` decoded in ``chunk``-step scans — bitwise-identical
-    output at the same rng (the equivalence the chunked-decode tests pin).
-    The resilient serving path is :class:`orion_tpu.serving.DecodeSession`,
-    which adds snapshots, the finite-state probe, and the degradation
-    ladder around this same walk."""
-    assert chunk > 0, chunk
-    sample_cfg = sample or SampleConfig()
-    rng = rng if rng is not None else jax.random.PRNGKey(0)
-    if prompt.ndim == 1:
-        prompt = prompt[None]
-    prompt = jnp.asarray(prompt, jnp.int32)
-    carry = prefill_carry(model, params, prompt, sample_cfg, rng)
-    out = []
-    n = 0
-    while n < max_new_tokens:
-        c = min(chunk, max_new_tokens - n)
-        carry, toks = decode_chunk(model, params, carry, rng, n, c, sample_cfg)
-        out.append(toks)
-        n += c
-    return jnp.concatenate(out, axis=1)
 
 
 def _compute_dtype_modules(model: TransformerLM, params: Any) -> set:

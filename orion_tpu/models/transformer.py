@@ -522,30 +522,11 @@ def linear_layer_indices(cfg: ModelConfig) -> Tuple[int, ...]:
 
 def snapshot_decode_state(states: List[State]) -> List[State]:
     """O(1) snapshot of the per-layer decode state for the serving rewind
-    path (orion_tpu/serving/session.py). jax arrays are immutable, so a
+    path (orion_tpu/serving/batching.py). jax arrays are immutable, so a
     snapshot only needs fresh *containers* — the rewind target must not see
     dicts that a later chunk's bookkeeping mutated in place. No device copy
     happens (the decode chunks never donate their state buffers)."""
     return jax.tree.map(lambda x: x, states)
-
-
-@jax.jit
-def _all_finite(states: List[State]) -> Array:
-    acc = jnp.bool_(True)
-    for leaf in jax.tree.leaves(states):
-        if jnp.issubdtype(leaf.dtype, jnp.floating):
-            acc = jnp.logical_and(acc, jnp.all(jnp.isfinite(leaf)))
-    return acc
-
-
-def decode_state_finite(states: List[State]) -> Array:
-    """Cheap jitted all-finite probe over the (S, z)/KV/ring decode state:
-    one fused reduction per floating leaf, ANDed to a scalar bool on
-    device. Integer leaves (cache slot bookkeeping) are skipped. Returns
-    the DEVICE scalar — the caller decides where to sync it to host
-    (serving's designated probe point, see analysis rule
-    ``decode-host-sync``)."""
-    return _all_finite(states)
 
 
 @jax.jit
@@ -562,12 +543,15 @@ def _per_slot_finite(states: List[State]) -> Array:
 
 
 def decode_state_finite_per_slot(states: List[State]) -> Array:
-    """Per-SEQUENCE all-finite probe: [B] bool vector, one entry per slot
-    of the batched decode state. The slot-multiplexed serving engine
-    (orion_tpu/serving/batching.py) replaces the global scalar probe with
-    this so one poisoned slot walks the degradation ladder for THAT
-    request only while co-resident slots keep streaming. Still ONE device
-    reduction and one host transfer per chunk regardless of slot count."""
+    """Per-SEQUENCE all-finite probe over the (S, z)/KV/ring decode state:
+    [B] bool vector, one entry per slot of the batched decode state, one
+    fused reduction per floating leaf (integer leaves, the cache slot
+    bookkeeping, are skipped). The slot-multiplexed serving engine
+    (orion_tpu/serving/batching.py) probes per slot so one poisoned slot
+    walks the degradation ladder for THAT request only while co-resident
+    slots keep streaming. Returns the DEVICE vector: ONE device reduction
+    and one host transfer per chunk regardless of slot count, at the
+    engine's designated probe point (analysis rule ``decode-host-sync``)."""
     return _per_slot_finite(states)
 
 
@@ -615,7 +599,6 @@ def init_decode_state(
 
 __all__ = [
     "TransformerLM", "Block", "MLP", "init_decode_state",
-    "snapshot_decode_state", "decode_state_finite",
-    "decode_state_finite_per_slot", "insert_decode_slot",
-    "extract_decode_slot", "linear_layer_indices",
+    "snapshot_decode_state", "decode_state_finite_per_slot",
+    "insert_decode_slot", "extract_decode_slot", "linear_layer_indices",
 ]

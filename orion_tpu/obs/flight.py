@@ -31,7 +31,7 @@ Design constraints:
 
 A module-level default recorder (:func:`recorder`, :func:`record`,
 :func:`configure`) serves code without an obvious owner (the trainer,
-the solo DecodeSession, the fleet supervisor); the Server builds its own
+the fleet supervisor); the Server builds its own
 per-instance recorder so replicas don't interleave rings.
 """
 

@@ -25,13 +25,14 @@ Hook sites wired today:
                           serving-side checkpoint restore
 ``"serve.tokenizer_io"``  serving/server.py tokenizer load, inside the retry
                           region
-``"serve.chunk"``         serving/session.py DecodeSession, at each decode
-                          chunk boundary (step = the request's chunk index)
-                          — where :meth:`FaultPlan.preempt_at_chunk`
+``"serve.chunk"``         serving/batching.py SlotEngine.step, at each decode
+                          chunk boundary (step = the engine's boundary
+                          index) — where :meth:`FaultPlan.preempt_at_chunk`
                           delivers a real SIGTERM mid-request
 ``"decode.state_nan"``    consumed via :func:`decode_nan_armed` by
-                          DecodeSession to poison one chunk's (S, z)/KV
-                          decode state to NaN — each rung of the serving
+                          SlotEngine._attempt to poison one chunk's (S, z)/KV
+                          decode state to NaN, whichever slot is at that
+                          chunk index — each rung of the serving
                           degradation ladder is reached by arming 1, 2, or
                           unlimited deliveries at the same chunk
 ``"decode.slot_nan.K"``   consumed via :func:`decode_slot_nan_armed` by the
@@ -105,11 +106,11 @@ SITES = {
     "train.nan": "Trainer.step NaN-gradient poisoning marker",
     "serve.ckpt_load": "generate.load_params, inside retry",
     "serve.tokenizer_io": "serving/server.py tokenizer load, inside retry",
-    "serve.chunk": "serving decode loops, each chunk boundary",
+    "serve.chunk": "serving/batching.py SlotEngine.step, each chunk boundary",
     "serve.chunk_delay": "serving/server.py _step_chunk, INSIDE the timed "
                          "chunk boundary (step = server-lifetime chunk "
                          "ordinal) — added host latency for SLO chaos",
-    "decode.state_nan": "DecodeSession decode-state poisoning marker",
+    "decode.state_nan": "SlotEngine per-chunk decode-state poisoning marker",
     "serve.session_save": "serving/session_store.py save, inside retry",
     "serve.session_load": "serving/session_store.py load, inside retry",
     "serve.session_scan": "serving/session_store.py generations(), before "
@@ -361,7 +362,7 @@ class FaultPlan:
 
     def poison_decode_state_at(self, chunk: int, times: int = 1) -> "FaultPlan":
         """Arm NaN-poisoning of the decode state at a chunk boundary
-        (consumed by serving's DecodeSession via :func:`decode_nan_armed`
+        (consumed by serving's SlotEngine via :func:`decode_nan_armed`
         after each attempt at that chunk). ``times=1`` exercises the
         rewind rung of the degradation ladder, ``times=2`` forces the
         re-prefill rung, ``times<0`` (unlimited) exhausts the ladder and
@@ -498,7 +499,7 @@ def nan_armed(step: int) -> bool:
 
 def decode_nan_armed(chunk: int) -> bool:
     """Is a decode-state NaN-poisoning armed for this chunk? Consumes one
-    delivery — the DecodeSession asks again after every ladder rung's
+    delivery — the SlotEngine asks again after every ladder rung's
     retry of the same chunk, so multi-delivery plans poison each attempt
     in turn."""
     plan = _active
@@ -508,9 +509,8 @@ def decode_nan_armed(chunk: int) -> bool:
 def decode_slot_nan_armed(slot: int, chunk: int) -> bool:
     """Is a slot-addressed decode-state poisoning armed for (slot, that
     request's chunk index)? Consumed per attempt, like
-    :func:`decode_nan_armed` (the SlotEngine also consumes the legacy
-    unaddressed site so single-request plans behave as under the solo
-    DecodeSession)."""
+    :func:`decode_nan_armed` (the SlotEngine consumes the unaddressed
+    site too: a single-request plan needs no slot index)."""
     plan = _active
     return plan is not None and plan.consume_marker(_decode_slot_site(slot), chunk)
 

@@ -1,15 +1,18 @@
 """Serving: continuous batching + the inference-side counterpart of the
 training resilience stack (orion_tpu/resilience/, PR 2).
 
-- :mod:`batching` — :class:`SlotEngine`: slot-multiplexed continuous
-  batching — a fixed number of requests share one jitted batched decode
-  scan (O(1) recurrent state makes a "slot" just a row of the carry);
-  admission/eviction at chunk boundaries, per-slot degradation ladder.
-- :mod:`session` — :class:`DecodeSession`: single-request chunked decode
-  with per-chunk state snapshots, a jitted all-finite probe, a rewind ->
-  re-prefill -> fail-request degradation ladder, and chunk-granular
-  deadlines (the slots=1-equivalent reference path; the engine's parity
-  oracle).
+- :mod:`batching` — :class:`SlotEngine`, the ONE engine: slot-multiplexed
+  continuous batching — a fixed number of requests share one jitted
+  batched decode scan (O(1) recurrent state makes a "slot" just a row of
+  the carry; ``slots=1`` is the solo case). ONE admission path: a prompt
+  is staged into the carry and consumed in-scan, in pieces. Eviction at
+  chunk boundaries, per-chunk state snapshots, a per-slot all-finite
+  probe, a rewind -> re-prefill -> fail-request degradation ladder, and
+  chunk-granular deadlines. Its parity oracle is ``generate()``'s solo
+  scan: equal tokens on every pinned seed (XLA:CPU; on the chip the
+  cells' `correct` tolerance, ROADMAP C12).
+- :mod:`session` — :class:`DecodeRequest` / :class:`DecodeResult`, the
+  records a client submits and gets back.
 - :mod:`server`  — :class:`Server`: the scheduler loop over the engine —
   bounded admission with explicit shed-on-overload, per-request
   isolation, watchdog heartbeats, and SIGTERM -> drain (finish in-flight
@@ -23,7 +26,8 @@ training resilience stack (orion_tpu/resilience/, PR 2).
 - :mod:`prefix_store` — the content-addressed prefix cache: a shared
   prompt prefix (system prompt) is ONE O(1) decode-state snapshot keyed
   by hash(params identity, qmode, token bytes); a hit admits as a row
-  copy + in-scan prefill of only the uncached suffix (``--prefix-dir``;
+  copy + in-scan prefill of only the uncached suffix, with the tokens of
+  the cold request (``--prefix-dir``;
   shared by every replica of a fleet).
 
 ``python -m orion_tpu.serving`` is the CLI (``--slots``, ``--chunk``,
@@ -48,12 +52,7 @@ from orion_tpu.serving.server import (
     Server,
     load_tokenizer,
 )
-from orion_tpu.serving.session import (
-    DecodeRequest,
-    DecodeResult,
-    DecodeSession,
-    LadderExhausted,
-)
+from orion_tpu.serving.session import DecodeRequest, DecodeResult
 from orion_tpu.serving.prefix_store import PrefixEntry, PrefixStore
 from orion_tpu.serving.session_store import (
     SessionIntegrityError,
@@ -67,7 +66,7 @@ __all__ = [
     "Health", "HealthMachine", "InvalidTransition",
     "Server", "ServeConfig", "Pending", "OverloadError", "RejectedError",
     "load_tokenizer", "SlotEngine", "parse_buckets", "PHASES",
-    "DecodeRequest", "DecodeResult", "DecodeSession", "LadderExhausted",
+    "DecodeRequest", "DecodeResult",
     "SessionStore", "SessionState", "SessionIntegrityError",
     "PrefixStore", "PrefixEntry",
 ]

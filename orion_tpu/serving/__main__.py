@@ -51,21 +51,18 @@ def build_argparser() -> argparse.ArgumentParser:
                         "drain / admission granularity")
     p.add_argument("--slots", type=int, default=8,
                    help="concurrent decode slots sharing one batched scan "
-                        "(continuous batching); 1 = the serial PR 4 "
-                        "behaviour")
+                        "(continuous batching); 1 = one request at a time")
     p.add_argument("--prefill-buckets", default="pow2",
-                   help="prompt-length buckets for prefill padding: 'pow2' "
-                        "(default), a comma list like '32,64,128', or 'off' "
-                        "(one prefill compile per novel prompt length; "
-                        "host-prefill only — requires --prefill-chunk 0)")
+                   help="prompt-length buckets, the widths a staged prompt "
+                        "is padded to: 'pow2' (default) or a comma list "
+                        "like '32,64,128'")
     p.add_argument("--prefill-chunk", type=int, default=64,
                    help="in-scan chunked prefill: prompt tokens ONE slot "
                         "consumes per chunk boundary INSIDE the batched "
                         "program (up to slots // chunk slots a boundary), "
                         "so a long prompt never stalls co-resident "
-                        "decoders (admission becomes an O(1) slot "
-                        "insert); 0 = legacy host-thread prefill at "
-                        "admission")
+                        "decoders (admission is an O(1) slot insert); "
+                        "must be > 0")
     p.add_argument("--prompt-overflow", choices=["error", "clamp"],
                    default="error",
                    help="prompts longer than the largest prefill bucket: "
@@ -116,8 +113,7 @@ def build_argparser() -> argparse.ArgumentParser:
                         "prompt prefix (system prompt) is one O(1) "
                         "decode-state snapshot — a hit admits at "
                         "O(suffix) instead of O(prompt); replicas "
-                        "sharing the directory share the cache. Needs "
-                        "--prefill-chunk > 0")
+                        "sharing the directory share the cache")
     p.add_argument("--prefix-len", type=int, default=0,
                    help="declare the first N tokens of every prompt as a "
                         "shared cacheable prefix: a miss publishes its "
@@ -451,8 +447,7 @@ def _run(args, guard) -> int:
           f"{up['trace_lower_s']:.2f} s)"
           + ("" if up["ready"] else "; no token was emitted"),
           file=sys.stderr)
-    mode = (f"in-scan prefill, {server.engine.prefill_chunk} tok/piece"
-            if args.prefill_chunk else "host prefill")
+    mode = f"in-scan prefill, {server.engine.prefill_chunk} tok/piece"
     print(f"slot occupancy: {server.occupancy_lifetime():.3f} "
           f"({args.slots} slot(s), chunk {args.chunk}, {mode}"
           + (f", qmode {args.qmode}" if args.qmode != "off" else "")

@@ -1,36 +1,35 @@
 """SlotEngine: slot-multiplexed continuous batching for the decode path.
 
-PR 4's :class:`~orion_tpu.serving.session.DecodeSession` serves one request
-at a time — correct, resilient, and leaving (N-1)/N of the hardware's batch
-throughput on the table. The paper's recurrent formulation makes the fix
-cheap: every sequence's decode state is O(1) — a few (S, z) matrices and
-fixed-size caches per layer — so a "slot" is nothing but one ROW of a
-batched state pytree. No paged KV, no block tables, no attention-kernel
-surgery: Orca-style iteration-level scheduling reduces to row inserts and
-row evictions on one carry.
+The one serving engine: serving one request at a time would leave (N-1)/N of
+the hardware's batch throughput on the table, and the paper's recurrent
+formulation makes the fix cheap: every sequence's decode state is O(1) — a
+few (S, z) matrices and fixed-size caches per layer — so a "slot" is nothing
+but one ROW of a batched state pytree. No paged KV, no block tables, no
+attention-kernel surgery: Orca-style iteration-level scheduling reduces to
+row inserts and row evictions on one carry. ``slots=1`` is the solo case.
 
 - **slots** — a fixed number of rows share ONE jitted chunked decode scan
   (``generate.decode_batched_chunk``). The slot count is static, so the
   whole serving lifetime costs one decode compile per (slots, chunk)
   regardless of arrival order; per-slot positions (vector ``t``), per-slot
   rng streams, and the active mask all ride in traced.
-- **admission** — at chunk boundaries only, and since ISSUE 7 an O(1)
-  row insert: the prompt is STAGED into the carry (padded to its bucket
-  on the host; all of a boundary's admissions in ONE donated dispatch,
+- **admission** — at chunk boundaries only, and an O(1) row insert: the
+  prompt is STAGED into the carry (padded to its bucket on the host; all
+  of a boundary's admissions in ONE donated dispatch,
   ``_stage_rows_carry``) and consumed INSIDE the batched scan
   (``generate.decode_batched_prefill_chunk``) — each boundary runs one
   ``prefill_chunk``-token piece for each waiting slot, up to
   ``slots // chunk`` of them (shortest remaining first; a slot passed
-  over too long goes first), each a batch-1 parallel-forward piece that
-  replays the monolithic prefill's exact op sequence, so the carry a
-  staged slot reaches is BITWISE what host-side prefill built, while
+  over too long goes first), each a batch-1 parallel-forward piece, while
   co-resident decoders never wait behind a long prompt (the
   Sarathi-style head-of-line fix, without a scheduler: O(1) state makes
-  chunked prefill a mask). ``prefill_chunk=0`` keeps the legacy path —
-  prefill each prompt solo on the host thread
-  (``generate.prefill_carry``) and row-write the ready carry
-  (``transformer.insert_decode_slot``). Mid-stream admission at a
-  nonzero position is the normal case, not an edge case.
+  chunked prefill a mask). There is no other admission path. Against a
+  whole-prompt prefill (``generate.prefill_carry``: the ladder's
+  re-prefill rung and the prefix store's publish, never admission) the
+  staged slot's TOKENS are equal on every pinned seed and its state equal
+  to fp32 rounding on XLA:CPU; on the chip, agreement is the cells'
+  `correct` tolerance (ROADMAP C12). Mid-stream admission at a nonzero
+  position is the normal case, not an edge case.
 - **eviction** — a slot is freed at the boundary where its request
   finishes: per-slot EOS (every later token is PAD by construction, so the
   tail is filled host-side, bitwise what the solo scan emits), max-tokens,
@@ -38,16 +37,27 @@ row evictions on one carry.
   but emit PAD and hold their position.
 - **per-slot ladder** — the finite probe is per-SEQUENCE
   (``transformer.decode_state_finite_per_slot``): one poisoned slot walks
-  PR 4's degradation ladder — rewind (redo the chunk from the boundary
-  snapshot; co-resident slots recompute bitwise-identical tokens) →
-  re-prefill that request from its prompt + emitted tokens → fail THAT
-  request — while the other slots keep streaming. Still one host sync per
-  chunk attempt, a [slots]-bool vector instead of PR 4's scalar.
-- **bitwise parity** — every device op in the batched body is batch-row
-  independent and each slot folds its own request's seed, so N multiplexed
-  requests produce tokens BITWISE-identical to N solo runs at the same
-  seeds (tests/test_batching.py pins this for slots {2, 4, 8}, greedy and
-  sampled, including late admission).
+  the degradation ladder — rewind (redo the chunk from the boundary
+  snapshot: ONE program run twice, so co-resident slots recompute
+  bitwise-identical tokens) → re-prefill that request from its prompt +
+  emitted tokens → fail THAT request, never the process — while the other
+  slots keep streaming. One host sync per chunk attempt, a [slots]-bool
+  vector (``_probe_bad``, the decode loop's DESIGNATED sync point;
+  analysis rule ``decode-host-sync`` flags any other).
+- **deadline** — enforced at chunk granularity against an injectable
+  clock, before the chunk is paid for; an expired request returns its
+  partial tokens with status ``"deadline"``.
+- **fault hooks** — ``inject.fire("serve.chunk", step=boundary)`` at every
+  boundary (where chaos tests deliver a real mid-request SIGTERM) and the
+  ``decode.state_nan`` / ``decode.slot_nan`` markers consumed after each
+  chunk attempt, so every rung of the ladder is deterministically
+  reachable.
+- **parity with the solo scan** — every device op in the batched body is
+  batch-row independent and each slot folds its own request's seed, so N
+  multiplexed requests produce the TOKENS of N solo ``generate()`` runs at
+  the same seeds (tests/test_batching.py pins this on XLA:CPU for slots
+  {2, 4, 8}, greedy and sampled, including late admission; on the chip a
+  request's ids can depend on which programs serve it, ROADMAP C12).
 - **self-speculation** (ISSUE 13) — with ``spec_depth > 0``, pure-decode
   boundaries run a speculative round instead of the plain chunk: the
   model's own global-linear layers draft up to k tokens per slot
@@ -99,7 +109,7 @@ from orion_tpu.models.transformer import (
     linear_layer_indices,
     snapshot_decode_state,
 )
-from orion_tpu.ops.dispatch import row_sparse
+from orion_tpu.ops.dispatch import resolve, resolve_chunk, row_sparse
 from orion_tpu.resilience import inject
 from orion_tpu.resilience.breaker import StoreUnavailableError
 from orion_tpu.serving.session import DecodeRequest, DecodeResult
@@ -175,10 +185,10 @@ def _spec_flags(states, done, accepted) -> Array:
 
 @jax.jit
 def _insert_carry(carry, rngs, plen, pfold, sub_carry, rng, i, n_emitted):
-    """Row-write one solo prefill carry (batch 1) + its rng key into slot
-    ``i`` of the batched carry — ONE fused dispatch for the whole
-    admission (a dozen eager ``.at`` updates would cost more host time
-    than the prefill itself; admissions sit on the scheduler's hot path).
+    """Row-write one READY solo carry (batch 1: a resumed session's, or the
+    ladder's re-prefill) + its rng key into slot ``i`` of the batched
+    carry — ONE fused dispatch (a dozen eager ``.at`` updates would cost
+    more host time than the row is worth on the scheduler's hot path).
     ``i`` and ``n_emitted`` ride traced: one compile, ever. The slot's
     staged-prompt length is zeroed — a row inserted with a READY carry is
     past its prompt by definition, so the unified in-scan program must
@@ -336,6 +346,17 @@ def tree_nbytes(tree) -> int:
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
 
+# The width of ONE slot's in-scan prompt piece where the caller names none
+# (``ServeConfig.prefill_chunk`` is this constant too); the engine rounds it
+# up to the linear-attention chunk.
+PREFILL_CHUNK = 64
+
+_NO_HOST_PREFILL = (
+    "host-side prefill at admission is gone: every prompt is staged into "
+    "the carry and consumed in-scan, which needs prompt buckets (e.g. "
+    "'pow2') and prefill_chunk > 0"
+)
+
 # the share of a device's memory left to the boundary programs' own
 # temporaries when the engine decides whether the carry fits twice: they
 # took 0.8 to 2.3 GB of a v5e's 16.9 in the served configurations' compiles
@@ -357,10 +378,11 @@ def fits_once_only(carry, params, device) -> bool:
 
 def parse_buckets(spec: str, max_seq_len: int) -> Tuple[int, ...]:
     """``--prefill-buckets`` spec -> sorted bucket lengths. ``"pow2"``:
-    powers of two from 16 up to max_seq_len; ``"a,b,c"``: explicit;
-    ``""``/``"off"``: disabled (one prefill compile per novel length)."""
+    powers of two from 16 up to max_seq_len; ``"a,b,c"``: explicit. An
+    empty spec or ``"off"`` is refused: the staged buffers' widths are
+    the buckets."""
     if not spec or spec == "off":
-        return ()
+        raise ValueError(_NO_HOST_PREFILL)
     if spec == "pow2":
         out, b = [], 16
         while b < max_seq_len:
@@ -396,10 +418,10 @@ class _Slot:
     spec_rounds: int = 0
     spec_accepted: int = 0  # drafts accepted across this slot's rounds
     spec_drafted: int = 0  # drafts proposed (rounds x depth while on)
-    # prompt tokens the in-scan prefill has yet to consume (0 = decoding;
-    # host-prefill admissions are always 0). The host mirror of the
-    # device-side ``plen - t`` — deterministic, so no readback is needed
-    # to know when a slot starts emitting.
+    # prompt tokens the in-scan prefill has yet to consume (0 = decoding,
+    # as a resumed session always is). The host mirror of the device-side
+    # ``plen - t`` — deterministic, so no readback is needed to know when
+    # a slot starts emitting.
     prompt_remaining: int = 0
     # boundaries this slot waited mid-prompt and was passed over, since
     # admission or its last piece: the unified program's ``pwait`` row
@@ -438,8 +460,8 @@ class SlotEngine:
         slots: int = 8,
         chunk: int = 16,
         clock: Callable[[], float] = time.monotonic,
-        prefill_buckets: Tuple[int, ...] = (),
-        prefill_chunk: int = 0,
+        prefill_buckets: Optional[Tuple[int, ...]] = None,
+        prefill_chunk: int = PREFILL_CHUNK,
         prompt_overflow: str = "error",
         on_event: Optional[Callable[[str, dict], None]] = None,
         prefix_store: Optional[Any] = None,
@@ -528,40 +550,35 @@ class SlotEngine:
         # obs-device-sync gate this). The Server wires it to its flight
         # recorder / metrics registry.
         self._on_event = on_event
-        self.buckets = tuple(prefill_buckets)
-        self.prompt_overflow = prompt_overflow
         cfg = model.cfg
-        # in-scan chunked prefill (prefill_chunk > 0): admission stages
-        # the prompt into the carry and the unified chunk program runs a
-        # piece of at most prefill_chunk tokens for each waiting slot,
-        # up to slots // chunk a boundary — no host-side prefill call,
-        # no head-of-line stall. 0 = the legacy host-prefill admission.
-        self.prefill_chunk = 0
-        # the linear-attention chunk the in-scan piece boundaries align
-        # to — also the prefix store's entry alignment (a cached state at
-        # a non-chunk position could not extend bitwise)
-        self.chunk_align = 0
-        if prefill_chunk:
-            from orion_tpu.ops.dispatch import resolve, resolve_chunk
-
-            if not self.buckets:
-                # staged buffers need a bounded width set — refusing is
-                # better than silently overriding an explicit
-                # prefill_buckets="off" (whose one-compile-per-length
-                # semantics in-scan staging cannot deliver)
-                raise ValueError(
-                    "in-scan prefill (prefill_chunk > 0) needs prompt "
-                    "buckets to bound the staged-buffer widths; set "
-                    "prefill_buckets (e.g. 'pow2') or prefill_chunk=0 "
-                    "for host-side prefill"
-                )
-            # piece boundaries must land on linear-attention chunk
-            # boundaries (the left-fold bitwise contract — see
-            # ops/linear_attention.py return_zcum): round the knob up
-            c = resolve_chunk(cfg.chunk, cfg.max_seq_len,
-                              resolve(cfg.backend))
-            self.prefill_chunk = -(-int(prefill_chunk) // c) * c
-            self.chunk_align = c
+        # the staged buffers' widths (and the re-prefill rung's and the
+        # prefix publish's pad-to lengths): a bounded set, so the unified
+        # program's compile key is bounded too
+        self.buckets = (
+            parse_buckets("pow2", cfg.max_seq_len)
+            if prefill_buckets is None else tuple(prefill_buckets)
+        )
+        if not self.buckets or int(prefill_chunk) <= 0:
+            raise ValueError(
+                f"{_NO_HOST_PREFILL}; got prefill_buckets="
+                f"{self.buckets}, prefill_chunk={prefill_chunk}"
+            )
+        self.prompt_overflow = prompt_overflow
+        # in-scan chunked prefill: admission stages the prompt into the
+        # carry and the unified chunk program runs a piece of at most
+        # prefill_chunk tokens for each waiting slot, up to slots // chunk
+        # a boundary — no prefill call on the host thread, no
+        # head-of-line stall. Piece boundaries must land on
+        # linear-attention chunk boundaries (the left fold — see
+        # ops/linear_attention.py return_zcum): round the knob up.
+        # ``chunk_align`` is also the prefix store's entry alignment (a
+        # cached state at a non-chunk position could not extend)
+        self.chunk_align = resolve_chunk(
+            cfg.chunk, cfg.max_seq_len, resolve(cfg.backend)
+        )
+        self.prefill_chunk = (
+            -(-int(prefill_chunk) // self.chunk_align) * self.chunk_align
+        )
         # content-addressed prefix cache (serving/prefix_store.py): a hit
         # stages the cached state row at its position and in-scan
         # prefills only the suffix — O(prompt) admission becomes
@@ -679,22 +696,15 @@ class SlotEngine:
             self._on_event(kind, fields)
 
     def attach_prefix_store(self, store) -> None:
-        """Wire a :class:`~orion_tpu.serving.prefix_store.PrefixStore`.
-        Requires in-scan prefill (the hit path IS "stage with the cached
-        state at position t0 and let the scan consume the suffix" — the
-        host-prefill admission path has no staging to ride) and an entry
-        alignment on this engine's linear-attention chunk boundaries."""
-        if not self.prefill_chunk:
-            raise ValueError(
-                "the prefix cache rides in-scan prefill (a hit stages the "
-                "cached state and scan-consumes only the suffix); set "
-                "prefill_chunk > 0 or drop the prefix store"
-            )
+        """Wire a :class:`~orion_tpu.serving.prefix_store.PrefixStore`
+        (a hit stages the cached state at position t0 and the scan
+        consumes the suffix). Requires an entry alignment on this
+        engine's linear-attention chunk boundaries."""
         if store.align % self.chunk_align != 0:
             raise ValueError(
                 f"prefix store alignment {store.align} is not a multiple "
                 f"of the linear-attention chunk {self.chunk_align}: "
-                "entries at non-chunk positions cannot extend bitwise"
+                "entries at non-chunk positions cannot extend"
             )
         self.prefix_store = store
 
@@ -964,12 +974,11 @@ class SlotEngine:
         sample_index: int = 0,
         seed: Optional[int] = None,
     ) -> int:
-        """Admit ``request`` into a free slot. With in-scan prefill and
-        no prefix hit this is the HOST half only: the slot is claimed and
-        the prompt joins the rows the boundary's one staging dispatch
-        writes (:meth:`_flush_staged`); a prefix hit stages its cached
-        row at once, and ``prefill_chunk=0`` prefills solo and inserts.
-        Raises ValueError for requests the engine cannot multiplex (no
+        """Admit ``request`` into a free slot. Without a prefix hit this
+        is the HOST half only: the slot is claimed and the prompt joins
+        the rows the boundary's one staging dispatch writes
+        (:meth:`_flush_staged`); a prefix hit stages its cached row at
+        once. No prefill runs here. Raises ValueError for requests the engine cannot multiplex (no
         free slot, batch != 1, over-capacity, or a SampleConfig differing
         from the resident batch's static config); the caller decides
         whether that fails the request or reroutes it.
@@ -1002,32 +1011,23 @@ class SlotEngine:
             session_id = request.session_id
         seed = request.seed if seed is None else seed
         key = _seed_key(seed)
-        remaining = prompt.shape[1] if self.prefill_chunk else 0
-        if self.prefill_chunk:
-            # O(1) in-scan admission: no prefill here — the prompt is
-            # staged into the carry and consumed prefill_chunk tokens per
-            # boundary inside the batched scan. With a prefix store, a
-            # content hit stages the cached state row at its position
-            # instead, so the scan consumes only the uncached suffix.
-            entry = self._prefix_lookup(request, prompt, tag)
-            if entry is not None:
-                self._stage_prefix(i, prompt, jnp.asarray(key), sample_index,
-                                   entry)
-                remaining = prompt.shape[1] - entry.t
-            else:
-                # the host half only: the row waits for the boundary's
-                # ONE staging dispatch (:meth:`_flush_staged`)
-                self._grow_staging(prompt.shape[1])
-                self._staged.append((i, prompt[0], sample_index, key))
-                self._queue_prefix_publish(request, int(prompt.shape[1]))
+        remaining = prompt.shape[1]
+        # O(1) in-scan admission: no prefill here — the prompt is staged
+        # into the carry and consumed prefill_chunk tokens per boundary
+        # inside the batched scan. With a prefix store, a content hit
+        # stages the cached state row at its position instead, so the
+        # scan consumes only the uncached suffix.
+        entry = self._prefix_lookup(request, prompt, tag)
+        if entry is not None:
+            self._stage_prefix(i, prompt, jnp.asarray(key), sample_index,
+                               entry)
+            remaining -= entry.t
         else:
-            rng = jnp.asarray(key)
-            sub = prefill_carry(
-                self.model, self.params, prompt, self._sample, rng,
-                sample_index=sample_index, buckets=self.buckets,
-                exec_lookup=self._warm_prefill_exec,
-            )
-            self._insert(i, sub, rng, n_emitted=sample_index)
+            # the host half only: the row waits for the boundary's ONE
+            # staging dispatch (:meth:`_flush_staged`)
+            self._grow_staging(prompt.shape[1])
+            self._staged.append((i, prompt[0], sample_index, key))
+            self._queue_prefix_publish(request, int(prompt.shape[1]))
         self._slots[i] = _Slot(
             request=request,
             tag=tag,
@@ -1042,7 +1042,6 @@ class SlotEngine:
         )
         self._emit(
             "admit", slot=i, tag=tag,
-            staged=bool(self.prefill_chunk),
             prompt_len=int(prompt.shape[1]),
             session=session_id,
         )
@@ -1058,8 +1057,6 @@ class SlotEngine:
         the largest bucket IS max_seq_len, so clamping to it would just
         trip the capacity check instead of serving the request); if no
         bucket leaves room, the request is refused like the error mode."""
-        if not self.buckets:
-            return prompt
         if bucket_for(prompt.shape[1], self.buckets) is not None:
             return prompt
         if self.prompt_overflow == "clamp":
@@ -1251,7 +1248,7 @@ class SlotEngine:
     @_serialized
     def publish_pending_prefixes(self) -> int:
         """Publish queued prefix snapshots: prefill the prefix solo (the
-        bucketed host-prefill compile, one per bucket) and hand the
+        bucketed whole-prompt program, one compile per bucket) and hand the
         state to the store, which serializes on its side. A failed
         publish degrades to "not cached" with a warning — the cache must
         never fail the serving path. Returns how many entries written.
@@ -1264,7 +1261,8 @@ class SlotEngine:
         prefill: pieces advance ``t`` by ``prefill_chunk`` steps, so the
         scan's state never sits exactly at the declared aligned length
         to be extracted for free (and the publish must not change the
-        piece schedule, which is part of the bitwise contract)."""
+        piece schedule: a replayed boundary is bit-for-bit only under the
+        schedule it first ran)."""
         self._flush_staged()
         done = 0
         br = self.prefix_store.breaker
@@ -1283,7 +1281,7 @@ class SlotEngine:
                     continue
                 carry = prefill_carry(
                     self.model, self.params, row, self._sample,
-                    jax.random.PRNGKey(0), buckets=self.buckets,
+                    jax.random.PRNGKey(0), self.buckets,
                     exec_lookup=self._warm_prefill_exec,
                 )
                 gen = self.prefix_store.publish(row, carry[1])
@@ -1399,8 +1397,7 @@ class SlotEngine:
         finished: List[Tuple[Any, DecodeResult]] = []
         self.last_boundary = []
         self.moe_rows = np.zeros((4,), np.int64)
-        # deadlines are checked BEFORE paying for the chunk, like the solo
-        # session's boundary check
+        # deadlines are checked BEFORE paying for the chunk
         now = self._clock()
         for i, slot in enumerate(self._slots):
             if slot is not None and slot.deadline_at is not None and now >= slot.deadline_at:
@@ -1590,7 +1587,7 @@ class SlotEngine:
         the buffer holds — which also keeps piece boundaries trivially
         chunk-aligned). How many pieces a boundary runs is
         ``generate.prefill_piece_cap(slots, chunk)``."""
-        if not self.prefill_chunk or self._pbuf is None:
+        if self._pbuf is None:
             return self.prefill_chunk
         return min(self.prefill_chunk, self._pbuf.shape[1])
 
@@ -1623,8 +1620,7 @@ class SlotEngine:
 
     def _snapshot(self):
         """Container-fresh snapshot of the batched carry (O(1): jax arrays
-        are immutable; the rewind target must not alias mutated dicts —
-        the same contract as the solo session's
+        are immutable; the rewind target must not alias mutated dicts,
         ``transformer.snapshot_decode_state``)."""
         token, states, t, emit, done = self._carry
         return (token, snapshot_decode_state(states), t, emit, done)
@@ -1636,7 +1632,7 @@ class SlotEngine:
         decode program otherwise (whose compiled bytes this feature must
         not perturb; golden ``decode_batched_tiny``). Returns
         (carry, emitted, accepted-or-None). Applies any armed per-slot
-        (or legacy per-chunk) decode-state poisoning afterwards so each
+        (or per-chunk) decode-state poisoning afterwards so each
         ladder rung is deterministically reachable per slot."""
         # the FIRST launch of each program kind (per staged-buffer width
         # for the unified program — a wider bucket is a new executable)
@@ -1804,7 +1800,7 @@ class SlotEngine:
             snap2 = self._reprefill_into(snap2, i)
             self._slots[i].reprefills += 1
             rung = ("prefill_restart" if self._slots[i].prompt_remaining > 0
-                    and self.prefill_chunk else "reprefill")
+                    else "reprefill")
             self._emit("ladder", rung=rung, slot=i,
                        chunk=self._slots[i].chunks, tag=self._slots[i].tag)
         carry, toks, accepted = self._attempt(snap2, active_dev, unified, spec)
@@ -1830,8 +1826,8 @@ class SlotEngine:
 
     def _reprefill_into(self, snap, i: int):
         """Ladder rung 2 for slot ``i``: solo re-prefill of prompt + the
-        tokens emitted so far (the shared :func:`generate.reprefill_carry`
-        — identical rng/done alignment to the solo session's rung),
+        tokens emitted so far (:func:`generate.reprefill_carry`, which
+        keeps the rng/done alignment of the uninterrupted walk),
         row-written over the slot's poisoned snapshot state. For a
         resumed session the history spans turns: ``prior`` (earlier
         turns' emissions) precedes this turn's chunks, and the fold index
@@ -1842,9 +1838,9 @@ class SlotEngine:
         if slot.prompt_remaining > 0:
             # mid-prefill: nothing emitted yet — the one known-good input
             # is the staged prompt itself, so this rung RESTARTS the
-            # in-scan prefill from a zero state row (no host-side prefill
-            # sneaks back onto the admission path; the tokens come out
-            # bitwise-identical, a few boundaries later)
+            # in-scan prefill from a zero state row (the same program over
+            # the same staged prompt: the tokens come out bitwise-identical,
+            # a few boundaries later)
             slot.prompt_remaining = slot.prompt.shape[1]
             return _restart_prefill_row(snap, jnp.int32(i))
         emitted = list(slot.prior) + [
@@ -1854,7 +1850,7 @@ class SlotEngine:
         fold = slot.fold_base + slot.n_emitted
         sub = reprefill_carry(
             self.model, self.params, slot.prompt, emitted, self._sample,
-            rng, buckets=self.buckets, sample_index=fold,
+            rng, self.buckets, sample_index=fold,
             exec_lookup=self._warm_prefill_exec,
         )
         new_snap, self._rngs, self._plen, self._pfold = _insert_carry(
@@ -1970,7 +1966,7 @@ class SlotEngine:
         the SIGTERM drain path: conversations survive the restart as one
         O(1) snapshot each instead of holding the drain hostage for their
         remaining tokens. Sessionless slots are untouched (they drain to
-        completion, the PR 4/5 contract)."""
+        completion)."""
         out = []
         for i, slot in enumerate(self._slots):
             if slot is not None and slot.session_id is not None:

@@ -378,7 +378,7 @@ class ExecStore:
                         stacklevel=2,
                     )
                     continue
-                exe = self._deserialize(key, gen, blob)
+                exe = self._deserialize(key, gen, blob, doc)
                 if exe is None:
                     continue
                 self._observe("load", t0, len(blob))
@@ -432,15 +432,27 @@ class ExecStore:
             raise ValueError(f"exec {key} gen {gen}: payload sha mismatch")
         return blob, doc
 
-    def _deserialize(self, key: str, gen: int, blob: bytes) -> Optional[Any]:
-        """Pickle triple -> loaded executable; None (counted, warned) on
-        any failure — the backend gets the final say on whether this
-        artifact is loadable, and its refusal is a miss, not an error."""
+    def _deserialize(self, key: str, gen: int, blob: bytes,
+                     doc: dict) -> Optional[Any]:
+        """Pickle triple -> executable loaded onto the devices it was
+        compiled for (the manifest's ``devices``, by id: one for an
+        unsharded engine, the mesh's for a tp engine — jax's default is
+        every visible device, which refuses a one-device program's
+        arguments); None (counted, warned) on any failure — the backend
+        gets the final say on whether this artifact is loadable, and its
+        refusal is a miss, not an error."""
+        import jax
         from jax.experimental import serialize_executable as se
 
         try:
             payload, in_tree, out_tree = pickle.loads(blob)
-            return se.deserialize_and_load(payload, in_tree, out_tree)
+            devices = None
+            if doc.get("devices"):
+                by_id = {d.id: d for d in jax.devices()}
+                devices = [by_id[i] for i in doc["devices"]]
+            return se.deserialize_and_load(
+                payload, in_tree, out_tree, execution_devices=devices
+            )
         except Exception as e:
             self.stats["errors"] += 1
             warnings.warn(
@@ -512,6 +524,9 @@ class ExecStore:
             "runtime": runtime_fingerprint(),
             "decl": decl_fingerprint(str(ident.get("kind", ""))),
             "ident": dict(ident),
+            "devices": [
+                d.id for d in compiled.runtime_executable().local_devices()
+            ],
             "sample": sample,
             "generation": gen,
             "nbytes": len(blob),

@@ -23,9 +23,15 @@ the Server derives a config-hash default and the CLIs pin the checkpoint
 identity.
 
 Alignment: entries are published only at multiples of ``align`` (the
-linear-attention chunk), because the in-scan prefill's bitwise contract
-requires every piece boundary on a chunk boundary
-(``transformer.prefill_extend`` / ops/linear_attention.py). A lookup
+linear-attention chunk), because the in-scan prefill extends a state only
+from a chunk boundary: every piece boundary lies on one
+(``transformer.prefill_extend`` / ops/linear_attention.py). A published
+state is the whole-prompt prefill's (``generate.prefill_carry``), so a hit
+continues from a state that agrees with the one the cold request's own
+pieces build to fp32 rounding on XLA:CPU, and the hit's TOKENS are the
+cold request's on every pinned seed; on the chip, agreement is the cells'
+`correct` tolerance (ROADMAP C12). What is bit-for-bit is the round trip:
+a loaded entry is the published bytes (per-leaf crc32). A lookup
 probes the aligned prefix lengths of the prompt longest-first — each
 probe is one sha256 over the candidate's token bytes plus one directory
 check, host-only ("hash + disk only"; the ``decode-host-sync`` lint keeps
@@ -123,8 +129,8 @@ class PrefixStore:
     """Content-addressed prefix snapshots under ``directory/<key>/``.
 
     ``align``: candidate prefix lengths are multiples of this (the
-    engine's linear-attention chunk — piece boundaries must land on chunk
-    boundaries for the in-scan bitwise contract). ``max_probes`` bounds
+    engine's linear-attention chunk — in-scan piece boundaries land on
+    chunk boundaries). ``max_probes`` bounds
     the per-lookup candidate walk (longest candidates first).
     ``observer``: host-only telemetry tap ``(op, ms, nbytes)`` with op in
     {"load", "save"} after each completed store I/O."""
@@ -471,8 +477,8 @@ class PrefixStore:
         if toks.shape[1] % self.align != 0 or toks.shape[1] == 0:
             raise ValueError(
                 f"prefix length {toks.shape[1]} is not a positive multiple "
-                f"of the alignment {self.align}: the in-scan bitwise "
-                "contract needs piece boundaries on chunk boundaries"
+                f"of the alignment {self.align}: in-scan pieces extend "
+                "a state only from a chunk boundary"
             )
         if self.breaker is not None and not self.breaker.allow():
             raise StoreUnavailableError("prefix")

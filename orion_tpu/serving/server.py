@@ -79,6 +79,7 @@ from orion_tpu.resilience.inject import fire
 from orion_tpu.resilience.preempt import PreemptionGuard
 from orion_tpu.resilience.retry import RetryPolicy, call_with_retries
 from orion_tpu.resilience.watchdog import Watchdog
+from orion_tpu.serving.batching import PREFILL_CHUNK, SlotEngine, parse_buckets
 from orion_tpu.serving.health import HTTP_STATUS, Health, HealthMachine
 from orion_tpu.serving.session import DecodeRequest, DecodeResult
 from orion_tpu.serving.session_store import SessionState, SessionStore
@@ -179,13 +180,13 @@ class ServeConfig:
     stall_timeout: float = 0.0  # watchdog heartbeat budget (0 = off)
     grace: float = 30.0  # SIGTERM drain budget, as in training
     poll: float = 0.05  # idle queue poll cadence (seconds)
-    prefill_buckets: str = "pow2"  # pad-to-bucket prompt lengths ("" = off)
+    # pad-to-bucket prompt lengths, the staged buffers' widths
+    prefill_buckets: str = "pow2"
     # in-scan chunked prefill: the width of ONE slot's prompt piece
     # (rounded up to the linear-attention chunk); a boundary runs a piece
     # for each waiting slot, up to slots // chunk of them, before its
-    # decode scan. 0 = legacy host-thread prefill at admission (the
-    # head-of-line-blocking path, kept for comparison).
-    prefill_chunk: int = 64
+    # decode scan. Must be > 0: there is no other admission path.
+    prefill_chunk: int = PREFILL_CHUNK
     # prompts longer than the largest prefill bucket: "error" refuses the
     # request cleanly; "clamp" serves the newest bucket-sized context
     prompt_overflow: str = "error"
@@ -194,16 +195,15 @@ class ServeConfig:
     # construction (per-out-channel scales, weights stored int8 /
     # nibble-packed int4) and shared by every slot — each decode step
     # then streams 1/4 (1/8) of the fp32 weight bytes. The state stays
-    # fp32/bf16 (only weights quantize), so every bitwise contract —
-    # batched-vs-solo parity, ladder rewind, session suspend/resume,
-    # in-scan == host prefill — holds unchanged PER qmode: quantization
-    # changes the numbers, never the determinism.
+    # fp32/bf16 (only weights quantize), so every contract — batched
+    # tokens == the solo scan's, ladder rewind and session suspend/resume
+    # bit for bit — holds unchanged PER qmode: quantization changes the
+    # numbers, never the determinism.
     qmode: str = "off"
     # -- content-addressed prefix cache (serving/prefix_store.py);
-    # None = disabled. Needs in-scan prefill (prefill_chunk > 0): a hit
-    # admits as one cached-state row copy + in-scan prefill of only the
-    # uncached suffix — O(prompt) admission becomes O(suffix). Shared by
-    # every replica pointing at the same directory.
+    # None = disabled. A hit admits as one cached-state row copy + in-scan
+    # prefill of only the uncached suffix — O(prompt) admission becomes
+    # O(suffix). Shared by every replica pointing at the same directory.
     prefix_dir: Optional[str] = None
     prefix_keep: int = 2  # retained generations per prefix entry
     # identity of the WEIGHTS for prefix-cache addressing (config name +
@@ -454,7 +454,6 @@ class Server:
 
     def _build(self, model, params, cfg, clock, flight) -> None:
         from orion_tpu import generate as _gen
-        from orion_tpu.serving.batching import SlotEngine, parse_buckets
 
         self.cfg = cfg
         self._clock = clock
@@ -1149,7 +1148,7 @@ class Server:
 
             entries = decode_cost_entries(
                 model.cfg, slots=self.cfg.slots, chunk=self.cfg.chunk,
-                bucket=max(self.engine.buckets) if self.engine.buckets else 0,
+                bucket=max(self.engine.buckets),
                 prefill_chunk=self.engine.prefill_chunk,
                 qmode=self.qmode, tp=self.tp,
                 spec_depth=self.cfg.spec_depth,
@@ -1445,11 +1444,6 @@ class Server:
             self.trace.instant(kind, id=rid,
                                session=fields.get("session"),
                                slot=fields.get("slot"))
-            if (kind == "admit" and not fields.get("staged")
-                    and isinstance(tag, Pending)):
-                # host-side prefill (prefill_chunk=0): the solo prefill
-                # the engine just ran sampled the request's first token
-                self._first_token(tag, self._clock())
         elif kind == "prefix_hit":
             self._c_prefix_hits.inc()
             self.trace.instant("prefix_hit", id=rid,

@@ -1,70 +1,19 @@
-"""DecodeSession: one request's chunked, fault-tolerant decode walk.
+"""The request and result records of the serving path.
 
-``generate()`` runs the whole decode as ONE monolithic ``lax.scan`` — fast,
-but a single NaN in the recurrent (S, z) kv-cumsum state poisons every
-remaining step with no observation point, and nothing host-side (deadline,
-SIGTERM bookkeeping, watchdog beat) can happen until all N tokens are done.
-The session instead decodes in bounded chunks (``generate.decode_chunk``,
-same scan body — bitwise-identical at a fixed rng) and uses the chunk
-boundaries as its control points:
-
-- **snapshot** — the carry at each boundary is kept as the rewind target
-  (O(1): jax arrays are immutable, the snapshot is container-fresh
-  aliasing, ``models.transformer.snapshot_decode_state``).
-- **probe** — a cheap jitted all-finite reduction over the decode state
-  (``decode_state_finite``); the one scalar-bool host sync per chunk is
-  the serving path's DESIGNATED sync point (analysis rule
-  ``decode-host-sync`` flags any other).
-- **degradation ladder** — on a non-finite state: (1) rewind to the last
-  finite snapshot and redo the chunk (clears transient corruption — a bit
-  flip, an injected fault); (2) rebuild state from scratch by
-  re-prefilling the prompt plus every token emitted so far (clears a
-  poisoned snapshot); (3) fail the REQUEST with status ``"failed"`` —
-  never the process.
-- **deadline** — enforced at chunk granularity against an injectable
-  clock; an expired request returns its partial tokens with status
-  ``"deadline"``.
-- **fault hooks** — ``fire("serve.chunk", step=chunk_idx)`` at every
-  boundary (where chaos tests deliver a real mid-request SIGTERM) and the
-  ``decode.state_nan`` marker consumed after each chunk attempt, so every
-  rung of the ladder is deterministically reachable.
-
-Re-prefill caveat: rows that already emitted EOS are rebuilt from their
-PAD-filled emitted tail rather than the raw post-EOS samples the
-monolithic scan would have carried — those rows are done and keep
-emitting PAD either way, but their dead-state contents differ from an
-uninterrupted run's.
+A :class:`DecodeRequest` is what a client submits and a :class:`DecodeResult`
+what it gets back; :class:`~orion_tpu.serving.batching.SlotEngine` is the one
+engine that turns the first into the second (a one-slot engine is the solo
+case).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import time
-from typing import Any, Callable, List, Optional
+from typing import Any, Optional
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from orion_tpu.generate import (
-    SampleConfig,
-    decode_chunk,
-    prefill_carry,
-    reprefill_carry,
-)
-from orion_tpu.models.transformer import (
-    decode_state_finite,
-    snapshot_decode_state,
-)
-from orion_tpu.obs import flight
-from orion_tpu.resilience.inject import decode_nan_armed, fire
-
-Array = jax.Array
-
-
-class LadderExhausted(RuntimeError):
-    """Every rung of the degradation ladder produced non-finite decode
-    state; the request is failed (the process keeps serving)."""
+from orion_tpu.generate import SampleConfig
 
 
 @dataclasses.dataclass(frozen=True)
@@ -111,8 +60,8 @@ class DecodeResult:
     chunks: int
     rewinds: int = 0
     reprefills: int = 0
-    # -- cost attribution (ISSUE 15; batched Server path only — the solo
-    # DecodeSession reports zeros): this request's share of the measured
+    # -- cost attribution (ISSUE 15; filled by the Server, zeros from a
+    # bare engine): this request's share of the measured
     # chunk wall time (shares across co-residents sum to the boundary's
     # chunk_ms — conservation), the ledger-derived flops billed, and the
     # device prefill/decode token counts behind them
@@ -130,185 +79,4 @@ class DecodeResult:
         return self.rewinds > 0 or self.reprefills > 0
 
 
-def _poison_states(states):
-    """NaN-fill every floating leaf of the decode state — the injected
-    fault's effect, applied host-side the way the trainer's NaN-gradient
-    poisoning is (resilience/inject.py docstring)."""
-    def leaf(x):
-        if jnp.issubdtype(x.dtype, jnp.floating):
-            return jnp.full_like(x, jnp.nan)
-        return x
-
-    return jax.tree.map(leaf, states)
-
-
-class DecodeSession:
-    """Chunked decode with snapshots, the finite probe, and the
-    degradation ladder. One session serves many requests (the jit caches
-    for prefill and the chunk bodies are shared); it owns no threads and
-    installs no handlers — that is the Server's job."""
-
-    def __init__(
-        self,
-        model,
-        params,
-        *,
-        chunk: int = 16,
-        clock: Callable[[], float] = time.monotonic,
-    ):
-        assert chunk > 0, chunk
-        self.model = model
-        self.params = params
-        self.chunk = int(chunk)
-        self._clock = clock
-
-    # -- probes / ladder internals -------------------------------------------
-
-    def _probe_finite(self, carry) -> bool:
-        """The designated host-sync point of the serving decode loop: one
-        scalar bool crosses the device boundary per chunk (analysis rule
-        ``decode-host-sync`` allows syncs only inside probe functions)."""
-        return bool(decode_state_finite(carry[1]))
-
-    def _attempt(self, carry, rng, start, n_steps, sample, chunk_idx):
-        """One chunk attempt from ``carry``; consumes an armed
-        decode-state NaN fault afterwards so multi-delivery plans poison
-        each ladder rung's retry in turn."""
-        carry, toks = decode_chunk(
-            self.model, self.params, carry, rng, start, n_steps, sample
-        )
-        if decode_nan_armed(chunk_idx):
-            carry = (carry[0], _poison_states(carry[1]), carry[2], carry[3])
-        return carry, toks
-
-    def _reprefill(self, prompt, emitted: List[Array], n: int, sample, rng):
-        """Ladder rung 2: rebuild the decode carry by re-prefilling the
-        prompt plus the ``n`` tokens emitted so far (the shared
-        :func:`generate.reprefill_carry` — one definition of the rung's
-        rng/done alignment for the solo and slot-multiplexed paths)."""
-        del n  # implied by the emitted tokens
-        return reprefill_carry(
-            self.model, self.params, prompt, emitted, sample, rng
-        )
-
-    def _chunk_with_ladder(
-        self, prompt, emitted, snap, rng, n, n_steps, sample, chunk_idx
-    ):
-        """Advance one chunk, walking the degradation ladder on non-finite
-        state. Returns (carry, tokens, rewinds, reprefills) or raises
-        :class:`LadderExhausted`."""
-        carry, toks = self._attempt(snap, rng, n, n_steps, sample, chunk_idx)
-        if self._probe_finite(carry):
-            return carry, toks, 0, 0
-        # rung 1: rewind to the last finite boundary snapshot and redo —
-        # transient corruption (injected fault, bit flip) won't recur
-        # (each rung leaves a black-box event: the solo session feeds the
-        # process-default flight ring, obs/flight.py)
-        flight.record("ladder", rung="rewind", chunk=chunk_idx)
-        carry, toks = self._attempt(snap, rng, n, n_steps, sample, chunk_idx)
-        if self._probe_finite(carry):
-            return carry, toks, 1, 0
-        # rung 2: the snapshot itself may be poisoned — rebuild the state
-        # from the tokens, the one thing known good (they were emitted)
-        flight.record("ladder", rung="reprefill", chunk=chunk_idx)
-        fresh = self._reprefill(prompt, emitted, n, sample, rng)
-        carry, toks = self._attempt(fresh, rng, n, n_steps, sample, chunk_idx)
-        if self._probe_finite(carry):
-            return carry, toks, 1, 1
-        flight.record("ladder", rung="exhausted", chunk=chunk_idx)
-        raise LadderExhausted(
-            f"decode state non-finite at chunk {chunk_idx} after rewind "
-            "and re-prefill; failing the request"
-        )
-
-    # -- request entrypoint ---------------------------------------------------
-
-    def run(
-        self,
-        request: DecodeRequest,
-        on_chunk: Optional[Callable[[int], None]] = None,
-        deadline_at: Optional[float] = None,
-    ) -> DecodeResult:
-        """Serve one request. ``on_chunk(chunk_idx)`` runs at every chunk
-        boundary (the Server's watchdog beat + drain check). Never raises
-        for decode-state faults or deadlines — those come back as the
-        result's ``status``; only programmer errors (bad shapes) raise.
-
-        ``deadline_at`` is an ABSOLUTE clock value overriding the
-        request's relative ``deadline_ms``: the Server anchors it at
-        admission time, so queue wait counts against the budget (a
-        request that waited out its whole deadline in the queue must not
-        decode to a too-late 'ok')."""
-        prompt = jnp.asarray(request.prompt, jnp.int32)
-        if prompt.ndim == 1:
-            prompt = prompt[None]
-        cap = self.model.cfg.max_seq_len
-        if prompt.shape[1] + request.max_new_tokens > cap:
-            raise ValueError(
-                f"prompt {prompt.shape[1]} + new {request.max_new_tokens} "
-                f"exceeds max_seq_len {cap}"
-            )
-        sample = request.sample
-        rng = jax.random.PRNGKey(request.seed)
-        if deadline_at is not None:
-            deadline = deadline_at
-        else:
-            deadline = (
-                self._clock() + request.deadline_ms / 1000.0
-                if request.deadline_ms > 0
-                else None
-            )
-        if deadline is not None and self._clock() >= deadline:
-            # already expired (queue wait ate the budget): don't even
-            # pay for the prefill
-            return DecodeResult(
-                tokens=np.zeros((prompt.shape[0], 0), np.int32),
-                status="deadline", new_tokens=0, chunks=0,
-            )
-        carry = prefill_carry(self.model, self.params, prompt, sample, rng)
-        emitted: List[Array] = []
-        n = 0
-        chunk_idx = 0
-        rewinds = reprefills = 0
-        status = "ok"
-        while n < request.max_new_tokens:
-            fire("serve.chunk", step=chunk_idx)
-            if on_chunk is not None:
-                on_chunk(chunk_idx)
-            if deadline is not None and self._clock() >= deadline:
-                status = "deadline"
-                break
-            n_steps = min(self.chunk, request.max_new_tokens - n)
-            snap = (
-                carry[0], snapshot_decode_state(carry[1]), carry[2], carry[3]
-            )
-            try:
-                carry, toks, r, rp = self._chunk_with_ladder(
-                    prompt, emitted, snap, rng, n, n_steps, sample, chunk_idx
-                )
-            except LadderExhausted:
-                status = "failed"
-                break
-            rewinds += r
-            reprefills += rp
-            emitted.append(toks)
-            n += n_steps
-            chunk_idx += 1
-        tokens = (
-            jnp.concatenate(emitted, axis=1)
-            if emitted
-            else jnp.zeros((prompt.shape[0], 0), jnp.int32)
-        )
-        return DecodeResult(
-            tokens=np.asarray(tokens),
-            status=status,
-            new_tokens=n,
-            chunks=chunk_idx,
-            rewinds=rewinds,
-            reprefills=reprefills,
-        )
-
-
-__all__ = [
-    "DecodeRequest", "DecodeResult", "DecodeSession", "LadderExhausted",
-]
+__all__ = ["DecodeRequest", "DecodeResult"]

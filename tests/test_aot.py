@@ -37,8 +37,8 @@ def test_hybrid_7b_lowers_sharded():
 def test_decode_plan_inventories_serving_programs():
     """ISSUE 14 satellite: ``aot.decode_plan`` lists EVERY executable a
     replica of a given shape compiles — the batched decode per
-    (slots, chunk, qmode, tp), the unified prefill and host bucketed
-    prefill per bucket, the spec round per depth — the complete
+    (slots, chunk, qmode, tp), the unified prefill and the whole-prompt
+    bucketed prefill per bucket, the spec round per depth — the complete
     inventory ROADMAP item 4's warm-start persistence needs. Lower-only
     keeps the test cheap; the compiled/collectives path is covered by
     the tp goldens and the CLI smoke."""
@@ -64,8 +64,8 @@ def test_decode_plan_inventories_serving_programs():
     assert {p["tp"] for p in rep["programs"]} == {1}
     # the inventory lists the pchunk the ENGINE compiles, not the raw
     # knob: SlotEngine rounds prefill_chunk up to the linear-attention
-    # chunk alignment, and prefill_chunk=0 (host-side prefill) has no
-    # unified program at all — phantom entries would defeat the
+    # chunk alignment; and a footprint no engine can have (no in-scan
+    # prefill, no buckets) is refused — phantom entries would defeat the
     # "runs precisely these executables" warm-start contract
     from orion_tpu.ops.dispatch import resolve, resolve_chunk
 
@@ -76,12 +76,9 @@ def test_decode_plan_inventories_serving_programs():
     )
     uni = [p for p in rep2["programs"] if p["kind"] == "unified_prefill"]
     assert [p["prefill_chunk"] for p in uni] == [2 * align], uni
-    rep0 = decode_plan(
-        cfg, slots=4, chunk=8, prefill_buckets=(16,),
-        prefill_chunk=0, compile_step=False,
-    )
-    kinds0 = [p["kind"] for p in rep0["programs"]]
-    assert "unified_prefill" not in kinds0 and "prefill_bucketed" in kinds0
+    with pytest.raises(ValueError, match="no engine has this footprint"):
+        decode_plan(cfg, slots=4, chunk=8, lower=False, prefill_buckets=(),
+                    prefill_chunk=16)
 
 
 def _topo_mesh_or_skip(mc):
@@ -349,9 +346,10 @@ def test_verify_decode_plan_reports_drift():
 def test_engine_lifetime_compile_count_matches_plan_prediction():
     """Acceptance: a replica's MEASURED lifetime compile count equals the
     plan's prediction — cache-stat deltas on the real jit wrappers while
-    a fresh engine serves prompts touching every declared bucket (with a
-    repeat hit proving bucket reuse does not recompile, and the plain
-    prefill wrapper proving its plan=\"never\" declaration)."""
+    a fresh engine serves, for every declared bucket, one request whose
+    staged prompt has that width and whose ladder re-prefills it at that
+    width (the whole-prompt program runs on the repair path alone), with
+    a repeat hit proving bucket reuse does not recompile."""
     from collections import Counter
 
     import jax
@@ -359,25 +357,25 @@ def test_engine_lifetime_compile_count_matches_plan_prediction():
 
     from orion_tpu.aot import decode_plan
     from orion_tpu.analysis import programs as P
-    from orion_tpu.generate import (
-        SampleConfig,
-        _decode_batched_chunk_jit,
-        _prefill_carry_bucketed_jit,
-        _prefill_carry_jit,
-    )
+    from orion_tpu.generate import SampleConfig, DECODE_PROGRAMS
     from orion_tpu.models.transformer import TransformerLM
+    from orion_tpu.resilience import inject
     from orion_tpu.serving import DecodeRequest
     from orion_tpu.serving.batching import SlotEngine
 
     # the smallest model that exercises the real wrappers: cache COUNTS
-    # are what's asserted, so one linear layer keeps the five compiles
-    # this test pays as cheap as they get
+    # are what's asserted, so one linear layer keeps the compiles this
+    # test pays as cheap as they get
     cfg = ModelConfig(
         name="aot_engine_test", vocab_size=32, d_model=16, n_layers=1,
         n_heads=2, layer_types=("linear",), window=4,
         max_seq_len=64, dtype="float32", backend="xla",
     )
     greedy = SampleConfig(temperature=0.0)
+    counted = ("decode_batched", "unified_prefill", "prefill_bucketed")
+
+    def sizes():
+        return Counter({k: DECODE_PROGRAMS[k]._cache_size() for k in counted})
 
     for fp in P.CHECK_FOOTPRINTS:
         plan_kinds = Counter(
@@ -393,31 +391,33 @@ def test_engine_lifetime_compile_count_matches_plan_prediction():
         ))
         params = model.init(jax.random.PRNGKey(0),
                             jnp.zeros((1, 8), jnp.int32))
-        before = {
-            "decode_batched": _decode_batched_chunk_jit._cache_size(),
-            "prefill_bucketed": _prefill_carry_bucketed_jit._cache_size(),
-            "prefill": _prefill_carry_jit._cache_size(),
-        }
+        before = sizes()
         eng = SlotEngine(
             model, params, slots=fp["slots"], chunk=fp["chunk"],
             prefill_buckets=fp["prefill_buckets"],
+            prefill_chunk=fp["prefill_chunk"],
         )
-        lengths = [b - 3 for b in fp["prefill_buckets"]]
-        lengths.append(fp["prefill_buckets"][-1] - 1)  # bucket reuse
-        for i, ln in enumerate(lengths):
+
+        def serve(i, ln, plan=None):
             prompt = jax.random.randint(
                 jax.random.PRNGKey(7000 + i), (1, ln), 0, cfg.vocab_size
             ).astype(jnp.int32)
-            eng.admit(DecodeRequest(prompt=prompt, max_new_tokens=6,
-                                    sample=greedy, seed=i))
-        while eng.busy:
-            eng.step()
-        measured = Counter({
-            "decode_batched": _decode_batched_chunk_jit._cache_size()
-            - before["decode_batched"],
-            "prefill_bucketed": _prefill_carry_bucketed_jit._cache_size()
-            - before["prefill_bucketed"],
-            "prefill": _prefill_carry_jit._cache_size()
-            - before["prefill"],
-        })
-        assert measured == plan_kinds, (fp, measured, plan_kinds)
+            eng.admit(DecodeRequest(prompt=prompt, sample=greedy, seed=i,
+                                    max_new_tokens=2 * fp["chunk"]), tag=i)
+            done = {}
+            with inject.inject(plan or inject.FaultPlan()):
+                while eng.busy:
+                    done.update(dict(eng.step()))
+            return done[i]
+
+        # prompt + its first chunk of tokens fills bucket b less one: the
+        # prompt stages at width b, and two poisonings of the second chunk
+        # re-prefill prompt + chunk at width b
+        for i, b in enumerate(fp["prefill_buckets"]):
+            ln = b - fp["chunk"] - 1
+            assert ln > 0 and (i == 0 or ln > fp["prefill_buckets"][i - 1])
+            r = serve(i, ln, inject.FaultPlan().poison_decode_slot_at(
+                0, chunk=1, times=2))
+            assert r.status == "ok" and r.reprefills == 1, (fp, b, r)
+        assert serve(9, fp["prefill_buckets"][-1] - 1).status == "ok"  # reuse
+        assert sizes() - before == plan_kinds, (fp, sizes() - before)

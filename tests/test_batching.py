@@ -21,7 +21,7 @@ from orion_tpu.generate import (
     _decode_batched_chunk_jit,
     _prefill_carry_bucketed_jit,
     bucket_for,
-    decode_chunk,
+    decode_batched_chunk,
     generate,
     prefill_carry,
 )
@@ -218,16 +218,15 @@ def test_one_decode_compile_per_slot_count(mp):
 
 
 def test_prefill_bucketing_bounds_compile_cache(mp):
-    """Every novel prompt length through UNBUCKETED prefill is a fresh
-    compile (the leak); bucketed prefill is bounded by the bucket count
-    no matter how many lengths traffic brings."""
+    """Bucketed prefill is bounded by the bucket count no matter how many
+    lengths traffic brings (a compile a novel prompt length would leak)."""
     model, params = mp
     buckets = (8, 16, 32)
     before = _prefill_carry_bucketed_jit._cache_size()
     for ln in range(3, 20):  # 17 distinct lengths -> 2 buckets (8, 16, 32)
         prompt = jnp.ones((1, ln), jnp.int32)
         prefill_carry(model, params, prompt, GREEDY, jax.random.PRNGKey(0),
-                      buckets=buckets)
+                      buckets)
     delta = _prefill_carry_bucketed_jit._cache_size() - before
     assert delta <= len(buckets), (
         f"{delta} prefill compiles for {len(buckets)} buckets"
@@ -235,34 +234,43 @@ def test_prefill_bucketing_bounds_compile_cache(mp):
 
 
 def test_bucketed_prefill_bitwise_equals_exact(mp):
-    """The carry out of a bucket-padded prefill must DECODE bitwise like
-    the exact-length compile's: same first token, same tokens for 16 more
-    steps (crossing the swa window, so ring-cache reconstruction under
-    padding is covered too)."""
+    """The carry out of a bucket-padded prefill must DECODE like the
+    unpadded one's (a bucket of the prompt's own length): same first
+    token, same position, same tokens for 16 more steps of the engine's
+    decode program (crossing the swa window, so ring-cache reconstruction
+    under padding is covered too)."""
     model, params = mp
+    one = jnp.ones((1,), bool)
     for ln in (3, 5, 7, 11):
         prompt = jax.random.randint(
             jax.random.PRNGKey(ln), (1, ln), 0, CFG.vocab_size
         ).astype(jnp.int32)
         rng = jax.random.PRNGKey(42)
-        exact = prefill_carry(model, params, prompt, SAMPLED, rng)
+        exact = prefill_carry(model, params, prompt, SAMPLED, rng, (ln,))
         bucketed = prefill_carry(model, params, prompt, SAMPLED, rng,
-                                 buckets=(16, 32))
+                                 (16, 32))
         np.testing.assert_array_equal(
             np.asarray(exact[0]), np.asarray(bucketed[0]),
             err_msg=f"first token, len {ln}",
         )
         assert int(exact[2]) == int(bucketed[2]) == ln
-        ce, te = decode_chunk(model, params, exact, rng, 0, 16, SAMPLED)
-        cb, tb = decode_chunk(model, params, bucketed, rng, 0, 16, SAMPLED)
+        te, tb = (
+            decode_batched_chunk(
+                model, params,
+                (tok, states, t[None], jnp.zeros((1,), jnp.int32), done),
+                rng[None], one, 16, SAMPLED,
+            )[1]
+            for tok, states, t, done in (exact, bucketed)
+        )
         np.testing.assert_array_equal(
             np.asarray(te), np.asarray(tb), err_msg=f"decode, len {ln}"
         )
 
 
 def test_parse_buckets():
-    assert parse_buckets("", 512) == ()
-    assert parse_buckets("off", 512) == ()
+    for off in ("", "off"):  # host-side prefill is gone: no bucket-less engine
+        with pytest.raises(ValueError, match="host-side prefill"):
+            parse_buckets(off, 512)
     assert parse_buckets("pow2", 512) == (16, 32, 64, 128, 256, 512)
     assert parse_buckets("pow2", 48) == (16, 32, 48)
     assert parse_buckets("32,8,64", 64) == (8, 32, 64)
@@ -415,14 +423,16 @@ def test_per_slot_deadline_evicts_one_slot_others_stream(mp):
     eng.admit(
         DecodeRequest(prompt=prompts[1], max_new_tokens=12, sample=GREEDY,
                       seed=501),
-        tag="tight", deadline_at=1.5,
+        tag="tight", deadline_at=2.5,
     )
     done = {}
     while eng.busy:
         done.update(dict(eng.step()))
         now[0] += 1.0
     assert done["tight"].status == "deadline"
-    assert done["tight"].new_tokens == 8, "2 chunks before the t=2.0 boundary"
+    # 2 slots x chunk 4 is one prompt piece a boundary: "tight" waits out
+    # the t=0 boundary behind "slow", then emits at t=1 and t=2
+    assert done["tight"].new_tokens == 8, "2 chunks before the t=3.0 boundary"
     np.testing.assert_array_equal(done["tight"].tokens, refs[1][:, :8])
     assert done["slow"].status == "ok"
     np.testing.assert_array_equal(done["slow"].tokens, refs[0])
@@ -471,7 +481,8 @@ def test_insert_extract_slot_roundtrip(mp):
     model, params = mp
     batched = init_decode_state(CFG, 4)
     prompt = jnp.ones((1, 5), jnp.int32)
-    one = prefill_carry(model, params, prompt, GREEDY, jax.random.PRNGKey(0))
+    one = prefill_carry(model, params, prompt, GREEDY, jax.random.PRNGKey(0),
+                        (8,))
     inserted = insert_decode_slot(batched, one[1], 2)
     back = extract_decode_slot(inserted, 2)
     for a, b in zip(jax.tree.leaves(back), jax.tree.leaves(one[1])):

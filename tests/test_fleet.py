@@ -751,7 +751,7 @@ def test_fleet_cli_local_roundtrip(tmp_path, capsys):
         "--set", "d_model=32", "--set", "n_layers=1", "--set", "n_heads=2",
         "--set", "max_seq_len=64",
         "--prompts-file", str(pf), "--max-new-tokens", "4",
-        "--chunk", "2", "--slots", "2", "--prefill-chunk", "0",
+        "--chunk", "2", "--slots", "2", "--prefill-chunk", "8",
         "--temperature", "0",
         "--session-dir", str(tmp_path / "store"),
     ])

@@ -7,6 +7,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from orion_tpu.generate import SampleConfig, generate, sample_logits
 from orion_tpu.models import ModelConfig, TransformerLM
@@ -207,23 +208,28 @@ def test_eos_pads_rows_independently():
     assert len(set(firsts)) > 1, f"degenerate fixture: {firsts}"
 
 
-def test_chunked_decode_matches_monolithic_bitwise():
-    """generate_chunked must reproduce generate() token-for-token at the
-    same rng for every chunking — including chunk=1 and a ragged tail —
+@pytest.mark.parametrize("chunk", [1, 3, 8, 16])
+def test_chunked_decode_matches_monolithic_bitwise(chunk):
+    """A one-slot engine must reproduce generate() token-for-token at the
+    same seed for every chunking — including chunk=1 and a ragged tail —
     with sampling filters AND eos padding active (the serving layer's
     correctness floor)."""
-    from orion_tpu.generate import generate_chunked
+    from orion_tpu.serving import DecodeRequest, SlotEngine
 
     model, params = _model_and_params()
-    prompt = jnp.ones((2, 5), jnp.int32)
+    prompt = jnp.ones((1, 5), jnp.int32)
     cfg = SampleConfig(0.8, top_k=5, top_p=0.9, eos_token=3, pad_token=0)
-    rng = jax.random.PRNGKey(9)
-    ref = np.asarray(generate(model, params, prompt, 8, cfg, rng=rng))
-    for chunk in (1, 3, 8, 16):
-        out = generate_chunked(
-            model, params, prompt, 8, chunk=chunk, sample=cfg, rng=rng
-        )
-        np.testing.assert_array_equal(np.asarray(out), ref, err_msg=f"chunk={chunk}")
+    ref = np.asarray(
+        generate(model, params, prompt, 8, cfg, rng=jax.random.PRNGKey(9))
+    )
+    eng = SlotEngine(model, params, slots=1, chunk=chunk)
+    eng.admit(DecodeRequest(prompt=prompt, max_new_tokens=8, sample=cfg,
+                            seed=9), tag="r")
+    done = {}
+    while eng.busy:
+        done.update(dict(eng.step()))
+    assert done["r"].status == "ok"
+    np.testing.assert_array_equal(done["r"].tokens, ref)
 
 
 def test_sharded_generate_parity():

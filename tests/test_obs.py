@@ -14,7 +14,7 @@ The ISSUE 10 proofs too — (4) the interpolated-quantile helper matches
 ``numpy.percentile`` to within one bucket width (inf overflow bucket and
 empty/single-sample edges included); (5) ``/healthz``'s status code
 tracks every HealthMachine transition under the PR 4 chaos scenarios;
-(6) scraping the live endpoints mid-stream leaves all four decode/
+(6) scraping the live endpoints mid-stream leaves all three decode/
 prefill jit caches untouched; (7) THE actuation chaos run: with
 ``serve.chunk_delay`` injected into replica A of a 2-replica fleet, the
 router's dispatch share shifts to B while A is still SERVING, A's
@@ -43,7 +43,6 @@ from orion_tpu.generate import (
     _decode_batched_chunk_jit,
     _decode_batched_prefill_chunk_jit,
     _prefill_carry_bucketed_jit,
-    _prefill_carry_jit,
     generate,
 )
 from orion_tpu.models.configs import ModelConfig
@@ -338,7 +337,7 @@ def test_server_stats_ride_the_registry(mp, tmp_path):
     assert {g["labels"]["cache"] for g in caches} == {
         # one gauge per entry of generate.DECODE_PROGRAMS (ISSUE 15
         # made that registry the single naming source)
-        "decode_batched", "unified_prefill", "prefill", "prefill_bucketed",
+        "decode_batched", "unified_prefill", "prefill_bucketed",
         "spec_round", "prefill_piece_donated", "decode_scan_donated",
     }
     assert any(g["value"] > 0 for g in caches), "the engine compiled SOMETHING"
@@ -567,7 +566,6 @@ def test_full_telemetry_adds_zero_compiles(mp, tmp_path):
     sizes = lambda: (  # noqa: E731
         _decode_batched_chunk_jit._cache_size(),
         _decode_batched_prefill_chunk_jit._cache_size(),
-        _prefill_carry_jit._cache_size(),
         _prefill_carry_bucketed_jit._cache_size(),
     )
     before = sizes()
@@ -962,7 +960,7 @@ def test_healthz_body_carries_store_outage_reason(mp, tmp_path):
 
 def test_live_scrape_mid_stream_adds_zero_compiles(mp, tmp_path):
     """The zero-cost acceptance: serving with the HTTP endpoint live and
-    scraped mid-stream (every ~20 ms, all four routes) leaves all four
+    scraped mid-stream (every ~20 ms, all four routes) leaves all three
     decode/prefill jit caches EXACTLY as the dark run left them — a
     scrape reads host snapshots, never a device value."""
     model, params = mp
@@ -981,7 +979,6 @@ def test_live_scrape_mid_stream_adds_zero_compiles(mp, tmp_path):
     sizes = lambda: (  # noqa: E731
         _decode_batched_chunk_jit._cache_size(),
         _decode_batched_prefill_chunk_jit._cache_size(),
-        _prefill_carry_jit._cache_size(),
         _prefill_carry_bucketed_jit._cache_size(),
     )
     before = sizes()

@@ -55,17 +55,6 @@ def spec_of(cfg, **over):
     }
 
 
-@pytest.fixture(scope="module", autouse=True)
-def release_compiled_programs():
-    """This file compiles some hundred programs (three engines, two
-    backends), and every loaded executable holds memory maps of its worker
-    process: a worker that runs it before other heavy files came within
-    reach of ``vm.max_map_count`` (65,530), where XLA's CPU compiler
-    segfaults. Drop them when the file is done."""
-    yield
-    jax.clear_caches()
-
-
 @pytest.fixture(scope="module")
 def model_params():
     cfg = tiny_cfg()

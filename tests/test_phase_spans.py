@@ -257,17 +257,21 @@ def test_first_token_at_is_the_end_of_the_boundary_that_finished_the_prompt(mp):
     srv.close()
 
 
-def test_first_token_at_admission_with_host_prefill(mp):
-    srv = _server(mp, prefill_chunk=0)
+def test_first_token_of_a_one_piece_prompt_is_not_at_admission(mp):
+    """No prefill runs at admission: a prompt shorter than one piece gets
+    its first token from its first boundary, after that boundary's
+    dispatch."""
+    srv = _server(mp)
     p = srv.submit(DecodeRequest(prompt=_prompt(0, 9), max_new_tokens=8,
                                  sample=GREEDY, seed=0))
     srv.serve(drain_when_idle=True)
     assert p.result.status == "ok"
+    events = srv.trace.events()
     first_boundary = min(
-        e["ts"] for e in srv.trace.events() if e["name"] == "serve.dispatch"
+        e["ts"] for e in events if e["name"] == "serve.dispatch"
     )
-    assert p.admitted_at <= p.first_token_at
-    assert p.first_token_at * 1e6 <= first_boundary
+    assert len([e for e in events if e["name"] == "prefill_piece"]) == 1
+    assert p.admitted_at * 1e6 <= first_boundary <= p.first_token_at * 1e6
     srv.close()
 
 

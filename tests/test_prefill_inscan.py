@@ -8,13 +8,18 @@ boundary against the one-piece program it replaced.
 
 The two acceptance proofs live here — (1) a request admitted by STAGING
 its prompt into the carry and consuming it ``prefill_chunk`` tokens per
-boundary inside the batched scan emits tokens BITWISE-identical to the
-host-prefill path (and to the solo monolithic scan) at the same seed, for
-slot counts {2, 4, 8}, greedy and sampled, staggered admission, prompt
-lengths straddling bucket / linear-chunk / piece boundaries; and (2) the
-engine's lifetime decode-compile count stays one per
-(slots, chunk, prompt_bucket) and admission itself never compiles or
-runs a prefill. Plus the satellite coverage: ladder rungs fired while a
+boundary inside the batched scan emits the TOKENS of the solo monolithic
+scan at the same seed, for slot counts {2, 4, 8}, greedy and sampled,
+staggered admission, prompt lengths straddling bucket / linear-chunk /
+piece boundaries; and (2) the engine's lifetime decode-compile count
+stays one per (slots, chunk, prompt_bucket) and admission itself never
+compiles or runs a prefill. What holds between the piecewise and the
+monolithic prefill — two XLA programs — is stated where it is tested:
+equal tokens on every pinned seed, states equal to fp32 rounding on
+XLA:CPU; on the chip, agreement is the cells' `correct` tolerance
+(ROADMAP C12). What is bit-for-bit here is what one program gives twice:
+a rewind's replay, a suspend/resume row copy, one flush against
+single-row stagings. Plus the satellite coverage: ladder rungs fired while a
 co-resident slot is mid-prefill, bucket-overflow refusal/clamping before
 any jit, mid-prefill deadline/drain behaviour, and a PR 6 session
 suspended and resumed across an in-scan-admitted turn.
@@ -35,7 +40,6 @@ from orion_tpu.generate import (
     _decode_batched_prefill_body,
     _decode_batched_prefill_chunk_jit,
     _prefill_carry_bucketed_jit,
-    _prefill_carry_jit,
     _prefill_extend_row,
     _prefill_selection,
     _sample_rows,
@@ -43,6 +47,7 @@ from orion_tpu.generate import (
     generate,
     prefill_overdue_after,
     prefill_piece_cap,
+    sample_logits,
 )
 from orion_tpu.models.configs import ModelConfig
 from orion_tpu.ops.dispatch import decode_live_rows
@@ -71,17 +76,6 @@ SAMPLED = SampleConfig(temperature=0.8, top_k=5, top_p=0.9, eos_token=3,
 BUCKETS = (8, 16, 32)
 
 
-@pytest.fixture(scope="module", autouse=True)
-def release_compiled_programs():
-    """This file leaves ~53,600 memory maps in its worker process (every
-    loaded XLA:CPU executable holds a few), and a worker that then runs
-    another engine-heavy file reaches ``vm.max_map_count`` (65,530), where the
-    next compile segfaults (PERF.md section 7, PR 43). Drop the programs when
-    the file is done."""
-    yield
-    jax.clear_caches()
-
-
 @pytest.fixture(scope="module")
 def mp():
     model = TransformerLM(CFG)
@@ -95,11 +89,11 @@ def _prompt(i, ln):
     ).astype(jnp.int32)
 
 
-def _engine(mp, mode, slots=2, chunk=4, **kw):
+def _engine(mp, slots=2, chunk=4, **kw):
     model, params = mp
     return SlotEngine(
         model, params, slots=slots, chunk=chunk, prefill_buckets=BUCKETS,
-        prefill_chunk=8 if mode == "inscan" else 0, **kw,
+        prefill_chunk=8, **kw,
     )
 
 
@@ -111,7 +105,7 @@ def _drain(eng):
 
 
 # ---------------------------------------------------------------------------
-# model layer: piecewise prefill_extend == monolithic prefill, bitwise
+# model layer: piecewise prefill_extend against monolithic prefill
 # ---------------------------------------------------------------------------
 
 
@@ -122,11 +116,17 @@ def _drain(eng):
     (13, 4),   # piece == linear-attention chunk
     (31, 12),  # piece = 3 linear chunks, ragged tail
 ])
-def test_prefill_extend_pieces_bitwise_equal_monolithic(mp, plen, pchunk):
-    """Piece-by-piece prefill_extend_step replays monolithic prefill's
-    exact op sequence: (S, z), the KV cache's real rows, the ring's
-    readable rows, and the last-real-row logits are all BITWISE equal —
-    the identity the in-scan admission path is built on."""
+def test_prefill_extend_pieces_agree_with_monolithic(mp, plen, pchunk):
+    """Piece-by-piece prefill_extend_step and monolithic prefill are two
+    XLA programs over the same left fold: (S, z), the KV cache's real rows,
+    the ring's readable rows, and the last-real-row logits agree to fp32
+    rounding, and the greedy and the sampled token drawn from those logits
+    are EQUAL — what the in-scan admission path, the ladder's re-prefill
+    rung and the prefix store's publish rest on. Measured over these five
+    cases on XLA:CPU (jax 0.9.0): logits differ by at most 1.0728836e-06
+    (of a largest logit of 2.6), the ring's rows by at most 8.3446503e-07,
+    (S, z) and the KV cache's rows by nothing; the bound below is about
+    twice that."""
     model, params = mp
     bucket = -(-plen // 8) * 8
     tokens = _prompt(plen, plen)
@@ -145,7 +145,14 @@ def test_prefill_extend_pieces_bitwise_equal_monolithic(mp, plen, pchunk):
             method="prefill_extend_step",
         )
         off += cons
-    np.testing.assert_array_equal(np.asarray(ref_logits), np.asarray(logits))
+    close = partial(np.testing.assert_allclose, rtol=2e-5, atol=2e-6)
+    close(np.asarray(logits), np.asarray(ref_logits))
+    key = jax.random.PRNGKey(plen)
+    for sample in (GREEDY, SAMPLED):
+        np.testing.assert_array_equal(
+            np.asarray(sample_logits(logits, key, sample)),
+            np.asarray(sample_logits(ref_logits, key, sample)),
+        )
     for li, (lt, sr, sg) in enumerate(
         zip(CFG.layer_types, ref_states, states)
     ):
@@ -156,21 +163,21 @@ def test_prefill_extend_pieces_bitwise_equal_monolithic(mp, plen, pchunk):
             if lt == "swa":
                 pos = np.arange(max(0, plen - CFG.window), plen)
                 a, b = a[:, :, pos % CFG.window], b[:, :, pos % CFG.window]
-            np.testing.assert_array_equal(a, b, err_msg=f"layer{li}.{key}")
+            close(b, a, err_msg=f"layer{li}.{key}")
 
 
 # ---------------------------------------------------------------------------
-# acceptance: in-scan vs host-prefill admission, bitwise, engine-level
+# acceptance: in-scan admission against the solo scan, engine-level
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("slots", [2, 4, 8])
 @pytest.mark.parametrize("sample", [GREEDY, SAMPLED], ids=["greedy", "sampled"])
-def test_inscan_bitwise_equals_host_prefill_staggered(mp, slots, sample):
+def test_inscan_tokens_equal_solo_scan_staggered(mp, slots, sample):
     """Staggered admission (one new request per boundary) with prompt
     lengths straddling bucket edges (8/16) and piece/linear-chunk
-    boundaries: every request's tokens through the in-scan engine are
-    BITWISE what the host-prefill engine and the solo scan emit."""
+    boundaries: every request's tokens through the engine are what the
+    solo scan emits."""
     model, params = mp
     lengths = [3, 8, 9, 16, 17, 21][: slots + 2]
     prompts = [_prompt(i, ln) for i, ln in enumerate(lengths)]
@@ -179,37 +186,31 @@ def test_inscan_bitwise_equals_host_prefill_staggered(mp, slots, sample):
                             rng=jax.random.PRNGKey(500 + i)))
         for i, p in enumerate(prompts)
     ]
-    results = {}
-    for mode in ("host", "inscan"):
-        eng = _engine(mp, mode, slots=slots)
-        done, pending = {}, list(enumerate(prompts))
-        while pending or eng.busy:
-            if pending and eng.has_free_slot:
-                i, p = pending.pop(0)  # ONE admission per boundary
-                eng.admit(DecodeRequest(prompt=p, max_new_tokens=8,
-                                        sample=sample, seed=500 + i), tag=i)
-            done.update(dict(eng.step()))
-        results[mode] = done
+    eng = _engine(mp, slots=slots)
+    done, pending = {}, list(enumerate(prompts))
+    while pending or eng.busy:
+        if pending and eng.has_free_slot:
+            i, p = pending.pop(0)  # ONE admission per boundary
+            eng.admit(DecodeRequest(prompt=p, max_new_tokens=8,
+                                    sample=sample, seed=500 + i), tag=i)
+        done.update(dict(eng.step()))
     for i, ref in enumerate(refs):
-        for mode in ("host", "inscan"):
-            r = results[mode][i]
-            assert r.status == "ok", (mode, i)
-            np.testing.assert_array_equal(
-                r.tokens, ref, err_msg=f"{mode} slots={slots} request {i}"
-            )
+        assert done[i].status == "ok", i
+        np.testing.assert_array_equal(
+            done[i].tokens, ref, err_msg=f"slots={slots} request {i}"
+        )
 
 
 def test_admission_is_o1_no_prefill_compile_no_prompt_work(mp):
-    """In-scan admission must not touch the prefill jits at all (the
+    """Admission must not touch the whole-prompt prefill at all (the
     bucket-overflow satellite's stronger sibling): serving prompts of
-    many lengths leaves BOTH host-prefill compile caches untouched, and
-    the unified program compiles once per (slots, chunk, bucket)."""
+    many lengths leaves its compile cache untouched, and the unified
+    program compiles once per (slots, chunk, bucket)."""
     model, params = mp
     pb_before = _prefill_carry_bucketed_jit._cache_size()
-    pe_before = _prefill_carry_jit._cache_size()
     un_before = _decode_batched_prefill_chunk_jit._cache_size()
     de_before = _decode_batched_chunk_jit._cache_size()
-    eng = _engine(mp, "inscan", slots=3, chunk=3)
+    eng = _engine(mp, slots=3, chunk=3)
     done = {}
     for i, ln in enumerate([3, 5, 7, 8, 4, 6, 2]):  # all in bucket 8
         eng.admit(DecodeRequest(prompt=_prompt(50 + i, ln),
@@ -219,10 +220,7 @@ def test_admission_is_o1_no_prefill_compile_no_prompt_work(mp):
     done.update(_drain(eng))
     assert all(r.status == "ok" for r in done.values())
     assert _prefill_carry_bucketed_jit._cache_size() == pb_before, (
-        "in-scan admission ran a host-side bucketed prefill"
-    )
-    assert _prefill_carry_jit._cache_size() == pe_before, (
-        "in-scan admission ran a host-side exact-length prefill"
+        "admission ran a whole-prompt prefill"
     )
     assert _decode_batched_prefill_chunk_jit._cache_size() - un_before == 1, (
         "the unified program must compile once per (slots, chunk, bucket)"
@@ -235,7 +233,7 @@ def test_unified_compiles_once_per_bucket(mp):
     unified compile (the staged buffer's width is the compile key);
     lengths within a bucket never add one."""
     model, params = mp
-    eng = _engine(mp, "inscan", slots=2, chunk=5)
+    eng = _engine(mp, slots=2, chunk=5)
     before = _decode_batched_prefill_chunk_jit._cache_size()
     for i, ln in enumerate([3, 7, 8]):  # bucket 8
         eng.admit(DecodeRequest(prompt=_prompt(70 + i, ln),
@@ -258,22 +256,18 @@ def test_unified_compiles_once_per_bucket(mp):
 
 def test_prompt_overflow_is_clean_error_before_any_jit(mp):
     """A prompt longer than the largest bucket is refused at admission —
-    no prefill compile, no unified compile, no slot claimed — in BOTH
-    admission modes."""
+    no prefill compile, no unified compile, no slot claimed."""
     model, params = mp
     long_prompt = _prompt(0, BUCKETS[-1] + 5)
-    for mode in ("inscan", "host"):
-        eng = _engine(mp, mode)
-        pb = _prefill_carry_bucketed_jit._cache_size()
-        pe = _prefill_carry_jit._cache_size()
-        un = _decode_batched_prefill_chunk_jit._cache_size()
-        with pytest.raises(ValueError, match="largest prefill bucket"):
-            eng.admit(DecodeRequest(prompt=long_prompt, max_new_tokens=4,
-                                    sample=GREEDY, seed=0))
-        assert not eng.busy, "the refused request must not hold a slot"
-        assert _prefill_carry_bucketed_jit._cache_size() == pb
-        assert _prefill_carry_jit._cache_size() == pe
-        assert _decode_batched_prefill_chunk_jit._cache_size() == un
+    eng = _engine(mp)
+    pb = _prefill_carry_bucketed_jit._cache_size()
+    un = _decode_batched_prefill_chunk_jit._cache_size()
+    with pytest.raises(ValueError, match="largest prefill bucket"):
+        eng.admit(DecodeRequest(prompt=long_prompt, max_new_tokens=4,
+                                sample=GREEDY, seed=0))
+    assert not eng.busy, "the refused request must not hold a slot"
+    assert _prefill_carry_bucketed_jit._cache_size() == pb
+    assert _decode_batched_prefill_chunk_jit._cache_size() == un
 
 
 def test_prompt_overflow_clamp_serves_newest_context(mp):
@@ -288,14 +282,14 @@ def test_prompt_overflow_clamp_serves_newest_context(mp):
     clamped = long_prompt[:, -BUCKETS[-1]:]  # 32 + 8 new <= cap 96
     ref = np.asarray(generate(model, params, clamped, 8, GREEDY,
                               rng=jax.random.PRNGKey(11)))
-    eng = _engine(mp, "inscan", prompt_overflow="clamp")
+    eng = _engine(mp, prompt_overflow="clamp")
     eng.admit(DecodeRequest(prompt=long_prompt, max_new_tokens=8,
                             sample=GREEDY, seed=11), tag="r")
     done = _drain(eng)
     assert done["r"].status == "ok"
     np.testing.assert_array_equal(done["r"].tokens, ref)
     # max_new 70: bucket 32 no longer fits under cap 96 -> clamp picks 16
-    eng2 = _engine(mp, "inscan", prompt_overflow="clamp")
+    eng2 = _engine(mp, prompt_overflow="clamp")
     i = eng2.admit(DecodeRequest(prompt=long_prompt, max_new_tokens=70,
                                  sample=GREEDY, seed=12), tag="r2")
     assert eng2._slots[i].prompt.shape[1] == 16
@@ -306,12 +300,26 @@ def test_prompt_overflow_clamp_serves_newest_context(mp):
 
 
 def test_inscan_requires_buckets_loudly(mp):
-    """In-scan prefill with prefill_buckets off must refuse at engine
-    construction (a silent pow2 override would ignore the user's
-    explicit choice), pointing at the two valid configurations."""
+    """An engine with no buckets, or with ``prefill_chunk=0``, must refuse
+    at construction and say that host-side prefill is gone (a silent
+    override would ignore the caller's explicit choice), and so must a
+    server and a plan of such an engine; with neither named it stages at
+    pow2 buckets."""
+    from orion_tpu.aot import decode_plan
+
     model, params = mp
-    with pytest.raises(ValueError, match="prefill_buckets"):
-        SlotEngine(model, params, slots=2, chunk=4, prefill_chunk=8)
+    gone = pytest.raises(ValueError, match="host-side prefill .* is gone")
+    for off in (dict(prefill_buckets=()), dict(prefill_chunk=0)):
+        with gone:
+            SlotEngine(model, params, slots=2, chunk=4, **off)
+    for off in (dict(prefill_buckets="off"), dict(prefill_chunk=0)):
+        with gone:
+            Server(model, params, ServeConfig(chunk=4, slots=2, **off))
+    with pytest.raises(ValueError, match="no engine has this footprint"):
+        decode_plan(CFG, slots=2, chunk=4, lower=False,
+                    prefill_buckets=BUCKETS, prefill_chunk=0)
+    eng = SlotEngine(model, params, slots=2, chunk=4)
+    assert eng.buckets == (16, 32, 64, 96) and eng.prefill_chunk == 64
 
 
 # ---------------------------------------------------------------------------
@@ -330,7 +338,7 @@ def test_rewind_during_neighbour_prefill_bitwise(mp):
                             rng=jax.random.PRNGKey(500 + i)))
         for i, p in enumerate((p0, p1))
     ]
-    eng = _engine(mp, "inscan")
+    eng = _engine(mp)
     eng.admit(DecodeRequest(prompt=p0, max_new_tokens=8, sample=GREEDY,
                             seed=500), tag=0)
     done = dict(eng.step())  # slot 0 decodes its first chunk
@@ -359,7 +367,7 @@ def test_reprefill_rung_restarts_midprefill_slot_bitwise(mp):
                             rng=jax.random.PRNGKey(600 + i)))
         for i, p in enumerate((p0, p1))
     ]
-    eng = _engine(mp, "inscan")
+    eng = _engine(mp)
     eng.admit(DecodeRequest(prompt=p0, max_new_tokens=8, sample=GREEDY,
                             seed=600), tag=0)
     eng.admit(DecodeRequest(prompt=p1, max_new_tokens=8, sample=GREEDY,
@@ -385,7 +393,7 @@ def test_deadline_mid_prefill_evicts_with_zero_tokens(mp):
     ref0 = np.asarray(generate(model, params, p0, 12, GREEDY,
                                rng=jax.random.PRNGKey(700)))
     now = [0.0]
-    eng = _engine(mp, "inscan", clock=lambda: now[0])
+    eng = _engine(mp, clock=lambda: now[0])
     eng.admit(DecodeRequest(prompt=p0, max_new_tokens=12, sample=GREEDY,
                             seed=700), tag="fast")
     eng.admit(DecodeRequest(prompt=p1, max_new_tokens=12, sample=GREEDY,
@@ -466,7 +474,7 @@ def test_drain_mid_prefill_suspends_without_snapshot(mp, tmp_path):
 
 def test_occupancy_distinguishes_prefilling_from_decoding(mp):
     model, params = mp
-    eng = _engine(mp, "inscan")
+    eng = _engine(mp)
     eng.admit(DecodeRequest(prompt=_prompt(60, 5), max_new_tokens=8,
                             sample=GREEDY, seed=0), tag=0)
     eng.admit(DecodeRequest(prompt=_prompt(61, 30), max_new_tokens=8,
@@ -538,7 +546,7 @@ def test_k_slots_admitted_before_one_boundary_bitwise(mp, k, sample):
                              sample=sample, seed=500 + i)
 
     log, tap = _pieces_by_boundary()
-    eng = _engine(mp, "inscan", **WIDE, on_event=tap)
+    eng = _engine(mp, **WIDE, on_event=tap)
     for i in range(k):
         eng.admit(request(i), tag=i)
     together = _step(eng, log)
@@ -547,7 +555,7 @@ def test_k_slots_admitted_before_one_boundary_bitwise(mp, k, sample):
         together.update(_step(eng, log))
     assert all(len(b) <= CAP for b in log)
 
-    eng = _engine(mp, "inscan", **WIDE)
+    eng = _engine(mp, **WIDE)
     one_by_one, pending = {}, list(range(k))
     while pending or eng.busy:
         if pending and eng.prefilling_count == 0:
@@ -625,7 +633,7 @@ def test_host_selection_equals_device_every_boundary(mp, monkeypatch, seed):
     prompts = [_prompt(300 + 20 * seed + i, int(ln))
                for i, ln in enumerate(lengths)]
     attempts = _recording_unified(monkeypatch)
-    eng = _engine(mp, "inscan", slots=5, chunk=2)
+    eng = _engine(mp, slots=5, chunk=2)
     picks = _recording_host(eng)
     done, pending, unified = {}, list(range(n)), 0
     while pending or eng.busy:
@@ -661,7 +669,7 @@ def test_rung3_masks_a_prefilling_slot_and_the_next_moves_up(mp, monkeypatch):
     lengths = [20, 5, 9, 30]
     prompts = [_prompt(400 + i, ln) for i, ln in enumerate(lengths)]
     attempts = _recording_unified(monkeypatch)
-    eng = _engine(mp, "inscan", slots=4, chunk=2)
+    eng = _engine(mp, slots=4, chunk=2)
     picks = _recording_host(eng)
     for i, p in enumerate(prompts):
         eng.admit(DecodeRequest(prompt=p, max_new_tokens=6, sample=GREEDY,
@@ -691,7 +699,7 @@ def test_long_prompt_beside_a_stream_of_short_ones_is_served_in_bound(mp):
     bound = prefill_overdue_after(slots, chunk) + (slots - 1) // cap
     long_prompt = _prompt(450, 30)  # four pieces of 8
     log, tap = _pieces_by_boundary()
-    eng = _engine(mp, "inscan", slots=slots, chunk=chunk, on_event=tap)
+    eng = _engine(mp, slots=slots, chunk=chunk, on_event=tap)
     eng.admit(DecodeRequest(prompt=long_prompt, max_new_tokens=4,
                             sample=GREEDY, seed=900), tag="long")
     done, shorts, worst, boundaries = {}, 0, 0, 0
@@ -758,7 +766,7 @@ def test_one_waiting_slot_leaves_every_carry_leaf_as_the_one_piece_program(
     with the only waiting slot masked out (a rung-3 replay) it runs no
     piece where the old program ran one and discarded it."""
     model, params = mp
-    eng = _engine(mp, "inscan", slots=4, chunk=2)
+    eng = _engine(mp, slots=4, chunk=2)
     eng.admit(DecodeRequest(prompt=_prompt(480, 5), max_new_tokens=12,
                             sample=sample, seed=1), tag=0)
     eng.step()  # slot 0 decodes from here on
@@ -837,7 +845,7 @@ def test_one_flush_writes_what_single_row_stagings_wrote(mp, lens, folds,
     solo tokens."""
     model, params = mp
     n = len(lens)
-    eng = _engine(mp, "inscan", slots=max(n, 2), chunk=4)
+    eng = _engine(mp, slots=max(n, 2), chunk=4)
     eng.donate_carry = donate
     _dirty(eng, n)
     want = (eng._carry, eng._rngs, eng._plen, eng._pfold, eng._pbuf)
@@ -901,7 +909,7 @@ def test_a_boundary_makes_one_staging_dispatch(mp, monkeypatch):
     """However many prompts a boundary admits (up to K), ``step`` stages
     them with ONE call; a boundary that admits nothing makes none."""
     calls = _counting_stage(monkeypatch)
-    eng = _engine(mp, "inscan", slots=6, chunk=4)
+    eng = _engine(mp, slots=6, chunk=4)
     for i, ln in enumerate((5, 11, 3)):
         eng.admit(DecodeRequest(prompt=_prompt(800 + i, ln), max_new_tokens=9,
                                 sample=GREEDY, seed=i), tag=i)
@@ -974,7 +982,7 @@ def _both_orders(mp, act, **kw):
     device state of each."""
     out = []
     for at_once in (False, True):
-        eng = _engine(mp, "inscan", slots=4, chunk=4, **kw)
+        eng = _engine(mp, slots=4, chunk=4, **kw)
 
         def admit(*a, eng=eng, at_once=at_once, **k):
             slot = eng.admit(*a, **k)
@@ -999,7 +1007,7 @@ def _request(i, ln, new=6, **kw):
 
 
 def _suspended_session(mp):
-    eng = _engine(mp, "inscan", slots=2, chunk=4)
+    eng = _engine(mp, slots=2, chunk=4)
     eng.admit(_request(0, 9, new=8), tag="s", session_id="conv")
     return _drain(eng)["s"].session
 
@@ -1031,7 +1039,7 @@ def test_prefix_hit_sees_the_rows_still_pending(mp, tmp_path):
 
     store = PrefixStore(str(tmp_path), params_id="inscan-test", align=8)
     shared = np.asarray(_prompt(950, 16))
-    first = _engine(mp, "inscan", slots=2, chunk=4, prefix_store=store)
+    first = _engine(mp, slots=2, chunk=4, prefix_store=store)
     miss = np.concatenate([shared, np.asarray(_prompt(952, 5))], axis=1)
     first.admit(DecodeRequest(prompt=miss, max_new_tokens=4, sample=SAMPLED,
                               seed=1, prefix_len=16), tag=0)

@@ -32,7 +32,6 @@ from orion_tpu.generate import (
     _decode_batched_chunk_jit,
     _decode_batched_prefill_chunk_jit,
     _prefill_carry_bucketed_jit,
-    _prefill_carry_jit,
     generate,
     quantize_for_decode,
 )
@@ -323,8 +322,7 @@ def test_prefix_hit_zero_new_compiles(mp, tmp_path):
     assert srv.metrics.counters_flat()["prefix_hits"] == 1
     caches = (
         _decode_batched_chunk_jit, _decode_batched_prefill_chunk_jit,
-        _prefill_carry_jit, _prefill_carry_bucketed_jit,
-        _stage_prefix_carry,
+        _prefill_carry_bucketed_jit, _stage_prefix_carry,
     )
     before = [c._cache_size() for c in caches]
     hit = srv.submit(DecodeRequest(prompt=_shared_prefix_prompt(3),
@@ -445,17 +443,18 @@ def test_session_refuses_cross_qmode_resume(mp, tmp_path):
     assert t3.result is not None and t3.result.status == "ok", t3.error
 
 
-def test_prefix_requires_inscan_prefill(mp, tmp_path):
-    """The hit path IS staged in-scan consumption; host-prefill servers
-    must refuse a prefix store loudly at construction."""
+def test_prefix_store_rides_the_one_admission_path(mp, tmp_path):
+    """The hit path IS staged in-scan consumption, and no other admission
+    is left to refuse a prefix store: an engine built with no prefill
+    option takes one whose alignment its linear-attention chunk divides
+    and refuses one it does not."""
     model, params = mp
-    with pytest.raises(ValueError, match="prefill_chunk"):
-        Server(model, params, ServeConfig(
-            prefix_dir=str(tmp_path / "p"), prefill_chunk=0,
-        ))
     store = PrefixStore(str(tmp_path / "q"), params_id="x", align=8)
-    with pytest.raises(ValueError, match="in-scan"):
-        SlotEngine(model, params, slots=2, chunk=4, prefix_store=store)
+    eng = SlotEngine(model, params, slots=2, chunk=4, prefix_store=store)
+    assert eng.prefix_store is store and eng.chunk_align == 8
+    odd = PrefixStore(str(tmp_path / "r"), params_id="x", align=12)
+    with pytest.raises(ValueError, match="not a multiple"):
+        SlotEngine(model, params, slots=2, chunk=4, prefix_store=odd)
 
 
 # ---------------------------------------------------------------------------

@@ -21,7 +21,6 @@ from orion_tpu.resilience import inject
 from orion_tpu.resilience.retry import RetryPolicy
 from orion_tpu.serving import (
     DecodeRequest,
-    DecodeSession,
     Health,
     HealthMachine,
     InvalidTransition,
@@ -29,6 +28,7 @@ from orion_tpu.serving import (
     RejectedError,
     ServeConfig,
     Server,
+    SlotEngine,
     load_tokenizer,
 )
 from orion_tpu.training.trainer import TrainConfig
@@ -66,6 +66,24 @@ def _req(**kw):
     base = dict(prompt=PROMPT, max_new_tokens=8, sample=GREEDY, seed=0)
     base.update(kw)
     return DecodeRequest(**base)
+
+
+def _solo(mp, chunk, **kw):
+    """A one-slot engine: the solo session."""
+    model, params = mp
+    return SlotEngine(model, params, slots=1, chunk=chunk, **kw)
+
+
+def _run(eng, request, tick=None, **admit):
+    """Serve ``request`` alone: admit, ``step()`` until the engine is not
+    busy (``tick()`` first at every boundary), return its result."""
+    eng.admit(request, tag="r", **admit)
+    done = {}
+    while eng.busy:
+        if tick is not None:
+            tick()
+        done.update(dict(eng.step()))
+    return done["r"]
 
 
 # ---------------------------------------------------------------------------
@@ -121,11 +139,10 @@ def test_injected_nan_rewinds_bitwise(mp, ref_tokens):
     """Acceptance: NaN injected into the decode state at chunk 1 — the
     session rewinds to the chunk-boundary snapshot and the completed
     request's tokens are BITWISE-identical to an uninjected run."""
-    model, params = mp
-    sess = DecodeSession(model, params, chunk=4)
+    sess = _solo(mp, chunk=4)
     plan = inject.FaultPlan().poison_decode_state_at(1)
     with inject.inject(plan):
-        r = sess.run(_req())
+        r = _run(sess, _req())
     assert plan.delivered == ["decode.state_nan@1"]
     assert r.status == "ok" and (r.rewinds, r.reprefills) == (1, 0)
     assert r.degraded
@@ -136,11 +153,10 @@ def test_persistent_nan_escalates_to_reprefill(mp, ref_tokens):
     """Two deliveries at the same chunk poison the rewind retry too — the
     ladder's second rung rebuilds state by re-prefilling prompt + emitted
     tokens, and (greedy) the output still matches the uninjected run."""
-    model, params = mp
-    sess = DecodeSession(model, params, chunk=4)
+    sess = _solo(mp, chunk=4)
     plan = inject.FaultPlan().poison_decode_state_at(1, times=2)
     with inject.inject(plan):
-        r = sess.run(_req())
+        r = _run(sess, _req())
     assert r.status == "ok" and (r.rewinds, r.reprefills) == (1, 1)
     np.testing.assert_array_equal(r.tokens, ref_tokens)
 
@@ -148,16 +164,15 @@ def test_persistent_nan_escalates_to_reprefill(mp, ref_tokens):
 def test_unrecoverable_nan_fails_request_never_process(mp, ref_tokens):
     """Unlimited deliveries exhaust the ladder: the REQUEST fails with its
     partial tokens; the session (the process, in effigy) keeps serving."""
-    model, params = mp
-    sess = DecodeSession(model, params, chunk=4)
+    sess = _solo(mp, chunk=4)
     plan = inject.FaultPlan().poison_decode_state_at(1, times=-1)
     with inject.inject(plan):
-        r = sess.run(_req())
+        r = _run(sess, _req())
     assert r.status == "failed"
     assert r.new_tokens == 4, "the finite chunk before the fault is kept"
     np.testing.assert_array_equal(r.tokens, ref_tokens[:, :4])
     # the next request on the same session is untouched
-    r2 = sess.run(_req())
+    r2 = _run(sess, _req())
     assert r2.status == "ok"
     np.testing.assert_array_equal(r2.tokens, ref_tokens)
 
@@ -167,16 +182,14 @@ def test_deadline_enforced_at_chunk_granularity(mp, ref_tokens):
     deadline: the boundary at t=3.0 refuses to start chunk 2, and the
     request returns its 2 completed chunks with status 'deadline' —
     bounded scans are what make the deadline checkable at all."""
-    model, params = mp
     now = [0.0]
-    sess = DecodeSession(model, params, chunk=2, clock=lambda: now[0])
+    sess = _solo(mp, chunk=2, clock=lambda: now[0])
 
-    def tick(chunk_idx):
+    def tick():
         now[0] += 1.0
 
-    r = sess.run(
-        _req(max_new_tokens=12, deadline_ms=2500.0), on_chunk=tick
-    )
+    r = _run(sess, _req(max_new_tokens=12, deadline_ms=2500.0), tick,
+             deadline_at=2.5)
     assert r.status == "deadline"
     assert r.new_tokens == 4 and r.chunks == 2
     np.testing.assert_array_equal(r.tokens, ref_tokens[:, :4])

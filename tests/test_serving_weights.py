@@ -54,14 +54,6 @@ def family_cfg(config: str, **over) -> ModelConfig:
     return ModelConfig(name=config, **fields)
 
 
-@pytest.fixture(scope="module", autouse=True)
-def release_compiled_programs():
-    """Twelve engines' programs: drop them when the file is done (every
-    loaded executable holds memory maps of its pytest worker)."""
-    yield
-    jax.clear_caches()
-
-
 @pytest.fixture(scope="module", params=list(FAMILIES))
 def family(request):
     config, keep = FAMILIES[request.param]

@@ -23,7 +23,8 @@ import pytest
 from orion_tpu.generate import (
     SampleConfig,
     _decode_batched_chunk_jit,
-    _prefill_carry_jit,
+    _decode_batched_prefill_chunk_jit,
+    _prefill_carry_bucketed_jit,
     generate,
 )
 from orion_tpu.models.configs import ModelConfig
@@ -571,29 +572,30 @@ def test_mismatched_continuation_sample_isolated_error(mp, tmp_path):
 def test_resume_reuses_existing_decode_compile(mp, tmp_path):
     """Suspend/resume must ride the existing (slots, chunk) jit entry: a
     whole suspend -> restart -> resume cycle adds ZERO batched-decode
-    compiles and ZERO prefill compiles (resume is a row insert, not a
-    prefill). Uses a (slots, chunk) pair unique to this test so the
-    global cache delta is attributable."""
+    compiles and ZERO prefill compiles, in-scan or whole-prompt (resume
+    is a row insert, not a prefill). Uses a (slots, chunk) pair unique to
+    this test so the global cache delta is attributable."""
     model, params = mp
     prompt = _prompt(70)
-    # host-prefill mode: bucketing off (exact-length prefill) is the
-    # configuration whose compile caches this test counts — in-scan
-    # staging (prefill_chunk > 0) requires buckets and never prefills
-    cfgkw = dict(slots=5, chunk=3, prefill_buckets="", prefill_chunk=0)
+    cfgkw = dict(slots=5, chunk=3)
     srv1 = Server(model, params, _serve_cfg(tmp_path, **cfgkw))
     _run_turn(srv1, prompt, 6, GREEDY, 1, "conv")
     srv1.close()
     decode_before = _decode_batched_chunk_jit._cache_size()
-    prefill_before = _prefill_carry_jit._cache_size()
+    prefill_before = (
+        _decode_batched_prefill_chunk_jit._cache_size(),
+        _prefill_carry_bucketed_jit._cache_size(),
+    )
     srv2 = Server(model, params, _serve_cfg(tmp_path, **cfgkw))
     p2 = _run_turn(srv2, np.zeros((1, 0), np.int32), 6, GREEDY, 1, "conv")
     assert p2.result.status == "ok"
     assert _decode_batched_chunk_jit._cache_size() == decode_before, (
         "resume must reuse the resident (slots, chunk) decode compile"
     )
-    assert _prefill_carry_jit._cache_size() == prefill_before, (
-        "an O(1) resume must not prefill"
-    )
+    assert (
+        _decode_batched_prefill_chunk_jit._cache_size(),
+        _prefill_carry_bucketed_jit._cache_size(),
+    ) == prefill_before, "an O(1) resume must not prefill"
     srv2.close()
 
 
