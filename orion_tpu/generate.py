@@ -291,12 +291,22 @@ def _sample_rows(logits: Array, keys: Array, cfg: SampleConfig) -> Array:
     )
 
 
+def _moe_counted(model) -> bool:
+    """Do the model's MoE layers honour ``live`` and count their rows
+    (``models/moe.py::masks_rows``: the dropless layers one device holds)?
+    Its decode programs then hand them the rows whose token counts and sum
+    the counters they sow."""
+    from orion_tpu.models.moe import masks_rows
+
+    return masks_rows(model.cfg, model.quant, model.mesh)
+
+
 def _counted(model, params, *args, method: str):
     """``model.apply(params, *args, method=method)`` and, for a model whose
-    MoE layers are one chip's share (``cfg.moe_held``), the row counters they
+    MoE layers count their rows (``_moe_counted``), the row counters they
     sowed as ``[4]`` int32 (``models/moe.py::stats_vector``); None for every
     other model, whose programs stay what they were."""
-    if not model.cfg.moe_held:
+    if not _moe_counted(model):
         return model.apply(params, *args, method=method), None
     from orion_tpu.models.moe import stats_vector
 
@@ -307,16 +317,16 @@ def _counted(model, params, *args, method: str):
 
 
 def _decode_step_counted(model, params, token, states, t, rows, live):
-    """One decode step; a ``moe_held`` model's layers get ``live`` [S], the
-    rows whose token counts."""
-    args = (token, states, t, rows) + ((live,) if model.cfg.moe_held else ())
+    """One decode step; MoE layers that count their rows get ``live`` [S],
+    the rows whose token counts."""
+    args = (token, states, t, rows) + ((live,) if _moe_counted(model) else ())
     return _counted(model, params, *args, method="decode_step")
 
 
 def _scan_outputs(model, ys):
     """A chunk scan's stacked outputs -> (tokens [S, n_steps], the steps'
     MoE row counters summed [4] or None)."""
-    tokens, stats = ys if model.cfg.moe_held else (ys, None)
+    tokens, stats = ys if _moe_counted(model) else (ys, None)
     return jnp.moveaxis(tokens, 0, 1), None if stats is None else stats.sum(0)
 
 
@@ -385,9 +395,9 @@ def decode_batched_chunk(
     whole serving lifetime costs ONE compile per (slot count, chunk
     length) regardless of arrival order (asserted via jit cache stats in
     tests/test_batching.py). Returns (carry, tokens [S, n_steps]); for a
-    ``cfg.moe_held`` model also the boundary's MoE row counters [4] int32
-    (``models/moe.py::STAT_NAMES``), as every slot-multiplexed program
-    below does."""
+    row-counting MoE model (``_moe_counted``) also the boundary's MoE row
+    counters [4] int32 (``models/moe.py::STAT_NAMES``), as every
+    slot-multiplexed program below does."""
     return _decode_batched_chunk_jit(
         model, params, carry, rngs, active, int(n_steps), sample_cfg
     )
@@ -504,8 +514,9 @@ def _prefill_extend_row(
     path exists to kill. Jitted so that the unified program, which holds
     the piece twice (inline and in its loop), traces and lowers the
     model's forward once. Returns (last-real-row logits [V], the
-    advanced state row), and for a ``cfg.moe_held`` model the piece's MoE
-    row counters [4] (its ``length`` real rows route; padding does not)."""
+    advanced state row), and for a row-counting MoE model
+    (``_moe_counted``) the piece's MoE row counters [4] (its ``length`` real
+    rows route; padding does not)."""
     idx = jnp.clip(offset + jnp.arange(pchunk), 0, pbuf.shape[1] - 1)
     piece = jnp.take(pbuf[sel], idx)[None]
     st1 = jax.tree.map(lambda x: x[sel][None], states)
@@ -640,7 +651,7 @@ def _decode_batched_prefill_chunk_jit(
     rem = jnp.maximum(plen - t, 0)
     order, n = _prefill_selection(active, rem, pwait, n_steps)
 
-    held = model.cfg.moe_held
+    held = _moe_counted(model)
 
     def serve(k, served):
         token, states, t, emit, *counted = served
@@ -781,21 +792,22 @@ def decode_boundary_donated(
     """One boundary on a donated carry: a prompt piece for each slot of
     ``served`` (the host's schedule, in its order), then the decode scan.
     ``carry`` is consumed. Returns (carry, tokens [S, n_steps]); for a
-    ``cfg.moe_held`` model also the TUPLE of its programs' MoE row counters
-    ([4] each, on the device: whoever syncs next sums them)."""
+    row-counting MoE model (``_moe_counted``) also the TUPLE of its
+    programs' MoE row counters ([4] each, on the device: whoever syncs next
+    sums them)."""
     counted = []
     for sel in served:
         carry = _prefill_piece_donated_jit(
             model, params, carry, rngs, pbuf, plen, pfold, jnp.int32(sel),
             int(pchunk), sample_cfg,
         )
-        if model.cfg.moe_held:
+        if _moe_counted(model):
             carry, stats = carry
             counted.append(stats)
     out = _decode_scan_donated_jit(
         model, params, carry, rngs, active, plen, int(n_steps), sample_cfg
     )
-    if not model.cfg.moe_held:
+    if not _moe_counted(model):
         return out
     return out[0], out[1], tuple(counted) + (out[2],)
 

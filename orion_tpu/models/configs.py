@@ -111,6 +111,15 @@ class ModelConfig:
     sparse_window: int = 2048
     sparse_topk: int = 64
     sparse_dense_len: int = 8192
+    # -- "indexed" layers (models/mixers/indexed.py): grouped softmax
+    # attention (rotary in the rotate-half pairing, base rotary_base) whose
+    # every token attends to the index_topk earlier tokens that a learned
+    # indexer scores highest: index_heads query heads of index_dim against
+    # ONE index key of index_dim a token, held in the cache beside K and V.
+    # index_topk 0: no such layer (every other layer's programs as they were)
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
     # the embedding's output, each residual branch (h = x + residual_scale *
     # f(norm(x))) and the final-normed hidden state before the head are
     # multiplied by these; 1.0 leaves the program as it was
@@ -242,8 +251,8 @@ class ModelConfig:
     @property
     def moe_held(self) -> bool:
         """Are the MoE layers one chip's share of an expert-parallel layer
-        (models/moe.py::_dropless_held)? Such a model's decode programs sum
-        its row counters (generate.py)."""
+        (models/moe.py::_dropless_held)? A router as wide as the experts
+        held, however that width is spelled, is no share."""
         return self.n_experts > 0 and bool(
             self.resolved_router_width != self.n_experts or self.moe_expert_offset
         )
@@ -268,7 +277,7 @@ class ModelConfig:
 # one mixer class each: models/mixers/__init__.py::MIXERS
 LAYER_TYPES = (
     "linear", "softmax", "swa", "gated_delta", "gated_softmax",
-    "decay_linear", "block_sparse", "ssm", "latent",
+    "decay_linear", "block_sparse", "ssm", "latent", "indexed",
 )
 
 
@@ -614,6 +623,46 @@ OPENPANGU_ULTRA_MOE_718B = ModelConfig(
     param_dtype="bfloat16",
 )
 
+KEYE_VL_2_0_30B_A3B = ModelConfig(
+    # Keye-VL-2.0-30B-A3B's language model at its published widths, four of
+    # its 48 layers deep: one pipeline stage that holds every expert of its
+    # layers (benchmark/configs/keye_vl_2_0_30b_a3b.json states the source,
+    # the cut and what is assumed). Every layer: grouped attention (32 query
+    # heads over 4 KV heads x 128, per-head q / k norm, rotary base 1e7)
+    # over the 2,048 cache rows a learned indexer picks for each token (16
+    # index heads x 64 against one 64-wide index key a token), then a
+    # softmax top-8 mixture of 128 SwiGLU experts of 768, renormalised over
+    # the chosen, no shared expert; untied head over 151,936; bfloat16.
+    name="keye_vl_2_0_30b_a3b",
+    vocab_size=151936,
+    d_model=2048,
+    n_layers=4,
+    layer_types=("indexed",) * 4,
+    n_heads=32,
+    n_kv_heads=4,
+    head_dim=128,
+    qk_norm="head",
+    rotary_base=1e7,
+    index_heads=16,
+    index_dim=64,
+    index_topk=2048,
+    norm="rmsnorm",
+    pos_embed="none",
+    tie_embeddings=False,
+    mlp="swiglu",
+    moe_hidden=768,
+    moe_period=1,
+    n_experts=128,
+    moe_top_k=8,
+    moe_score="softmax",
+    moe_dropless=True,
+    moe_ep_buffer=1.0,  # the buffer holds every pair: nothing can drop
+    param_init_dtype="float32",
+    max_seq_len=33280,
+    dtype="bfloat16",
+    param_dtype="bfloat16",
+)
+
 LRA_LISTOPS_LINEAR = ModelConfig(
     name="lra_listops_linear",
     vocab_size=32,  # digits + operators + specials
@@ -664,6 +713,7 @@ CONFIGS = {
         MINICPM_SALA,
         GRANITE_4_0_H_MICRO,
         OPENPANGU_ULTRA_MOE_718B,
+        KEYE_VL_2_0_30B_A3B,
         LRA_LISTOPS_LINEAR,
         LRA_LISTOPS_SOFTMAX,
         LRA_TEXT_LINEAR,

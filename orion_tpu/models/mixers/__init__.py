@@ -3,7 +3,7 @@
 ``MIXERS[layer_type]`` is the class ``models/transformer.py::Block`` builds
 as its ``attn`` submodule for every entry of ``configs.LAYER_TYPES``
 (``linear``, ``softmax`` / ``swa``, ``gated_delta``, ``gated_softmax``,
-``decay_linear``, ``block_sparse``, ``ssm``, ``latent``). A new
+``decay_linear``, ``block_sparse``, ``ssm``, ``latent``, ``indexed``). A new
 mechanism is one file here with a :class:`Mixer` subclass, its entry in
 ``LAYER_TYPES`` and in ``MIXERS`` below, and nothing else: ``Block``,
 ``TransformerLM``, ``init_decode_state``, the decode programs and the
@@ -231,7 +231,7 @@ class Mixer(nn.Module):
         raise NotImplementedError(
             f"layer type {self.layer_type!r} does not build this serving "
             "entry point: gated_softmax has a training forward only; "
-            "gated_delta, decay_linear, block_sparse, ssm and latent serve "
+            "gated_delta, decay_linear, block_sparse, ssm, latent and indexed serve "
             "(prefill, its pieces, the decode step) but have no speculative "
             "verify_extend / advance_verified"
         )
@@ -384,6 +384,7 @@ from orion_tpu.models.mixers.gated_delta import GatedDeltaNet  # noqa: E402
 from orion_tpu.models.mixers.gated_softmax import (  # noqa: E402
     GatedSoftmaxAttention,
 )
+from orion_tpu.models.mixers.indexed import IndexedAttention  # noqa: E402
 from orion_tpu.models.mixers.latent import LatentAttention  # noqa: E402
 from orion_tpu.models.mixers.linear import LinearAttention  # noqa: E402
 from orion_tpu.models.mixers.softmax import SoftmaxAttention  # noqa: E402
@@ -399,11 +400,12 @@ MIXERS = {
     "block_sparse": BlockSparseAttention,
     "ssm": StateSpace,
     "latent": LatentAttention,
+    "indexed": IndexedAttention,
 }
 assert set(MIXERS) == set(LAYER_TYPES), (sorted(MIXERS), LAYER_TYPES)
 
 __all__ = [
     "MIXERS", "Mixer", "LinearAttention", "SoftmaxAttention", "GatedDeltaNet",
     "GatedSoftmaxAttention", "DecayLinearAttention", "BlockSparseAttention",
-    "StateSpace", "LatentAttention", "ZeroCentredRMSNorm", "kernel_bh", "whole_array_backend",
+    "StateSpace", "LatentAttention", "IndexedAttention", "ZeroCentredRMSNorm", "kernel_bh", "whole_array_backend",
 ]

@@ -74,9 +74,12 @@ chosen and multiplied by ``moe_route_scale``.
 
 The SERVING methods hand the layer ``live`` (``[B]`` at a decode step, ``[B,
 T]`` in a prompt piece): a row outside it (a slot that is not emitting, a
-piece's padding) routes nowhere and counts nowhere. The held layer then runs
-its grouped product only over the tiles that hold a row, so a buffer that
-holds every pair the router can send here (``moe_ep_buffer >= router width /
+piece's padding) routes nowhere and counts nowhere. Every dropless layer
+that one chip holds without an exchange honours it (:func:`masks_rows`:
+a share of the experts or all of them, whatever ``moe_router_width`` says),
+and does so on the held-rows path, the one form that can leave a row out:
+it then runs its grouped product only over the tiles that hold a row, so a
+buffer that holds every pair the router can send here (``moe_ep_buffer >= router width /
 experts held``: ``dropless_overflow`` 0 by construction) costs what the held
 rows cost. :func:`stats_vector` is what the decode programs sum a boundary.
 """
@@ -99,6 +102,19 @@ Array = jax.Array
 
 def _dtype(name: str):
     return {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[name]
+
+
+def masks_rows(cfg: ModelConfig, quant: str = "", mesh: Any = None) -> bool:
+    """Do this model's MoE layers honour the serving methods' ``live`` row
+    mask and count their rows (``MoEMLP._dropless_held``)? The dropless
+    SwiGLU layer of ONE device does, whether it holds a share of its router's
+    experts or all of them; a capacity layer, an int8 one and a layer spread
+    over a mesh have no such form. The serving programs ask this to know
+    whether to hand ``live`` over and sum the counters (generate.py)."""
+    return bool(
+        cfg.n_experts and cfg.moe_dropless and not quant and cfg.mlp == "swiglu"
+        and (mesh is None or mesh.devices.size == 1)
+    )
 
 
 def _expert_init(in_axis: int = -2):
@@ -176,9 +192,12 @@ class MoEMLP(nn.Module):
     @nn.compact
     def __call__(self, x: Array, live: Optional[Array] = None) -> Array:
         """``live``: the serving methods' row mask, ``x.shape[:-1]`` bool
-        (module docstring); only the held-experts layer is served."""
+        (module docstring); a layer that cannot leave a row out
+        (:func:`masks_rows`) computes every row, as it did."""
         cfg = self.cfg
-        if cfg.moe_held:
+        if live is not None and not masks_rows(cfg, self.quant, self.mesh):
+            live = None
+        if cfg.moe_held or live is not None:
             y = self._dropless_held(x, live)
         else:
             y = self._routed(x)
@@ -499,7 +518,9 @@ class MoEMLP(nn.Module):
         ``[moe_expert_offset, moe_expert_offset + n_experts)`` — are
         computed (``_held_rows_ffn``, the body an ep shard runs too), and
         the others add nothing (their chips add them). Single-device only:
-        on one chip the layer runs without its exchange.
+        on one chip the layer runs without its exchange. A chip that holds
+        every expert of its router (served with ``live``: ``__call__``) is
+        the case with nothing left out.
 
         The buffer is ``moe_ep_buffer x`` the held experts' even share of
         the rows (an even router fills ``1 / moe_ep_buffer`` of it; one of
@@ -1042,4 +1063,6 @@ def stats_vector(collection) -> Array:
     return jnp.stack([total[name] for name in STAT_NAMES])
 
 
-__all__ = ["MoEMLP", "STAT_NAMES", "stats_vector", "top_k_routing", "top_k_choice"]
+__all__ = [
+    "MoEMLP", "STAT_NAMES", "masks_rows", "stats_vector", "top_k_routing", "top_k_choice",
+]

@@ -348,7 +348,8 @@ def ssm_state_step(x, dt, a, bm, cm, state, pack, rows=None, *, backend: str = "
 
 
 def cache_attention(
-    q, k_cache, v_cache, lengths, rows=None, *, backend: str = "auto", blocks=None
+    q, k_cache, v_cache, lengths, rows=None, *, backend: str = "auto", blocks=None,
+    row_list=None,
 ):
     """Decode attention of one query a sequence over the first ``lengths``
     [B] rows of its KV cache: q ``[B, H, Dh]``, caches ``[B, KV, cap, Dh]``
@@ -370,7 +371,30 @@ def cache_attention(
     list. Under a Pallas backend with a row list the kernel
     ``ops/pallas/cache_attention.py::block_attention`` fetches the listed
     blocks only; otherwise they are gathered
-    (``ops/softmax_attention.py::cached_block_attention``)."""
+    (``ops/softmax_attention.py::cached_block_attention``).
+
+    ``row_list`` = (list ``[B, L]`` int32, counts ``[B]``) restricts each
+    sequence to the first ``counts`` cache ROWS its list names, one list for
+    all of its KV heads; the caches are then ``[B, cap, KV Dh]``, a token's K
+    (and its V) one contiguous row, and only the listed rows are read, on
+    every backend (a gather: ``ops/softmax_attention.py::
+    cached_row_attention``). A sequence the row list ``rows`` leaves out
+    counts no row: ``(0, -1e30)``."""
+    if row_list is not None:
+        import jax.numpy as jnp
+
+        from orion_tpu.ops.softmax_attention import cached_row_attention
+
+        lists, counts = row_list
+        b, kvd = k_cache.shape[0], k_cache.shape[-1]
+        d = q.shape[-1]
+        if rows is not None:
+            counts = jnp.where(decode_rows_mask(rows, b), counts, 0)
+            lists = jnp.where(counts[:, None] > 0, lists, 0)
+        out, lse = cached_row_attention(
+            q.reshape(b, kvd // d, -1, d), k_cache, v_cache, lists, counts
+        )
+        return out.reshape(b, -1, d), lse.reshape(b, -1)
     if blocks is not None:
         return _block_list_attention(
             q, k_cache, v_cache, lengths, rows, blocks, backend

@@ -197,9 +197,41 @@ def cached_block_attention(
     return out, jnp.where(empty, _NEG, lse)
 
 
+def cached_row_attention(
+    q: Array, k_cache: Array, v_cache: Array, lists: Array, counts: Array,
+):
+    """Decode-step attention over a LIST OF ROWS a sequence, shared by every
+    KV head: q ``[B, KV, G, Dh]``, caches ``[B, cap, KV Dh]`` (a token's K,
+    and its V, one contiguous row), ``lists`` ``[B, L]`` the cache rows
+    sequence ``b`` attends to, of which the first ``counts`` ``[B]`` count.
+    Gathers the listed rows ``[B, L, KV Dh]`` and nothing else of the cache
+    -> (out ``[B, KV, G, Dh]``, lse ``[B, KV, G]``) in fp32; ``(0, -1e30)``
+    where the list is empty."""
+    f32 = jnp.float32
+    b, kvh, _, d = q.shape
+
+    def listed(c):
+        return jnp.take_along_axis(c, lists[..., None], axis=1).reshape(b, -1, kvh, d)
+
+    live = (jnp.arange(lists.shape[-1]) < counts[:, None])[:, None, None]  # [B, 1, 1, L]
+    s = jnp.einsum(
+        "bkgd,blkd->bkgl", q, listed(k_cache).astype(q.dtype), preferred_element_type=f32
+    ) * d ** -0.5
+    s = jnp.where(live, s, _NEG)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    p = jnp.where(live, jnp.exp(s - lse[..., None]), 0.0)
+    out = jnp.einsum(
+        "bkgl,blkd->bkgd", p.astype(v_cache.dtype), listed(v_cache),
+        preferred_element_type=f32,
+    )
+    empty = (counts == 0)[:, None, None]
+    return jnp.where(empty[..., None], 0.0, out), jnp.where(empty, _NEG, lse)
+
+
 __all__ = [
     "softmax_attention",
     "softmax_attention_xla",
     "cached_attention",
     "cached_block_attention",
+    "cached_row_attention",
 ]

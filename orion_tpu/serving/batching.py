@@ -161,7 +161,7 @@ def _slot_flags(states, done) -> Array:
 def _counted_flags(states, done, counted) -> Array:
     """[2 slots + 4] int32: the finite mask, the done flags and the
     boundary's MoE row counters (``counted``: the [4] vectors its programs
-    returned, ``models/moe.py::STAT_NAMES``, summed here) — a ``cfg.moe_held``
+    returned, ``models/moe.py::STAT_NAMES``, summed here) — a row-counting MoE
     model's whole host readback, still ONE device transfer a boundary."""
     return jnp.concatenate([
         decode_state_finite_per_slot(states).astype(jnp.int32),
@@ -597,8 +597,8 @@ class SlotEngine:
         # prefill, never correctness.
         self.max_pending_prefixes = 32
         self.dropped_prefixes = 0  # lifetime counted drops
-        # a ``cfg.moe_held`` model: the MoE row counters of the boundary just
-        # probed ([routed, held, busiest expert's, dropped], summed over its
+        # a row-counting MoE model (``moe.masks_rows``): the MoE row counters
+        # of the boundary just probed ([routed, held, busiest expert's, dropped], summed over its
         # pieces, steps and layers; ``models/moe.py::STAT_NAMES``), read with
         # the probe's own transfer; zeros for every other model
         self.moe_rows = np.zeros((4,), np.int64)
@@ -927,6 +927,47 @@ class SlotEngine:
             sum(blocks_read(cfg, n) for n in lengths),
             sparse, len(lengths) - sparse,
         )
+
+    def kv_rows_listed(self) -> Tuple[int, int]:
+        """(listed, scored) for ONE ``indexed`` layer at the boundary about to
+        run, over the slots that will emit, each at the position its last
+        step attends from: the cache rows its decode attention lists (all of
+        them under ``index_topk``) and the live rows its indexer scores to
+        choose them, which are all the slot holds. Zeros for a model without
+        such a layer."""
+        cfg = self.model.cfg
+        if "indexed" not in cfg.resolved_layer_types:
+            return 0, 0
+        from orion_tpu.models.mixers.indexed import rows_listed
+
+        grown = 0 if self.donate_carry else self.chunk
+        lengths = [
+            min(cfg.max_seq_len, end + grown)
+            for end in self._emitting_ends(self._slot_ends())
+        ]
+        return sum(rows_listed(cfg, n) for n in lengths), sum(lengths)
+
+    def index_piece_pairs(self) -> Tuple[int, int]:
+        """(visible, selected) (query, key) pairs of ONE ``indexed`` layer in
+        the prompt pieces the boundary about to run serves: a piece's query at
+        position ``i`` sees ``i + 1`` keys, which its indexer scores, and
+        attends to ``min(index_topk, i + 1)`` of them. Zeros for a model
+        without such a layer."""
+        cfg = self.model.cfg
+        if "indexed" not in cfg.resolved_layer_types:
+            return 0, 0
+        piece, visible, selected = self._piece_tokens(), 0, 0
+        ends = self._slot_ends()
+        for i in self._selected_prefill_slots([s is not None for s in self._slots]):
+            slot = self._slots[i]
+            if slot is None or slot.prompt_remaining <= 0:
+                continue
+            start = ends[i] - slot.n_emitted - slot.prompt_remaining
+            n = min(piece, slot.prompt_remaining)
+            visible += n * start + n * (n + 1) // 2
+            full = max(0, min(n, cfg.index_topk - start))  # rows that list all they see
+            selected += full * start + full * (full + 1) // 2 + (n - full) * cfg.index_topk
+        return visible, selected
 
     def slot_info(self) -> List[Tuple[int, Any, str, int]]:
         """Per-resident-slot (index, tag, phase, request-local chunk
@@ -1658,9 +1699,10 @@ class SlotEngine:
         # undonated programs only.
         warm = None if donate else self._warm_boundary_exec(kind, seen_key)
         accepted = None
-        # a ``cfg.moe_held`` model's programs also return their MoE row
-        # counters, [4] on the device (one vector, or the donated
-        # boundary's tuple of them): ``_probe_bad`` reads them
+        # the programs of a model whose MoE layers count their rows
+        # (``moe.masks_rows``) also return those counters, [4] on the device
+        # (one vector, or the donated boundary's tuple of them):
+        # ``_probe_bad`` reads them
         counted = ()
         try:
             if donate:
@@ -1743,10 +1785,11 @@ class SlotEngine:
         done row is stashed for the eviction pass. At a speculative
         boundary the per-slot accepted counts ride the SAME transfer
         ([3, slots] int32 instead of [2, slots] bool) — the accept/
-        reject decision never costs a second readback. So do a
-        ``cfg.moe_held`` model's MoE row counters (``moe_rows``: the
-        boundary's [routed, held, busiest expert's, dropped], the programs'
-        vectors padded to one length so that one program sums them)."""
+        reject decision never costs a second readback. So do the MoE row
+        counters of a model whose layers count (``moe.masks_rows``;
+        ``moe_rows``: the boundary's [routed, held, busiest expert's,
+        dropped], the programs' vectors padded to one length so that one
+        program sums them)."""
         if self._moe_counted:
             pad = 1 + prefill_piece_cap(self.slots, self.chunk) - len(self._moe_counted)
             if self._moe_zero is None:

@@ -147,6 +147,16 @@ _ADMIT_KEYS = ("admit_dispatches",)
 _KV_BLOCK_KEYS = (
     "kv_blocks_live", "kv_blocks_read", "sparse_steps", "dense_steps",
 )
+# the indexed layers' cache rows at each boundary, per emitting slot and
+# indexed layer (``SlotEngine.kv_rows_listed``): rows the slot's decode
+# attention lists / live rows its indexer scores to choose them; and the
+# (query, key) pairs of the boundary's prompt pieces, per indexed layer
+# (``SlotEngine.index_piece_pairs``): pairs the indexer scores / pairs the
+# attention keeps (0 for a model without such a layer)
+_KV_LIST_KEYS = (
+    "kv_rows_listed", "index_rows_scored", "index_pairs_visible",
+    "index_pairs_selected",
+)
 # the state-space layers' work at each boundary, summed over ``ssm`` layers
 # (0 for a model without one): the row-steps their decode step ran (the
 # slots that emitted x the scan's steps: a listed row steps at every one) and
@@ -497,7 +507,8 @@ class Server:
         # obs-device-sync + the cache-stat asserts in tests/test_obs.py)
         self.metrics = MetricsRegistry(clock=clock, lock=self._stats_lock)
         for key in (_STAT_KEYS + _SLOT_CLASS_KEYS + _KV_ROW_KEYS
-                    + _KV_BLOCK_KEYS + _ADMIT_KEYS + _SSM_KEYS + _MOE_KEYS
+                    + _KV_BLOCK_KEYS + _KV_LIST_KEYS + _ADMIT_KEYS + _SSM_KEYS
+                    + _MOE_KEYS
                     + _KV_ATTENDED_KEYS):
             self.metrics.counter(key)  # the legacy stats dict's cells
         # what jax built while this server lived (obs/trace.py
@@ -2131,6 +2142,7 @@ class Server:
         kv_rows = self.engine.kv_rows()
         kv_attended = self.engine.kv_rows_attended()
         kv_blocks = self.engine.kv_blocks()
+        kv_listed = self.engine.kv_rows_listed() + self.engine.index_piece_pairs()
         infos = self.engine.slot_info() if self.trace.enabled else ()
         t0 = self._clock()
         finished = ()
@@ -2201,6 +2213,8 @@ class Server:
                     _KV_BLOCK_KEYS, (live * layers, read * layers, sparse, dense)
                 ):
                     self._bump(key, n)
+                for key, n in zip(_KV_LIST_KEYS, kv_listed):
+                    self._bump(key, n * kinds.count("indexed"))
                 # the tp label makes a fleet's per-footprint boundary cost
                 # separable at the aggregated endpoint (a tp=4 replica's
                 # chunks cost collectives a tp=1 replica's don't)

@@ -189,6 +189,18 @@ def _gmm_live(x, w, sizes):
     return gmm_live(x, w, sizes, tile_rows=128, block_h=512)
 
 
+def _index_scores(qi, w, ki):
+    from orion_tpu.ops.pallas.indexed_attention import index_scores
+
+    return index_scores(qi, w, ki)
+
+
+def _indexed_masked(q, k, v, keep):
+    from orion_tpu.ops.pallas.indexed_attention import masked_attention
+
+    return masked_attention(q, k, v, keep)
+
+
 def _latent_flash(q, k, v):
     from orion_tpu.ops.pallas.flash_attention import flash_attention_lse
 
@@ -283,6 +295,14 @@ _GMM_LIVE_UP = [((3072, 7680), jnp.bfloat16), ((16, 7680, 2048), jnp.bfloat16),
                 ((16,), jnp.int32)]
 _GMM_LIVE_DOWN = [((3072, 2048), jnp.bfloat16), ((16, 2048, 7680), jnp.bfloat16),
                   ((16,), jnp.int32)]
+# keye_vl_2_0_30b_a3b.serve_long: a 1,024-token piece over the longest static
+# key length; 16 index heads x 64, 4 KV heads x 8 query heads x 128; experts 768 wide
+_INDEX_PIECE = [((1, 1024, 16, 64), jnp.bfloat16), ((1, 1024, 16), jnp.float32),
+                ((1, 33280, 64), jnp.bfloat16)]
+_INDEXED_PIECE = [((1, 4, 8, 1024, 128), jnp.bfloat16), *[((1, 33280, 512), jnp.bfloat16)] * 2,
+                  ((1, 1024, 33280), jnp.int8)]
+_GMM_LIVE_768 = [((24576, 2048), jnp.bfloat16), ((128, 2048, 768), jnp.bfloat16),
+                 ((128,), jnp.int32)]
 _LATENT_PIECE = [*[((1, 128, 1024, 256), jnp.bfloat16)] * 2,
                  ((1, 128, 1024, 128), jnp.bfloat16)]
 # moe_1b3_4e dropless: 24576 padded rows through 4 experts of 2048 x 5504
@@ -341,6 +361,9 @@ KERNELS = [
     pytest.param(_gmm_live, _GMM_LIVE_UP, id="gmm_live-held16-7680x2048"),
     pytest.param(_gmm_live, _GMM_LIVE_DOWN, id="gmm_live-held16-2048x7680"),
     pytest.param(_latent_flash, _LATENT_PIECE, id="flash-latent-piece1024-d256-v128"),
+    pytest.param(_index_scores, _INDEX_PIECE, id="index_scores-piece1024-33280keys"),
+    pytest.param(_indexed_masked, _INDEXED_PIECE, id="indexed_attention-piece1024-33280keys"),
+    pytest.param(_gmm_live, _GMM_LIVE_768, id="gmm_live-held128-2048x768"),
     # -- the rest of the main path's kernels ---------------------------------
     pytest.param(_plain, _QKV, id="causal_dot-plain-fwd", marks=slow),
     pytest.param(_grad3(_plain), _QKV, id="causal_dot-plain-bwd", marks=slow),
@@ -619,6 +642,52 @@ def test_openpangu_boundary_programs_hold_the_carry_once(v5e):
         text = compiled.as_text()
         assert "gmm_live" in text, name
         assert ("latent_attention" if name == "scan" else "flash_attn_fwd") in text
+
+
+@slow
+def test_keye_vl2_boundary_programs_hold_the_carry_once(v5e):
+    """``keye_vl_2_0_30b_a3b.serve_long``'s programs at 16 slots x 33,280 for
+    the chip, the carry donated: one slot's 1,024-token prompt piece and the
+    decode scan. Each fits 16 GB with its arguments (weights 6.25 GB + the
+    three caches 4.63 GB as counted), aliases the carry and holds K, V and
+    the index keys once (``chunk_split``: no temporary of a cache's size in
+    the scan), and both run the 128 held experts through the grouped product
+    over live tiles. A compile, not a chip run."""
+    from orion_tpu import generate as gen
+    from orion_tpu.generate import SampleConfig
+    from orion_tpu.models.configs import get_config
+    from orion_tpu.models.transformer import TransformerLM, init_decode_state
+
+    slots, chunk, piece, width = 16, 4, 1024, 32768
+    cfg = dataclasses.replace(get_config("keye_vl_2_0_30b_a3b"), backend="pallas")
+    model = TransformerLM(cfg)
+    one = SingleDeviceSharding(v5e[0])
+    put = lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one)  # noqa: E731
+    arr = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    params = jax.tree.map(put, jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 16), jnp.int32))))
+    assert sum(l.size for l in jax.tree.leaves(params)) == 3123858944
+    states = jax.tree.map(put, jax.eval_shape(lambda: init_decode_state(cfg, slots)))
+    ints, flags = arr((slots,), jnp.int32), arr((slots,), jnp.bool_)
+    carry = (ints, states, ints, ints, flags)
+    rngs, pbuf = arr((slots, 2), jnp.uint32), arr((slots, width), jnp.int32)
+    scalar, sample = arr((), jnp.int32), SampleConfig(temperature=0.0)
+    programs = {
+        "piece": gen._prefill_piece_donated_jit.lower(
+            model, params, carry, rngs, pbuf, ints, ints, scalar, piece, sample),
+        "scan": gen._decode_scan_donated_jit.lower(
+            model, params, carry, rngs, flags, ints, chunk, sample),
+    }
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        m = compiled.memory_analysis()
+        live = (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes)
+        assert live < 14e9, (name, live)
+        assert m.alias_size_in_bytes > 4.6e9, (name, m.alias_size_in_bytes)
+        assert "gmm_live" in compiled.as_text(), name
+        if name == "scan":  # one slot's K is 0.034 GB, all slots' 0.55 GB a layer
+            assert m.temp_size_in_bytes < 1.0e9, m.temp_size_in_bytes
 
 
 def test_minicpm_sala_boundary_programs_compile_and_fit(v5e):

@@ -19,7 +19,7 @@ from orion_tpu.models.mixers import MIXERS, Mixer
 from orion_tpu.models.transformer import TransformerLM, init_decode_state
 
 SERVED = ("linear", "softmax", "swa", "gated_delta", "decay_linear", "block_sparse", "ssm",
-          "latent")
+          "latent", "indexed")
 TRAIN_ONLY = ("gated_softmax",)
 
 # benchmark/configs/qwen3_next_80b.json's ``rehearse`` sizes
@@ -40,7 +40,7 @@ def one_layer(lt):
         sparse_window=8, sparse_topk=2, sparse_dense_len=16,
         ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=2,
         latent_q_rank=16, latent_kv_rank=8, latent_nope_dim=8, latent_rope_dim=4,
-        latent_value_dim=8,
+        latent_value_dim=8, index_heads=2, index_dim=8, index_topk=8,
     )
 
 
@@ -49,7 +49,7 @@ def test_registry_has_one_mixer_per_layer_type():
     assert all(issubclass(m, Mixer) for m in MIXERS.values())
     assert {lt for lt, m in MIXERS.items() if m.rows_in_place} == {
         "linear", "softmax", "swa", "gated_delta", "decay_linear", "block_sparse", "ssm",
-        "latent",
+        "latent", "indexed",
     }
 
 
@@ -99,7 +99,7 @@ def test_train_only_mixer_refuses_every_serving_entry_point(lt):
         init_decode_state(cfg, 1)
 
 
-@pytest.mark.parametrize("lt", ["gated_delta", "decay_linear", "block_sparse"])
+@pytest.mark.parametrize("lt", ["gated_delta", "decay_linear", "block_sparse", "indexed"])
 def test_served_without_a_speculative_pair(lt):
     """Served (prefill, pieces, the decode step), but the speculative
     verify / advance entry points stay the base class's, which raise."""

@@ -238,13 +238,19 @@ def _moe_apply(cfg, p, x):
 MOE_BACKENDS = [("xla", 96), ("pallas_interpret", 512)]
 
 
+@pytest.mark.parametrize("width", [0, 16])
 @pytest.mark.parametrize("backend,t", MOE_BACKENDS)
-def test_moe_layer_matches_reference_with_all_experts_held(backend, t):
-    cfg = tiny(n_experts=16, moe_router_width=16, backend=backend)
+def test_moe_layer_matches_reference_with_all_experts_held(backend, t, width):
+    """16 experts, all here, the router's width left at 0 or spelled out
+    (16 = ``n_experts``): one layer, the ordinary dropless one, which is the
+    reference's and counts nothing (served with ``live`` it masks and counts:
+    tests/test_keye_vl2.py)."""
+    cfg = tiny(n_experts=16, moe_router_width=width, backend=backend)
+    assert not cfg.moe_held
     _, params, blk = _layer(cfg, 1)
     x = jax.random.normal(jax.random.key(2), (2, t, cfg.d_model))
     got, sown = _moe_apply(cfg, blk["mlp"], x)
-    want = ref.moe(spec_of(cfg), blk["mlp"], x)
+    want = ref.moe(spec_of(dataclasses.replace(cfg, moe_router_width=16)), blk["mlp"], x)
     assert float(jnp.abs(want).max()) > 0.05
     assert float(jnp.abs(got - want).max()) < TOL
     assert "moe_stats" not in sown  # the plain dropless path counts nothing
