@@ -6,6 +6,14 @@ convolution + SiLU; q and k are l2-normalised and each key head serves
 head, gated by ``silu(z)`` and projected back. ``beta = sigmoid(b)``, or
 ``2 sigmoid(b)`` under ``cfg.gdn_allow_neg_eigval``.
 
+The parallel forward hands the conv's output ``[B, T, C]`` to
+``ops.dispatch.gated_delta_qkv`` where its kernels read q, k and v in place
+(``gated_delta_reads_qkv``: a Pallas backend, whole lane tiles, no mesh
+whose data axes split): the norm, q's scale and the group sum of the
+cotangents are inside them. Every other call (a state in or out, other
+widths, ``xla`` / ``eager``, meshes) forms the operands in ``_operands`` and
+runs ``_rule`` on them head-major, the program it always was.
+
 Served, the decode state is ``{"s": [B, Hv, dk, dv] fp32, "conv": [B, (W - 1)
 x channels]}``: the rule's state and the conv's last ``W - 1`` PRE-conv
 ``[q | k | v]`` rows, oldest first, side by side (a ``[B, W - 1, channels]``
@@ -40,17 +48,13 @@ from orion_tpu.models.mixers import (
     whole_array_backend,
 )
 from orion_tpu.ops.dispatch import (
-    causal_short_conv, decode_rows_mask, gated_delta_rule, gated_delta_step,
-    gated_rms_norm,
+    causal_short_conv, decode_rows_mask, gated_delta_qkv, gated_delta_reads_qkv,
+    gated_delta_rule, gated_delta_step, gated_rms_norm,
 )
+from orion_tpu.ops.gated_delta import l2norm
 from orion_tpu.utils.profiling import scope
 
 Array = jax.Array
-
-
-def _l2norm(x: Array) -> Array:
-    xf = x.astype(jnp.float32)
-    return xf * jax.lax.rsqrt(jnp.sum(jnp.square(xf), -1, keepdims=True) + NORM_EPS)
 
 
 def _widths(cfg: ModelConfig) -> Tuple[int, int, int, int]:
@@ -120,13 +124,21 @@ class GatedDeltaNet(Mixer):
         q = qkv[..., :kd].reshape(lead + (hk, dk))
         k = qkv[..., kd: 2 * kd].reshape(lead + (hk, dk))
         v = qkv[..., 2 * kd:].reshape(lead + (hv, dv))
-        beta = jax.nn.sigmoid(ba[..., :hv])  # fp32
-        if cfg.gdn_allow_neg_eigval:
+        beta, g = self._gates(ba)
+        q = (l2norm(q, NORM_EPS) * dk ** -0.5).astype(dt)
+        return q, l2norm(k, NORM_EPS).astype(dt), v, beta, g
+
+    def _gates(self, ba: Array) -> Tuple[Array, Array]:
+        """[b | a] [..., 2 Hv] fp32 -> the write strength beta and the
+        log-decay g, [..., Hv] fp32."""
+        hv = self.cfg.gdn_value_heads
+        beta = jax.nn.sigmoid(ba[..., :hv])
+        if self.cfg.gdn_allow_neg_eigval:
             beta = 2.0 * beta
         g = -jnp.exp(self.a_log.astype(jnp.float32)) * jax.nn.softplus(
             ba[..., hv:] + self.dt_bias.astype(jnp.float32)
         )
-        return (_l2norm(q) * dk ** -0.5).astype(dt), _l2norm(k).astype(dt), v, beta, g
+        return beta, g
 
     def _output(self, o: Array, z: Array) -> Array:
         """o [B, Hv, T, dv] as the rule leaves it, z [B, T, Hv dv] -> the
@@ -161,13 +173,22 @@ class GatedDeltaNet(Mixer):
 
     def __call__(self, x: Array, mask: Optional[Array] = None) -> Array:
         assert mask is None, "gated_delta is causal-LM only"
+        hk, hv, dk, dv = _widths(self.cfg)
+        backend = whole_array_backend(self.cfg, self.mesh)
         with scope("gated_delta"):
             pre, z, ba = self._project(x)
             with scope("short_conv"):
-                qkv = causal_short_conv(
-                    pre, self.conv, backend=whole_array_backend(self.cfg, self.mesh)
+                qkv = causal_short_conv(pre, self.conv, backend=backend)
+            if gated_delta_reads_qkv(hk, hv, dk, dv, backend=backend):
+                # the rule's kernels read q, k and v where the conv left
+                # them and hand its cotangent back in the same layout
+                o = gated_delta_qkv(
+                    qkv, *(jnp.swapaxes(y, 1, 2) for y in self._gates(ba)),
+                    key_heads=hk, key_dim=dk, value_dim=dv, eps=NORM_EPS, backend=backend,
                 )
-            return self._output(self._rule(*self._operands(qkv, ba)), z)
+            else:
+                o = self._rule(*self._operands(qkv, ba))
+            return self._output(o, z)
 
     # -- prefill and its pieces -----------------------------------------------
 

@@ -6,12 +6,13 @@ backend='xla' dispatch"). "auto" picks Pallas on TPU and the pure-XLA
 chunked scan elsewhere (CPU/GPU and unit tests). The Pallas kernel can also
 run anywhere via interpret mode (used by the parity tests).
 
-Ten ops dispatch here (``ssm_scan`` and ``ssm_state_step``, the
+Eleven ops dispatch here (``ssm_scan`` and ``ssm_state_step``, the
 state-space layers' prompt pass and one-token step, ``causal_short_conv``,
 the delta-rule and state-space layers' short conv over a sequence or a
-prompt piece, ``gated_rms_norm``, the delta-rule layer's output gate, and
-``latent_cache_attention``, the latent layers' absorbed query over a held
-latent cache, are described at their definitions):
+prompt piece, ``gated_delta_qkv``, the delta rule's kernels on that conv's
+output as it lies, ``gated_rms_norm``, the delta-rule layer's output gate,
+and ``latent_cache_attention``, the latent layers' absorbed query over a
+held latent cache, are described at their definitions):
 ``gated_delta_rule`` (the gated delta-rule
 layers' parallel forward, with or without a state carried in and out:
 under Pallas the chunked form as Mosaic kernels, forward and backward,
@@ -184,7 +185,9 @@ def gated_delta_rule(
     (the CPU, and on the chip a training width the kernels do not take)
     runs the same form as XLA fusions and a scan in chunks of 64, on key
     heads repeated to the value heads; training, a block of batch rows at a
-    time."""
+    time. A training call that holds the short conv's output goes through
+    :func:`gated_delta_qkv` instead, whose kernels read q, k and v where
+    they lie."""
     from orion_tpu.ops import gated_delta as gd
 
     b = resolve(backend)
@@ -207,6 +210,53 @@ def gated_delta_rule(
     if stateful:
         return gd.gated_delta_chunked(q, k, v, beta, g, **state)
     return gd.gated_delta_by_rows(q, k, v, beta, g)
+
+
+def gated_delta_reads_qkv(
+    key_heads: int, value_heads: int, key_dim: int, value_dim: int, *, backend: str = "auto"
+) -> bool:
+    """Whether :func:`gated_delta_qkv` runs the kernels that read the short
+    conv's output where it lies: a Pallas backend, v's columns on whole
+    blocks of a key head's value heads and, compiled, head widths of whole
+    lane tiles. Shapes alone decide; a caller that holds a state, or a mesh
+    whose data axes split, calls :func:`gated_delta_rule`."""
+    b = resolve(backend)
+    if not b.startswith("pallas"):
+        return False
+    from orion_tpu.ops.pallas import gated_delta as pgd
+
+    return pgd.reads_qkv(key_heads, value_heads, key_dim, value_dim) and (
+        b == "pallas_interpret" or pgd.supports(key_dim, value_dim)
+    )
+
+
+def gated_delta_qkv(
+    qkv, beta, g, *, key_heads: int, key_dim: int, value_dim: int, eps: float,
+    backend: str = "auto",
+):
+    """Dispatch the delta-rule layer from its short conv's output to the
+    rule's (``ops/gated_delta.py::gated_delta_qkv``, the specification):
+    ``qkv [..., T, C]`` with columns ``[q | k | v]``, beta, g ``[..., Hv,
+    T]`` -> ``o [..., Hv, T, Dv]`` head-major, no state in or out. Where
+    :func:`gated_delta_reads_qkv` holds, ``pallas`` and ``pallas_interpret``
+    run the rule's kernels on ``qkv`` as it lies: q, k and v are column
+    blocks of the one array, the l2 norm of q and k and q's scale are formed
+    in VMEM, and the backward writes ONE cotangent in ``qkv``'s layout, ``dq``
+    and ``dk`` summed over a key head's value heads and passed through the
+    norm's VJP before their one rounding (``ops/pallas/gated_delta.py``).
+    Anything else is the specification's operands through
+    :func:`gated_delta_rule`."""
+    heads = dict(key_heads=key_heads, key_dim=key_dim, value_dim=value_dim, eps=eps)
+    value_heads = (qkv.shape[-1] - 2 * key_heads * key_dim) // value_dim
+    if gated_delta_reads_qkv(key_heads, value_heads, key_dim, value_dim, backend=backend):
+        from orion_tpu.ops.pallas.gated_delta import gated_delta_qkv_pallas
+
+        return gated_delta_qkv_pallas(
+            qkv, beta, g, interpret=(resolve(backend) == "pallas_interpret"), **heads
+        )
+    from orion_tpu.ops.gated_delta import qkv_operands
+
+    return gated_delta_rule(*qkv_operands(qkv, **heads), beta, g, backend=backend)
 
 
 def causal_short_conv(
@@ -540,6 +590,8 @@ __all__ = [
     "decode_state_step",
     "gated_delta_step",
     "default_backend",
+    "gated_delta_qkv",
+    "gated_delta_reads_qkv",
     "gated_delta_rule",
     "gated_rms_norm",
     "latent_cache_attention",

@@ -261,6 +261,42 @@ def gated_delta_by_rows(q, k, v, beta, g, *, chunk: int = DEFAULT_CHUNK):
     return out.reshape((b,) + out.shape[2:])
 
 
+def l2norm(x: Array, eps: float) -> Array:
+    """``x / sqrt(sum(x^2) + eps)`` over the last axis, in fp32."""
+    xf = x.astype(jnp.float32)
+    return xf * jax.lax.rsqrt(jnp.sum(jnp.square(xf), -1, keepdims=True) + eps)
+
+
+def qkv_operands(qkv: Array, key_heads: int, key_dim: int, value_dim: int, eps: float):
+    """The rule's operands from the short conv's output ``qkv [..., T, C]``,
+    columns ``[q | k | v]`` (``key_heads`` x ``key_dim`` twice, then the
+    value heads x ``value_dim``): q l2-normalised and scaled by ``key_dim **
+    -0.5``, k l2-normalised, both rounded to qkv's dtype; heads first. ->
+    q, k ``[..., Hk, T, Dk]``, v ``[..., Hv, T, Dv]``."""
+    kd, lead = key_heads * key_dim, qkv.shape[:-1]
+    heads_first = lambda x, d: jnp.swapaxes(x.reshape(lead + (-1, d)), -3, -2)  # noqa: E731
+    q = l2norm(heads_first(qkv[..., :kd], key_dim), eps) * key_dim ** -0.5
+    k = l2norm(heads_first(qkv[..., kd: 2 * kd], key_dim), eps)
+    return q.astype(qkv.dtype), k.astype(qkv.dtype), heads_first(qkv[..., 2 * kd:], value_dim)
+
+
+def gated_delta_qkv(
+    qkv: Array, beta: Array, g: Array, *, key_heads: int, key_dim: int, value_dim: int,
+    eps: float, rule=gated_delta_recurrent,
+):
+    """The delta-rule layer from its short conv's output to the rule's:
+    ``qkv_operands``, each key head repeated to the value heads it serves,
+    then ``rule`` (one of this file's forms). qkv ``[..., T, C]``, beta, g
+    ``[..., Hv, T]`` -> ``o [..., Hv, T, Dv]``. The specification of the
+    kernels that read ``qkv`` as it lies and write its cotangent in the same
+    layout (``ops/pallas/gated_delta.py::gated_delta_qkv_pallas``)."""
+    q, k, v = qkv_operands(qkv, key_heads, key_dim, value_dim, eps)
+    group = v.shape[-3] // key_heads
+    if group > 1:
+        q, k = (jnp.repeat(x, group, axis=-3) for x in (q, k))
+    return rule(q, k, v, beta, g)
+
+
 def gated_delta_step(q: Array, k: Array, v: Array, beta: Array, g: Array, s: Array):
     """One token of the recurrence for every row, fp32: q, k ``[B, H, Dk]``,
     v ``[B, H, Dv]``, beta, g ``[B, H]``, ``s [B, H, Dk, Dv]`` fp32 ->
@@ -312,7 +348,10 @@ __all__ = [
     "causal_short_conv",
     "gated_delta_by_rows",
     "gated_delta_chunked",
+    "gated_delta_qkv",
     "gated_delta_recurrent",
     "gated_delta_step",
     "gated_rms_norm",
+    "l2norm",
+    "qkv_operands",
 ]

@@ -32,8 +32,29 @@ only ``o``.
   ``g -> G`` stays outside, differentiated by autodiff.
 - *Grouped heads.* q and k keep their ``Hk`` key heads: the value head
   ``hv`` reads key head ``hv // (Hv / Hk)`` through the ``BlockSpec``
-  index map, so nothing is repeated in HBM; ``dq`` / ``dk`` come out per
-  value head and are summed over each group right after.
+  index map, so nothing is repeated in HBM. On head-major operands ``dq`` /
+  ``dk`` come out per value head and the wrapper sums each group; read in
+  place (next) the sum is inside the kernel.
+- *q, k, v where the short conv left them* (``gated_delta_qkv_pallas``: a
+  training call with no state, ``reads_qkv``). The operands are three
+  ``BlockSpec``s on the ONE array ``qkv [B, T, C]``, columns ``[q | k |
+  v]``: a head is a column block, time stays on sublanes, and ``o`` leaves
+  head-major as before (the gate's kernel reads it there). The l2 norm of
+  q and k and q's ``Dk ** -0.5`` (``ops/gated_delta.py::qkv_operands``, the
+  specification) are formed from each chunk's rows in VMEM, fp32 inside,
+  rounded to the input dtype where the mixer's ``_operands`` rounds: the
+  forward adds and removes no rounding. The backward's grid is ``(batch x
+  KEY heads, blocks last to first, column blocks of the cotangent)``. At the
+  innermost axis's first step a key head's ``Hv / Hk`` value heads are
+  walked side by side, chunk by chunk (two independent chains: one's
+  products fill the MXU while the other's wait), their ``dq`` and ``dk``
+  summed in fp32, passed through the norm's VJP (``dx = r (dy - xn (xn .
+  dy))``, the row scales recomputed from the same block) and rounded ONCE
+  into a VMEM scratch beside each head's ``dv``; every step of that axis
+  then hands one column block to the ONE cotangent ``d qkv [B, T, C]``, so
+  an output block is visited once and nothing around the kernels relays,
+  sums, pads or re-converts (136.8 ms of a 1,710.6 ms step at
+  ``qwen3_next_80b.train``, PERF.md s5).
 - *Precision.* Matmul operands in the input dtype with fp32 accumulation,
   rounded where the XLA form rounds them (``T diag(beta)`` once, from the
   fp32 ``T``); decays, ``A``, the solve and ``S`` in fp32. The solve's
@@ -65,6 +86,7 @@ only ``o``.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +108,13 @@ _TN = (((0,), (0,)), ((), ()))  # x^T @ y
 def supports(dk: int, dv: int) -> bool:
     """Widths the compiled kernels take: whole 128-lane tiles."""
     return dk % 128 == 0 and dv % 128 == 0
+
+
+def reads_qkv(hk: int, hv: int, dk: int, dv: int) -> bool:
+    """Whether q, k and v can be read as column blocks of ``[q | k | v]``
+    channels: v's columns start on a whole block of a key head's ``hv / hk``
+    value heads (q's and k's always do)."""
+    return hv % hk == 0 and (2 * hk * dk) % (hv // hk * dv) == 0
 
 
 def _dot(x, y, dims=None):
@@ -181,6 +210,22 @@ def _decays(gr, br):
     )
 
 
+def _unit(x, eps, scale=1.0):
+    """A chunk's rows of q or k as the layer hands them to the rule:
+    ``ops/gated_delta.py::l2norm`` in fp32, times ``scale``, rounded to
+    ``x``'s dtype where the mixer's ``_operands`` rounds -> ``(operand, the
+    fp32 unit rows, the rsqrt that made them)``."""
+    xf = x.astype(_F32)
+    r = jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + eps)
+    xn = xf * r
+    return (xn if scale == 1.0 else xn * scale).astype(x.dtype), xn, r
+
+
+def _unit_vjp(dy, xn, r):
+    """``l2norm``'s VJP on fp32 rows: ``dx = r (dy - xn (xn . dy))``."""
+    return r * (dy - xn * jnp.sum(xn * dy, axis=-1, keepdims=True))
+
+
 def _block_fwd(chunks, s):
     """A block's chunks ``(q, k, v, G row, beta row)`` from the state ``s``
     (fp32 ``[Dk, Dv]``): ``(S_out, [(o, T, u)])``, ``T`` and ``u`` in v's
@@ -264,7 +309,9 @@ def _chunks(*refs):
         yield tok, [r[0, tok, :] if len(r.shape) == 3 else r[0, 0, i:i + 1, :] for r in refs]
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, unit=None):
+    """``unit`` = (eps, q's scale): q_ref and k_ref hold the short conv's
+    rows, normalised here chunk by chunk."""
     s_scr = rest[-1]
 
     @pl.when(pl.program_id(1) == 0)
@@ -275,6 +322,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest):
     if len(rest) > 1:  # for the backward: the state entering this block
         rest[0][0, 0] = s
     toks, chunks = zip(*_chunks(q_ref, k_ref, v_ref, g_ref, b_ref))
+    if unit is not None:
+        eps, scale = unit
+        chunks = [(_unit(q, eps, scale)[0], _unit(k, eps)[0], *rest_) for q, k, *rest_ in chunks]
     s_scr[:], outs = _block_fwd(chunks, s)
     for tok, (o, t, u) in zip(toks, outs):
         o_ref[0, tok, :] = o.astype(o_ref.dtype)
@@ -343,28 +393,37 @@ def _specs(group, beta, dk, dv, reverse):
 _PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
-def _forward(q, k, v, beta, gcum, save, interpret):
-    """``o``; with ``save`` also ``(states, T, u)`` for the backward."""
-    bh, tp, dv = v.shape
-    dk = q.shape[-1]
-    nblk = beta.shape[1]
-    qk_spec, v_spec, t_spec, tok_spec, s_spec = _specs(bh // q.shape[0], beta, dk, dv, False)
-    sds = lambda shape, dtype: _sds(shape, dtype, v)  # noqa: E731
+def _forward_call(specs, operands, dk, dv, beta, gcum, save, interpret, unit=None):
+    """The forward kernel on q, k, v as ``operands`` under ``specs`` (three
+    arrays head-major, or the one ``qkv`` array three times, then ``unit``
+    says how its q and k rows are normalised): ``o [B Hv, T, dv]``; with
+    ``save`` also ``(states, T, u)`` for the backward."""
+    bh, nblk, chunks = beta.shape[:3]
+    tp, dtype = nblk * chunks * CHUNK, operands[2].dtype
+    _, v_spec, t_spec, tok_spec, s_spec = _specs(1, beta, dk, dv, False)
+    sds = lambda shape, dt: _sds(shape, dt, operands[2])  # noqa: E731
     outs = pl.pallas_call(
-        _fwd_kernel,
+        functools.partial(_fwd_kernel, unit=unit),
         name="gated_delta_fwd",
         grid=(bh, nblk),
-        in_specs=[qk_spec, qk_spec, v_spec, tok_spec, tok_spec],
+        in_specs=[*specs, tok_spec, tok_spec],
         out_specs=[v_spec] + [s_spec, t_spec, v_spec] * save,
-        out_shape=[sds(v.shape, v.dtype)] + [
-            sds((bh, nblk, dk, dv), _F32), sds((bh, tp, CHUNK), v.dtype),
-            sds(v.shape, v.dtype),
+        out_shape=[sds((bh, tp, dv), dtype)] + [
+            sds((bh, nblk, dk, dv), _F32), sds((bh, tp, CHUNK), dtype), sds((bh, tp, dv), dtype),
         ] * save,
         scratch_shapes=[pltpu.VMEM((dk, dv), _F32)],
         compiler_params=_PARAMS,
         interpret=interpret,
-    )(q, k, v, gcum, beta)
+    )(*operands, gcum, beta)
     return (outs[0], outs[1:]) if save else outs[0]
+
+
+def _forward(q, k, v, beta, gcum, save, interpret):
+    dk, dv = q.shape[-1], v.shape[-1]
+    qk_spec, v_spec = _specs(v.shape[0] // q.shape[0], beta, dk, dv, False)[:2]
+    return _forward_call(
+        (qk_spec, qk_spec, v_spec), (q, k, v), dk, dv, beta, gcum, save, interpret
+    )
 
 
 def _forward_state(q, k, v, beta, gcum, s0, interpret):
@@ -440,6 +499,190 @@ def _rule_bwd(interpret, res, do):
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
+# -- q, k, v read where the short conv left them ------------------------------
+
+
+def _bwd_qkv_kernel(
+    q_ref, k_ref, v_ref, g_ref, b_ref, do_ref, s_ref, t_ref, u_ref,
+    dx_ref, dg_ref, db_ref, ds_scr, sin_scr, dx_scr, sems, *, eps, scale, hk,
+):
+    """A key head's block: its ``group`` value heads walked side by side
+    (their chains are independent: the MXU takes one's products while the
+    other's wait), ``dq`` and ``dk`` summed over them in fp32, passed
+    through the norm's VJP and rounded once. ``dx_ref`` is the whole
+    cotangent ``[B, T, C]`` in HBM: the block's column blocks (``dq``,
+    ``dk``, each head's ``dv``) are formed in ``dx_scr`` and copied out
+    while the next block's states are replayed."""
+    group, tokens, dv = do_ref.shape
+    dk, width = q_ref.shape[-1], dx_scr.shape[-1]
+    qp, vp = dk // width, dv // width  # column blocks of dq (and dk), of a head's dv
+    n = tokens // CHUNK
+    toks = [slice(i * CHUNK, (i + 1) * CHUNK) for i in range(n)]
+    row, step, last = pl.program_id(0), pl.program_id(1), pl.num_programs(1) - 1
+    key = row % hk
+    first = (key * qp, (hk + key) * qp, 2 * hk * qp + key * group * vp)  # of dq, dk, dv
+
+    def copies():  # this step's column blocks, scratch -> their place in HBM
+        rows = pl.ds((last - step) * tokens, tokens)
+        for p in range(2 * qp + group * vp):
+            col = first[min(p // qp, 2)] + (p % qp if p < 2 * qp else p - 2 * qp)
+            yield pltpu.make_async_copy(
+                dx_scr.at[p], dx_ref.at[row // hk, rows, pl.ds(col * width, width)], sems.at[p]
+            )
+
+    def wait():
+        for copy in copies():  # the same sizes: what a wait counts
+            copy.wait()
+
+    def put(first, tok, y):  # fp32 [C, D] -> D / width column blocks
+        for p in range(y.shape[-1] // width):
+            dx_scr[first + p, tok, :] = y[:, p * width:(p + 1) * width].astype(dx_scr.dtype)
+
+    @pl.when(step == 0)
+    def _():
+        ds_scr[:] = jnp.zeros_like(ds_scr)
+
+    heads = range(group)
+    ss = [s_ref[h, 0] for h in heads]
+    for i, tok in enumerate(toks):  # each chunk's incoming states, replayed
+        kf = _unit(k_ref[0, tok, :], eps)[0].astype(_F32)
+        for h in heads:
+            sin_scr[h, i] = ss[h]
+            x = _decays(g_ref[h, 0, i:i + 1, :], b_ref[h, 0, i:i + 1, :])
+            u = u_ref[h, tok, :]
+            ss[h] = ss[h] * x["egl"] + _dot((kf * x["rho"]).astype(u.dtype), u, _TN)
+    pl.when(step > 0)(wait)  # the block before this one has left dx_scr
+    dss = [ds_scr[h] for h in heads]
+    for i, tok in reversed(list(enumerate(toks))):
+        q, qn, qr = _unit(q_ref[0, tok, :], eps, scale)
+        k, kn, kr = _unit(k_ref[0, tok, :], eps)
+        dq = dk_ = 0.0
+        for h in heads:
+            dq_h, dk_h, dv_h, db, dg, dss[h] = _chunk_bwd(
+                q, k, v_ref[0, tok, h * dv:(h + 1) * dv], g_ref[h, 0, i:i + 1, :],
+                b_ref[h, 0, i:i + 1, :], do_ref[h, tok, :], t_ref[h, tok, :],
+                u_ref[h, tok, :], sin_scr[h, i], dss[h],
+            )
+            dq, dk_ = dq + dq_h, dk_ + dk_h
+            put(2 * qp + h * vp, tok, dv_h)
+            db_ref[h, 0, i:i + 1, :] = db
+            dg_ref[h, 0, i:i + 1, :] = dg
+        put(0, tok, _unit_vjp(dq * scale, qn, qr))
+        put(qp, tok, _unit_vjp(dk_, kn, kr))
+    for h in heads:
+        ds_scr[h] = dss[h]
+    for copy in copies():
+        copy.start()
+    pl.when(step == last)(wait)  # a row's last block: nothing outlives the row
+
+
+def _qkv_specs(dims, beta, reverse, each):
+    """Block specs of q, k and v on ``qkv [B, T, C]`` for the grid ``(B x
+    heads, blocks)`` with ``each`` value heads a grid step: 1 (a value head
+    a step, q and k read at its key head's columns) or ``Hv / Hk`` (a key
+    head a step, its value heads' columns side by side)."""
+    hk, hv, dk, dv = dims
+    nblk, chunks = beta.shape[1:3]
+    blk = (lambda c: nblk - 1 - c) if reverse else (lambda c: c)
+    per = hv // each  # grid rows a batch row
+    key = lambda i: i % per // (per // hk)  # noqa: E731
+    cols = lambda d, of: pl.BlockSpec(  # noqa: E731
+        (1, chunks * CHUNK, d), lambda i, c: (i // per, blk(c), of(i)), memory_space=pltpu.VMEM
+    )
+    return (
+        cols(dk, key), cols(dk, lambda i: hk + key(i)),
+        cols(each * dv, lambda i: 2 * hk * dk // (each * dv) + i % per),
+    )
+
+
+_VMEM_BYTES = 64 << 20  # a key head's step holds its value heads' operands
+
+
+def _forward_qkv(qkv, beta, gcum, dims, eps, save, interpret):
+    _, _, dk, dv = dims
+    return _forward_call(
+        _qkv_specs(dims, beta, False, 1), (qkv,) * 3, dk, dv, beta, gcum, save, interpret,
+        unit=(eps, dk ** -0.5),
+    )
+
+
+def _backward_qkv(qkv, beta, gcum, do, saved, dims, eps, interpret):
+    """``(d qkv [B, T, C], dG, dbeta)``: the grid ``(B x key heads, blocks
+    last to first)``; the cotangent stays in HBM and the kernel copies its
+    column blocks there."""
+    hk, hv, dk, dv = dims
+    group = hv // hk
+    nblk, chunks = beta.shape[1:3]
+    tokens = chunks * CHUNK
+    width = math.gcd(dk, dv)
+    pieces = (2 * dk + group * dv) // width
+    heads = lambda *shape: pl.BlockSpec(  # noqa: E731
+        (group,) + shape, lambda i, c: (i, nblk - 1 - c) + (0,) * (len(shape) - 1),
+        memory_space=pltpu.VMEM,
+    )
+    rows = lambda d: heads(tokens, d)  # noqa: E731
+    per_block = lambda *shape: heads(1, *shape)  # noqa: E731
+    sds = lambda shape, dtype: _sds(shape, dtype, qkv)  # noqa: E731
+    return pl.pallas_call(
+        functools.partial(_bwd_qkv_kernel, eps=eps, scale=dk ** -0.5, hk=hk),
+        name="gated_delta_bwd",
+        grid=(beta.shape[0] // group, nblk),
+        in_specs=[
+            *_qkv_specs(dims, beta, True, group), per_block(chunks, CHUNK),
+            per_block(chunks, CHUNK), rows(dv), per_block(dk, dv), rows(CHUNK), rows(dv),
+        ],
+        out_specs=[
+            pl.BlockSpec(memory_space=pl.ANY), per_block(chunks, CHUNK), per_block(chunks, CHUNK),
+        ],
+        out_shape=[sds(qkv.shape, qkv.dtype), sds(beta.shape, _F32), sds(beta.shape, _F32)],
+        scratch_shapes=[
+            pltpu.VMEM((group, dk, dv), _F32), pltpu.VMEM((group, chunks, dk, dv), _F32),
+            pltpu.VMEM((pieces, tokens, width), qkv.dtype), pltpu.SemaphoreType.DMA((pieces,)),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_VMEM_BYTES
+        ),
+        interpret=interpret,
+    )(qkv, qkv, qkv, gcum, beta, do, *saved)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _rule_qkv(qkv, beta, gcum, dims, eps, interpret):
+    return _forward_qkv(qkv, beta, gcum, dims, eps, False, interpret)
+
+
+def _rule_qkv_fwd(qkv, beta, gcum, dims, eps, interpret):
+    out, saved = _forward_qkv(qkv, beta, gcum, dims, eps, True, interpret)
+    return out, (qkv, beta, gcum, saved)
+
+
+def _rule_qkv_bwd(dims, eps, interpret, res, do):
+    qkv, beta, gcum, saved = res
+    dx, dg, db = _backward_qkv(
+        qkv, beta, gcum, do.astype(qkv.dtype), saved, dims, eps, interpret
+    )
+    return dx, db, dg
+
+
+_rule_qkv.defvjp(_rule_qkv_fwd, _rule_qkv_bwd)
+
+
+def _tiling(t: int):
+    """``(chunks a block, rows of zero padding, blocks)`` for ``t`` tokens:
+    a short T is one smaller block."""
+    chunks = min(BLOCK_CHUNKS, -(-t // CHUNK))
+    pad = (-t) % (chunks * CHUNK)
+    return chunks, pad, (t + pad) // (chunks * CHUNK)
+
+
+def _per_chunk(x, t):
+    """A per-token scalar ``[..., T]`` as fp32 ``[BH, blocks, chunks, C]``."""
+    chunks, pad, nblk = _tiling(t)
+    x = x.astype(_F32).reshape((-1, t))
+    x = jnp.pad(x, [(0, 0), (0, pad)]) if pad else x
+    return x.reshape(-1, nblk, chunks, CHUNK)
+
+
 def gated_delta_rule_pallas(
     q: Array, k: Array, v: Array, beta: Array, g: Array, *, interpret: bool = False,
     initial_state=None, return_state: bool = False,
@@ -455,9 +698,7 @@ def gated_delta_rule_pallas(
     hk, dk = q.shape[-3], q.shape[-1]
     assert q.shape == k.shape and q.shape[:-3] == lead and hv % hk == 0, (q.shape, k.shape, v.shape)
     assert beta.shape == g.shape == lead + (hv, t), (beta.shape, g.shape)
-    chunks = min(BLOCK_CHUNKS, -(-t // CHUNK))  # a short T is one smaller block
-    pad = (-t) % (chunks * CHUNK)
-    nblk = (t + pad) // (chunks * CHUNK)
+    pad = _tiling(t)[1]
     stateful = initial_state is not None or return_state
     # the state-carrying kernel takes every width, as whole lane tiles
     wk, wv = ((-dk) % 128, (-dv) % 128) if stateful else (0, 0)
@@ -466,14 +707,9 @@ def gated_delta_rule_pallas(
         x = x.reshape((-1, t, d))
         return jnp.pad(x, [(0, 0), (0, pad), (0, wide)]) if pad or wide else x
 
-    def per_chunk(x):
-        x = x.astype(_F32).reshape((-1, t))
-        x = jnp.pad(x, [(0, 0), (0, pad)]) if pad else x
-        return x.reshape(-1, nblk, chunks, CHUNK)
-
     args = (
         flat(q.astype(v.dtype), dk, wk), flat(k.astype(v.dtype), dk, wk), flat(v, dv, wv),
-        per_chunk(beta), jnp.cumsum(per_chunk(g), axis=-1),
+        _per_chunk(beta, t), jnp.cumsum(_per_chunk(g, t), axis=-1),
     )
     if not stateful:
         return _rule(*args, interpret)[:, :t].reshape(lead + (hv, t, dv))
@@ -488,4 +724,32 @@ def gated_delta_rule_pallas(
     return (out, s[:, :dk, :dv].reshape(lead + (hv, dk, dv))) if return_state else out
 
 
-__all__ = ["BLOCK_CHUNKS", "CHUNK", "gated_delta_rule_pallas", "supports"]
+def gated_delta_qkv_pallas(
+    qkv: Array, beta: Array, g: Array, *, key_heads: int, key_dim: int, value_dim: int,
+    eps: float, interpret: bool = False,
+):
+    """``ops/gated_delta.py::gated_delta_qkv`` as the kernels above: the
+    short conv's output ``qkv [..., T, C]`` (columns ``[q | k | v]``) read
+    as it lies, beta, g ``[..., Hv, T]`` -> ``o [..., Hv, T, Dv]`` head-major
+    in qkv's dtype; differentiable in all three, the cotangent of ``qkv``
+    written in its layout. For heads that ``reads_qkv`` takes."""
+    lead, (t, c) = qkv.shape[:-2], qkv.shape[-2:]
+    hk, dk, dv = key_heads, key_dim, value_dim
+    hv = (c - 2 * hk * dk) // dv
+    assert c == 2 * hk * dk + hv * dv and reads_qkv(hk, hv, dk, dv), (c, hk, hv, dk, dv)
+    assert beta.shape == g.shape == lead + (hv, t), (beta.shape, g.shape)
+    pad = _tiling(t)[1]
+    x = qkv.reshape((-1, t, c))
+    if pad:  # zero rows: k = v = 0 under the norm too, the state passes through
+        x = jnp.pad(x, [(0, 0), (0, pad), (0, 0)])
+    o = _rule_qkv(
+        x, _per_chunk(beta, t), jnp.cumsum(_per_chunk(g, t), axis=-1),
+        (hk, hv, dk, dv), eps, interpret,
+    )
+    return o[:, :t].reshape(lead + (hv, t, dv))
+
+
+__all__ = [
+    "BLOCK_CHUNKS", "CHUNK", "gated_delta_qkv_pallas", "gated_delta_rule_pallas",
+    "reads_qkv", "supports",
+]

@@ -13,7 +13,9 @@ chip run: nothing here says anything about results or times.
 """
 
 import dataclasses
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 
@@ -129,6 +131,14 @@ def _gated_delta(q, k, v, beta, g):
     return gated_delta_rule(q, k, v, beta, g, backend="pallas")
 
 
+def _gated_delta_qkv(qkv, beta, g):
+    from orion_tpu.ops.dispatch import gated_delta_qkv
+
+    return gated_delta_qkv(
+        qkv, beta, g, key_heads=16, key_dim=128, value_dim=128, eps=1e-6, backend="pallas"
+    )
+
+
 def _gated_delta_state(q, k, v, beta, g, s0):
     from orion_tpu.ops.dispatch import gated_delta_rule
 
@@ -231,6 +241,8 @@ _GMM_HELD = [((131072, 2048), jnp.bfloat16), ((64, 2048, 512), jnp.bfloat16),
 _DELTA = [*[((2, 16, 8192, 128), jnp.bfloat16)] * 2,
           ((2, 32, 8192, 128), jnp.bfloat16),
           *[((2, 32, 8192), jnp.float32)] * 2]
+# the whole batch of it read where the short conv leaves q, k and v
+_DELTA_QKV = [((8, 8192, 8192), jnp.bfloat16), *[((8, 32, 8192), jnp.float32)] * 2]
 # and that layer's short conv over its [q | k | v] channels, window 4
 _CONV = [((8, 8192, 8192), jnp.bfloat16), ((4, 8192), jnp.bfloat16)]
 # and its output gate: the rule's o head-major, z time-major, the norm's scale
@@ -338,6 +350,11 @@ KERNELS = [
         jax.grad(lambda *a: _f32sum(_gated_delta(*a)), argnums=(0, 1, 2, 3, 4)),
         _DELTA, id="gated_delta-T8192-bwd",
     ),
+    pytest.param(_gated_delta_qkv, _DELTA_QKV, id="gated_delta-T8192-qkv-in-place-fwd"),
+    pytest.param(
+        jax.grad(lambda *a: _f32sum(_gated_delta_qkv(*a)), argnums=(0, 1, 2)),
+        _DELTA_QKV, id="gated_delta-T8192-qkv-in-place-bwd",
+    ),
     pytest.param(_short_conv, _CONV, id="short_conv-fwd"),
     pytest.param(
         jax.grad(lambda x, w: _f32sum(_short_conv(x, w)), argnums=(0, 1)),
@@ -394,16 +411,10 @@ def test_kernel_compiles_for_v5e(v5e, fn, shapes):
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
-def test_delta_rule_block_gates_its_output_in_one_kernel(v5e):
-    """The compiled forward of one of ``qwen3_next_80b``'s delta-rule blocks
-    (two rows of the train point's 8,192): between the rule's kernel and
-    ``wo`` stands ``gated_norm_fwd`` alone, and under ``attn._output`` no
-    fp32 array the size of a head's slab or more (as XLA fusions the gate
-    wrote ``o`` and ``z`` out in fp32: ``f32[.., 4096]``, ``f32[.., 8192,
-    128]``, ``f32[.., 32, 128]``; PERF.md s5)."""
-    import math
-    import re
-
+def _delta_block_lines(v5e, fn):
+    """The compiled program's lines for ``fn(block, params, x)`` on one of
+    ``qwen3_next_80b``'s delta-rule blocks, two rows of the train point's
+    8,192, for the described chip."""
     from orion_tpu.models.configs import get_config
     from orion_tpu.models.transformer import Block
 
@@ -413,17 +424,66 @@ def test_delta_rule_block_gates_its_output_in_one_kernel(v5e):
     params = jax.eval_shape(block.init, jax.random.key(0), x)
     one = SingleDeviceSharding(v5e[0])
     on_chip = lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one)  # noqa: E731
-    text = jax.jit(block.apply).lower(
+    return jax.jit(lambda p, y: fn(block, p, y)).lower(
         jax.tree.map(on_chip, params), on_chip(x)
-    ).compile().as_text()
-    gate = [line for line in text.splitlines() if "attn._output" in line]
-    assert sum("custom_call_target" in line and "gated_norm_fwd" in line for line in gate) == 1
-    wide = [
-        m.group(0) for line in gate
-        for m in re.finditer(r"= \(?f32\[([\d,]+)\]", line)
-        if math.prod(int(n) for n in m.group(1).split(",")) >= 8192 * 128
+    ).compile().as_text().splitlines()
+
+
+def _result_sizes(line, dtype=r"\w+"):
+    """Elements of each array of ``dtype`` in an instruction's result."""
+    result = line.split(" = ", 1)[1].split("(%", 1)[0] if " = " in line else ""
+    return [
+        math.prod(int(n) for n in m.group(1).split(","))
+        for m in re.finditer(dtype + r"\[([\d,]+)\]", result)
     ]
+
+
+def test_delta_rule_block_gates_its_output_in_one_kernel(v5e):
+    """The compiled forward of one of ``qwen3_next_80b``'s delta-rule blocks
+    (two rows of the train point's 8,192): between the rule's kernel and
+    ``wo`` stands ``gated_norm_fwd`` alone, and under ``attn._output`` no
+    fp32 array the size of a head's slab or more (as XLA fusions the gate
+    wrote ``o`` and ``z`` out in fp32: ``f32[.., 4096]``, ``f32[.., 8192,
+    128]``, ``f32[.., 32, 128]``; PERF.md s5)."""
+    lines = _delta_block_lines(v5e, lambda block, p, y: block.apply(p, y))
+    gate = [line for line in lines if "attn._output" in line]
+    assert sum("custom_call_target" in line and "gated_norm_fwd" in line for line in gate) == 1
+    wide = [line for line in gate if max(_result_sizes(line, "f32"), default=0) >= 8192 * 128]
     assert not wide, wide
+
+
+def test_delta_rule_block_hands_qkv_to_the_rule_where_the_conv_left_it(v5e):
+    """The compiled GRADIENT of the same block: ``gated_delta_fwd`` reads
+    ``short_conv_fwd``'s output itself and ``short_conv_bwd`` reads
+    ``gated_delta_bwd``'s cotangent itself, and in the layer's scope outside
+    its projections, conv and gate nothing but those two kernels makes an
+    array the size of q (``[2, 8192, 2048]``) or more: no fp32 copy of q or
+    k (``f32[.., 8192, 2048]``, ``f32[.., 8192, 128]``), no head-major copy
+    or transpose of q, k, v or their cotangents, no reduce over a group's
+    value heads, no pad to ``[.., 8192, 8192]`` (PERF.md s5, PR 45's table;
+    the parent's program held 69 such lines, its fusions' bodies counted)."""
+    lines = _delta_block_lines(
+        v5e, lambda block, p, y: jax.grad(
+            lambda *a: _f32sum(block.apply(*a)), argnums=(0, 1))(p, y))
+    call = lambda kernel: [  # noqa: E731
+        line for line in lines if "custom_call_target" in line and f"/{kernel}/" in line]
+    name = lambda line: line.split(" = ")[0].split()[-1]  # noqa: E731
+    conv_fwd, rule_fwd, rule_bwd, conv_bwd = (
+        call(k) for k in ("short_conv_fwd", "gated_delta_fwd", "gated_delta_bwd", "short_conv_bwd"))
+    assert [len(c) for c in (conv_fwd, rule_fwd, rule_bwd, conv_bwd)] == [1, 1, 1, 1]
+    assert rule_fwd[0].count(name(conv_fwd[0]) + ",") == 3  # q, k and v: the one array
+    dqkv = [
+        name(line) for line in lines
+        if "get-tuple-element(" + name(rule_bwd[0]) + ")" in line and "bf16[2,8192,8192]" in line]
+    assert len(dqkv) == 1 and re.search(re.escape(dqkv[0]) + r"[,)]", conv_bwd[0])
+    own = ("short_conv", "attn._project", "attn._output", "/in_qkvz/", "/in_ba/", "/wo/")
+    around = [
+        line for line in lines
+        if "attn/gated_delta/" in line and not any(s in line for s in own)
+        and "/pallas_call" not in line
+        and max(_result_sizes(line), default=0) >= 2 * 8192 * 2048
+    ]
+    assert not around, around
 
 
 # -- whole programs (slow: ~20 s to ~4 min each) -----------------------------
@@ -474,12 +534,15 @@ def test_smoke_train_step_compiles_and_fits(v5e, layout, collective):
 def test_qwen3_next_train_step_compiles_and_fits(v5e):
     """benchmark/workloads/qwen3_next_80b.train.json's step — b8 x T8192,
     adafactor, bfloat16_sr, every block rematted — fits one chip with the
-    flash, grouped-matmul, delta-rule and short-conv kernels in it (the
-    memory point known before a chip call: 2.07 GB of arguments + 13.1 GB
-    of temporaries by the compiler's count, of which the donated state's
-    2.07 GB is counted twice; 13.4 GB with the conv as XLA fusions, whose
-    backward held fp32 pads, 14.3 GB with the delta rule in its XLA form
-    too; the chip holds 15.1 GB while it runs, PERF.md s5)."""
+    flash, grouped-matmul, delta-rule, short-conv and gate kernels in it
+    (the memory point known before a chip call, by the compiler's count at
+    PR 48: 2.07 GB of arguments + 11.53 GB of temporaries, of which the
+    donated state's 2.07 GB is counted twice, and 354.6 MB of generated
+    code; 12.62 GB and 365.6 MB on PR 48's parent, which held q, k and v
+    head-major and in fp32 beside the conv's output; 13.1 GB before the
+    gate's kernels, 13.4 GB with the conv as XLA fusions, whose backward
+    held fp32 pads, 14.3 GB with the delta rule in its XLA form too; the
+    chip's own peak while it runs is PERF.md s5's ``hbm_gb``)."""
     from orion_tpu.aot import plan
     from orion_tpu.models.configs import get_config
     from orion_tpu.parallel.mesh import MeshConfig, make_mesh

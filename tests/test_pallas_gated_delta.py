@@ -12,18 +12,26 @@ rounded to 8 bits where the XLA form rounds them, so the kernel is held
 to a few roundings of the output (2^-6 of its largest entry against the
 fp32 recurrence, 2^-7 against the XLA form at the same inputs) and a few
 percent of each gradient's largest entry.
+
+The kernels that read q, k and v where the short conv left them
+(``gated_delta_qkv_pallas``) are held to the same limits against the
+specification ``ops/gated_delta.py::gated_delta_qkv`` (slice, l2 norm, scale,
+the recurrence), forward and ``jax.grad`` with respect to qkv, beta and g.
 """
+
+import dataclasses
+import hashlib
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from orion_tpu.ops import dispatch
 from orion_tpu.ops.dispatch import gated_delta_rule
-from orion_tpu.ops.gated_delta import gated_delta_chunked, gated_delta_recurrent
+from orion_tpu.ops.gated_delta import (
+    gated_delta_chunked, gated_delta_qkv, gated_delta_recurrent,
+)
 from orion_tpu.ops.pallas import gated_delta as pgd
-
-ARGNUMS = (0, 1, 2, 3, 4)
-
 
 def inputs(t, g_scale, *, lead=(1, 2), hk=None, dk=16, dv=24, dtype=jnp.float32, seed=0):
     """q, k on ``hk`` key heads (default: one a value head), unit keys."""
@@ -85,20 +93,21 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("case", CASES)
-def test_kernel_equals_the_recurrence_forward_and_grad(case):
-    make, opts = CASES[case]
-    args = make()
-    bf16 = opts.get("bf16", False)
-    want, got = recurrence(*args), jax.jit(kernel)(*args)
-    assert got.dtype == args[2].dtype and got.shape == want.shape
+def assert_forward_and_grads_agree(want_fn, got_fn, args, names, bf16, opts=None):
+    """``got_fn`` (a kernel) against ``want_fn`` (the fp32 recurrence) on
+    ``args``: the output, and ``jax.grad`` of a weighted sum of it with
+    respect to every argument (``names``), to this file's tolerances."""
+    opts = opts or {}
+    want, got = want_fn(*args), jax.jit(got_fn)(*args)
+    assert got.dtype == want.dtype and got.shape == want.shape
     scale = float(jnp.abs(want.astype(jnp.float32)).max())
     assert scale > 0.1  # not a comparison of zeros
     err = float(jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32)).max())
     assert err < (2.0 ** -6 * scale if bf16 else 2e-5), err
-    g_want = jax.grad(weighted(recurrence, args[2]), argnums=ARGNUMS)(*args)
-    g_got = jax.jit(jax.grad(weighted(kernel, args[2]), argnums=ARGNUMS))(*args)
-    for name, w, g, a in zip(("dq", "dk", "dv", "dbeta", "dg"), g_want, g_got, args):
+    argnums = tuple(range(len(args)))
+    g_want = jax.grad(weighted(want_fn, want), argnums=argnums)(*args)
+    g_got = jax.jit(jax.grad(weighted(got_fn, want), argnums=argnums))(*args)
+    for name, w, g, a in zip(names, g_want, g_got, args):
         assert g.shape == a.shape and g.dtype == a.dtype, name
         w, g = w.astype(jnp.float32), g.astype(jnp.float32)
         assert bool(jnp.isfinite(g).all()), name
@@ -109,6 +118,15 @@ def test_kernel_equals_the_recurrence_forward_and_grad(case):
         assert top > 1e-6, name
         tol = 0.04 if bf16 else opts.get(name, 1e-4)
         assert float(jnp.abs(g - w).max()) < tol * top, (name, float(jnp.abs(g - w).max()) / top)
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_kernel_equals_the_recurrence_forward_and_grad(case):
+    make, opts = CASES[case]
+    assert_forward_and_grads_agree(
+        recurrence, kernel, make(), ("dq", "dk", "dv", "dbeta", "dg"),
+        opts.get("bf16", False), opts,
+    )
 
 
 def test_kernel_and_xla_chunked_form_agree_to_the_outputs_rounding():
@@ -175,3 +193,109 @@ def test_mixer_on_a_mesh_runs_the_kernel_on_each_shard():
     assert float(jnp.abs(got - want).max()) < 2e-5
     g_want, g_got = jax.grad(loss(plain))(x), jax.jit(jax.grad(loss(sharded)))(x)
     assert float(jnp.abs(g_got - g_want).max()) < 1e-4 * float(jnp.abs(g_want).max())
+
+
+def qkv_inputs(t, *, hk, group, d=16, dtype=jnp.float32, batch=2, seed=0):
+    """The short conv's output ``[B, T, C]`` (SiLU of noise, as the conv
+    leaves it), beta and g ``[B, Hv, T]``, and the heads' widths."""
+    hv = hk * group
+    ks = jax.random.split(jax.random.key(seed), 3)
+    qkv = jax.nn.silu(jax.random.normal(ks[0], (batch, t, (2 * hk + hv) * d))).astype(dtype)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[1], (batch, hv, t)))
+    g = -0.5 * jax.nn.softplus(jax.random.normal(ks[2], (batch, hv, t)))
+    return (qkv, beta, g), dict(key_heads=hk, key_dim=d, value_dim=d, eps=1e-6)
+
+
+# batch 2 throughout; T of one chunk (whole and ragged), of one short block
+# with a ragged tail, of two blocks; one and two value heads a key head
+QKV_CASES = {
+    "T100-one-ragged-chunk-group1": dict(t=100, hk=2, group=1),
+    "T128-one-chunk-group2": dict(t=128, hk=2, group=2),
+    "T128-one-chunk-group2-bf16": dict(t=128, hk=2, group=2, dtype=jnp.bfloat16),
+    "T600-one-short-block-group2": dict(t=600, hk=1, group=2),
+    "T1100-two-blocks-group1": dict(t=1100, hk=1, group=1),
+    "T1100-two-blocks-group2-bf16": dict(t=1100, hk=1, group=2, dtype=jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", QKV_CASES)
+def test_in_place_kernels_equal_the_specification_forward_and_grad(case):
+    args, heads = qkv_inputs(**QKV_CASES[case])
+    assert_forward_and_grads_agree(
+        lambda *a: gated_delta_qkv(*a, **heads),
+        lambda *a: dispatch.gated_delta_qkv(*a, backend="pallas_interpret", **heads),
+        args, ("dqkv", "dbeta", "dg"), args[0].dtype == jnp.bfloat16,
+    )
+
+
+# sha256[:16] of str(jax.make_jaxpr(...)) on the PARENT of PR 48 (f06ed3f)
+PARENT_PROGRAMS = {
+    "olmo-pallas-forward": "0d8275577c09e90e", "olmo-pallas-piece": "be1c5c2997640eeb",
+    "olmo-pallas-step": "3f5ccda737843369", "olmo-xla-piece": "70328a13b4957845",
+    "qwen-xla-forward": "11479214086f6eb4", "qwen-eager-forward": "0a28a85c13b596aa",
+}
+
+
+def test_in_place_form_is_reached_only_where_the_kernels_read_qkv(monkeypatch):
+    """Whole lane tiles (compiled), v's columns on whole blocks, a Pallas
+    backend, no state and no split mesh: everything else is the program it
+    was. At ``olmo_hybrid_7b``'s widths (96 x 192) the forward, a prompt piece
+    and a decode step, and under ``xla`` / ``eager`` the training forward,
+    trace to the parent's jaxpr."""
+    from orion_tpu.models.configs import get_config
+    from orion_tpu.models.mixers import GatedDeltaNet
+    from orion_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    reads = dispatch.gated_delta_reads_qkv
+    assert reads(16, 32, 128, 128, backend="pallas")  # qwen3_next_80b's heads
+    assert reads(4, 4, 256, 128, backend="pallas")
+    assert not reads(30, 30, 96, 192, backend="pallas")  # olmo_hybrid_7b's
+    assert not reads(3, 12, 128, 128, backend="pallas")  # v starts inside a group's block
+    assert reads(2, 4, 16, 16, backend="pallas_interpret")
+    assert not any(reads(16, 32, 128, 128, backend=b) for b in ("xla", "eager"))
+
+    calls = []
+    real = pgd.gated_delta_qkv_pallas
+    monkeypatch.setattr(
+        pgd, "gated_delta_qkv_pallas", lambda *a, **kw: calls.append(a[0].shape) or real(*a, **kw)
+    )
+    olmo = lambda backend: dataclasses.replace(  # noqa: E731
+        get_config("olmo_hybrid_7b"), d_model=96, gdn_key_heads=3, gdn_value_heads=3,
+        gdn_key_dim=96, gdn_value_dim=192, dtype="bfloat16", param_dtype="float32",
+        backend=backend)
+    qwen = lambda backend: dataclasses.replace(  # noqa: E731
+        get_config("qwen3_next_80b"), d_model=64, gdn_key_heads=2, gdn_value_heads=4,
+        gdn_key_dim=16, gdn_value_dim=16, dtype="float32", backend=backend)
+
+    def programs(cfg, t, mesh=None):
+        m = GatedDeltaNet(cfg, mesh=mesh)
+        x = jnp.zeros((2, t, cfg.d_model), jnp.dtype(cfg.dtype))
+        params = jax.eval_shape(m.init, jax.random.key(0), x)
+        state = jax.eval_shape(lambda: GatedDeltaNet.decode_state(cfg, "gated_delta", 2, x.dtype))
+        return {
+            "forward": lambda: jax.make_jaxpr(m.apply)(params, x),
+            "piece": lambda: jax.make_jaxpr(
+                lambda p, y, s, n: m.apply(p, y, s, 0, n, method="prefill_extend")
+            )(params, x, state, jnp.int32(100)),
+            "step": lambda: jax.make_jaxpr(
+                lambda p, y, s: m.apply(p, y, s, jnp.int32(5), method="decode_step")
+            )(params, x[:, 0], state),
+        }
+
+    traced = {}
+    for name, cfg, t in (("olmo-pallas", olmo("pallas"), 160), ("olmo-xla", olmo("xla"), 160),
+                         ("qwen-xla", qwen("xla"), 70), ("qwen-eager", qwen("eager"), 70)):
+        for kind, trace in programs(cfg, t).items():
+            traced[f"{name}-{kind}"] = hashlib.sha256(str(trace()).encode()).hexdigest()[:16]
+    assert not calls
+    assert {k: traced[k] for k in PARENT_PROGRAMS} == PARENT_PROGRAMS
+
+    interpret = programs(qwen("pallas_interpret"), 70)
+    calls.clear()  # init traced the forward
+    interpret["piece"](), interpret["step"]()
+    assert not calls  # a state in or out: the head-major kernels
+    interpret["forward"]()
+    assert calls == [(2, 70, 128)]
+    mesh = make_mesh(MeshConfig(dp=2, tp=2).resolve(4), devices=jax.devices()[:4])
+    programs(qwen("pallas_interpret"), 70, mesh)["forward"]()
+    assert len(calls) == 1  # a mesh whose data axes split: kernel_bh's head-major form
