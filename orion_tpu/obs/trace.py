@@ -51,7 +51,11 @@ Event model (Chrome trace-event format, ``ts``/``dur`` in microseconds):
   ``compile.lower``, ``compile.backend`` with ``fun_name`` and, on the
   last, ``source`` (``compiled`` or ``cache``) and ``cache_load_ms``.
   ``utils/profiling.py`` hears them from ``jax.monitoring`` and hands
-  them to :func:`compile_event`; a ``Server`` that is serving hears them
+  them to :func:`compile_event`; ``compile.kernel`` (``fun_name`` the
+  ``pallas_call``'s name, ``source`` ``traced``) comes from
+  ``ops/pallas.kernel_entry`` each time a Mosaic kernel's Python body is
+  traced, inside the ``compile.trace`` of the program that called it; a
+  ``Server`` that is serving hears them
   from here (:func:`on_compile`) and writes them into its ring with the
   boundary they fell in.
 
@@ -95,13 +99,16 @@ RECORD_CATS = ("setup", "compile")
 _RECORD: Dict[str, deque] = {
     "setup": deque(maxlen=1 << 12), "compile": deque(maxlen=1 << 13),
 }
-# what the compile events add up to over the process's life: the five
+# what the compile events add up to over the process's life: the
 # counters a registry shows (``Server.metrics``, ``MetricsLogger.registry``)
 COMPILE_COUNTERS = (
     "programs_traced", "programs_compiled", "programs_cache_loaded",
-    "compile_ms_total", "trace_lower_ms_total",
+    "compile_ms_total", "trace_lower_ms_total", "kernel_bodies_traced",
 )
-_COMPILE_TOTALS: Dict[str, float] = dict.fromkeys(COMPILE_COUNTERS, 0)
+# beside them the calls of kernel entries, traced or found in jax's trace
+# cache: no event each (a program holds hundreds), so only the process counts
+_COMPILE_TOTALS: Dict[str, float] = dict.fromkeys(
+    COMPILE_COUNTERS + ("kernel_call_sites",), 0)
 _COMPILE_SINKS: List[weakref.WeakMethod] = []
 # entry packages whose import is under way (one may import the other), and
 # whether the process's first ``setup.import`` has been written
@@ -403,6 +410,8 @@ def compile_counts(name: str, dur_s: float, args: dict):
         loaded = args.get("source") == "cache"
         return (("programs_cache_loaded" if loaded else "programs_compiled", 1),
                 ("compile_ms_total", dur_s * 1e3))
+    if name == "compile.kernel":  # its seconds lie inside a compile.trace
+        return (("kernel_bodies_traced", 1),)
     return (("programs_traced", 1 if name == "compile.trace" else 0),
             ("trace_lower_ms_total", dur_s * 1e3))
 
@@ -420,8 +429,17 @@ def compile_event(name: str, start_s: float, dur_s: float, **args) -> None:
             sink(name, start_s, dur_s, args)
 
 
+def kernel_call_site() -> None:
+    """One call of a Mosaic kernel entry (``ops/pallas.kernel_entry``),
+    whether jax then traces its body (a ``compile.kernel`` event) or binds
+    the jaxpr it kept."""
+    _COMPILE_TOTALS["kernel_call_sites"] += 1
+
+
 def compile_totals() -> Dict[str, float]:
-    """The process's :data:`COMPILE_COUNTERS` so far."""
+    """The process's :data:`COMPILE_COUNTERS` so far and
+    ``kernel_call_sites``: ``kernel_bodies_traced`` over it is the share of
+    kernel calls whose body had to be traced."""
     return dict(_COMPILE_TOTALS)
 
 
@@ -527,7 +545,7 @@ if __name__ == "__main__":
 __all__ = [
     "Tracer", "Span", "NULL_SPAN", "read_jsonl", "merge_traces", "span_pairs",
     "PROCESS_TRACER", "setup_record", "setup_summary", "compile_event",
-    "compile_counts", "compile_totals", "on_compile", "import_begin",
+    "compile_counts", "compile_totals", "kernel_call_site", "on_compile", "import_begin",
     "import_done",
     "COMPILE_COUNTERS",
 ]

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from orion_tpu.ops.pallas import kernel_entry
 from orion_tpu.ops.softmax_attention import _NEG
 
 Array = jax.Array
@@ -191,6 +192,7 @@ def _kernel(dot, bk, nblk, idx_ref, len_ref, q_ref, k_ref, v_ref, o_in, lse_in,
         lse_ref[0] = m_scr[...] + jnp.log(safe)
 
 
+@kernel_entry("cache_attention", "interpret")
 def cache_attention(
     q: Array, k_cache: Array, v_cache: Array, lengths: Array,
     rows: Tuple[Array, Array], *, interpret: bool = False,
@@ -342,6 +344,7 @@ def _block_kernel(bs, width, kvh, idx_ref, len_ref, cnt_ref, blk_ref,
         lse_ref[0, 0] = m_scr[...] + jnp.log(safe)
 
 
+@kernel_entry("block_attention", "block", "interpret")
 def block_attention(
     q: Array, k_cache: Array, v_cache: Array, lengths: Array, blocks: Array,
     counts: Array, rows: Tuple[Array, Array], *, block: int,
@@ -485,6 +488,7 @@ def _latent_kernel(bk, nblk, idx_ref, len_ref, qt_ref, qr_ref, c_ref, kr_ref,
         lse_ref[0] = m_scr[...] + jnp.log(safe)
 
 
+@kernel_entry("latent_attention", "scale", "interpret")
 def latent_attention(
     qt: Array, qr: Array, c_cache: Array, kr_cache: Array, lengths: Array,
     rows: Tuple[Array, Array], *, scale: float, interpret: bool = False,

@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from orion_tpu.ops.pallas import kernel_entry
+
 Array = jax.Array
 
 
@@ -111,6 +113,7 @@ def _kernel(q_ref, k_ref, v_ref, s0_ref, out_ref, sf_ref, s_scr):
     sf_ref[0] = s_scr[:]
 
 
+@kernel_entry("causal_dot_fwd", "chunk", "interpret")
 def _cdp_flat(
     q: Array, k: Array, v: Array, s0: Array, chunk: int, interpret: bool
 ) -> Tuple[Array, Array]:
@@ -289,6 +292,7 @@ def _bwd_dq_den_kernel(
     z_scr[:] = z_scr[:] + jnp.sum(kf, axis=0, keepdims=True)
 
 
+@kernel_entry("causal_dot_dq", "chunk", "interpret")
 def _cdp_dq_den_flat(g, v, k, s0t, gden, z0, chunk, interpret):
     """dq (numerator + denominator parts) on flat inputs, emitted directly
     in ``g``'s dtype — nothing downstream adds to it."""
@@ -326,6 +330,7 @@ def _cdp_dq_den_flat(g, v, k, s0t, gden, z0, chunk, interpret):
 _bwd_rev_den_kernel = _bwd_rev_core
 
 
+@kernel_entry("causal_dot_norm_dkv", "chunk", "interpret")
 def _cdp_rev_den_flat(q, k, v, g, gden, rinit, zr0, chunk, interpret):
     """Fused (dk, dv, ds0, dz0) for the normalized backward. dk/dv in the
     input dtypes (final values); ds0 [BH, Dk, Dv] and dz0 [BH, 1, Dk] fp32."""
@@ -369,6 +374,7 @@ def _cdp_rev_den_flat(q, k, v, g, gden, rinit, zr0, chunk, interpret):
     return dk_out, dv_out, ds0, zrfin
 
 
+@kernel_entry("causal_dot_dkv", "chunk", "interpret")
 def _cdp_rev_flat(q, k, v, g, rinit, chunk, interpret):
     """Fused (dk, dv, ds0) on flat [BH, T, D] inputs (T % chunk == 0).
     ``rinit`` = dSf^T [BH, Dv, Dk] fp32; returns ds0 [BH, Dk, Dv] fp32."""
@@ -531,6 +537,7 @@ def _kernel_norm(
     zf_ref[0] = z_scr[:]
 
 
+@kernel_entry("causal_dot_norm_fwd", "chunk", "interpret")
 def _cdpn_flat(q, k, v, s0, z0, chunk, interpret):
     """Fused pass on flat [BH, T, D] inputs (T % chunk == 0): returns
     (num fp32, den fp32 [BH,T,1], sf fp32, zf fp32 [BH,1,Dk])."""
@@ -808,6 +815,7 @@ def _decay_kernel(len_ref, a_ref, q_ref, k_ref, v_ref, s0_ref, out_ref, sf_ref, 
     sf_ref[0] = s_scr[:]
 
 
+@kernel_entry("causal_dot_decay_fwd", "chunk", "interpret")
 def decayed_causal_dot_pallas(
     q: Array, k: Array, v: Array, slopes: Array, *, chunk: Optional[int] = None,
     initial_state: Optional[Array] = None, length=None, interpret: bool = False,

@@ -62,6 +62,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from orion_tpu.ops.linear_attention import _DEFAULT_EPS
+from orion_tpu.ops.pallas import kernel_entry
 
 Array = jax.Array
 
@@ -132,10 +133,7 @@ def _step_kernel(eps, rows_ref, j_ref, s_ref, z_ref, q_ref, k_ref, kc_ref,
     o_ref[0] = (num / den).astype(o_ref.dtype)
 
 
-# jitted, as the flush is: a decode program calls each once a layer, and an
-# inner jit is traced and lowered once a program, not once a layer (24 x 2
-# kernels one by one added 8 s to a server's warm-up: PERF.md, PR 38)
-@functools.partial(jax.jit, static_argnames=("eps", "interpret"))
+@kernel_entry("decode_state_step", "eps", "interpret")
 def decode_state_step(
     q: Array,
     k: Array,
@@ -227,6 +225,7 @@ def _delta_kernel(rows_ref, s_ref, q_ref, k_ref, eg_ref, v_ref, b_ref, s_out, o_
     o_ref[0] = jnp.sum(s * q_ref[0][:, :, None], axis=1)
 
 
+@kernel_entry("gated_delta_step", "interpret")
 def gated_delta_step(
     q: Array, k: Array, v: Array, beta: Array, g: Array, s: Array,
     rows: Tuple[Array, Array], *, interpret: bool = False,
@@ -285,6 +284,7 @@ def _decay_kernel(rows_ref, s_ref, lam_ref, q_ref, k_ref, v_ref, s_out, o_ref):
     o_ref[0] = jnp.sum(qf[:, :, None] * sf, axis=1).astype(o_ref.dtype)
 
 
+@kernel_entry("decay_state_step", "interpret")
 def decay_state_step(
     q: Array, k: Array, v: Array, s: Array, slopes: Array,
     rows: Tuple[Array, Array], *, interpret: bool = False,
@@ -340,7 +340,7 @@ def _flush_kernel(precision, rows_ref, s_ref, z_ref, kt_ref, vt_ref, s_out, z_ou
     z_out[0] = z_ref[0] + jnp.sum(kt_ref[0].astype(jnp.float32), axis=1)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@kernel_entry("decode_state_flush", "interpret")
 def decode_state_flush(
     state: Tuple[Array, Array],
     chunk: Tuple[Array, Array],

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from orion_tpu.ops.pallas import kernel_entry
 from orion_tpu.ops.pallas.causal_dot import _sds  # vma-carrying out_shape:
 # lets these kernels compose with shard_map(check_vma=True) bodies
 # (parallel/kernel_shard.py, parallel/pipeline.py) the same way the
@@ -176,6 +177,10 @@ def _fwd_kernel(
         lse_ref[0] = m_scr[:] + jnp.log(safe)  # (Bq, 1)
 
 
+@kernel_entry(
+    "flash_attn_fwd", "scale", "causal", "window", "bq", "bk", "interpret", "shift",
+    "q_offset",
+)
 def _flash_fwd_flat(q, k, v, scale, causal, window, bq, bk, interpret, shift=0,
                     q_offset=0):
     bh, t_q, d = q.shape
@@ -333,6 +338,10 @@ def _dkv_kernel(
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+@kernel_entry(
+    "flash_attn_bwd", "scale", "causal", "window", "bq", "bk", "interpret", "shift",
+    "q_offset",
+)
 def _flash_bwd_flat(q, k, v, out, lse, g, scale, causal, window, bq, bk, interpret,
                     shift=0, dlse=None, q_offset=0):
     bh, t_q, d = q.shape

@@ -93,6 +93,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from orion_tpu.ops.pallas import kernel_entry
 from orion_tpu.ops.pallas.causal_dot import _sds
 
 Array = jax.Array
@@ -418,6 +419,7 @@ def _forward_call(specs, operands, dk, dv, beta, gcum, save, interpret, unit=Non
     return (outs[0], outs[1:]) if save else outs[0]
 
 
+@kernel_entry("gated_delta_fwd", "save", "interpret")
 def _forward(q, k, v, beta, gcum, save, interpret):
     dk, dv = q.shape[-1], v.shape[-1]
     qk_spec, v_spec = _specs(v.shape[0] // q.shape[0], beta, dk, dv, False)[:2]
@@ -426,6 +428,7 @@ def _forward(q, k, v, beta, gcum, save, interpret):
     )
 
 
+@kernel_entry("gated_delta_fwd_state", "interpret")
 def _forward_state(q, k, v, beta, gcum, s0, interpret):
     """``(o, final state)`` from the state ``s0 [BH, Dk, Dv]`` fp32."""
     bh, _, dv = v.shape
@@ -446,6 +449,7 @@ def _forward_state(q, k, v, beta, gcum, s0, interpret):
     )(q, k, v, gcum, beta, s0)
 
 
+@kernel_entry("gated_delta_bwd", "interpret")
 def _backward(q, k, v, beta, gcum, do, saved, interpret):
     bh, tp, dv = v.shape
     dk = q.shape[-1]
@@ -598,6 +602,7 @@ def _qkv_specs(dims, beta, reverse, each):
 _VMEM_BYTES = 64 << 20  # a key head's step holds its value heads' operands
 
 
+@kernel_entry("gated_delta_fwd", "dims", "eps", "save", "interpret")
 def _forward_qkv(qkv, beta, gcum, dims, eps, save, interpret):
     _, _, dk, dv = dims
     return _forward_call(
@@ -606,6 +611,7 @@ def _forward_qkv(qkv, beta, gcum, dims, eps, save, interpret):
     )
 
 
+@kernel_entry("gated_delta_bwd", "dims", "eps", "interpret")
 def _backward_qkv(qkv, beta, gcum, do, saved, dims, eps, interpret):
     """``(d qkv [B, T, C], dG, dbeta)``: the grid ``(B x key heads, blocks
     last to first)``; the cotangent stays in HBM and the kernel copies its

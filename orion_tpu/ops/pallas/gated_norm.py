@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from orion_tpu.ops.pallas import kernel_entry
+
 Array = jax.Array
 
 # rows of a sublane tile of bf16 (16) and two of fp32 (8)
@@ -164,6 +166,7 @@ def _operands(o, z, w):
     )
 
 
+@kernel_entry("gated_norm_fwd", "eps", "interpret")
 def _forward(o, z, w, eps, interpret):
     o4, z3, w2 = _operands(o, z, w)
     grid, block = _blocks(o4)
@@ -180,6 +183,7 @@ def _forward(o, z, w, eps, interpret):
     return y.reshape(z.shape)
 
 
+@kernel_entry("gated_norm_bwd", "eps", "interpret")
 def _backward(o, z, w, dy, eps, interpret):
     o4, z3, w2 = _operands(o, z, w)
     grid, block = _blocks(o4)

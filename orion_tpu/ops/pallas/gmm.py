@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from orion_tpu.ops.pallas import kernel_entry
 from orion_tpu.ops.pallas.causal_dot import _sds  # vma-carrying out_shape:
 # lets these kernels compose with shard_map(check_vma=True) bodies (the
 # dropless-ep gmm region, models/moe.py::_dropless_ep_gmm)
@@ -80,6 +81,7 @@ def _fwd_kernel(te_ref, x_ref, w_ref, o_ref):
     ).astype(o_ref.dtype)
 
 
+@kernel_entry("gmm_fwd", "tile_rows", "block_h", "interpret")
 def _gmm_call(x, w, tile_expert, tile_rows, block_h, interpret):
     m, d = x.shape
     e, _, h = w.shape
@@ -126,6 +128,7 @@ def _dw_kernel(te_ref, x_ref, g_ref, dw_ref):
     )[None]
 
 
+@kernel_entry("gmm_dw", "n_experts", "tile_rows", "block_d", "block_h", "interpret")
 def _dw_call(x, g, tile_expert, n_experts, tile_rows, block_d, block_h,
              interpret):
     """dw[e] = sum over e's rows of x^T g, BOTH output dims tiled: the
@@ -231,6 +234,7 @@ def _gmm_bwd(tile_rows, block_h, interpret, res, dy):
 gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
+@kernel_entry("gmm_live", "tile_rows", "block_h", "interpret")
 def gmm_live(
     x: Array, w: Array, group_sizes: Array, tile_rows: int = 128,
     block_h: int = 512, interpret: bool = False,

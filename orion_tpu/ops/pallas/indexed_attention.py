@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from orion_tpu.ops.pallas import kernel_entry
+
 Array = jax.Array
 
 _NEG = -1e30
@@ -50,6 +52,7 @@ def _score_kernel(q_ref, w_ref, k_ref, o_ref, *, heads: int):
     o_ref[0] = acc
 
 
+@kernel_entry("index_scores", "interpret")
 def index_scores(qi: Array, w: Array, ki: Array, *, interpret: bool = False) -> Array:
     """qi ``[B, P, IH, ID]``, w ``[B, P, IH]`` fp32, ki ``[B, S, ID]`` -> ``I``
     ``[B, P, S]`` fp32 (``mixers/indexed.py::index_scores``)."""
@@ -108,6 +111,7 @@ def _attend_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, m_scr, l_scr, acc_scr,
         o_ref[0, 0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
+@kernel_entry("indexed_attention", "interpret")
 def masked_attention(q: Array, k_rows: Array, v_rows: Array, keep: Array,
                      *, interpret: bool = False) -> Array:
     """q ``[B, KV, G, P, Dh]``, caches ``[B, S, KV Dh]``, ``keep`` int8 ``[B,

@@ -47,6 +47,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from orion_tpu.ops.gated_delta import causal_short_conv as _xla_form
+from orion_tpu.ops.pallas import kernel_entry
 
 Array = jax.Array
 
@@ -270,6 +271,7 @@ def _params(*semantics: str):
     )
 
 
+@kernel_entry("short_conv_fwd", "activation", "interpret")
 def _forward(x, w, tail, bias, activation, interpret):
     x3, halo, wb = _operands(x, w, tail, bias)
     grid, block = _grid(x3)
@@ -286,6 +288,7 @@ def _forward(x, w, tail, bias, activation, interpret):
     return out.reshape(x.shape)
 
 
+@kernel_entry("short_conv_bwd", "activation", "interpret")
 def _backward(x, w, tail, bias, dy, activation, interpret):
     x3, halo, wb = _operands(x, w, tail, bias)
     dy3 = dy.reshape(x3.shape)

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from orion_tpu.ops.pallas import kernel_entry
 from orion_tpu.ops.ssm import packed_step_operands
 
 Array = jax.Array
@@ -64,6 +65,7 @@ def check_step_operands(x, bm, s, idx) -> None:
         raise ValueError(f"operands do not fit S {s.shape}: {shapes}")
 
 
+@kernel_entry("ssm_state_step", "pack", "interpret")
 def ssm_state_step(
     x: Array, dt: Array, a: Array, bm: Array, cm: Array, s: Array, pack: int,
     rows: Tuple[Array, Array], *, interpret: bool = False,

@@ -486,6 +486,50 @@ def test_delta_rule_block_hands_qkv_to_the_rule_where_the_conv_left_it(v5e):
     assert not around, around
 
 
+def test_stacked_delta_rule_blocks_keep_their_own_name_stacks(v5e):
+    """Two of ``qwen3_next_80b``'s delta-rule blocks one after the other,
+    the gradient lowered for the described chip: every ``tpu_custom_call``
+    carries the name stack of the layer that calls it, forward and backward,
+    though the second layer's kernels bind jaxprs that the first layer's
+    calls traced (``ops/pallas.kernel_entry``: inlined; one shared callee a
+    kernel would give them all to ``layers_0``, and the scope readers of
+    ``benchmark/readers/scope_share.py`` with it); and there are as many
+    custom calls as before the kernels' entries were jitted: twelve."""
+    import flax.linen as nn
+
+    from orion_tpu.models.configs import get_config
+    from orion_tpu.models.transformer import Block
+
+    cfg = dataclasses.replace(get_config("qwen3_next_80b"), backend="pallas", remat=False)
+
+    class Two(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            for i in range(2):
+                x = Block(cfg, "gated_delta", name=f"layers_{i}")(x)
+            return x
+
+    two = Two()
+    x = jax.ShapeDtypeStruct((2, 8192, cfg.d_model), jnp.bfloat16)
+    one = SingleDeviceSharding(v5e[0])
+    on_chip = lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one)  # noqa: E731
+    params = jax.tree.map(on_chip, jax.eval_shape(two.init, jax.random.key(0), x))
+    text = jax.jit(jax.grad(lambda *a: _f32sum(two.apply(*a)), argnums=(0, 1))).lower(
+        params, on_chip(x)).as_text(debug_info=True)
+    stacks = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    calls = [
+        stacks[loc] for loc in
+        re.findall(r"stablehlo\.custom_call @tpu_custom_call.*?loc\((#loc\d+)\)", text)]
+    assert len(calls) == 12
+    kernels = ("short_conv_fwd", "gated_delta_fwd", "gated_norm_fwd",
+               "gated_norm_bwd", "gated_delta_bwd", "short_conv_bwd")
+    for layer in ("layers_0", "layers_1"):
+        own = [c for c in calls if f"/{layer}/" in c]
+        assert sorted(k for c in own for k in kernels if f"/{k}/" in c) == sorted(kernels), own
+        assert not any(other in c for c in own for other in {"layers_0", "layers_1"} - {layer})
+    assert any("transpose(jvp(" in c for c in calls)  # the backward's are among them
+
+
 # -- whole programs (slow: ~20 s to ~4 min each) -----------------------------
 
 
