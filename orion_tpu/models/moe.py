@@ -919,6 +919,7 @@ def _gmm_matmul(tm: int, bh: int, interpret: bool, live_tiles: bool = False):
 
     matmul.tile = tm
     matmul.unwritten_tail = live_tiles
+    matmul.interpret = interpret
     return matmul
 
 
@@ -952,7 +953,15 @@ def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt):
     counted. Everything M-sized is an index: the buffer's row ``r`` belongs
     to local expert ``c`` at offset ``off`` of its segment, so it is pair
     ``order[tight[c] + off]``, and only buffer-sized arrays are d wide (at a
-    router 8 x the held experts, [M, d] is 8 x the rows that do work)."""
+    router 8 x the held experts, [M, d] is 8 x the rows that do work).
+
+    Three forms, told apart by what ``matmul`` says of itself: the tiled
+    Mosaic product in training moves the rows into the buffer and out of it
+    by list in Mosaic kernels (``ops/pallas/moe_rows.py``, with their own
+    backward); serving (``unwritten_tail``) takes them with ``jnp.take`` and
+    gathers its combine (``_gather_combine``); ``_ragged_matmul`` is the
+    plain form, ``jnp.take`` and a scatter-add, which the tests hold the
+    other two to."""
     el, tm = ws[0].shape[0], matmul.tile
     (n, d), m = x2.shape, flat.shape[0]
     k = m // n
@@ -975,10 +984,27 @@ def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt):
         pair = order[jnp.clip(tight[c] + off, 0, m - 1)]
         token = pair // k
         gate_row = jnp.where(valid, gates[pair], 0.0)
+        by_list = tm > 1 and not matmul.unwritten_tail
+        if by_list:
+            from orion_tpu.ops.pallas import moe_rows
+
+            # the same rows from the pairs' side: pair p of class c sits at
+            # offset rank[p] - tight[c] of c's segment (a select over [M, el]
+            # reads the per-expert tables: XLA's gather costs a pair ~15 ns)
+            mine = cls[:, None] == jnp.arange(el, dtype=cls.dtype)[None, :]
+            per_pair = lambda table: jnp.sum(jnp.where(mine, table[None, :], 0), axis=1)  # noqa: E731
+            listed = jnp.where(valid, token, -1)
+            lists = moe_rows.combine_lists(
+                rank < per_pair(tight + gs), rank + per_pair(starts - tight),
+                jax.lax.stop_gradient(gates), n,
+            )
 
     with scope("moe_experts"):
         # pad rows are zeros: they flow through the FFN as zeros
-        xs = jnp.where(valid[:, None], jnp.take(x2.astype(dt), token, axis=0), 0)
+        if by_list:
+            xs = moe_rows.gather_rows(x2.astype(dt), listed, lists, tm, matmul.interpret)
+        else:
+            xs = jnp.where(valid[:, None], jnp.take(x2.astype(dt), token, axis=0), 0)
         mm = lambda lhs, w: matmul(lhs, w.astype(dt), seg, gs)  # noqa: E731
         if len(ws) == 3:
             mid = jax.nn.silu(mm(xs, ws[0])) * mm(xs, ws[1])
@@ -990,9 +1016,12 @@ def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt):
                 ys, cls, rank, gates, tight, gs, starts, n
             ), held, cum[-1] - cumc[-1]
         # each token gathers its held experts' rows, weighted
-        y = jnp.zeros((n, d), jnp.float32).at[token].add(
-            ys.astype(jnp.float32) * gate_row[:, None]
-        )
+        if by_list:
+            y = moe_rows.combine_rows(ys, gate_row, listed, lists, tm, matmul.interpret)
+        else:
+            y = jnp.zeros((n, d), jnp.float32).at[token].add(
+                ys.astype(jnp.float32) * gate_row[:, None]
+            )
     return y, held, cum[-1] - cumc[-1]
 
 

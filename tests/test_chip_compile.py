@@ -243,6 +243,17 @@ def _full_piece(q, k, v, offset, hi):
     return piece_attention(q, k, v, offset, 0, hi, name="full_piece_attention")
 
 
+def _moe_rows(x, gate, held, row, idx):
+    """``qwen3_next_80b.train``'s held rows moved by list: the buffer filled
+    from the tokens (``idx``: a row's token), then summed back into them
+    (``held``, ``row``: a pair's row, ten pairs a token)."""
+    from orion_tpu.ops.pallas import moe_rows
+
+    lists = moe_rows.combine_lists(held, row, jnp.ones(held.shape), x.shape[0])
+    xs = moe_rows.gather_rows(x, idx, lists, 128)
+    return moe_rows.combine_rows(xs, gate, idx, lists, 128)
+
+
 def _latent_flash(q, k, v):
     from orion_tpu.ops.pallas.flash_attention import flash_attention_lse
 
@@ -270,6 +281,10 @@ _QKV = [(BHTD, jnp.bfloat16)] * 3
 _QKV_GQA = [((8, 16, 8192, 256), jnp.bfloat16)] * 3
 _GMM_HELD = [((131072, 2048), jnp.bfloat16), ((64, 2048, 512), jnp.bfloat16),
              ((64,), jnp.int32)]
+# its 65,536 tokens' rows into the 131,072-row buffer and back: x2, a gate a
+# row, the held pairs of 655,360, the row a pair has and the token a row holds
+_MOE_ROWS = [((65536, 2048), jnp.bfloat16), ((131072,), jnp.float32),
+             ((655360,), jnp.bool_), ((655360,), jnp.int32), ((131072,), jnp.int32)]
 _DELTA = [*[((2, 16, 8192, 128), jnp.bfloat16)] * 2,
           ((2, 32, 8192, 128), jnp.bfloat16),
           *[((2, 32, 8192), jnp.float32)] * 2]
@@ -442,6 +457,11 @@ KERNELS = [
     pytest.param(_ring_write, _RING_WRITE, id="ring_row_write-64slots-ring2048"),
     pytest.param(_window_piece, _WINDOW_PIECE, id="window_piece_attention-piece1024-ring2048"),
     pytest.param(_full_piece, _FULL_PIECE, id="full_piece_attention-piece1024-17408keys"),
+    pytest.param(_moe_rows, _MOE_ROWS, id="moe_rows-65536tokens-131072rows-fwd"),
+    pytest.param(
+        jax.grad(lambda *a: _f32sum(_moe_rows(*a)), argnums=(0, 1)),
+        _MOE_ROWS, id="moe_rows-65536tokens-131072rows-bwd",
+    ),
     # -- the rest of the main path's kernels ---------------------------------
     pytest.param(_plain, _QKV, id="causal_dot-plain-fwd", marks=slow),
     pytest.param(_grad3(_plain), _QKV, id="causal_dot-plain-bwd", marks=slow),
@@ -470,6 +490,39 @@ def test_kernel_compiles_for_v5e(v5e, fn, shapes):
     compiled = _compile(v5e, fn, *shapes)
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
+def test_moe_rows_compile_inside_the_ep_region(v5e):
+    """An ep shard of ``_dropless_ep_gmm`` on a described dp2 x ep2: the row
+    kernels under the fully manual ``shard_map`` with ``check_vma`` on (which
+    interpret mode cannot run: the tokens vary over dp, the lists over dp and
+    ep, and ``d x`` has to come back varying over dp alone), forward and
+    backward, at ``qwen3_next_80b``'s row width."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from orion_tpu.models.configs import ModelConfig
+    from orion_tpu.models.moe import MoEMLP
+    from orion_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    mesh = make_mesh(MeshConfig(dp=2, ep=2).resolve(4), devices=v5e)
+    cfg = ModelConfig(
+        name="t", d_model=2048, n_experts=8, moe_top_k=2, moe_hidden=512, dtype="bfloat16",
+        moe_dropless=True, moe_ep_buffer=2.0, backend="pallas",
+    )
+    layer = MoEMLP(cfg, mesh=mesh)
+    put = lambda l, spec: jax.ShapeDtypeStruct(  # noqa: E731
+        l.shape, l.dtype, sharding=NamedSharding(mesh, spec))
+    params = jax.tree.map(
+        lambda l: put(l, P("ep", None, None) if l.ndim == 3 else P()),
+        jax.eval_shape(lambda: layer.init(jax.random.key(0), jnp.zeros((2, 16, 2048), jnp.bfloat16))),
+    )
+    x = put(jax.ShapeDtypeStruct((4, 2048, 2048), jnp.bfloat16), P("dp", None, None))
+
+    def loss(p, x):
+        return _f32sum(layer.apply(p, x, mutable=["losses", "moe_stats"])[0] ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile().as_text()
+    assert "moe_rows_gather" in text and "moe_rows_combine" in text
 
 
 def _delta_block_lines(v5e, fn):
@@ -639,15 +692,20 @@ def test_smoke_train_step_compiles_and_fits(v5e, layout, collective):
 def test_qwen3_next_train_step_compiles_and_fits(v5e):
     """benchmark/workloads/qwen3_next_80b.train.json's step — b8 x T8192,
     adafactor, bfloat16_sr, every block rematted — fits one chip with the
-    flash, grouped-matmul, delta-rule, short-conv and gate kernels in it
-    (the memory point known before a chip call, by the compiler's count at
-    PR 48: 2.07 GB of arguments + 11.53 GB of temporaries, of which the
-    donated state's 2.07 GB is counted twice, and 354.6 MB of generated
-    code; 12.62 GB and 365.6 MB on PR 48's parent, which held q, k and v
-    head-major and in fp32 beside the conv's output; 13.1 GB before the
-    gate's kernels, 13.4 GB with the conv as XLA fusions, whose backward
-    held fp32 pads, 14.3 GB with the delta rule in its XLA form too; the
-    chip's own peak while it runs is PERF.md s5's ``hbm_gb``)."""
+    flash, grouped-matmul, row-mover, delta-rule, short-conv and gate
+    kernels in it (the memory point known before a chip call, by the
+    compiler's count at PR 52: 2.07 GB of arguments + 11.25 GB of
+    temporaries, of which the donated state's 2.07 GB is counted twice, and
+    318.1 MB of generated code; 11.53 GB and 354.6 MB on PR 52's parent,
+    which moved the held experts' rows through XLA's gather and scatter-add
+    and held an fp32 copy of the buffer; 12.62 GB and 365.6 MB on PR 48's
+    parent, which held q, k and v head-major and in fp32 beside the conv's
+    output; 13.1 GB before the gate's kernels, 13.4 GB with the conv as XLA
+    fusions, whose backward held fp32 pads, 14.3 GB with the delta rule in
+    its XLA form too; the chip's own peak while it runs is PERF.md s5's
+    ``hbm_gb``). 119 Mosaic calls: the parent's 79 and 40 ``moe_rows_*``, a
+    layer's forward 4 (a pack and a gather, a pack and a combine), its
+    recompute 2 and its backward 4."""
     from orion_tpu.aot import plan
     from orion_tpu.models.configs import get_config
     from orion_tpu.parallel.mesh import MeshConfig, make_mesh
@@ -665,7 +723,7 @@ def test_qwen3_next_train_step_compiles_and_fits(v5e):
     mesh = make_mesh(mc.resolve(1), devices=v5e[:1])
     rep = plan(cfg, compile_step=True, mesh=mesh)
     assert rep["compiled"] and rep["n_params"] == 1028320320, rep
-    assert rep["collectives"]["mosaic_kernels"] > 0, rep["collectives"]
+    assert rep["collectives"]["mosaic_kernels"] == 79 + 4 * 10, rep["collectives"]
 
 
 def test_olmo_hybrid_boundary_programs_hold_the_carry_once(v5e):

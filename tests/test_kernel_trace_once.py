@@ -288,6 +288,26 @@ def _piece_attention(n):
         q, k, v, 32, 5, 44, window=32, interpret=True)), (q, k, v)
 
 
+def _moe_rows(n):
+    from orion_tpu.ops.pallas import moe_rows as m
+
+    tokens, k, rows = 8 * n, 2, 12 * n
+    j = np.arange(rows)
+    at_row = np.where(j % 3 == 2, -1, j * 5 % (tokens * k))  # the pair a row holds
+    idx = jnp.asarray(np.where(at_row >= 0, at_row // k, -1), jnp.int32)
+    held, row = np.zeros(tokens * k, bool), np.zeros(tokens * k, np.int32)
+    held[at_row[at_row >= 0]], row[at_row[at_row >= 0]] = True, j[at_row >= 0]
+    gates = _sigmoid(1, (rows,))
+
+    def fn(x, gate):
+        pairs = jnp.where(held, gate[row], 0.0)  # the gates from the pairs' side
+        lists = m.combine_lists(jnp.asarray(held), jnp.asarray(row), pairs, tokens)
+        ys = jnp.tanh(m.gather_rows(x, idx, lists, interpret=True))
+        return m.combine_rows(ys, gate, idx, lists, interpret=True)
+
+    return fn, (_normal(0, (tokens, 16)), gates)
+
+
 # (module, entry) -> the driver that reaches it
 ENTRY_DRIVER = {
     ("gmm", "_gmm_call"): _gmm,
@@ -322,6 +342,9 @@ ENTRY_DRIVER = {
     ("indexed_attention", "index_scores"): _index_scores,
     ("indexed_attention", "masked_attention"): _masked_attention,
     ("piece_attention", "piece_attention"): _piece_attention,
+    ("moe_rows", "moe_rows_gather"): _moe_rows,
+    ("moe_rows", "moe_rows_combine"): _moe_rows,
+    ("moe_rows", "pack_rows"): _moe_rows,
 }
 
 
@@ -329,7 +352,8 @@ ENTRY_DRIVER = {
 CALLS_HELD = {"flash_attn_bwd": ["flash_attn_dq", "flash_attn_dkv"]}
 
 # the drivers whose function has a custom_vjp: their gradient is taken too
-DIFFERENTIABLE = {_gmm, _causal_dot, _fused, _flash, _delta, _delta_qkv, _gated_norm, _short_conv}
+DIFFERENTIABLE = {_gmm, _causal_dot, _fused, _flash, _delta, _delta_qkv, _gated_norm, _short_conv,
+                  _moe_rows}
 
 
 def _loss(fn):
@@ -531,3 +555,56 @@ def test_a_four_layer_delta_rule_step_traces_each_body_once():
         by_shape.setdefault(key, set()).add(id(e.params["jaxpr"]))
     assert all(len(ids) == 1 for ids in by_shape.values()), by_shape
     np.testing.assert_array_equal(trace()[1], [0, sites])  # another Trainer's step: none
+
+
+@pytest.mark.parametrize("layers", [1, 4])
+def test_an_expert_stack_traces_the_row_movers_once_a_shape(layers):
+    """Held-expert layers under ``jax.checkpoint`` and ``jax.grad`` (the tiled
+    Mosaic product's training form, 2,048 pairs a layer): the forward, the
+    recompute and the backward of every layer call ``moe_rows_gather``,
+    ``moe_rows_combine`` and ``moe_rows_pack`` (a source laid out a row a
+    tile: the tokens' side and the buffer's), and their bodies are traced once
+    a distinct (entry, shapes and dtypes), four in all whatever the number of
+    layers: the backward meets the forward's shapes. This is what a row mover
+    may cost a program's set-up (PERF.md section 6, PR 52)."""
+    from orion_tpu.analysis.jaxpr_audit import iter_eqns
+    from orion_tpu.models.configs import ModelConfig
+    from orion_tpu.models.moe import MoEMLP
+
+    cfg = ModelConfig(
+        name="t", d_model=32, n_experts=2, moe_router_width=8, moe_top_k=2, moe_hidden=16,
+        moe_dropless=True, moe_ep_buffer=4.0, dtype="float32", backend="pallas_interpret",
+    )
+    assert cfg.moe_held
+    layer = MoEMLP(cfg)
+    x = _normal(0, (2, 512, 32))
+    params = [layer.init(jax.random.key(i), x[:, :16])["params"] for i in range(layers)]
+
+    @jax.checkpoint
+    def block(p, x):
+        return x + layer.apply({"params": p}, x, mutable=["losses", "moe_stats"])[0]
+
+    def loss(params, x):
+        for p in params:
+            x = block(p, x)
+        return jnp.sum(x * x)
+
+    jax.clear_caches()
+    start = _counts()
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params, x)
+    bodies, sites = _counts() - start
+    calls = [e for e in iter_eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"
+             and e.params["name"].startswith("moe_rows_")]
+    assert {e.params["name"] for e in calls} == {
+        "moe_rows_gather", "moe_rows_combine", "moe_rows_pack"}
+    assert len(calls) >= 8 * layers  # a pack a mover: forward 2, recompute 1 or 2, backward 1 or 2
+    by_shape = {}
+    for e in calls:
+        key = (e.params["name"], tuple(str(v.aval) for v in e.invars))
+        by_shape.setdefault(key, set()).add(id(e.params["jaxpr"]))
+    assert all(len(ids) == 1 for ids in by_shape.values()), by_shape
+    assert len(by_shape) == 4, sorted(by_shape)
+    traced = [e["args"]["fun_name"] for e in _kernel_events()]
+    rows = [n for n in traced[len(traced) - bodies:] if n.startswith("moe_rows_")]
+    assert sorted(rows) == ["moe_rows_combine", "moe_rows_gather"] + ["moe_rows_pack"] * 2
+    assert sites >= 4 * bodies or layers == 1, (bodies, sites)

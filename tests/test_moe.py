@@ -1222,3 +1222,154 @@ class TestDroplessDenseMeshGmm:
         np.testing.assert_allclose(
             np.asarray(y_tp), np.asarray(y_ref), atol=2e-5, rtol=2e-5
         )
+
+
+def _held_case(n=48, k=3, e=8, el=4, lo=2, d=32, h=24, seed=0, skip=None, dtype=jnp.float32):
+    """Inputs of ``_held_rows_ffn``: every token's ``k`` distinct experts of
+    ``e`` (``skip``: an expert no token picks), of which ``[lo, lo + el)`` are
+    held; token 0 picks held experts only and token 1 none."""
+    rng = np.random.default_rng(seed)
+    pool = np.array([x for x in range(e) if x != skip])
+    held = [x for x in range(lo, lo + el) if x != skip]
+    away = [x for x in pool if not lo <= x < lo + el]
+    ids = np.stack([rng.choice(pool, k, replace=False) for _ in range(n)])
+    ids[0], ids[1] = held[:k], away[:k]
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x2 = jax.random.normal(keys[0], (n, d)).astype(dtype)
+    gates = jax.random.uniform(keys[1], (n * k,), minval=0.1)
+    ws = tuple(
+        0.3 * jax.random.normal(key, shape)
+        for key, shape in zip(keys[2:], ((el, d, h), (el, d, h), (el, h, d)))
+    )
+    return x2, jnp.asarray(ids.reshape(-1), jnp.int32), gates, ws, lo
+
+
+# name -> (the case's keywords, the budget in rows)
+HELD_CASES = {
+    "several_pairs_a_token_and_none": (dict(), 144),
+    "rows_past_the_budget": (dict(seed=1), 24),
+    "an_expert_with_no_rows": (dict(seed=2, skip=3), 144),
+    "one_pair_a_token": (dict(seed=3, k=1, n=64), 64),
+    "wide_rows": (dict(seed=4, d=256, h=16, n=16), 48),
+    "bfloat16_rows": (dict(seed=5, d=256, h=16, n=16, dtype=jnp.bfloat16), 48),
+    # 1,088 pairs: the lists pass one 1,024-entry tile and are padded to whole ones
+    "lists_longer_than_a_tile": (dict(seed=6, n=136, k=8, e=16, el=8, lo=0, d=16, h=8), 1088),
+}
+
+
+class TestHeldRowsByList:
+    """``_held_rows_ffn``'s training form with the tiled Mosaic product moves
+    its rows by list in the ``moe_rows_*`` kernels (ops/pallas/moe_rows.py,
+    interpret mode here) and is held to the plain form: ``ragged_dot``,
+    ``jnp.take`` and ``.at[].add``."""
+
+    @staticmethod
+    def _forms(dtype=jnp.float32):
+        from orion_tpu.models.moe import _gmm_matmul, _held_rows_ffn, _ragged_matmul
+
+        def form(matmul, budget):
+            return lambda x2, flat, gates, ws, lo: _held_rows_ffn(
+                x2, flat, gates, ws, lo, budget, matmul, dtype
+            )
+
+        return form, _gmm_matmul(8, 128, True), _ragged_matmul
+
+    @pytest.mark.parametrize("name", list(HELD_CASES))
+    def test_values_and_counts_match_the_plain_form(self, name):
+        kw, budget = HELD_CASES[name]
+        x2, flat, gates, ws, lo = _held_case(**kw)
+        form, tiled, plain = self._forms(kw.get("dtype", jnp.float32))
+        y, held, dropped = jax.jit(form(tiled, budget), static_argnums=4)(x2, flat, gates, ws, lo)
+        y0, held0, dropped0 = jax.jit(form(plain, budget), static_argnums=4)(x2, flat, gates, ws, lo)
+        assert y.dtype == jnp.float32 and y.shape == x2.shape
+        tol = 2e-2 if x2.dtype == jnp.bfloat16 else 2e-5
+        np.testing.assert_allclose(np.asarray(y), np.asarray(y0), atol=tol, rtol=tol)
+        np.testing.assert_array_equal(np.asarray(held), np.asarray(held0))
+        assert int(dropped) == int(dropped0)
+        assert (int(dropped) > 0) == (name == "rows_past_the_budget")
+        assert float(jnp.abs(y0[0]).max()) > 0  # token 0: held pairs only
+        if kw.get("k", 3) > 1:
+            assert float(jnp.abs(y[1]).max()) == 0.0  # token 1: none held here
+
+    @pytest.mark.parametrize("name", list(HELD_CASES))
+    def test_gradients_match_the_plain_form(self, name):
+        kw, budget = HELD_CASES[name]
+        x2, flat, gates, ws, lo = _held_case(**kw)
+        form, tiled, plain = self._forms(kw.get("dtype", jnp.float32))
+        mix = jax.random.normal(jax.random.PRNGKey(9), x2.shape)
+
+        def grads(matmul):
+            loss = lambda x2, gates, ws: jnp.sum(  # noqa: E731
+                form(matmul, budget)(x2, flat, gates, ws, lo)[0] * mix
+            )
+            return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(x2, gates, ws)
+
+        got, want = grads(tiled), grads(plain)
+        tol = 5e-2 if x2.dtype == jnp.bfloat16 else 3e-5
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            b = np.asarray(b, np.float32)  # sums of up to 256 products: to their scale
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), b, atol=tol * max(1.0, np.abs(b).max()), rtol=tol
+            )
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_gather_leaves_unlisted_rows_zero_and_combine_skips_them(self, dtype):
+        """Tile padding and the spare buffer: ``idx < 0`` rows of the buffer
+        are zeros (no mask outside the kernel), and the combine adds nothing
+        for them whatever they hold."""
+        from orion_tpu.ops.pallas import moe_rows
+
+        n, k, d, r = 16, 2, 256, 24
+        x = jax.random.normal(jax.random.PRNGKey(0), (n, d)).astype(dtype)
+        at_row = np.asarray([5, 4, -1, -1, 31, 0, 9, -1] + [-1] * 16)  # the pair a row holds
+        idx = jnp.asarray(np.where(at_row >= 0, at_row // k, -1), jnp.int32)
+        held, row = np.zeros(n * k, bool), np.zeros(n * k, np.int32)
+        held[at_row[at_row >= 0]], row[at_row[at_row >= 0]] = True, np.nonzero(at_row >= 0)[0]
+        lists = moe_rows.combine_lists(jnp.asarray(held), jnp.asarray(row), jnp.ones((n * k,)), n)
+        xs = moe_rows.gather_rows(x, idx, lists, interpret=True)
+        want = jnp.where((idx >= 0)[:, None], x[jnp.clip(idx, 0)], 0)
+        np.testing.assert_array_equal(np.asarray(xs, np.float32), np.asarray(want, np.float32))
+        junk = jnp.where((idx >= 0)[:, None], xs, 7).astype(dtype)  # unlisted rows hold junk
+        y = moe_rows.combine_rows(junk, jnp.ones((r,)), idx, lists, interpret=True)
+        back = jnp.zeros((n, d), jnp.float32).at[jnp.clip(idx, 0)].add(want.astype(jnp.float32))
+        np.testing.assert_allclose(np.asarray(y), np.asarray(back), atol=1e-6)
+
+    @pytest.mark.parametrize("form", ["training", "serving", "plain"])
+    def test_only_the_training_form_holds_the_row_kernels(self, form):
+        """The serving form (``live_tiles``: the grouped product leaves a tail
+        unwritten and the combine is a gather already) and the plain form are
+        the parent's programs: no ``moe_rows_*`` call in their jaxprs."""
+        from orion_tpu.models.moe import _gmm_matmul, _held_rows_ffn, _ragged_matmul
+
+        matmul = {"training": _gmm_matmul(8, 128, True),
+                  "serving": _gmm_matmul(8, 128, True, live_tiles=True),
+                  "plain": _ragged_matmul}[form]
+        x2, flat, gates, ws, lo = _held_case()
+        text = str(jax.make_jaxpr(
+            lambda x2, gates, ws: _held_rows_ffn(x2, flat, gates, ws, lo, 144, matmul, jnp.float32)
+        )(x2, gates, ws))
+        for name in ("moe_rows_gather", "moe_rows_combine"):
+            assert (name in text) == (form == "training"), (form, name)
+        assert ("scatter-add" in text) == (form == "plain")
+
+    def test_ep_shard_gradients_hold_the_kernels_under_shard_map(self):
+        """An ep shard of ``_dropless_ep_gmm``: the row kernels inside the
+        fully manual region, gradients against the single-host layer."""
+        from orion_tpu.parallel.mesh import make_mesh
+
+        kw = dict(name="t", d_model=32, n_experts=4, dtype="float32",
+                  moe_dropless=True, moe_ep_buffer=2.0, moe_top_k=2)
+        mesh = make_mesh(MeshConfig(dp=2, ep=2))
+        ref = MoEMLP(ModelConfig(backend="xla", **kw))
+        ep = MoEMLP(ModelConfig(backend="pallas_interpret", **kw), mesh=mesh)
+        x = jax.random.normal(jax.random.PRNGKey(5), (4, 256, 32))
+        p = ref.init(jax.random.PRNGKey(1), jnp.zeros((2, 16, 32)))
+        loss = lambda m: lambda p, x: (  # noqa: E731
+            m.apply(p, x, mutable=["losses", "moe_stats"])[0] ** 2).mean()
+        text = str(jax.make_jaxpr(jax.grad(loss(ep)))(p, x))
+        assert "moe_rows_gather" in text and "moe_rows_combine" in text
+        want = jax.jit(jax.grad(loss(ref), argnums=(0, 1)))(p, x)
+        got = jax.jit(jax.grad(loss(ep), argnums=(0, 1)))(p, x)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5, rtol=3e-5)
