@@ -58,11 +58,22 @@ Event model (Chrome trace-event format, ``ts``/``dur`` in microseconds):
   ``Server`` that is serving hears them
   from here (:func:`on_compile`) and writes them into its ring with the
   boundary they fell in.
+- **step spans** (``ph`` ``X``, ``cat`` ``step``) — what the training
+  loop does in one iteration of ``Trainer.train``, through the same
+  :meth:`Tracer.span`: ``train.step`` (``step``, ``tokens``) from the top
+  of one iteration to the top of the next, so consecutive ones tile the
+  loop, and inside it, none overlapping and each with its ``step``,
+  ``train.next_batch`` (``ready``: the batches the ``DataLoader`` held
+  when asked), ``train.dispatch``, ``train.log_readback``,
+  ``train.eval``, ``train.checkpoint`` and ``train.hook`` (the caller's
+  time); ``host.gc`` (``generation``, ``collected``) for each collection
+  of the interpreter that took 1 ms or more while the loop ran.
 
-Both of the last two categories are few (hundreds a process) and must
-outlive the ring's turnover, so they are ALSO kept in one bounded
-process-wide list, :func:`setup_record`, whatever ``Tracer`` wrote them
-and whether or not it is enabled.
+The last three categories must outlive the ring's turnover, so they are
+ALSO kept in bounded process-wide lists, whatever ``Tracer`` wrote them
+and whether or not it is enabled: :func:`setup_record` (``setup`` and
+``compile``: hundreds a process) and :func:`step_record` (``step``: the
+newest ~3,000 steps), all on ``time.monotonic``.
 
 Wire format: one JSON object per line (JSONL), appended live — files
 from several processes (fleet parent + children) concatenate trivially.
@@ -80,6 +91,8 @@ walk.
 
 from __future__ import annotations
 
+import contextlib
+import gc
 import json
 import os
 import threading
@@ -95,9 +108,10 @@ _EVENT_FIELDS = ("name", "cat", "ph", "ts", "id", "args")
 # a bounded list of its own: a process that goes on compiling (a prompt
 # length nobody warmed up, every hour) turns its compile events over and
 # keeps how it came up
-RECORD_CATS = ("setup", "compile")
+RECORD_CATS = ("setup", "compile", "step")
 _RECORD: Dict[str, deque] = {
     "setup": deque(maxlen=1 << 12), "compile": deque(maxlen=1 << 13),
+    "step": deque(maxlen=1 << 14),
 }
 # what the compile events add up to over the process's life: the
 # counters a registry shows (``Server.metrics``, ``MetricsLogger.registry``)
@@ -114,6 +128,9 @@ _COMPILE_SINKS: List[weakref.WeakMethod] = []
 # whether the process's first ``setup.import`` has been written
 _imports_open: List[float] = []
 _import_written = False
+# per thread: the innermost :class:`Span` that is open (``.span``), which
+# holds the one round it (``Span._outer``)
+_thread = threading.local()
 
 
 class Span:
@@ -125,7 +142,7 @@ class Span:
     the serve loop learns only later whether an iteration was a boundary."""
 
     __slots__ = ("_tracer", "name", "cat", "args", "record", "start", "dur",
-                 "_ann", "_open")
+                 "_ann", "_open", "_outer")
 
     def __init__(self, tracer, name, cat, record, args):
         self._tracer = tracer
@@ -134,7 +151,7 @@ class Span:
         self.args = args
         self.record = record
         self.start = self.dur = 0.0
-        self._ann = None
+        self._ann = self._outer = None
         self._open = False
 
     def __enter__(self):
@@ -143,12 +160,15 @@ class Span:
             self._ann = annotate(self.name)
             self._ann.__enter__()
         self._open = True
+        self._outer = getattr(_thread, "span", None)
+        _thread.span = self
         self.start = self._tracer._clock()
         return self
 
     def __exit__(self, *exc):
         self.dur = self._tracer._clock() - self.start
         self._open = False
+        _thread.span = self._outer
         if self._ann is not None:
             self._ann.__exit__(*exc)
         if self.record:
@@ -171,6 +191,18 @@ class Span:
             return
         self._tracer.complete(self.name, self.start, self.dur, cat=self.cat,
                               **self.args)
+
+
+def note_open(name: str, **args) -> None:
+    """Arguments for the span ``name`` if one is open on the calling
+    thread, from code below it that was handed no span (the
+    ``DataLoader``, asked for a batch inside the loop's
+    ``train.next_batch`` through whatever iterator wraps it)."""
+    span = getattr(_thread, "span", None)
+    while span is not None and span.name != name:
+        span = span._outer
+    if span is not None:
+        span.args.update(args)
 
 
 class _NullSpan:
@@ -274,16 +306,42 @@ class Tracer:
 
     def keep(self, name: str, cat: str, ph: str, start_s, dur_s=None,
              **args) -> None:
-        """A set-up or compile event: into the process-wide record
-        (:func:`setup_record`) whether or not this tracer is enabled, and
-        into the ring when it is. Off the hot path: it runs at set-up and
-        when jax builds a program, never in the steady state."""
+        """An event of a kept category (:data:`RECORD_CATS`): into the
+        process-wide record (:func:`setup_record`, :func:`step_record`)
+        whether or not this tracer is enabled, and into the ring when it
+        is. A tuple and a dict each: set-up and compile events are off the
+        hot path, and a training step writes a handful."""
         row = (name, cat, ph, start_s * 1e6,
                None if dur_s is None else dur_s * 1e6, None, args or None,
                threading.get_ident() & 0xFFFF)
         _RECORD[cat].append(self._to_dict(row))
         if self.enabled:
             self._buf.append(row)
+
+    @contextlib.contextmanager
+    def gc_events(self, min_s: float = 1e-3):
+        """While the ``with`` runs, each collection of the interpreter
+        that took ``min_s`` or longer, on whatever thread set it off, is
+        a ``host.gc`` complete event of category ``step`` (args
+        ``generation``, ``collected``); ``gc.callbacks`` is as found
+        afterwards."""
+        began = 0.0
+
+        def on_gc(phase, info):
+            nonlocal began
+            now = self._clock()
+            if phase == "start":
+                began = now
+            elif now - began >= min_s:
+                self.keep("host.gc", "step", "X", began, now - began,
+                          generation=info["generation"],
+                          collected=info["collected"])
+
+        gc.callbacks.append(on_gc)
+        try:
+            yield
+        finally:
+            gc.callbacks.remove(on_gc)
 
     # -- draining -------------------------------------------------------------
 
@@ -359,21 +417,34 @@ class Tracer:
 PROCESS_TRACER = Tracer(path=None, clock=time.monotonic, enabled=False)
 
 
-def setup_record() -> List[dict]:
-    """The ``setup`` and ``compile`` events of this process so far (the
-    newest 4,096 and 8,192), as Chrome-format dicts in the order they
-    ended (a complete event is written at its END, so a parent follows
-    its children)."""
+def _kept(*cats: str) -> List[dict]:
     events: List[dict] = []
-    for kept in _RECORD.values():
+    for cat in cats:
         for _ in range(8):
             try:
-                events += list(kept)
+                events += list(_RECORD[cat])
                 break
             except RuntimeError:
                 continue  # an append on another thread landed mid-copy
     events.sort(key=lambda e: e["ts"] + e.get("dur", 0.0))
     return events
+
+
+def setup_record() -> List[dict]:
+    """The ``setup`` and ``compile`` events of this process so far (the
+    newest 4,096 and 8,192), as Chrome-format dicts in the order they
+    ended (a complete event is written at its END, so a parent follows
+    its children)."""
+    return _kept("setup", "compile")
+
+
+def step_record() -> List[dict]:
+    """The ``step`` events of this process so far (the newest 16,384:
+    some 3,000 steps of ``Trainer.train``), in the same form and order.
+    The ``compile`` events of a program built inside the loop are in
+    :func:`setup_record`, on the same clock: a reader places them in a
+    step by their timestamps."""
+    return _kept("step")
 
 
 def import_begin() -> None:
@@ -544,7 +615,8 @@ if __name__ == "__main__":
 
 __all__ = [
     "Tracer", "Span", "NULL_SPAN", "read_jsonl", "merge_traces", "span_pairs",
-    "PROCESS_TRACER", "setup_record", "setup_summary", "compile_event",
+    "PROCESS_TRACER", "setup_record", "step_record", "note_open",
+    "setup_summary", "compile_event",
     "compile_counts", "compile_totals", "kernel_call_site", "on_compile", "import_begin",
     "import_done",
     "COMPILE_COUNTERS",

@@ -19,10 +19,11 @@ import argparse
 import contextlib
 import dataclasses
 import sys
+import time
 from typing import Optional, Tuple
 
 from orion_tpu.models.configs import get_config
-from orion_tpu.obs.trace import PROCESS_TRACER
+from orion_tpu.obs.trace import PROCESS_TRACER, Tracer
 from orion_tpu.parallel.mesh import MeshConfig, initialize_distributed
 from orion_tpu.resilience.preempt import PreemptionGuard
 from orion_tpu.resilience.watchdog import Watchdog
@@ -39,9 +40,12 @@ def train(
     log_path: Optional[str] = None,
     resume: bool = True,
     metrics_path: Optional[str] = None,
+    trace_path: Optional[str] = None,
 ) -> Tuple[object, dict]:
     """Build everything, optionally resume, run to cfg.steps. Returns
-    (final TrainState, last metrics dict)."""
+    (final TrainState, last metrics dict). ``trace_path``: the set-up and
+    ``step`` spans of this run (obs/trace.py) as JSONL, appended at the log
+    cadence and at exit; the process-wide record holds them either way."""
     # config errors before the expensive part: Trainer materializes multi-GB
     # state and the loader spawns its prefetch thread
     if eval_data and not cfg.eval_every:
@@ -52,8 +56,12 @@ def train(
         )
     ckpt = None
     start = 0
-    with PROCESS_TRACER.span("setup.weights", "setup") as weights:
-        trainer = Trainer(cfg)
+    tracer = (
+        Tracer(path=trace_path, clock=time.monotonic) if trace_path
+        else PROCESS_TRACER
+    )
+    with tracer.span("setup.weights", "setup") as weights:
+        trainer = Trainer(cfg, tracer=tracer)
         if cfg.ckpt_dir:
             ckpt = Checkpointer(
                 cfg.ckpt_dir, max_to_keep=cfg.ckpt_keep,
@@ -173,6 +181,7 @@ def train(
         if watchdog is not None:
             watchdog.close()
         loader.close()
+        tracer.close()
         if metrics_path:
             # final scrape on every exit path (same contract as the
             # serving CLI's on-drain dump): Prometheus text + .json
@@ -208,6 +217,13 @@ def build_argparser() -> argparse.ArgumentParser:
                    help="Prometheus-text metrics exposition file "
                         "(+ .json sibling), written on exit — the same "
                         "registry format the serving/fleet CLIs expose")
+    p.add_argument("--trace-path", default=None,
+                   help="step-trace JSONL (Chrome trace events): one "
+                        "train.step span per iteration of the loop with "
+                        "its phases inside (next_batch, dispatch, "
+                        "log_readback, eval, checkpoint, hook) and the "
+                        "interpreter's collections; merge with `python -m "
+                        "orion_tpu.obs.trace merge` and load in Perfetto")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument(
         "--preempt-grace", type=float, default=10.0,
@@ -275,8 +291,13 @@ def main(argv=None) -> int:
     _, last = train(
         cfg, data=args.data, eval_data=args.eval_data,
         log_path=args.log_path, metrics_path=args.metrics_path,
+        trace_path=args.trace_path,
     )
     print({k: round(v, 5) for k, v in last.items()})
+    if args.trace_path:
+        print(f"trace: {args.trace_path} — merge for Perfetto with "
+              f"`python -m orion_tpu.obs.trace merge {args.trace_path} "
+              f"-o trace.json`", file=sys.stderr)
     return 0
 
 

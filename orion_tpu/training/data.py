@@ -25,6 +25,7 @@ from typing import Iterator, Optional
 import jax
 import numpy as np
 
+from orion_tpu.obs.trace import note_open
 from orion_tpu.resilience.inject import fire
 from orion_tpu.resilience.retry import RetryPolicy, call_with_retries
 from orion_tpu.resilience.watchdog import StallError
@@ -278,6 +279,9 @@ class DataLoader:
         return self
 
     def __next__(self) -> Array:
+        # how many batches stood ready when the loop asked: 0 is a loop
+        # that waits for the prefetch thread (obs/trace.py step spans)
+        note_open("train.next_batch", ready=self._q.qsize())
         deadline = (
             time.monotonic() + self.stall_timeout
             if self.stall_timeout

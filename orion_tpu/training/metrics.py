@@ -4,11 +4,12 @@ Since ISSUE 9 the logger is a thin view over the shared
 :class:`~orion_tpu.obs.metrics.MetricsRegistry` (the same registry kind
 the serving and fleet layers expose): every scalar the trainer hands
 over lands as a ``train_<name>`` gauge, steps count into
-``train_steps_total``, and step wall time feeds a ``step_time_ms``
-histogram — so one Prometheus scrape covers a box that both trains and
-serves. The legacy behaviour (one JSON line per log point + a
-human-readable stdout line with tokens/sec) is unchanged; callers that
-never pass a registry get a private one for free.
+``train_steps_total``, and every step's period (the loop's own
+``train.step`` span, a host float) feeds a ``step_time_ms`` histogram —
+so one Prometheus scrape covers a box that both trains and serves. The
+legacy behaviour (one JSON line per log point + a human-readable stdout
+line with tokens/sec) is unchanged; callers that never pass a registry
+get a private one for free.
 
 The registry only ever sees HOST floats: the trainer already
 materializes metrics at log cadence precisely so device scalars aren't
@@ -46,7 +47,6 @@ class MetricsLogger:
             dt = now - self._last_time
             rec["tokens_per_sec"] = tokens_per_step * (step - self._last_step) / dt
             rec["step_time_ms"] = 1000.0 * dt / (step - self._last_step)
-            self._h_step_ms.observe(rec["step_time_ms"])
         if self._last_step is not None and step > self._last_step:
             self._c_steps.inc(step - self._last_step)
         self._last_time, self._last_step = now, step
@@ -64,6 +64,12 @@ class MetricsLogger:
                 v = rec[k]
                 parts.append(f"{k} {v:.4g}")
         print("  ".join(parts), file=self._stream, flush=True)
+
+    def observe_step(self, period_ms: float) -> None:
+        """One step's period into the ``step_time_ms`` histogram: the log
+        line's ``step_time_ms`` is a mean over the log cadence, which hides
+        exactly one long step."""
+        self._h_step_ms.observe(period_ms)
 
     def dump(self, path: str) -> None:
         """Prometheus-text + JSON exposition of the training registry

@@ -20,6 +20,7 @@ raises at the next log point if the counter advanced.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from functools import partial
@@ -39,7 +40,7 @@ from orion_tpu.parallel.sharding import batch_sharding, param_shardings
 from orion_tpu.resilience import inject as _inject
 from orion_tpu.utils import rng as rngs
 from orion_tpu.obs.trace import NULL_SPAN, PROCESS_TRACER, compile_totals
-from orion_tpu.utils.profiling import annotate, annotated_steps
+from orion_tpu.utils.profiling import annotate
 
 Array = jax.Array
 
@@ -815,114 +816,159 @@ class Trainer:
         tokens_per_step = cfg.batch_size * cfg.seq_len
         last: Dict[str, float] = {}
         start_step = int(self.state.step)
-        # host spans for a profiler capture (utils/profiling.py): what the
-        # loop itself does between two dispatches of the step program
-        for step in annotated_steps("train", range(start_step + 1, cfg.steps + 1)):
-            if watchdog is not None:
-                watchdog.beat(f"train step {step}")
-            with annotate("train.next_batch"), self._once("setup.loader"):
-                batch = next(data_iter)
-            # the first call traces, lowers and compiles or loads the
-            # step: host time, no wait for the device
-            with self._once("setup.first_step") as first:
-                metrics = self.step(batch)
-            if first is not NULL_SPAN:
-                self._first_loss = metrics["loss"]
-            # only materialize metrics on the host at log cadence — reading a
-            # device scalar every step would serialize the pipeline
-            if step % cfg.log_every == 0 or step == cfg.steps:
-                # cumulative device-side counter: catches non-finite steps
-                # that happened *between* log points too
-                with annotate("train.log_readback"):  # waits for the step
-                    nf_total = int(metrics["nonfinite_total"])
-                if nf_total > self.nonfinite_steps:
-                    # black-box the non-finite step window (the flight
-                    # recorder is the training run's post-mortem ring,
-                    # same spine as serving's — obs/flight.py)
-                    _flight.record("train_nonfinite", step=step,
-                                   total=nf_total)
-                    self.nonfinite_steps = nf_total
-                    if cfg.nan_policy == "halt":
-                        _flight.recorder().dump("train-nan-halt")
-                        # emergency checkpoint BEFORE halting: the offending
-                        # state must be post-mortem restorable (params are
-                        # the pre-skip values, counter included)
-                        if watchdog is not None:
-                            watchdog.disarm()  # don't escalate vs the save
-                        if ckpt is not None:
-                            ckpt.maybe_save(step, self.state, force=True)
-                            ckpt.wait()
-                        raise FloatingPointError(
-                            f"{nf_total} non-finite step(s) by step {step}"
-                            + (
-                                f"; emergency checkpoint saved at step {step}"
-                                if ckpt is not None else ""
+        # each phase of an iteration is ONE ``with self.trace.span``: the
+        # step record, the tracer's ring and a running capture's host lines
+        with contextlib.closing(self._steps(
+            range(start_step + 1, cfg.steps + 1), tokens_per_step, logger,
+        )) as steps:
+            for step in steps:
+                if watchdog is not None:
+                    watchdog.beat(f"train step {step}")
+                with self.trace.span("train.next_batch", "step", step=step), \
+                        self._once("setup.loader"):
+                    batch = next(data_iter)
+                # host time to enqueue the step, no wait for the device; the
+                # first call traces, lowers and compiles or loads it
+                with self.trace.span("train.dispatch", "step", step=step), \
+                        self._once("setup.first_step") as first:
+                    metrics = self.step(batch)
+                if first is not NULL_SPAN:
+                    self._first_loss = metrics["loss"]
+                # only materialize metrics on the host at log cadence — reading a
+                # device scalar every step would serialize the pipeline
+                if step % cfg.log_every == 0 or step == cfg.steps:
+                    # cumulative device-side counter: catches non-finite steps
+                    # that happened *between* log points too; reading it
+                    # waits for the step
+                    with self.trace.span("train.log_readback", "step", step=step):
+                        nf_total = int(metrics["nonfinite_total"])
+                    if nf_total > self.nonfinite_steps:
+                        # black-box the non-finite step window (the flight
+                        # recorder is the training run's post-mortem ring,
+                        # same spine as serving's — obs/flight.py)
+                        _flight.record("train_nonfinite", step=step,
+                                       total=nf_total)
+                        self.nonfinite_steps = nf_total
+                        if cfg.nan_policy == "halt":
+                            _flight.recorder().dump("train-nan-halt")
+                            # emergency checkpoint BEFORE halting: the offending
+                            # state must be post-mortem restorable (params are
+                            # the pre-skip values, counter included)
+                            if watchdog is not None:
+                                watchdog.disarm()  # don't escalate vs the save
+                            if ckpt is not None:
+                                self._save(ckpt, step, force=True)
+                            raise FloatingPointError(
+                                f"{nf_total} non-finite step(s) by step {step}"
+                                + (
+                                    f"; emergency checkpoint saved at step {step}"
+                                    if ckpt is not None else ""
+                                )
                             )
-                        )
-                last = {k: float(v) for k, v in metrics.items()}
-                last["ppl"] = float(jnp.exp(jnp.minimum(last["loss"], 20.0)))
-                if logger:
-                    self._show_compiles(logger.registry)
-                    logger.log(step, last, tokens_per_step)
-            if (
-                (eval_iter is not None or eval_factory is not None)
-                and cfg.eval_every
-                and (step % cfg.eval_every == 0 or step == cfg.steps)
-            ):
-                if watchdog is not None:
-                    # an eval pass (first one includes its jit compile) may
-                    # legitimately exceed one step's budget — suspend stall
-                    # detection across it rather than misread it as a hang;
-                    # a hung EVAL DATA read is still caught by the eval
-                    # loader's own stall_timeout (train.py)
-                    watchdog.disarm()
-                with annotate("train.eval"):
-                    ev = self.evaluate(
-                        eval_factory(step) if eval_factory is not None
-                        else eval_iter
-                    )
-                last.update(ev)
-                if logger:
-                    logger.log(step, ev)
-                if watchdog is not None:
-                    watchdog.arm(f"train step {step} (post-eval)")
-            if ckpt is not None:
-                with annotate("train.checkpoint"):
-                    ckpt.maybe_save(step, self.state)
-            if hook is not None:
-                hook(step, metrics)
-            if self._first_loss is not None and self._first_loss.is_ready():
-                # the loop's own log or hook has waited for the first
-                # step (asked, not waited for, here): set-up is over
-                self._first_loss = None
-                self.trace.instant("setup.ready", "setup", step=step)
-            # chaos harness: simulated preemption delivers a real signal
-            # here; the installed guard's handler runs synchronously and
-            # flips should_stop before the check below
-            _inject.fire("train.step_boundary", step=step)
-            if preempt is not None and preempt.should_stop:
-                # graceful stop at the step boundary (the only place the
-                # state is consistent): emergency checkpoint, then return
-                # resumable — maybe_save is idempotent per step, so a
-                # cadence save this same step isn't re-written
-                if watchdog is not None:
-                    # the save may take longer than one step budget; the
-                    # watchdog must not escalate against the very save its
-                    # stall action triggered
-                    watchdog.disarm()
-                if ckpt is not None:
-                    ckpt.maybe_save(step, self.state, force=True)
-                    ckpt.wait()
-                self.preempted_at = step
-                _flight.record("train_preempt", step=step,
-                               signum=getattr(preempt, "signum", None))
-                _flight.recorder().dump("train-preempt")
-                if not last:
                     last = {k: float(v) for k, v in metrics.items()}
-                break
+                    last["ppl"] = float(jnp.exp(jnp.minimum(last["loss"], 20.0)))
+                    if logger:
+                        self._show_compiles(logger.registry)
+                        logger.log(step, last, tokens_per_step)
+                    if self.trace.path:  # the CLI's --trace-path
+                        self.trace.flush()
+                if (
+                    (eval_iter is not None or eval_factory is not None)
+                    and cfg.eval_every
+                    and (step % cfg.eval_every == 0 or step == cfg.steps)
+                ):
+                    if watchdog is not None:
+                        # an eval pass (first one includes its jit compile) may
+                        # legitimately exceed one step's budget — suspend stall
+                        # detection across it rather than misread it as a hang;
+                        # a hung EVAL DATA read is still caught by the eval
+                        # loader's own stall_timeout (train.py)
+                        watchdog.disarm()
+                    with self.trace.span("train.eval", "step", step=step):
+                        ev = self.evaluate(
+                            eval_factory(step) if eval_factory is not None
+                            else eval_iter
+                        )
+                    last.update(ev)
+                    if logger:
+                        logger.log(step, ev)
+                    if watchdog is not None:
+                        watchdog.arm(f"train step {step} (post-eval)")
+                if ckpt is not None:
+                    self._save(ckpt, step)
+                if hook is not None:
+                    # the caller's time (the benchmark's hook waits here
+                    # for the step before)
+                    with self.trace.span("train.hook", "step", step=step):
+                        hook(step, metrics)
+                if self._first_loss is not None and self._first_loss.is_ready():
+                    # the loop's own log or hook has waited for the first
+                    # step (asked, not waited for, here): set-up is over
+                    self._first_loss = None
+                    self.trace.instant("setup.ready", "setup", step=step)
+                # chaos harness: simulated preemption delivers a real signal
+                # here; the installed guard's handler runs synchronously and
+                # flips should_stop before the check below
+                _inject.fire("train.step_boundary", step=step)
+                if preempt is not None and preempt.should_stop:
+                    # graceful stop at the step boundary (the only place the
+                    # state is consistent): emergency checkpoint, then return
+                    # resumable — maybe_save is idempotent per step, so a
+                    # cadence save this same step isn't re-written
+                    if watchdog is not None:
+                        # the save may take longer than one step budget; the
+                        # watchdog must not escalate against the very save its
+                        # stall action triggered
+                        watchdog.disarm()
+                    if ckpt is not None:
+                        self._save(ckpt, step, force=True)
+                    self.preempted_at = step
+                    _flight.record("train_preempt", step=step,
+                                   signum=getattr(preempt, "signum", None))
+                    _flight.recorder().dump("train-preempt")
+                    if not last:  # waits for this last step
+                        with self.trace.span("train.log_readback", "step", step=step):
+                            last = {k: float(v) for k, v in metrics.items()}
+                    break
         if not last and start_step < cfg.steps:
             last = {k: float(v) for k, v in metrics.items()}
         return last
+
+    def _steps(self, steps, tokens: int, logger):
+        """``for step in self._steps(...)``: the loop's ``train.step`` spans
+        (obs/trace.py, category ``step``). Each runs from the top of one
+        iteration to the top of the next, or to the loop's exit, and starts
+        where the one before ended, so that they tile the loop; its period
+        goes to the logger's ``step_time_ms`` histogram. For the length of
+        the loop the tracer holds the profiler's annotation factory (every
+        span is then also a host line of whatever capture runs) and hears
+        the interpreter's collections (``host.gc``); ``close()`` ends the
+        open span and leaves the tracer and ``gc.callbacks`` as found."""
+        trace = self.trace
+        found, trace.annotate = trace.annotate, annotate
+        end = None
+        try:
+            with trace.gc_events():
+                for step in steps:
+                    span = trace.span("train.step", "step", step=step, tokens=tokens)
+                    try:
+                        with span:
+                            if end is not None:
+                                span.start = end
+                            yield step
+                    finally:
+                        end = span.start + span.dur
+                        if logger is not None:
+                            logger.observe_step(1e3 * span.dur)
+        finally:
+            trace.annotate = found
+
+    def _save(self, ckpt, step: int, force: bool = False) -> None:
+        """A cadence save, or a forced one waited for, as ``train.checkpoint``."""
+        with self.trace.span("train.checkpoint", "step", step=step):
+            ckpt.maybe_save(step, self.state, force=force)
+            if force:
+                ckpt.wait()
 
     def evaluate(self, data_iter, n_batches: Optional[int] = None) -> Dict[str, float]:
         assert self.state is not None, (

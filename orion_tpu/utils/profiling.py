@@ -19,7 +19,10 @@ for a program it builds into ``compile.trace`` / ``compile.lower`` /
 A listener runs only when jax compiles: the steady state pays nothing.
 ``setup``: the spans themselves are written where the work is
 (``Server.__init__``, ``Trainer.__init__``, the CLIs); while a capture
-runs they are annotations too, through :func:`annotate`.
+runs they are annotations too, through :func:`annotate`: the factory a
+``Server`` hands its tracer for the length of ``arm_profile``'s capture
+and a ``Trainer`` for the length of ``train()``, whose ``step`` spans are
+so the host lines of any capture that runs meanwhile.
 """
 
 from __future__ import annotations
@@ -125,14 +128,4 @@ def scoped(name: str):
     return decorate
 
 
-def annotated_steps(name: str, steps):
-    """``for step in annotated_steps("train", range(a, b)):`` — each
-    iteration's body runs inside a ``StepTraceAnnotation`` (the profiler
-    groups device work by these); it closes when the loop asks for the next
-    step or leaves."""
-    for step in steps:
-        with jax.profiler.StepTraceAnnotation(name, step_num=step):
-            yield step
-
-
-__all__ = ["annotate", "annotated_steps", "listen_for_compiles", "scope", "scoped"]
+__all__ = ["annotate", "listen_for_compiles", "scope", "scoped"]
