@@ -16,14 +16,43 @@ Backward (custom VJP, two kernels — the standard flash decomposition):
     delta = rowsum(dO ⊙ O)                       (XLA, one fused reduce)
     dQ kernel (grid B·H × Tq/Bq × Tk/Bk):  P = exp(S − lse);
         dS = P ⊙ (dO Vᵀ − delta);  dQ += dS K · scale
-    dK/dV kernel (grid B·H × Tk/Bk × Tq/Bq): same q-major (Bq, Bk) tile
-        orientation — PᵀdO and dSᵀQ come out of dot_general by contracting
-        the q dim, so no in-kernel transposes;  dV += PᵀdO;  dK += dSᵀQ·scale
+    dK/dV kernel (grid B·H × Tk/Bk × Tq/Bq): the tile is K-MAJOR, Sᵀ = K Qᵀ
+        (Bk, Bq), so that Pᵀ and dSᵀ leave the MXU the way the two k-side
+        products read them (no transposed-lhs contraction) and lse / delta
+        come in as (1, Bq) rows;  dV += PᵀdO;  dK += dSᵀQ·scale
 Both recompute P from (q, k, lse) — O(T) memory, matmuls on the MXU.
 
 ``window=w`` = each query sees keys s ∈ (t−w, t]. Masks are structural
 (computed from block indices + iota), so sliding-window skips every tile
 outside the band — cost O(T·w), not O(T²).
+
+Precision: every product hands the MXU its operands in the dtype the caller
+STORED them in, and accumulates in fp32. Q Kᵀ and dO Vᵀ read q, k, v, dO as
+they are; the tile-shaped operand of the second products — P for P V and
+Pᵀ dO, dS for dS K and dSᵀ Q — is computed in fp32 and cast to the other
+operand's dtype on its way in (``p.astype(v.dtype)``), as the serving kernels
+(piece_attention, indexed_attention, cache_attention) and jax's own TPU flash
+kernel do. With bf16 inputs that is what the chip did already: Mosaic
+multiplies fp32 operands at default precision in ONE bf16 pass, so the
+up-casts this replaced changed neither a bit nor a millisecond there (PERF.md
+§6 PR 54); with fp32 inputs the casts are no-ops. The scores, the running max
+and sum, ``exp``, ``lse``, ``delta``, P and dS before their cast and every
+accumulator stay fp32.
+
+When a tile is masked: a grid step's tile is SKIPPED (not computed) where the
+structural mask is all-false over it (`_skip_tile`, decided from the grid
+indices for all three kernels, the banded grid and the ring callers'
+``shift`` / ``q_offset`` alike), and every computed tile builds the mask from
+two iotas. A second body without the mask for the tiles it leaves whole was
+measured and taken out: the mask hides under the products (PERF.md §6 PR 54).
+
+What these kernels were bound by, and no longer pay for (the products alone
+take nearly the whole kernel's time: the softmax hides under them): the
+forward's per-row statistics broadcast across lanes at every tile — m and l
+live lane-replicated (Bq, 128) (`_stat_lanes`, `_across`); transposed-lhs
+contractions and column broadcasts in dK/dV (its k-major tile); and the K / V
+(or Q / dO) blocks of a SKIPPED step: its index map names the row's nearest
+computed tile, a block already resident (`_fetched_k`, `_fetched_q`).
 """
 
 from __future__ import annotations
@@ -75,10 +104,78 @@ def _skip_tile(qi, ki, bq, bk, causal, window, shift: int = 0,
     return skip
 
 
-def _rowscol(qi, ki, bq, bk):
-    rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return rows, cols
+def _fetched_k(qi, j, nk, bq, bk, causal, window, shift: int = 0,
+               q_offset: int = 0):
+    """The k-tile that step (qi, j) of the classic grid FETCHES: j itself
+    wherever :func:`_skip_tile` computes the tile, and where it skips, the
+    nearest computed tile of the row — the block the step before fetched (past
+    the causal bound) or the one the next computed step needs (before the
+    window). A block index that does not change moves no bytes: fetched by
+    its own index, a skipped step waited ~0.47 us for a K and a V tile with no
+    product to hide the DMA under, 7.2 ms of a 44 ms forward at T 8,192
+    (PERF.md §6 PR 54)."""
+    if causal:
+        j = jnp.minimum(j, (qi * bq + q_offset + (bq - 1) - shift) // bk)
+    if window is not None:
+        j = jnp.maximum(j, (qi * bq + q_offset - window + 1) // bk)
+    return jnp.clip(j, 0, nk - 1)
+
+
+def _fetched_q(ki, j, nq, bq, bk, causal, window, shift: int = 0,
+               q_offset: int = 0):
+    """:func:`_fetched_k` for the dK/dV kernel's sweep over q tiles."""
+    if causal:
+        j = jnp.maximum(j, (ki * bk + shift - q_offset) // bq)
+    if window is not None:
+        j = jnp.minimum(j, (ki * bk + bk + window - 2 - q_offset) // bq)
+    return jnp.clip(j, 0, nq - 1)
+
+
+def _live(qi, ki, oob, geo):
+    """True where grid step (qi, ki) computes its tile: inside the banded
+    sweep (not ``oob``) and not entirely masked."""
+    return jnp.logical_not(oob | _skip_tile(
+        qi, ki, geo["bq"], geo["bk"], geo["causal"], geo["window"], geo["shift"],
+        geo["q_offset"],
+    ))
+
+
+def _mask_of(qi, ki, geo, k_major: bool = False):
+    """The structural mask's tile at grid step (qi, ki): (Bq, Bk), or (Bk, Bq)
+    for the dK/dV kernel's k-major tile."""
+    bq, bk = geo["bq"], geo["bk"]
+    shape, q_axis = ((bk, bq), 1) if k_major else ((bq, bk), 0)
+    rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return _tile_mask(rows, cols, geo["causal"], geo["window"], geo["t_k"],
+                      geo["shift"], geo["q_offset"])
+
+
+_NT = (((1,), (1,)), ((), ()))  # a bᵀ: both operands contract their last dim
+_LANES = 128
+
+
+def _nt(a, b):
+    return jax.lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+
+
+def _stat_lanes(*widths: int) -> int:
+    """Lanes the forward's per-row statistics (m, l) are kept over in VMEM: 128,
+    every lane holding the row's value, where each width it is laid across is
+    whole vregs; else 1 (the interpret-mode tests' small blocks, a short
+    sequence's ragged block)."""
+    return _LANES if all(w % _LANES == 0 for w in widths) else 1
+
+
+def _across(stat, width: int):
+    """A per-row statistic laid across ``width`` lanes. Held (Bq, 1) it is
+    broadcast across lanes at every use — a pass through the cross-lane unit
+    over Bq / 8 vregs, which at (512, 512) tiles cost the forward kernel more
+    than its mask (PERF.md §6 PR 54); held lane-replicated (Bq, 128) it is the
+    same vregs read again."""
+    if stat.shape[1] == 1:
+        return stat
+    return pltpu.repeat(stat, width // _LANES, 1)
 
 
 def _banded_ok(causal, window, shift, q_offset, t_q, t_k) -> bool:
@@ -128,14 +225,26 @@ def _banded_q_nj(nk: int, bq: int, bk: int, window: int) -> int:
     return m
 
 
+def _k_sweep(banded, nq, nk, bq, bk, causal, window, shift, q_offset):
+    """(steps, K / V index map) of a q tile's sweep over k tiles, for the
+    forward and the dQ kernel: the band's tiles alone on the banded grid, all
+    ``nk`` on the classic one, a skipped step naming a resident block."""
+    if banded:
+        return _banded_nj(nq, bq, bk, window), lambda b, i, j: (
+            b, jnp.clip(_banded_base(i, bq, bk, window) + j, 0, nk - 1), 0
+        )
+    return nk, lambda b, i, j: (
+        b, _fetched_k(i, j, nk, bq, bk, causal, window, shift, q_offset), 0
+    )
+
+
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, scale, causal, window, shift, q_offset, t_k, bq, bk, nk, banded,
-    nk_real,
+    *, scale, nk, banded, nk_real, **geo,
 ):
     qi, j = pl.program_id(1), pl.program_id(2)
     if banded:  # k-tile index is band-relative (swa clip, module docstring)
-        ki = _banded_base(qi, bq, bk, window) + j
+        ki = _banded_base(qi, geo["bq"], geo["bk"], geo["window"]) + j
         oob = (ki < 0) | (ki >= nk_real)
     else:
         ki = j
@@ -147,34 +256,26 @@ def _fwd_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(jnp.logical_not(
-        oob | _skip_tile(qi, ki, bq, bk, causal, window, shift, q_offset)
-    ))
+    @pl.when(_live(qi, ki, oob, geo))
     def _():
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (Bq, Bk)
-        rows, cols = _rowscol(qi, ki, bq, bk)
-        s = jnp.where(_tile_mask(rows, cols, causal, window, t_k, shift, q_offset), s, _NEG)
-
-        m_prev = m_scr[:]
+        s = _nt(q_ref[0], k_ref[0]) * scale  # (Bq, Bk) fp32
+        s = jnp.where(_mask_of(qi, ki, geo), s, _NEG)
+        m_prev = m_scr[:]  # (Bq, 1) or lane-replicated (Bq, 128): _stat_lanes
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)  # (Bq, Bk) fp32
+        p = jnp.exp(s - _across(m_new, s.shape[1]))  # (Bq, Bk) fp32
         l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
-            p, v_ref[0].astype(jnp.float32), preferred_element_type=jnp.float32
+        acc_scr[:] = acc_scr[:] * _across(alpha, acc_scr.shape[1]) + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[0], preferred_element_type=jnp.float32
         )
         m_scr[:] = m_new
 
     @pl.when(j == nk - 1)
     def _():
-        l = l_scr[:]
+        l = l_scr[:, :1]
         safe = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows (padding) -> 0
         o_ref[0] = (acc_scr[:] / safe).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[:] + jnp.log(safe)  # (Bq, 1)
+        lse_ref[0] = m_scr[:, :1] + jnp.log(safe)  # (Bq, 1)
 
 
 @kernel_entry(
@@ -193,14 +294,7 @@ def _flash_fwd_flat(q, k, v, scale, causal, window, bq, bk, interpret, shift=0,
     nq, nk = qp.shape[1] // bq, kp.shape[1] // bk
 
     banded = _banded_ok(causal, window, shift, q_offset, t_q, t_k)
-    if banded:
-        grid_k = _banded_nj(nq, bq, bk, window)
-        kvmap = lambda b, i, j: (  # noqa: E731
-            b, jnp.clip(_banded_base(i, bq, bk, window) + j, 0, nk - 1), 0
-        )
-    else:
-        grid_k = nk
-        kvmap = lambda b, i, j: (b, j, 0)  # noqa: E731
+    grid_k, kvmap = _k_sweep(banded, nq, nk, bq, bk, causal, window, shift, q_offset)
 
     kern = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, window=window, shift=shift,
@@ -225,8 +319,8 @@ def _flash_fwd_flat(q, k, v, scale, causal, window, bq, bk, interpret, shift=0,
             _sds((bh, nq * bq, 1), jnp.float32, q),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, _stat_lanes(bk, dv)), jnp.float32),
+            pltpu.VMEM((bq, _stat_lanes(bk, dv)), jnp.float32),
             pltpu.VMEM((bq, dv), jnp.float32),
         ],
         interpret=interpret,
@@ -241,12 +335,11 @@ def _flash_fwd_flat(q, k, v, scale, causal, window, bq, bk, interpret, shift=0,
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
-    *, scale, causal, window, shift, q_offset, t_k, bq, bk, nk, banded,
-    nk_real,
+    *, scale, nk, banded, nk_real, **geo,
 ):
     qi, j = pl.program_id(1), pl.program_id(2)
     if banded:
-        ki = _banded_base(qi, bq, bk, window) + j
+        ki = _banded_base(qi, geo["bq"], geo["bk"], geo["window"]) + j
         oob = (ki < 0) | (ki >= nk_real)
     else:
         ki = j
@@ -256,26 +349,16 @@ def _dq_kernel(
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(jnp.logical_not(
-        oob | _skip_tile(qi, ki, bq, bk, causal, window, shift, q_offset)
-    ))
+    @pl.when(_live(qi, ki, oob, geo))
     def _():
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        rows, cols = _rowscol(qi, ki, bq, bk)
-        mask = _tile_mask(rows, cols, causal, window, t_k, shift, q_offset)
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0]), 0.0)  # lse: (Bq, 1)
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0]) * scale
+        s = _nt(q_ref[0], k_ref[0]) * scale  # (Bq, Bk) fp32
+        # lse, delta: (Bq, 1) columns. Laid across lanes once a q tile
+        # instead (as the forward keeps m and l) this kernel ran 1-5% SLOWER:
+        # its three products hide the two broadcasts (PERF.md §6 PR 54)
+        p = jnp.where(_mask_of(qi, ki, geo), jnp.exp(s - lse_ref[0]), 0.0)
+        ds = p * (_nt(do_ref[0], v_ref[0]) - delta_ref[0]) * scale
         dq_scr[:] = dq_scr[:] + jnp.dot(
-            ds, k_ref[0].astype(jnp.float32), preferred_element_type=jnp.float32
+            ds.astype(k_ref.dtype), k_ref[0], preferred_element_type=jnp.float32
         )
 
     @pl.when(j == nk - 1)
@@ -286,12 +369,11 @@ def _dq_kernel(
 def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_scr, dv_scr,
-    *, scale, causal, window, shift, q_offset, t_k, bq, bk, nq, banded,
-    nq_real,
+    *, scale, nq, banded, nq_real, **geo,
 ):
     ki, j = pl.program_id(1), pl.program_id(2)
     if banded:  # q-tile index is band-relative: q rows in [ki*bk, ki*bk+bk+w)
-        qi = (ki * bk) // bq + j
+        qi = (ki * geo["bk"]) // geo["bq"] + j
         oob = qi >= nq_real
     else:
         qi = j
@@ -302,34 +384,21 @@ def _dkv_kernel(
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(jnp.logical_not(
-        oob | _skip_tile(qi, ki, bq, bk, causal, window, shift, q_offset)
-    ))
+    @pl.when(_live(qi, ki, oob, geo))
     def _():
-        # q-major (Bq, Bk) tile; k-side grads via contraction over the q dim
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        rows, cols = _rowscol(qi, ki, bq, bk)
-        mask = _tile_mask(rows, cols, causal, window, t_k, shift, q_offset)
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0]), 0.0)
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p, do_ref[0].astype(jnp.float32),
-            dimension_numbers=(((0,), (0,)), ((), ())),  # Pᵀ dO
-            preferred_element_type=jnp.float32,
+        # k-major (Bk, Bq) tile: Pᵀ and dSᵀ come out of the MXU the way the
+        # two k-side products read them, and lse / delta are (1, Bq) ROWS
+        st = _nt(k_ref[0], q_ref[0]) * scale
+        pt = jnp.where(
+            _mask_of(qi, ki, geo, k_major=True), jnp.exp(st - lse_ref[0, 0]), 0.0
         )
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0],
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        dv_scr[:] = dv_scr[:] + jnp.dot(  # Pᵀ dO
+            pt.astype(do_ref.dtype), do_ref[0], preferred_element_type=jnp.float32
         )
-        ds = p * (dp - delta_ref[0]) * scale
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds, q_ref[0].astype(jnp.float32),
-            dimension_numbers=(((0,), (0,)), ((), ())),  # dSᵀ Q
-            preferred_element_type=jnp.float32,
+        dpt = _nt(v_ref[0], do_ref[0])
+        dst = pt * (dpt - delta_ref[0, 0]) * scale
+        dk_scr[:] = dk_scr[:] + jnp.dot(  # dSᵀ Q
+            dst.astype(q_ref.dtype), q_ref[0], preferred_element_type=jnp.float32
         )
 
     @pl.when(j == nq - 1)
@@ -369,14 +438,7 @@ def _flash_bwd_flat(q, k, v, out, lse, g, scale, causal, window, bq, bk, interpr
     nq, nk = qp.shape[1] // bq, kp.shape[1] // bk
 
     banded = _banded_ok(causal, window, shift, q_offset, t_q, t_k)
-    if banded:
-        grid_k = _banded_nj(nq, bq, bk, window)
-        kvmap = lambda b, i, j: (  # noqa: E731
-            b, jnp.clip(_banded_base(i, bq, bk, window) + j, 0, nk - 1), 0
-        )
-    else:
-        grid_k = nk
-        kvmap = lambda b, i, j: (b, j, 0)  # noqa: E731
+    grid_k, kvmap = _k_sweep(banded, nq, nk, bq, bk, causal, window, shift, q_offset)
 
     col_spec_q = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0), memory_space=pltpu.VMEM)
 
@@ -407,14 +469,24 @@ def _flash_bwd_flat(q, k, v, out, lse, g, scale, causal, window, bq, bk, interpr
 
     if banded:
         grid_q = _banded_q_nj(nk, bq, bk, window)
-        qmap = lambda b, j, i: (  # noqa: E731
-            b, jnp.clip((j * bk) // bq + i, 0, nq - 1), 0
-        )
+        qtile = lambda j, i: jnp.clip((j * bk) // bq + i, 0, nq - 1)  # noqa: E731
     else:
         grid_q = nq
-        qmap = lambda b, j, i: (b, i, 0)  # noqa: E731
+        qtile = lambda j, i: _fetched_q(  # noqa: E731
+            j, i, nq, bq, bk, causal, window, shift, q_offset
+        )
+    qmap = lambda b, j, i: (b, qtile(j, i), 0)  # noqa: E731
 
-    col_spec_q_inner = pl.BlockSpec((1, bq, 1), qmap, memory_space=pltpu.VMEM)
+    # the k-major tile reads lse / delta as (1, Bq) rows, one a q tile:
+    # [BH, nq, 1, Bq], whose block spans its last two dims whole, so any Bq
+    # the sublane rounding of `_blocks` gives is a legal block (a (1, 1, Bq)
+    # block of [BH, 1, Tq] needs Bq in whole vregs of 128 lanes). A relayout
+    # of 4 bytes a query, one sublane of 8 used: what it adds to a training
+    # step's peak is in PERF.md §6 PR 54
+    as_rows = lambda x: x.reshape(bh, nq, 1, bq)  # noqa: E731
+    row_spec_q = pl.BlockSpec(
+        (1, 1, 1, bq), lambda b, j, i: (b, qtile(j, i), 0, 0), memory_space=pltpu.VMEM
+    )
     dkv_kern = functools.partial(
         _dkv_kernel, scale=scale, causal=causal, window=window, shift=shift,
         q_offset=q_offset,
@@ -429,8 +501,8 @@ def _flash_bwd_flat(q, k, v, out, lse, g, scale, causal, window, bq, bk, interpr
             pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((1, bk, dv), lambda b, j, i: (b, j, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((1, bq, dv), qmap, memory_space=pltpu.VMEM),
-            col_spec_q_inner,
-            col_spec_q_inner,
+            row_spec_q,
+            row_spec_q,
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0), memory_space=pltpu.VMEM),
@@ -445,7 +517,7 @@ def _flash_bwd_flat(q, k, v, out, lse, g, scale, causal, window, bq, bk, interpr
             pltpu.VMEM((bk, dv), jnp.float32),
         ],
         interpret=interpret,
-    )(qp, kp, vp, gp, lsep, deltap)
+    )(qp, kp, vp, gp, as_rows(lsep), as_rows(deltap))
     return dq[:, :t_q, :], dk[:, :t_k, :], dv_[:, :t_k, :]
 
 

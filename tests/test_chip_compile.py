@@ -92,11 +92,11 @@ def _raw(q, k, v):
     return _f32sum(num) + _f32sum(den)
 
 
-def _flash(window):
+def _flash(window, **blocks):
     def fn(q, k, v):
         from orion_tpu.ops.pallas.flash_attention import flash_attention
 
-        return flash_attention(q, k, v, causal=True, window=window)
+        return flash_attention(q, k, v, causal=True, window=window, **blocks)
 
     return fn
 
@@ -279,6 +279,7 @@ _QKV = [(BHTD, jnp.bfloat16)] * 3
 # two rows of its delta-rule layer (16 key heads serving 32 value heads of
 # 128, bf16 q/k/v, fp32 beta and log-decay)
 _QKV_GQA = [((8, 16, 8192, 256), jnp.bfloat16)] * 3
+_QKV_SMALL = [((1, 2, 1000, 128), jnp.bfloat16)] * 3
 _GMM_HELD = [((131072, 2048), jnp.bfloat16), ((64, 2048, 512), jnp.bfloat16),
              ((64,), jnp.int32)]
 # its 65,536 tokens' rows into the 131,072-row buffer and back: x2, a gate a
@@ -409,6 +410,12 @@ KERNELS = [
     pytest.param(_flash(None), _QKV_GQA, id="flash-causal-d256-T8192-fwd"),
     pytest.param(_grad3(_flash(None)), _QKV_GQA,
                  id="flash-causal-d256-T8192-bwd"),
+    # `attn_block_q` is a public field: several q tiles of a block that is
+    # whole sublanes but not whole vregs (dK/dV reads lse / delta as rows)
+    pytest.param(_grad3(_flash(None, block_q=64, block_k=64)), _QKV_SMALL,
+                 id="flash-causal-block64-bwd"),
+    pytest.param(_grad3(_flash(256, block_q=192, block_k=128)), _QKV_SMALL,
+                 id="flash-w256-block192-bwd"),
     pytest.param(_gmm, _GMM_HELD, id="gmm-held64-fwd"),
     pytest.param(
         jax.grad(lambda x, w, g: _f32sum(_gmm(x, w, g)), argnums=(0, 1)),

@@ -179,3 +179,184 @@ def test_banded_swa_matches_xla(t, w, bq, bk):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4
         )
+
+
+# -- bf16 operands, lane-replicated statistics, k-major dK/dV (PR 54)
+# Five q tiles by five k tiles, the last of each ragged (T 300 under 64 x 64
+# blocks, T 600 under 128 x 128), so that one call holds tiles the mask leaves
+# whole, tiles an edge of the mask crosses, a tile with padded keys and skipped
+# tiles at once. Blocks and head of 128 take the forward's lane-replicated form
+# of m and l (``_stat_lanes``), 64 the column form; dK/dV reads lse and delta
+# as (1, Bq) rows in both.
+
+_TILE_CASES = {  # lengths in units of a block
+    "causal_full": dict(causal=True),
+    "bidirectional_ragged_keys": dict(causal=False, t_k=4.375),
+    "swa_banded": dict(causal=True, window=2.34375),
+    "swa_unbanded": dict(causal=True, window=5),
+    "shift_1": dict(causal=True, shift=1),
+    # the halo caller's geometry (parallel/ring.py): the keys are the shard
+    # before the queries', so the causal bound never bites and the window does
+    "q_offset": dict(causal=True, window=3.125, q_offset=4.6875),
+}
+_T_BLOCKS = 4.6875  # 300 / 64
+# max |error| against the fp32 reference, the inputs' rounding excluded (the
+# reference reads the same bf16 inputs as fp32): P, dS and every output are
+# rounded to bf16 (2^-9 relative) where the fp32 path rounds nothing
+_TOL = {"float32": dict(atol=3e-5, rtol=3e-5), "bfloat16": dict(atol=2e-2, rtol=2e-2)}
+
+
+def _tile_case(case, blk):
+    """(t_q, t_k, the kernels' mask arguments) of ``case`` at block ``blk``."""
+    geo = {k: (v if isinstance(v, bool) or k == "shift" else int(v * blk))
+           for k, v in _TILE_CASES[case].items()}
+    t_q = int(_T_BLOCKS * blk)
+    return t_q, geo.pop("t_k", t_q), geo
+
+
+def _visible(t_q, t_k, causal=True, window=None, shift=0, q_offset=0):
+    """The structural mask as the kernels define it, [t_q, t_k]."""
+    rows = np.arange(t_q)[:, None] + q_offset
+    cols = np.arange(t_k)[None, :]
+    m = np.ones((t_q, t_k), bool)
+    if causal:
+        m &= rows >= cols + shift
+    if window is not None:
+        m &= (rows - cols) < window
+    return m
+
+
+def _tile_kinds(t_q, t_k, blk, **geo):
+    """How many tiles of the grid the mask leaves whole, crosses (or pads) and
+    empties."""
+    nq, nk = -(-t_q // blk), -(-t_k // blk)
+    vis = np.zeros((nq * blk, nk * blk), bool)
+    vis[:, :t_k] = _visible(nq * blk, t_k, **geo)
+    seen = vis.reshape(nq, blk, nk, blk).sum(axis=(1, 3))
+    return {"interior": int((seen == blk * blk).sum()),
+            "skipped": int((seen == 0).sum()),
+            "edge": int(((seen > 0) & (seen < blk * blk)).sum())}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("blk", [64, 128], ids=["columns", "lanes"])
+@pytest.mark.parametrize("case", list(_TILE_CASES))
+def test_flash_tiles_match_xla(case, blk, dtype):
+    """Forward and ``jax.grad`` of the interpret-mode kernels against the
+    materialising fp32 reference, over every kind of tile in one call."""
+    from orion_tpu.ops.pallas.flash_attention import _stat_lanes, flash_attention_lse
+
+    t_q, t_k, geo = _tile_case(case, blk)
+    kinds = _tile_kinds(t_q, t_k, blk, **geo)
+    assert kinds["interior"] and kinds["edge"], kinds
+    assert kinds["skipped"] or not geo["causal"], kinds
+    assert _stat_lanes(blk, blk) == (128 if blk == 128 else 1)
+
+    ks = jax.random.split(jax.random.PRNGKey(54), 4)
+    q = _rand(ks[0], 2, t_q, blk, dtype=dtype)  # head dim = the block
+    k = _rand(ks[1], 2, t_k, blk, dtype=dtype)
+    v = _rand(ks[2], 2, t_k, blk, dtype=dtype)
+    vis = _visible(t_q, t_k, **geo)
+    # a query that sees no key at all (row 0 under shift 1, the halo's far
+    # rows) has no softmax to compare: it carries no weight
+    seen = vis.any(axis=1)
+    w = _rand(ks[3], 2, t_q, blk) * seen[None, :, None]
+
+    def loss_ref(q, k, v):
+        out = softmax_attention_xla(
+            q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
+            causal=False, mask=jnp.asarray(vis),
+        )
+        return jnp.sum(out * w), out
+
+    def loss_flash(q, k, v):
+        out, _ = flash_attention_lse(q, k, v, block_q=blk, block_k=blk, interpret=True,
+                                     **geo)
+        return jnp.sum(out.astype(jnp.float32) * w), out
+
+    (_, out_r), gr = jax.value_and_grad(loss_ref, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, out_f), gf = jax.value_and_grad(loss_flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    assert out_f.dtype == q.dtype
+    np.testing.assert_allclose(
+        np.asarray(out_f, np.float32)[:, seen], np.asarray(out_r)[:, seen], **_TOL[dtype]
+    )
+    for a, b in zip(gf, gr):
+        assert a.dtype == q.dtype
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), **_TOL[dtype]
+        )
+
+
+@pytest.mark.parametrize("case", list(_TILE_CASES))
+def test_flash_fwd_lane_replicated_stats_bit_identical_to_columns(case, monkeypatch):
+    """m and l held lane-replicated (Bq, 128) are the same numbers read from
+    more lanes: with fp32 inputs (the operand casts are no-ops) the forward
+    gives the BITS of the (Bq, 1) column form it had before PR 54."""
+    from orion_tpu.ops.pallas import flash_attention as fa
+
+    blk = 128
+    t_q, t_k, geo = _tile_case(case, blk)
+    ks = jax.random.split(jax.random.PRNGKey(5454), 3)
+    q, k, v = _rand(ks[0], 2, t_q, blk), _rand(ks[1], 2, t_k, blk), _rand(ks[2], 2, t_k, blk)
+
+    def run():  # the function as written: no trace cache between the two
+        return fa._flash_fwd_flat.__wrapped__(
+            q, k, v, 0.125, geo["causal"], geo.get("window"), blk, blk, True,
+            shift=geo.get("shift", 0), q_offset=geo.get("q_offset", 0))
+
+    assert fa._stat_lanes(blk, blk) == 128
+    lanes = run()
+    monkeypatch.setattr(fa, "_stat_lanes", lambda *widths: 1)
+    columns = run()
+    for a, b in zip(lanes, columns):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("geo", [
+    dict(causal=True),
+    dict(causal=False),
+    dict(causal=True, window=40),
+    dict(causal=True, window=16),      # window == a block: no tile left whole
+    dict(causal=False, window=24),
+    dict(causal=True, shift=1),
+    dict(causal=True, q_offset=21),
+    dict(causal=True, window=40, q_offset=48),
+    dict(causal=True, window=30, shift=1, q_offset=5),
+], ids=lambda g: "-".join(f"{k}{v}" for k, v in g.items()))
+@pytest.mark.parametrize("t_k", [96, 90], ids=["whole", "padded_keys"])
+@pytest.mark.parametrize("bq,bk", [(16, 16), (32, 16), (16, 32)])
+def test_skip_and_fetch_predicates_are_the_mask(geo, t_k, bq, bk):
+    """For every (qi, ki): ``_skip_tile`` is true exactly when the structural
+    mask over REAL keys' columns is all-false (a skipped tile is never read;
+    a tile of padded keys alone does not exist: nk = ceil(t_k / bk)). A
+    computed tile fetches its own blocks; a skipped step fetches the row's
+    NEAREST computed tile (the block already resident, which moves no bytes),
+    or any block of the grid where its row computes nothing."""
+    from orion_tpu.ops.pallas.flash_attention import (
+        _fetched_k, _fetched_q, _skip_tile, _tile_mask,
+    )
+
+    causal, window = geo["causal"], geo.get("window")
+    shift, q_offset = geo.get("shift", 0), geo.get("q_offset", 0)
+    t_q = 96
+    nq, nk = t_q // bq, -(-t_k // bk)
+    skips = np.array([[bool(_skip_tile(qi, ki, bq, bk, causal, window, shift, q_offset))
+                       for ki in range(nk)] for qi in range(nq)])
+
+    def nearest(computed, at):
+        return {c for c in computed if abs(c - at) == min(abs(computed - at))}
+
+    for qi in range(nq):
+        for ki in range(nk):
+            rows, cols = np.meshgrid(qi * bq + np.arange(bq), ki * bk + np.arange(bk),
+                                     indexing="ij")
+            skip = skips[qi, ki]
+            # key padding apart (it never empties a tile on its own)
+            unpadded = _tile_mask(rows, cols, causal, window, 10 ** 9, shift, q_offset)
+            assert skip == (not unpadded.any()), (qi, ki)
+            fk = int(_fetched_k(qi, ki, nk, bq, bk, causal, window, shift, q_offset))
+            fq = int(_fetched_q(ki, qi, nq, bq, bk, causal, window, shift, q_offset))
+            assert 0 <= fk < nk and 0 <= fq < nq, (qi, ki, fk, fq)
+            row, col = np.flatnonzero(~skips[qi]), np.flatnonzero(~skips[:, ki])
+            assert not row.size or fk in nearest(row, ki), (qi, ki, fk)
+            assert not col.size or fq in nearest(col, qi), (qi, ki, fq)
