@@ -231,6 +231,9 @@ class ModelConfig:
     # it; none is built): under random weights it is drawn normal at this
     # standard deviation. 0: no such leaf, the router as it was
     moe_route_bias: float = 0.0
+    # > 0: the chosen experts' weights are s_e / (sum over the chosen + this)
+    # as a family publishes it; 0: s_e / max(sum, 1e-9), the router as it was
+    moe_gate_eps: float = 0.0
     # a routed expert's width where it differs from the dense layers'
     # mlp_hidden (0: resolved_mlp_hidden serves both)
     moe_hidden: int = 0
@@ -302,7 +305,7 @@ class ModelConfig:
 # one mixer class each: models/mixers/__init__.py::MIXERS
 LAYER_TYPES = (
     "linear", "softmax", "swa", "gated_delta", "gated_softmax",
-    "decay_linear", "block_sparse", "ssm", "latent", "indexed",
+    "decay_linear", "block_sparse", "ssm", "latent", "indexed", "gated_conv",
 )
 
 
@@ -741,6 +744,65 @@ TRINITY_MINI = ModelConfig(
     param_dtype="bfloat16",
 )
 
+def conv_full_pattern(periods: int, period: int = 4) -> Tuple[str, ...]:
+    """One leading gated_conv layer, then ``periods`` times (softmax,
+    gated_conv x (period - 1))."""
+    return ("gated_conv",) + (
+        ("softmax",) + ("gated_conv",) * (period - 1)
+    ) * periods
+
+
+LFM2_8B_A1B = ModelConfig(
+    # LFM2-8B-A1B at its published widths, the first of two pipeline stages:
+    # the published layer 0 (a leading dense gated_conv layer; the second
+    # counts once) and layers 2-13, three whole periods of (full_attention,
+    # conv, conv, conv) with ALL 32 experts of each (benchmark/configs/
+    # lfm2_8b_a1b.json states the source, the cut and what is assumed).
+    # The conv layers are a gated short convolution alone (three taps over
+    # 2,048 channels, no bias, no activation; a slot's state is two rows);
+    # the attention layers 32 query heads over 8 KV heads x 64, per-head q /
+    # k norm, rotary at 1e6; a dense SwiGLU of 7,168 then sigmoid top-4 of
+    # 32 experts of 1,792 with a per-expert selection bias, renormalised
+    # over the chosen with the published 1e-6; tied head over 65,536; bf16.
+    name="lfm2_8b_a1b",
+    vocab_size=65536,
+    d_model=2048,
+    n_layers=13,
+    layer_types=conv_full_pattern(3),
+    n_heads=32,
+    n_kv_heads=8,
+    head_dim=64,
+    qk_norm="head",
+    rotary_base=1e6,
+    norm="rmsnorm",
+    norm_eps=1e-5,
+    pos_embed="none",
+    tie_embeddings=True,
+    embed_init_std=0.005,
+    mlp="swiglu",
+    mlp_hidden=7168,
+    moe_hidden=1792,
+    moe_first_dense=1,
+    moe_period=1,
+    n_experts=32,
+    moe_top_k=4,
+    moe_score="sigmoid",
+    moe_route_scale=1.0,
+    moe_route_bias=0.05,
+    moe_gate_eps=1e-6,
+    moe_dropless=True,
+    moe_ep_buffer=1.0,  # the buffer holds every pair: nothing can drop
+    moe_step_tile=32,
+    # prefill_group stays 1: four slots' pieces in one program had XLA relay
+    # every 64-wide cache whole around their write-back (46% of the cell's
+    # busy time on the chip, PERF.md section 6, PR 55); a piece a program
+    # updates a slot's rows where the cache lies
+    param_init_dtype="float32",
+    max_seq_len=2560,
+    dtype="bfloat16",
+    param_dtype="bfloat16",
+)
+
 LRA_LISTOPS_LINEAR = ModelConfig(
     name="lra_listops_linear",
     vocab_size=32,  # digits + operators + specials
@@ -793,6 +855,7 @@ CONFIGS = {
         OPENPANGU_ULTRA_MOE_718B,
         KEYE_VL_2_0_30B_A3B,
         TRINITY_MINI,
+        LFM2_8B_A1B,
         LRA_LISTOPS_LINEAR,
         LRA_LISTOPS_SOFTMAX,
         LRA_TEXT_LINEAR,
@@ -810,5 +873,5 @@ def get_config(name: str, **overrides) -> ModelConfig:
 
 __all__ = [
     "ModelConfig", "CONFIGS", "get_config", "hybrid_pattern",
-    "gated_pattern", "delta_full_pattern", "decay_sparse_pattern", "ssm_full_pattern", "F32_MATMUL_SCOPES", "LAYER_TYPES",
+    "gated_pattern", "delta_full_pattern", "decay_sparse_pattern", "ssm_full_pattern", "conv_full_pattern", "F32_MATMUL_SCOPES", "LAYER_TYPES",
 ]

@@ -3,7 +3,8 @@
 ``MIXERS[layer_type]`` is the class ``models/transformer.py::Block`` builds
 as its ``attn`` submodule for every entry of ``configs.LAYER_TYPES``
 (``linear``, ``softmax`` / ``swa``, ``gated_delta``, ``gated_softmax``,
-``decay_linear``, ``block_sparse``, ``ssm``, ``latent``, ``indexed``). A new
+``decay_linear``, ``block_sparse``, ``ssm``, ``latent``, ``indexed``,
+``gated_conv``). A new
 mechanism is one file here with a :class:`Mixer` subclass, its entry in
 ``LAYER_TYPES`` and in ``MIXERS`` below, and nothing else: ``Block``,
 ``TransformerLM``, ``init_decode_state``, the decode programs and the
@@ -171,6 +172,10 @@ class Mixer(nn.Module):
     # position-indexed cache, how many rows of it a slot reserves
     # (``cache_rows``) and how many a decode step streams (``cache_rows_read``)
     cache_leaves: Tuple[str, ...] = ()
+    # the state leaves that are a short convolution's tail: its last
+    # ``width - 1`` input rows, a fixed few KB a slot whatever the prompt
+    # (``held_bytes``' ``tail_bytes``)
+    tail_leaves: Tuple[str, ...] = ()
 
     @staticmethod
     def cache_rows(cfg: ModelConfig, layer_type: str) -> int:
@@ -239,9 +244,9 @@ class Mixer(nn.Module):
         raise NotImplementedError(
             f"layer type {self.layer_type!r} does not build this serving "
             "entry point: gated_softmax has a training forward only; "
-            "gated_delta, decay_linear, block_sparse, ssm, latent and indexed serve "
-            "(prefill, its pieces, the decode step) but have no speculative "
-            "verify_extend / advance_verified"
+            "gated_delta, decay_linear, block_sparse, ssm, latent, indexed and "
+            "gated_conv serve (prefill, its pieces, the decode step) but have "
+            "no speculative verify_extend / advance_verified"
         )
 
     def prefill(
@@ -389,6 +394,7 @@ from orion_tpu.models.mixers.block_sparse import (  # noqa: E402
 from orion_tpu.models.mixers.decay_linear import (  # noqa: E402
     DecayLinearAttention,
 )
+from orion_tpu.models.mixers.gated_conv import GatedConv  # noqa: E402
 from orion_tpu.models.mixers.gated_delta import GatedDeltaNet  # noqa: E402
 from orion_tpu.models.mixers.gated_softmax import (  # noqa: E402
     GatedSoftmaxAttention,
@@ -410,11 +416,12 @@ MIXERS = {
     "ssm": StateSpace,
     "latent": LatentAttention,
     "indexed": IndexedAttention,
+    "gated_conv": GatedConv,
 }
 assert set(MIXERS) == set(LAYER_TYPES), (sorted(MIXERS), LAYER_TYPES)
 
 __all__ = [
     "MIXERS", "Mixer", "LinearAttention", "SoftmaxAttention", "GatedDeltaNet",
     "GatedSoftmaxAttention", "DecayLinearAttention", "BlockSparseAttention",
-    "StateSpace", "LatentAttention", "IndexedAttention", "ZeroCentredRMSNorm", "kernel_bh", "whole_array_backend",
+    "StateSpace", "LatentAttention", "IndexedAttention", "GatedConv", "ZeroCentredRMSNorm", "kernel_bh", "whole_array_backend",
 ]

@@ -68,6 +68,7 @@ class GatedDeltaNet(Mixer):
     layer_type: str = "gated_delta"
 
     rows_in_place = True
+    tail_leaves = ("conv",)
 
     def setup(self):
         cfg = self.cfg

@@ -77,6 +77,7 @@ class StateSpace(Mixer):
     layer_type: str = "ssm"
 
     rows_in_place = True
+    tail_leaves = ("conv",)
 
     def setup(self):
         cfg = self.cfg

@@ -70,7 +70,8 @@ routed path ran. The held path counts its rows into "moe_stats"
 (``rows_routed``, ``rows_held``, ``rows_max_expert``). ``moe_score:
 "sigmoid"`` scores each expert ``sigmoid(x W_r)`` instead of the softmax
 over the router's width; either way the top-k are renormalised over the k
-chosen and multiplied by ``moe_route_scale``. ``moe_route_bias`` adds a
+chosen (over their sum ``+ moe_gate_eps`` where a family publishes one) and
+multiplied by ``moe_route_scale``. ``moe_route_bias`` adds a
 per-expert fp32 buffer ``router_bias`` to the scores for the CHOICE of the
 top-k alone: the weights are the chosen experts' scores without it (forward
 only: the balancing rule that would move the buffer is not built).
@@ -129,7 +130,9 @@ def _expert_init(in_axis: int = -2):
     )
 
 
-def top_k_choice(probs: Array, k: int, select: Optional[Array] = None):
+def top_k_choice(
+    probs: Array, k: int, select: Optional[Array] = None, eps: float = 0.0
+):
     """probs [N, E] fp32 -> (ids [N, k] int32, gates [N, k] fp32): greedy
     top-k expert choice (slot s = argmax with slots <s masked out), gates
     renormalized to sum to 1 over the k picks. The ONE choice rule both
@@ -137,7 +140,8 @@ def top_k_choice(probs: Array, k: int, select: Optional[Array] = None):
     the dropless path consumes ids/gates directly. ``select`` [N, E]: the
     values the CHOICE is made on where they are not the scores themselves
     (the scores plus a per-expert bias, any sign); the gates are the chosen
-    experts' ``probs`` either way."""
+    experts' ``probs`` either way. ``eps`` > 0: the gates are divided by
+    ``sum + eps`` (a published form) instead of ``max(sum, 1e-9)``."""
     masked = probs if select is None else select
     taken = -1.0 if select is None else -jnp.inf
     ids, gates = [], []
@@ -152,7 +156,8 @@ def top_k_choice(probs: Array, k: int, select: Optional[Array] = None):
         ids.append(idx.astype(jnp.int32))
     ids = jnp.stack(ids, axis=1)
     g = jnp.stack(gates, axis=1)
-    return ids, g / jnp.maximum(g.sum(axis=1, keepdims=True), 1e-9)
+    total = g.sum(axis=1, keepdims=True)
+    return ids, g / (total + eps if eps else jnp.maximum(total, 1e-9))
 
 
 def top_k_routing(probs: Array, k: int, capacity: int):
@@ -360,7 +365,9 @@ class MoEMLP(nn.Module):
                 (cfg.resolved_router_width,), jnp.float32,
             )
             select = probs + jax.lax.stop_gradient(bias)
-        ids, gates = top_k_choice(probs, cfg.moe_top_k, select)  # [N, k] x2
+        ids, gates = top_k_choice(  # [N, k] x2
+            probs, cfg.moe_top_k, select, cfg.moe_gate_eps
+        )
         if cfg.moe_route_scale != 1.0:
             gates = gates * cfg.moe_route_scale
         return logits, probs, ids, gates

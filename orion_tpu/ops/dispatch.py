@@ -262,8 +262,9 @@ def gated_delta_qkv(
 def causal_short_conv(
     x, w, activation: bool = True, tail=None, bias=None, *, backend: str = "auto"
 ):
-    """Dispatch the delta-rule and state-space layers' short causal
-    depthwise convolution + SiLU over a whole sequence or a prompt piece
+    """Dispatch the short causal depthwise convolution of the delta-rule
+    and state-space layers (+ SiLU) and of the gated-convolution layers
+    (``activation=False``: the bare sum) over a whole sequence or a prompt piece
     (``ops/gated_delta.py::causal_short_conv``, the specification: x ``[...,
     T, C]``, w ``[W, C]``, ``tail [..., W - 1, C]`` the inputs just before
     ``x``, ``bias`` [C]). ``pallas`` and ``pallas_interpret`` run it as a
@@ -466,6 +467,34 @@ def cache_attention(
     return cached_attention(q, k_cache, v_cache, valid, with_lse=True)
 
 
+# lanes of a vector register: what the last axis of a kernel's operand is
+# tiled to
+_LANES = 128
+
+
+def cache_copy_nbytes(tree) -> int:
+    """Bytes of the copies the row-list step kernels read in place of the
+    caches they are handed, for the leaves of ``tree`` (arrays or their
+    shapes; a decode carry): a cache whose last axis is under a lane tile (a
+    grouped KV cache of 64-wide heads, ``[B, KV, rows, 64]``) is held by the
+    chip compactly, its rows as the minor axis, and :func:`cache_attention`'s
+    kernel is handed a copy relaid to rows of 128 lanes, twice the cache at
+    64, once a decode scan (the described chip's compile of 256 slots x 2,560
+    rows: six copies of 1.25 GB beside caches of 0.67; ``tests/
+    test_chip_compile.py`` pins it). The engine's memory account adds this to
+    the carry (``serving/batching.py::fits_once_only``, which has the carry's
+    shapes and no layer types: so by shape, and an upper bound: a narrow leaf
+    that no kernel reads is never copied). Vectors (one axis) are no such
+    leaf; a leaf 128 wide or more is read where it lies."""
+    import jax
+
+    return sum(
+        x.size // x.shape[-1] * _LANES * x.dtype.itemsize
+        for x in jax.tree.leaves(tree)
+        if len(x.shape) > 1 and x.shape[-1] < _LANES
+    )
+
+
 def latent_cache_attention(
     qt, qr, c_cache, kr_cache, lengths, rows=None, *, scale: float,
     backend: str = "auto",
@@ -584,6 +613,7 @@ def decode_state_flush(state, chunk, rows, *, backend: str = "auto"):
 
 __all__ = [
     "cache_attention",
+    "cache_copy_nbytes",
     "causal_dot_product",
     "causal_short_conv",
     "decode_live_rows",

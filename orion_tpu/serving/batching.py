@@ -109,7 +109,12 @@ from orion_tpu.models.transformer import (
     linear_layer_indices,
     snapshot_decode_state,
 )
-from orion_tpu.ops.dispatch import resolve, resolve_chunk, row_sparse
+from orion_tpu.ops.dispatch import (
+    cache_copy_nbytes,
+    resolve,
+    resolve_chunk,
+    row_sparse,
+)
 from orion_tpu.resilience import inject
 from orion_tpu.resilience.breaker import StoreUnavailableError
 from orion_tpu.serving.session import DecodeRequest, DecodeResult
@@ -367,13 +372,16 @@ PROGRAM_RESERVE = 0.125
 
 def fits_once_only(carry, params, device) -> bool:
     """Does ``device`` hold the carry once beside the weights, but not
-    twice with :data:`PROGRAM_RESERVE` of it left for the programs? From its
+    twice (and the copies the step kernels read of its narrow caches, which
+    the kernels' own layer counts: ``ops.dispatch.cache_copy_nbytes``) with
+    :data:`PROGRAM_RESERVE` of it left for the programs? From its
     ``memory_stats()["bytes_limit"]``; False where the backend reports
     none. Arrays or their shapes."""
     limit = (device.memory_stats() or {}).get("bytes_limit")
     if not limit:
         return False
-    return 2 * tree_nbytes(carry) + tree_nbytes(params) > (1 - PROGRAM_RESERVE) * limit
+    held = 2 * tree_nbytes(carry) + cache_copy_nbytes(carry) + tree_nbytes(params)
+    return held > (1 - PROGRAM_RESERVE) * limit
 
 
 def parse_buckets(spec: str, max_seq_len: int) -> Tuple[int, ...]:
@@ -634,6 +642,10 @@ class SlotEngine:
             for lt, st in zip(cfg.resolved_layer_types, self._carry[1])
         ]
         kv_bytes = sum(n for _, n in caches)
+        tail_bytes = sum(
+            tree_nbytes([st[n] for n in MIXERS[lt].tail_leaves])
+            for lt, st in zip(cfg.resolved_layer_types, self._carry[1])
+        )
         self.held_bytes = {
             "carry_bytes": tree_nbytes(self._carry),
             "params_bytes": tree_nbytes(params),
@@ -643,6 +655,9 @@ class SlotEngine:
             "kv_bytes": kv_bytes,
             "ring_bytes": sum(n for ring, n in caches if ring),
             "state_bytes": tree_nbytes(self._carry[1]) - kv_bytes,
+            # of which the short convolutions' tails: a fixed few rows a
+            # slot and layer, whatever the prompt
+            "tail_bytes": tail_bytes,
         }
         self.state_writes_per_chunk = self._state_writes_per_chunk()
         self._rngs = jnp.tile(

@@ -199,10 +199,16 @@ def _gmm_live(x, w, sizes):
     return gmm_live(x, w, sizes, tile_rows=128, block_h=512)
 
 
-def _gmm_live_step(x, w, sizes):
-    from orion_tpu.ops.pallas.gmm import gmm_live
+def _gmm_live_tiled(tile_rows):
+    def fn(x, w, sizes):
+        from orion_tpu.ops.pallas.gmm import gmm_live
 
-    return gmm_live(x, w, sizes, tile_rows=16, block_h=512)
+        return gmm_live(x, w, sizes, tile_rows=tile_rows, block_h=512)
+
+    return fn
+
+
+_gmm_live_step, _gmm_live_tile32 = _gmm_live_tiled(16), _gmm_live_tiled(32)
 
 
 def _index_scores(qi, w, ki):
@@ -264,6 +270,12 @@ def _short_conv(x, w):
     from orion_tpu.ops.dispatch import causal_short_conv
 
     return causal_short_conv(x, w, backend="pallas")
+
+
+def _bare_conv(x, w, tail):
+    from orion_tpu.ops.dispatch import causal_short_conv
+
+    return causal_short_conv(x, w, activation=False, tail=tail, backend="pallas")
 
 
 def _gated_norm(o, z, w):
@@ -385,6 +397,22 @@ _WINDOW_PIECE = [_PIECE_Q, *[((1, 4, 3072, 128), jnp.bfloat16)] * 2,
                  ((), jnp.int32), ((), jnp.int32)]
 _FULL_PIECE = [_PIECE_Q, *[((1, 4, 17408, 128), jnp.bfloat16)] * 2,
                ((), jnp.int32), ((), jnp.int32)]
+# lfm2_8b_a1b.serve_batch (128 slots x 2,560): a 1,024-row piece's bare
+# three-tap conv over 2,048 channels against the two-row tail; a step's 512
+# pairs over 32 experts of 1,792 (not a multiple of 512: 256-wide blocks) on
+# 32-row tiles, up and down; a piece's 4,096 pairs on 128-row tiles; a token's
+# 32-head query a slot over an 8-head cache of 64-wide rows
+_BARE_CONV = [((1, 1024, 2048), jnp.bfloat16), ((3, 2048), jnp.bfloat16),
+              ((1, 2, 2048), jnp.bfloat16)]
+_GMM_LIVE_1792_UP = [((1536, 2048), jnp.bfloat16), ((32, 2048, 1792), jnp.bfloat16),
+                     ((32,), jnp.int32)]
+_GMM_LIVE_1792_DOWN = [((1536, 1792), jnp.bfloat16), ((32, 1792, 2048), jnp.bfloat16),
+                       ((32,), jnp.int32)]
+_GMM_LIVE_1792_PIECE = [((8192, 2048), jnp.bfloat16), ((32, 2048, 1792), jnp.bfloat16),
+                        ((32,), jnp.int32)]
+_KV_64WIDE = [((128, 32, 64), jnp.bfloat16),
+              *[((128, 8, 2560, 64), jnp.bfloat16)] * 2,
+              ((128,), jnp.int32), ((128,), jnp.bool_)]
 _LATENT_PIECE = [*[((1, 128, 1024, 256), jnp.bfloat16)] * 2,
                  ((1, 128, 1024, 128), jnp.bfloat16)]
 # moe_1b3_4e dropless: 24576 padded rows through 4 experts of 2048 x 5504
@@ -460,6 +488,11 @@ KERNELS = [
     pytest.param(_gmm_live_step, _GMM_LIVE_STEP_UP, id="gmm_live-tile16-held128-2048x1024"),
     pytest.param(_gmm_live_step, _GMM_LIVE_STEP_DOWN, id="gmm_live-tile16-held128-1024x2048"),
     pytest.param(_gmm_live, _GMM_LIVE_GROUP, id="gmm_live-group4-held128-2048x1024"),
+    pytest.param(_bare_conv, _BARE_CONV, id="short_conv-bare-3taps-tail-piece1024"),
+    pytest.param(_gmm_live_tile32, _GMM_LIVE_1792_UP, id="gmm_live-tile32-held32-2048x1792"),
+    pytest.param(_gmm_live_tile32, _GMM_LIVE_1792_DOWN, id="gmm_live-tile32-held32-1792x2048"),
+    pytest.param(_gmm_live, _GMM_LIVE_1792_PIECE, id="gmm_live-piece1024-held32-2048x1792"),
+    pytest.param(_cache_attention, _KV_64WIDE, id="cache_attention-grouped-64wide-128slots"),
     pytest.param(_ring_step, _RING_STEP, id="window_step_attention-64slots-ring2048"),
     pytest.param(_ring_write, _RING_WRITE, id="ring_row_write-64slots-ring2048"),
     pytest.param(_window_piece, _WINDOW_PIECE, id="window_piece_attention-piece1024-ring2048"),
@@ -982,6 +1015,107 @@ def test_trinity_mini_boundary_programs_hold_the_carry_once(v5e):
         assert "mini-gather" not in text, name  # nor every slot's cache read for four rows
         if name == "scan":  # no ring (0.13 GB each of eight) among the temporaries
             assert m.temp_size_in_bytes < 0.5e9, m.temp_size_in_bytes
+
+
+def _lfm2_programs(v5e, slots: int):
+    """``lfm2_8b_a1b.serve_batch``'s two donated programs at ``slots`` x
+    2,560 for the described chip, all 13 layers, lowered and not yet
+    compiled: one slot's 512-token prompt piece and the decode scan of 8
+    steps. Returns (lowered by name, the states' shapes)."""
+    from orion_tpu import generate as gen
+    from orion_tpu.generate import SampleConfig
+    from orion_tpu.models.configs import get_config
+    from orion_tpu.models.transformer import TransformerLM, init_decode_state
+
+    chunk, piece, width = 8, 512, 2048
+    cfg = dataclasses.replace(get_config("lfm2_8b_a1b"), backend="pallas")
+    assert cfg.prefill_group == 1
+    model = TransformerLM(cfg)
+    one = SingleDeviceSharding(v5e[0])
+    put = lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one)  # noqa: E731
+    arr = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    params = jax.tree.map(put, jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 16), jnp.int32))))
+    assert sum(l.size for l in jax.tree.leaves(params)) == 4606249728
+    states = jax.tree.map(put, jax.eval_shape(lambda: init_decode_state(cfg, slots)))
+    ints, flags = arr((slots,), jnp.int32), arr((slots,), jnp.bool_)
+    carry = (ints, states, ints, ints, flags)
+    rngs, pbuf = arr((slots, 2), jnp.uint32), arr((slots, width), jnp.int32)
+    scalar, sample = arr((), jnp.int32), SampleConfig(temperature=0.0)
+    return {
+        "piece": gen._prefill_piece_donated_jit.lower(
+            model, params, carry, rngs, pbuf, ints, ints, scalar, piece, sample),
+        "scan": gen._decode_scan_donated_jit.lower(
+            model, params, carry, rngs, flags, ints, chunk, sample),
+    }, states
+
+
+@slow
+def test_lfm2_boundary_programs_hold_the_carry_once(v5e):
+    """``lfm2_8b_a1b.serve_batch``'s programs at 128 slots x 2,560 for the
+    chip, all 13 layers, the carry donated: one slot's 512-token prompt
+    piece and the decode scan on 32-row tiles (``moe_step_tile``). Each fits
+    the chip with its arguments (weights 9.21 GB + three growing caches and
+    ten two-row tails a slot, 2.02 GB as counted) and aliases the carry; the
+    piece runs the bare conv's Mosaic kernel in the ten conv layers, both
+    programs the 32 held experts through the grouped product over live
+    tiles, the scan attends by row list. What 64-wide heads cost is pinned
+    too: the device holds ``[128, 8, 2560, 64]`` with the rows minor; the
+    PIECE updates a slot's rows in that layout, in place (with
+    ``prefill_group`` 4 the compiler relaid every cache whole, slots minor,
+    around the four write-backs: 46% of the cell's busy time on the chip);
+    the SCAN's row-list kernel wants rows of lanes, so each of the six caches
+    is COPIED, padded to 128 lanes (0.67 GB from 0.34), once a scan:
+    ``ops.dispatch.cache_copy_nbytes`` counts exactly that. A compile, not a
+    chip run."""
+    from orion_tpu.ops.dispatch import cache_copy_nbytes
+    from orion_tpu.serving.batching import tree_nbytes
+
+    programs, states = _lfm2_programs(v5e, 128)
+    assert tree_nbytes(states) == 2023751680 and cache_copy_nbytes(states) == 2 * 2013265920
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        m = compiled.memory_analysis()
+        live = (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes)
+        assert live < 15.75 * 2 ** 30 - 258e6, (name, live)
+        assert m.alias_size_in_bytes > 2.0e9, (name, m.alias_size_in_bytes)
+        text = compiled.as_text()
+        assert "gmm_live" in text, name
+        copies = len(re.findall(r"bf16\[128,8,2560,64\]\S* copy\(", text))
+        if name == "piece":
+            assert "short_conv_fwd" in text and copies == 0  # no cache relaid whole
+            assert m.temp_size_in_bytes < 1.0e9, m.temp_size_in_bytes
+        else:
+            assert "cache_attention" in text and copies == 6
+            assert m.temp_size_in_bytes < cache_copy_nbytes(states) + 0.3e9, m.temp_size_in_bytes
+            assert re.search(r'op_name="[^"]*while/body[^"]*/gated_conv/[^"]*gated_conv_conv', text)
+
+
+@slow
+@pytest.mark.parametrize("slots,gib", [(192, 17.5), (256, 20.5)])
+def test_lfm2_scan_does_not_fit_the_chip_past_128_slots(v5e, slots, gib):
+    """Why the cell has 128 slots and not ISSUE 55's 256, nor 192: the decode
+    scan at those slot counts is REFUSED by the chip's compiler, which wants
+    ``gib`` GiB of the chip's 15.75 (weights 9.21 GB, the caches once, and
+    the six padded copies ``ops.dispatch.cache_copy_nbytes`` counts: twice
+    the caches). The arrays alone would fit (13.24 GB at 256 slots, the
+    ISSUE's arithmetic). When the row-list kernel reads 64-wide rows where
+    they lie this test fails, and the cell can take the ISSUE's slots."""
+    from orion_tpu.ops.dispatch import cache_copy_nbytes
+    from orion_tpu.serving.batching import tree_nbytes
+
+    programs, states = _lfm2_programs(v5e, slots)
+    caches = slots * 3 * 2 * 8 * 2560 * 64 * 2
+    assert cache_copy_nbytes(states) == 2 * caches
+    assert tree_nbytes(states) + 9212499456 < 13.3e9  # the arrays alone fit
+    with pytest.raises(Exception) as refused:
+        programs["scan"].compile()
+    said = str(refused.value)
+    assert re.search(r"RESOURCE_EXHAUSTED|[Rr]an out of memory|exceed", said), said[:600]
+    used = re.search(r"[Uu]sed ([0-9.]+)G of ([0-9.]+)G", said)
+    assert used and float(used.group(2)) == 15.75, said[:600]
+    assert abs(float(used.group(1)) - gib) < 0.3, said[:600]
 
 
 def test_minicpm_sala_boundary_programs_compile_and_fit(v5e):

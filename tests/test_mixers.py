@@ -19,7 +19,7 @@ from orion_tpu.models.mixers import MIXERS, Mixer
 from orion_tpu.models.transformer import TransformerLM, init_decode_state
 
 SERVED = ("linear", "softmax", "swa", "gated_delta", "decay_linear", "block_sparse", "ssm",
-          "latent", "indexed")
+          "latent", "indexed", "gated_conv")
 TRAIN_ONLY = ("gated_softmax",)
 
 # benchmark/configs/qwen3_next_80b.json's ``rehearse`` sizes
@@ -49,7 +49,7 @@ def test_registry_has_one_mixer_per_layer_type():
     assert all(issubclass(m, Mixer) for m in MIXERS.values())
     assert {lt for lt, m in MIXERS.items() if m.rows_in_place} == {
         "linear", "softmax", "swa", "gated_delta", "decay_linear", "block_sparse", "ssm",
-        "latent", "indexed",
+        "latent", "indexed", "gated_conv",
     }
 
 
@@ -123,7 +123,8 @@ def test_train_only_mixer_refuses_every_serving_entry_point(lt):
         init_decode_state(cfg, 1)
 
 
-@pytest.mark.parametrize("lt", ["gated_delta", "decay_linear", "block_sparse", "indexed"])
+@pytest.mark.parametrize("lt", ["gated_delta", "decay_linear", "block_sparse", "indexed",
+                                "gated_conv"])
 def test_served_without_a_speculative_pair(lt):
     """Served (prefill, pieces, the decode step), but the speculative
     verify / advance entry points stay the base class's, which raise."""
