@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from orion_tpu.models.configs import ModelConfig, get_config
 from orion_tpu.models.mixers import MIXERS
+from orion_tpu.models.moe import STAT_NAMES
 from orion_tpu.models.transformer import TransformerLM, init_decode_state
 from orion_tpu.ops.dispatch import decode_live_rows
 
@@ -304,7 +305,7 @@ def _moe_counted(model) -> bool:
 def _counted(model, params, *args, method: str):
     """``model.apply(params, *args, method=method)`` and, for a model whose
     MoE layers count their rows (``_moe_counted``), the row counters they
-    sowed as ``[4]`` int32 (``models/moe.py::stats_vector``); None for every
+    sowed as ``[6]`` int32 (``models/moe.py::stats_vector``); None for every
     other model, whose programs stay what they were."""
     if not _moe_counted(model):
         return model.apply(params, *args, method=method), None
@@ -325,7 +326,7 @@ def _decode_step_counted(model, params, token, states, t, rows, live):
 
 def _scan_outputs(model, ys):
     """A chunk scan's stacked outputs -> (tokens [S, n_steps], the steps'
-    MoE row counters summed [4] or None)."""
+    MoE row counters summed [6] or None)."""
     tokens, stats = ys if _moe_counted(model) else (ys, None)
     return jnp.moveaxis(tokens, 0, 1), None if stats is None else stats.sum(0)
 
@@ -396,7 +397,7 @@ def decode_batched_chunk(
     length) regardless of arrival order (asserted via jit cache stats in
     tests/test_batching.py). Returns (carry, tokens [S, n_steps]); for a
     row-counting MoE model (``_moe_counted``) also the boundary's MoE row
-    counters [4] int32 (``models/moe.py::STAT_NAMES``), as every
+    counters [6] int32 (``models/moe.py::STAT_NAMES``), as every
     slot-multiplexed program below does."""
     return _decode_batched_chunk_jit(
         model, params, carry, rngs, active, int(n_steps), sample_cfg
@@ -515,7 +516,7 @@ def _prefill_extend_row(
     the piece twice (inline and in its loop), traces and lowers the
     model's forward once. Returns (last-real-row logits [V], the
     advanced state row), and for a row-counting MoE model
-    (``_moe_counted``) the piece's MoE row counters [4] (its ``length`` real
+    (``_moe_counted``) the piece's MoE row counters [6] (its ``length`` real
     rows route; padding does not)."""
     idx = jnp.clip(offset + jnp.arange(pchunk), 0, pbuf.shape[1] - 1)
     piece = jnp.take(pbuf[sel], idx)[None]
@@ -679,7 +680,7 @@ def _decode_batched_prefill_chunk_jit(
     # program's opening copies and casts as it did the one piece this
     # program used to run (inside the loop a piece measured 9.5 ms on
     # the chip against 5.2 ms inline); the loop runs the pieces after it
-    zero = (jnp.zeros((4,), jnp.int32),) if held else ()
+    zero = (jnp.zeros((len(STAT_NAMES),), jnp.int32),) if held else ()
     served = serve(0, (token, states, t, emit, *zero))
     token, states, t, emit, *counted = jax.lax.fori_loop(
         1, jnp.maximum(n, 1), serve, served
@@ -867,7 +868,7 @@ def decode_boundary_donated(
     ``served`` (the host's schedule, in its order), then the decode scan.
     ``carry`` is consumed. Returns (carry, tokens [S, n_steps]); for a
     row-counting MoE model (``_moe_counted``) also the TUPLE of its
-    programs' MoE row counters ([4] each, on the device: whoever syncs next
+    programs' MoE row counters ([6] each, on the device: whoever syncs next
     sums them)."""
     counted = []
     group = model.cfg.prefill_group

@@ -85,7 +85,10 @@ and does so on the held-rows path, the one form that can leave a row out:
 it then runs its grouped product only over the tiles that hold a row, so a
 buffer that holds every pair the router can send here (``moe_ep_buffer >= router width /
 experts held``: ``dropless_overflow`` 0 by construction) costs what the held
-rows cost. :func:`stats_vector` is what the decode programs sum a boundary.
+rows cost, and counts the visits (``tiles_live``, ``experts_live``). The
+product's row tile and output block come from the call's shapes
+(:func:`serve_tiles`). :func:`stats_vector` is what the decode programs sum a
+boundary.
 """
 
 from __future__ import annotations
@@ -584,10 +587,8 @@ class MoEMLP(nn.Module):
             alive = jnp.repeat(live.reshape(-1), k)
             flat = jnp.where(alive, flat, -1)  # no expert's id: held nowhere
             routed = alive.sum().astype(jnp.int32)
+            tm, bh = serve_tiles(m, r, cfg.moe_step_tile, d, h, jnp.dtype(dt).itemsize)
         if b.startswith("pallas") and live is not None:
-            tm, bh = _SERVE_TILES
-            if m <= cfg.moe_step_tile * r:  # a step: an expert's rows are few
-                tm = cfg.moe_step_tile
             matmul = _gmm_matmul(tm, bh, b == "pallas_interpret", live_tiles=True)
         elif b.startswith("pallas") and budget >= 1024:
             matmul = _gmm_matmul(128, 512, b == "pallas_interpret")
@@ -601,6 +602,12 @@ class MoEMLP(nn.Module):
             self.sow("moe_stats", "rows_routed", routed)
             self.sow("moe_stats", "rows_held", held_counts.sum())
             self.sow("moe_stats", "rows_max_expert", held_counts.max())
+            if live is not None:
+                # the served grouped product's visits, on whichever backend:
+                # tiles / experts is how often the blocked order streams an
+                # expert, 1 - experts / tiles the visits a resident block serves
+                self.sow("moe_stats", "tiles_live", (-(-held_counts // tm)).sum())
+                self.sow("moe_stats", "experts_live", (held_counts > 0).sum())
         return y.reshape(x.shape).astype(dt)
 
     def _dropless_gmm(
@@ -911,12 +918,37 @@ def _data_shards(mesh) -> int:
 _SERVE_TILES = (128, 512)
 
 
-def _gmm_matmul(tm: int, bh: int, interpret: bool, live_tiles: bool = False):
+def serve_tiles(m: int, r: int, step_tile: int, d: int, h: int, itemsize: int):
+    """(row tile, output block) of the served grouped product, from a call's
+    shapes alone: ``m`` (token, expert) pairs over a router ``r`` wide, the
+    configuration's step tile, experts ``[d, h]`` of ``itemsize`` bytes. An
+    even router gives an expert ``m / r`` rows. A step (an expert's rows are
+    few) takes the small tile. A call that can give an expert a tile or more
+    (four 1,024-row pieces to a program: 256 rows) gives it several under a
+    real router, so its block is the WHOLE width, ``None``: ``gmm_live`` then
+    holds an expert's matrix across its consecutive tiles and streams it
+    once, where blocks of 512 stream it once a tile; taken only where two
+    buffers of the matrix fit the kernel's VMEM budget
+    (``gmm.live_whole_width_fits``). Every other call keeps the block of 512:
+    with one tile an expert the blocked order already reads the weights once,
+    and a whole-width block there only exposes its first fetch."""
+    from orion_tpu.ops.pallas.gmm import live_whole_width_fits
+
+    tm, bh = _SERVE_TILES
+    if m <= step_tile * r:  # a step: an expert's rows are few
+        tm = step_tile
+    if m // r >= tm and live_whole_width_fits(d, h, itemsize):
+        bh = None
+    return tm, bh
+
+
+def _gmm_matmul(tm: int, bh: Optional[int], interpret: bool, live_tiles: bool = False):
     """``_held_rows_ffn``'s matmul through the grouped-matmul kernel, which
     wants every expert's rows in whole tiles of ``tm``. ``live_tiles``: the
     forward-only form that visits the tiles up to the last segment's end and
     leaves the rows past it unwritten (``matmul.unwritten_tail``: the caller
-    masks them)."""
+    masks them); its ``bh`` may be ``None``, each product's whole width held
+    resident (:func:`serve_tiles`)."""
     from orion_tpu.ops.pallas.gmm import gmm, gmm_live
 
     def matmul(lhs, w, seg, gs):
@@ -1105,11 +1137,16 @@ def _group_size(t: int, target: int) -> int:
 
 
 # what a held layer sows into "moe_stats", in :func:`stats_vector`'s order
-STAT_NAMES = ("rows_routed", "rows_held", "rows_max_expert", "dropless_overflow")
+STAT_NAMES = (
+    "rows_routed", "rows_held", "rows_max_expert", "dropless_overflow",
+    # a SERVED layer's alone (``live``): the row tiles its grouped product
+    # visits and the experts that got a row
+    "tiles_live", "experts_live",
+)
 
 
 def stats_vector(collection) -> Array:
-    """The "moe_stats" collection of one ``apply`` as ``[4]`` int32 in
+    """The "moe_stats" collection of one ``apply`` as ``[6]`` int32 in
     :data:`STAT_NAMES`' order, each summed over the layers that sowed it."""
     total = {name: jnp.zeros((), jnp.int32) for name in STAT_NAMES}
     for path, leaf in jax.tree_util.tree_leaves_with_path(collection):
@@ -1121,5 +1158,6 @@ def stats_vector(collection) -> Array:
 
 
 __all__ = [
-    "MoEMLP", "STAT_NAMES", "masks_rows", "stats_vector", "top_k_routing", "top_k_choice",
+    "MoEMLP", "STAT_NAMES", "masks_rows", "serve_tiles", "stats_vector", "top_k_routing",
+    "top_k_choice",
 ]

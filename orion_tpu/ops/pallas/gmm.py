@@ -33,7 +33,7 @@ SURVEY.md §0).
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -234,25 +234,60 @@ def _gmm_bwd(tile_rows, block_h, interpret, res, dy):
 gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
+# [7,680, 512] bf16 weight blocks, double-buffered, pass the 16 MB default
+_LIVE_VMEM_BYTES = 64 << 20
+# what a whole-width weight block may take of that, double-buffered: a quarter,
+# so the x and out tiles and the fp32 product have the rest ([2048, 1024] bf16
+# = 8 MB fits; [7,680, 2,048] = 63 MB does not)
+_LIVE_WHOLE_WIDTH_BYTES = _LIVE_VMEM_BYTES // 4
+
+
+def live_whole_width_fits(d: int, h: int, itemsize: int) -> bool:
+    """May :func:`gmm_live` hold an expert's whole ``[d, h]`` matrix as ONE
+    block (two buffers of it) beside its tiles?"""
+    return 2 * d * h * itemsize <= _LIVE_WHOLE_WIDTH_BYTES
+
+
 @kernel_entry("gmm_live", "tile_rows", "block_h", "interpret")
 def gmm_live(
     x: Array, w: Array, group_sizes: Array, tile_rows: int = 128,
-    block_h: int = 512, interpret: bool = False,
+    block_h: Optional[int] = 512, interpret: bool = False,
 ) -> Array:
     """:func:`gmm`'s forward over the row tiles that HOLD a segment only: the
     grid's first dimension is ``sum(group_sizes) / tile_rows``, read at run
     time, so a buffer sized for the worst router costs what the rows in it
     cost (the serving path of ``models/moe.py::_dropless_held``). Rows past
-    the last segment are NOT written: the caller masks them. Row tiles are
-    the outer dimension (a dynamic bound leads the grid) and an x tile stays
-    put while its expert's output blocks sweep; with the one or two tiles an
-    expert has at serving sizes the weights are still read about once. No
-    backward."""
+    the last segment are NOT written: the caller masks them. No backward.
+
+    What is read how often. Row tiles are the outer dimension (a dynamic
+    bound leads the grid): an x tile ``[tile_rows, d]`` is fetched once and
+    stays put while its expert's output blocks sweep inside it, so with
+    ``block_h < h`` an expert's whole ``[d, h]`` matrix crosses HBM once for
+    EVERY row tile the expert has. That is the right order where an expert
+    has one tile: a decode step (512 pairs over 32 or 128 experts on tiles of
+    32 or 16: 87-95% of the chip's bandwidth, PERF.md section 5) and a lone
+    prompt piece (64 rows an expert). It is the wrong one where a call gives
+    an expert several tiles (four 1,024-row pieces to a program: 256 rows an
+    expert, ~2.6 tiles under a real router, 3.9 GB read a layer where the
+    experts are 1.6). There the caller asks for the WHOLE width as the block
+    (``block_h=None``) and an expert's matrix stays in VMEM across its
+    consecutive tiles: a call streams each live expert's weights once
+    whatever the number of its tiles, and a repeated tile moves its x and out
+    tiles alone (:func:`_live_resident`). The products are the same either
+    way: one ``dot_general`` over the whole contraction, fp32 accumulation,
+    so both forms give the same bits.
+
+    Which form a call takes is decided from shapes alone by
+    ``models/moe.py::serve_tiles`` (the pairs over the router's width against
+    the row tile, and :func:`live_whole_width_fits`), in ``_dropless_held``:
+    no configuration names it."""
     m, d = x.shape
     _, _, h = w.shape
     assert m % tile_rows == 0, (m, tile_rows)
     te = tile_expert_table(group_sizes, m // tile_rows, tile_rows)
     live = (jnp.sum(group_sizes) // tile_rows).astype(jnp.int32)
+    if block_h is None:
+        return _live_resident(x, w.astype(x.dtype), group_sizes, te, live, tile_rows, interpret)
     # a block of whole lanes that divides the width, where there is one: a
     # padded copy of the weights costs their bytes again at every call
     # (experts 768 wide under blocks of 512: 19% of a boundary's busy time)
@@ -282,8 +317,70 @@ def gmm_live(
     return out[:, :h] if hp != h else out
 
 
-# [7,680, 512] bf16 weight blocks, double-buffered, pass the 16 MB default
-_LIVE_VMEM_BYTES = 64 << 20
+def _resident_kernel(te_ref, next_ref, slot_ref, x_ref, w_hbm, o_ref, w_vmem, sem):
+    i = pl.program_id(0)
+    expert, slot = te_ref[i], slot_ref[i]
+    first = jnp.logical_or(i == 0, expert != te_ref[jnp.maximum(i - 1, 0)])
+
+    def copy(e, s):
+        return pltpu.make_async_copy(w_hbm.at[e], w_vmem.at[s], sem.at[s])
+
+    @pl.when(i == 0)
+    def _():
+        copy(expert, slot).start()
+
+    @pl.when(first)
+    def _():
+        copy(expert, slot).wait()
+        following = next_ref[i]
+
+        @pl.when(following >= 0)
+        def _():
+            copy(following, 1 - slot).start()
+
+    o_ref[...] = jax.lax.dot_general(
+        x_ref[...], w_vmem[slot],
+        (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ).astype(o_ref.dtype)
+
+
+def _live_resident(x, w, group_sizes, te, live, tile_rows, interpret):
+    """:func:`gmm_live` with an expert's whole ``[d, h]`` matrix resident: the
+    weights stay in HBM and the kernel keeps TWO VMEM buffers of its own, the
+    current expert's and the next live expert's, whose copy it starts at the
+    current expert's FIRST tile (every expert with a row has a tile, so every
+    copy started is waited for, at its expert's first tile). A ``BlockSpec``
+    of the whole width gives the same traffic, but the pipeline fetches ONE
+    grid step ahead, so a new expert's 4 MB (5-6 us) hid behind one tile's
+    product (~3.6 us) and not behind the expert's run: 1.52 ms against 1.36
+    here at ``[49152, 2048] x [128, 2048, 1024]`` under a skewed split, 1.39
+    against 1.05 under an even one (PERF.md section 6, PR 56)."""
+    m, d = x.shape
+    e, _, h = w.shape
+    has_rows = group_sizes > 0
+    ids = jnp.where(has_rows, jnp.arange(e), e)
+    after = jnp.concatenate([jax.lax.cummin(ids, reverse=True)[1:], jnp.array([e])])
+    next_live = jnp.where(after < e, after, -1)[te].astype(jnp.int32)
+    slot = ((jnp.cumsum(has_rows) - 1) % 2)[te].astype(jnp.int32)  # experts alternate
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(live,),
+        in_specs=[
+            pl.BlockSpec((tile_rows, d), lambda i, *_: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((tile_rows, h), lambda i, *_: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((2, d, h), x.dtype), pltpu.SemaphoreType.DMA((2,))],
+    )
+    return pl.pallas_call(
+        _resident_kernel,
+        name="gmm_live",
+        out_shape=jax.ShapeDtypeStruct((m, h), x.dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_LIVE_VMEM_BYTES),
+        interpret=interpret,
+    )(te, next_live, slot, x, w)
 
 
 def pad_group_sizes(counts: Array, tile_rows: int) -> Tuple[Array, Array]:
@@ -294,4 +391,6 @@ def pad_group_sizes(counts: Array, tile_rows: int) -> Tuple[Array, Array]:
     return seg.astype(jnp.int32), starts.astype(jnp.int32)
 
 
-__all__ = ["gmm", "gmm_live", "pad_group_sizes", "tile_expert_table"]
+__all__ = [
+    "gmm", "gmm_live", "live_whole_width_fits", "pad_group_sizes", "tile_expert_table",
+]

@@ -101,6 +101,7 @@ from orion_tpu.generate import (
     reprefill_carry,
 )
 from orion_tpu.models.mixers import MIXERS
+from orion_tpu.models.moe import STAT_NAMES
 from orion_tpu.models.transformer import (
     decode_state_finite_per_slot,
     extract_decode_slot,
@@ -164,8 +165,8 @@ def _slot_flags(states, done) -> Array:
 
 @jax.jit
 def _counted_flags(states, done, counted) -> Array:
-    """[2 slots + 4] int32: the finite mask, the done flags and the
-    boundary's MoE row counters (``counted``: the [4] vectors its programs
+    """[2 slots + 6] int32: the finite mask, the done flags and the
+    boundary's MoE row counters (``counted``: the [6] vectors its programs
     returned, ``models/moe.py::STAT_NAMES``, summed here) — a row-counting MoE
     model's whole host readback, still ONE device transfer a boundary."""
     return jnp.concatenate([
@@ -606,10 +607,11 @@ class SlotEngine:
         self.max_pending_prefixes = 32
         self.dropped_prefixes = 0  # lifetime counted drops
         # a row-counting MoE model (``moe.masks_rows``): the MoE row counters
-        # of the boundary just probed ([routed, held, busiest expert's, dropped], summed over its
-        # pieces, steps and layers; ``models/moe.py::STAT_NAMES``), read with
-        # the probe's own transfer; zeros for every other model
-        self.moe_rows = np.zeros((4,), np.int64)
+        # of the boundary just probed ([routed, held, busiest expert's, dropped,
+        # row tiles visited, experts with a row], summed over its pieces,
+        # steps and layers; ``models/moe.py::STAT_NAMES``), read with the
+        # probe's own transfer; zeros for every other model
+        self.moe_rows = np.zeros((len(STAT_NAMES),), np.int64)
         self._moe_counted: Tuple[Array, ...] = ()
         self._moe_zero: Optional[Array] = None
         self._sample: Optional[SampleConfig] = None  # set by first admit
@@ -1494,7 +1496,7 @@ class SlotEngine:
         inject.fire("serve.chunk", step=self._chunk_counter)
         finished: List[Tuple[Any, DecodeResult]] = []
         self.last_boundary = []
-        self.moe_rows = np.zeros((4,), np.int64)
+        self.moe_rows = np.zeros((len(STAT_NAMES),), np.int64)
         # deadlines are checked BEFORE paying for the chunk
         now = self._clock()
         for i, slot in enumerate(self._slots):
@@ -1757,7 +1759,7 @@ class SlotEngine:
         warm = None if donate else self._warm_boundary_exec(kind, seen_key)
         accepted = None
         # the programs of a model whose MoE layers count their rows
-        # (``moe.masks_rows``) also return those counters, [4] on the device
+        # (``moe.masks_rows``) also return those counters, [6] on the device
         # (one vector, or the donated boundary's tuple of them):
         # ``_probe_bad`` reads them
         counted = ()
@@ -1845,12 +1847,12 @@ class SlotEngine:
         reject decision never costs a second readback. So do the MoE row
         counters of a model whose layers count (``moe.masks_rows``;
         ``moe_rows``: the boundary's [routed, held, busiest expert's,
-        dropped], the programs' vectors padded to one length so that one
-        program sums them)."""
+        dropped, tiles visited, experts with a row], the programs' vectors
+        padded to one length so that one program sums them)."""
         if self._moe_counted:
             pad = 1 + prefill_piece_cap(self.slots, self.chunk) - len(self._moe_counted)
             if self._moe_zero is None:
-                self._moe_zero = jnp.zeros((4,), jnp.int32)
+                self._moe_zero = jnp.zeros((len(STAT_NAMES),), jnp.int32)
             flags = np.asarray(_counted_flags(
                 carry[1], carry[4], self._moe_counted + (self._moe_zero,) * pad
             ))

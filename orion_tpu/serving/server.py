@@ -173,9 +173,12 @@ _SSM_KEYS = ("ssm_row_steps", "ssm_piece_rows")
 # (``SlotEngine.moe_rows``; 0 for a model without such a layer): (token,
 # expert) pairs the router sent out for rows that count / those whose expert
 # is held here / the busiest held expert's, a layer and call / rows dropped
-# past the buffer (0 by construction where the buffer holds every pair)
+# past the buffer (0 by construction where the buffer holds every pair) / the
+# row tiles the grouped product visits / the experts that got a row (tiles /
+# experts: how often an output-blocked product streams an expert's weights)
 _MOE_KEYS = (
     "moe_rows_routed", "moe_rows_held", "moe_rows_max_expert", "moe_rows_dropped",
+    "moe_tiles_live", "moe_experts_live",
 )
 
 

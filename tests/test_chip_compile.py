@@ -199,16 +199,19 @@ def _gmm_live(x, w, sizes):
     return gmm_live(x, w, sizes, tile_rows=128, block_h=512)
 
 
-def _gmm_live_tiled(tile_rows):
+def _gmm_live_tiled(tile_rows, block_h=512):
     def fn(x, w, sizes):
         from orion_tpu.ops.pallas.gmm import gmm_live
 
-        return gmm_live(x, w, sizes, tile_rows=tile_rows, block_h=512)
+        return gmm_live(x, w, sizes, tile_rows=tile_rows, block_h=block_h)
 
     return fn
 
 
 _gmm_live_step, _gmm_live_tile32 = _gmm_live_tiled(16), _gmm_live_tiled(32)
+# the whole output width held resident: an expert's [d, h] stays in VMEM
+# across its consecutive row tiles (``models/moe.py::serve_tiles``)
+_gmm_live_whole = _gmm_live_tiled(128, None)
 
 
 def _index_scores(qi, w, ki):
@@ -387,6 +390,8 @@ _GMM_LIVE_STEP_DOWN = [((2560, 1024), jnp.bfloat16), ((128, 1024, 2048), jnp.bfl
                        ((128,), jnp.int32)]
 _GMM_LIVE_GROUP = [((49152, 2048), jnp.bfloat16), ((128, 2048, 1024), jnp.bfloat16),
                    ((128,), jnp.int32)]
+_GMM_LIVE_GROUP_DOWN = [((49152, 1024), jnp.bfloat16), ((128, 1024, 2048), jnp.bfloat16),
+                        ((128,), jnp.int32)]
 _RING_STEP = [((64, 32, 128), jnp.bfloat16),
               *[((64, 4, 2048, 128), jnp.bfloat16)] * 2,
               ((64,), jnp.int32), ((64,), jnp.bool_)]
@@ -488,6 +493,9 @@ KERNELS = [
     pytest.param(_gmm_live_step, _GMM_LIVE_STEP_UP, id="gmm_live-tile16-held128-2048x1024"),
     pytest.param(_gmm_live_step, _GMM_LIVE_STEP_DOWN, id="gmm_live-tile16-held128-1024x2048"),
     pytest.param(_gmm_live, _GMM_LIVE_GROUP, id="gmm_live-group4-held128-2048x1024"),
+    pytest.param(_gmm_live_whole, _GMM_LIVE_GROUP, id="gmm_live-group4-whole-width-2048x1024"),
+    pytest.param(_gmm_live_whole, _GMM_LIVE_GROUP_DOWN,
+                 id="gmm_live-group4-whole-width-1024x2048"),
     pytest.param(_bare_conv, _BARE_CONV, id="short_conv-bare-3taps-tail-piece1024"),
     pytest.param(_gmm_live_tile32, _GMM_LIVE_1792_UP, id="gmm_live-tile32-held32-2048x1792"),
     pytest.param(_gmm_live_tile32, _GMM_LIVE_1792_DOWN, id="gmm_live-tile32-held32-1792x2048"),
@@ -957,7 +965,7 @@ def test_keye_vl2_boundary_programs_hold_the_carry_once(v5e):
 
 
 @slow
-def test_trinity_mini_boundary_programs_hold_the_carry_once(v5e):
+def test_trinity_mini_boundary_programs_hold_the_carry_once(v5e, monkeypatch):
     """``trinity_mini.serve_mixed``'s programs at 64 slots x 17,408 for the
     chip, the carry donated: four slots' 1,024-token prompt pieces in one
     program (``prefill_group``) and the decode scan, whose grouped product
@@ -969,13 +977,20 @@ def test_trinity_mini_boundary_programs_hold_the_carry_once(v5e):
     as it lies (XLA's slice update had them relaid at every step, 1.09 GB of
     temporaries); the ring's step, the ring's piece and the growing cache's
     piece are the Mosaic kernels by their names, and both programs run the
-    128 held experts through the grouped product over live tiles. A compile,
-    not a chip run."""
+    128 held experts through the grouped product over live tiles: the group's
+    with each product's WHOLE width as its block (PR 56: 256 rows an expert
+    on an even router, so an expert's weights stay in VMEM across its tiles),
+    the scan's in blocks of 512 on 16-row tiles as before. A compile, not a
+    chip run."""
     from orion_tpu import generate as gen
     from orion_tpu.generate import SampleConfig
     from orion_tpu.models.configs import get_config
     from orion_tpu.models.transformer import TransformerLM, init_decode_state
+    from orion_tpu.ops.pallas import gmm as gmm_mod
 
+    blocks, real = {}, gmm_mod.gmm_live
+    monkeypatch.setattr(gmm_mod, "gmm_live", lambda x, w, gs, tm, bh, interpret: (
+        blocks.setdefault((x.shape, w.shape[1:]), (tm, bh)), real(x, w, gs, tm, bh, interpret))[1])
     slots, chunk, piece, width = 64, 8, 1024, 16384
     cfg = dataclasses.replace(get_config("trinity_mini"), backend="pallas")
     model = TransformerLM(cfg)
@@ -995,6 +1010,10 @@ def test_trinity_mini_boundary_programs_hold_the_carry_once(v5e):
             model, params, carry, rngs, pbuf, ints, ints, scalar, piece, sample),
         "scan": gen._decode_scan_donated_jit.lower(
             model, params, carry, rngs, flags, ints, chunk, sample),
+    }
+    assert blocks == {
+        ((49152, 2048), (2048, 1024)): (128, None), ((49152, 1024), (1024, 2048)): (128, None),
+        ((2560, 2048), (2048, 1024)): (16, 512), ((2560, 1024), (1024, 2048)): (16, 512),
     }
     kernels = {"piece": ("window_piece_attention", "full_piece_attention"),
                "scan": ("window_step_attention", "ring_row_write", "cache_attention")}

@@ -908,6 +908,37 @@ class TestGmm:
         dw = jax.grad(lambda w: gmm(x, w, seg, tm, 16, True).sum())(w)
         assert np.abs(np.asarray(dw[1])).max() == 0.0
 
+    @pytest.mark.parametrize("form", ["blocked", "whole"])
+    @pytest.mark.parametrize("h", [1024, 1792, 768])
+    @pytest.mark.parametrize("split", ["skewed", "even", "gaps"])
+    def test_gmm_live_matches_per_row(self, split, h, form):
+        """``gmm_live`` against a plain per-row product: experts of 0, 1, 3
+        and 10 tiles in one call (``skewed``), four tiles each, or experts
+        without a row between and after those with some (``gaps``: the next
+        LIVE expert's weights are what the resident form fetches ahead);
+        widths that blocks of 512 divide (1,024), that take a smaller block
+        (1,792: 256; 768: 384), the output blocked or the whole width one
+        block (an expert's weights then stay put over its consecutive tiles). The
+        buffer is longer than the live rows and its tail is NaN: the rows past
+        ``live`` are never visited, and the caller's to mask. Both forms are
+        one product over the whole contraction, so they give the same bits
+        (small whole numbers here, which fp32 sums exactly in any order:
+        XLA:CPU picks another matmul routine at another block width)."""
+        from orion_tpu.ops.pallas.gmm import gmm_live
+
+        tm, d = 8, 32
+        tiles = {"skewed": [0, 1, 3, 10], "even": [4, 4, 4, 4], "gaps": [2, 0, 0, 5, 0]}[split]
+        seg = jnp.asarray([t * tm for t in tiles], jnp.int32)
+        rows = int(seg.sum())
+        x = jnp.round(3 * jax.random.normal(jax.random.PRNGKey(6), (rows + 3 * tm, d)))
+        x = x.at[rows:].set(jnp.nan)
+        w = jnp.round(3 * jax.random.normal(jax.random.PRNGKey(7), (len(tiles), d, h)))
+        blocked = gmm_live(x, w, seg, tm, 512, True)[:rows]
+        got = blocked if form == "blocked" else gmm_live(x, w, seg, tm, None, True)[:rows]
+        assert got.shape == (rows, h) and bool(jnp.isfinite(got).all())
+        np.testing.assert_array_equal(np.asarray(got), self._ref(x[:rows], w, seg))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(blocked))
+
     def test_dropless_gmm_matches_ragged_path(self, monkeypatch):
         """The gmm-backed dropless MoE layer == the ragged_dot path,
         values AND grads (same params, same router). The input is above
@@ -951,6 +982,101 @@ class TestGmm:
             gg, gr,
         )
         assert calls, "the gmm branch was never taken — threshold changed?"
+
+
+# -- the served grouped product: its blocks from shapes, its visits counted -----
+
+# the six calls of the four committed mixture cells (PERF.md section 6, PR 56):
+# preset, the call's tokens (a group of four 1,024-row pieces, a lone piece, a
+# step's slots) -> (row tile, output block; None = each product's whole width)
+_SERVE_CALLS = {
+    "trinity_mini-group-of-4-pieces": ("trinity_mini", 4096, (128, None)),
+    "trinity_mini-step": ("trinity_mini", 64, (16, 512)),
+    "lfm2_8b_a1b-piece-of-512": ("lfm2_8b_a1b", 512, (128, 512)),
+    "lfm2_8b_a1b-step": ("lfm2_8b_a1b", 128, (32, 512)),
+    "keye_vl_2_0_30b_a3b-piece-of-1024": ("keye_vl_2_0_30b_a3b", 1024, (128, 512)),
+    "openpangu_ultra_moe_718b-piece-of-1024": ("openpangu_ultra_moe_718b", 1024, (128, 512)),
+}
+
+
+@pytest.mark.parametrize("call", sorted(_SERVE_CALLS))
+def test_served_blocks_follow_from_the_calls_shapes(call):
+    """``serve_tiles`` at the committed cells' calls: the whole-width block
+    is asked for by ONE program family, ``trinity_mini``'s group of four
+    pieces (256 rows an expert on an even router), and by nothing a preset
+    says: a later preset, or a change of the rule, that moves another cell's
+    block shows here. What the engaged kernel holds in VMEM is under its
+    limit; ``openpangu_ultra_moe_718b``'s ``[7680, 2048]`` experts would not
+    be, however many rows a call gave them."""
+    from orion_tpu.models.configs import get_config
+    from orion_tpu.models.moe import serve_tiles
+    from orion_tpu.ops.pallas import gmm
+
+    preset, tokens, want = _SERVE_CALLS[call]
+    cfg = get_config(preset)
+    r, d, h = cfg.resolved_router_width, cfg.d_model, cfg.resolved_moe_hidden
+    shapes = (r, cfg.moe_step_tile, d, h, 2)
+    assert serve_tiles(tokens * cfg.moe_top_k, *shapes) == want
+    if want[1] is None:
+        assert (d, h) == (2048, 1024)
+        blocks = 2 * 2 * (d * h + want[0] * d + want[0] * h) + 4 * want[0] * max(d, h)
+        assert 2 * 2 * d * h == 8 << 20 <= gmm._LIVE_WHOLE_WIDTH_BYTES
+        assert blocks < gmm._LIVE_VMEM_BYTES // 4
+    if preset == "openpangu_ultra_moe_718b":
+        assert serve_tiles(64 * tokens * cfg.moe_top_k, *shapes) == (128, 512)
+        assert not gmm.live_whole_width_fits(d, h, 2)
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
+def test_served_layer_counts_its_tiles_and_experts(backend):
+    """A held layer handed ``live`` sows ``tiles_live`` and ``experts_live``
+    (from the per-expert counts it had: ``sum(ceil(counts / tile))`` and
+    ``sum(counts > 0)``, the tile the served product's on either backend),
+    ``stats_vector`` carries them in ``STAT_NAMES``' order and the server's
+    counters name them; a layer that is not served (training) sows neither."""
+    from orion_tpu.models.moe import STAT_NAMES, serve_tiles, stats_vector
+    from orion_tpu.serving.server import _MOE_KEYS
+
+    cfg = ModelConfig(
+        name="t", d_model=32, n_experts=8, moe_top_k=2, moe_hidden=128, mlp="swiglu",
+        moe_dropless=True, moe_step_tile=4, dtype="float32", param_dtype="float32",
+        backend=backend,
+    )
+    layer = MoEMLP(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(8), (1, 96, 32))
+    params = jax.jit(layer.init)(jax.random.PRNGKey(9), x)
+    live = jnp.arange(96)[None, :] < 90
+    y, sown = jax.jit(
+        lambda p, x, live: layer.apply(p, x, live, mutable=["moe_stats"])
+    )(params, x, live)
+    vec = np.asarray(stats_vector(sown["moe_stats"]))
+    stats = dict(zip(STAT_NAMES, vec))
+    # 192 pairs over 8 experts is past the step tile's 32: tiles of 128 rows,
+    # and 24 rows an expert are under one, so the block stays 512
+    assert serve_tiles(192, 8, 4, 32, 128, 4) == (128, 512)
+    assert stats["rows_routed"] == stats["rows_held"] == 180
+    assert 1 <= stats["experts_live"] <= 8 and stats["tiles_live"] == stats["experts_live"]
+    assert STAT_NAMES[4:] == ("tiles_live", "experts_live")
+    assert _MOE_KEYS[4:] == ("moe_tiles_live", "moe_experts_live") and len(_MOE_KEYS) == len(vec)
+    # a step: 8 rows x top-2 = 16 pairs on tiles of 4
+    ys, sown = layer.apply(params, x[:, :8], live[:, :8], mutable=["moe_stats"])
+    logits = np.asarray(x[0, :8]) @ np.asarray(params["params"]["router"]["kernel"])
+    counts = np.bincount(np.argsort(-logits, axis=1)[:, :2].reshape(-1), minlength=8)
+    step = dict(zip(STAT_NAMES, np.asarray(stats_vector(sown["moe_stats"]))))
+    assert step["tiles_live"] == int(np.ceil(counts / 4).sum())
+    assert step["experts_live"] == int((counts > 0).sum())
+    _, sown = layer.apply(params, x, mutable=["losses", "moe_stats"])
+    assert "moe_stats" not in sown
+    # 512 rows: 128 an expert on an even router, so the layer asks for the
+    # whole width held resident, and gives what the plain form gives
+    big = jax.random.normal(jax.random.PRNGKey(10), (1, 512, 32))
+    assert serve_tiles(1024, 8, 4, 32, 128, 4) == (128, None)
+    served = lambda cfg: jax.jit(lambda p, x: MoEMLP(cfg).apply(  # noqa: E731
+        p, x, jnp.ones(x.shape[:2], bool), mutable=["moe_stats"]))(params, big)
+    (got, sown), (want, _) = served(cfg), served(dataclasses.replace(cfg, backend="xla"))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
+    many = dict(zip(STAT_NAMES, np.asarray(stats_vector(sown["moe_stats"]))))
+    assert many["tiles_live"] > many["experts_live"] == 8
 
 
 def test_moe_overflow_metric_surfaces_in_trainer():

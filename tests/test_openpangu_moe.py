@@ -317,7 +317,7 @@ def test_engine_serves_as_generate(model_params, backend, donate):
                for i, n in enumerate((5, 20, 37))]
     for i, pr in enumerate(prompts):
         eng.admit(DecodeRequest(prompt=pr, max_new_tokens=9, sample=GREEDY, seed=i), tag=i)
-    done, rows = {}, np.zeros(4, np.int64)
+    done, rows = {}, np.zeros(len(STAT_NAMES), np.int64)
     while eng.busy:
         done.update(dict(eng.step()))
         rows += eng.moe_rows
@@ -332,6 +332,8 @@ def test_engine_serves_as_generate(model_params, backend, donate):
     # 9 tokens are 3 chunks of 4, the first from the piece's last row)
     assert rows[0] == 2 * 4 * (5 + 20 + 37 + 3 * 12)
     assert 0 < rows[2] <= rows[1] < rows[0] and rows[3] == 0
+    # the grouped product's visits: an expert with a row has a tile or more
+    assert 0 < rows[5] <= rows[4] <= rows[1]
     assert eng.kv_rows()[1] == 4 * cfg.max_seq_len
     assert eng.held_bytes["kv_bytes"] == 3 * 4 * cfg.max_seq_len * (16 + 8) * 4
 

@@ -439,6 +439,10 @@ def test_the_classes_say_what_a_slot_holds():
 # ``hybrid_1b3`` in tests/test_granite_hybrid.py, where ``hybrid_1b3``'s piece
 # changed with this PR and says why.) A PR that changes one of these programs
 # on purpose reads the new value from the assertion and replaces it here.
+# PR 56 did, for the two mixtures' piece and step: a served held layer now sows
+# ``tiles_live`` and ``experts_live`` (two scalar sums of the counts it had,
+# seven equations a layer; the diff of the jaxpr text against the parent's
+# holds nothing else), and at these widths no call takes the whole-width block.
 _PRESETS = {
     "openpangu_ultra_moe_718b": dict(
         vocab_size=256, d_model=64, n_layers=3, layer_types=("latent",) * 3, n_heads=4, head_dim=24,
@@ -457,11 +461,11 @@ _PRESETS = {
 }
 _TRACED = {
     "openpangu_ultra_moe_718b.forward": "1f29899ffe7fe667",
-    "openpangu_ultra_moe_718b.piece": "d049e4f1bd02cc3d",
-    "openpangu_ultra_moe_718b.step": "1d7fa84247cfb9bb",
+    "openpangu_ultra_moe_718b.piece": "787bb6ca7e979b3e",
+    "openpangu_ultra_moe_718b.step": "9f9a9594f33b461f",
     "keye_vl_2_0_30b_a3b.forward": "a731e1d746341ad8",
-    "keye_vl_2_0_30b_a3b.piece": "edc1733dc245c09d",
-    "keye_vl_2_0_30b_a3b.step": "d2053fd057058bea",
+    "keye_vl_2_0_30b_a3b.piece": "40b4fe50520885e3",
+    "keye_vl_2_0_30b_a3b.step": "8c89c16d05a18f1b",
     "granite_4_0_h_micro.forward": "d3b8ef058abe58e9",
     "granite_4_0_h_micro.piece": "72ba2232c8e4fee1",
     "granite_4_0_h_micro.step": "26b3f5ecbf5d81f0",
