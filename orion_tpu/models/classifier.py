@@ -37,7 +37,7 @@ class LRAClassifier(nn.Module):
         )
         self.blocks = [
             Block(
-                cfg, lt, causal=False, use_moe=cfg.moe_at(i), name=f"block_{i}"
+                cfg, lt, causal=False, name=f"block_{i}", **cfg.block_form(i)
             )
             for i, lt in enumerate(cfg.resolved_layer_types)
         ]

@@ -12,7 +12,7 @@ CLI. Configs are plain frozen dataclasses overridable via
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 
 def hybrid_pattern(n_layers: int, period: int = 4) -> Tuple[str, ...]:
@@ -33,7 +33,15 @@ class ModelConfig:
     n_heads: int = 4
     head_dim: Optional[int] = None  # default d_model // n_heads
     mlp_hidden: Optional[int] = None  # default 4*d_model (gelu) / 8/3 (swiglu)
-    mlp: str = "swiglu"  # "swiglu" | "gelu"
+    # "swiglu": three matrices, silu(gate) * up; "gelu" and "relu2" (the
+    # squared ReLU, max(x, 0)^2): two matrices, no gate. Dense layers, routed
+    # experts and the shared expert all take the one form
+    mlp: str = "swiglu"  # "swiglu" | "gelu" | "relu2"
+    # a comma list of the blocks (0-based) that are a mixer ALONE: x +
+    # mixer(norm1(x)) and nothing after it, no ``norm2`` and no feed-forward
+    # part (a published layer that is one residual step, where its neighbour
+    # has no feed-forward step to pair with). "": every block has both
+    mixer_only: str = ""
     # "rmsnorm_zero": zero-centred RMSNorm, x * rsqrt(mean x^2 + 1e-6) *
     # (1 + w) with w initialised 0
     norm: str = "rmsnorm"  # "rmsnorm" | "layernorm" | "rmsnorm_zero"
@@ -149,7 +157,11 @@ class ModelConfig:
     # -- "ssm" layers (models/mixers/ssm.py): a state-space layer with a
     # scalar decay per head that depends on the token, S_t = exp(dt_t A_h)
     # S_{t-1} + dt_t x_t B_t^T, ssm_heads heads of ssm_head_dim x ssm_state;
-    # B_t and C_t are shared by the ssm_heads / ssm_groups heads of a group;
+    # B_t and C_t are shared by the ssm_heads / ssm_groups heads of a group,
+    # and the gated norm before the output projection is taken over each
+    # group's channels (one norm over all of them at ssm_groups 1): the one
+    # field fixes both groupings, so several B / C groups under a SINGLE norm
+    # over all channels cannot be said (no model served here is of that form);
     # x, B and C pass a causal depthwise conv (ssm_conv_width taps, a bias)
     ssm_heads: int = 0
     ssm_head_dim: int = 0
@@ -239,6 +251,12 @@ class ModelConfig:
     moe_hidden: int = 0
     # the first moe_first_dense blocks keep a dense MLP whatever moe_period
     moe_first_dense: int = 0
+    # > 0: the routed experts work in a latent of this width: u W_dn (d_model
+    # -> moe_latent) goes in, the experts are [moe_latent, moe_hidden] and
+    # back, and their weighted sum passes W_up (moe_latent -> d_model); the
+    # router and the shared expert read the block's own input. No bias, norm
+    # or activation on either projection. Dropless layers of one device
+    moe_latent: int = 0
     # the held-rows serving path's row tile where a call brings an expert at
     # most this many rows on average (a decode step: slots x k pairs over the
     # whole router): the buffer is padded by a tile an expert, so at 128 a
@@ -268,13 +286,25 @@ class ModelConfig:
             return max(128, (h + 127) // 128 * 128)
         return 4 * self.d_model
 
+    def has_mlp(self, layer: int) -> bool:
+        """Does block ``layer`` (0-based) have a feed-forward part?"""
+        return str(layer) not in self.mixer_only.split(",")
+
     def moe_at(self, layer: int) -> bool:
         """Does block ``layer`` (0-based) carry a routed-expert MLP?"""
         return (
             self.n_experts > 0
             and layer >= self.moe_first_dense
             and (layer + 1) % self.moe_period == 0
+            and self.has_mlp(layer)
         )
+
+    def block_form(self, layer: int) -> Dict[str, bool]:
+        """What every builder of block ``layer`` hands ``Block`` beside its
+        mixer's type (``Block(cfg, kind, ..., **cfg.block_form(i))``): the
+        one place that says which feed-forward part a block has, so no
+        builder can leave a part of the rule out."""
+        return {"use_moe": self.moe_at(layer), "mixer_only": not self.has_mlp(layer)}
 
     @property
     def moe_held(self) -> bool:
@@ -803,6 +833,93 @@ LFM2_8B_A1B = ModelConfig(
     param_dtype="bfloat16",
 )
 
+def step_pattern_blocks(pattern: str) -> Tuple[Tuple[str, ...], str]:
+    """A published pattern of ONE residual step a layer (``M`` a state-space
+    mixer, ``*`` full attention, ``E`` a feed-forward step) as this repo's
+    blocks -> (``layer_types``, ``mixer_only``): a mixer and the ``E`` after
+    it are one block, a mixer with no ``E`` after it a block that is a mixer
+    alone. The mathematics is the same: ``x + F(N(x))`` twice is ``h = x +
+    Mix(N1 x); y = h + F(N2 h)``. An ``E`` with no mixer before it has no
+    block here and is refused."""
+    kinds, alone = [], []
+    steps = list(pattern)
+    while steps:
+        letter = steps.pop(0)
+        assert letter in "M*", f"a feed-forward step with no mixer before it: {pattern!r}"
+        kinds.append({"M": "ssm", "*": "softmax"}[letter])
+        if steps and steps[0] == "E":
+            steps.pop(0)
+        else:
+            alone.append(str(len(kinds) - 1))
+    return tuple(kinds), ",".join(alone)
+
+
+_NEMOTRON_STAGE_0 = step_pattern_blocks("MEMEMEM*EME")
+
+NEMOTRON_3_SUPER_120B = ModelConfig(
+    # NVIDIA-Nemotron-3-Super-120B-A12B at its published widths, as chip 0 of
+    # the first of eight pipeline stages whose layers four chips share
+    # (benchmark/configs/nemotron_3_super_120b.json states the source, the
+    # cut and what is assumed): the published layers 0-10, MEMEMEM*EME, as six
+    # blocks, (ssm, experts) x 3, ssm alone, (attention, experts), (ssm,
+    # experts). The state-space layers are 128 heads of 64 x 128 in 8 groups
+    # (a biased conv of 4 taps over 10,240 channels, the gate before a norm
+    # over each group's 1,024 channels); the attention layer 32 query heads
+    # over 2 KV heads x 128 with no position term; the experts work in a
+    # 1,024-wide latent: a sigmoid top-22 router over 512 with a selection
+    # bias, renormalised over the chosen x 5, 128 squared-ReLU experts of
+    # 2,688 held (two matrices each), beside an ungated squared-ReLU shared
+    # expert of 5,376 on the full width; untied head over 32,768 of the
+    # 131,072 vocabulary rows; served in bfloat16.
+    name="nemotron_3_super_120b",
+    vocab_size=32768,
+    d_model=4096,
+    n_layers=6,
+    layer_types=_NEMOTRON_STAGE_0[0],
+    mixer_only=_NEMOTRON_STAGE_0[1],
+    n_heads=32,
+    n_kv_heads=2,
+    head_dim=128,
+    rotary=False,
+    ssm_heads=128,
+    ssm_head_dim=64,
+    ssm_state=128,
+    ssm_groups=8,
+    ssm_conv_width=4,
+    norm="rmsnorm",
+    norm_eps=1e-5,
+    pos_embed="none",
+    tie_embeddings=False,
+    mlp="relu2",
+    moe_hidden=2688,
+    moe_latent=1024,
+    moe_shared_hidden=5376,
+    moe_shared_gated=False,
+    moe_period=1,
+    n_experts=128,
+    moe_router_width=512,
+    moe_expert_offset=0,
+    moe_top_k=22,
+    moe_score="sigmoid",
+    moe_route_scale=5.0,
+    moe_route_bias=0.02,
+    moe_gate_eps=1e-20,
+    moe_dropless=True,
+    # the held rows' buffer holds every pair the router can send here
+    # (router width / experts held x their even share): nothing can drop
+    moe_ep_buffer=4.0,
+    moe_step_tile=16,
+    # prefill_group stays 1: four slots' pieces in one program had XLA relay
+    # every layer's 0.54 GB state whole, slots minor, around their write-back
+    # (6% of the cell's busy time, and 7% of its tokens/s against a piece a
+    # program: PERF.md section 6, PR 57); a piece a program updates a slot's
+    # row where the state lies
+    param_init_dtype="float32",
+    max_seq_len=4096,
+    dtype="bfloat16",
+    param_dtype="bfloat16",
+)
+
 LRA_LISTOPS_LINEAR = ModelConfig(
     name="lra_listops_linear",
     vocab_size=32,  # digits + operators + specials
@@ -856,6 +973,7 @@ CONFIGS = {
         KEYE_VL_2_0_30B_A3B,
         TRINITY_MINI,
         LFM2_8B_A1B,
+        NEMOTRON_3_SUPER_120B,
         LRA_LISTOPS_LINEAR,
         LRA_LISTOPS_SOFTMAX,
         LRA_TEXT_LINEAR,
@@ -873,5 +991,6 @@ def get_config(name: str, **overrides) -> ModelConfig:
 
 __all__ = [
     "ModelConfig", "CONFIGS", "get_config", "hybrid_pattern",
-    "gated_pattern", "delta_full_pattern", "decay_sparse_pattern", "ssm_full_pattern", "conv_full_pattern", "F32_MATMUL_SCOPES", "LAYER_TYPES",
+    "gated_pattern", "delta_full_pattern", "decay_sparse_pattern", "ssm_full_pattern", "conv_full_pattern",
+    "step_pattern_blocks", "F32_MATMUL_SCOPES", "LAYER_TYPES",
 ]

@@ -48,6 +48,13 @@ def drawn_kernel_init(cfg: ModelConfig) -> dict:
     return {"kernel_init": drawn_in(cfg, nn.linear.default_kernel_init)}
 
 
+def ungated_activation(mlp: str):
+    """The activation of a two-matrix feed-forward form (``cfg.mlp`` other
+    than "swiglu"): "gelu", or "relu2", the squared ReLU ``max(x, 0)^2``."""
+    assert mlp in ("gelu", "relu2"), mlp
+    return jax.nn.gelu if mlp == "gelu" else lambda v: jnp.square(jax.nn.relu(v))
+
+
 def _dense_factory(cfg: ModelConfig, quant: str = "", mesh=None):
     """``(name, features) -> module``: the bias-free projection every layer
     uses, or its weight-streamed form in the decode modes. "int8": every

@@ -7,14 +7,18 @@ groups (``cfg.ssm_groups``):
     xBC_t = silu(b + sum_j w_j xBC_{t-(W-1)+j})     causal, depthwise, biased
     dt_t  = softplus(dt_t + dt_bias);   A = -exp(A_log)
     S_t^h = exp(dt_t^h A_h) S_{t-1}^h + dt_t^h x_t^h B_t^T;  y_t^h = S_t^h C_t + D_h x_t^h
-    out   = W_out( rms(merge(y_t) * silu(z_t)) * w_norm )
+    out   = W_out( rms_g(merge(y_t) * silu(z_t)) * w_norm )
 
 ``B_t``, ``C_t`` [N] are one pair for the ``H / G`` heads of a group; the
-gate multiplies BEFORE the one norm over the merged ``d_inner`` (eps
-``cfg.norm_eps``); ``dt`` is not clamped.
+gate multiplies BEFORE the norm, which is taken over EACH GROUP's ``d_inner
+/ G`` merged channels (``rms_g``, eps ``cfg.norm_eps``, one ``[d_inner]``
+weight: the family's gated norm; at ``G = 1`` it is one norm over all of
+``d_inner``); ``dt`` is not clamped.
 
 Served, the decode state is ``{"s": [B, H / k, N, k P] fp32, "conv": [B, (W
-- 1) x channels]}``: the recurrence's state as ``ops/ssm.py::pack_state``
+- 1) x channels]}`` (128 heads of 64 x 128 in 8 groups: ``k`` = 2, ``S [B,
+64, 128, 128]``, 4.19 MB a row a layer, which the step kernel takes whole;
+64 heads in one group: 2.1 MB): the recurrence's state as ``ops/ssm.py::pack_state``
 holds it (``k`` heads of a group side by side on lanes) and the conv's last
 ``W - 1`` PRE-conv ``xBC`` rows, oldest first, side by side (as
 ``gated_delta.py`` holds its own). The prompt and its pieces go through
@@ -26,8 +30,9 @@ unlisted row is selected back). The training forward is the same chunked
 form: autodiff of it is the gradient. Speculative decode is not built for
 this mixer: the base class's raise.
 
-The plain reference it is tested against is ``benchmark/reference/
-plain_granite_hybrid.py``, which reads the same parameter layout:
+The plain references it is tested against are ``benchmark/reference/
+plain_granite_hybrid.py`` (one group) and ``plain_nemotron_h.py`` (eight),
+which read the same parameter layout:
 ``in_proj`` columns are ``[z | x | B | C | dt]``, ``conv`` is ``[width,
 channels]`` over the ``[x | B | C]`` channels with row ``width - 1`` on the
 current token, ``conv_bias`` [channels].
@@ -148,15 +153,20 @@ class StateSpace(Mixer):
 
     def _output(self, y: Array, xh: Array, z: Array) -> Array:
         """y, xh [..., H, P], z [..., H P] -> the layer's output [..., D]:
-        the skip, the gate, then one norm over the merged heads."""
+        the skip, the gate, then a norm over each group's merged heads (one
+        norm over all of them where there is one group)."""
         f32 = jnp.float32
+        g = self.cfg.ssm_groups
+        # a reshape to the shape an array has is no operation: one group
+        # traces as the one norm it is
+        by_group = z.shape[:-1] + ((g, -1) if g > 1 else (-1,))
         with scope("ssm_gate_norm"):
             y = y.astype(f32) + self.d_skip.astype(f32)[:, None] * xh.astype(f32)
-            y = y.reshape(z.shape) * jax.nn.silu(z.astype(f32))
+            y = (y.reshape(z.shape) * jax.nn.silu(z.astype(f32))).reshape(by_group)
             y = y * jax.lax.rsqrt(
                 jnp.mean(jnp.square(y), -1, keepdims=True) + self.cfg.norm_eps
             )
-            y = (y * self.out_norm.astype(f32)).astype(_dtype(self.cfg.dtype))
+            y = (y.reshape(z.shape) * self.out_norm.astype(f32)).astype(_dtype(self.cfg.dtype))
         return self.wo(y)
 
     def _conv(self, pre: Array, tail: Optional[Array]) -> Array:

@@ -76,6 +76,20 @@ per-expert fp32 buffer ``router_bias`` to the scores for the CHOICE of the
 top-k alone: the weights are the chosen experts' scores without it (forward
 only: the balancing rule that would move the buffer is not built).
 
+An expert takes one of two FORMS, by ``cfg.mlp`` (:func:`expert_form`): three
+matrices with a gate, ``down(silu(gate x) * up x)`` ("swiglu"), or two without,
+``down(act(up x))`` ("gelu"; "relu2", the squared ReLU): two grouped products
+a layer and not three. Routed experts, the shared expert and every dispatch
+path take either. ``moe_latent`` > 0 puts the routed experts in a LATENT of
+that width (dropless layers of one device): ``latent_down`` (``d_model ->
+moe_latent``) before them and ``latent_up`` after their weighted sum, both
+under the ``moe_latent`` scope, no bias, norm or activation; the expert
+stacks are ``[E, moe_latent, h]`` and ``[E, h, moe_latent]`` and everything
+buffer-sized on the held-rows path (the rows taken into the buffer, the
+combine's gather) is that wide; the router and the shared expert read the
+block's own input. A share of such a layer up-projects its own partial sum:
+the projection is linear, so the shares' outputs add to the whole layer's.
+
 The SERVING methods hand the layer ``live`` (``[B]`` at a decode step, ``[B,
 T]`` in a prompt piece): a row outside it (a slot that is not emitting, a
 piece's padding) routes nowhere and counts nowhere. Every dropless layer
@@ -101,7 +115,7 @@ import jax
 import jax.numpy as jnp
 
 from orion_tpu.models.configs import ModelConfig
-from orion_tpu.models.mixers import drawn_in, drawn_kernel_init
+from orion_tpu.models.mixers import drawn_in, drawn_kernel_init, ungated_activation
 from orion_tpu.utils.profiling import scope
 
 Array = jax.Array
@@ -114,14 +128,25 @@ def _dtype(name: str):
 def masks_rows(cfg: ModelConfig, quant: str = "", mesh: Any = None) -> bool:
     """Do this model's MoE layers honour the serving methods' ``live`` row
     mask and count their rows (``MoEMLP._dropless_held``)? The dropless
-    SwiGLU layer of ONE device does, whether it holds a share of its router's
-    experts or all of them; a capacity layer, an int8 one and a layer spread
-    over a mesh have no such form. The serving programs ask this to know
-    whether to hand ``live`` over and sum the counters (generate.py)."""
+    layer of ONE device does, whether it holds a share of its router's
+    experts or all of them, and whichever form its experts take (three
+    matrices with a gate or two without: :func:`expert_form`); a capacity
+    layer, an int8 one and a layer spread over a mesh have no such form. The
+    serving programs ask this to know whether to hand ``live`` over and sum
+    the counters (generate.py)."""
     return bool(
-        cfg.n_experts and cfg.moe_dropless and not quant and cfg.mlp == "swiglu"
+        cfg.n_experts and cfg.moe_dropless and not quant
         and (mesh is None or mesh.devices.size == 1)
     )
+
+
+def expert_form(cfg: ModelConfig):
+    """(the names of an expert's matrices, its activation) by ``cfg.mlp``:
+    gate, up, down with ``silu(gate) * up`` ("swiglu"), or up, down with the
+    activation on ``up`` alone ("gelu", "relu2")."""
+    if cfg.mlp == "swiglu":
+        return ("gate", "up", "down"), jax.nn.silu
+    return ("up", "down"), ungated_activation(cfg.mlp)
 
 
 def _expert_init(in_axis: int = -2):
@@ -212,33 +237,58 @@ class MoEMLP(nn.Module):
         cfg = self.cfg
         if live is not None and not masks_rows(cfg, self.quant, self.mesh):
             live = None
+        xe, route_on = x, None
+        if cfg.moe_latent:
+            # the experts work in the latent; the router reads the block's
+            # own input (``route_on``), as the shared expert does
+            assert cfg.moe_dropless and not self.quant, "the latent is the dropless layer's"
+            xe, route_on = self._latent("latent_down", cfg.moe_latent, x), x
         if cfg.moe_held or live is not None:
-            y = self._dropless_held(x, live)
+            y = self._dropless_held(xe, live, route_on)
         else:
-            y = self._routed(x)
+            y = self._routed(xe, route_on)
+        if cfg.moe_latent:
+            y = self._latent("latent_up", x.shape[-1], y)
         if cfg.moe_shared_hidden:
             y = y + self._shared(x)
         return y
 
-    def _shared(self, x: Array) -> Array:
-        """The shared expert every token passes, scaled by sigmoid(w . x)."""
+    def _latent(self, name: str, features: int, x: Array) -> Array:
+        """One of the two projections around the routed experts (``d_model
+        -> moe_latent`` before them, back after their weighted sum): no bias,
+        norm or activation."""
         cfg = self.cfg
-        assert not self.quant and cfg.mlp == "swiglu", (self.quant, cfg.mlp)
+        with scope("moe_latent"):
+            return nn.Dense(
+                features, use_bias=False, dtype=_dtype(cfg.dtype),
+                param_dtype=_dtype(cfg.param_dtype), name=name, **drawn_kernel_init(cfg)
+            )(x)
+
+    def _shared(self, x: Array) -> Array:
+        """The shared expert every token passes, in the experts' own form
+        (:func:`expert_form`: ``shared_gate`` exists where they are gated),
+        scaled by sigmoid(w . x) unless ``moe_shared_gated`` is off."""
+        cfg = self.cfg
+        assert not self.quant, self.quant
+        names, act = expert_form(cfg)
         dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
         init = drawn_kernel_init(cfg)
         dense = lambda n, feats: nn.Dense(  # noqa: E731
             feats, use_bias=False, dtype=dt, param_dtype=pdt, name=n, **init
         )
         with scope("moe_shared"):
-            mid = jax.nn.silu(dense("shared_gate", cfg.moe_shared_hidden)(x)) * dense(
-                "shared_up", cfg.moe_shared_hidden
-            )(x)
+            if len(names) == 3:
+                mid = act(dense("shared_gate", cfg.moe_shared_hidden)(x)) * dense(
+                    "shared_up", cfg.moe_shared_hidden
+                )(x)
+            else:
+                mid = act(dense("shared_up", cfg.moe_shared_hidden)(x))
             if not cfg.moe_shared_gated:
                 return dense("shared_down", x.shape[-1])(mid)
             gate = jax.nn.sigmoid(dense("shared_scale", 1)(x).astype(jnp.float32))
             return dense("shared_down", x.shape[-1])(mid) * gate.astype(dt)
 
-    def _routed(self, x: Array) -> Array:
+    def _routed(self, x: Array, route_on: Optional[Array] = None) -> Array:
         cfg = self.cfg
         dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
         e, k, h = cfg.n_experts, cfg.moe_top_k, cfg.resolved_moe_hidden
@@ -247,7 +297,8 @@ class MoEMLP(nn.Module):
         assert 1 <= k <= e, f"moe_top_k={k} must be in [1, n_experts={e}]"
         d = x.shape[-1]
         if cfg.moe_dropless:
-            return self._dropless(x)
+            return self._dropless(x, route_on)
+        act = expert_form(cfg)[1]
         assert not cfg.moe_route_bias, "the selection bias is the dropless router's"
         single = x.ndim == 2  # decode: [B, D]
         if single:
@@ -316,7 +367,7 @@ class MoEMLP(nn.Module):
                     "gecd,edh->gech", xe, wu, bs
                 )
             else:
-                mid = jax.nn.gelu(qein("gecd,edh->gech", xe, wu, bs))
+                mid = act(qein("gecd,edh->gech", xe, wu, bs))
             ye = qein("gech,ehd->gecd", mid, wdn, bs)
             ye = self._ep_constraint(ye)
             y = jnp.einsum("gecd,gsec->gsd", ye, combine.astype(dt))
@@ -336,7 +387,7 @@ class MoEMLP(nn.Module):
             up = jnp.einsum("gecd,edh->gech", xe, wu.astype(dt))
             mid = jax.nn.silu(gt) * up
         else:
-            mid = jax.nn.gelu(jnp.einsum("gecd,edh->gech", xe, wu.astype(dt)))
+            mid = act(jnp.einsum("gecd,edh->gech", xe, wu.astype(dt)))
         ye = jnp.einsum("gech,ehd->gecd", mid, wdn.astype(dt))
         ye = self._ep_constraint(ye)
         y = jnp.einsum("gecd,gsec->gsd", ye, combine.astype(dt))
@@ -391,7 +442,7 @@ class MoEMLP(nn.Module):
             cfg.moe_aux_weight * aux + cfg.moe_zloss_weight * z,
         )
 
-    def _dropless(self, x: Array) -> Array:
+    def _dropless(self, x: Array, route_on: Optional[Array] = None) -> Array:
         """Dropless dispatch (SURVEY §7 r2 carry; VERDICT r2 #5): tokens are
         sorted by routed expert and run through ``jax.lax.ragged_dot`` —
         static shapes, exactly the routed FLOPs, and EVERY token reaches
@@ -404,13 +455,19 @@ class MoEMLP(nn.Module):
         contention, a token's output depends only on its own features.
 
         ep meshes route to ``_dropless_ep`` (static-budget sharded form);
-        this body is the single-host (dp/fsdp/tp) path.
+        this body is the single-host (dp/fsdp/tp) path, the one that takes
+        ``route_on`` (the router's input where it is not the experts':
+        ``moe_latent``).
         """
         cfg = self.cfg
         dt, pdt = _dtype(cfg.dtype), _dtype(cfg.param_dtype)
         e, k, h = cfg.n_experts, cfg.moe_top_k, cfg.resolved_moe_hidden
         d = x.shape[-1]
+        act = expert_form(cfg)[1]
         ep = 1 if self.mesh is None else self.mesh.shape.get("ep", 1)
+        assert route_on is None or self.mesh is None or self.mesh.devices.size == 1, (
+            "experts in a latent are one device's layer: no exchange is built for it"
+        )
         if ep > 1:
             # r3 VERDICT #3: the exact path and the scalable path were
             # disjoint — _dropless_ep removes the single-host assert
@@ -471,7 +528,9 @@ class MoEMLP(nn.Module):
         x2 = x.reshape(-1, d)
         n = x2.shape[0]
 
-        logits, probs, ids, gates = self._route_flat(x2)
+        logits, probs, ids, gates = self._route_flat(
+            x2 if route_on is None else route_on.reshape(n, -1)
+        )
         self._sow_flat_aux(logits, probs, ids)
 
         flat = ids.reshape(-1)  # [N*k], token-major
@@ -515,7 +574,7 @@ class MoEMLP(nn.Module):
                     "experts_up", (e, d, h), h, xs
                 )
             else:
-                mid = jax.nn.gelu(qrd("experts_up", (e, d, h), h, xs))
+                mid = act(qrd("experts_up", (e, d, h), h, xs))
             ys = qrd("experts_down", (e, h, d), d, mid)
         else:
             if cfg.mlp == "swiglu":
@@ -531,14 +590,16 @@ class MoEMLP(nn.Module):
             if cfg.mlp == "swiglu":
                 mid = jax.nn.silu(rd(xs, wg)) * rd(xs, wu)
             else:
-                mid = jax.nn.gelu(rd(xs, wu))
+                mid = act(rd(xs, wu))
             ys = rd(mid, wdn)
 
         y = jnp.take(ys, inv, axis=0).reshape(n, k, d)
         y = jnp.sum(y * gates[..., None].astype(dt), axis=1)
         return y.reshape(x.shape).astype(dt)
 
-    def _dropless_held(self, x: Array, live: Optional[Array] = None) -> Array:
+    def _dropless_held(
+        self, x: Array, live: Optional[Array] = None, route_on: Optional[Array] = None
+    ) -> Array:
         """The dropless layer of ONE chip of an expert-parallel group: the
         router is ``moe_router_width`` wide and picks its top-k over all of
         it, renormalised over all k as published; of the chosen (token,
@@ -556,9 +617,12 @@ class MoEMLP(nn.Module):
         can be); rows past it are dropped and COUNTED. ``live`` (the serving
         methods' row mask): the pairs of a row outside it belong to no
         expert and to no counter, and the grouped product visits only the
-        tiles that hold a row."""
+        tiles that hold a row. The experts take either form (:func:`expert_form`:
+        three grouped products a layer or two). ``route_on``: the router's
+        input where it is not the experts' (``moe_latent``: ``x`` is then the
+        latent, and everything buffer-sized here is that wide)."""
         cfg = self.cfg
-        assert cfg.moe_dropless and not self.quant and cfg.mlp == "swiglu"
+        assert cfg.moe_dropless and not self.quant
         assert self.mesh is None or self.mesh.devices.size == 1, (
             "the held-experts layer is one chip's share; it has no exchange"
         )
@@ -575,12 +639,17 @@ class MoEMLP(nn.Module):
 
         b = resolve(cfg.backend)
         with scope("moe_route"):
-            logits, probs, ids, gates = self._route_flat(x2)
+            logits, probs, ids, gates = self._route_flat(
+                x2 if route_on is None else route_on.reshape(x2.shape[0], -1)
+            )
             self._sow_flat_aux(logits, probs, ids)
+        names, act = expert_form(cfg)
         ws = tuple(
-            self.param(name, drawn_in(cfg, _expert_init()), shape, pdt)
-            for name, shape in (("experts_gate", (e, d, h)), ("experts_up", (e, d, h)),
-                                ("experts_down", (e, h, d)))
+            self.param(
+                f"experts_{name}", drawn_in(cfg, _expert_init()),
+                (e, h, d) if name == "down" else (e, d, h), pdt,
+            )
+            for name in names
         )
         flat, routed = ids.reshape(-1), jnp.asarray(m, jnp.int32)
         if live is not None:
@@ -595,7 +664,7 @@ class MoEMLP(nn.Module):
         else:
             matmul = _ragged_matmul
         y, held_counts, dropped = _held_rows_ffn(
-            x2, flat, gates.reshape(-1), ws, lo, budget, matmul, dt
+            x2, flat, gates.reshape(-1), ws, lo, budget, matmul, dt, act
         )
         if not self.is_initializing():
             self.sow("moe_stats", "dropless_overflow", dropped)
@@ -649,7 +718,7 @@ class MoEMLP(nn.Module):
             )
         else:
             wu = self.param("experts_up", _expert_init(), (e, d, h), pdt)
-            mid = jax.nn.gelu(gmm(xs, wu, seg, tm, bh, interpret))
+            mid = expert_form(cfg)[1](gmm(xs, wu, seg, tm, bh, interpret))
         wdn = self.param("experts_down", _expert_init(), (e, h, d), pdt)
         ys = gmm(mid, wdn, seg, tm, bh, interpret)  # [M2, d]
 
@@ -732,7 +801,7 @@ class MoEMLP(nn.Module):
                 ) * jax.lax.ragged_dot(xs, aug(wul), gs)
             else:
                 wul, wdl = ws
-                mid = jax.nn.gelu(jax.lax.ragged_dot(xs, aug(wul), gs))
+                mid = expert_form(cfg)[1](jax.lax.ragged_dot(xs, aug(wul), gs))
             ys = jax.lax.ragged_dot(mid, aug(wdl), gs)  # [B, d]
             part = jnp.zeros((m, d), dt).at[sel].set(ys)
             part = jax.lax.psum(part, "ep")
@@ -849,7 +918,7 @@ class MoEMLP(nn.Module):
                     jax.lax.pcast(w, row_axes, to="varying") for w in ws
                 )
             y, _, dropped = _held_rows_ffn(
-                xl, flat, gl, ws, lo, budget, matmul, dt
+                xl, flat, gl, ws, lo, budget, matmul, dt, expert_form(cfg)[1]
             )
             return (
                 jax.lax.psum(y, "ep"),  # [n_loc, d]
@@ -974,7 +1043,7 @@ _ragged_matmul.tile = 1
 _ragged_matmul.unwritten_tail = False
 
 
-def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt):
+def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt, act=jax.nn.silu):
     """What the experts held here add to each token: the ONE sort-and-matmul
     body of the expert-parallel forms (an ep shard of ``_dropless_ep_gmm``,
     where ``lo`` is the shard's first expert, and ``_dropless_held``, one
@@ -982,7 +1051,9 @@ def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt):
 
     ``x2 [N, d]``; ``flat``, ``gates`` ``[M = N k]`` token-major (pair ``p``
     is slot ``p % k`` of token ``p // k``); ``ws`` the held experts' stacks
-    ``[El, ...]`` (gate, up, down or up, down), experts ``[lo, lo + El)``.
+    ``[El, ...]`` (gate, up, down or up, down) and ``act`` their activation
+    (:func:`expert_form`; the default is the gated three's), experts ``[lo,
+    lo + El)``.
     Returns (``y [N, d]`` fp32, rows per held expert ``[El]``, rows dropped).
 
     Held rows are counting-sorted by local expert (every other expert is one
@@ -1046,9 +1117,9 @@ def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt):
             xs = jnp.where(valid[:, None], jnp.take(x2.astype(dt), token, axis=0), 0)
         mm = lambda lhs, w: matmul(lhs, w.astype(dt), seg, gs)  # noqa: E731
         if len(ws) == 3:
-            mid = jax.nn.silu(mm(xs, ws[0])) * mm(xs, ws[1])
+            mid = act(mm(xs, ws[0])) * mm(xs, ws[1])
         else:
-            mid = jax.nn.gelu(mm(xs, ws[0]))
+            mid = act(mm(xs, ws[0]))
         ys = mm(mid, ws[-1])  # [M2, d]
         if matmul.unwritten_tail:
             return _gather_combine(
@@ -1158,6 +1229,6 @@ def stats_vector(collection) -> Array:
 
 
 __all__ = [
-    "MoEMLP", "STAT_NAMES", "masks_rows", "serve_tiles", "stats_vector", "top_k_routing",
+    "MoEMLP", "STAT_NAMES", "expert_form", "masks_rows", "serve_tiles", "stats_vector", "top_k_routing",
     "top_k_choice",
 ]

@@ -34,6 +34,7 @@ from orion_tpu.models.mixers import (
     _dense_factory,
     _dtype,
     drawn_in,
+    ungated_activation,
 )
 
 Array = jax.Array
@@ -67,7 +68,7 @@ class MLP(nn.Module):
             up = dense("up", h)(x)
             y = jax.nn.silu(gate) * up
         else:
-            y = jax.nn.gelu(dense("up", h)(x))
+            y = ungated_activation(cfg.mlp)(dense("up", h)(x))
         return dense("down", cfg.d_model)(y)
 
 
@@ -80,7 +81,10 @@ class Block(nn.Module):
 
     ``use_moe`` swaps the dense MLP for the routed-expert MoEMLP
     (models/moe.py, ep-sharded); same name "mlp" so one sharding rule set
-    covers both layouts."""
+    covers both layouts. ``mixer_only`` (``cfg.mixer_only`` names the
+    blocks): the block is its first residual step alone, with no ``norm2``,
+    no ``mlp`` and no ``post_norm2`` among its parameters; every method
+    returns after the mixer's residual add."""
 
     cfg: ModelConfig
     layer_type: str
@@ -90,6 +94,7 @@ class Block(nn.Module):
     use_moe: bool = False
     quant: str = ""
     sp_local_kernels: bool = False
+    mixer_only: bool = False
 
     def setup(self):
         self.norm1 = _norm(self.cfg, "norm1")
@@ -98,9 +103,14 @@ class Block(nn.Module):
             self.sp_local, quant=self.quant,
             sp_local_kernels=self.sp_local_kernels, name="attn"
         )
-        self.norm2 = _norm(self.cfg, "norm2")
+        self.drop = nn.Dropout(self.cfg.dropout)
         if self.cfg.norm_placement == "sandwich":
             self.post_norm1 = _norm(self.cfg, "post_norm1")
+        if self.mixer_only:
+            assert not self.use_moe, "a block that is a mixer alone has no experts"
+            return
+        self.norm2 = _norm(self.cfg, "norm2")
+        if self.cfg.norm_placement == "sandwich":
             self.post_norm2 = _norm(self.cfg, "post_norm2")
         if self.use_moe:
             from orion_tpu.models.moe import MoEMLP
@@ -112,7 +122,6 @@ class Block(nn.Module):
             self.mlp = MLP(
                 self.cfg, quant=self.quant, mesh=self.mesh, name="mlp"
             )
-        self.drop = nn.Dropout(self.cfg.dropout)
 
     def _sublayer(self, norm, f, x):
         """``f(norm(x))``, or ``norm(f(x))`` where the configuration
@@ -138,7 +147,10 @@ class Block(nn.Module):
 
     def _mlp_residual(self, x, live=None):
         """``live``: the serving methods' row mask, which a routed-expert MLP
-        takes (models/moe.py); a dense MLP has no use for it."""
+        takes (models/moe.py); a dense MLP has no use for it. A block that is
+        a mixer alone ends before this step."""
+        if self.mixer_only:
+            return x
         mlp = self.mlp if live is None or not self.use_moe else (
             lambda y: self.mlp(y, live)
         )
@@ -149,6 +161,8 @@ class Block(nn.Module):
             self._branch(self._sublayer(self.norm1, lambda y: self.attn(y, mask), x)),
             deterministic=deterministic,
         )
+        if self.mixer_only:
+            return x
         x = x + self.drop(
             self._branch(self._sublayer(self.norm2, self.mlp, x)),
             deterministic=deterministic,
@@ -252,7 +266,7 @@ class TransformerLM(nn.Module):
         self.blocks = [
             (block_cls if i < first_remat else Block)(
                 cfg, lt, True, self.mesh,
-                use_moe=cfg.moe_at(i), quant=self.quant, name=f"block_{i}",
+                quant=self.quant, name=f"block_{i}", **cfg.block_form(i),
             )
             for i, lt in enumerate(cfg.resolved_layer_types)
         ]

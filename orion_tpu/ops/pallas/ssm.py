@@ -17,7 +17,10 @@ wrapper): the shapes of ``decode_state.py``'s delta-rule step, so nothing
 in the kernel reduces across lanes. ``S`` is aliased in place and the output
 onto ``u``: an unlisted row keeps its state's bits and reads back its ``u``
 row, finite and the same on every replay. All on the VPU: a row moves 2 MB
-each way for 1.5 MFLOP.
+each way for 1.5 MFLOP (64 heads; 4.19 MB and 3 MFLOP at 128). With several
+groups the wrapper repeats each group's ``B`` and ``C`` for the ``H / (k
+G)`` packed rows of the group (``packed_step_operands``): eight pairs a row
+become 64 sublane rows of ``N``, 64 KB beside the 4 MB of state.
 
 reference: none (the reference has no state-space layer; checkout never
 mounted, SURVEY.md s0).
@@ -37,8 +40,12 @@ from orion_tpu.ops.ssm import packed_step_operands
 
 Array = jax.Array
 
-# a row's S block is 2 MB at 32 x 128 x 128, double-buffered in and out,
-# beside the kernel's own intermediates of that size
+# a row's S block is 2 MB at 32 x 128 x 128 (64 heads in one group) and 4.19
+# MB at 64 x 128 x 128 (128 heads in 8 groups, two of a group side by side):
+# double-buffered in and out that is 8 or 16.8 MB, beside the kernel's own
+# intermediates of a row's size (the updated S and S * C before its sum). The
+# larger compiles for a v5e under this limit (tests/test_chip_compile.py), so
+# a row is not split over head groups
 _VMEM_BYTES = 64 << 20
 
 

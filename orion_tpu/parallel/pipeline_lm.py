@@ -45,13 +45,15 @@ Array = jax.Array
 
 def stage_group(cfg) -> int:
     """Smallest period g such that the BLOCK-STRUCTURE pattern — layer type
-    AND MoE-vs-dense MLP — repeats with period g and g divides n_layers.
+    AND which feed-forward part the block has (routed experts, a dense MLP or
+    none: ``cfg.block_form``) — repeats with period g and g divides n_layers.
     Blocks are stacked in GROUPS of g — a group's param structure is then
     identical across depth even for heterogeneous patterns (e.g. the 7B's
     swa,swa,swa,linear × 8 has g=4; an every-other-layer MoE has g=2),
     which is what lets such models pipeline. Homogeneous models get g=1."""
     sig = [
-        (lt, cfg.moe_at(i)) for i, lt in enumerate(cfg.resolved_layer_types)
+        (lt, *cfg.block_form(i).values())
+        for i, lt in enumerate(cfg.resolved_layer_types)
     ]
     n = len(sig)
     for g in range(1, n):
@@ -168,7 +170,7 @@ def pp_lm_logits(
     blocks = [
         Block(
             cfg, cfg.resolved_layer_types[j], True, None, sp_on,
-            use_moe=cfg.moe_at(j), sp_local_kernels=bool(full_manual),
+            sp_local_kernels=bool(full_manual), **cfg.block_form(j),
         )
         for j in range(g)
     ]

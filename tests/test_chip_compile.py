@@ -424,6 +424,27 @@ _LATENT_PIECE = [*[((1, 128, 1024, 256), jnp.bfloat16)] * 2,
 _GMM = [((24576, 2048), jnp.bfloat16), ((4, 2048, 5504), jnp.bfloat16),
         ((4,), jnp.int32)]
 
+# nemotron_3_super_120b.serve_batch (128 slots x 4,096): the state-space step
+# of 128 heads in 8 groups as it is held (two heads of a group side by side:
+# a 4.19 MB row a grid step, in and out double-buffered under the kernel's
+# 64 MB); a step's 2,816 pairs in a buffer of 4,864 rows on 16-row tiles
+# through 128 experts of 1,024 x 2,688 (21 x 128 wide: blocks of 384, not 512)
+# and back; a 512-row piece's 11,264 pairs in 27,648 rows; a token's
+# 32-head query a slot over a 2-head cache of 128-wide rows, 16 query heads a
+# KV head
+_SSM_STATE_8G = [((128, 64, 128, 128), jnp.float32), ((128, 128, 64), jnp.bfloat16),
+                 ((128, 128), jnp.float32), ((128,), jnp.float32),
+                 *[((128, 8, 128), jnp.bfloat16)] * 2, ((128,), jnp.bool_)]
+_GMM_LIVE_LATENT_UP = [((4864, 1024), jnp.bfloat16), ((128, 1024, 2688), jnp.bfloat16),
+                       ((128,), jnp.int32)]
+_GMM_LIVE_LATENT_DOWN = [((4864, 2688), jnp.bfloat16), ((128, 2688, 1024), jnp.bfloat16),
+                         ((128,), jnp.int32)]
+_GMM_LIVE_LATENT_PIECE = [((27648, 1024), jnp.bfloat16), ((128, 1024, 2688), jnp.bfloat16),
+                          ((128,), jnp.int32)]
+_KV_16_TO_1 = [((128, 32, 128), jnp.bfloat16),
+               *[((128, 2, 4096, 128), jnp.bfloat16)] * 2,
+               ((128,), jnp.int32), ((128,), jnp.bool_)]
+
 slow = pytest.mark.slow
 KERNELS = [
     # -- on chip_smoke.py's path: the quick tier -----------------------------
@@ -505,6 +526,11 @@ KERNELS = [
     pytest.param(_ring_write, _RING_WRITE, id="ring_row_write-64slots-ring2048"),
     pytest.param(_window_piece, _WINDOW_PIECE, id="window_piece_attention-piece1024-ring2048"),
     pytest.param(_full_piece, _FULL_PIECE, id="full_piece_attention-piece1024-17408keys"),
+    pytest.param(_ssm_step, _SSM_STATE_8G, id="ssm_state_step-8groups-4MB-rows-128slots"),
+    pytest.param(_gmm_live_step, _GMM_LIVE_LATENT_UP, id="gmm_live-tile16-held128-1024x2688"),
+    pytest.param(_gmm_live_step, _GMM_LIVE_LATENT_DOWN, id="gmm_live-tile16-held128-2688x1024"),
+    pytest.param(_gmm_live, _GMM_LIVE_LATENT_PIECE, id="gmm_live-piece512-held128-1024x2688"),
+    pytest.param(_cache_attention, _KV_16_TO_1, id="cache_attention-16-to-1-128slots"),
     pytest.param(_moe_rows, _MOE_ROWS, id="moe_rows-65536tokens-131072rows-fwd"),
     pytest.param(
         jax.grad(lambda *a: _f32sum(_moe_rows(*a)), argnums=(0, 1)),
@@ -1135,6 +1161,76 @@ def test_lfm2_scan_does_not_fit_the_chip_past_128_slots(v5e, slots, gib):
     used = re.search(r"[Uu]sed ([0-9.]+)G of ([0-9.]+)G", said)
     assert used and float(used.group(2)) == 15.75, said[:600]
     assert abs(float(used.group(1)) - gib) < 0.3, said[:600]
+
+
+@slow
+def test_nemotron_boundary_programs_hold_the_carry_once(v5e, monkeypatch):
+    """``nemotron_3_super_120b.serve_batch``'s programs at 128 slots x 4,096
+    for the chip, all six blocks, the carry donated: one slot's 512-token
+    prompt piece (``prefill_group`` 1: a group's write-back had the compiler
+    relay every layer's state whole) and the decode scan of 8 steps on 16-row
+    tiles (``moe_step_tile``). Each fits the chip with its
+    arguments (weights 9.30 GB + five 4.19 MB state rows, five conv tails and
+    one grouped cache a slot, 3.26 GB as counted) and aliases the carry. The
+    scan steps the state in the row kernel at its 4 MB rows and attends by row
+    list; both run the 128 held experts' TWO products a layer through the
+    grouped product over live tiles, in the 1,024-wide latent: blocks of 512
+    for the way back and of 384 for the way in (2,688 = 7 x 384). The block
+    without a feed-forward part passes through both. A compile, not a chip
+    run."""
+    from orion_tpu import generate as gen
+    from orion_tpu.generate import SampleConfig
+    from orion_tpu.models.configs import get_config
+    from orion_tpu.models.transformer import TransformerLM, init_decode_state
+    from orion_tpu.ops.pallas import gmm as gmm_mod
+    from orion_tpu.serving.batching import tree_nbytes
+
+    blocks, real = {}, gmm_mod.gmm_live
+    monkeypatch.setattr(gmm_mod, "gmm_live", lambda x, w, gs, tm, bh, interpret: (
+        blocks.setdefault((x.shape, w.shape[1:]), (tm, bh)), real(x, w, gs, tm, bh, interpret))[1])
+    slots, chunk, piece, width = 128, 8, 512, 2048
+    cfg = dataclasses.replace(get_config("nemotron_3_super_120b"), backend="pallas")
+    assert cfg.prefill_group == 1 and cfg.moe_step_tile == 16
+    model = TransformerLM(cfg)
+    one = SingleDeviceSharding(v5e[0])
+    put = lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one)  # noqa: E731
+    arr = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
+    params = jax.tree.map(put, jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 16), jnp.int32))))
+    assert sum(l.size for l in jax.tree.leaves(params)) == 4648163712
+    states = jax.tree.map(put, jax.eval_shape(lambda: init_decode_state(cfg, slots)))
+    assert tree_nbytes(states) == 3260547072
+    ints, flags = arr((slots,), jnp.int32), arr((slots,), jnp.bool_)
+    carry = (ints, states, ints, ints, flags)
+    rngs, pbuf = arr((slots, 2), jnp.uint32), arr((slots, width), jnp.int32)
+    scalar, sample = arr((), jnp.int32), SampleConfig(temperature=0.0)
+    programs = {
+        "piece": gen._prefill_piece_donated_jit.lower(
+            model, params, carry, rngs, pbuf, ints, ints, scalar, piece, sample),
+        "scan": gen._decode_scan_donated_jit.lower(
+            model, params, carry, rngs, flags, ints, chunk, sample),
+    }
+    assert blocks == {
+        ((27648, 1024), (1024, 2688)): (128, 512), ((27648, 2688), (2688, 1024)): (128, 512),
+        ((4864, 1024), (1024, 2688)): (16, 512), ((4864, 2688), (2688, 1024)): (16, 512),
+    }
+    kernels = {"piece": ("full_piece_attention",), "scan": ("ssm_state_step", "cache_attention")}
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        m = compiled.memory_analysis()
+        live = (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes)
+        assert live < 14.8e9, (name, live)
+        assert m.alias_size_in_bytes > 3.2e9, (name, m.alias_size_in_bytes)
+        text = compiled.as_text()
+        assert "gmm_live" in text and "moe_latent" in text, name
+        assert "experts_gate" not in text, name  # two products a layer, not three
+        for kernel in kernels[name]:
+            assert kernel in text, (name, kernel)
+        # no layer's state (0.54 GB) copied or relaid whole, in either program
+        assert not re.search(r"f32\[128,8192,128\]\S* copy\(", text), name
+        if name == "scan":
+            assert m.temp_size_in_bytes < 0.3e9, m.temp_size_in_bytes
 
 
 def test_minicpm_sala_boundary_programs_compile_and_fit(v5e):
