@@ -652,15 +652,14 @@ class MoEMLP(nn.Module):
             for name in names
         )
         flat, routed = ids.reshape(-1), jnp.asarray(m, jnp.int32)
+        tm, bh = _TILES
         if live is not None:
             alive = jnp.repeat(live.reshape(-1), k)
             flat = jnp.where(alive, flat, -1)  # no expert's id: held nowhere
             routed = alive.sum().astype(jnp.int32)
             tm, bh = serve_tiles(m, r, cfg.moe_step_tile, d, h, jnp.dtype(dt).itemsize)
-        if b.startswith("pallas") and live is not None:
-            matmul = _gmm_matmul(tm, bh, b == "pallas_interpret", live_tiles=True)
-        elif b.startswith("pallas") and budget >= 1024:
-            matmul = _gmm_matmul(128, 512, b == "pallas_interpret")
+        if b.startswith("pallas") and (live is not None or budget >= 1024):
+            matmul = _gmm_matmul(tm, bh, b == "pallas_interpret", served=live is not None)
         else:
             matmul = _ragged_matmul
         y, held_counts, dropped = _held_rows_ffn(
@@ -677,6 +676,12 @@ class MoEMLP(nn.Module):
                 # expert, 1 - experts / tiles the visits a resident block serves
                 self.sow("moe_stats", "tiles_live", (-(-held_counts // tm)).sum())
                 self.sow("moe_stats", "experts_live", (held_counts > 0).sum())
+            else:
+                # the training product's visits (its tile on either backend):
+                # over layers x the buffer's tiles, the share of its grid that
+                # holds a row. Rows past the budget have no tile
+                kept = jnp.diff(jnp.minimum(jnp.cumsum(held_counts), budget), prepend=0)
+                self.sow("moe_stats", "tiles_live", (-(-kept // tm)).sum())
         return y.reshape(x.shape).astype(dt)
 
     def _dropless_gmm(
@@ -983,8 +988,9 @@ def _data_shards(mesh) -> int:
     return out
 
 
-# the serving path's row tile and output block of the grouped product
-_SERVE_TILES = (128, 512)
+# the held layer's row tile and output block of the grouped product: in
+# training, and in serving but for what :func:`serve_tiles` decides
+_TILES = (128, 512)
 
 
 def serve_tiles(m: int, r: int, step_tile: int, d: int, h: int, itemsize: int):
@@ -1003,7 +1009,7 @@ def serve_tiles(m: int, r: int, step_tile: int, d: int, h: int, itemsize: int):
     and a whole-width block there only exposes its first fetch."""
     from orion_tpu.ops.pallas.gmm import live_whole_width_fits
 
-    tm, bh = _SERVE_TILES
+    tm, bh = _TILES
     if m <= step_tile * r:  # a step: an expert's rows are few
         tm = step_tile
     if m // r >= tm and live_whole_width_fits(d, h, itemsize):
@@ -1011,22 +1017,25 @@ def serve_tiles(m: int, r: int, step_tile: int, d: int, h: int, itemsize: int):
     return tm, bh
 
 
-def _gmm_matmul(tm: int, bh: Optional[int], interpret: bool, live_tiles: bool = False):
+def _gmm_matmul(tm: int, bh: Optional[int], interpret: bool, served: bool = False):
     """``_held_rows_ffn``'s matmul through the grouped-matmul kernel, which
-    wants every expert's rows in whole tiles of ``tm``. ``live_tiles``: the
-    forward-only form that visits the tiles up to the last segment's end and
-    leaves the rows past it unwritten (``matmul.unwritten_tail``: the caller
-    masks them); its ``bh`` may be ``None``, each product's whole width held
-    resident (:func:`serve_tiles`)."""
+    wants every expert's rows in whole tiles of ``tm``. Either form visits the
+    tiles up to the last segment's end and leaves the rows past it unwritten
+    (``matmul.unwritten_tail``: the caller never reads them unmasked).
+    ``served``: the forward-only ``gmm_live``, whose ``bh`` may be ``None``,
+    each product's whole width held resident (:func:`serve_tiles`), and whose
+    rows move by ``jnp.take``; training's ``gmm`` has a backward and its rows
+    move by list (``matmul.by_list``)."""
     from orion_tpu.ops.pallas.gmm import gmm, gmm_live
 
     def matmul(lhs, w, seg, gs):
-        if live_tiles:
+        if served:
             return gmm_live(lhs, w, seg.astype(jnp.int32), tm, bh, interpret)
         return gmm(lhs, w, seg.astype(jnp.int32), tm, bh, interpret)
 
     matmul.tile = tm
-    matmul.unwritten_tail = live_tiles
+    matmul.unwritten_tail = True
+    matmul.by_list = not served
     matmul.interpret = interpret
     return matmul
 
@@ -1041,6 +1050,7 @@ def _ragged_matmul(lhs, w, seg, gs):
 
 _ragged_matmul.tile = 1
 _ragged_matmul.unwritten_tail = False
+_ragged_matmul.by_list = False
 
 
 def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt, act=jax.nn.silu):
@@ -1067,11 +1077,16 @@ def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt, act=jax.nn.
 
     Three forms, told apart by what ``matmul`` says of itself: the tiled
     Mosaic product in training moves the rows into the buffer and out of it
-    by list in Mosaic kernels (``ops/pallas/moe_rows.py``, with their own
-    backward); serving (``unwritten_tail``) takes them with ``jnp.take`` and
-    gathers its combine (``_gather_combine``); ``_ragged_matmul`` is the
-    plain form, ``jnp.take`` and a scatter-add, which the tests hold the
-    other two to."""
+    by list in Mosaic kernels (``by_list``: ``ops/pallas/moe_rows.py``, with
+    their own backward); serving takes them with ``jnp.take`` and gathers its
+    combine (``_gather_combine``); ``_ragged_matmul`` is the plain form,
+    ``jnp.take`` and a scatter-add, which the tests hold the other two to.
+    Both tiled products leave the rows past the last segment UNWRITTEN
+    (``unwritten_tail``), in training in every cotangent of the buffer too:
+    everything between the products works a row at a time, and the buffer is
+    read by list (the row kernels, the combine's gather), so what those rows
+    hold reaches nothing; the plain form's scatter-add reads every row under
+    a gate of 0 and needs them finite, which ``ragged_dot`` gives."""
     el, tm = ws[0].shape[0], matmul.tile
     (n, d), m = x2.shape, flat.shape[0]
     k = m // n
@@ -1094,7 +1109,7 @@ def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt, act=jax.nn.
         pair = order[jnp.clip(tight[c] + off, 0, m - 1)]
         token = pair // k
         gate_row = jnp.where(valid, gates[pair], 0.0)
-        by_list = tm > 1 and not matmul.unwritten_tail
+        by_list = matmul.by_list
         if by_list:
             from orion_tpu.ops.pallas import moe_rows
 
@@ -1121,7 +1136,7 @@ def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt, act=jax.nn.
         else:
             mid = act(mm(xs, ws[0]))
         ys = mm(mid, ws[-1])  # [M2, d]
-        if matmul.unwritten_tail:
+        if matmul.unwritten_tail and not by_list:
             return _gather_combine(
                 ys, cls, rank, gates, tight, gs, starts, n
             ), held, cum[-1] - cumc[-1]

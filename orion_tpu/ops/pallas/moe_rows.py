@@ -68,8 +68,8 @@ _VMEM_BYTES = 48 << 20
 
 def _out(shape, dtype, *operands: Array) -> jax.ShapeDtypeStruct:
     """A kernel's ``out_shape``, varying over every mesh axis an operand varies
-    over (``gmm.py::_vma_union_like``'s union, read off the types: no traced
-    operation), so that the kernels stand inside ``shard_map``'s ``check_vma``."""
+    over (the union, read off the types: no traced operation), so that the
+    kernels here and ``gmm.py``'s stand inside ``shard_map``'s ``check_vma``."""
     vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma) if vma else jax.ShapeDtypeStruct(shape, dtype)
 

@@ -272,6 +272,7 @@ MOE_STATS = {
     "rows_routed": "moe_rows_routed",
     "rows_held": "moe_rows_held",
     "rows_max_expert": "moe_rows_max_expert",
+    "tiles_live": "moe_tiles_live",
 }
 
 
@@ -295,7 +296,8 @@ def lm_loss(
     ``moe_overflow`` (rows dropped past a static budget,
     models/moe.py::_dropless_ep / _dropless_held) and the held-experts
     layer's row counters ``moe_rows_routed`` / ``moe_rows_held`` /
-    ``moe_rows_max_expert``; 0 whenever nothing sowed. The structure is
+    ``moe_rows_max_expert`` and ``moe_tiles_live``, the row tiles its grouped
+    product visits; 0 whenever nothing sowed. The structure is
     static so it can ride a grad-accumulation scan carry (ADVICE r4: the
     counter existed but had no consumer — "counted, never silent" requires
     a reader)."""
@@ -666,8 +668,10 @@ class Trainer:
             if cfg.model.resolved_router_width != cfg.model.n_experts:
                 # one chip's share of an expert-parallel layer: the rows
                 # the router sent out, those whose expert is held here,
-                # and the busiest held expert's (summed over layers)
-                for name in ("moe_rows_routed", "moe_rows_held", "moe_rows_max_expert"):
+                # the busiest held expert's, and the row tiles the grouped
+                # product visits of its buffer's (summed over layers)
+                for name in ("moe_rows_routed", "moe_rows_held", "moe_rows_max_expert",
+                             "moe_tiles_live"):
                     metrics[name] = stats[name]
         return new_state, metrics
 

@@ -297,6 +297,8 @@ _QKV_GQA = [((8, 16, 8192, 256), jnp.bfloat16)] * 3
 _QKV_SMALL = [((1, 2, 1000, 128), jnp.bfloat16)] * 3
 _GMM_HELD = [((131072, 2048), jnp.bfloat16), ((64, 2048, 512), jnp.bfloat16),
              ((64,), jnp.int32)]
+_GMM_HELD_DOWN = [((131072, 512), jnp.bfloat16), ((64, 512, 2048), jnp.bfloat16),
+                  ((64,), jnp.int32)]
 # its 65,536 tokens' rows into the 131,072-row buffer and back: x2, a gate a
 # row, the held pairs of 655,360, the row a pair has and the token a row holds
 _MOE_ROWS = [((65536, 2048), jnp.bfloat16), ((131072,), jnp.float32),
@@ -475,6 +477,13 @@ KERNELS = [
         jax.grad(lambda x, w, g: _f32sum(_gmm(x, w, g)), argnums=(0, 1)),
         _GMM_HELD, id="gmm-held64-bwd",
     ),
+    # the down product (and dx of gate and up): the grid's length is read at
+    # run time and an expert's whole matrix is the block, in all three kernels
+    pytest.param(_gmm, _GMM_HELD_DOWN, id="gmm-held64-down-fwd"),
+    pytest.param(
+        jax.grad(lambda x, w, g: _f32sum(_gmm(x, w, g)), argnums=(0, 1)),
+        _GMM_HELD_DOWN, id="gmm-held64-down-bwd",
+    ),
     pytest.param(_gated_delta, _DELTA, id="gated_delta-T8192-fwd"),
     pytest.param(
         jax.grad(lambda *a: _f32sum(_gated_delta(*a)), argnums=(0, 1, 2, 3, 4)),
@@ -597,6 +606,43 @@ def test_moe_rows_compile_inside_the_ep_region(v5e):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile().as_text()
     assert "moe_rows_gather" in text and "moe_rows_combine" in text
+
+
+@pytest.mark.parametrize("hidden", [512, 5504])
+def test_gmm_compiles_inside_the_ep_region_with_a_run_time_bound(v5e, hidden):
+    """The same ep shard's grouped products, forward and backward: their grids
+    stop at the last row tile that holds a segment, a number read on the chip,
+    and their ``out_shape``s still vary over what their operands vary over
+    under ``check_vma``. Experts ``[2048, 512]`` are held whole (the grid IS
+    the live tiles); ``[2048, 5504]`` are not (column blocks outer, the tail's
+    steps skipped)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from orion_tpu.models.configs import ModelConfig
+    from orion_tpu.models.moe import MoEMLP
+    from orion_tpu.ops.pallas.gmm import live_whole_width_fits
+    from orion_tpu.parallel.mesh import MeshConfig, make_mesh
+
+    assert live_whole_width_fits(2048, hidden, 2) == (hidden == 512)
+    mesh = make_mesh(MeshConfig(dp=2, ep=2).resolve(4), devices=v5e)
+    cfg = ModelConfig(
+        name="t", d_model=2048, n_experts=8, moe_top_k=2, moe_hidden=hidden, dtype="bfloat16",
+        moe_dropless=True, moe_ep_buffer=2.0, backend="pallas",
+    )
+    layer = MoEMLP(cfg, mesh=mesh)
+    put = lambda l, spec: jax.ShapeDtypeStruct(  # noqa: E731
+        l.shape, l.dtype, sharding=NamedSharding(mesh, spec))
+    params = jax.tree.map(
+        lambda l: put(l, P("ep", None, None) if l.ndim == 3 else P()),
+        jax.eval_shape(lambda: layer.init(jax.random.key(0), jnp.zeros((2, 16, 2048), jnp.bfloat16))),
+    )
+    x = put(jax.ShapeDtypeStruct((4, 2048, 2048), jnp.bfloat16), P("dp", None, None))
+
+    def loss(p, x):
+        return _f32sum(layer.apply(p, x, mutable=["losses", "moe_stats"])[0] ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).compile().as_text()
+    assert "gmm_fwd" in text and "gmm_dw" in text
 
 
 def _delta_block_lines(v5e, fn):

@@ -869,9 +869,9 @@ class TestGmm:
         m = 48
         x = jax.random.normal(jax.random.PRNGKey(0), (m, d))
         w = jax.random.normal(jax.random.PRNGKey(1), (e, d, h)) * 0.1
-        got = gmm(x, w, seg, tm, 16, True)
+        got = gmm(x, w, seg, tm, 16, True)[:40]  # the tile past the segments is not written
         np.testing.assert_allclose(
-            np.asarray(got), self._ref(x, w, seg), atol=1e-5, rtol=1e-5
+            np.asarray(got), self._ref(x, w, seg)[:40], atol=1e-5, rtol=1e-5
         )
 
     def test_gmm_grads_match_autodiff_reference(self):
@@ -938,6 +938,72 @@ class TestGmm:
         assert got.shape == (rows, h) and bool(jnp.isfinite(got).all())
         np.testing.assert_array_equal(np.asarray(got), self._ref(x[:rows], w, seg))
         np.testing.assert_array_equal(np.asarray(got), np.asarray(blocked))
+
+    # tiles an expert of a buffer of 20: two experts without a row and 30% of
+    # the tiles past the last segment; or no segment at all
+    UNDERFULL = {"skewed": [5, 0, 1, 0, 8], "none": [0, 0, 0, 0, 0]}
+
+    def _underfull(self, split, form, monkeypatch):
+        """``gmm``'s forward, ``dx`` and ``dw`` over a buffer the segments do
+        not fill, in either form (an expert's whole matrix a block; or, with
+        the VMEM budget for that taken away, column blocks and a skipped
+        tail): a function of (x, dy) that returns the live rows of ``y`` and
+        ``dx`` and ``dw``, what the ragged form gives for them, and the
+        number of live rows. The widths differ by form, so that no jitted
+        entry of one is found for the other."""
+        from orion_tpu.ops.pallas import gmm as G
+
+        tm, h, d = 8, 24, {"whole": 32, "blocked": 48}[form]
+        if form == "blocked":
+            monkeypatch.setattr(G, "_LIVE_WHOLE_WIDTH_BYTES", 0)
+        seg = jnp.asarray([t * tm for t in self.UNDERFULL[split]], jnp.int32)
+        rows, m = int(seg.sum()), 20 * tm
+        keys = jax.random.split(jax.random.PRNGKey(11), 3)
+        x, dy = jax.random.normal(keys[0], (m, d)), jax.random.normal(keys[1], (m, h))
+        w = 0.1 * jax.random.normal(keys[2], (len(seg), d, h))
+        product = lambda x, w: G.gmm(x, w, seg, tm, 16, True)  # noqa: E731
+
+        def run(x, dy):
+            y, vjp = jax.vjp(product, x, w)
+            dx, dw = vjp(dy)
+            return y[:rows], dx[:rows], dw
+
+        # the form is the one asked for: the tiles lead the forward's grid with
+        # the table its one scalar operand, and are dw's whole grid; or they
+        # are the last dimension and the live count a second scalar operand
+        grids = {e.params["name"]: (len(e.params["grid_mapping"].grid),
+                                    e.params["grid_mapping"].num_index_operands)
+                 for e in jax.make_jaxpr(run)(x, dy).jaxpr.eqns if e.primitive.name == "pallas_call"}
+        assert grids == ({"gmm_fwd": (2, 1), "gmm_dw": (1, 2)} if form == "whole"
+                         else {"gmm_fwd": (2, 2), "gmm_dw": (3, 2)})
+        if rows:
+            y, vjp = jax.vjp(lambda x, w: jax.lax.ragged_dot(x, w, seg), x[:rows], w)
+            want = (y, *vjp(dy[:rows]))
+        else:
+            want = (jnp.zeros((0, h)), jnp.zeros((0, d)), jnp.zeros_like(w))
+        return run, (x, dy), want, rows
+
+    @pytest.mark.parametrize("form", ["whole", "blocked"])
+    @pytest.mark.parametrize("split", sorted(UNDERFULL))
+    def test_gmm_underfull_buffer_matches_the_ragged_form(self, split, form, monkeypatch):
+        run, args, want, _ = self._underfull(split, form, monkeypatch)
+        for a, b in zip(run(*args), want):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("form", ["whole", "blocked"])
+    @pytest.mark.parametrize("split", sorted(UNDERFULL))
+    def test_gmm_never_reads_the_rows_past_the_last_segment(self, split, form, monkeypatch):
+        """NaN in every row of ``x`` and of ``dy`` past ``sum(group_sizes)``:
+        the live rows of ``y`` and ``dx`` and the whole of ``dw`` are bit for
+        bit what they were, and finite (a tail multiplied into ``dw``, or an
+        expert's block left unwritten and unmasked, would show)."""
+        run, (x, dy), _, rows = self._underfull(split, form, monkeypatch)
+        clean = run(x, dy)
+        poisoned = run(x.at[rows:].set(jnp.nan), dy.at[rows:].set(jnp.nan))
+        for a, b in zip(poisoned, clean):
+            assert bool(jnp.isfinite(a).all())
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_dropless_gmm_matches_ragged_path(self, monkeypatch):
         """The gmm-backed dropless MoE layer == the ragged_dot path,
@@ -1077,6 +1143,37 @@ def test_served_layer_counts_its_tiles_and_experts(backend):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
     many = dict(zip(STAT_NAMES, np.asarray(stats_vector(sown["moe_stats"]))))
     assert many["tiles_live"] > many["experts_live"] == 8
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("buffer", [1.5, 0.5])
+def test_training_held_layer_counts_the_tiles_its_product_visits(backend, buffer):
+    """A held layer that is NOT served sows ``tiles_live`` too: the row tiles
+    of 128 its grouped product visits (on either backend), over the rows that
+    are inside the budget (``buffer`` 0.5 drops some: they have no tile);
+    against the buffer's tiles it is the share of the grid that holds a row.
+    ``experts_live`` stays the served layers' alone."""
+    from orion_tpu.models.moe import STAT_NAMES, stats_vector
+
+    cfg = ModelConfig(
+        name="t", d_model=32, n_experts=4, moe_router_width=16, moe_top_k=2, moe_hidden=64,
+        moe_dropless=True, moe_ep_buffer=buffer, dtype="float32", param_dtype="float32",
+        backend=backend,
+    )
+    layer = MoEMLP(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(8), (4, 1024, 32))
+    params = jax.jit(layer.init)(jax.random.PRNGKey(9), x)
+    _, sown = jax.jit(lambda p, x: layer.apply(p, x, mutable=["losses", "moe_stats"]))(params, x)
+    stats = dict(zip(STAT_NAMES, np.asarray(stats_vector(sown["moe_stats"]))))
+    logits = np.asarray(x.reshape(-1, 32)) @ np.asarray(params["params"]["router"]["kernel"])
+    counts = np.bincount(np.argsort(-logits, axis=1)[:, :2].reshape(-1), minlength=16)[:4]
+    budget = int(np.ceil(buffer * 8192 * 4 / 16))
+    kept = np.diff(np.minimum(np.cumsum(counts), budget), prepend=0)
+    assert stats["rows_held"] == counts.sum()
+    assert (stats["dropless_overflow"] > 0) == (buffer == 0.5) == (kept.sum() < counts.sum())
+    assert stats["tiles_live"] == int(np.ceil(kept / 128).sum()) > 0
+    assert stats["tiles_live"] <= -(-(budget + 4 * 128) // 128)  # the buffer's tiles
+    assert stats["experts_live"] == 0
 
 
 def test_moe_overflow_metric_surfaces_in_trainer():
@@ -1439,6 +1536,53 @@ class TestHeldRowsByList:
                 np.asarray(a, np.float32), b, atol=tol * max(1.0, np.abs(b).max()), rtol=tol
             )
 
+    @pytest.mark.parametrize(
+        "name", ["several_pairs_a_token_and_none", "rows_past_the_budget", "an_expert_with_no_rows"])
+    def test_nothing_reads_the_buffers_unwritten_tail(self, name):
+        """The grouped product leaves the rows past the last segment unwritten,
+        in the forward and in ``dx``: here every product's tail and every
+        cotangent's, into the product and out of it, is NaN, and ``y``, the
+        loss and the gradients of ``x2``, the gates and the three stacks are
+        finite and the plain form's. Everything between the products works a
+        row at a time, and the buffer is read by list."""
+        kw, budget = HELD_CASES[name]
+        x2, flat, gates, ws, lo = _held_case(**kw)
+        form, tiled, plain = self._forms()
+        mix = jax.random.normal(jax.random.PRNGKey(9), x2.shape)
+
+        @jax.custom_vjp
+        def nan_tail(rows, tail):
+            return jnp.where(tail, jnp.nan, rows)
+
+        nan_tail.defvjp(lambda rows, tail: (nan_tail(rows, tail), tail),
+                        lambda tail, g: (jnp.where(tail, jnp.nan, g), None))
+        tails = []
+
+        def poisoned(lhs, w, seg, gs):
+            tail = (jnp.arange(lhs.shape[0]) >= seg.sum())[:, None]
+            tails.append(tail)
+            return nan_tail(tiled(nan_tail(lhs, tail), w, seg, gs), tail)
+
+        for fact in ("tile", "by_list", "unwritten_tail", "interpret"):
+            setattr(poisoned, fact, getattr(tiled, fact))
+
+        def outcome(matmul):
+            def loss(x2, gates, ws):
+                y = form(matmul, budget)(x2, flat, gates, ws, lo)[0]
+                return jnp.sum(y * mix), y
+
+            (value, y), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(
+                x2, gates, ws)
+            return y, value, grads
+
+        got, want = outcome(poisoned), outcome(plain)
+        assert len(tails) == 3 and all(bool(t.any()) for t in tails)  # there IS a tail
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert bool(jnp.isfinite(a).all())
+            b = np.asarray(b)
+            np.testing.assert_allclose(
+                np.asarray(a), b, atol=3e-5 * max(1.0, np.abs(b).max()), rtol=3e-5)
+
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_gather_leaves_unlisted_rows_zero_and_combine_skips_them(self, dtype):
         """Tile padding and the spare buffer: ``idx < 0`` rows of the buffer
@@ -1463,13 +1607,13 @@ class TestHeldRowsByList:
 
     @pytest.mark.parametrize("form", ["training", "serving", "plain"])
     def test_only_the_training_form_holds_the_row_kernels(self, form):
-        """The serving form (``live_tiles``: the grouped product leaves a tail
-        unwritten and the combine is a gather already) and the plain form are
+        """The serving form (``served``: the combine is a gather already) and
+        the plain form are
         the parent's programs: no ``moe_rows_*`` call in their jaxprs."""
         from orion_tpu.models.moe import _gmm_matmul, _held_rows_ffn, _ragged_matmul
 
         matmul = {"training": _gmm_matmul(8, 128, True),
-                  "serving": _gmm_matmul(8, 128, True, live_tiles=True),
+                  "serving": _gmm_matmul(8, 128, True, served=True),
                   "plain": _ragged_matmul}[form]
         x2, flat, gates, ws, lo = _held_case()
         text = str(jax.make_jaxpr(

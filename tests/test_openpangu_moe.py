@@ -381,14 +381,17 @@ def test_cell_rehearses_on_the_cpu():
     assert m["kv_live_share.batch"]["value"] > 0
 
 
-QWEN_TRAIN_FORWARD = "f4d1341c0115e7c2"
+# PR 58: the held layer sows ``tiles_live`` in training too (seven equations a
+# layer, the diff of the jaxpr text against the parent's holds nothing else);
+# f4d1341c0115e7c2 until then
+QWEN_TRAIN_FORWARD = "58b1e83adae5d1f0"
 
 
 def test_qwen3_next_train_program_is_what_it_was():
     """The edited MoE layer under the delta-rule preset's configuration
     (softmax scores, a gated shared expert, the 1.5x buffer, no ``live``):
     the jaxpr of its tiny train forward, hashed on the PARENT of PR 43
-    (f9dd22b) and equal on its tree."""
+    (f9dd22b), equal on its tree and until PR 58's counter."""
     import hashlib
 
     cfg = dataclasses.replace(

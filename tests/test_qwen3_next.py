@@ -388,6 +388,9 @@ def test_train_cli_path_trains_the_preset_tiny():
     assert last["moe_overflow"] == 0
     assert last["moe_rows_routed"] == 4 * 2 * 64 * 2
     assert 0 < last["moe_rows_max_expert"] <= last["moe_rows_held"] < last["moe_rows_routed"]
+    # the grouped product's visits: tiles of 128 rows, a partial one an expert and layer
+    held_experts = 4 * trainer.cfg.model.n_experts
+    assert 0 <= last["moe_tiles_live"] - last["moe_rows_held"] / 128 < held_experts
 
 
 # -- the older presets are what they were ------------------------------------

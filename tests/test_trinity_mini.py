@@ -443,6 +443,11 @@ def test_the_classes_say_what_a_slot_holds():
 # ``tiles_live`` and ``experts_live`` (two scalar sums of the counts it had,
 # seven equations a layer; the diff of the jaxpr text against the parent's
 # holds nothing else), and at these widths no call takes the whole-width block.
+# PR 58 did, for ``openpangu_ultra_moe_718b``'s TRAINING forward alone (no cell
+# runs it): a held layer that is not served sows ``tiles_live`` too, the row
+# tiles its grouped product visits (seven equations a layer: the in-budget
+# counts and the sum of their tiles; the diff holds nothing else). The pieces
+# and steps, which the cells run, are the parent's.
 _PRESETS = {
     "openpangu_ultra_moe_718b": dict(
         vocab_size=256, d_model=64, n_layers=3, layer_types=("latent",) * 3, n_heads=4, head_dim=24,
@@ -460,7 +465,7 @@ _PRESETS = {
         ssm_state=16, mlp_hidden=128, max_seq_len=256, dtype="float32", param_dtype="float32"),
 }
 _TRACED = {
-    "openpangu_ultra_moe_718b.forward": "1f29899ffe7fe667",
+    "openpangu_ultra_moe_718b.forward": "105f7bf64f3cf720",
     "openpangu_ultra_moe_718b.piece": "787bb6ca7e979b3e",
     "openpangu_ultra_moe_718b.step": "9f9a9594f33b461f",
     "keye_vl_2_0_30b_a3b.forward": "a731e1d746341ad8",
