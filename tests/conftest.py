@@ -21,9 +21,22 @@ assert jax.device_count() >= 8, jax.devices()
 
 
 # -- quick/slow tiers ---------------------------------------------------------
-# Tests >=10s single-process on this 1-core box (from `pytest --durations`),
-# marked centrally so the list is regenerable. Dev loop: `-m "not slow"`
-# (~9 min); the full suite (~36 min) stays the merge gate.
+# The quick tier is what gates a PR: the driver's command
+# (``/root/TESTS_LAST_RUN.json``'s ``commands[0]``), SIX xdist workers that
+# each take whole files, under a 1,470 s limit:
+#
+#   timeout -k 10 1470 env JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 \
+#     python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors \
+#     -p no:cacheprovider -p xdist -n 6 --dist loadfile \
+#     --junitxml=/tmp/_t1.xml -p no:randomly
+#
+# ``slow`` is the tier NOTHING runs: the names below were moved out of the
+# quick tier when each took >= 10 s in one process (PRs 11-13, and budget
+# keeping since), no command of the driver's or of a builder's collects them,
+# and what only they cover is not covered (ROADMAP C24: bring back what now
+# fits, or delete the tier with the code only it tests). Marking a test slow
+# is therefore not a way to make the quick tier fit its limit: it stops the
+# test, and the driver's floor on the count of passes refuses the PR.
 _SLOW = {
     # ISSUE 11 acceptance matrix (>=10s each): the full per-qmode
     # batched-vs-solo sweep and the int4/greedy prefix-hit variants run
@@ -191,6 +204,14 @@ def release_compiled_programs():
     (PERF.md section 7)."""
     yield
     jax.clear_caches()
+
+
+def pytest_generate_tests(metafunc):
+    """A served configuration's contract (tests/served_contract.py) says
+    which backends and engines its case takes."""
+    parametrise = getattr(metafunc.cls, "parametrise", None)
+    if parametrise is not None:
+        parametrise(metafunc)
 
 
 def pytest_collection_modifyitems(config, items):

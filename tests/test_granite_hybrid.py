@@ -4,93 +4,114 @@ shared by the heads of a group, a biased short conv, the gate before one
 norm) : un-rotated softmax attention over a grouped KV cache with a config
 scale, a scaled embedding, scaled residual branches, scaled logits, a tied
 head, against ``benchmark/reference/plain_granite_hybrid.py``; tiny, CPU,
-fp32."""
-
-import dataclasses
-import os
-import sys
+fp32. The contract every served configuration takes is
+``tests/served_contract.py``'s."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from served_contract import (
+    Because, ByBackend, Cell, ServedCase, ServedContract, Walk, served_fixture,
+)
 
-from orion_tpu.generate import SampleConfig, generate
 from orion_tpu.models.configs import get_config
 from orion_tpu.models.mixers import MIXERS
 from orion_tpu.models.transformer import TransformerLM, init_decode_state
 from orion_tpu.ops import dispatch
 from orion_tpu.ops import ssm as ssm_ops
-from orion_tpu.serving import DecodeRequest, ServeConfig, Server, SlotEngine
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(ROOT, "benchmark"))
-from reference import plain_granite_hybrid as ref  # noqa: E402
 
 # both layer kinds in the pattern, ONE group of eight heads as the model
 # publishes (since PR 57 the gated norm is taken over each group, and this
 # family's reference norms all channels at once: the two are one at one group;
 # two groups with the norm by groups are tests/test_nemotron_h.py's), four
 # query heads to two KV heads; T = 600 is over two scan chunks of 256
-TINY = dict(vocab_size=256, d_model=64, n_layers=5,
-            layer_types=("ssm", "ssm", "softmax", "ssm", "softmax"),
-            n_heads=4, n_kv_heads=2, head_dim=16, attn_scale=1 / 32,
-            ssm_heads=8, ssm_head_dim=8, ssm_state=16, ssm_groups=1,
-            mlp_hidden=128, max_seq_len=768, dtype="float32", param_dtype="float32",
-            embed_init_std=None)  # flax's own: at 64 wide the preset's leaves x0 ~ 0
+WHY = "this file's since PR 41: the engine's byte counts below are asserted at it"
 T = 600
-# fp32 against fp32 on logits of ~1: summation order only
-LOGIT_TOL = 5e-5
-GREEDY = SampleConfig(temperature=0.0)
+CASE = ServedCase(
+    "granite_4_0_h_micro", seq=T,
+    logit_tol=5e-5,  # fp32 against fp32 on logits of ~1: summation order only
+    over=dict(
+        n_layers=5, layer_types=Because(("ssm", "ssm", "softmax", "ssm", "softmax"), WHY),
+        attn_scale=Because(1 / 32, "neither head_dim^-1/2 nor 1/head_dim: a scale read elsewhere shows"),
+        ssm_head_dim=Because(8, WHY), ssm_groups=1, max_seq_len=768,
+        embed_init_std=None),  # flax's own: at 64 wide the preset's leaves x0 ~ 0
+    moved=("scale", "out_norm", "'D'"),  # the skip too
+    floor=0.3,
+    # logits to 2e-4, states to 1e-4 (fp32; the chunked form sums in another
+    # order than the recurrence); pieces of 64, the last one partly padding
+    walk=Walk(n=ByBackend(xla=300, pallas_interpret=70), piece=64, cold=70, against="reference",
+              tol=2e-4, padded=20, states=dict(atol=1e-4),
+              cold_states=ByBackend(pallas_interpret=dict(atol=1e-4)), cache_rows=True),
+    server=True,
+    cell=Cell("granite_4_0_h_micro.serve_batch", seed=2 ** 31 + 41),
+    # read on the parent of PR 59 (44d93ca) at this case's sizes; until then
+    # tests/test_trinity_mini.py pinned them at sizes of its own
+    pins={"forward": "01623535d2826a4c",
+          "piece": "8b97b9bdaebf9838", "step": "c189e771054a617c"},
+)
+served = served_fixture(CASE)
 
 
-def tiny_cfg(backend="xla", **over):
-    return dataclasses.replace(
-        get_config("granite_4_0_h_micro"), backend=backend, **{**TINY, **over})
+class TestServed(ServedContract):
+    case = CASE
 
+    def published(self, cfg):
+        assert (cfg.d_model, cfg.n_layers, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (2048, 40, 32, 8, 64)
+        assert (cfg.mlp_hidden, cfg.vocab_size, cfg.tie_embeddings) == (8192, 100352, True)
+        kinds = cfg.resolved_layer_types
+        assert [i for i, k in enumerate(kinds) if k == "softmax"] == [5, 15, 25, 35]
+        assert kinds.count("ssm") == 36
+        assert (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups, cfg.ssm_conv_width) == (64, 64, 128, 1, 4)
+        assert (cfg.embed_scale, cfg.residual_scale, cfg.logit_scale) == (12, 0.22, 1 / 8)
+        assert cfg.attn_scale == 1 / 64 and cfg.norm_eps == 1e-5 and not cfg.rotary and cfg.pos_embed == "none"
+        assert cfg.embed_init_std == 0.005
+        shapes = jax.eval_shape(lambda: init_decode_state(cfg, 2))
+        assert [sorted(s) for s in shapes] == [["k", "v"] if k == "softmax" else ["conv", "s"] for k in kinds]
+        # two heads side by side on lanes; 3 pre-conv rows of 4,352 channels
+        assert shapes[0]["s"].shape == (2, 32, 128, 128) and shapes[0]["s"].dtype == jnp.float32
+        assert shapes[0]["conv"].shape == (2, 3 * 4352)
+        assert shapes[5]["k"].shape == (2, 8, 2048, 64)
+        n = sum(x.size for x in jax.tree.leaves(jax.eval_shape(
+            lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))))
+        assert n == 36 * 76182976 + 4 * 60821504 + 100352 * 2048 + 2048, n
 
-def spec_of(cfg, **over):
-    keys = ("n_heads", "n_kv_heads", "head_dim", "attn_scale", "ssm_heads", "ssm_head_dim",
-            "ssm_state", "ssm_groups", "embed_scale", "residual_scale", "logit_scale", "norm_eps")
-    return {"layer_types": cfg.resolved_layer_types, **{k: getattr(cfg, k) for k in keys}, **over}
+    def test_decode_step_with_a_row_list_touches_no_other_row(self, served):
+        """Under the kernels, given a row list, the state-space step and the
+        cache write leave an unlisted row's state bitwise alone, conv tail
+        included; a listed row steps as the XLA form does."""
+        params, kernels, plain = served.params, served.programs("pallas_interpret"), served.programs()
+        toks = jnp.concatenate([served.toks[:, :40], served.toks[:, 100:140]], 0)  # 4 rows
+        _, states = plain.prefill(params, toks)
+        mask = jnp.asarray([True, False, True, False])
+        rows = dispatch.decode_live_rows(mask, backend="pallas_interpret")
+        t = jnp.full((4,), 40, jnp.int32)
+        lg, new = kernels.step(params, toks[:, 0], states, t, rows)
+        want_lg, want = plain.step(params, toks[:, 0], states, t)
+        for layer, (n, o, w) in enumerate(zip(new, states, want)):
+            for name in o:
+                got = np.asarray(n[name])
+                np.testing.assert_array_equal(got[1::2], np.asarray(o[name])[1::2], err_msg=f"{layer}.{name}")
+                np.testing.assert_allclose(got[0::2], np.asarray(w[name])[0::2], atol=1e-5)
+        np.testing.assert_allclose(lg[0::2], want_lg[0::2], atol=2e-4)
+        assert all(MIXERS[k].rows_in_place for k in served.cfg.resolved_layer_types)
 
+    def after_engine(self, served, run, backend, donate):
+        """With the carry donated the scan holds the grouped K and V and
+        carries a chunk's own rows (``chunk_split``)."""
+        held, kinds = run.engine.held_bytes, run.cfg.resolved_layer_types
+        assert held["kv_bytes"] == kinds.count("softmax") * 2 * 4 * 2 * 768 * 16 * 4
+        assert held["state_bytes"] == kinds.count("ssm") * 4 * (8 * 8 * 16 * 4 + 3 * (64 + 32) * 4)
 
-@pytest.fixture(scope="module")
-def model_params():
-    cfg = tiny_cfg()
-    model = TransformerLM(cfg)
-    toks = jax.random.randint(jax.random.key(1), (2, T), 0, cfg.vocab_size)
-    params = jax.jit(model.init)(jax.random.key(0), toks[:, :16])
-    # norm weights and the skip off 1, so that one left out or misplaced shows
-    params = jax.tree_util.tree_map_with_path(
-        lambda path, x: x + 0.3 * jax.random.normal(jax.random.key(len(str(path))), x.shape)
-        if any(n in str(path) for n in ("scale", "out_norm", "'D'")) else x, params)
-    with jax.default_matmul_precision("highest"):
-        want = ref.forward(spec_of(cfg), params, toks)
-        got = model.apply(params, toks)
-    return cfg, params, toks, want, got
+    def after_server(self, served, counters, prompts):
+        """The state-space counters follow the boundaries."""
+        layers = served.cfg.resolved_layer_types.count("ssm")
+        assert counters["ssm_piece_rows"] == layers * sum(len(p) for p in prompts)
+        assert counters["ssm_row_steps"] == layers * 4 * counters["slot_steps_emitting"] > 0
 
-
-def test_preset_is_the_published_shape():
-    cfg = get_config("granite_4_0_h_micro")
-    assert (cfg.d_model, cfg.n_layers, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (2048, 40, 32, 8, 64)
-    assert (cfg.mlp_hidden, cfg.vocab_size, cfg.tie_embeddings) == (8192, 100352, True)
-    kinds = cfg.resolved_layer_types
-    assert [i for i, k in enumerate(kinds) if k == "softmax"] == [5, 15, 25, 35]
-    assert kinds.count("ssm") == 36
-    assert (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups, cfg.ssm_conv_width) == (64, 64, 128, 1, 4)
-    assert (cfg.embed_scale, cfg.residual_scale, cfg.logit_scale) == (12, 0.22, 1 / 8)
-    assert cfg.attn_scale == 1 / 64 and cfg.norm_eps == 1e-5 and not cfg.rotary and cfg.pos_embed == "none"
-    assert cfg.embed_init_std == 0.005
-    shapes = jax.eval_shape(lambda: init_decode_state(cfg, 2))
-    assert [sorted(s) for s in shapes] == [["k", "v"] if k == "softmax" else ["conv", "s"] for k in kinds]
-    # two heads side by side on lanes; 3 pre-conv rows of 4,352 channels
-    assert shapes[0]["s"].shape == (2, 32, 128, 128) and shapes[0]["s"].dtype == jnp.float32
-    assert shapes[0]["conv"].shape == (2, 3 * 4352)
-    assert shapes[5]["k"].shape == (2, 8, 2048, 64)
-    n = sum(x.size for x in jax.tree.leaves(jax.eval_shape(
-        lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))))
-    assert n == 36 * 76182976 + 4 * 60821504 + 100352 * 2048 + 2048, n
+    def after_cell(self, result, lines):
+        """The served kind for a tied head."""
+        assert 0 < result["metrics"]["kv_live_share.batch"]["value"] < 100
 
 
 def test_other_presets_build_what_they_built():
@@ -103,12 +124,6 @@ def test_other_presets_build_what_they_built():
     assert shapes[3]["k"].shape == (1, 30, 4096, 128)
 
 
-def test_model_matches_the_reference(model_params):
-    cfg, params, toks, want, got = model_params
-    assert float(jnp.abs(want).max()) > 0.3
-    np.testing.assert_allclose(got, want, atol=LOGIT_TOL)
-
-
 @pytest.mark.parametrize("what,over", [
     ("an unscaled residual", {"residual_scale": 1.0}),
     ("an unscaled embedding", {"embed_scale": 1.0}),
@@ -116,23 +131,19 @@ def test_model_matches_the_reference(model_params):
     ("a scale of head_dim^-1/2 in attention", {"attn_scale": 16 ** -0.5}),
     ("another epsilon", {"norm_eps": 1e-2}),
 ])
-def test_the_comparison_sees(model_params, what, over):
+def test_the_comparison_sees(served, what, over):
     """The tolerance is tight enough to tell the model from a reference
     that differs in one of the mechanisms."""
-    cfg, params, toks, want, got = model_params
-    with jax.default_matmul_precision("highest"):
-        other = ref.forward({**spec_of(cfg), **over}, params, toks)
-    assert float(jnp.abs(other - got).max()) > 20 * LOGIT_TOL, what
+    served.differs(served.spec(**over))
 
 
 @pytest.mark.parametrize("patch", [
     "dt missing from the input term", "no D skip", "no conv bias", "B and C per head",
     "the norm before the gate", "rotary applied", "K and V per query head"])
-def test_the_comparison_sees_a_changed_layer(model_params, monkeypatch, patch):
+def test_the_comparison_sees_a_changed_layer(served, monkeypatch, patch):
     """The reference with one line of a layer changed reads differently from
     the model, by far more than the tolerance."""
-    cfg, params, toks, want, got = model_params
-    spec = spec_of(cfg)
+    ref, params = served.ref, served.params
     if patch == "dt missing from the input term":
         plain = ref.ssm_recurrence
         monkeypatch.setattr(ref, "ssm_recurrence", lambda x, dt, a, bm, cm: plain(
@@ -186,88 +197,7 @@ def test_the_comparison_sees_a_changed_layer(model_params, monkeypatch, patch):
             return q, k, v
 
         monkeypatch.setattr(ref, "qkv", regrouped)
-    with jax.default_matmul_precision("highest"):
-        other = ref.forward(spec, params, toks)
-    assert float(jnp.abs(other - got).max()) > 20 * LOGIT_TOL, patch
-
-
-# -- the serving entry points --------------------------------------------------
-
-
-def walk(model, params, cfg, toks):
-    states = init_decode_state(cfg, toks.shape[0], jnp.float32)
-    step = jax.jit(lambda p, tok, st, t: model.apply(p, tok, st, t, method=model.decode_step))
-    outs = []
-    for t in range(toks.shape[1]):
-        lg, states = step(params, toks[:, t], states, jnp.int32(t))
-        outs.append(lg)
-    return jnp.stack(outs, 1), states
-
-
-def assert_states_close(got, want, rows, atol):
-    """Recurrent states and conv tails whole; caches up to ``rows``."""
-    for g, w in zip(got, want):
-        for name in w:
-            a, b = np.asarray(g[name]), np.asarray(w[name])
-            if name in ("k", "v"):
-                a, b = a[:, :, :rows], b[:, :, :rows]
-            np.testing.assert_allclose(a, b, atol=atol, err_msg=name)
-
-
-@pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
-def test_prefill_equals_pieces_equals_the_decode_walk(model_params, backend):
-    """``prefill`` = pieces of ``prefill_extend`` (the last one padded: it
-    stops at its ``length``) = the ``decode_step`` walk, for the state-space
-    mixer and the grouped softmax: logits to 2e-4, states to 1e-4 (fp32;
-    the chunked form sums in another order than the recurrence)."""
-    cfg, params, toks, want, _ = model_params
-    cfg = dataclasses.replace(cfg, backend=backend)
-    model = TransformerLM(cfg)
-    n = 300 if backend == "xla" else 70  # the interpreter is slow
-    toks = toks[:, :n]
-    logits, states = jax.jit(lambda p, x: model.apply(p, x, method=model.prefill))(params, toks)
-    np.testing.assert_allclose(logits, want[:, :n], atol=2e-4)
-    # padded whole: the state stops at the real length
-    padded = jnp.pad(toks, ((0, 0), (0, 20)))
-    _, stopped = model.apply(params, padded, jnp.int32(n), method=model.prefill_last)
-    assert_states_close(stopped, states, n, 1e-5)
-    # pieces of 64, the last one partly padding
-    piece = jax.jit(lambda p, x, st, off, ln: model.apply(
-        p, x, st, off, ln, method=model.prefill_extend_step))
-    st = init_decode_state(cfg, toks.shape[0], jnp.float32)
-    for off in range(0, n, 64):
-        real = min(64, n - off)
-        x = jnp.zeros((toks.shape[0], 64), toks.dtype).at[:, :real].set(toks[:, off:off + real])
-        last, st = piece(params, x, st, jnp.int32(off), jnp.int32(real))
-    np.testing.assert_allclose(last, logits[:, -1], atol=2e-4)
-    assert_states_close(st, states, n, 1e-4)
-    walked, wst = walk(model, params, cfg, toks[:, :70])
-    np.testing.assert_allclose(walked, logits[:, :70], atol=2e-4)
-    if n == 70:
-        assert_states_close(wst, states, n, 1e-4)
-
-
-def test_decode_step_with_a_row_list_touches_no_other_row(model_params):
-    """Under the kernels, given a row list, the state-space step and the
-    cache write leave an unlisted row's state bitwise alone, conv tail
-    included; a listed row steps as the XLA form does."""
-    cfg, params, toks, _, _ = model_params
-    model = TransformerLM(dataclasses.replace(cfg, backend="pallas_interpret"))
-    plain = TransformerLM(cfg)
-    toks = jnp.concatenate([toks[:, :40], toks[:, 100:140]], 0)  # 4 rows
-    _, states = plain.apply(params, toks, method=plain.prefill)
-    mask = jnp.asarray([True, False, True, False])
-    rows = dispatch.decode_live_rows(mask, backend="pallas_interpret")
-    t = jnp.full((4,), 40, jnp.int32)
-    lg, new = model.apply(params, toks[:, 0], states, t, rows, method=model.decode_step)
-    want_lg, want = plain.apply(params, toks[:, 0], states, t, method=plain.decode_step)
-    for layer, (n, o, w) in enumerate(zip(new, states, want)):
-        for name in o:
-            got = np.asarray(n[name])
-            np.testing.assert_array_equal(got[1::2], np.asarray(o[name])[1::2], err_msg=f"{layer}.{name}")
-            np.testing.assert_allclose(got[0::2], np.asarray(w[name])[0::2], atol=1e-5)
-    np.testing.assert_allclose(lg[0::2], want_lg[0::2], atol=2e-4)
-    assert all(MIXERS[k].rows_in_place for k in cfg.resolved_layer_types)
+    served.differs(params=params)
 
 
 # -- the ops -------------------------------------------------------------------
@@ -361,164 +291,29 @@ def test_conv_bias_enters_before_the_silu():
     np.testing.assert_array_equal(causal_short_conv(x, w), causal_short_conv(x, w, bias=None))
 
 
-# -- through the engine and the server -----------------------------------------
-
-
-def serve(cfg, params, prompts, max_new, donate=False):
-    engine = SlotEngine(TransformerLM(cfg), params, slots=4, chunk=4,
-                        prefill_buckets=(64, 128, 256), prefill_chunk=32)
-    engine.donate_carry = donate
-    for i, p in enumerate(prompts):
-        engine.admit(DecodeRequest(prompt=p, max_new_tokens=max_new, sample=GREEDY, seed=i), tag=i)
-    done = {}
-    while engine.busy:
-        for tag, res in engine.step():
-            assert res.status == "ok", res.status
-            done[tag] = np.asarray(res.tokens).reshape(-1)
-    return [done[i] for i in range(len(prompts))], engine
-
-
-@pytest.mark.parametrize("backend,donate", [
-    ("xla", False), ("pallas_interpret", False), ("xla", True), ("pallas_interpret", True)])
-def test_engine_serves_as_generate(model_params, backend, donate):
-    """Through ``SlotEngine``: three requests of one, three and six pieces
-    resident together, pieces and decode interleaved; each request's ids are
-    ``generate()``'s for it alone. With the carry donated the scan holds the
-    grouped K and V and carries a chunk's own rows (``chunk_split``)."""
-    cfg, params, toks, _, _ = model_params
-    cfg = dataclasses.replace(cfg, backend=backend)
-    prompts = [np.asarray(toks[0, :30]), np.asarray(toks[1, :90]), np.asarray(toks[0, 20:190])]
-    together, engine = serve(cfg, params, prompts, 9, donate)
-    xla = dataclasses.replace(cfg, backend="xla")
-    for p, ids in zip(prompts, together):
-        alone = generate(TransformerLM(xla), params, jnp.asarray(p)[None], 9, GREEDY)
-        np.testing.assert_array_equal(ids, np.asarray(alone)[0, -9:])
-    held = engine.held_bytes
-    kinds = cfg.resolved_layer_types
-    assert held["kv_bytes"] == kinds.count("softmax") * 2 * 4 * 2 * 768 * 16 * 4
-    assert held["state_bytes"] == kinds.count("ssm") * 4 * (8 * 8 * 16 * 4 + 3 * (64 + 32) * 4)
-
-
-def test_server_answers_as_generate(model_params):
-    """The ``Server`` over the tiny preset: 4 slots, five requests, pieces
-    and decode interleaved; every answer is ``generate()``'s, and the
-    state-space counters follow the boundaries."""
-    cfg, params, toks, _, _ = model_params
-    model = TransformerLM(cfg)
-    srv = Server(model, params, ServeConfig(chunk=4, slots=4, max_inflight=8, prefill_chunk=32,
-                                            prefill_buckets="64,128,256", cost=False))
-    prompts = [np.asarray(toks[i % 2, a:b]) for i, (a, b) in
-               enumerate([(0, 100), (0, 20), (50, 200), (10, 75), (3, 150)])]
-    handles = [srv.submit(DecodeRequest(prompt=p, max_new_tokens=7, sample=GREEDY, seed=i))
-               for i, p in enumerate(prompts)]
-    srv.serve(drain_when_idle=True)
-    counters = srv.metrics.counters_flat()
-    srv.close()
-    for p, h in zip(prompts, handles):
-        assert h.result.status == "ok"
-        alone = generate(model, params, jnp.asarray(p)[None], 7, GREEDY)
-        np.testing.assert_array_equal(np.asarray(h.result.tokens).reshape(-1), np.asarray(alone)[0, -7:])
-    layers = cfg.resolved_layer_types.count("ssm")
-    assert counters["ssm_piece_rows"] == layers * sum(len(p) for p in prompts)
-    assert counters["ssm_row_steps"] == layers * 4 * counters["slot_steps_emitting"] > 0
-
-
-def test_cell_rehearses_on_the_cpu(tmp_path):
-    """``granite_4_0_h_micro.serve_batch`` end to end at tiny sizes: the
-    served kind for a tied head, the reference named by the configuration's
-    file, the check on what was served in the window."""
-    import json
-    import subprocess
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT,
-               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
-    out = subprocess.run(
-        [sys.executable, "benchmark/run.py", "--workload", "granite_4_0_h_micro.serve_batch",
-         "--seed", str(2 ** 31 + 41), "--seconds", "3", "--trace", "1", "--rehearse"],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=1200)
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["correct"] and line["failed"] == 0
-    assert 0 < line["metrics"]["kv_live_share.batch"]["value"] < 100
-
-
-# -- what the other presets trace is the parent's -------------------------------
-
-# sha256 (first 16 hex) of the jaxpr text of four programs of three presets
-# at tiny widths, read on the PARENT of PR 41 (461ecb8) and equal on its
-# tree: with ``n_kv_heads``, ``attn_scale``, ``embed_init_std`` and the conv's
-# bias absent nothing new is traced. A PR that changes one of these programs
-# on purpose reads the new value from the assertion and replaces it here.
-# PR 45 did, for ``olmo_hybrid_7b``'s prefill, piece and step: the delta-rule
-# layer's gate is an op that takes ``o`` head-major, so the ``swapaxes`` that
-# ``_rule`` did now comes after the conv tail's equations (prefill and piece:
-# the same equations, in another order) and a decode step's one row passes it
-# as ``[B, Hv, 1, dv]`` (unit axes; the arithmetic is bit for bit the old
-# ``_output``'s: tests/test_gated_norm_kernel.py).
-# PR 51 did, for ``hybrid_1b3``'s piece: a window layer's piece now writes its
-# last min(length, window) rows into the ring at their slots (one scatter of
-# the piece's rows, the rest dropped) where it rebuilt all ``window`` rows by a
-# gather and a scatter; the attention's equations and the ring's contents are
-# the old ones (tests/test_prefill_inscan.py holds them bitwise).
-_OLMO = dict(vocab_size=256, d_model=96, n_heads=3, head_dim=16, gdn_key_heads=3,
-             gdn_value_heads=3, gdn_key_dim=8, gdn_value_dim=24, mlp_hidden=128,
-             max_seq_len=256, dtype="float32", param_dtype="float32")
-_LM = dict(vocab_size=256, d_model=64, n_layers=2, n_heads=4, max_seq_len=128, dtype="float32")
-_PRESETS = {
-    "olmo_hybrid_7b": _OLMO,
-    "lm_1b3": _LM,
-    "hybrid_1b3": {**_LM, "n_layers": 4, "layer_types": ("swa", "swa", "swa", "linear"), "window": 32},
-}
-_TRACED = {
-    "olmo_hybrid_7b.forward": "1d0bffda123d623c", "olmo_hybrid_7b.prefill": "5e76eafde9c2cca9",
-    "olmo_hybrid_7b.piece": "8f805726bc5d85fb", "olmo_hybrid_7b.step": "2c5a2873d6be3918",
-    "lm_1b3.forward": "91150cac1cbb2ee7", "lm_1b3.prefill": "55bfda62ea1cc771",
-    "lm_1b3.piece": "067511f7d2e33163", "lm_1b3.step": "bf7be0cd0cf2078e",
-    "hybrid_1b3.forward": "e12b2be89edde12c", "hybrid_1b3.prefill": "ff81ed1f94e804b7",
-    "hybrid_1b3.piece": "d038a27629d8002b", "hybrid_1b3.step": "535629313893979d",
-}
-
-
-@pytest.mark.parametrize("which", sorted(_TRACED))
-def test_other_presets_trace_the_parents_programs(which):
-    import hashlib
-
-    preset, program = which.split(".")
-    cfg = dataclasses.replace(get_config(preset), **_PRESETS[preset])
-    model = TransformerLM(cfg)
-    toks = jnp.zeros((2, 48), jnp.int32)
-    params = jax.eval_shape(lambda: model.init(jax.random.key(0), toks))
-    states = jax.eval_shape(lambda: init_decode_state(cfg, 2, jnp.float32))
-    traced = {
-        "forward": lambda: jax.make_jaxpr(lambda p, x: model.apply(p, x))(params, toks),
-        "prefill": lambda: jax.make_jaxpr(lambda p, x: model.apply(
-            p, x, jnp.int32(40), method=model.prefill_last))(params, toks),
-        "piece": lambda: jax.make_jaxpr(lambda p, x, st: model.apply(
-            p, x[:, :16], st, jnp.int32(16), jnp.int32(9),
-            method=model.prefill_extend_step))(params, toks, states),
-        "step": lambda: jax.make_jaxpr(lambda p, x, st: model.apply(
-            p, x[:, 0], st, jnp.full((2,), 5, jnp.int32), method=model.decode_step))(params, toks, states),
-    }[program]()
-    assert hashlib.sha256(str(traced).encode()).hexdigest()[:16] == _TRACED[which]
-
-
 def test_a_sliding_window_takes_grouped_kv_heads_too():
     """``swa`` under ``n_kv_heads``: the ring holds KV heads, and prefill =
-    pieces = the decode walk past the window."""
+    pieces = the decode walk past the window (each method one program)."""
     cfg = get_config("tiny", vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
                      layer_types=("swa", "linear"), window=16, max_seq_len=64)
     model = TransformerLM(cfg)
     toks = jax.random.randint(jax.random.key(2), (2, 40), 0, 64)
-    params = model.init(jax.random.key(0), toks)
+    params = jax.jit(model.init)(jax.random.key(0), toks)
     assert params["params"]["block_0"]["attn"]["wk"]["kernel"].shape == (32, 16)
-    logits, states = model.apply(params, toks, method=model.prefill)
+    logits, states = jax.jit(lambda p, x: model.apply(p, x, method=model.prefill))(params, toks)
     assert states[0]["k"].shape == (2, 2, 16, 8)
-    np.testing.assert_allclose(model.apply(params, toks), logits, atol=1e-5)
-    walked, _ = walk(model, params, cfg, toks)
-    np.testing.assert_allclose(walked, logits, atol=2e-4)
+    np.testing.assert_allclose(jax.jit(model.apply)(params, toks), logits, atol=1e-5)
+    step = jax.jit(lambda p, tok, st, t: model.apply(p, tok, st, t, method=model.decode_step))
+    st, walked = init_decode_state(cfg, 2, jnp.float32), []
+    for t in range(toks.shape[1]):
+        lg, st = step(params, toks[:, t], st, jnp.int32(t))
+        walked.append(lg)
+    np.testing.assert_allclose(jnp.stack(walked, 1), logits, atol=2e-4)
+    piece = jax.jit(lambda p, x, st, off, n: model.apply(
+        p, x, st, off, n, method=model.prefill_extend_step))
     st = init_decode_state(cfg, 2, jnp.float32)
     for off in (0, 16, 32):
         n = min(16, 40 - off)
         x = jnp.zeros((2, 16), toks.dtype).at[:, :n].set(toks[:, off:off + n])
-        last, st = model.apply(params, x, st, jnp.int32(off), jnp.int32(n), method=model.prefill_extend_step)
+        last, st = piece(params, x, st, jnp.int32(off), jnp.int32(n))
     np.testing.assert_allclose(last, logits[:, -1], atol=2e-4)

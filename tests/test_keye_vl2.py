@@ -2,18 +2,21 @@
 attention over the ``index_topk`` cache rows a learned per-token indexer picks
 (16-head index queries against one index key a token, held in the cache
 beside K and V), beside a softmax top-k mixture of experts all held on the
-chip, against ``benchmark/reference/plain_keye_vl2.py``; tiny, CPU, fp32."""
+chip, against ``benchmark/reference/plain_keye_vl2.py``; tiny, CPU, fp32. The
+contract every served configuration takes is ``tests/served_contract.py``'s."""
 
 import dataclasses
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from served_contract import (
+    ROOT, Because, Cell, Scan, ServedCase, ServedContract, Walk, other_presets, served_fixture,
+    tiny_cfg,
+)
 
-from orion_tpu.generate import SampleConfig, generate
 from orion_tpu.models.configs import get_config
 from orion_tpu.models.mixers import MIXERS
 from orion_tpu.models.mixers.indexed import rows_listed
@@ -22,98 +25,102 @@ from orion_tpu.models.transformer import TransformerLM, init_decode_state
 from orion_tpu.ops import dispatch
 from orion_tpu.ops.softmax_attention import cached_attention
 from orion_tpu.ops.topk_select import mask_to_list, top_k_mask
-from orion_tpu.serving import DecodeRequest, ServeConfig, Server, SlotEngine
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(ROOT, "benchmark"))
-from reference import plain_keye_vl2 as ref  # noqa: E402
-
-# the indexer scaled down: 4 index heads of 8, top-24 of up to 203 rows
-TINY = dict(vocab_size=256, d_model=64, n_layers=2, layer_types=("indexed",) * 2, n_heads=4,
-            n_kv_heads=2, head_dim=16, index_heads=4, index_dim=8, index_topk=24,
-            moe_hidden=32, n_experts=8, moe_top_k=2, max_seq_len=256,
-            dtype="float32", param_dtype="float32")
-T = 203
-# fp32 against fp32 on logits of ~4: summation order only
-LOGIT_TOL = 5e-5
-GREEDY = SampleConfig(temperature=0.0)
-
-
-def tiny_cfg(backend="xla", **over):
-    return dataclasses.replace(get_config("keye_vl_2_0_30b_a3b"), backend=backend, **{**TINY, **over})
-
-
-def spec_of(cfg, **over):
-    return {**dict(
-        layer_types=cfg.resolved_layer_types, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-        head_dim=cfg.head_dim, rope_base=cfg.rotary_base, index_heads=cfg.index_heads,
-        index_dim=cfg.index_dim, index_topk=cfg.index_topk, top_k=cfg.moe_top_k,
-        query_tile=64), **over}
-
-
-@pytest.fixture(scope="module")
-def model_params():
-    cfg = tiny_cfg()
-    model = TransformerLM(cfg)
-    toks = jax.random.randint(jax.random.key(1), (2, T), 0, cfg.vocab_size)
-    params = jax.jit(model.init)(jax.random.key(0), toks[:, :16])
-    # norm weights off 1 and biases off 0, so that a norm left out shows
-    params = jax.tree_util.tree_map_with_path(
-        lambda path, x: x + 0.3 * jax.random.normal(jax.random.key(len(str(path))), x.shape)
-        if ("scale" in str(path) or "bias" in str(path)) else x, params)
-    with jax.default_matmul_precision("highest"):
-        want = ref.forward(spec_of(cfg), params, toks)
-        got = model.apply(params, toks)
-    yield cfg, params, toks, want, got
-    jax.clear_caches()  # ROADMAP C13: a worker's compiled programs map memory
+# the indexer scaled down: 4 index heads of 8
+T, N = 203, 150
+CASE = ServedCase(
+    "keye_vl_2_0_30b_a3b", seq=T,
+    logit_tol=5e-5,  # fp32 against fp32 on logits of ~4: summation order only
+    over=dict(index_topk=Because(24, "T = 203 is more than eight times the rows a query keeps"),
+              max_seq_len=256),
+    constants=dict(query_tile=64),
+    moved=("scale", "bias"),  # biases off 0 too, so that a LayerNorm left out shows
+    bites=dict(index_topk=10 ** 6),  # the reference that keeps every row
+    # the selection past ``index_topk`` rows is the same whatever the chunking;
+    # from nothing: under and past index_topk rows
+    walk=Walk(n=N, piece=48, steps=T - N, cold=40, states=dict(atol=2e-5, rtol=2e-5)),
+    row_list=127,
+    scan=Scan(n=N, steps=8, layer=0, held=("k", "v", "ki"), carried=("kn", "vn", "kin", "t0")),
+    server=True,
+    cell=Cell("keye_vl_2_0_30b_a3b.serve_long", seed=2 ** 31 + 47),
+    # read on the parent of PR 59 (44d93ca) at this case's sizes; until then
+    # tests/test_trinity_mini.py pinned them at sizes of its own, where PR 56
+    # changed the piece and the step: a served held layer sows ``tiles_live``
+    # and ``experts_live`` (seven equations a layer, nothing else)
+    pins={"forward": "a731e1d746341ad8",
+          "piece": "40b4fe50520885e3", "step": "8c89c16d05a18f1b"},
+)
+served = served_fixture(CASE)
 
 
-def test_preset_is_the_published_shape():
-    cfg = get_config("keye_vl_2_0_30b_a3b")
-    assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (2048, 32, 4, 128)
-    assert (cfg.index_heads, cfg.index_dim, cfg.index_topk) == (16, 64, 2048)
-    assert (cfg.n_experts, cfg.moe_top_k, cfg.moe_hidden, cfg.moe_period) == (128, 8, 768, 1)
-    # every expert is here: no share of a wider router, and no width spelled out
-    assert not cfg.moe_held and cfg.moe_router_width == 0 and cfg.resolved_router_width == 128
-    assert masks_rows(cfg) and cfg.moe_shared_hidden == 0
-    assert (cfg.vocab_size, cfg.tie_embeddings, cfg.rotary_base) == (151936, False, 1e7)
-    assert cfg.resolved_layer_types == ("indexed",) * 4 and cfg.max_seq_len == 32768 + 512
-    shapes = jax.eval_shape(lambda: init_decode_state(cfg, 2))
-    assert [sorted(s) for s in shapes] == [["k", "ki", "v"]] * 4
-    assert shapes[0]["k"].shape == (2, 33280, 512) and shapes[0]["ki"].shape == (2, 33280, 64)
-    assert shapes[0]["ki"].dtype == jnp.bfloat16
-    tree = jax.eval_shape(
-        lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
-    layer = sum(x.size for x in jax.tree.leaves(tree["params"]["block_0"]))
-    assert layer == 625_381_760
-    assert sum(x.size for x in jax.tree.leaves(tree)) == 3_123_858_944
-    blk = tree["params"]["block_3"]
-    assert blk["attn"]["wqi"]["kernel"].shape == (2048, 16 * 64)
-    assert blk["attn"]["ki_norm"]["bias"].shape == (64,)
-    assert blk["mlp"]["experts_down"].shape == (128, 768, 2048)
-    # index_topk 0 is no field of any other preset's programs
-    assert all(get_config(n).index_topk == 0 for n in ("lm_1b3", "minicpm_sala", "qwen3_next_80b"))
+class TestServed(ServedContract):
+    case = CASE
 
+    def published(self, cfg):
+        assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (2048, 32, 4, 128)
+        assert (cfg.index_heads, cfg.index_dim, cfg.index_topk) == (16, 64, 2048)
+        assert (cfg.n_experts, cfg.moe_top_k, cfg.moe_hidden, cfg.moe_period) == (128, 8, 768, 1)
+        # every expert is here: no share of a wider router, and no width spelled out
+        assert not cfg.moe_held and cfg.moe_router_width == 0 and cfg.resolved_router_width == 128
+        assert masks_rows(cfg) and cfg.moe_shared_hidden == 0
+        assert (cfg.vocab_size, cfg.tie_embeddings, cfg.rotary_base) == (151936, False, 1e7)
+        assert cfg.resolved_layer_types == ("indexed",) * 4 and cfg.max_seq_len == 32768 + 512
+        shapes = jax.eval_shape(lambda: init_decode_state(cfg, 2))
+        assert [sorted(s) for s in shapes] == [["k", "ki", "v"]] * 4
+        assert shapes[0]["k"].shape == (2, 33280, 512) and shapes[0]["ki"].shape == (2, 33280, 64)
+        assert shapes[0]["ki"].dtype == jnp.bfloat16
+        tree = jax.eval_shape(
+            lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+        layer = sum(x.size for x in jax.tree.leaves(tree["params"]["block_0"]))
+        assert layer == 625_381_760
+        assert sum(x.size for x in jax.tree.leaves(tree)) == 3_123_858_944
+        blk = tree["params"]["block_3"]
+        assert blk["attn"]["wqi"]["kernel"].shape == (2048, 16 * 64)
+        assert blk["attn"]["ki_norm"]["bias"].shape == (64,)
+        assert blk["mlp"]["experts_down"].shape == (128, 768, 2048)
+        # index_topk 0 is no field of any other preset's programs
+        assert all(c.index_topk == 0 for c in other_presets(cfg.name))
 
-def test_model_matches_the_reference(model_params):
-    """Logits of the whole forward, T more than eight times ``index_topk``;
-    and the selection bites: the reference that keeps every row reads
-    differently."""
-    cfg, params, toks, want, got = model_params
-    np.testing.assert_allclose(got, want, atol=LOGIT_TOL)
-    with jax.default_matmul_precision("highest"):
-        dense = ref.forward(spec_of(cfg, index_topk=10 ** 6), params, toks)
-    assert float(jnp.abs(dense - want).max()) > 100 * LOGIT_TOL
+    def before_boundary(self, engine):
+        return engine.kv_rows_listed() + engine.index_piece_pairs()
+
+    def after_engine(self, served, run, backend, donate):
+        """The row counters follow the positions. With the carry donated the
+        scan reads the three caches and carries a chunk's own rows of each
+        (``chunk_split``)."""
+        cfg = run.cfg
+        listed, scored, visible, selected = (sum(x) for x in zip(*run.seen))
+        assert 0 < listed < scored and 0 < selected < visible
+        # the prompts' pieces: every (query, key) pair at or before the query, once
+        assert visible == sum(n * (n + 1) // 2 for n in (30, 90, 170))
+        assert selected == sum(sum(min(cfg.index_topk, i + 1) for i in range(n)) for n in (30, 90, 170))
+        # the last boundaries: the long request alone, past index_topk rows
+        assert run.seen[-1][0] == cfg.index_topk and run.seen[-1][1] >= 170
+        assert run.engine.held_bytes["kv_bytes"] == 2 * 4 * 256 * (2 * 32 + 8) * 4  # k, v and ki
+        assert run.engine.kv_rows()[1] == 4 * 256
+
+    def after_server(self, served, counters, prompts):
+        """The MoE row counters add up: every routed pair is held, none dropped."""
+        assert 0 < counters["kv_rows_listed"] < counters["index_rows_scored"]
+        assert 0 < counters["index_pairs_selected"] < counters["index_pairs_visible"]
+        assert counters["moe_rows_routed"] == counters["moe_rows_held"] > 0
+        assert counters["moe_rows_dropped"] == 0
+        # a prompt row routes top_k pairs a layer, once
+        prompt_pairs = sum(len(p) for p in prompts) * served.cfg.moe_top_k * served.cfg.n_layers
+        assert counters["moe_rows_routed"] >= prompt_pairs
+
+    def after_cell(self, result, lines):
+        assert 0 < result["metrics"]["kv_row_read_share.long"]["value"] < 100
+        assert result["metrics"]["moe_rows_dropped.batch"]["value"] == 0
 
 
 @pytest.mark.parametrize("patch", [
     "no ReLU", "an unscaled w", "no LayerNorm on kI", "rotary off the indexer",
     "a selection per KV head", "ties to the higher s", "k - 1", "an un-renormalised router"])
-def test_the_comparison_sees(model_params, monkeypatch, patch):
+def test_the_comparison_sees(served, monkeypatch, patch):
     """The tolerance is tight enough to tell the model from a reference that
     differs in one of the mechanisms."""
-    cfg, params, toks, want, got = model_params
-    spec = spec_of(cfg)
+    ref, cfg, spec = served.ref, served.cfg, served.spec()
     if patch == "no ReLU":
         monkeypatch.setattr(ref, "activation", lambda s: s)
     elif patch == "an unscaled w":
@@ -148,7 +155,7 @@ def test_the_comparison_sees(model_params, monkeypatch, patch):
         # half the index heads' rows makes many
         monkeypatch.setattr(ref, "activation", lambda s: jnp.floor(jax.nn.relu(s)))
     elif patch == "k - 1":
-        spec = spec_of(cfg, index_topk=cfg.index_topk - 1)
+        spec = served.spec(index_topk=cfg.index_topk - 1)
     else:
         def raw(spec, p, x):
             probs = jax.nn.softmax(x @ jnp.asarray(p["router"]["kernel"], jnp.float32), axis=-1)
@@ -156,17 +163,14 @@ def test_the_comparison_sees(model_params, monkeypatch, patch):
             return jnp.einsum("nk,nke->ne", top, jax.nn.one_hot(ids, probs.shape[-1]))
 
         monkeypatch.setattr(ref, "routing_weights", raw)
-    with jax.default_matmul_precision("highest"):
-        other = ref.forward(spec, params, toks)
     if patch == "ties to the higher s":
         # the floored activation is a different model: compare the two tie rules on it
+        other = served.reference(spec)
         monkeypatch.undo()
         monkeypatch.setattr(ref, "activation", lambda s: jnp.floor(jax.nn.relu(s)))
-        with jax.default_matmul_precision("highest"):
-            base = ref.forward(spec, params, toks)
-        assert float(jnp.abs(other - base).max()) > 20 * LOGIT_TOL, patch
+        assert float(jnp.abs(other - served.reference(spec)).max()) > 20 * CASE.logit_tol, patch
         return
-    assert float(jnp.abs(other - got).max()) > 20 * LOGIT_TOL, patch
+    served.differs(spec)
 
 
 def _top_k_set(scores, valid, k):
@@ -282,87 +286,6 @@ def test_grouped_product_blocks_divide_an_expert_768_wide():
     assert " pad" in traced(800)  # no block of whole lanes divides it
 
 
-@pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
-def test_prefill_equals_pieces_equals_the_decode_walk(model_params, backend):
-    """``prefill`` = pieces of ``prefill_extend`` (a padded last piece) =
-    ``decode_step`` token by token: every state leaf and the logits, the
-    selection past ``index_topk`` rows the same whatever the chunking."""
-    cfg, params, toks, _, full = model_params
-    cfg = dataclasses.replace(cfg, backend=backend)
-    model = TransformerLM(cfg)
-    n = 150
-    logits, states = jax.jit(lambda x: model.apply(params, x, method="prefill"))(toks[:, :n])
-    np.testing.assert_allclose(logits, full[:, :n], atol=LOGIT_TOL)
-    st, off = init_decode_state(cfg, 2), 0
-    extend = jax.jit(lambda *a: model.apply(params, *a, method="prefill_extend_step"))
-    for real in (48, 48, 48, 6):
-        piece = jnp.pad(toks[:, off:off + real], ((0, 0), (0, 48 - real)))
-        last, st = extend(piece, st, jnp.int32(off), jnp.int32(real))
-        off += real
-    np.testing.assert_allclose(last, full[:, n - 1], atol=LOGIT_TOL)
-    for a, b in zip(jax.tree.leaves(st), jax.tree.leaves(states)):
-        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
-    rows = dispatch.decode_live_rows(jnp.ones((2,), bool), backend=backend)
-    step = jax.jit(lambda tok, st, t, rows: model.apply(params, tok, st, t, rows, method="decode_step"))
-    for t in range(n, T):
-        out, states = step(toks[:, t], states, jnp.full((2,), t), rows)
-        np.testing.assert_allclose(out, full[:, t], atol=LOGIT_TOL)
-    st = init_decode_state(cfg, 2)
-    for t in range(40):  # from nothing, one position for all: under and past index_topk rows
-        out, st = step(toks[:, t], st, jnp.int32(t), None)
-        np.testing.assert_allclose(out, full[:, t], atol=LOGIT_TOL)
-
-
-@pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
-def test_a_scan_that_holds_the_cache_walks_as_one_that_carries_it(model_params, backend):
-    """``chunk_split`` / ``chunk_merge``: eight decode steps over K, V and
-    the index keys held read-only, the chunk's own rows of each scored,
-    selected and attended beside them, give the plain walk's logits and,
-    merged, its state; with a row list the sequence it leaves out keeps
-    every bit. A program that returns a new carry carries everything."""
-    cfg, params, toks, _, _ = model_params
-    cfg = dataclasses.replace(cfg, backend=backend)
-    model, kinds = TransformerLM(cfg), cfg.resolved_layer_types
-    n, steps = 150, 8
-    _, start = jax.jit(lambda x: model.apply(params, x, method="prefill"))(toks[:, :n])
-    whole = MIXERS["indexed"].chunk_split(cfg, "indexed", start[0], steps, jnp.full((2,), n), False)
-    assert whole[0] == {} and whole[1] is start[0]
-    for mask in ([True, True], [True, False]):
-        live = jnp.array(mask)
-        rows = dispatch.decode_live_rows(live, backend=backend)
-        if rows is None and not all(mask):
-            continue  # without a list the decode programs freeze rows themselves
-        split = [MIXERS[lt].chunk_split(cfg, lt, st, steps, jnp.full((2,), n), True)
-                 for lt, st in zip(kinds, start)]
-        held, carried, plain = [h for h, _ in split], [c for _, c in split], start
-        assert set(held[0]) == {"k", "v", "ki"} and set(carried[0]) == {"kn", "vn", "kin", "t0"}
-        step = jax.jit(lambda tok, st, t, rows: model.apply(params, tok, st, t, rows, method="decode_step"))
-        for t in range(n, n + steps):
-            at = jnp.where(live, t, n)  # a sequence that is not emitting holds its position
-            want, plain = step(toks[:, t], plain, at, rows)
-            out, new = step(toks[:, t], [{**h, **c} for h, c in zip(held, carried)], at, rows)
-            carried = [{name: st[name] for name in c} for st, c in zip(new, carried)]
-            np.testing.assert_allclose(out[live], want[live], atol=LOGIT_TOL)
-        merged = [MIXERS[lt].chunk_merge(cfg, lt, h, c, live)
-                  for lt, h, c in zip(kinds, held, carried)]
-        for got, want, old in zip(*(jax.tree.leaves(x) for x in (merged, plain, start))):
-            np.testing.assert_allclose(got[live], want[live], atol=2e-5, rtol=2e-5)
-            assert bool((got[~live] == old[~live]).all())
-
-
-def test_decode_step_with_a_row_list_touches_no_other_row(model_params):
-    cfg, params, toks, _, _ = model_params
-    model = TransformerLM(dataclasses.replace(cfg, backend="pallas_interpret"))
-    _, states = jax.jit(lambda x: model.apply(params, x, method="prefill"))(toks[:, :127])
-    states = jax.tree.map(lambda x: jnp.concatenate([x, x[:1] + 1], axis=0), states)  # 3 rows
-    rows = dispatch.decode_live_rows(jnp.array([True, False, True]), backend="pallas_interpret")
-    _, new = jax.jit(lambda *a: model.apply(params, *a, method="decode_step"))(
-        jnp.array([5, 6, 7]), states, jnp.array([127, 127, 127]), rows)
-    for old, now in zip(jax.tree.leaves(states), jax.tree.leaves(new)):
-        assert bool((now[1] == old[1]).all())
-        assert not bool((now[0] == old[0]).all())
-
-
 @pytest.mark.parametrize("width", [0, 8])
 @pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
 def test_rows_outside_live_route_nowhere_with_every_expert_held(backend, width):
@@ -372,11 +295,11 @@ def test_rows_outside_live_route_nowhere_with_every_expert_held(backend, width):
     nothing drops, and the live rows read what the layer gives without a
     mask; without ``live`` it is the ordinary dropless layer and counts
     nothing. The two spellings trace to one program either way."""
-    cfg = tiny_cfg(backend, moe_ep_buffer=1.0, moe_router_width=width)
+    cfg = tiny_cfg(CASE, backend, moe_ep_buffer=1.0, moe_router_width=width)
     assert not cfg.moe_held and masks_rows(cfg)
     x = jax.random.normal(jax.random.key(2), (1, 24, cfg.d_model))
     layer = MoEMLP(cfg)
-    params = layer.init(jax.random.key(0), x)
+    params = jax.jit(layer.init)(jax.random.key(0), x)
     live = jnp.arange(24)[None, :] < 17
     y, sown = layer.apply(params, x, live, mutable=["moe_stats"])
     stats = {k: int(v[0]) for k, v in sown["moe_stats"].items()}
@@ -396,7 +319,7 @@ def test_a_layer_that_cannot_mask_rows_ignores_live():
     """``masks_rows`` is decided by what the layer IS, not by a field that
     names a path: a capacity layer, an int8 one and one on a mesh of several
     devices have no held-rows form, and compute every row as they did."""
-    cfg = tiny_cfg()
+    cfg = tiny_cfg(CASE)
     assert masks_rows(cfg) and not masks_rows(cfg, quant="int8")
     assert not masks_rows(dataclasses.replace(cfg, moe_dropless=False))
     assert not masks_rows(dataclasses.replace(cfg, n_experts=0))
@@ -404,108 +327,18 @@ def test_a_layer_that_cannot_mask_rows_ignores_live():
     assert not masks_rows(cfg, mesh=mesh)
     capacity = MoEMLP(dataclasses.replace(cfg, moe_dropless=False))
     x = jax.random.normal(jax.random.key(2), (1, 24, cfg.d_model))
-    params = capacity.init(jax.random.key(0), x)
+    params = jax.jit(capacity.init)(jax.random.key(0), x)
     live = jnp.arange(24)[None, :] < 17
     np.testing.assert_array_equal(capacity.apply(params, x, live), capacity.apply(params, x))
 
 
-def serve(cfg, params, prompts, max_new, donate=False):
-    engine = SlotEngine(TransformerLM(cfg), params, slots=4, chunk=4,
-                        prefill_buckets=(64, 128, 256), prefill_chunk=32)
-    engine.donate_carry = donate
-    for i, p in enumerate(prompts):
-        engine.admit(DecodeRequest(prompt=p, max_new_tokens=max_new, sample=GREEDY, seed=i), tag=i)
-    done, seen = {}, []
-    while engine.busy:
-        seen.append(engine.kv_rows_listed() + engine.index_piece_pairs())
-        for tag, res in engine.step():
-            assert res.status == "ok", res.status
-            done[tag] = np.asarray(res.tokens).reshape(-1)
-    return [done[i] for i in range(len(prompts))], seen, engine
-
-
-@pytest.mark.parametrize("backend,donate", [
-    ("xla", False), ("pallas_interpret", False), ("xla", True), ("pallas_interpret", True)])
-def test_engine_serves_as_generate(model_params, backend, donate):
-    """Through ``SlotEngine``: three requests of one, three and six pieces
-    resident together, pieces and decode interleaved; each request's ids are
-    ``generate()``'s for it alone, and the row counters follow the
-    positions. With the carry donated the scan reads the three caches and
-    carries a chunk's own rows of each (``chunk_split``)."""
-    cfg, params, toks, _, _ = model_params
-    cfg = dataclasses.replace(cfg, backend=backend)
-    prompts = [np.asarray(toks[0, :30]), np.asarray(toks[1, :90]), np.asarray(toks[0, 20:190])]
-    together, seen, engine = serve(cfg, params, prompts, 9, donate)
-    xla = dataclasses.replace(cfg, backend="xla")
-    for p, ids in zip(prompts, together):
-        alone = generate(TransformerLM(xla), params, jnp.asarray(p)[None], 9, GREEDY)
-        np.testing.assert_array_equal(ids, np.asarray(alone)[0, -9:])
-    listed, scored, visible, selected = (sum(x) for x in zip(*seen))
-    assert 0 < listed < scored and 0 < selected < visible
-    # the prompts' pieces: every (query, key) pair at or before the query, once
-    assert visible == sum(n * (n + 1) // 2 for n in (30, 90, 170))
-    assert selected == sum(sum(min(cfg.index_topk, i + 1) for i in range(n)) for n in (30, 90, 170))
-    # the last boundaries: the long request alone, past index_topk rows
-    assert seen[-1][0] == cfg.index_topk and seen[-1][1] >= 170
-    assert engine.held_bytes["kv_bytes"] == 2 * 4 * 256 * (2 * 32 + 8) * 4  # k, v and ki
-    assert engine.kv_rows()[1] == 4 * 256
-
-
-def test_server_answers_as_generate(model_params):
-    """The ``Server`` over the tiny preset: 4 slots, five requests, pieces
-    and decode interleaved; every answer is ``generate()``'s, and the MoE
-    row counters add up: every routed pair is held, none dropped."""
-    cfg, params, toks, _, _ = model_params
-    model = TransformerLM(cfg)
-    srv = Server(model, params, ServeConfig(chunk=4, slots=4, max_inflight=8, prefill_chunk=32,
-                                            prefill_buckets="64,128,256", cost=False))
-    prompts = [np.asarray(toks[i % 2, a:b]) for i, (a, b) in
-               enumerate([(0, 100), (0, 20), (50, 200), (10, 75), (3, 150)])]
-    handles = [srv.submit(DecodeRequest(prompt=p, max_new_tokens=7, sample=GREEDY, seed=i))
-               for i, p in enumerate(prompts)]
-    srv.serve(drain_when_idle=True)
-    counters = srv.metrics.counters_flat()
-    srv.close()
-    for p, h in zip(prompts, handles):
-        assert h.result.status == "ok"
-        alone = generate(model, params, jnp.asarray(p)[None], 7, GREEDY)
-        np.testing.assert_array_equal(np.asarray(h.result.tokens).reshape(-1), np.asarray(alone)[0, -7:])
-    assert 0 < counters["kv_rows_listed"] < counters["index_rows_scored"]
-    assert 0 < counters["index_pairs_selected"] < counters["index_pairs_visible"]
-    assert counters["moe_rows_routed"] == counters["moe_rows_held"] > 0
-    assert counters["moe_rows_dropped"] == 0
-    # a prompt row routes top_k pairs a layer, once
-    prompt_pairs = sum(len(p) for p in prompts) * cfg.moe_top_k * cfg.n_layers
-    assert counters["moe_rows_routed"] >= prompt_pairs
-
-
 def test_rows_listed_is_the_list_the_step_builds():
-    cfg = tiny_cfg()
+    cfg = tiny_cfg(CASE)
     assert [rows_listed(cfg, n) for n in (1, 23, 24, 25, 200)] == [1, 23, 24, 24, 24]
     big = get_config("keye_vl_2_0_30b_a3b")
     assert rows_listed(big, 2048) == 2048 == rows_listed(big, 33280)
     assert MIXERS["indexed"].cache_rows_read(big, "indexed", 20480) == 2048
     assert MIXERS["indexed"].cache_leaves == ("k", "v", "ki")
-
-
-def test_cell_rehearses_on_the_cpu(tmp_path):
-    """``keye_vl_2_0_30b_a3b.serve_long`` end to end at tiny sizes: the served
-    kind, the reference named by the configuration's file, the check on what
-    was served in the window, the new counters' metrics."""
-    import json
-    import subprocess
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT,
-               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
-    out = subprocess.run(
-        [sys.executable, "benchmark/run.py", "--workload", "keye_vl_2_0_30b_a3b.serve_long",
-         "--seed", str(2 ** 31 + 47), "--seconds", "3", "--trace", "1", "--rehearse"],
-        cwd=ROOT, env=env, capture_output=True, text=True, timeout=1200)
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["correct"] and line["failed"] == 0
-    assert 0 < line["metrics"]["kv_row_read_share.long"]["value"] < 100
-    assert line["metrics"]["moe_rows_dropped.batch"]["value"] == 0
 
 
 # -- the cell's rooflines: work and time of the SAME captured boundaries ----------

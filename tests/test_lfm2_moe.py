@@ -3,125 +3,111 @@ layers whose whole decode state is the last two rows of ``b * u``, one
 full-attention layer in four (per-head q / k norms, rotary), a dense layer
 then a sigmoid top-k mixture with a per-expert selection bias whose weights
 are divided by the chosen's sum ``+ 1e-6``, every expert held, a tied head;
-against ``benchmark/reference/plain_lfm2_moe.py``; tiny, CPU, fp32.
+against ``benchmark/reference/plain_lfm2_moe.py``; tiny, CPU, fp32. The
+contract every served configuration takes is ``tests/served_contract.py``'s.
 
-No depth-share test is needed: every expert and the whole vocabulary are
-held, so there is no share whose parts would have to add up."""
+No depth-share test is needed (``share=None``): every expert and the whole
+vocabulary are held, so there is no share whose parts would have to add up."""
 
 import dataclasses
 import json
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from served_contract import (
+    ROOT, Because, ServedCase, ServedContract, Walk, other_presets, served_fixture, tiny_cfg,
+)
 
-from orion_tpu.generate import SampleConfig, generate
 from orion_tpu.models.configs import get_config
 from orion_tpu.models.mixers import MIXERS
 from orion_tpu.models.moe import MoEMLP, masks_rows
 from orion_tpu.models.transformer import TransformerLM, init_decode_state
-from orion_tpu.serving import DecodeRequest, SlotEngine
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(ROOT, "benchmark"))
-from reference import plain_lfm2_moe as ref  # noqa: E402
 
 # the published pattern's head: conv, conv (both dense), then one whole period
 # (full_attention, conv, conv, conv) of expert layers
 KINDS = ("gated_conv", "gated_conv", "softmax", "gated_conv", "gated_conv", "gated_conv")
-TINY = dict(n_layers=6, layer_types=KINDS, moe_first_dense=2, vocab_size=256, d_model=64,
-            n_heads=4, n_kv_heads=2, head_dim=16, mlp_hidden=128, moe_hidden=32, n_experts=8,
-            moe_top_k=2, moe_route_bias=0.5, embed_init_std=None, max_seq_len=64,
-            dtype="float32", param_dtype="float32")
 T = 29
 P = 8  # rows of a prompt piece
-LOGIT_TOL = 5e-5  # fp32 against fp32 on logits of ~4: summation order only
-GREEDY = SampleConfig(temperature=0.0)
+CASE = ServedCase(
+    "lfm2_8b_a1b", seq=T,
+    logit_tol=5e-5,  # fp32 against fp32 on logits of ~4: summation order only
+    over=dict(
+        n_layers=6, layer_types=KINDS, moe_first_dense=2, max_seq_len=64,
+        d_model=Because(64, "as every other file's: the tails' byte counts below are asserted at it"),
+        moe_route_bias=Because(0.5, "large enough that the bias moves a choice"),
+        embed_init_std=Because(None, "flax's own: logits of ~4, which the tolerance was read at")),
+    constants=dict(query_tile=16),
+    # a whole-prompt ``prefill`` padded to a bucket, its state taken at the real
+    # length, then steps; pieces of 8 and 5 of 8
+    walk=Walk(n=13, piece=P, steps=4, steps_from="padded", padded=3, against="reference",
+              backends=("xla",)),
+    # the chip's programs: the row lists, the conv kernel in the pieces, a
+    # piece a program, the carry held once
+    engines=(("pallas_interpret", True),),
+    engine=dict(slots=4, chunk=4, prefill_buckets=(8, 16, 32), prefill_chunk=8),
+    prompts=((0, 0, 5), (1, 0, 8), (0, 3, 29)),
+    served_gap=5e-5,  # the reference's own choice to a logit gap of rounding
+)
+served = served_fixture(CASE)
 
 
-def tiny_cfg(backend="xla", **over):
-    return dataclasses.replace(get_config("lfm2_8b_a1b"), backend=backend, **{**TINY, **over})
+class TestServed(ServedContract):
+    case = CASE
 
+    def published(self, cfg):
+        assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (2048, 32, 8, 64)
+        assert cfg.resolved_layer_types == ("gated_conv",) + ("softmax", "gated_conv", "gated_conv", "gated_conv") * 3
+        assert cfg.qk_norm == "head" and cfg.rotary_base == 1e6
+        assert cfg.rotary and cfg.rotary_layers is None and not cfg.attn_gate and cfg.attn_scale is None
+        assert (cfg.n_experts, cfg.moe_top_k, cfg.moe_hidden, cfg.mlp_hidden) == (32, 4, 1792, 7168)
+        assert (cfg.moe_score, cfg.moe_route_scale, cfg.moe_first_dense) == ("sigmoid", 1.0, 1)
+        assert cfg.moe_route_bias > 0 and cfg.moe_gate_eps == 1e-6 and not cfg.moe_shared_hidden
+        assert not cfg.moe_held and masks_rows(cfg) and cfg.resolved_router_width == 32
+        assert [cfg.moe_at(i) for i in range(13)] == [False] + [True] * 12
+        assert (cfg.vocab_size, cfg.tie_embeddings, cfg.max_seq_len) == (65536, True, 2048 + 512)
+        assert cfg.norm_eps == 1e-5 and cfg.norm_placement == "pre" and cfg.pos_embed == "none"
+        shapes = jax.eval_shape(lambda: init_decode_state(cfg, 2))
+        assert [sorted(s) for s in shapes] == [["conv"]] + [["k", "v"], ["conv"], ["conv"], ["conv"]] * 3
+        assert shapes[0]["conv"].shape == (2, 2 * 2048)  # two rows a slot: 8 KB in bf16
+        assert shapes[1]["k"].shape == (2, 8, 2560, 64)
+        tree = jax.eval_shape(
+            lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
+        count = lambda t: sum(x.size for x in jax.tree.leaves(t))  # noqa: E731
+        assert count(tree["params"]["block_0"]["attn"]) == 16_783_360
+        assert count(tree["params"]["block_1"]["attn"]) == 10_485_888
+        assert count(tree["params"]["block_0"]) == 60_827_648
+        assert count(tree["params"]["block_2"]["mlp"]) == 352_387_104
+        assert count(tree) == 4_606_249_728
+        assert "lm_head_kernel" not in tree["params"]
+        blk = tree["params"]["block_2"]
+        assert blk["attn"]["in_proj"]["kernel"].shape == (2048, 6144) and blk["attn"]["conv"].shape == (3, 2048)
+        assert blk["mlp"]["router_bias"].shape == (32,) and blk["mlp"]["router_bias"].dtype == jnp.float32
+        # the new layer type is no part of any other preset's programs; the
+        # gates' epsilon is, of the one later mixture that publishes one
+        others = other_presets(cfg.name)
+        assert all("gated_conv" not in c.resolved_layer_types for c in others)
+        assert {c.name for c in others if c.moe_gate_eps} == {"nemotron_3_super_120b"}
 
-def spec_of(cfg, **over):
-    return {**dict(
-        layer_types=cfg.resolved_layer_types, conv_layers="gated_conv", n_heads=cfg.n_heads,
-        n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim, rope_base=cfg.rotary_base,
-        norm_eps=cfg.norm_eps, top_k=cfg.moe_top_k, route_scale=cfg.moe_route_scale,
-        gate_eps=cfg.moe_gate_eps, query_tile=16), **over}
-
-
-@pytest.fixture(scope="module")
-def model_params():
-    cfg = tiny_cfg()
-    model = TransformerLM(cfg)
-    toks = jax.random.randint(jax.random.key(1), (2, T), 0, cfg.vocab_size)
-    params = jax.jit(model.init)(jax.random.key(0), toks[:, :16])
-    # norm weights off 1, so that a norm left out shows
-    params = jax.tree_util.tree_map_with_path(
-        lambda path, x: x + 0.3 * jax.random.normal(jax.random.key(len(str(path))), x.shape)
-        if "scale" in str(path) else x, params)
-    with jax.default_matmul_precision("highest"):
-        want = ref.forward(spec_of(cfg), params, toks)
-        got = model.apply(params, toks)
-    yield cfg, params, toks, want, got
-    jax.clear_caches()  # ROADMAP C13: a worker's compiled programs map memory
-
-
-def test_preset_is_the_published_shape():
-    cfg = get_config("lfm2_8b_a1b")
-    assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (2048, 32, 8, 64)
-    assert cfg.resolved_layer_types == ("gated_conv",) + ("softmax", "gated_conv", "gated_conv", "gated_conv") * 3
-    assert cfg.qk_norm == "head" and cfg.rotary_base == 1e6
-    assert cfg.rotary and cfg.rotary_layers is None and not cfg.attn_gate and cfg.attn_scale is None
-    assert (cfg.n_experts, cfg.moe_top_k, cfg.moe_hidden, cfg.mlp_hidden) == (32, 4, 1792, 7168)
-    assert (cfg.moe_score, cfg.moe_route_scale, cfg.moe_first_dense) == ("sigmoid", 1.0, 1)
-    assert cfg.moe_route_bias > 0 and cfg.moe_gate_eps == 1e-6 and not cfg.moe_shared_hidden
-    assert not cfg.moe_held and masks_rows(cfg) and cfg.resolved_router_width == 32
-    assert [cfg.moe_at(i) for i in range(13)] == [False] + [True] * 12
-    assert (cfg.vocab_size, cfg.tie_embeddings, cfg.max_seq_len) == (65536, True, 2048 + 512)
-    assert cfg.norm_eps == 1e-5 and cfg.norm_placement == "pre" and cfg.pos_embed == "none"
-    shapes = jax.eval_shape(lambda: init_decode_state(cfg, 2))
-    assert [sorted(s) for s in shapes] == [["conv"]] + [["k", "v"], ["conv"], ["conv"], ["conv"]] * 3
-    assert shapes[0]["conv"].shape == (2, 2 * 2048)  # two rows a slot: 8 KB in bf16
-    assert shapes[1]["k"].shape == (2, 8, 2560, 64)
-    tree = jax.eval_shape(
-        lambda: TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))
-    count = lambda t: sum(x.size for x in jax.tree.leaves(t))  # noqa: E731
-    assert count(tree["params"]["block_0"]["attn"]) == 16_783_360
-    assert count(tree["params"]["block_1"]["attn"]) == 10_485_888
-    assert count(tree["params"]["block_0"]) == 60_827_648
-    assert count(tree["params"]["block_2"]["mlp"]) == 352_387_104
-    assert count(tree) == 4_606_249_728
-    assert "lm_head_kernel" not in tree["params"]
-    blk = tree["params"]["block_2"]
-    assert blk["attn"]["in_proj"]["kernel"].shape == (2048, 6144) and blk["attn"]["conv"].shape == (3, 2048)
-    assert blk["mlp"]["router_bias"].shape == (32,) and blk["mlp"]["router_bias"].dtype == jnp.float32
-    # the new fields are no part of any other preset's programs
-    others = [get_config(n) for n in (
-        "lm_1b3", "hybrid_1b3", "olmo_hybrid_7b", "granite_4_0_h_micro", "minicpm_sala",
-        "openpangu_ultra_moe_718b", "keye_vl_2_0_30b_a3b", "qwen3_next_80b", "trinity_mini")]
-    assert all(not c.moe_gate_eps and "gated_conv" not in c.resolved_layer_types for c in others)
-
-
-def test_model_matches_the_reference(model_params):
-    """Logits of the whole forward through every layer kind."""
-    cfg, params, toks, want, got = model_params
-    np.testing.assert_allclose(got, want, atol=LOGIT_TOL)
+    def after_engine(self, served, run, backend, donate):
+        """The engine's memory account names the tails."""
+        cfg, held = run.cfg, run.engine.held_bytes
+        assert held["tail_bytes"] == 4 * 5 * 2 * cfg.d_model * 4  # 5 conv layers, two fp32 rows a slot
+        assert held["kv_bytes"] == 4 * 2 * 2 * cfg.max_seq_len * 16 * 4 and held["ring_bytes"] == 0
+        assert held["state_bytes"] == held["tail_bytes"]  # nothing else: no state matrix
+        assert run.engine.kv_rows()[1] == 4 * cfg.max_seq_len
 
 
 @pytest.mark.parametrize("patch", [
     "no b gate", "no c gate", "a SiLU on the conv", "the taps reversed", "the split order [b | u | c]",
     "the bias in the weights", "no bias", "no normalisation over the chosen", "no rotary",
     "no q norm", "no k norm", "an untied head"])
-def test_the_comparison_sees(model_params, monkeypatch, patch):
+def test_the_comparison_sees(served, monkeypatch, patch):
     """The tolerance is tight enough to tell the model from a reference that
     differs in one of the mechanisms."""
-    cfg, params, toks, want, got = model_params
-    spec = spec_of(cfg)
+    ref, cfg, params = served.ref, served.cfg, served.params
     if patch == "no b gate":
         monkeypatch.setattr(ref, "split_in", lambda proj: (
             jnp.ones_like(proj[..., :cfg.d_model]), *jnp.split(proj, 3, axis=-1)[1:]))
@@ -166,9 +152,7 @@ def test_the_comparison_sees(model_params, monkeypatch, patch):
         monkeypatch.setattr(ref, "logits", lambda spec, p, x, columns=None: plain(
             spec, {"params": {**p["params"], "embed": {
                 "embedding": jnp.roll(p["params"]["embed"]["embedding"], 1, axis=0)}}}, x, columns))
-    with jax.default_matmul_precision("highest"):
-        other = ref.forward(spec, params, toks)
-    assert float(jnp.abs(other - got).max()) > 20 * LOGIT_TOL, patch
+    served.differs(params=params)
 
 
 # -- the serving path: prefill, pieces and steps against ONE full forward -----------
@@ -192,25 +176,15 @@ def _pieces(model, params, piece, toks, n, fault=None):
     return out, states
 
 
-@pytest.fixture(scope="module")
-def programs(model_params):
-    cfg, params, *_ = model_params
-    model = TransformerLM(cfg)
-    piece = jax.jit(lambda p, x, st, off, n: model.apply(
-        p, x, st, off, n, method=model.prefill_extend_step))
-    step = jax.jit(lambda p, tok, st, t: model.apply(p, tok, st, t, method=model.decode_step))
-    return model, piece, step
-
-
-def _two_slots(programs, model_params, fault=None):
+def _two_slots(served, fault=None):
     """Slot 0 takes 19 tokens of sequence 0 (pieces of 8, 8 and 3 padded to 8),
     slot 1 takes 17 of sequence 1 (8, 8 and ONE row: shorter than the conv's
     two-row tail, so the new tail is a row of the old and the row); then both
     decode together at their own positions, teacher-forced, slot 1 sitting
     out the third step (its state selected back, as the decode programs do
     for a row that is not emitting). Yields (sequence, position, logits)."""
-    model, piece, step = programs
-    cfg, params, toks, *_ = model_params
+    prog, params, toks = served.programs(), served.params, served.toks
+    model, piece, step = prog.model, prog.piece, prog.step
     starts, read, rows = (19, 17), [], []
     for b, n in enumerate(starts):
         got, states = _pieces(model, params, piece, toks[b], n, fault)
@@ -232,57 +206,38 @@ def _two_slots(programs, model_params, fault=None):
     return read
 
 
-def test_pieces_then_steps_match_one_full_forward(programs, model_params):
+def test_pieces_then_steps_match_one_full_forward(served):
     """``prefill_extend`` in pieces (one padded, every boundary inside the
     three-tap window, one piece shorter than the tail) then ``decode_step``s
     of two slots at different positions: every logit row read on the way is
     the reference's full forward's at that position."""
-    cfg, params, toks, want, _ = model_params
-    read = _two_slots(programs, model_params)
+    read = _two_slots(served)
     assert len(read) == 6 + 8 + 7 and {b for b, *_ in read} == {0, 1}
     for b, pos, logits in read:
-        np.testing.assert_allclose(logits, want[b, pos], atol=LOGIT_TOL, err_msg=f"{b} {pos}")
-
-
-def test_prefill_then_steps_match_one_full_forward(programs, model_params):
-    """A whole-prompt ``prefill`` padded to a bucket, its state taken at the
-    real length, then steps."""
-    model, _, step = programs
-    cfg, params, toks, want, _ = model_params
-    n = 13
-    padded = jnp.zeros((1, 16), jnp.int32).at[0, :n].set(toks[0, :n])
-    logits, states = jax.jit(lambda p, x, n: model.apply(p, x, n, method=model.prefill_last))(
-        params, padded, jnp.int32(n))
-    np.testing.assert_allclose(logits[0], want[0, n - 1], atol=LOGIT_TOL)
-    states = jax.tree.map(lambda a: jnp.concatenate([a, a]), states)
-    for pos in range(n, n + 4):
-        logits, states = step(params, jnp.asarray([toks[0, pos]] * 2), states,
-                              jnp.full((2,), pos, jnp.int32))
-        np.testing.assert_allclose(logits[1], want[0, pos], atol=LOGIT_TOL)
+        np.testing.assert_allclose(logits, served.want[b, pos], atol=CASE.logit_tol, err_msg=f"{b} {pos}")
 
 
 @pytest.mark.parametrize("fault", [
     "the tail dropped at a piece boundary", "the tail taken at the padded length",
     "an unlisted row's tail moved by a step"])
-def test_the_served_comparison_sees(programs, model_params, fault):
+def test_the_served_comparison_sees(served, fault):
     """A serving path that mishandles the two-row tail reads logits that are
     not the reference's."""
-    cfg, params, toks, want, _ = model_params
-    read = _two_slots(programs, model_params, fault)
-    worst = max(float(jnp.abs(logits - want[b, pos]).max()) for b, pos, logits in read)
-    assert worst > 20 * LOGIT_TOL, (fault, worst)
+    read = _two_slots(served, fault)
+    worst = max(float(jnp.abs(logits - served.want[b, pos]).max()) for b, pos, logits in read)
+    assert worst > 20 * CASE.logit_tol, (fault, worst)
 
 
 def test_a_step_leaves_an_unlisted_rows_tail_where_it_is():
     """The mixer's own step under a row list: the tail of a row outside the
     list keeps its bits, a listed row's moves up by the token's ``b * u``, and
     the listed rows' outputs are what a step of every row gives them."""
-    cfg = tiny_cfg()
+    cfg = tiny_cfg(CASE)
     mixer = MIXERS["gated_conv"](cfg, "gated_conv")
     assert MIXERS["gated_conv"].rows_in_place and MIXERS["gated_conv"].tail_leaves == ("conv",)
     assert MIXERS["gated_conv"].cache_leaves == () and MIXERS["gated_conv"].cache_rows(cfg, "gated_conv") == 0
     x = jax.random.normal(jax.random.key(0), (3, cfg.d_model))
-    params = mixer.init(jax.random.key(1), x[:, None])
+    params = jax.jit(mixer.init)(jax.random.key(1), x[:, None])
     state = {"conv": jax.random.normal(jax.random.key(2), (3, 2 * cfg.d_model))}
     t = jnp.zeros((3,), jnp.int32)
     rows = (jnp.array([0, 2, 0], jnp.int32), jnp.array([2], jnp.int32))  # rows 0 and 2 listed
@@ -295,11 +250,11 @@ def test_a_step_leaves_an_unlisted_rows_tail_where_it_is():
     np.testing.assert_array_equal(listed[jnp.array([0, 2])], every[jnp.array([0, 2])])
 
 
-def test_the_gates_divide_by_the_sum_plus_1e_6():
+def test_the_gates_divide_by_the_sum_plus_1e_6(served):
     """A router whose scores are small (a chosen pair sums to ~0.01) shows the
     published ``+ 1e-6`` at 1e-4 of the layer's output: the layer is the
     reference's with it and is not the reference's without."""
-    cfg = tiny_cfg(moe_route_bias=0.01)
+    cfg = tiny_cfg(CASE, moe_route_bias=0.01)
     layer = MoEMLP(cfg)
     x = jax.random.normal(jax.random.key(2), (1, 24, cfg.d_model))
     params = jax.jit(layer.init)(jax.random.key(0), x)
@@ -310,53 +265,13 @@ def test_the_gates_divide_by_the_sum_plus_1e_6():
     p["router"]["kernel"] = p["router"]["kernel"] - 5.3 / cfg.d_model
     got = jax.jit(layer.apply)({"params": p}, x)
     with jax.default_matmul_precision("highest"):
-        want = ref.mlp(spec_of(cfg), p, x)
-        without = ref.mlp(spec_of(cfg, gate_eps=0.0), p, x)
+        want = served.ref.mlp(served.spec(cfg), p, x)
+        without = served.ref.mlp(served.spec(cfg, gate_eps=0.0), p, x)
     scale = float(jnp.abs(want).max())
     assert float(jnp.abs(got - want).max()) < 1e-5 * scale
     assert float(jnp.abs(got - without).max()) > 5e-5 * scale
     plain = jax.jit(MoEMLP(dataclasses.replace(cfg, moe_gate_eps=0.0)).apply)({"params": p}, x)
     assert float(jnp.abs(plain - without).max()) < 1e-5 * scale  # 0: the router as it was
-
-
-# -- through the engine ---------------------------------------------------------------
-
-
-def test_engine_serves_as_generate_and_as_the_reference(model_params):
-    """Through ``SlotEngine`` under the interpreted kernels with the carry
-    held once (the chip's programs: the row lists, the conv kernel in the
-    pieces, a piece a program): three requests resident
-    together at different positions, pieces and decode interleaved. Each
-    request's ids are ``generate()``'s for it alone on the XLA backend, and,
-    teacher-forced through the reference's ONE full forward, each served id
-    is the reference's own choice to a logit gap of rounding; the engine's
-    memory account names the tails."""
-    cfg, params, toks, _, _ = model_params
-    served = dataclasses.replace(cfg, backend="pallas_interpret")
-    prompts = [np.asarray(toks[0, :5]), np.asarray(toks[1, :8]), np.asarray(toks[0, 3:29])]
-    engine = SlotEngine(TransformerLM(served), params, slots=4, chunk=4,
-                        prefill_buckets=(8, 16, 32), prefill_chunk=8)
-    engine.donate_carry = True
-    for i, p in enumerate(prompts):
-        engine.admit(DecodeRequest(prompt=p, max_new_tokens=9, sample=GREEDY, seed=i), tag=i)
-    done = {}
-    while engine.busy:
-        for tag, res in engine.step():
-            assert res.status == "ok", res.status
-            done[tag] = np.asarray(res.tokens).reshape(-1)
-    for i, p in enumerate(prompts):
-        alone = generate(TransformerLM(cfg), params, jnp.asarray(p)[None], 9, GREEDY)
-        np.testing.assert_array_equal(done[i], np.asarray(alone)[0, -9:])
-        whole = jnp.concatenate([jnp.asarray(p), jnp.asarray(done[i])])[None]
-        with jax.default_matmul_precision("highest"):
-            logits = ref.forward(spec_of(cfg), params, whole)[0, len(p) - 1:-1]
-        mine = jnp.take_along_axis(logits, jnp.asarray(done[i])[:, None], axis=-1)[:, 0]
-        assert float((logits.max(-1) - mine).max()) <= LOGIT_TOL
-    held = engine.held_bytes
-    assert held["tail_bytes"] == 4 * 5 * 2 * cfg.d_model * 4  # 5 conv layers, two fp32 rows a slot
-    assert held["kv_bytes"] == 4 * 2 * 2 * cfg.max_seq_len * 16 * 4 and held["ring_bytes"] == 0
-    assert held["state_bytes"] == held["tail_bytes"]  # nothing else: no state matrix
-    assert engine.kv_rows()[1] == 4 * cfg.max_seq_len
 
 
 def test_the_other_conv_users_name_their_tails_too():
@@ -402,12 +317,11 @@ def test_the_donation_rule_counts_what_a_program_relays():
 
 
 def _served_cells():
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    files = {c["name"]: os.path.join(root, c["file"]) for c in manifest["configs"]}
+    files = {c["name"]: os.path.join(ROOT, c["file"]) for c in manifest["configs"]}
     for cell in manifest["workloads"]:
-        with open(os.path.join(root, "benchmark", "workloads", cell["name"] + ".json")) as f:
+        with open(os.path.join(ROOT, "benchmark", "workloads", cell["name"] + ".json")) as f:
             server = json.load(f).get("server")
         if server and cell["chips"] == 1:
             yield pytest.param(files[cell["config"]], server, id=cell["name"])

@@ -3,11 +3,13 @@ linear-attention invariant — parallel forward == prefill + recurrent decode
 — on a model mixing all three layer types."""
 
 import dataclasses
+import hashlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from served_contract import ServedCase, tiny_cfg, trace_pins
 
 from orion_tpu.models import (
     LRAClassifier,
@@ -161,3 +163,54 @@ def test_remat_skip_matches():
         lambda a, b: np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5),
         jax.grad(loss(m))(params), jax.grad(loss(ms))(params),
     )
+
+
+# -- the two oldest presets are what they were ---------------------------------
+# Pinned in the file of the preset they pin (the served configurations' are in
+# their own files' ``ServedCase.pins``): a PR that adds a field to
+# ``ModelConfig`` and changes what these trace or compute fails HERE. All read
+# on the parent of PR 59 (44d93ca) at the sizes of the presets' own
+# ``rehearse`` blocks; until then tests/test_granite_hybrid.py pinned the
+# programs (at sizes of its own, where PR 51 changed ``hybrid_1b3``'s piece: a
+# window layer's piece writes its last min(length, window) rows into the ring
+# at their slots, one scatter, where it rebuilt all ``window`` rows) and
+# tests/test_qwen3_next.py the tree and the logits.
+LM_1B3 = ServedCase("lm_1b3", over=dict(max_seq_len=128, remat=False), pins={
+    "forward": "cc4f861c36f01b53", "prefill": "8eb8b78443b25087",
+    "piece": "793ceeba9b1a24ea", "step": "6d977d51dc7e0331"})
+HYBRID_1B3 = ServedCase("hybrid_1b3", over=dict(max_seq_len=128, remat=False), pins={
+    "forward": "962e37b8ef9c4072", "prefill": "1e3f293050ea13a7",
+    "piece": "05e89162f9914671", "step": "45b69d7543bf72e4"})
+# sha256 of (path, shape, dtype) of every parameter of the full preset, and of
+# the float32 logits of the tiny model on seeded weights and tokens
+WAS = {
+    "lm_1b3": ("93d48b1c0999df4354acee038db1d0c81c82da52d701a68fb879ac4340bdc304",
+               "641480d61d6da9f1b26b5f27fe15dab90d9d160e9d2d998714d8cdba622e0b50"),
+    "hybrid_1b3": ("93d48b1c0999df4354acee038db1d0c81c82da52d701a68fb879ac4340bdc304",
+                   "1c35d13dea3f6a75aed8e152abb8d758151b7e779726826c80c657efb0755945"),
+}
+
+
+@pytest.mark.parametrize("program", sorted(LM_1B3.pins))
+@pytest.mark.parametrize("case", [LM_1B3, HYBRID_1B3], ids=lambda c: c.name)
+def test_oldest_presets_trace_the_pinned_programs(case, program):
+    assert trace_pins(case, (program,))[program] == case.pins[program]
+
+
+def fingerprints(case):
+    shapes = jax.eval_shape(
+        TransformerLM(get_config(case.name)).init, jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
+    tree = hashlib.sha256(repr([
+        (jax.tree_util.keystr(p), x.shape, str(x.dtype))
+        for p, x in jax.tree_util.tree_leaves_with_path(shapes)
+    ]).encode()).hexdigest()
+    model = TransformerLM(tiny_cfg(case))
+    toks = jax.random.randint(jax.random.key(1), (2, 64), 0, 256)
+    params = jax.jit(model.init)(jax.random.key(0), toks)
+    logits = np.asarray(jax.jit(model.apply)(params, toks), np.float32)
+    return tree, hashlib.sha256(logits.tobytes()).hexdigest()
+
+
+@pytest.mark.parametrize("case", [LM_1B3, HYBRID_1B3], ids=lambda c: c.name)
+def test_oldest_presets_are_bitwise_what_they_were(case):
+    assert fingerprints(case) == WAS[case.name]

@@ -4,105 +4,119 @@ attention layer with no position term, experts that work in a latent (a
 sigmoid top-k router with a selection bias over a router wider than the
 experts held, two squared-ReLU matrices an expert, an ungated shared expert on
 the full width), and one block in six that is a mixer alone; against
-``benchmark/reference/plain_nemotron_h.py``; tiny, CPU, fp32."""
+``benchmark/reference/plain_nemotron_h.py``; tiny, CPU, fp32. The contract
+every served configuration takes is ``tests/served_contract.py``'s."""
 
 import dataclasses
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from served_contract import (
+    ServedCase, ServedContract, Share, Walk, config_file, moe_stats, served_fixture, tiny_cfg,
+)
 
-from orion_tpu.generate import SampleConfig, generate
 from orion_tpu.models.configs import get_config, step_pattern_blocks
 from orion_tpu.models.mixers import MIXERS
-from orion_tpu.models.moe import STAT_NAMES, MoEMLP, expert_form, masks_rows, stats_vector
+from orion_tpu.models.moe import STAT_NAMES, MoEMLP, expert_form, masks_rows
 from orion_tpu.models.transformer import TransformerLM, init_decode_state
-from orion_tpu.serving import DecodeRequest, SlotEngine
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(ROOT, "benchmark"))
-from reference import plain_nemotron_h as ref  # noqa: E402
 
 PUBLISHED = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
              "EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
-# the preset's six blocks (ssm+E x3, ssm alone, attention+E, ssm+E) at toy
-# widths: 4 of a 16-wide router's experts held, 5 chosen
-TINY = dict(vocab_size=256, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
-            ssm_heads=8, ssm_head_dim=8, ssm_state=16, ssm_groups=2,
-            moe_hidden=48, moe_latent=32, moe_shared_hidden=96, n_experts=4,
-            moe_router_width=16, moe_top_k=5, moe_route_bias=0.1, prefill_group=1,
-            max_seq_len=64, dtype="float32", param_dtype="float32")
+# the rehearse block is the preset's six blocks (ssm+E x3, ssm alone,
+# attention+E, ssm+E) at toy widths: 4 of a 16-wide router's experts held, 5
+# chosen
 T = 29
 P = 8  # rows of a prompt piece
-LOGIT_TOL = 5e-5  # fp32 against fp32 on logits of ~4: summation order only
-GREEDY = SampleConfig(temperature=0.0)
+CASE = ServedCase(
+    "nemotron_3_super_120b", seq=T,
+    logit_tol=5e-5,  # fp32 against fp32 on logits of ~4: summation order only
+    over=dict(max_seq_len=64),
+    moved=("scale", "out_norm"),  # a norm taken over other channels shows too
+    floor=1.0,
+    # a whole-prompt ``prefill`` padded to a bucket, its state taken at the real
+    # length, then steps; pieces of 8 and 5 of 8
+    walk=Walk(n=13, piece=P, steps=3, steps_from="padded", live=True, padded=3,
+              against="reference", backends=("xla",)),
+    # every share's routed part is computed in the latent and up-projected
+    # there (the up-projection is linear)
+    share=Share(experts=("experts_up", "experts_down"), part_tol=2e-5, sum_tol=5e-5),
+    # the chip's programs: the row lists, the state-space step's kernel, the
+    # grouped product over live tiles, two slots' pieces a program, the carry
+    # held once
+    engines=(("pallas_interpret", True, {"prefill_group": 2}),),
+    engine=dict(slots=4, chunk=4, prefill_buckets=(8, 16, 32), prefill_chunk=8),
+    prompts=((0, 0, 5), (1, 0, 8), (0, 3, 29)),
+    served_gap=5e-5,  # the reference's own choice to a logit gap of rounding
+)
+served = served_fixture(CASE)
 
 
-def tiny_cfg(backend="xla", **over):
-    return dataclasses.replace(
-        get_config("nemotron_3_super_120b"), backend=backend, **{**TINY, **over})
+class TestServed(ServedContract):
+    case = CASE
 
+    def published(self, cfg):
+        assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (4096, 32, 2, 128)
+        assert not cfg.rotary and cfg.qk_norm == "none" and cfg.attn_scale is None and not cfg.attn_gate
+        assert (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups) == (128, 64, 128, 8)
+        assert cfg.ssm_heads * cfg.ssm_head_dim == 2 * cfg.d_model and cfg.ssm_conv_width == 4
+        assert cfg.resolved_layer_types == ("ssm", "ssm", "ssm", "ssm", "softmax", "ssm")
+        assert cfg.mixer_only == "3" and [cfg.has_mlp(i) for i in range(6)] == [
+            True, True, True, False, True, True]
+        assert [cfg.moe_at(i) for i in range(6)] == [True, True, True, False, True, True]
+        assert (cfg.mlp, cfg.moe_hidden, cfg.moe_latent, cfg.moe_shared_hidden) == (
+            "relu2", 2688, 1024, 5376)
+        assert expert_form(cfg)[0] == ("up", "down") and not cfg.moe_shared_gated
+        assert (cfg.n_experts, cfg.resolved_router_width, cfg.moe_expert_offset, cfg.moe_top_k) == (
+            128, 512, 0, 22)
+        assert (cfg.moe_score, cfg.moe_route_scale, cfg.moe_gate_eps) == ("sigmoid", 5.0, 1e-20)
+        assert cfg.moe_route_bias > 0 and cfg.moe_held and masks_rows(cfg)
+        assert cfg.moe_ep_buffer == cfg.resolved_router_width / cfg.n_experts  # nothing can drop
+        assert (cfg.vocab_size, cfg.tie_embeddings, cfg.max_seq_len) == (32768, False, 4096)
+        assert cfg.norm == "rmsnorm" and cfg.norm_eps == 1e-5 and cfg.norm_placement == "pre"
+        assert cfg.pos_embed == "none" and cfg.embed_scale == 1.0
+        states = jax.eval_shape(lambda: init_decode_state(cfg, 2))
+        assert [sorted(s) for s in states] == [["conv", "s"]] * 4 + [["k", "v"]] + [["conv", "s"]]
+        # two heads of a group side by side on lanes: 4.19 MB a row a layer
+        assert states[0]["s"].shape == (2, 64, 128, 128) and states[0]["s"].dtype == jnp.float32
+        assert states[0]["conv"].shape == (2, 3 * 10240)
+        assert states[4]["k"].shape == (2, 2, 4096, 128)
 
-def spec_of(cfg, **over):
-    return {**dict(
-        layer_types=cfg.resolved_layer_types, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-        head_dim=cfg.head_dim, ssm_heads=cfg.ssm_heads, ssm_head_dim=cfg.ssm_head_dim,
-        ssm_state=cfg.ssm_state, ssm_groups=cfg.ssm_groups, norm_eps=cfg.norm_eps,
-        top_k=cfg.moe_top_k, experts_held=cfg.n_experts, expert_offset=cfg.moe_expert_offset,
-        router_width=cfg.resolved_router_width, route_scale=cfg.moe_route_scale), **over}
+    def share_layer(self, served, spec, p, x):
+        return served.ref.latent_experts(spec, p, x)
 
+    def after_share(self, served, whole, p, x, want, stats):
+        """The counters of the two-matrix path say so too; and the layer every
+        expert of which is held, through the training forward's
+        sort-and-ragged-dot form, is the same layer."""
+        assert p["experts_up"].shape == (16, 32, 48) and p["experts_down"].shape == (16, 48, 32)
+        for s in stats:
+            assert 0 < s["experts_live"] <= 4 and s["tiles_live"] >= s["experts_live"]
+            assert s["rows_max_expert"] * 4 >= s["rows_held"]
+        plain = MoEMLP(dataclasses.replace(whole, backend="xla")).apply({"params": p}, x)
+        assert float(jnp.abs(plain - want).max()) < 5e-5
 
-@pytest.fixture(scope="module")
-def model_params():
-    cfg = tiny_cfg()
-    model = TransformerLM(cfg)
-    toks = jax.random.randint(jax.random.key(1), (2, T), 0, cfg.vocab_size)
-    params = jax.jit(model.init)(jax.random.key(0), toks[:, :16])
-    # norm weights off 1, so that a norm left out (or taken over other
-    # channels) shows
-    params = jax.tree_util.tree_map_with_path(
-        lambda path, x: x + 0.3 * jax.random.normal(jax.random.key(len(str(path))), x.shape)
-        if "scale" in str(path) or "out_norm" in str(path) else x, params)
-    with jax.default_matmul_precision("highest"):
-        want = ref.forward(spec_of(cfg), params, toks)
-        got = model.apply(params, toks)
-    yield cfg, params, toks, want, got
-    jax.clear_caches()  # ROADMAP C13: a worker's compiled programs map memory
+    def after_boundary(self, engine):
+        return np.asarray(engine.moe_rows)
 
-
-# -- the preset ----------------------------------------------------------------------
-
-
-def test_preset_is_the_published_shape():
-    cfg = get_config("nemotron_3_super_120b")
-    assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (4096, 32, 2, 128)
-    assert not cfg.rotary and cfg.qk_norm == "none" and cfg.attn_scale is None and not cfg.attn_gate
-    assert (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups) == (128, 64, 128, 8)
-    assert cfg.ssm_heads * cfg.ssm_head_dim == 2 * cfg.d_model and cfg.ssm_conv_width == 4
-    assert cfg.resolved_layer_types == ("ssm", "ssm", "ssm", "ssm", "softmax", "ssm")
-    assert cfg.mixer_only == "3" and [cfg.has_mlp(i) for i in range(6)] == [
-        True, True, True, False, True, True]
-    assert [cfg.moe_at(i) for i in range(6)] == [True, True, True, False, True, True]
-    assert (cfg.mlp, cfg.moe_hidden, cfg.moe_latent, cfg.moe_shared_hidden) == (
-        "relu2", 2688, 1024, 5376)
-    assert expert_form(cfg)[0] == ("up", "down") and not cfg.moe_shared_gated
-    assert (cfg.n_experts, cfg.resolved_router_width, cfg.moe_expert_offset, cfg.moe_top_k) == (
-        128, 512, 0, 22)
-    assert (cfg.moe_score, cfg.moe_route_scale, cfg.moe_gate_eps) == ("sigmoid", 5.0, 1e-20)
-    assert cfg.moe_route_bias > 0 and cfg.moe_held and masks_rows(cfg)
-    assert cfg.moe_ep_buffer == cfg.resolved_router_width / cfg.n_experts  # nothing can drop
-    assert (cfg.vocab_size, cfg.tie_embeddings, cfg.max_seq_len) == (32768, False, 4096)
-    assert cfg.norm == "rmsnorm" and cfg.norm_eps == 1e-5 and cfg.norm_placement == "pre"
-    assert cfg.pos_embed == "none" and cfg.embed_scale == 1.0
-    states = jax.eval_shape(lambda: init_decode_state(cfg, 2))
-    assert [sorted(s) for s in states] == [["conv", "s"]] * 4 + [["k", "v"]] + [["conv", "s"]]
-    # two heads of a group side by side on lanes: 4.19 MB a row a layer
-    assert states[0]["s"].shape == (2, 64, 128, 128) and states[0]["s"].dtype == jnp.float32
-    assert states[0]["conv"].shape == (2, 3 * 10240)
-    assert states[4]["k"].shape == (2, 2, 4096, 128)
+    def after_engine(self, served, run, backend, donate):
+        """The engine's memory account is the states' and the counters are the
+        held-rows path's."""
+        cfg, prompts = run.cfg, served.prompts
+        stats = dict(zip(STAT_NAMES, np.sum(run.counted, axis=0)))
+        # five expert layers x five experts a row that counts: every prompt row
+        # and every step a slot emitted at (at least the 8 after each first token)
+        rows = sum(len(p) for p in prompts) + 3 * 8
+        assert stats["rows_routed"] % 25 == 0 and stats["rows_routed"] >= 25 * rows
+        assert 0 < stats["rows_held"] < stats["rows_routed"] and stats["dropless_overflow"] == 0
+        assert stats["experts_live"] > 0 and stats["tiles_live"] >= stats["experts_live"]
+        held = run.engine.held_bytes
+        state = 4 * 5 * 8 * 8 * 16 * 4  # five layers' fp32 S a slot
+        tails = 4 * 5 * 3 * (64 + 2 * 2 * 16) * 4
+        assert held["tail_bytes"] == tails and held["state_bytes"] == state + tails
+        assert held["kv_bytes"] == 4 * 2 * 2 * cfg.max_seq_len * 16 * 4 and held["ring_bytes"] == 0
+        assert run.engine.kv_rows()[1] == 4 * cfg.max_seq_len
 
 
 def test_the_published_widths_count_the_issues_parameters():
@@ -132,10 +146,7 @@ def test_the_config_file_recounts_the_deployment():
     """``benchmark/configs/nemotron_3_super_120b.json``: the published keys
     under their own names, the model as run equal to the preset, and the
     numbers its ``deployment`` states recounted from the shapes."""
-    import json
-
-    with open(os.path.join(ROOT, "benchmark", "configs", "nemotron_3_super_120b.json")) as f:
-        spec = json.load(f)
+    spec = config_file("nemotron_3_super_120b")
     assert spec["hybrid_override_pattern"] == PUBLISHED and spec["num_hidden_layers"] == 88
     assert (spec["mamba_num_heads"], spec["mamba_head_dim"], spec["ssm_state_size"],
             spec["n_groups"], spec["conv_kernel"]) == (128, 64, 128, 8, 4)
@@ -143,10 +154,7 @@ def test_the_config_file_recounts_the_deployment():
             spec["moe_intermediate_size"], spec["moe_shared_expert_intermediate_size"],
             spec["routed_scaling_factor"]) == (512, 22, 1024, 2688, 5376, 5)
     assert spec["reduced"] == ["n_layers", "n_experts", "vocab_size"]
-    cfg = get_config("nemotron_3_super_120b")
-    for key, value in spec["model"].items():
-        if key != "rehearse":
-            assert getattr(cfg, key) == (tuple(value) if isinstance(value, list) else value), key
+    cfg = get_config("nemotron_3_super_120b")  # the model as run: the contract's preset test
     said = spec["deployment"]
     for number in ("4,648,163,712", "109,640,064", "35,655,680", "759,173,632", "5,505,024",
                    "9.30 GB", "3.26 GB", "25.5 MB"):
@@ -187,7 +195,7 @@ def test_every_builder_of_blocks_takes_the_same_form():
     from orion_tpu.models.configs import ModelConfig
     from orion_tpu.parallel.pipeline_lm import stage_group
 
-    cfg = tiny_cfg()
+    cfg = tiny_cfg(CASE)
     assert [cfg.block_form(i) for i in (2, 3)] == [
         {"use_moe": True, "mixer_only": False}, {"use_moe": False, "mixer_only": True}]
     assert stage_group(cfg) == 6  # one whole period: nothing in it repeats
@@ -206,33 +214,24 @@ def test_every_builder_of_blocks_takes_the_same_form():
     assert all(sorted(lm[f"block_{i}"]) == sorted(shapes[f"block_{i}"]) for i in range(4))
 
 
-def test_the_block_form_is_the_published_step_form(model_params):
+def test_the_block_form_is_the_published_step_form(served):
     """``block`` over the served tree (one or two steps a block) gives what the
     flat list of published layers gives, letter for letter."""
-    cfg, params, toks, want, _ = model_params
-    steps = ref.steps_of(spec_of(cfg), params)
+    steps = served.ref.steps_of(served.spec(), served.params)
     assert "".join(letter for letter, _ in steps) == PUBLISHED[:11]
     with jax.default_matmul_precision("highest"):
-        flat = ref.forward_steps(spec_of(cfg), params, toks)
-    np.testing.assert_array_equal(flat, want)
+        flat = served.ref.forward_steps(served.spec(), served.params, served.toks)
+    np.testing.assert_array_equal(flat, served.want)
 
 
 # -- the training forward against the reference -----------------------------------------
 
 
-def test_model_matches_the_reference(model_params):
-    """Logits of the whole forward through every layer kind."""
-    cfg, params, toks, want, got = model_params
-    assert float(jnp.abs(want).max()) > 1.0
-    np.testing.assert_allclose(got, want, atol=LOGIT_TOL)
-
-
-def test_the_loss_and_its_gradient_are_finite(model_params):
+def test_the_loss_and_its_gradient_are_finite(served):
     """``orion_tpu.train``'s forward and loss at a small size: the routed
     experts in the latent, the selection bias (no gradient reaches it) and the
     block without a feed-forward part all differentiate."""
-    cfg, params, toks, *_ = model_params
-    model = TransformerLM(cfg)
+    model, params, toks = served.model, served.params, served.toks
 
     def loss(p):
         logits = model.apply(p, toks[:, :-1])
@@ -254,11 +253,10 @@ def test_the_loss_and_its_gradient_are_finite(model_params):
     "the router reads the latent", "the bias in the weights", "no bias", "no scaling factor",
     "rotary on the attention layer", "the shared expert in the latent",
     "a feed-forward part after every mixer"])
-def test_the_comparison_sees(model_params, monkeypatch, patch):
+def test_the_comparison_sees(served, monkeypatch, patch):
     """The tolerance is tight enough to tell the model from a reference that
     differs in one of the mechanisms."""
-    cfg, params, toks, want, got = model_params
-    spec = spec_of(cfg)
+    ref, cfg, params, spec = served.ref, served.cfg, served.params, served.spec()
     f32 = lambda w: jnp.asarray(w, jnp.float32)  # noqa: E731
     if patch == "one norm over all groups":
         monkeypatch.setattr(ref, "gated_group_norm", lambda s, y, z, w: ref.rms(s, y * jax.nn.silu(z), w))
@@ -305,33 +303,32 @@ def test_the_comparison_sees(model_params, monkeypatch, patch):
         blk = params["params"]
         params = {"params": {**blk, "block_3": {**blk["block_3"], "norm2": blk["block_2"]["norm2"],
                                                 "mlp": blk["block_2"]["mlp"]}}}
-    with jax.default_matmul_precision("highest"):
-        other = ref.forward(spec, params, toks)
-    assert float(jnp.abs(other - got).max()) > 20 * LOGIT_TOL, patch
+    served.differs(spec, params)
 
 
 # -- the gated norm by groups ------------------------------------------------------------
 
 
-def test_the_gated_norm_is_taken_over_each_group(monkeypatch):
+def test_the_gated_norm_is_taken_over_each_group(served, monkeypatch):
     """The mixer's output norm at 2 groups is the reference's by groups and is
     NOT one norm over all channels; at 1 group the two are one and the mixer
     gives what it gave (``granite_4_0_h_micro``'s form)."""
     x = jax.random.normal(jax.random.key(0), (2, 12, 64))
     outs = {}
     for groups in (1, 2):
-        cfg = tiny_cfg(ssm_groups=groups)
+        cfg = tiny_cfg(CASE, ssm_groups=groups)
         mixer = MIXERS["ssm"](cfg, "ssm")
-        params = mixer.init(jax.random.key(1), x)
+        params = jax.jit(mixer.init)(jax.random.key(1), x)
         p = dict(params["params"])
         p["out_norm"] = p["out_norm"] + 0.3 * jax.random.normal(jax.random.key(2), p["out_norm"].shape)
         got = mixer.apply({"params": p}, x)
+        ref = served.ref
         with jax.default_matmul_precision("highest"):
-            by_group = ref.ssm(spec_of(cfg), p, x)
+            by_group = ref.ssm(served.spec(cfg), p, x)
             with monkeypatch.context() as patched:
                 patched.setattr(ref, "gated_group_norm",
                                 lambda s, y, z, w: ref.rms(s, y * jax.nn.silu(z), w))
-                one_norm = ref.ssm(spec_of(cfg), p, x)
+                one_norm = ref.ssm(served.spec(cfg), p, x)
         outs[groups] = (got, by_group, one_norm)
         np.testing.assert_allclose(got, by_group, atol=2e-5)
     assert float(jnp.abs(outs[1][1] - outs[1][2]).max()) < 1e-6  # one group: the same norm
@@ -341,66 +338,19 @@ def test_the_gated_norm_is_taken_over_each_group(monkeypatch):
 # -- the experts in the latent ---------------------------------------------------------
 
 
-def _moe_apply(cfg, p, x, live=None):
-    return MoEMLP(cfg).apply({"params": p}, x, live, mutable=["moe_stats"])
-
-
-def _stats(sown):
-    return dict(zip(STAT_NAMES, (int(v) for v in stats_vector(sown.get("moe_stats", {})))))
-
-
-@pytest.mark.parametrize("backend", ["xla", "pallas_interpret"])
-def test_share_sum_of_all_chips_equals_the_uncut_layer(backend):
-    """16 experts over 4 chips, 4 held each: every share's routed part is
-    computed in the latent and up-projected there; the four partial sums plus
-    the shared expert ONCE are the uncut reference layer (the up-projection
-    is linear); every routed pair has one owner, nothing drops, and the
-    counters of the two-matrix path say so."""
-    cfg = tiny_cfg(backend)
-    whole = dataclasses.replace(cfg, n_experts=16, moe_router_width=16)
-    p = TransformerLM(whole).init(
-        jax.random.key(3), jnp.zeros((1, 8), jnp.int32))["params"]["block_1"]["mlp"]
-    assert p["experts_up"].shape == (16, 32, 48) and p["experts_down"].shape == (16, 48, 32)
-    x = jax.random.normal(jax.random.key(2), (2, 40, cfg.d_model))
-    live = jnp.ones((2, 40), bool)
-    with jax.default_matmul_precision("highest"):
-        want = ref.latent_experts(spec_of(whole), p, x)
-        shared = ref.shared_expert(spec_of(whole), p, x)
-    total, held = jnp.zeros_like(x), 0
-    for chip in range(4):
-        mine_cfg = dataclasses.replace(cfg, moe_expert_offset=4 * chip)
-        mine = {**p, **{n: p[n][4 * chip:4 * chip + 4] for n in ("experts_up", "experts_down")}}
-        got, sown = _moe_apply(mine_cfg, mine, x, live)
-        s = _stats(sown)
-        assert s["dropless_overflow"] == 0 and s["rows_routed"] == 2 * 40 * 5
-        assert 0 < s["experts_live"] <= 4 and s["tiles_live"] >= s["experts_live"]
-        assert s["rows_max_expert"] * 4 >= s["rows_held"]
-        held += s["rows_held"]
-        with jax.default_matmul_precision("highest"):
-            part = ref.latent_experts(spec_of(mine_cfg), mine, x, shared=False)
-        assert float(jnp.abs(got - shared - part).max()) < 2e-5
-        total = total + (got - shared)
-    assert held == 2 * 40 * 5
-    assert float(jnp.abs(total + shared - want).max()) < 5e-5
-    # the layer every expert of which is held, through the training forward's
-    # sort-and-ragged-dot form: the same layer
-    plain = MoEMLP(dataclasses.replace(whole, backend="xla")).apply({"params": p}, x)
-    assert float(jnp.abs(plain - want).max()) < 5e-5
-
-
-def test_rows_that_do_not_count_route_nowhere():
+def test_rows_that_do_not_count_route_nowhere(served):
     """A row outside ``live`` adds nothing to any counter and its experts'
     output is the shared expert's alone."""
-    cfg = tiny_cfg()
+    cfg = tiny_cfg(CASE)
     layer = MoEMLP(cfg)
     x = jax.random.normal(jax.random.key(2), (6, cfg.d_model))
-    params = layer.init(jax.random.key(0), x)
+    params = jax.jit(layer.init)(jax.random.key(0), x)
     live = jnp.array([True, False, True, True, False, True])
     got, sown = layer.apply(params, x, live, mutable=["moe_stats"])
     every, sown_all = layer.apply(params, x, jnp.ones((6,), bool), mutable=["moe_stats"])
-    assert _stats(sown)["rows_routed"] == 4 * 5 and _stats(sown_all)["rows_routed"] == 6 * 5
+    assert moe_stats(sown)["rows_routed"] == 4 * 5 and moe_stats(sown_all)["rows_routed"] == 6 * 5
     with jax.default_matmul_precision("highest"):
-        shared = ref.shared_expert(spec_of(cfg), params["params"], x)
+        shared = served.ref.shared_expert(served.spec(cfg), params["params"], x)
     np.testing.assert_allclose(got[jnp.array([1, 4])], shared[jnp.array([1, 4])], atol=2e-5)
     np.testing.assert_allclose(got[jnp.array([0, 2, 3, 5])], every[jnp.array([0, 2, 3, 5])], atol=2e-5)
 
@@ -409,7 +359,7 @@ def test_the_latent_projections_write_their_own_scope():
     """``moe_latent`` names the two projections, beside ``moe_route``,
     ``moe_experts`` and ``moe_shared``, in the lowered program's name stacks;
     a layer without a latent has no such scope and no such leaves."""
-    cfg = tiny_cfg()
+    cfg = tiny_cfg(CASE)
     x = jnp.zeros((4, cfg.d_model))
     layer = MoEMLP(cfg)
     params = jax.eval_shape(lambda: layer.init(jax.random.key(0), x))
@@ -427,17 +377,6 @@ def test_the_latent_projections_write_their_own_scope():
 # -- the serving path: pieces, a group of pieces and steps against ONE full forward ------
 
 
-@pytest.fixture(scope="module")
-def programs(model_params):
-    cfg, params, *_ = model_params
-    model = TransformerLM(cfg)
-    piece = jax.jit(lambda p, x, st, off, n: model.apply(
-        p, x, st, off, n, method=model.prefill_extend_step))
-    step = jax.jit(lambda p, tok, st, t, live: model.apply(
-        p, tok, st, t, None, live, method=model.decode_step))
-    return model, piece, step
-
-
 def _pieces(model, params, piece, toks, n):
     states = init_decode_state(model.cfg, 1, jnp.float32)
     out = []
@@ -449,14 +388,14 @@ def _pieces(model, params, piece, toks, n):
     return out, states
 
 
-def test_pieces_then_steps_match_one_full_forward(programs, model_params):
+def test_pieces_then_steps_match_one_full_forward(served):
     """``prefill_extend`` in pieces (one padded, one shorter than the conv's
     three-row tail) then ``decode_step``s of two slots at different positions,
     one sitting a step out: every logit row read on the way is the
     reference's full forward's at that position. The block that is a mixer
     alone passes through both methods."""
-    model, piece, step = programs
-    cfg, params, toks, want, _ = model_params
+    prog, params, toks, want = served.programs(), served.params, served.toks, served.want
+    model, piece = prog.model, prog.piece
     starts, read, rows = (19, 17), [], []
     for b, n in enumerate(starts):
         got, states = _pieces(model, params, piece, toks[b], n)
@@ -468,39 +407,22 @@ def test_pieces_then_steps_match_one_full_forward(programs, model_params):
         emitting = np.array([True, i != 2])
         tok = jnp.asarray([toks[b, t[b]] if emitting[b] else 7 for b in range(2)])
         mask = jnp.asarray(emitting)
-        logits, new = step(params, tok, states, jnp.asarray(t, jnp.int32), mask)
+        logits, new = prog.step(params, tok, states, jnp.asarray(t, jnp.int32), None, mask)
         states = jax.tree.map(
             lambda n, o: jnp.where(mask.reshape((-1,) + (1,) * (n.ndim - 1)), n, o), new, states)
         read += [(b, int(t[b]), logits[b]) for b in range(2) if emitting[b]]
         t = t + emitting
     assert len(read) == 6 + 8 + 7
     for b, pos, logits in read:
-        np.testing.assert_allclose(logits, want[b, pos], atol=LOGIT_TOL, err_msg=f"{b} {pos}")
+        np.testing.assert_allclose(logits, want[b, pos], atol=CASE.logit_tol, err_msg=f"{b} {pos}")
 
 
-def test_prefill_then_a_step_match_one_full_forward(programs, model_params):
-    """A whole-prompt ``prefill`` padded to a bucket, its state taken at the
-    real length, then steps."""
-    model, _, step = programs
-    cfg, params, toks, want, _ = model_params
-    n = 13
-    padded = jnp.zeros((1, 16), jnp.int32).at[0, :n].set(toks[0, :n])
-    logits, states = jax.jit(lambda p, x, n: model.apply(p, x, n, method=model.prefill_last))(
-        params, padded, jnp.int32(n))
-    np.testing.assert_allclose(logits[0], want[0, n - 1], atol=LOGIT_TOL)
-    states = jax.tree.map(lambda a: jnp.concatenate([a, a]), states)
-    for pos in range(n, n + 3):
-        logits, states = step(params, jnp.asarray([toks[0, pos]] * 2), states,
-                              jnp.full((2,), pos, jnp.int32), jnp.ones((2,), bool))
-        np.testing.assert_allclose(logits[1], want[0, pos], atol=LOGIT_TOL)
-
-
-def test_a_group_of_pieces_is_each_piece_alone(programs, model_params):
+def test_a_group_of_pieces_is_each_piece_alone(served):
     """``prefill_extend_group``: two sequences' pieces in one program (their
     feed-forward rows together, a block without one passing them on) give
     each the logits and the state its piece gives alone."""
-    model, piece, _ = programs
-    cfg, params, toks, want, _ = model_params
+    prog, params, toks, want = served.programs(), served.params, served.toks, served.want
+    model, piece = prog.model, prog.piece
     firsts, singles = [], []
     for b in range(2):
         _, st = _pieces(model, params, piece, toks[b], P)
@@ -509,64 +431,12 @@ def test_a_group_of_pieces_is_each_piece_alone(programs, model_params):
     rows = jnp.stack([toks[0, P:2 * P], jnp.zeros((P,), jnp.int32).at[:5].set(toks[1, P:P + 5])])
     for b in range(2):
         singles.append(piece(params, rows[b:b + 1], firsts[b], jnp.int32(P), lengths[b]))
-    logits, states = jax.jit(lambda p, x, st, off, n: model.apply(
-        p, x, st, off, n, method=model.prefill_extend_group))(
-            params, rows, firsts, jnp.full((2,), P, jnp.int32), lengths)
+    logits, states = prog.group(params, rows, firsts, jnp.full((2,), P, jnp.int32), lengths)
     for b in range(2):
-        np.testing.assert_allclose(logits[b], singles[b][0][0], atol=LOGIT_TOL)
-        np.testing.assert_allclose(logits[b], want[b, P + int(lengths[b]) - 1], atol=LOGIT_TOL)
+        np.testing.assert_allclose(logits[b], singles[b][0][0], atol=CASE.logit_tol)
+        np.testing.assert_allclose(logits[b], want[b, P + int(lengths[b]) - 1], atol=CASE.logit_tol)
         for got, alone in zip(jax.tree.leaves(states[b]), jax.tree.leaves(singles[b][1])):
             np.testing.assert_allclose(got, alone, atol=2e-5)
-
-
-# -- through the engine ------------------------------------------------------------------
-
-
-def test_engine_serves_as_generate_and_as_the_reference(model_params):
-    """Through ``SlotEngine`` under the interpreted kernels with the carry
-    held once (the chip's programs: the row lists, the state-space step's
-    kernel, the grouped product over live tiles, two slots' pieces a program):
-    three requests resident together at different positions, pieces and decode
-    interleaved. Each request's ids are ``generate()``'s for it alone on the
-    XLA backend, and, teacher-forced through the reference's ONE full forward,
-    each served id is the reference's own choice to a logit gap of rounding;
-    the engine's memory account is the states' and the counters are the
-    held-rows path's."""
-    cfg, params, toks, _, _ = model_params
-    served = dataclasses.replace(cfg, backend="pallas_interpret", prefill_group=2)
-    prompts = [np.asarray(toks[0, :5]), np.asarray(toks[1, :8]), np.asarray(toks[0, 3:29])]
-    engine = SlotEngine(TransformerLM(served), params, slots=4, chunk=4,
-                        prefill_buckets=(8, 16, 32), prefill_chunk=8)
-    engine.donate_carry = True
-    for i, p in enumerate(prompts):
-        engine.admit(DecodeRequest(prompt=p, max_new_tokens=9, sample=GREEDY, seed=i), tag=i)
-    done, counted = {}, np.zeros((len(STAT_NAMES),), np.int64)
-    while engine.busy:
-        for tag, res in engine.step():
-            assert res.status == "ok", res.status
-            done[tag] = np.asarray(res.tokens).reshape(-1)
-        counted += np.asarray(engine.moe_rows)
-    for i, p in enumerate(prompts):
-        alone = generate(TransformerLM(cfg), params, jnp.asarray(p)[None], 9, GREEDY)
-        np.testing.assert_array_equal(done[i], np.asarray(alone)[0, -9:])
-        whole = jnp.concatenate([jnp.asarray(p), jnp.asarray(done[i])])[None]
-        with jax.default_matmul_precision("highest"):
-            logits = ref.forward(spec_of(cfg), params, whole)[0, len(p) - 1:-1]
-        mine = jnp.take_along_axis(logits, jnp.asarray(done[i])[:, None], axis=-1)[:, 0]
-        assert float((logits.max(-1) - mine).max()) <= LOGIT_TOL
-    stats = dict(zip(STAT_NAMES, counted))
-    # five expert layers x five experts a row that counts: every prompt row
-    # and every step a slot emitted at (at least the 8 after each first token)
-    rows = sum(len(p) for p in prompts) + 3 * 8
-    assert stats["rows_routed"] % 25 == 0 and stats["rows_routed"] >= 25 * rows
-    assert 0 < stats["rows_held"] < stats["rows_routed"] and stats["dropless_overflow"] == 0
-    assert stats["experts_live"] > 0 and stats["tiles_live"] >= stats["experts_live"]
-    held = engine.held_bytes
-    state = 4 * 5 * 8 * 8 * 16 * 4  # five layers' fp32 S a slot
-    tails = 4 * 5 * 3 * (64 + 2 * 2 * 16) * 4
-    assert held["tail_bytes"] == tails and held["state_bytes"] == state + tails
-    assert held["kv_bytes"] == 4 * 2 * 2 * cfg.max_seq_len * 16 * 4 and held["ring_bytes"] == 0
-    assert engine.kv_rows()[1] == 4 * cfg.max_seq_len
 
 
 def test_the_carry_is_mostly_state_and_is_donated():

@@ -79,7 +79,7 @@ BUCKETS = (8, 16, 32)
 @pytest.fixture(scope="module")
 def mp():
     model = TransformerLM(CFG)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
     return model, params
 
 

@@ -5,14 +5,13 @@ rehearsal sizes, bf16 compute over fp32 parameters (what ``lm_1b3``'s serve
 cells hand the ``Server``)."""
 
 import dataclasses
-import json
-import os
 import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from served_contract import rehearsal_cfg
 
 from orion_tpu import aot
 from orion_tpu import generate as gen
@@ -22,7 +21,6 @@ from orion_tpu.models.transformer import TransformerLM
 from orion_tpu.obs.trace import Tracer
 from orion_tpu.serving import DecodeRequest, ServeConfig, Server
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 GREEDY = SampleConfig(temperature=0.0)
 
 # family -> (the benchmark configuration it is cut from, the leaves that must
@@ -45,13 +43,8 @@ FAMILIES = {
 def family_cfg(config: str, **over) -> ModelConfig:
     """The configuration's file at its rehearsal sizes, as the benchmark
     builds it, with bf16 compute over fp32 parameters."""
-    fields = json.load(open(os.path.join(ROOT, "benchmark", "configs", config + ".json")))["model"]
-    fields.update(fields.pop("rehearse"))
-    if fields.get("layer_types") is not None:
-        fields["layer_types"] = tuple(fields["layer_types"])
-    fields.update(dtype="bfloat16", param_dtype="float32", max_seq_len=256, backend="xla")
-    fields.update(over)
-    return ModelConfig(name=config, **fields)
+    return rehearsal_cfg(config, **{**dict(
+        dtype="bfloat16", param_dtype="float32", max_seq_len=256, backend="xla"), **over})
 
 
 @pytest.fixture(scope="module", params=list(FAMILIES))
