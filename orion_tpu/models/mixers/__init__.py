@@ -83,7 +83,11 @@ def kernel_bh(cfg: ModelConfig, mesh, fn, *args):
     GSPMD mesh whose data axes split, a Mosaic kernel must be
     manualized (XLA cannot auto-partition tpu_custom_call) — shard_map
     over (dp, fsdp, tp) via parallel/kernel_shard.py; everywhere else
-    the call goes straight through."""
+    the call goes straight through. A residual ``fn`` names for a rematted
+    block's policy (the flash forward's output and rows) is kept in either
+    form: ``shard_map``'s partial evaluation hands the policy down into its
+    body, where the kept bytes are one device's shard
+    (tests/test_remat_keeps.py)."""
     from orion_tpu.ops.dispatch import resolve
     from orion_tpu.parallel.kernel_shard import needs_manual, shard_map_bh
 

@@ -113,6 +113,7 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from orion_tpu.models.configs import ModelConfig
 from orion_tpu.models.mixers import drawn_in, drawn_kernel_init, ungated_activation
@@ -1108,7 +1109,6 @@ def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt, act=jax.nn.
         valid = off < gs[c]  # else tile padding / spare buffer
         pair = order[jnp.clip(tight[c] + off, 0, m - 1)]
         token = pair // k
-        gate_row = jnp.where(valid, gates[pair], 0.0)
         by_list = matmul.by_list
         if by_list:
             from orion_tpu.ops.pallas import moe_rows
@@ -1123,6 +1123,16 @@ def _held_rows_ffn(x2, flat, gates, ws, lo, budget: int, matmul, dt, act=jax.nn.
                 rank < per_pair(tight + gs), rank + per_pair(starts - tight),
                 jax.lax.stop_gradient(gates), n,
             )
+            # everything the experts and the backward read of the sort, by
+            # name: a rematted block that lists it (models/transformer.py::
+            # REMAT_KEEPS) holds these few MB and leaves the counting sort,
+            # the [M, el] selects and the lists' [slots, k, N] sums out of
+            # its recompute; the router's floats are computed again
+            listed, lists, seg, gs, valid, pair = jax.tree.map(
+                lambda a: checkpoint_name(a, "moe_lists"),
+                (listed, lists, seg, gs, valid, pair),
+            )
+        gate_row = jnp.where(valid, gates[pair], 0.0)
 
     with scope("moe_experts"):
         # pad rows are zeros: they flow through the FFN as zeros

@@ -21,6 +21,7 @@ kernel trick) + rotary applied inside softmax/swa layers.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, List, Optional, Tuple
 
 import flax.linen as nn
@@ -36,6 +37,7 @@ from orion_tpu.models.mixers import (
     drawn_in,
     ungated_activation,
 )
+from orion_tpu.obs import trace as _trace
 
 Array = jax.Array
 State = Dict[str, Array]
@@ -229,6 +231,49 @@ class Block(nn.Module):
         return self._mlp_residual(x + self._branch(h)), upd
 
 
+# What a rematted block keeps of its forward for its backward, by
+# ``checkpoint_name``: the names of its mixer's layer type and, for a block
+# with experts, those under "moe". Everything without a listed name is
+# computed again, as under no policy, and a block that lists nothing is the
+# program it was. A name belongs here when its recompute is dear and its bytes
+# are few (PERF.md section 6, PR 60):
+REMAT_KEEPS: Dict[str, Tuple[str, ...]] = {
+    # the flash forward's output and rows (ops/pallas/flash_attention.py::
+    # _flash_lse_vjp_fwd). ``gated_softmax`` attends causally over the WHOLE
+    # sequence: at T 8,192 its forward costs 65 ms a GB held. A sliding
+    # window's costs a third of that and the dense hybrids have spent the
+    # memory on ``remat_skip``, so ``swa`` / ``softmax`` blocks carry the same
+    # names through the same kernel and keep neither
+    "gated_softmax": ("flash_out", "flash_lse"),
+    # the integer lists of the experts' counting sort where the rows move by
+    # list (models/moe.py::_held_rows_ffn): a few MB for a fifth of the layer
+    "moe": ("moe_lists",),
+}
+
+
+def _keeps(names: Tuple[str, ...]):
+    """``save_only_these_names(*names)`` that also counts what it keeps, once
+    an equation and trace (``obs.trace.compile_totals``'s
+    ``remat_kept_residuals`` / ``remat_kept_bytes``)."""
+    listed = jax.checkpoint_policies.save_only_these_names(*names)
+
+    def policy(prim, *avals, **params):
+        keep = listed(prim, *avals, **params)
+        if keep:
+            _trace.remat_kept(sum(a.size * a.dtype.itemsize for a in avals))
+        return keep
+
+    return policy
+
+
+@functools.lru_cache(maxsize=None)
+def _rematted(layer_type: str, use_moe: bool):
+    """``Block`` under ``nn.remat`` with the names policy of what it is made
+    of: one lifted class a (mixer, feed-forward) kind, not one a model."""
+    names = REMAT_KEEPS.get(layer_type, ()) + (REMAT_KEEPS["moe"] if use_moe else ())
+    return nn.remat(Block, static_argnums=(3,), policy=_keeps(names))
+
+
 class TransformerLM(nn.Module):
     """Decoder LM over token ids; see module docstring for the 3 methods."""
 
@@ -258,13 +303,10 @@ class TransformerLM(nn.Module):
             )
         else:
             assert cfg.pos_embed == "none", cfg.pos_embed
-        block_cls = Block
-        if cfg.remat:
-            block_cls = nn.remat(Block, static_argnums=(3,))
         # remat_skip: the last K blocks keep their activations (configs.py)
-        first_remat = cfg.n_layers - max(0, cfg.remat_skip)
+        first_remat = (cfg.n_layers - max(0, cfg.remat_skip)) if cfg.remat else 0
         self.blocks = [
-            (block_cls if i < first_remat else Block)(
+            (_rematted(lt, cfg.moe_at(i)) if i < first_remat else Block)(
                 cfg, lt, True, self.mesh,
                 quant=self.quant, name=f"block_{i}", **cfg.block_form(i),
             )

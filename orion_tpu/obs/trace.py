@@ -120,9 +120,11 @@ COMPILE_COUNTERS = (
     "compile_ms_total", "trace_lower_ms_total", "kernel_bodies_traced",
 )
 # beside them the calls of kernel entries, traced or found in jax's trace
-# cache: no event each (a program holds hundreds), so only the process counts
+# cache, and the residuals a rematted block's names policy kept with their
+# bytes: no event each (a program holds hundreds), so only the process counts
 _COMPILE_TOTALS: Dict[str, float] = dict.fromkeys(
-    COMPILE_COUNTERS + ("kernel_call_sites",), 0)
+    COMPILE_COUNTERS
+    + ("kernel_call_sites", "remat_kept_residuals", "remat_kept_bytes"), 0)
 _COMPILE_SINKS: List[weakref.WeakMethod] = []
 # entry packages whose import is under way (one may import the other), and
 # whether the process's first ``setup.import`` has been written
@@ -507,10 +509,18 @@ def kernel_call_site() -> None:
     _COMPILE_TOTALS["kernel_call_sites"] += 1
 
 
+def remat_kept(nbytes: int) -> None:
+    """One named residual of ``nbytes`` that a rematted block's policy kept
+    for its backward (``models/transformer.py::_keeps``, while jax traces a
+    gradient program): what that program holds so as not to compute it twice."""
+    _COMPILE_TOTALS["remat_kept_residuals"] += 1
+    _COMPILE_TOTALS["remat_kept_bytes"] += nbytes
+
+
 def compile_totals() -> Dict[str, float]:
-    """The process's :data:`COMPILE_COUNTERS` so far and
-    ``kernel_call_sites``: ``kernel_bodies_traced`` over it is the share of
-    kernel calls whose body had to be traced."""
+    """The process's :data:`COMPILE_COUNTERS` so far, ``kernel_call_sites``
+    (``kernel_bodies_traced`` over it is the share of kernel calls whose body
+    had to be traced) and ``remat_kept_residuals`` / ``remat_kept_bytes``."""
     return dict(_COMPILE_TOTALS)
 
 

@@ -62,6 +62,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -541,16 +542,24 @@ def _flash_lse_vjp_fwd(q, k, v, scale, causal, window, shift, q_offset, bq,
         q, k, v, scale, causal, window, bq, bk, interpret, shift=shift,
         q_offset=q_offset,
     )
-    return (out, lse), (q, k, v, out, lse)
+    # the two residuals only this kernel can give carry a name: a rematted
+    # block whose policy lists them (models/transformer.py::REMAT_KEEPS) runs
+    # this forward once a step and not again in its backward; under any other
+    # policy, and outside a checkpoint, a name lowers to nothing. lse is named
+    # as [BH, T] rows, 4 bytes a query (the kernel's [BH, T, 1] column is a
+    # 128-lane tile a query in HBM); q, k, v carry none: a caller recomputes them
+    out = checkpoint_name(out, "flash_out")
+    rows = checkpoint_name(lse[..., 0], "flash_lse")
+    return (out, lse), (q, k, v, out, rows)
 
 
 def _flash_lse_vjp_bwd(scale, causal, window, shift, q_offset, bq, bk,
                        interpret, res, gs):
-    q, k, v, out, lse = res
+    q, k, v, out, rows = res
     g, dlse = gs
     dq, dk, dv = _flash_bwd_flat(
-        q, k, v, out, lse, g.astype(q.dtype), scale, causal, window, bq, bk,
-        interpret, shift=shift, dlse=dlse, q_offset=q_offset,
+        q, k, v, out, rows[..., None], g.astype(q.dtype), scale, causal, window,
+        bq, bk, interpret, shift=shift, dlse=dlse, q_offset=q_offset,
     )
     return dq, dk, dv
 

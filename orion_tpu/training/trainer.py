@@ -728,6 +728,21 @@ class Trainer:
             cell = registry.counter(key)
             cell.inc(total - cell.value())
 
+    def _first_step(self, batch: Array, span) -> Dict[str, float]:
+        """The step that builds the step program, inside its set-up span:
+        the span also says what the rematted blocks' names policy kept while
+        jax traced it (``models/transformer.py::REMAT_KEEPS``): the residuals
+        a step holds from forward to backward and their MB, 0 and 0.0 where
+        nothing engaged."""
+        before = compile_totals()
+        metrics = self.step(batch)
+        after = compile_totals()
+        kept, nbytes = (after[k] - before[k]
+                        for k in ("remat_kept_residuals", "remat_kept_bytes"))
+        span.note(remat_kept_residuals=int(kept), remat_kept_mb=round(nbytes / 1e6, 3))
+        self._first_loss = metrics["loss"]
+        return metrics
+
     def step(self, batch: Array) -> Dict[str, float]:
         assert self.state is not None, (
             "Trainer was built with materialize=False (AOT planning only); "
@@ -835,9 +850,10 @@ class Trainer:
                 # first call traces, lowers and compiles or loads it
                 with self.trace.span("train.dispatch", "step", step=step), \
                         self._once("setup.first_step") as first:
-                    metrics = self.step(batch)
-                if first is not NULL_SPAN:
-                    self._first_loss = metrics["loss"]
+                    if first is NULL_SPAN:
+                        metrics = self.step(batch)
+                    else:
+                        metrics = self._first_step(batch, first)
                 # only materialize metrics on the host at log cadence — reading a
                 # device scalar every step would serialize the pipeline
                 if step % cfg.log_every == 0 or step == cfg.steps:
